@@ -1,0 +1,840 @@
+#!/usr/bin/env python
+"""Standing proof that paddle_tpu's main paths start and run on the chip.
+
+    python chip_smoke.py              # one TPU v5e chip: every phase below
+    python chip_smoke.py --multichip  # four chips: the sharded phase only
+
+One process, TPU only: without a TPU it exits non-zero and prints no
+result.  Every phase raises on failure, so any failure is a non-zero
+exit.  The phases drive the entry points a user calls, at full
+published width (depth and weights are what they are: BERT-base is 12
+layers, the weights are random from a seed):
+
+  train      BERT-base b64 s512 bf16-O2 through nn.Layer -> amp.decorate
+             -> jit.TrainStep(donate=True): one compile, finite and
+             falling loss, the flash kernel in the executable
+  static     the same BERT-base through paddle.static Program ->
+             Executor, default flags: the fusion tier and the fused
+             optimizer as a user on a TPU gets them
+  serve      GenerationEngine over PagedDecoderLM (hidden 2048, head_dim
+             128) behind the real HTTP plane, 8 /generate requests
+             through serving.Client, tokens compared with the same model
+             on the reference attention tier
+  kernels    each Pallas kernel alone against its jnp reference
+  callbacks  a to_static function with a traced print and assert
+
+Timings printed here are SMOKE timings (one unrepeated reading each, on
+a machine that was just handed over) — not benchmark numbers.
+
+The last line of stdout is exactly
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": n}}``.
+"""
+import argparse
+import contextlib
+import gc
+import io
+import json
+import os
+import sys
+import threading
+import time
+
+import numpy as np
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+# ---------------------------------------------------------------- widths --
+BERT_BASE = dict(vocab=30522, hidden=768, layers=12, heads=12, ffn=3072,
+                 seq=512, batch=64, dropout=0.1)
+# The f32 static program keeps the full batch: compiled for a described
+# v5e it has 14.86 GiB live of the chip's 15.75 (PERF.md, Findings), so
+# it fits — as long as the phase before it left nothing on the device.
+STATIC_BATCH = 64
+SERVE = dict(vocab_size=32000, hidden=2048, num_layers=8, num_heads=16,
+             num_kv_heads=4, ffn=8192, seed=0, dyadic=True)
+SERVE_ENGINE = dict(num_slots=8, page_size=16, max_context=2048,
+                    prompt_buckets=(64, 256, 1024, 2048))
+SERVE_PROMPT_LENS = (16, 60, 130, 250, 400, 700, 1100, 1500)
+SERVE_NEW_TOKENS = 32
+
+
+def log(msg):
+    print(msg, flush=True)
+
+
+# ------------------------------------------------- what jax compiled/ran --
+class CompileLog:
+    """Every executable jax builds or loads, as jax itself reports it
+    (``jax.monitoring``): (function name, seconds) per compile, and how
+    many of them the persistent cache answered."""
+
+    def __init__(self):
+        import jax.monitoring as mon
+        self.compiles = []
+        self.cache_hits = 0
+        mon.register_event_duration_secs_listener(self._on_duration)
+        mon.register_event_listener(self._on_event)
+
+    def _on_duration(self, name, secs, **kw):
+        if name == "/jax/core/compile/backend_compile_duration":
+            self.compiles.append((kw.get("fun_name", "?"), float(secs)))
+
+    def _on_event(self, name, **kw):
+        if name == "/jax/compilation_cache/cache_hits":
+            self.cache_hits += 1
+
+    def mark(self):
+        return len(self.compiles), self.cache_hits
+
+    def since(self, mark):
+        """(compiles, their seconds, cache hits) since ``mark``."""
+        new = self.compiles[mark[0]:]
+        return new, sum(s for _, s in new), self.cache_hits - mark[1]
+
+
+def executable_text(module_name):
+    """Optimised HLO of the one live executable jax named
+    ``module_name`` — what actually runs on the device, whether it was
+    compiled in this process or loaded from the persistent cache."""
+    import jax
+    found = []
+    for ex in jax.devices()[0].client.live_executables():
+        mod = ex.hlo_modules()[0]
+        if mod.name == module_name:
+            found.append(mod.to_string())
+    if len(found) != 1:
+        raise AssertionError(
+            f"expected one live executable named {module_name!r}, "
+            f"found {len(found)}")
+    return found[0]
+
+
+def mosaic_kernels(text, at_least, what):
+    """Count the Mosaic custom calls in an executable's text.  An
+    interpret-mode kernel lowers to plain HLO and leaves none."""
+    n = text.count('custom_call_target="tpu_custom_call"')
+    if n < at_least:
+        raise AssertionError(
+            f"{what}: {n} Mosaic kernels in the executable, "
+            f"expected at least {at_least}")
+    return n
+
+
+def selected_since(before):
+    """Pallas kernels selected (at trace time) since ``before``."""
+    from paddle_tpu.ops.pallas.support import kernel_selections
+    return {k: v - before.get(k, 0) for k, v in kernel_selections.items()
+            if v != before.get(k, 0)}
+
+
+def selections():
+    from paddle_tpu.ops.pallas.support import kernel_selections
+    return dict(kernel_selections)
+
+
+def peak_bytes():
+    import jax
+    return jax.devices()[0].memory_stats()["peak_bytes_in_use"]
+
+
+def report(phase, clog, mark, extra):
+    compiles, secs, hits = clog.since(mark)
+    big = sorted(compiles, key=lambda c: -c[1])[:3]
+    log(f"[{phase}] compiles={len(compiles)} compile_s={secs:.1f} "
+        f"persistent_cache_hits={hits} "
+        f"slowest={[(n, round(s, 1)) for n, s in big]}")
+    log(f"[{phase}] peak_bytes_in_use(process so far)={peak_bytes()} "
+        + " ".join(f"{k}={v}" for k, v in extra.items()))
+
+
+def finite(values, what):
+    if not all(np.isfinite(v) for v in values):
+        raise AssertionError(f"{what}: non-finite value in {values}")
+
+
+# ----------------------------------------------------------------- train --
+def phase_train(clog, cfg=BERT_BASE, steps=6):
+    """bench.bench_bert's construction: Layer -> O2 decorate -> TrainStep."""
+    import jax.numpy as jnp
+
+    import bench
+    import paddle_tpu as paddle
+    import paddle_tpu.nn.functional as F
+    from paddle_tpu import amp, optimizer
+    from paddle_tpu.jit import TrainStep
+    from paddle_tpu.optimizer.clip import ClipGradByGlobalNorm
+
+    mark = clog.mark()
+    paddle.seed(2024)
+    model = bench.build_model(cfg["vocab"], cfg["hidden"], cfg["layers"],
+                              cfg["heads"], cfg["ffn"], cfg["seq"],
+                              cfg["dropout"])
+    opt = optimizer.AdamW(learning_rate=1e-4,
+                          parameters=model.parameters(), weight_decay=0.01,
+                          grad_clip=ClipGradByGlobalNorm(1.0),
+                          multi_precision=True)
+    model, opt = amp.decorate(model, opt, level="O2", dtype="bfloat16")
+
+    def loss_fn(out, labels):
+        return F.linear_cross_entropy(
+            out.reshape([-1, cfg["hidden"]]), model.head.weight,
+            model.head.bias, labels.reshape([-1]))
+
+    step = TrainStep(model, loss_fn, opt, n_inputs=1, donate=True)
+    rng = np.random.RandomState(0)
+    shape = (cfg["batch"], cfg["seq"])
+    x = jnp.asarray(rng.randint(0, cfg["vocab"], shape, dtype=np.int32))
+    y = jnp.asarray(rng.randint(0, cfg["vocab"], shape, dtype=np.int32))
+
+    losses, ms, marks = [], [], []
+    for _ in range(steps):
+        marks.append(clog.mark())
+        t0 = time.perf_counter()
+        losses.append(float(step(x, y)))        # float() waits for the chip
+        ms.append((time.perf_counter() - t0) * 1000)
+    finite(losses, "train loss")
+    if not losses[-1] < losses[0]:
+        raise AssertionError(f"train loss did not fall: {losses}")
+    n_step = sum("step_fn" in name for name, _ in clog.since(mark)[0])
+    late = clog.since(marks[2])[0]     # steps 0 and 1 may build helpers
+    if n_step != 1 or late:
+        raise AssertionError(
+            f"train step compiled {n_step} times (want 1); compiles "
+            f"after step 1: {late}")
+    # q/k/v dropout>0 at seq 512 meets the flash gate: fwd + 2 bwd
+    # kernels per layer would be 36; one is enough to prove the tier
+    n_kernels = mosaic_kernels(executable_text("jit_step_fn"), 3,
+                               "train step")
+    report("train", clog, mark, {
+        "losses": [round(v, 4) for v in losses],
+        "first_step_ms(compile incl.)": round(ms[0]),
+        "smoke_step_ms": [round(v, 1) for v in ms[2:]],
+        # flash attention is the only kernel TrainStep can select, and
+        # it does not count its selections
+        "mosaic_kernels(flash fwd+bwd)": n_kernels})
+
+
+# ---------------------------------------------------------------- static --
+def phase_static(clog, cfg=BERT_BASE, batch=STATIC_BATCH, steps=3):
+    """bench.build_bert_static through the Executor, default flags."""
+    import bench
+    import paddle_tpu as paddle
+    from paddle_tpu.observability import explain_compiles
+    from paddle_tpu.utils import monitor
+
+    mark, sel0 = clog.mark(), selections()
+    if batch != cfg["batch"]:
+        log(f"[static] batch cut {cfg['batch']} -> {batch} (f32 program, "
+            f"16 GB chip); widths and seq {cfg['seq']} unchanged")
+    paddle.enable_static()
+    try:
+        prog, loss, feeds = bench.build_bert_static(
+            cfg["vocab"], cfg["hidden"], cfg["layers"], cfg["heads"],
+            cfg["ffn"], cfg["seq"], batch)
+        exe = paddle.static.Executor()
+        feed = feeds(np.random.RandomState(0))
+        losses, ms = [], []
+        for _ in range(steps):
+            t0 = time.perf_counter()
+            out = exe.run(prog, feed=feed, fetch_list=[loss])
+            losses.append(float(np.asarray(out[0])))
+            ms.append((time.perf_counter() - t0) * 1000)
+        finite(losses, "static loss")
+        if exe.compile_count != 1:
+            raise AssertionError(
+                f"Executor compiled {exe.compile_count} times, want 1")
+        rec = [r for r in explain_compiles("executor")["records"]
+               if r["identity"] == prog._serial][-1]
+        kernels = list(rec.get("kernels") or ())
+        n_epi = sum(k.startswith("fused_epilogue[") for k in kernels)
+        # BERT-base: each block's proj+residual+LayerNorm fuses (the FFN
+        # weights are past the kernel's gate), so one per layer
+        if n_epi < cfg["layers"] or "fused_adam" not in kernels:
+            raise AssertionError(
+                f"compile record should name >= {cfg['layers']} fused "
+                f"epilogues and fused_adam, has {kernels}")
+        if monitor.get_stat("predicted.executor.errors"):
+            raise AssertionError("the cost model failed on this program "
+                                 "(predicted.executor.errors > 0)")
+        # each epilogue is a forward and a backward kernel; the fused
+        # Adam adds one kernel per parameter XLA did not fold away
+        n_kernels = mosaic_kernels(executable_text("jit_train_fn"),
+                                   2 * n_epi + 1, "static step")
+        report("static", clog, mark, {
+            "losses": [round(v, 4) for v in losses],
+            "first_step_ms(compile incl.)": round(ms[0]),
+            "smoke_step_ms": [round(v, 1) for v in ms[1:]],
+            "record_kernels": sorted(set(kernels)),
+            "predicted_step_s": (rec.get("predicted") or {}).get(
+                "predicted_step_s"),
+            "mosaic_kernels": n_kernels,
+            "selected": selected_since(sel0)})
+        exe.close()
+    finally:
+        paddle.disable_static()
+        paddle.static.reset_default_programs()
+
+
+# ----------------------------------------------------------------- serve --
+def phase_serve(clog, model_cfg=SERVE, engine_cfg=SERVE_ENGINE,
+                prompt_lens=SERVE_PROMPT_LENS, new_tokens=SERVE_NEW_TOKENS):
+    """GenerationEngine behind serving.ServingServer, as tools/serve.py
+    wires an engine: bind not-ready, warm up, mark ready, serve."""
+    from paddle_tpu import serving
+    from paddle_tpu.core.flags import get_flag, set_flags
+    from paddle_tpu.ops import attention as attn
+
+    mark, sel0 = clog.mark(), selections()
+    model = serving.PagedDecoderLM(**model_cfg)
+    rng = np.random.RandomState(7)
+    prompts = [rng.randint(1, model_cfg["vocab_size"], (n,)).tolist()
+               for n in prompt_lens]
+
+    eng = serving.GenerationEngine(model, **engine_cfg)
+    log(f"[serve] weights float32, KV pool dtype {eng.config.dtype} "
+        f"(serving/kv_cache.py default), pool {eng._pool.kv[0].shape}")
+    srv = serving.ServingServer(None, host="127.0.0.1", port=0,
+                                generation=eng, ready=False).start()
+    try:
+        t0 = time.perf_counter()
+        variants = eng.warmup()
+        warm_s = time.perf_counter() - t0
+        srv.mark_ready()
+        warm = clog.mark()
+
+        results, errors, lat = {}, [], {}
+
+        def one(i):
+            try:
+                t = time.perf_counter()
+                results[i] = serving.Client(srv.url, timeout=600).generate(
+                    prompts[i], max_new_tokens=new_tokens)
+                lat[i] = (time.perf_counter() - t) * 1000
+            except Exception as e:  # noqa: BLE001 - re-raised below
+                errors.append((i, e))
+
+        threads = [threading.Thread(target=one, args=(i,))
+                   for i in range(len(prompts))]
+        t0 = time.perf_counter()
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(900)
+        wall = time.perf_counter() - t0
+        if errors:
+            raise errors[0][1]
+        if len(results) != len(prompts):
+            raise AssertionError("a /generate request did not return")
+        st = eng.stats()
+        c = st["counters"]
+        late = clog.since(warm)[0]
+        late = [x for x in late if "step_fn" in x[0] or "prefill" in x[0]]
+        if st["recompiles_after_warmup"] or late:
+            raise AssertionError(
+                f"compiles after warmup: engine says "
+                f"{st['recompiles_after_warmup']}, jax says {late}")
+        if c["failed"] or c["decode_errors"] or c["decode_retries"]:
+            raise AssertionError(f"engine errors: {dict(c)}")
+        for i, toks in results.items():
+            if len(toks) != new_tokens or not all(
+                    0 <= t < model_cfg["vocab_size"] for t in toks):
+                raise AssertionError(f"request {i}: bad tokens {toks}")
+        # the engine holds its AOT executables itself, one per context
+        # bucket: one paged kernel per layer in the widest decode step
+        width = max(b for kind, b in eng._execs if kind == "decode")
+        n_kernels = mosaic_kernels(
+            eng._execs[("decode", width)].as_text(),
+            model_cfg["num_layers"], "decode step")
+    finally:
+        srv.close()
+        eng.close()
+
+    # the same model on the reference tier (gather + jnp attention): the
+    # only flag this script touches, restored right after
+    prev = get_flag("use_pallas_kernels")
+    set_flags({"use_pallas_kernels": False})
+    attn.register_paged_attention_kernel(None)
+    try:
+        ref_eng = serving.GenerationEngine(model, **engine_cfg)
+        try:
+            ref = {i: ref_eng.generate_sync(prompts[i],
+                                            max_new_tokens=new_tokens,
+                                            timeout=900)
+                   for i in range(len(prompts))}
+            if any("tpu_custom_call" in ex.as_text()
+                   for ex in ref_eng._execs.values()):
+                raise AssertionError("the reference tier's executables "
+                                     "hold a Mosaic kernel")
+        finally:
+            ref_eng.close()
+    finally:
+        set_flags({"use_pallas_kernels": prev})
+        attn.register_paged_attention_kernel(None)
+    same = [i for i in sorted(results) if results[i] == ref[i]]
+    first_diff = {i: next(j for j, (a, b) in enumerate(
+        zip(results[i], ref[i])) if a != b)
+        for i in sorted(results) if i not in same}
+    # The two tiers are not bitwise twins on the chip: the kernel
+    # accumulates f32 at HIGHEST precision, the gather reference at the
+    # backend's default (one bf16 pass), so over 32 greedy steps a
+    # near-tie between the top two of 32000 logits can flip.  A broken
+    # kernel diverges in every request within a token or two; a correct
+    # one agrees on most.  At least two requests must agree on all 32.
+    if len(same) < 2:
+        raise AssertionError(
+            f"kernel-tier tokens equal the reference tier's in only "
+            f"{len(same)} of {len(results)} requests; first "
+            f"differences at {first_diff}")
+    report("serve", clog, mark, {
+        "warm_variants": variants, "warmup_s": round(warm_s, 1),
+        "requests": len(results), "wall_s": round(wall, 2),
+        "smoke_request_ms": [round(lat[i]) for i in sorted(lat)],
+        "smoke_decode_step_ms_p50": (st["step_ms"] or {}).get("p50"),
+        "tokens_equal_reference": f"{len(same)}/{len(results)}",
+        "first_difference_at": first_diff,
+        "mosaic_kernels_decode": n_kernels,
+        "selected": selected_since(sel0)})
+
+
+# --------------------------------------------------------------- kernels --
+# Each Pallas kernel alone against the repo's jnp reference.  The
+# references run at matmul precision 'highest': the kernels accumulate
+# f32 exactly, the backend's default is one bf16 pass.  Tolerances are
+# relative to max|reference|: 1e-4 for f32 (reduction order only), 3e-2
+# for bf16 (the kernels keep the f32 accumulator through the epilogue,
+# the references round between ops; gradients get 4x).
+F32_TOL, BF16_TOL = 1e-4, 3e-2
+
+
+def _close(got, want, tol, what):
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    err = float(np.max(np.abs(got - want)))
+    scale = max(float(np.max(np.abs(want))), 1e-6)
+    if not np.isfinite(err) or err > tol * scale:
+        raise AssertionError(
+            f"{what}: max |diff| {err:.3e} > {tol:g} x max|ref| {scale:.3e}")
+    return float(f"{err / scale:.2e}")
+
+
+def _rnd(i, shape, dtype, scale=1.0):
+    import jax
+    import jax.numpy as jnp
+    x = jax.random.normal(jax.random.fold_in(jax.random.key(0), i), shape,
+                          jnp.float32)
+    return (x * scale).astype(dtype)
+
+
+def _sq(fn):
+    import jax.numpy as jnp
+    return lambda *a: jnp.sum(fn(*a).astype(jnp.float32) ** 2)
+
+
+def check_flash(errs, bert):
+    """Flash attention fwd+bwd at BERT-base heads, a slice of the batch."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas.flash_attention import (flash_attention,
+                                                       mha_reference)
+    shape = (4, bert["seq"], bert["heads"], bert["hidden"] // bert["heads"])
+    q, k, v = (_rnd(i, shape, jnp.bfloat16) for i in (1, 2, 3))
+    errs["flash_fwd"] = _close(jax.jit(flash_attention)(q, k, v),
+                               mha_reference(q, k, v), BF16_TOL,
+                               "flash fwd")
+    got = jax.jit(jax.grad(_sq(flash_attention), (0, 1, 2)))(q, k, v)
+    ref = jax.jit(jax.grad(_sq(mha_reference), (0, 1, 2)))(q, k, v)
+    for n, a, b in zip("qkv", got, ref):
+        errs[f"flash_d{n}"] = _close(a, b, 4 * BF16_TOL, f"flash d{n}")
+
+
+def check_flash_dropout(errs, bert):
+    """In-kernel dropout has no CPU oracle: the same seed must give the
+    same bits (forward and gradients), another seed must not."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    shape = (4, bert["seq"], bert["heads"], bert["hidden"] // bert["heads"])
+    q, k, v = (_rnd(i, shape, jnp.bfloat16) for i in (1, 2, 3))
+
+    def drop(q, k, v, seed):
+        out = flash_attention(q, k, v, dropout_p=bert["dropout"], seed=seed)
+        return jnp.sum(out.astype(jnp.float32) ** 2), out
+
+    vg = jax.jit(jax.value_and_grad(drop, (0, 1, 2), has_aux=True))
+    s1, s2 = (jnp.full((1, 1), s, jnp.int32) for s in (11, 12))
+    (_, o_a), g_a = vg(q, k, v, s1)
+    (_, o_b), g_b = vg(q, k, v, s1)
+    (_, o_c), _ = vg(q, k, v, s2)
+    same = all(bool(jnp.array_equal(a, b))
+               for a, b in zip((o_a,) + g_a, (o_b,) + g_b))
+    if not same or bool(jnp.array_equal(o_a, o_c)):
+        raise AssertionError("flash dropout is not a pure function of "
+                             "its seed")
+    finite([float(jnp.sum(g.astype(jnp.float32))) for g in g_a],
+           "flash dropout grads")
+    if bool(jnp.array_equal(o_a, jax.jit(flash_attention)(q, k, v))):
+        raise AssertionError("flash dropout dropped nothing")
+    errs["flash_dropout"] = "deterministic per seed"
+
+
+def check_epilogue(errs, bert, f32_rows):
+    """The recipe the Executor realises on BERT-base (proj + bias +
+    residual + LayerNorm): f32 at the static phase's rows, bf16 at the
+    train phase's."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas.fused_epilogue import (
+        fused_linear_epilogue, reference_epilogue)
+    H = bert["hidden"]
+    stages = (("add",), ("layer_norm", 1e-5, True, True))
+    for dtype, rows, tol in (
+            (jnp.float32, f32_rows, F32_TOL),
+            (jnp.bfloat16, bert["batch"] * bert["seq"], BF16_TOL)):
+        tag = jnp.dtype(dtype).name
+        args = (_rnd(10, (rows, H), dtype),
+                _rnd(11, (H, H), dtype, H ** -0.5),
+                _rnd(12, (H,), dtype, 0.1),
+                _rnd(13, (rows, H), dtype),
+                (1 + _rnd(14, (H,), jnp.float32, 0.1)).astype(dtype),
+                _rnd(15, (H,), dtype, 0.1))
+
+        def fused(x, w, b, *ops):
+            return fused_linear_epilogue(x, w, b, stages, ops)
+
+        def plain(x, w, b, *ops):
+            return reference_epilogue(x, w, b, stages, ops)
+
+        errs[f"epilogue_{tag}"] = _close(
+            jax.jit(fused)(*args), jax.jit(plain)(*args), tol,
+            f"epilogue fwd {tag}")
+        got = jax.jit(jax.grad(_sq(fused), tuple(range(6))))(*args)
+        ref = jax.jit(jax.grad(_sq(plain), tuple(range(6))))(*args)
+        for n, a, r in zip(("dx", "dw", "db", "dres", "dgamma", "dbeta"),
+                           got, ref):
+            errs[f"epilogue_{tag}_{n}"] = _close(
+                a, r, 4 * tol, f"epilogue {n} {tag}")
+
+
+def check_adam(errs, bert):
+    """Fused Adam on the embedding-sized parameter vs optimizer.Adam."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas.fused_adam import fused_adam_update
+    from paddle_tpu.optimizer.optimizer import Adam
+    shape = (bert["vocab"], bert["hidden"])
+    p, g = _rnd(20, shape, jnp.float32), _rnd(21, shape, jnp.float32, 0.01)
+    opt = Adam(learning_rate=1e-3)
+    slots = opt.init_slots(p)
+    lr, step = jnp.float32(1e-3), jnp.float32(3.0)
+    (ref_p,), (ref_s,) = jax.jit(
+        lambda p, g, s, lr, step: opt.functional_update(
+            [p], [g], [s], lr, step))(p, g, slots, lr, step)
+    got = jax.jit(fused_adam_update)(p, g, slots["m"], slots["v"], lr, step)
+    for n, a, r in zip("pmv", got, (ref_p, ref_s["m"], ref_s["v"])):
+        errs[f"adam_{n}"] = _close(a, r, 1e-6, f"fused adam {n}")
+
+
+def check_paged(errs, serve, engine):
+    """Paged decode attention at the serve phase's geometry."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.attention import paged_attention_reference
+    from paddle_tpu.ops.pallas.paged_attention import \
+        paged_attention_decode
+    S, page = engine["num_slots"], engine["page_size"]
+    P = engine["max_context"] // page
+    hd = serve["hidden"] // serve["num_heads"]
+    pool = (serve["num_layers"], S * P, page, serve["num_kv_heads"], hd)
+    table = jnp.asarray(np.random.RandomState(3).permutation(
+        S * P).reshape(S, P), jnp.int32)
+    # lengths from one token to the full context, page edges included
+    lens = jnp.asarray(np.linspace(1, P * page, S).astype(np.int32))
+    lens = lens.at[1].set(page).at[2].set(page + 1)
+    for dtype, tol in ((jnp.float32, F32_TOL), (jnp.bfloat16, BF16_TOL)):
+        tag = jnp.dtype(dtype).name
+        q = _rnd(30, (S, serve["num_heads"], hd), dtype)
+        kp, vp = _rnd(31, pool, dtype), _rnd(32, pool, dtype)
+        got = jax.jit(lambda *a: paged_attention_decode(*a, layer=1))(
+            q, kp, vp, table, lens)
+        ref = jax.jit(lambda *a: paged_attention_reference(*a, layer=1))(
+            q, kp, vp, table, lens)
+        errs[f"paged_{tag}"] = _close(got, ref, tol,
+                                      f"paged attention {tag}")
+
+
+def check_chunk_matmul(errs, bert):
+    """The collective-matmul chunk kernel at a BERT FFN chunk."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas.collective_matmul import chunk_matmul
+    H = bert["hidden"]
+    for dtype, tol in ((jnp.float32, F32_TOL), (jnp.bfloat16, BF16_TOL)):
+        tag = jnp.dtype(dtype).name
+        x = _rnd(40, (4096, H), dtype)
+        w = _rnd(41, (H, bert["ffn"]), dtype, H ** -0.5)
+        errs[f"chunk_mm_{tag}"] = _close(
+            jax.jit(chunk_matmul)(x, w), jax.jit(jnp.matmul)(x, w), tol,
+            f"chunk matmul {tag}")
+
+
+def phase_kernels(clog, bert=BERT_BASE, static_batch=STATIC_BATCH,
+                  serve=SERVE, engine=SERVE_ENGINE):
+    import jax
+
+    from paddle_tpu.ops.pallas.support import interpret_mode
+    if interpret_mode():
+        raise AssertionError("Pallas interpret mode is on: not a TPU run")
+    mark, sel0, errs = clog.mark(), selections(), {}
+    with jax.default_matmul_precision("highest"):
+        check_flash(errs, bert)
+        check_flash_dropout(errs, bert)
+        check_epilogue(errs, bert, static_batch * bert["seq"])
+        check_adam(errs, bert)
+        check_paged(errs, serve, engine)
+        check_chunk_matmul(errs, bert)
+    gc.collect()
+    report("kernels", clog, mark, {
+        "tolerance": f"f32 {F32_TOL:g} / bf16 {BF16_TOL:g} (x max|ref|)",
+        "rel_err": errs, "selected": selected_since(sel0)})
+
+
+# ------------------------------------------------------------- callbacks --
+def phase_callbacks(clog):
+    """Host callbacks on this backend: a traced print shows the runtime
+    value, a traced assert checks it."""
+    import jax
+
+    import paddle_tpu as paddle
+    from paddle_tpu import jit
+
+    mark = clog.mark()
+
+    @jit.to_static
+    def f(x):
+        print("chip_smoke traced print:", x.sum())
+        assert x.sum() > 0, "chip_smoke traced assert"
+        return x * 2
+
+    buf = io.StringIO()
+    with contextlib.redirect_stdout(buf):
+        out = f(paddle.to_tensor(np.array([1.5, 2.0], np.float32)))
+        jax.effects_barrier()
+    if "chip_smoke traced print: 3.5" not in buf.getvalue():
+        raise AssertionError(f"traced print did not reach the host: "
+                             f"{buf.getvalue()!r}")
+    if not np.allclose(np.asarray(out.data), [3.0, 4.0]):
+        raise AssertionError("to_static result wrong")
+    try:
+        f(paddle.to_tensor(np.array([-1.0, 0.5], np.float32)))
+        jax.effects_barrier()
+    except Exception as e:  # noqa: BLE001 - the runtime wraps the AssertionError
+        if "chip_smoke traced assert" not in str(e):
+            raise
+    else:
+        raise AssertionError("a failing traced assert did not raise")
+    # the failed callback's token is still queued; left there, jax waits
+    # on it again at exit and prints the same traceback under a passing
+    # run (private name: there is no public way to drop a failed token)
+    from jax._src import dispatch
+    dispatch.runtime_tokens.clear()
+    report("callbacks", clog, mark, {"print": "ok", "assert": "ok"})
+
+
+# ------------------------------------------------------------- multichip --
+def phase_multichip(clog, cfg=BERT_BASE, batch=STATIC_BATCH, steps=3):
+    """README "Sharded training": fleet.init + sharding_rules on a
+    {dp: 2, mp: 2} mesh through the Executor, against the same seeded
+    program on one device of this host."""
+    import bench
+    import paddle_tpu as paddle
+    import paddle_tpu.distributed as dist
+    from paddle_tpu.distributed.mesh import get_mesh
+
+    mark = clog.mark()
+
+    def build(sharded):
+        """bench.build_bert_static, with the optimizer routed through
+        fleet when sharded (the README recipe)."""
+        wrap = None
+        if sharded:
+            strategy = dist.DistributedStrategy()
+            strategy.tensor_parallel = True
+            strategy.tensor_parallel_configs = {"tensor_parallel_degree": 2}
+            strategy.sharding_rules = MP_RULES
+            dist.fleet.init(is_collective=True, strategy=strategy)
+            wrap = dist.fleet.distributed_optimizer
+        return bench.build_bert_static(
+            cfg["vocab"], cfg["hidden"], cfg["layers"], cfg["heads"],
+            cfg["ffn"], cfg["seq"], batch, wrap_optimizer=wrap)
+
+    def run(sharded):
+        paddle.enable_static()
+        try:
+            prog, loss, feeds = build(sharded)
+            exe = paddle.static.Executor()
+            feed = feeds(np.random.RandomState(0))
+            losses, ms = [], []
+            for i in range(steps):
+                if i == 1:
+                    warm = clog.mark()
+                t0 = time.perf_counter()
+                out = exe.run(prog, feed=feed, fetch_list=[loss])
+                losses.append(float(np.asarray(out[0])))
+                ms.append((time.perf_counter() - t0) * 1000)
+            placement = _placement(exe, prog) if sharded else None
+            late = [c for c in clog.since(warm)[0] if "train_fn" in c[0]]
+            if exe.compile_count != 1 or late:
+                raise AssertionError(
+                    f"Executor compiled {exe.compile_count} times; jax "
+                    f"compiled after the first step: {late}")
+            exe.close()
+            return losses, ms, placement
+        finally:
+            paddle.disable_static()
+            paddle.static.reset_default_programs()
+
+    one, one_ms, _ = run(False)
+    gc.collect()
+    four, four_ms, placement = run(True)
+    finite(one + four, "multichip loss")
+    tol = 2e-2      # bf16-pass matmuls, and the one-device run takes the
+    #                 f32-exact fused epilogue the sharded run does not
+    if abs(four[0] - one[0]) > tol * abs(one[0]):
+        raise AssertionError(f"step-0 loss: 4 chips {four[0]} vs one "
+                             f"device {one[0]} (tolerance {tol:g} rel)")
+    if not (four[-1] < four[0] and one[-1] < one[0]):
+        raise AssertionError(f"losses not falling: {four} / {one}")
+    if dict(get_mesh().shape) != {"dp": 2, "mp": 2}:
+        raise AssertionError("mesh is not {dp: 2, mp: 2}")
+    report("multichip", clog, mark, {
+        "mesh": "{dp: 2, mp: 2}", "batch": batch,
+        "one_device_losses": [round(v, 4) for v in one],
+        "four_chip_losses": [round(v, 4) for v in four],
+        "smoke_step_ms_one_device": [round(v, 1) for v in one_ms[1:]],
+        "smoke_step_ms_four_chips": [round(v, 1) for v in four_ms[1:]],
+        **placement})
+
+
+# Ordered (regex, spec) rules over the static BERT's parameter names:
+# both embeddings split their rows over 'mp', every Linear weight its
+# columns, biases and LayerNorms replicate.  Every split halves an array
+# over mp=2 — which is what `_placement` checks shard by shard.  (A
+# valid layout, not the Megatron-minimal one: the recorded names do not
+# tell q/k/v from proj.)  Specs as tuples, the form the rule engine
+# takes besides PartitionSpec.
+MP_RULES = [
+    (r"embedding.*", ("mp", None)),
+    (r"linear.*\.w_0$", (None, "mp")),
+    (r".*", ()),
+]
+
+
+def _placement(exe, prog):
+    """Where parameters and optimizer slots really live, read from
+    ``addressable_shards`` — code that has only seen virtual CPU devices
+    may put everything on device 0."""
+    import jax
+    state = exe._states[prog._serial]
+    devices = {d.id for d in jax.devices()}
+    per_dev = {d: 0 for d in devices}
+    total = split = 0
+    arrays = list(state.p_arrays)
+    for slot in state.opt_state:
+        arrays.extend(slot.values())
+    for a in arrays:
+        if a.ndim == 0:
+            continue
+        ids = set()
+        for sh in a.addressable_shards:
+            per_dev[sh.device.id] += sh.data.nbytes
+            ids.add(sh.device.id)
+        if ids != devices:
+            raise AssertionError(
+                f"an array of shape {a.shape} lives on devices {ids}, "
+                f"not on all of {devices}")
+        total += a.nbytes
+        spec = getattr(a.sharding, "spec", ())
+        if any(s is not None for s in spec):
+            split += a.nbytes
+            want = a.nbytes // 2           # every rule splits over mp=2
+            got = a.addressable_shards[0].data.nbytes
+            if got != want:
+                raise AssertionError(
+                    f"{a.shape} {spec}: shard holds {got} bytes, the "
+                    f"rule implies {want}")
+    # replicated arrays cost their full size on each device, mp-split
+    # ones half: the per-device bytes must be exactly that
+    want_dev = (total - split) + split // 2
+    if len(set(per_dev.values())) != 1 or per_dev[min(devices)] != want_dev:
+        raise AssertionError(f"per-device bytes {per_dev}, want {want_dev}")
+    if split == 0:
+        raise AssertionError("no parameter was split by the rules")
+    return {"state_bytes_total": total, "state_bytes_mp_split": split,
+            "state_bytes_per_device": want_dev, "devices": sorted(devices)}
+
+
+# ------------------------------------------------------------------ main --
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--multichip", action="store_true",
+                    help="run ONLY the four-chip sharded-Executor phase "
+                         "and the one-device run it is compared with")
+    args = ap.parse_args(argv)
+
+    from paddle_tpu.core.xla_env import place_compile_cache
+    cache_dir = place_compile_cache()
+
+    import jax
+    import jaxlib
+    devs = jax.devices()
+    if devs[0].platform != "tpu":
+        sys.stderr.write(
+            f"chip_smoke needs a TPU; jax found {devs[0].platform!r} "
+            f"({devs[0].device_kind}). Nothing ran.\n")
+        return 1
+    want = 4 if args.multichip else 1
+    if len(devs) != want:
+        sys.stderr.write(f"chip_smoke{' --multichip' if args.multichip else ''}"
+                         f" needs {want} chip(s), jax found {len(devs)}.\n")
+        return 1
+    from importlib.metadata import version
+    log(f"jax {jax.__version__} jaxlib {jaxlib.__version__} "
+        f"libtpu {version('libtpu')}; {len(devs)} x {devs[0].device_kind}")
+    log(f"compile cache: {cache_dir} "
+        f"({'JAX_COMPILATION_CACHE_DIR' if os.environ.get('JAX_COMPILATION_CACHE_DIR') else 'set by core.xla_env.place_compile_cache'})")
+    log("timings below are smoke timings, not benchmark numbers")
+
+    clog = CompileLog()
+    t0 = time.perf_counter()
+    phases = ([phase_multichip] if args.multichip else
+              [phase_kernels, phase_callbacks, phase_train, phase_static,
+               phase_serve])
+    for phase in phases:
+        t = time.perf_counter()
+        phase(clog)
+        # the two BERT phases each need nearly the whole chip: what a
+        # phase leaves behind is the next one's out-of-memory
+        gc.collect()
+        log(f"[{phase.__name__[6:]}] passed in "
+            f"{time.perf_counter() - t:.1f}s; live device arrays after "
+            f"it: {sum(a.nbytes for a in jax.live_arrays())} bytes")
+    compiles, secs, hits = clog.since((0, 0))
+    log(f"all phases passed in {time.perf_counter() - t0:.1f}s; "
+        f"{len(compiles)} compiles ({secs:.1f}s), {hits} answered by the "
+        f"persistent cache")
+    print(json.dumps({"ok": True, "device": {
+        "platform": devs[0].platform, "kind": devs[0].device_kind,
+        "count": len(devs)}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -12,9 +12,8 @@ Design constraints:
 
 - **We serialize ourselves** through the AOT ``lower().compile()`` +
   ``jax.experimental.serialize_executable`` path, routed via
-  :mod:`paddle_tpu.core.jax_compat`.  jax's own persistent compilation
-  cache stays OFF: it heap-corrupts reloading NamedSharding
-  executables on jaxlib 0.4.37 (PR 8 caveat, core/xla_env.py).
+  :mod:`paddle_tpu.core.jax_compat`, independent of whether jax's own
+  persistent compilation cache is on (core/xla_env.py places that one).
 - **Stamped invalidation.**  Each entry carries a version/topology
   stamp (jax, jaxlib, backend platform, device kind, device count,
   format version).  Any mismatch on load is a *reject* — counted as
@@ -154,23 +153,20 @@ def _device_fingerprint_ok(compiled) -> bool:
     return True
 
 
-def _single_device(compiled) -> bool:
-    """Only single-device executables are cacheable (module docstring):
-    judge the *executable*, not the process — a predictor bucket
-    compiled for one device on a multi-device host is still safe."""
+def _single_device(compiled):
+    """The one device a single-device executable runs on, else None.
+    Only those are cacheable (module docstring): judge the
+    *executable*, not the process — a predictor bucket compiled for one
+    device on a multi-device host is still safe."""
     import jax
-    try:
-        devs = set()
-        in_sh, _ = compiled.input_shardings
-        for sh in jax.tree_util.tree_leaves((in_sh,
-                                             compiled.output_shardings)):
-            for d in getattr(sh, "device_set", ()):
-                devs.add((d.platform, d.id))
-        if devs:
-            return len(devs) == 1
-    except Exception:
-        pass
-    return len(jax.devices()) == 1
+    devs = {}
+    in_sh, _ = compiled.input_shardings
+    for sh in jax.tree_util.tree_leaves((in_sh, compiled.output_shardings)):
+        for d in getattr(sh, "device_set", ()):
+            devs[(d.platform, d.id)] = d
+    if not devs and len(jax.devices()) == 1:
+        return jax.devices()[0]
+    return next(iter(devs.values())) if len(devs) == 1 else None
 
 
 def load(component: str, signature: dict):
@@ -200,10 +196,14 @@ def load(component: str, signature: dict):
         _emit("reject", component=component, why="stamp mismatch",
               entry_stamp=entry.get("stamp"), want=stamp())
         return None
+    import jax
     try:
         from . import jax_compat
+        # load onto the one device it was compiled for; an id this
+        # process does not have is a KeyError, i.e. a reject
+        dev = {d.id: d for d in jax.devices()}[entry["device_id"]]
         compiled = jax_compat.deserialize_executable(
-            entry["payload"], entry["in_tree"], entry["out_tree"])
+            entry["payload"], entry["in_tree"], entry["out_tree"], [dev])
     except Exception as e:              # incompatible payload
         _count("rejects")
         _emit("reject", component=component, why=f"deserialize: {e}")
@@ -226,16 +226,16 @@ def store(component: str, signature: dict, compiled) -> bool:
     if not enabled():
         return False
     try:
-        if not _single_device(compiled):
+        dev = _single_device(compiled)
+        if dev is None:
             return False
         from . import jax_compat
-        if not jax_compat.executable_serialization_available():
-            return False
         payload, in_tree, out_tree = jax_compat.serialize_executable(
             compiled)
         entry = {"stamp": stamp(), "component": component,
                  "signature": {str(k): _freeze(v)
                                for k, v in signature.items()},
+                 "device_id": dev.id,
                  "payload": payload, "in_tree": in_tree,
                  "out_tree": out_tree}
         d = cache_dir()

@@ -1,8 +1,8 @@
 """Typed error system.
 
 TPU-native equivalent of the reference's ``PADDLE_ENFORCE_*`` macros and error
-taxonomy (reference: paddle/fluid/platform/enforce.h:410-505,
-errors.cc, error_codes.proto).  We keep the error-code taxonomy as exception
+hierarchy (reference: paddle/fluid/platform/enforce.h:410-505,
+errors.cc, error_codes.proto).  We keep the error-code hierarchy as exception
 classes so user code can catch narrow categories, and attach the offending op
 name the way ``AppendErrorOpHint`` does (reference: imperative/tracer.cc:188).
 """
@@ -12,10 +12,10 @@ from . import obs_hook
 
 
 class EnforceError(RuntimeError):
-    """Base of the taxonomy (reference: error_codes.proto).
+    """Base of the hierarchy (reference: error_codes.proto).
 
     When a flight recorder is installed (observability), constructing
-    any error in the taxonomy dumps the black box — the framework's
+    any error in the hierarchy dumps the black box — the framework's
     typed failures are exactly the crashes worth a post-mortem.  The
     handler dedups by exception object, so a later re-report (e.g. the
     Executor catching this error) never double-dumps."""

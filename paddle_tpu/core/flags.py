@@ -219,7 +219,7 @@ define_flag("perf_chip", "",
             "Roofline chip spec used to turn the cost model's predicted "
             "FLOPs/traffic into a predicted step time for the drift "
             "tracker (static/analysis/cost.CHIP_SPECS key).  Empty = "
-            "auto: 'cpu' on the CPU backend, 'v5e' on TPU.")
+            "auto: 'cpu' on the CPU backend, by device_kind on a TPU.")
 define_flag("pallas_interpret", False,
             "Let the automatic Pallas-tier selectors (the static "
             "Executor's epilogue-fusion pass, the fused Adam update, "
@@ -296,10 +296,8 @@ define_flag("compile_cache_dir", "",
             "backend/topology stamp, so a version or device change "
             "invalidates cleanly (compile_cache.rejects) instead of "
             "loading a stale executable.  We serialize ourselves via "
-            "jax.experimental.serialize_executable — jax's own "
-            "persistent compilation cache is deliberately NOT enabled "
-            "(it heap-corrupts reloading NamedSharding executables on "
-            "jaxlib 0.4.37; see core/xla_env.py / PR 8).  Empty = "
+            "jax.experimental.serialize_executable, whatever jax's own "
+            "persistent compilation cache is set to.  Empty = "
             "disabled (no filesystem traffic).")
 define_flag("metrics_dump_max_mb", 0.0,
             "Size-based rotation threshold for the FLAGS_metrics_dump_"

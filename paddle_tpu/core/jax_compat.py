@@ -1,111 +1,28 @@
-"""jax version-compatibility shims.
+"""The one seam around ``jax.experimental.serialize_executable``.
 
-The container pins whatever jax the TPU runtime ships; the source tracks
-current jax spellings.  Differences are absorbed here, in one place:
-
-- ``shard_map`` moved from ``jax.experimental.shard_map`` to the top
-  level in jax 0.5;
-- its replication-check kwarg was renamed ``check_rep`` → ``check_vma``
-  (jax 0.6).  Callers use the new name; older jax gets it translated;
-- ``jax.lax.axis_size`` (jax 0.6) falls back to ``jax.core.axis_frame``
-  inside a bound axis context;
-- ``jax.lax.pvary`` falls back to identity (only the new varying-type
-  checker needs the annotation; we run with it disabled);
-- ``jax.ffi`` (jax 0.5) falls back to ``jax.extend.ffi`` — same
-  surface (ffi_call / include_dir / register_ffi_target / pycapsule);
-- AOT executable serialization lives behind
-  :func:`serialize_executable` / :func:`deserialize_executable`
-  (``jax.experimental.serialize_executable`` today) so the persistent
-  compile cache (core/compile_cache.py) has exactly one seam to absorb
-  the next module move.
+AOT executable serialization still lives under ``jax.experimental``,
+so the persistent compile cache (core/compile_cache.py) reaches it
+through these two functions and nothing else in the package does.
+Every other jax call is spelled directly at its call site.
 """
 from __future__ import annotations
-
-import inspect
-
-import jax
-
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.5
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-if hasattr(jax, "ffi"):
-    ffi = jax.ffi
-else:  # jax < 0.5
-    from jax.extend import ffi  # noqa: F401
-
-_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-    if check_vma is not None:
-        if "check_vma" in _PARAMS:
-            kw["check_vma"] = check_vma
-        elif "check_rep" in _PARAMS:  # pre-rename spelling
-            kw["check_rep"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-
-
-def distributed_is_initialized() -> bool:
-    """``jax.distributed.is_initialized()`` (jax 0.5); older jax probes
-    the coordination-service client directly.  Never initialises the
-    backend (that would break the rendezvous this probe guards)."""
-    fn = getattr(jax.distributed, "is_initialized", None)
-    if fn is not None:
-        return bool(fn())
-    try:
-        from jax._src.distributed import global_state
-        return global_state.client is not None
-    except Exception:  # pragma: no cover - internal layout changed
-        return False
-
-
-def axis_size(name) -> int:
-    """Concrete size of a bound mesh axis (inside shard_map)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(name)
-    frame = jax.core.axis_frame(name)  # older jax: frame or bare int
-    return getattr(frame, "size", frame)
-
-
-def executable_serialization_available() -> bool:
-    """Whether this jax can round-trip compiled executables at all."""
-    try:
-        from jax.experimental import serialize_executable  # noqa: F401
-        return True
-    except ImportError:
-        return False
 
 
 def serialize_executable(compiled):
     """``(payload_bytes, in_tree, out_tree)`` for a ``lower().compile()``
     result.  The trees are picklable pytree defs; donation and static
-    shapes ride the payload.  This is OUR serialization path — jax's
-    persistent compilation cache stays off (it heap-corrupts reloading
-    NamedSharding executables on jaxlib 0.4.37)."""
+    shapes ride the payload."""
     from jax.experimental.serialize_executable import serialize
     return serialize(compiled)
 
 
-def deserialize_executable(payload, in_tree, out_tree):
+def deserialize_executable(payload, in_tree, out_tree, devices):
     """Rebuild a callable ``Compiled`` from :func:`serialize_executable`
-    output on the current backend.  Raises on any incompatibility —
-    callers (compile_cache) treat every failure as a cache reject and
-    fall back to a fresh compile."""
+    output, loaded onto exactly ``devices`` — left to its default jax
+    loads onto every local device, and a single-device executable then
+    fails its first dispatch on a multi-device host.  Raises on any
+    incompatibility — callers (compile_cache) treat every failure as a
+    cache reject and fall back to a fresh compile."""
     from jax.experimental.serialize_executable import deserialize_and_load
-    return deserialize_and_load(payload, in_tree, out_tree)
-
-
-def pvary(x, axis_name):
-    """Mark ``x`` device-varying over ``axis_name`` for the replication
-    checker; identity on jax without varying types (checker disabled)."""
-    pcast = getattr(jax.lax, "pcast", None)  # jax >= 0.9 spelling
-    if pcast is not None:
-        return pcast(x, axis_name, to="varying")
-    fn = getattr(jax.lax, "pvary", None)
-    if fn is not None:
-        return fn(x, axis_name)
-    return x
+    return deserialize_and_load(payload, in_tree, out_tree,
+                                execution_devices=list(devices))

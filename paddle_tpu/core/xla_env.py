@@ -30,7 +30,7 @@ import os
 from typing import List, Optional
 
 __all__ = ["apply_latency_hiding_flags", "latency_hiding_active",
-           "LATENCY_HIDING_FLAGS"]
+           "LATENCY_HIDING_FLAGS", "place_compile_cache"]
 
 # per-platform scheduler flags — only ever appended for the platform
 # the process is about to initialise, because an unknown flag in
@@ -127,3 +127,25 @@ def apply_latency_hiding_flags(platform: Optional[str] = None
         return []
     os.environ["XLA_FLAGS"] = (current + " " + " ".join(add)).strip()
     return add
+
+
+def place_compile_cache() -> str:
+    """Decide where jax's persistent compilation cache lives, and return
+    the directory.  For the entry points (``chip_smoke.py``,
+    ``bench.py``, ``tools/serve.py``) to call before their first
+    compile — never ``import paddle_tpu`` itself, a library does not
+    start writing into its user's disk.
+
+    ``JAX_COMPILATION_CACHE_DIR`` wins: jax reads it by itself and no
+    other directory is set in code.  Without it the cache goes to
+    ``<checkout>/.jax_cache`` — a fixed path, because the path is part
+    of what a cache hit depends on (never a temp name, a pid, a time)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    path = os.path.join(checkout, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -36,16 +36,20 @@ def is_compiled_with_xpu() -> bool:
 
 
 def set_device(device: str):
-    """paddle.set_device parity: 'cpu' | 'tpu' | 'tpu:0' | 'gpu' (→ tpu)."""
+    """paddle.set_device parity: 'cpu' | 'tpu' | 'tpu:0' | 'gpu' (→ tpu).
+    An accelerator that is not there raises — it is never quietly
+    replaced by the CPU."""
     global _current_device
     kind = device.split(":")[0]
     idx = int(device.split(":")[1]) if ":" in device else 0
     if kind in ("gpu", "cuda", "tpu", "xpu"):
-        kind = "tpu" if is_compiled_with_tpu() else None
-    if kind in (None, "tpu") and is_compiled_with_tpu():
+        # jax.devices raises RuntimeError when no TPU backend exists
         _current_device = jax.devices("tpu")[idx]
+    elif kind == "cpu":
+        _current_device = jax.devices("cpu")[idx]
     else:
-        _current_device = jax.devices("cpu")[min(idx, device_count("cpu") - 1)]
+        raise ValueError(f"unknown device {device!r}; expected 'cpu', "
+                         f"'tpu' or 'tpu:<index>'")
     jax.config.update("jax_default_device", _current_device)
     return _current_device
 

@@ -31,7 +31,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
-from ..core.jax_compat import shard_map
+from jax import shard_map
 
 from ..core.dispatch import apply, as_array
 from ..core.enforce import UnimplementedError

@@ -30,8 +30,7 @@ def early_init():
     # NB: probe with is_initialized(), NOT jax.process_count() — the
     # latter initialises the backend, which would itself make the
     # rendezvous impossible
-    from ..core.jax_compat import distributed_is_initialized
-    if coord and n_proc > 1 and not distributed_is_initialized():
+    if coord and n_proc > 1 and not jax.distributed.is_initialized():
         jax.distributed.initialize(
             coordinator_address=coord,
             num_processes=n_proc,

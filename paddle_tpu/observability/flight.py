@@ -7,7 +7,7 @@ mid-dump never leaves a truncated file, and the path may carry a
 registered filesystem scheme):
 
 - an :class:`~paddle_tpu.core.enforce.EnforceError` being *constructed*
-  (the typed-error taxonomy every framework-detected failure passes
+  (the typed-error hierarchy every framework-detected failure passes
   through),
 - an exception escaping ``Executor.run`` (both route through the
   ``core.obs_hook`` crash handler; the same exception object is only

@@ -137,9 +137,7 @@ def _predicted_step_s(predicted: Optional[dict]) -> Optional[float]:
     if not flops and not traffic:
         return None
     from ..static.analysis.cost import CHIP_SPECS, resolve_perf_chip
-    spec = CHIP_SPECS.get(resolve_perf_chip())
-    if spec is None:
-        return None
+    spec = CHIP_SPECS[resolve_perf_chip()]
     return max((flops or 0) / spec.peak_flops,
                (traffic or 0) / spec.hbm_bw)
 

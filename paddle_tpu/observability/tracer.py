@@ -40,7 +40,7 @@ from typing import Dict, List, Optional
 
 __all__ = ["Tracer", "EVENT_KINDS"]
 
-# Documented event taxonomy (the "typed" in typed events).  ``emit``
+# Documented event vocabulary (the "typed" in typed events).  ``emit``
 # accepts any string so layers can grow new kinds without touching this
 # module; exporters only special-case "counter".
 EVENT_KINDS = (

@@ -2,7 +2,7 @@
 
 The ring forms in :mod:`paddle_tpu.ops.collective_matmul` interleave
 one chunk transfer with one chunk matmul per step; this kernel is the
-compute half — a row-blocked MXU matmul over the chunk that just
+compute half — a tiled MXU matmul over the chunk that just
 arrived, so each ring step is one ``pallas_call`` the scheduler can
 slot against the next ``ppermute``.  Communication stays in JAX
 (ppermute between kernel invocations): Mosaic's cross-chip RDMA form
@@ -42,23 +42,36 @@ def chunk_matmul_supported(x_shape, w_shape, x_dtype, w_dtype) -> bool:
 
 
 def _mm_kernel(x_ref, w_ref, o_ref):
-    o_ref[...] = dot(x_ref[...], w_ref[...], ((1,), (0,)))
+    # the f32 output block is the accumulator: it stays resident while
+    # the innermost grid axis walks the contraction
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    o_ref[...] += dot(x_ref[...], w_ref[...], ((1,), (0,)))
+
+
+def _lane_block(n: int) -> int:
+    """Largest of 512/256/128 that tiles a lane-multiple dim."""
+    return next(b for b in (512, 256, 128) if n % b == 0)
 
 
 def chunk_matmul(x, w, *, interpret=None):
-    """One chunk's ``x @ w`` as a row-blocked Pallas pass (f32
-    accumulation).  Callers gate via :func:`chunk_matmul_supported`."""
+    """One chunk's ``x @ w`` as a tiled Pallas pass (f32 accumulation).
+    Rows, columns and the contraction are all blocked, so the VMEM the
+    kernel needs (a few MB) does not grow with the chunk.  Callers gate
+    via :func:`chunk_matmul_supported`."""
     if interpret is None:
         interpret = _interpret_mode()
-    m, _ = x.shape
+    m, k = x.shape
     _, nc = w.shape
-    bm = block_rows(m, 256)
+    bm, bn, bk = block_rows(m, 256), _lane_block(nc), _lane_block(k)
     out = pl.pallas_call(
         _mm_kernel,
-        grid=(m // bm,),
-        in_specs=[pl.BlockSpec((bm, x.shape[1]), lambda i: (i, 0)),
-                  pl.BlockSpec((x.shape[1], nc), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((bm, nc), lambda i: (i, 0)),
+        grid=(m // bm, nc // bn, k // bk),
+        in_specs=[pl.BlockSpec((bm, bk), lambda i, j, c: (i, c)),
+                  pl.BlockSpec((bk, bn), lambda i, j, c: (c, j))],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, c: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, nc), jnp.float32),
         interpret=interpret,
     )(x, w)

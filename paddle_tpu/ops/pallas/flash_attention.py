@@ -434,7 +434,7 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(D)
     block_q = min(block_q, q.shape[1])
     block_k = min(block_k, k.shape[1])
-    if dropout_p > 0.0 and (_interpret() or pltpu is None):
+    if dropout_p > 0.0 and _interpret():
         raise NotImplementedError(
             "flash_attention dropout needs the Pallas TPU PRNG (real TPU "
             "only); use scaled_dot_product_attention, whose dispatch "

@@ -41,13 +41,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .support import block_rows, dot as _dot, dtype_ok, \
-    interpret_mode as _interpret_mode
+    interpret_mode as _interpret_mode, pltpu
 
 __all__ = ["fused_linear_epilogue", "fused_epilogue_supported",
            "reference_epilogue", "stage_label"]
 
-# VMEM budget for the weight block (staged whole per kernel; ~16 MB/core
-# total must also hold x/dy/z blocks and the f32 dw accumulator)
+# Scoped VMEM the kernels ask Mosaic for.  Its default (16 MiB on v5e)
+# is below what the f32 backward needs once the weight, its f32 dw
+# accumulator (both staged whole, double-buffered) and the row blocks
+# are resident; every TPU generation has at least twice this.
+_VMEM_LIMIT = 32 * 1024 * 1024
+# what `_row_block` lets its (deliberately high) estimate reach
+_VMEM_BUDGET = 30 * 1024 * 1024
+# the weight and its f32 dw accumulator are staged whole: past this the
+# row blocks left over are too thin to be worth a kernel
 _W_VMEM_CAP = 4 * 1024 * 1024
 
 _SQRT_2 = math.sqrt(2.0)
@@ -70,11 +77,32 @@ def _ops_per_stage(stage) -> int:
     return 0
 
 
+# Mosaic has no lowering for lax.erf, so the exact GELU evaluates the
+# same clamped rational approximation XLA's own f32 erf uses (within
+# 3e-7 of erf everywhere): multiplies, adds and one divide
+_ERF_P = (0.00022905065861350646, 0.0034082910107109506,
+          0.050955695062380861, 0.18520832239976145, 1.128379143519084)
+_ERF_Q = (-1.1791602954361697e-7, 0.000023547966471313185,
+          0.0010179625278914885, 0.014070470171167667,
+          0.11098505178285362, 0.49746925110067538, 1.0)
+
+
+def _erf_f32(x):
+    x = jnp.clip(x, -3.832506856900711, 3.832506856900711)
+    x2 = x * x
+    p, q = _ERF_P[0], _ERF_Q[0]
+    for c in _ERF_P[1:]:
+        p = p * x2 + c
+    for c in _ERF_Q[1:]:
+        q = q * x2 + c
+    return x * p / q
+
+
 def _gelu_f32(z, approximate):
     if approximate:
         u = _SQRT_2_OVER_PI * (z + 0.044715 * z * z * z)
         return 0.5 * z * (1.0 + jnp.tanh(u))
-    return 0.5 * z * (1.0 + jax.lax.erf(z / _SQRT_2))
+    return 0.5 * z * (1.0 + _erf_f32(z / _SQRT_2))
 
 
 def _dgelu_f32(z, approximate):
@@ -83,7 +111,7 @@ def _dgelu_f32(z, approximate):
         t = jnp.tanh(u)
         du = _SQRT_2_OVER_PI * (1.0 + 3.0 * 0.044715 * z * z)
         return 0.5 * (1.0 + t) + 0.5 * z * (1.0 - t * t) * du
-    cdf = 0.5 * (1.0 + jax.lax.erf(z / _SQRT_2))
+    cdf = 0.5 * (1.0 + _erf_f32(z / _SQRT_2))
     pdf = _INV_SQRT_2PI * jnp.exp(-0.5 * z * z)
     return cdf + z * pdf
 
@@ -100,13 +128,31 @@ def _ln_stats(h, eps):
 # gate
 # ---------------------------------------------------------------------------
 
+def _row_block(m, k, n, itemsize, stages) -> int:
+    """Largest power-of-two row block <= 256 tiling ``m`` with which the
+    backward kernel (the bigger of the two) fits the VMEM budget, or 0
+    when not even 8 rows do.  The estimate counts every pipelined block
+    twice (double buffering) and eight f32 [bm, N] temporaries for the
+    replayed chain — above what Mosaic was seen to allocate at every
+    shape compiled in tests/test_chip_compile.py."""
+    n_add = sum(st[0] == "add" for st in stages)
+    fixed = 2 * k * n * (itemsize + 4)            # w + f32 dw accumulator
+    per_row = (2 * (2 * k + (1 + 2 * n_add) * n) * itemsize  # x dx dy res
+               + (8 * n + k) * 4)                            # temporaries
+    bm = block_rows(m, 256)
+    while bm >= 8 and fixed + bm * per_row > _VMEM_BUDGET:
+        bm //= 2
+    return bm if bm >= 8 else 0
+
+
 def fused_epilogue_supported(x_shape, w_shape, dtype, stages=(),
                              operand_shapes=()) -> bool:
     """Capability gate, identical on every backend so the executor's
     selection is deterministic: Mosaic tile alignment (rows % 8,
-    N % 128, K % 8), f32/bf16, the weight block within its VMEM
-    budget, and every operand either the [N] per-feature vector or the
-    full [M, N] residual its stage expects."""
+    N % 128, K % 8), f32/bf16, the weight within its cap and a row
+    block with which the kernels fit VMEM, and every operand either the
+    [N] per-feature vector or the full [M, N] residual its stage
+    expects."""
     if not dtype_ok(dtype):
         return False
     if len(w_shape) != 2 or len(x_shape) < 2:
@@ -119,7 +165,9 @@ def fused_epilogue_supported(x_shape, w_shape, dtype, stages=(),
         m *= int(s)
     if m <= 0 or m % 8 or k % 8 or n % 128:
         return False
-    if k * n * 4 > _W_VMEM_CAP:  # f32 dw accumulator is the bound
+    if k * n * 4 > _W_VMEM_CAP:
+        return False
+    if not _row_block(m, k, n, jnp.dtype(dtype).itemsize, stages):
         return False
     oi = 0
     for st in stages:
@@ -195,7 +243,7 @@ def _op_block_spec(shape, bm):
 def _fwd(stages, interpret, x2, w, ops):
     m, k = x2.shape
     n = w.shape[1]
-    bm = block_rows(m, 256)
+    bm = _row_block(m, k, n, x2.dtype.itemsize, stages)
     out = pl.pallas_call(
         _make_fwd_kernel(stages),
         grid=(m // bm,),
@@ -205,6 +253,8 @@ def _fwd(stages, interpret, x2, w, ops):
         ] + [_op_block_spec(o.shape, bm) for o in ops],
         out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), x2.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(x2, w, *ops)
     return out
@@ -284,7 +334,7 @@ def _make_bwd_kernel(stages, n_ops):
 def _bwd_call(stages, interpret, x2, w, ops, dy):
     m, k = x2.shape
     n = w.shape[1]
-    bm = block_rows(m, 256)
+    bm = _row_block(m, k, n, x2.dtype.itemsize, stages)
     grid = (m // bm,)
     # grads: dx blocked; dw accumulated f32; per-operand — (1, N)
     # operands accumulate in f32, [M, N] residuals are blocked
@@ -309,6 +359,8 @@ def _bwd_call(stages, interpret, x2, w, ops, dy):
         ] + [_op_block_spec(o.shape, bm) for o in ops],
         out_specs=out_specs,
         out_shape=out_shapes,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(x2, w, dy, *ops)
     dx = outs[0]

@@ -145,36 +145,30 @@ def paged_attention_decode(q, k_pool, v_pool, page_table, lengths,
         kv_spec = pl.BlockSpec(
             (1, page, hkv, D), lambda s, p, t, l: (t[s, p], 0, 0, 0))
 
-    if pltpu is not None:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(S, P),
-            in_specs=[
-                pl.BlockSpec((1, H, D), lambda s, p, t, l: (s, 0, 0)),
-                kv_spec, kv_spec,
-            ],
-            out_specs=pl.BlockSpec((1, H, D),
-                                   lambda s, p, t, l: (s, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((H, D), jnp.float32),
-                pltpu.VMEM((H, 1), jnp.float32),
-                pltpu.VMEM((H, 1), jnp.float32),
-            ],
-        )
-        call = pl.pallas_call(
-            functools.partial(_decode_kernel, scale=float(scale),
-                              page=page, hkv=hkv, group=group,
-                              layered=layered),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
-            interpret=interpret,
-        )
-        out = call(table, lens, q, k_pool, v_pool)
-    else:  # pragma: no cover - CPU-only installs without pltpu
-        from ..attention import paged_attention_reference
-        return paged_attention_reference(q, k_pool, v_pool, page_table,
-                                         lengths, scale=scale,
-                                         layer=layer)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(S, P),
+        in_specs=[
+            pl.BlockSpec((1, H, D), lambda s, p, t, l: (s, 0, 0)),
+            kv_spec, kv_spec,
+        ],
+        out_specs=pl.BlockSpec((1, H, D),
+                               lambda s, p, t, l: (s, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((H, D), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+        ],
+    )
+    call = pl.pallas_call(
+        functools.partial(_decode_kernel, scale=float(scale),
+                          page=page, hkv=hkv, group=group,
+                          layered=layered),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
+        interpret=interpret,
+    )
+    out = call(table, lens, q, k_pool, v_pool)
     from .support import count_kernel_selection
     count_kernel_selection("paged_attention")
     return out

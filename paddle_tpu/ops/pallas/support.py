@@ -3,8 +3,8 @@
 Every kernel file (flash_attention, fused_epilogue, fused_adam,
 paged_attention) needs the same four decisions made the same way:
 
-- **backend**: ``pltpu`` import (absent on some CPU-only installs),
-  interpret mode when not on a real TPU;
+- **backend**: the ``pltpu`` import, interpret mode when not on a real
+  TPU;
 - **activation**: the tier is ON when ``FLAGS_use_pallas_kernels`` is
   set AND either the backend is TPU or ``FLAGS_pallas_interpret``
   explicitly opts a CPU process into interpret-mode execution (tests,
@@ -28,11 +28,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only module; absent on some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["pltpu", "interpret_mode", "tier_enabled", "dtype_ok",
            "smem_scalar_spec", "count_kernel_selection",
@@ -81,12 +77,9 @@ def dtype_ok(dtype) -> bool:
 
 
 def smem_scalar_spec():
-    """(1, 1) scalar operand placed in SMEM on TPU (plain block spec in
-    interpret mode / when pltpu is unavailable)."""
-    if pltpu is not None:
-        return pl.BlockSpec((1, 1), lambda *_: (0, 0),
-                            memory_space=pltpu.SMEM)
-    return pl.BlockSpec((1, 1), lambda *_: (0, 0))
+    """(1, 1) scalar operand placed in SMEM."""
+    return pl.BlockSpec((1, 1), lambda *_: (0, 0),
+                        memory_space=pltpu.SMEM)
 
 
 # selection counter: {kernel name: trace-time selections} (see module

@@ -53,7 +53,7 @@ from typing import Callable, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
-from ..core.jax_compat import shard_map
+from jax import shard_map
 
 from ..core import autograd
 from ..core.tensor import Tensor

@@ -22,8 +22,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
-from ..core.jax_compat import axis_size as _axis_size
-from ..core.jax_compat import shard_map
+from jax import shard_map
 
 from ..core.dispatch import apply
 from ..core.tensor import Tensor
@@ -54,7 +53,7 @@ def ring_attention_per_device(q, k, v, axis_name: str, is_causal: bool,
     q/k/v: local shards [B, L_local, H, D].  Returns [B, L_local, H, D]."""
     B, Lq, H, D = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
-    S = _axis_size(axis_name)
+    S = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % S) for i in range(S)]
 
@@ -117,7 +116,7 @@ def ring_attention_per_device_flash(q, k, v, axis_name: str, is_causal: bool,
     from ..ops.pallas.flash_attention import flash_attention_block
     B, Lq, H, D = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
-    S = _axis_size(axis_name)
+    S = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % S) for i in range(S)]
     qt = jnp.swapaxes(q, 1, 2)                 # [B, H, L, D]

@@ -39,7 +39,7 @@ from .tp_layers import set_placement
 def _row_sharded_lookup(w, ids, mesh, axis):
     """Shard-local gather + psum over ``axis``; differentiable (shard_map
     has full AD support), grads land as shard-local scatter-adds."""
-    from ..core.jax_compat import shard_map
+    from jax import shard_map
 
     n = mesh.shape[axis]
     rows_per = w.shape[0] // n

@@ -56,14 +56,6 @@ def _numel(shape):
     return n
 
 
-def _pvary(x, axis):
-    """Mark ``x`` as device-varying over ``axis`` inside shard_map
-    (jax>=0.9 spells this lax.pcast(to='varying'); identity on jax
-    without varying types)."""
-    from ..core.jax_compat import pvary
-    return pvary(x, axis)
-
-
 def _shardable(shape, n):
     return len(shape) > 0 and shape[0] % n == 0 and shape[0] >= n
 
@@ -305,7 +297,8 @@ class SpmdTrainStep(TrainStep):
                 # differentiate w.r.t. a device-VARYING copy of the params:
                 # grads stay local (no compiler-inserted f32 psum for the
                 # invariant cotangent) so the ONLY reduction is ours below
-                p_var = [_pvary(a, DP_AXIS) for a in p_cur]
+                p_var = [jax.lax.pcast(a, DP_AXIS, to="varying")
+                         for a in p_cur]
                 loss, new_b, grads = fn(p_var, b_cur, ins, labs, k)
                 # bucketed quantize → reduce → dequantize: the wire
                 # carries the plan's dtype (bf16 subsumes the old
@@ -322,7 +315,7 @@ class SpmdTrainStep(TrainStep):
                     lambda a: jax.lax.pmean(a, DP_AXIS), new_b)
                 return loss, new_b, grads
 
-            from ..core.jax_compat import shard_map
+            from jax import shard_map
             P = PartitionSpec
             # check_vma off: the int8 route's all_to_all/all_gather
             # results are replicated by construction, which the static

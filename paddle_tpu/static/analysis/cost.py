@@ -947,39 +947,38 @@ def analyze(program: Program, fetch_list: Optional[Sequence] = None,
     return rep
 
 
+# jax ``device_kind`` -> CHIP_SPECS key
+_KIND_TO_CHIP = {"TPU v4": "v4", "TPU v5 lite": "v5e", "TPU v5e": "v5e",
+                 "TPU v5p": "v5p", "TPU v5": "v5p"}
+
+
 def resolve_perf_chip() -> str:
     """The ``CHIP_SPECS`` key runtime predictions are priced against:
-    ``FLAGS_perf_chip`` when set to a known spec, else auto-detected
-    from the jax backend (``cpu`` on CPU, ``v5e`` on TPU).  The single
-    policy both ``compile_summary`` and the perf observatory's drift
-    fallback use — one place to extend when a backend is added."""
+    ``FLAGS_perf_chip`` when set, else the spec of the device jax
+    reports (``cpu`` on the CPU backend, by ``device_kind`` on a TPU).
+    A flag value or a device kind with no spec raises — a prediction
+    priced against some other chip's roofline is worse than none.  The
+    single policy both ``compile_summary`` and the perf observatory's
+    drift fallback use — one place to extend when a chip is added."""
     from ...core.flags import get_flag
     chip = get_flag("perf_chip")
     if chip:
-        if chip in CHIP_SPECS:
-            return chip
-        import warnings
-        warnings.warn(
-            f"FLAGS_perf_chip={chip!r} is not a known chip spec "
-            f"(choose from {sorted(CHIP_SPECS)}); falling back to "
-            f"backend auto-detection — drift predictions will be "
-            f"priced against the wrong roofline otherwise silently",
-            RuntimeWarning)
-    try:
-        import jax
-        backend = jax.default_backend()
-    except Exception:  # noqa: BLE001
+        if chip not in CHIP_SPECS:
+            raise ValueError(
+                f"FLAGS_perf_chip={chip!r} is not a known chip spec "
+                f"(choose from {sorted(CHIP_SPECS)})")
+        return chip
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
         return "cpu"
-    if backend == "cpu":
-        return "cpu"
-    if backend == "tpu":
-        return "v5e"
-    import warnings
-    warnings.warn(
-        f"no roofline chip spec for jax backend {backend!r}; pricing "
-        f"predictions against 'cpu' — set FLAGS_perf_chip to a "
-        f"CHIP_SPECS key to choose explicitly", RuntimeWarning)
-    return "cpu"
+    key = _KIND_TO_CHIP.get(dev.device_kind)
+    if key is None:
+        raise ValueError(
+            f"no roofline chip spec for device kind {dev.device_kind!r} "
+            f"(platform {dev.platform!r}); add one to CHIP_SPECS or set "
+            f"FLAGS_perf_chip to one of {sorted(CHIP_SPECS)}")
+    return key
 
 
 def compile_summary(program: Program, donate: bool = True,
@@ -991,17 +990,18 @@ def compile_summary(program: Program, donate: bool = True,
     (``FLAGS_perf_chip``, auto-detected backend by default) — the
     number the perf observatory's drift tracker compares measured
     steps against.  With a ``sharding`` plan the summary also carries
-    ``peak_bytes_per_shard`` — what one chip actually holds.  Returns
-    None instead of raising — a cost-model gap must never break a
-    compile."""
+    ``peak_bytes_per_shard`` — what one chip actually holds.  A chip
+    with no spec raises (see :func:`resolve_perf_chip`); a gap in the
+    cost model itself returns None and counts
+    ``predicted.executor.errors`` — it must never break a compile, but
+    an unpriced compile must not pass unnoticed either."""
+    chip = resolve_perf_chip()
     try:
-        # inside the try: resolve_perf_chip warns on a misconfigured
-        # flag/backend, and under warnings-as-errors (pytest/CI -W
-        # error) that warning RAISES — it must not break a compile
-        chip = resolve_perf_chip()
         rep = analyze(program, include_hazards=False, chip=chip,
                       top_k=0, sharding=sharding)
     except Exception:  # noqa: BLE001 - prediction is best-effort
+        from ...utils import monitor
+        monitor.stat_add("predicted.executor.errors")
         return None
     t = rep.totals
     peak = (rep.memory.peak_bytes_donated if donate

@@ -23,9 +23,9 @@ disagree about what fuses:
   reported candidate ``realized`` (with the kernel label) or not,
   so the report distinguishes realized from still-unrealized savings.
 
-Everything here is best-effort by contract: a chain the matcher cannot
-prove safe (unreadable closure, unexpected kwargs, gate miss) is left
-on the composite path untouched.
+Matching is conservative by contract: a chain the matcher cannot prove
+safe (unreadable closure, unexpected kwargs, gate miss) is left on the
+composite path untouched.  Errors in the analysis itself propagate.
 """
 from __future__ import annotations
 
@@ -198,26 +198,26 @@ def plan_fusions(program, fetch_list=None,
     """Match every ranked candidate of ``program`` against the kernel
     recipes under the given concrete feed shapes (run-time avals — the
     recorded placeholder batch of 1 would fail the row-tile gate).
-    Returns the realizable plans; empty on any analysis failure."""
+    Returns the realizable plans.  A chain the matcher cannot prove
+    safe is skipped (``_match_chain`` returns None); a failure of the
+    analysis itself raises — swallowed, it would turn a run that should
+    be fused into a silently unfused one."""
     from .cost import _propagate_avals
     from .graph import DefUseGraph
-    try:
-        graph = DefUseGraph(program)
-        avals = (_propagate_avals(graph, dict(feed_shapes))
-                 if feed_shapes else {})
-        fetched = set()
-        for f in (fetch_list or []):
-            v = graph.resolve_fetch(f)
-            if v is not None:
-                fetched.add(id(v))
-        plans = []
-        for cand in _candidates(graph, avals, fetched):
-            plan = _match_chain(graph.nodes, cand["ops"], avals)
-            if plan is not None:
-                plans.append(plan)
-        return plans
-    except Exception:  # noqa: BLE001 - fusion is best-effort by contract
-        return []
+    graph = DefUseGraph(program)
+    avals = (_propagate_avals(graph, dict(feed_shapes))
+             if feed_shapes else {})
+    fetched = set()
+    for f in (fetch_list or []):
+        v = graph.resolve_fetch(f)
+        if v is not None:
+            fetched.add(id(v))
+    plans = []
+    for cand in _candidates(graph, avals, fetched):
+        plan = _match_chain(graph.nodes, cand["ops"], avals)
+        if plan is not None:
+            plans.append(plan)
+    return plans
 
 
 def apply_plans(nodes: Sequence[_OpNode], plans: Sequence[FusionPlan]

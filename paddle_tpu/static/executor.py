@@ -35,7 +35,6 @@ lazy ``Parameter.data`` resolution above).
 """
 from __future__ import annotations
 
-import threading
 import time
 import weakref
 from typing import Dict, List, Optional, Sequence
@@ -230,36 +229,12 @@ class _ExecState:
         return out
 
 
-# serializes first-call compiles of sharded executables: the config
-# flip below is process-global, so concurrent flips could restore the
-# flag mid-compile of the other thread and let a sharded executable
-# reach the poisoned persistent cache after all
-_CACHE_FLIP_LOCK = threading.Lock()
-
-
-def _no_persistent_cache_first_call(jitted):
-    """jaxlib 0.4.37's persistent compilation cache corrupts the heap
-    when it RELOADS an executable that was compiled with explicit
-    NamedShardings (repro: two processes running the same sharded
-    program with jax_compilation_cache_dir set — the second dies with
-    'corrupted double-linked list').  Sharded executables therefore
-    compile with the persistent cache disabled: only the first call
-    (the one that compiles, and would otherwise serialize/deserialize)
-    pays the config flip + lock; steady-state dispatch is untouched."""
-    warmed = []
-
+def _settable(jitted):
+    """A plain function around a jitted one: the Executor hangs its
+    per-executable records (``_t_idx``, ``_predicted``, ...) on the
+    callable as attributes, which a jit object refuses."""
     def compiled(*args):
-        if warmed:
-            return jitted(*args)
-        with _CACHE_FLIP_LOCK:
-            prev = jax.config.jax_enable_compilation_cache
-            jax.config.update("jax_enable_compilation_cache", False)
-            try:
-                out = jitted(*args)
-            finally:
-                jax.config.update("jax_enable_compilation_cache", prev)
-            warmed.append(True)
-        return out
+        return jitted(*args)
 
     return compiled
 
@@ -913,6 +888,20 @@ class Executor:
                              jnp.asarray(int(seed), jnp.int32))
             if donate:
                 state.shield_escaped()
+            st_sh = getattr(compiled, "_state_shardings", None)
+            if st_sh is not None and not (
+                    state.p_arrays[0].sharding == st_sh[0][0]
+                    and state.aux["step"].sharding == st_sh[2]["step"]):
+                # jax keys its trace cache on the mesh an argument
+                # lives on.  State that was just built, restored or
+                # resynced sits on one device; handed over like that,
+                # the NEXT call (fed the step's own mesh-placed outputs)
+                # would retrace and recompile the whole step.  Place it
+                # the way the step returns it.
+                state.p_arrays, state.opt_state, state.aux = \
+                    jax.device_put(
+                        (state.p_arrays, state.opt_state, state.aux),
+                        st_sh)
             t_d0 = time.perf_counter() if perf is not None else 0.0
             fetches, new_p, new_s, new_aux = compiled(
                 state.p_arrays, state.opt_state, state.aux,
@@ -999,21 +988,21 @@ class Executor:
         p_sh = [plan.param_sharding(i) for i in range(len(params))]
         feed_sh = [plan.feed_sharding(a.shape) for a in feed_arrays]
         fetch_sh = [rep] * len(fetch_names)
-        s_sh = rep  # pytree prefix: replicate all slots (fallback)
+        s_sh = rep  # no optimizer: nothing to place
         if opt is not None:
-            try:
-                avals = [jax.ShapeDtypeStruct(
-                    tuple(param_array(params[i]).shape),
-                    np.dtype(param_array(params[i]).dtype))
-                    for i in t_idx]
-                state_shape = jax.eval_shape(opt.functional_init, avals)
-                s_specs = specs_for_state(
-                    [plan.param_spec(i) for i in t_idx], state_shape,
-                    param_shapes=[a.shape for a in avals])
-                s_sh = [{k: plan._ns(v) for k, v in e.items()}
-                        for e in s_specs]
-            except Exception:  # noqa: BLE001 - fall back to replicated
-                pass
+            # slots inherit their param's spec; a failure here raises —
+            # silently replicated slots would triple a sharded model's
+            # per-chip optimizer memory unnoticed
+            avals = [jax.ShapeDtypeStruct(
+                tuple(param_array(params[i]).shape),
+                np.dtype(param_array(params[i]).dtype))
+                for i in t_idx]
+            state_shape = jax.eval_shape(opt.functional_init, avals)
+            s_specs = specs_for_state(
+                [plan.param_spec(i) for i in t_idx], state_shape,
+                param_shapes=[a.shape for a in avals])
+            s_sh = [{k: plan._ns(v) for k, v in e.items()}
+                    for e in s_specs]
         aux_sh = {"run": rep, "step": rep}
         return (p_sh, s_sh, aux_sh, rep, feed_sh, fetch_sh)
 
@@ -1100,7 +1089,7 @@ class Executor:
         intact."""
         from jax.sharding import PartitionSpec
         from ..core import rng as _rng
-        from ..core.jax_compat import pvary, shard_map
+        from jax import shard_map
         from ..distributed import grad_comm as _gc
         from ..distributed.mesh import DP_AXIS
         from ..distributed.sharding import spec_axes
@@ -1255,7 +1244,8 @@ class Executor:
                         dim=gth["dim"], ring=ring_gather)
                 # differentiate w.r.t. device-VARYING copies: grads
                 # stay local, the ONLY reduction is grad_comm's below
-                t_var = [pvary(t_full.get(k, a), DP_AXIS)
+                t_var = [jax.lax.pcast(t_full.get(k, a), DP_AXIS,
+                                       to="varying")
                          for k, a in enumerate(t_shards)]
 
                 def loss_of(tlist):
@@ -1378,8 +1368,8 @@ class Executor:
         jit_kw["in_shardings"] = (p_sh, s_sh, aux_sh, rep, rep, rep,
                                   rep, *feed_sh)
         jit_kw["out_shardings"] = (fetch_sh, p_sh, s_sh, aux_sh)
-        compiled = _no_persistent_cache_first_call(
-            jax.jit(train_fn, **jit_kw))
+        compiled = _settable(jax.jit(train_fn, **jit_kw))
+        compiled._state_shardings = (p_sh, s_sh, aux_sh)
         compiled._t_idx = t_idx
         if sentry:
             compiled._n_sentry = 4
@@ -1528,8 +1518,7 @@ class Executor:
                                                  prov)
                         return ex(*args)
                 else:
-                    def compiled(*args):
-                        return jitted(*args)
+                    compiled = _settable(jitted)
 
                 compiled._pallas_kernels = realized_kernels
                 return compiled
@@ -1538,7 +1527,7 @@ class Executor:
             jitted = jax.jit(run_fn,
                              in_shardings=(p_sh, rep, *feed_sh),
                              out_shardings=fetch_sh)
-            return _no_persistent_cache_first_call(jitted)
+            return _settable(jitted)
 
         opt, loss_var, param_filter, no_grad_set = (opt_pack + (None,
                                                                 None))[:4]
@@ -1681,14 +1670,9 @@ class Executor:
             jit_kw["in_shardings"] = (p_sh, s_sh, aux_sh, rep, rep, rep,
                                       rep, *feed_sh)
             jit_kw["out_shardings"] = (fetch_sh, p_sh, s_sh, aux_sh)
-        jitted = jax.jit(train_fn, **jit_kw)
-
+        compiled = _settable(jax.jit(train_fn, **jit_kw))
         if plan is not None:
-            compiled = _no_persistent_cache_first_call(jitted)
-        else:
-            def compiled(*args):
-                return jitted(*args)
-
+            compiled._state_shardings = (p_sh, s_sh, aux_sh)
         compiled._t_idx = t_idx
         compiled._pallas_kernels = realized_kernels
         if sentry:

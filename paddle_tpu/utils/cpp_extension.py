@@ -30,7 +30,7 @@ from typing import Callable, Dict, Optional, Sequence, Union
 
 import jax
 
-from ..core.jax_compat import ffi as _ffi
+from jax import ffi as _ffi
 import numpy as np
 
 __all__ = ["load", "get_build_directory", "CppExtension"]

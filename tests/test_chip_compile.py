@@ -1,0 +1,187 @@
+"""The Pallas tier compiled by the chip's own compiler, without the chip.
+
+Interpret mode cannot see what Mosaic refuses (an op with no TPU
+lowering, a kernel that wants more VMEM than it may use), so each
+kernel of the main paths is compiled here for a *described* TPU v5e at
+the widths ``chip_smoke.py`` runs: nothing executes, but the compiler
+raises exactly what it would raise on the chip.  Every shape a gate
+had to be tightened for sits beside the positives as a negative case:
+its ``*_supported`` must say False.
+
+The topology is described inside the module-scoped fixture below and
+nowhere else — only one process may load the TPU library, and under
+xdist every worker imports this file.  Compiles happen in the test's
+own process; keep all of them in this one file.
+"""
+import importlib
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "no TPU compiler here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _compile(fn, *args):
+    """Compile for the described chip; the Mosaic kernel must be in the
+    executable (an interpret-mode lowering would leave none)."""
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+# ---------------------------------------------------------------- flash --
+@pytest.mark.parametrize("dropout", [0.0, 0.1], ids=["nodrop", "drop"])
+@pytest.mark.parametrize("shape,causal", [
+    ((4, 512, 12, 64), False),      # BERT-base
+    ((4, 1024, 16, 96), True),      # GPT-760M
+], ids=["bert", "gpt"])
+def test_flash_attention_fwd_bwd(one_chip, monkeypatch, shape, causal,
+                                 dropout):
+    # the package re-exports a function under the module's name
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    # capability + profitability: at seq 512 the dispatcher takes the
+    # kernel only with dropout (FLAGS_pallas_attention_min_seqlen)
+    assert fa.flash_attention_supported(
+        shape, shape, jnp.bfloat16,
+        dropout_p=dropout) == (shape[1] >= 1024 or dropout > 0)
+
+    def loss(q, k, v, seed):
+        out = fa.flash_attention(q, k, v, causal=causal,
+                                 dropout_p=dropout, seed=seed)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    qkv = _sds(one_chip, shape, jnp.bfloat16)
+    _compile(jax.value_and_grad(loss, (0, 1, 2)), qkv, qkv, qkv,
+             _sds(one_chip, (1, 1), jnp.int32))
+
+
+# ------------------------------------------------------- fused epilogue --
+_LN = ("layer_norm", 1e-5, True, True)
+# (M, K, N, stages): the recipe the Executor realises on BERT-base
+# (every block's proj+residual+LayerNorm) at chip_smoke's batches, and
+# the other stage kinds at widths their gate admits
+_EPILOGUES = {
+    "bert_b64_add_ln": (64 * 512, 768, 768, (("add",), _LN)),
+    "bert_b16_add_ln": (16 * 512, 768, 768, (("add",), _LN)),
+    "gelu_tanh": (32768, 768, 1280, (("gelu", True),)),
+    "gelu_exact": (32768, 768, 1280, (("gelu", False),)),
+    "relu": (32768, 1024, 1024, (("relu",),)),
+}
+
+
+def _epilogue_operands(one_chip, m, n, stages, dtype):
+    ops, shapes = [], []
+    for st in stages:
+        if st[0] == "add":
+            shapes.append((m, n))
+        elif st[0] == "layer_norm":
+            shapes.extend([(n,)] * (int(st[2]) + int(st[3])))
+    for s in shapes:
+        ops.append(_sds(one_chip, s, dtype))
+    return ops, shapes
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(_EPILOGUES))
+def test_fused_epilogue_fwd_bwd(one_chip, case, dtype):
+    from paddle_tpu.ops.pallas.fused_epilogue import (
+        fused_epilogue_supported, fused_linear_epilogue)
+    m, k, n, stages = _EPILOGUES[case]
+    ops, op_shapes = _epilogue_operands(one_chip, m, n, stages, dtype)
+    assert fused_epilogue_supported((m, k), (k, n), dtype,
+                                    (("bias",),) + stages,
+                                    [(n,)] + op_shapes)
+
+    def loss(x, w, b, *operands):
+        out = fused_linear_epilogue(x, w, b, stages, operands,
+                                    interpret=False)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    _compile(jax.value_and_grad(loss, tuple(range(3 + len(ops)))),
+             _sds(one_chip, (m, k), dtype), _sds(one_chip, (k, n), dtype),
+             _sds(one_chip, (n,), dtype), *ops)
+
+
+@pytest.mark.parametrize("m,k,n,stages,op_shapes", [
+    # BERT's FFN weights: staged whole with their f32 dw accumulator,
+    # they leave no room for a useful row block
+    (32768, 768, 3072, (("gelu", True),), []),
+    (32768, 3072, 768, (("add",), _LN), [(32768, 768), (768,), (768,)]),
+    # a wide row: even the smallest row block does not fit
+    (32768, 8, 131072, (("relu",),), []),
+], ids=["ffn_up", "ffn_down", "wide_row"])
+def test_fused_epilogue_gate_rejects(m, k, n, stages, op_shapes):
+    from paddle_tpu.ops.pallas.fused_epilogue import \
+        fused_epilogue_supported
+    for dtype in (jnp.float32, jnp.bfloat16):
+        assert not fused_epilogue_supported(
+            (m, k), (k, n), dtype, (("bias",),) + stages,
+            [(n,)] + op_shapes)
+
+
+# ----------------------------------------------------------- fused Adam --
+@pytest.mark.parametrize("shape", [(30522, 768), (768, 3072), (768,)],
+                         ids=["embedding", "ffn", "bias"])
+def test_fused_adam(one_chip, shape):
+    from paddle_tpu.ops.pallas.fused_adam import (fused_adam_supported,
+                                                  fused_adam_update)
+    assert fused_adam_supported(shape, jnp.float32)
+    assert not fused_adam_supported(shape, jnp.bfloat16)
+    a = _sds(one_chip, shape, jnp.float32)
+    s = _sds(one_chip, (), jnp.float32)
+    _compile(lambda p, g, m, v, lr, step: fused_adam_update(
+        p, g, m, v, lr, step, interpret=False), a, a, a, a, s, s)
+
+
+# ------------------------------------------------------ paged attention --
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_paged_attention_decode(one_chip, dtype):
+    """chip_smoke's serve geometry: 8 slots, 16 heads over 4 KV heads,
+    head_dim 128, page 16, 2048-token context, 8-layer stacked pool."""
+    from paddle_tpu.ops.pallas.paged_attention import (
+        paged_attention_decode, paged_decode_supported)
+    slots, heads, kv_heads, d, page, pages = 8, 16, 4, 128, 16, 128
+    pool = (8, slots * pages + 1, page, kv_heads, d)
+    assert paged_decode_supported((slots, heads, d), pool, dtype, page)
+    assert not paged_decode_supported((slots, heads, 64),
+                                      pool[:-1] + (64,), dtype, page)
+    kv = _sds(one_chip, pool, dtype)
+    _compile(lambda q, k, v, table, lens: paged_attention_decode(
+        q, k, v, table, lens, layer=3, interpret=False),
+        _sds(one_chip, (slots, heads, d), dtype), kv, kv,
+        _sds(one_chip, (slots, pages), jnp.int32),
+        _sds(one_chip, (slots,), jnp.int32))
+
+
+# --------------------------------------------------------- chunk matmul --
+@pytest.mark.parametrize("m,k,n,dtype", [
+    (4096, 768, 3072, jnp.float32),     # BERT FFN chunk
+    (2048, 1536, 6144, jnp.bfloat16),
+], ids=["bert_ffn_f32", "wide_bf16"])
+def test_chunk_matmul(one_chip, m, k, n, dtype):
+    from paddle_tpu.ops.pallas.collective_matmul import (
+        chunk_matmul, chunk_matmul_supported)
+    assert chunk_matmul_supported((m, k), (k, n), dtype, dtype)
+    _compile(lambda x, w: chunk_matmul(x, w, interpret=False),
+             _sds(one_chip, (m, k), dtype), _sds(one_chip, (k, n), dtype))
+

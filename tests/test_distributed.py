@@ -1014,7 +1014,7 @@ def test_grad_comm_error_feedback_accumulation_identity():
     of true gradients: the residual telescopes, so T steps of int8
     reduction with EF stay within a one-step error bound, while the
     EF-off error grows ~T times larger."""
-    from paddle_tpu.core.jax_compat import shard_map
+    from jax import shard_map
     dp, n, T = 8, 96, 24
     mesh = dist.get_mesh()
     plan = gcx.plan_reduction([(n,)], dp=dp, cfg=_spec(block=32))
@@ -1104,7 +1104,7 @@ def test_collective_matmul_composite_bitwise_oracles():
     bitwise at fp32: column-parallel all_gather_matmul == gather-then-
     matmul, row-parallel matmul_reduce_scatter == psum + row slice —
     on both the ring and fused forms."""
-    from paddle_tpu.core.jax_compat import shard_map
+    from jax import shard_map
     from paddle_tpu.ops.collective_matmul import (all_gather_matmul,
                                                   matmul_reduce_scatter)
     size, m, k, n = 8, 16, 8, 32
@@ -1287,7 +1287,7 @@ def test_grad_comm_fsdp_int8_ef_residual_telescoping():
     exactly like the gathered route: T steps of int8 reduce-scatter
     with EF stay within a one-step quantization bound of the true
     running mean, EF-off drifts ~T times further."""
-    from paddle_tpu.core.jax_compat import shard_map
+    from jax import shard_map
     dp, n, T = 8, 96, 24
     mesh = dist.get_mesh()
     plan = gcx.plan_reduction([(n,)], dp=dp, cfg=_spec(block=32),
@@ -1404,7 +1404,7 @@ def test_grad_comm_ring_reduction_bitwise_parity():
     the int8 ring stays within the one-step quantization bound of the
     fused all_to_all route."""
     import jax.numpy as jnp
-    from paddle_tpu.core.jax_compat import shard_map
+    from jax import shard_map
     dp = 8
     mesh = dist.get_mesh()
     shapes = [(33, 7), (130,), (9,)]

@@ -494,7 +494,7 @@ def test_flight_recorder_same_exception_dumps_once(tmp_path):
         paddle.disable_static()
         paddle.static.reset_default_programs()
     assert monitor.get_stat("flight.dumps") == 1
-    assert InvalidArgumentError  # imported for taxonomy visibility
+    assert InvalidArgumentError  # imported for error-class visibility
 
 
 def test_flight_recorder_distinct_exceptions_each_dump(tmp_path):
@@ -1104,17 +1104,36 @@ def test_slo_min_count_gates_quantile_windows():
     monitor.stat_reset("slo.t.mc_ms")
 
 
-def test_resolve_perf_chip_warns_on_unknown_flag():
+def test_resolve_perf_chip_rejects_unknown_flag():
     from paddle_tpu.core.flags import get_flag, set_flags
     from paddle_tpu.static.analysis.cost import resolve_perf_chip
     old = get_flag("perf_chip")
     try:
         set_flags({"perf_chip": "v5"})      # typo for v5p
-        with pytest.warns(RuntimeWarning, match="perf_chip"):
-            chip = resolve_perf_chip()
-        assert chip == "cpu"                # backend auto-detection
+        with pytest.raises(ValueError, match="perf_chip"):
+            resolve_perf_chip()
+        set_flags({"perf_chip": ""})
+        assert resolve_perf_chip() == "cpu"     # from the device
     finally:
         set_flags({"perf_chip": old})
+
+
+@pytest.mark.parametrize("kind,want", [("TPU v5 lite", "v5e"),
+                                       ("TPU v4", "v4"), ("TPU v99", None)])
+def test_resolve_perf_chip_from_device_kind(monkeypatch, kind, want):
+    """A TPU is priced by its device_kind; a kind with no spec is an
+    error, never some other chip's roofline."""
+    import types
+
+    import jax
+    from paddle_tpu.static.analysis.cost import resolve_perf_chip
+    monkeypatch.setattr(jax, "devices", lambda *a: [types.SimpleNamespace(
+        platform="tpu", device_kind=kind)])
+    if want is None:
+        with pytest.raises(ValueError, match="TPU v99"):
+            resolve_perf_chip()
+    else:
+        assert resolve_perf_chip() == want
 
 
 def test_engine_label_escapes_prometheus_value():

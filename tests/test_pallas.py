@@ -125,7 +125,7 @@ def test_ring_attention_flash_grads(low_seq_threshold):
     from paddle_tpu.parallel.ring_attention import (
         reference_attention, ring_attention_per_device_flash)
     from jax.sharding import PartitionSpec
-    from paddle_tpu.core.jax_compat import shard_map
+    from jax import shard_map
     mesh = init_mesh({"sp": 4})
     r = np.random.RandomState(5)
     qkv = [jnp.asarray(r.randn(1, 128, 2, 16), jnp.float32)
@@ -428,7 +428,7 @@ def test_collective_matmul_tier_selection_contract():
     composite."""
     from jax.sharding import PartitionSpec as P
     from paddle_tpu import distributed as dist
-    from paddle_tpu.core.jax_compat import shard_map
+    from jax import shard_map
     from paddle_tpu.ops.collective_matmul import (all_gather_matmul,
                                                   lowering_label)
     from paddle_tpu.ops.pallas.support import kernel_selections

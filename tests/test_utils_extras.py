@@ -201,3 +201,78 @@ def _spawn_probe(marker):
     rank = os.environ["PADDLE_TRAINER_ID"]
     assert os.environ["PADDLE_TRAINERS_NUM"] == "2"
     open(os.path.join(marker, f"rank{rank}"), "w").close()
+
+
+# -- no fallback that hides the device ------------------------------------
+def test_set_device_never_substitutes_the_cpu():
+    import jax
+    try:
+        with pytest.raises(RuntimeError):
+            paddle.set_device("tpu")        # no TPU in the test process
+        with pytest.raises(RuntimeError):
+            paddle.set_device("gpu:0")      # the alias means TPU too
+        with pytest.raises(ValueError, match="unknown device"):
+            paddle.set_device("npu")
+        assert paddle.set_device("cpu:1").id == 1
+        assert paddle.get_device() == "cpu:1"
+    finally:
+        jax.config.update("jax_default_device", None)
+        paddle.device._current_device = None
+
+
+@pytest.mark.parametrize("kind,platform,want", [
+    ("TPU v5 lite", "tpu", 197e12),
+    ("cpu", "cpu", 0.0),                    # CPU smoke path: no MFU
+    ("TPU v99", "tpu", None),               # unknown TPU: an error
+], ids=["v5e", "cpu", "unknown_tpu"])
+def test_bench_peak_flops_by_device_kind(kind, platform, want):
+    import sys
+    import types
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    import bench
+    dev = types.SimpleNamespace(device_kind=kind, platform=platform)
+    if want is None:
+        with pytest.raises(ValueError, match="TPU v99"):
+            bench._peak_flops(dev)
+    else:
+        assert bench._peak_flops(dev) == want
+
+
+def test_place_compile_cache(monkeypatch):
+    """The environment variable wins and nothing is set in code;
+    without it the cache goes to the fixed <checkout>/.jax_cache."""
+    import jax
+    from paddle_tpu.core.xla_env import place_compile_cache
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+        assert place_compile_cache() == "/somewhere/else"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert place_compile_cache() == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            repo, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_import_creates_no_backend():
+    """One process owns a chip: a parent that only imports the package
+    (launcher, supervisor) must not have created a jax backend, and the
+    probe ``core/xla_env.py`` uses for that must read right."""
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import paddle_tpu, jax\n"
+        "import jax._src.xla_bridge as xb\n"
+        "from paddle_tpu.core.xla_env import _backend_initialized\n"
+        "assert not xb._backends and not _backend_initialized()\n"
+        "jax.devices()\n"
+        "assert _backend_initialized()\n")
+    out = subprocess.run([sys.executable, "-c", code], cwd=repo,
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr

@@ -103,6 +103,9 @@ def main(argv=None) -> int:
                     help="log every HTTP request")
     args = ap.parse_args(argv)
 
+    from paddle_tpu.core.xla_env import place_compile_cache
+    place_compile_cache()
+
     from paddle_tpu import inference, serving
 
     if args.models:
