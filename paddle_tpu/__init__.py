@@ -11,6 +11,10 @@ switch with minimal changes; internals are idiomatic JAX, not a port.
 """
 from __future__ import annotations
 
+import time as _time
+
+_t_import = _time.perf_counter()     # setup.import_s, see the last lines
+
 __version__ = "0.1.0"
 
 # PRNG impl: 'rbg' (XLA RngBitGenerator for bits, threefry for split/fold_in)
@@ -133,3 +137,7 @@ from .ops.manipulation import (crop_tensor, scatter_, shard_index,  # noqa
                                slice, squeeze_, strided_slice, unsqueeze_)
 from .ops.math import (add_n, broadcast_shape, mv, rank, shape,  # noqa
                        tanh_)
+
+# always-on set-up counter: this file's first line to its last, jax's
+# own import included when it happens here
+utils.monitor.stat_set("setup.import_s", _time.perf_counter() - _t_import)

@@ -139,11 +139,21 @@ def place_compile_cache() -> str:
     ``JAX_COMPILATION_CACHE_DIR`` wins: jax reads it by itself and no
     other directory is set in code.  Without it the cache goes to
     ``<checkout>/.jax_cache`` — a fixed path, because the path is part
-    of what a cache hit depends on (never a temp name, a pid, a time)."""
+    of what a cache hit depends on (never a temp name, a pid, a time).
+
+    Either way the cache key takes in the program's metadata (its scope
+    names, and with them its source lines): an executable is loaded only
+    for the program that made it, names included."""
+    import jax
+    # scopes are metadata, and jax's cache key leaves metadata out unless
+    # asked: a program that differs from a cached one in its
+    # ``jax.named_scope`` names alone would be answered with the old
+    # executable and its old ``op_name``s, which every scope-reading
+    # metric would then take for the program's own
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    import jax
     checkout = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     path = os.path.join(checkout, ".jax_cache")

@@ -22,6 +22,7 @@ Also compiled in-graph (zero host syncs per step):
 from __future__ import annotations
 
 import contextlib
+import time
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
@@ -29,6 +30,8 @@ import jax.numpy as jnp
 
 from ..core import autograd, rng
 from ..core.tensor import Tensor
+from ..observability import scopes, span
+from ..utils import monitor
 from .bind import bind, buffer_arrays, buffer_names, param_list
 
 _as_arr = lambda x: x.data if isinstance(x, Tensor) else jnp.asarray(x)
@@ -184,7 +187,7 @@ class TrainStep:
                     k_mb = jax.random.fold_in(key, kidx)
                     p_model = self._decode_params(p_list)
                     with autograd.no_grad(), rng.seed_scope(k_mb), \
-                            amp_scope():
+                            amp_scope(), jax.named_scope(scopes.LOSS):
                         with bind(model, p_model, list(b_cur)) as res:
                             out = model(*[Tensor(a) for a in mb_inputs])
                             lab = [Tensor(a) for a in mb_labels]
@@ -230,44 +233,48 @@ class TrainStep:
                 grads = [g / K for g in g_acc]
 
             if scaler is not None:
-                inv = 1.0 / scale
-                grads = [g * inv for g in grads]
-                finite = jnp.all(jnp.stack(
-                    [jnp.all(jnp.isfinite(g)) for g in grads]))
-                found_inf = jnp.logical_not(finite)
+                with jax.named_scope(scopes.UNSCALE):
+                    inv = 1.0 / scale
+                    grads = [g * inv for g in grads]
+                    finite = jnp.all(jnp.stack(
+                        [jnp.all(jnp.isfinite(g)) for g in grads]))
+                    found_inf = jnp.logical_not(finite)
 
-            grads = grad_transform(grads)
-            new_p, new_s = opt.functional_update(
-                list(p_arr), grads, opt_state, lr, step_i,
-                params_meta=params_meta)
+            with jax.named_scope(scopes.GRAD_CLIP):
+                grads = grad_transform(grads)
+            with jax.named_scope(scopes.OPTIMIZER):
+                new_p, new_s = opt.functional_update(
+                    list(p_arr), grads, opt_state, lr, step_i,
+                    params_meta=params_meta)
 
             new_aux = dict(aux)
             new_aux["draw"] = draw
             if scaler is not None:
-                # skip the update on non-finite grads (reference:
-                # check_finite_and_unscale) ...
-                new_p = _select(found_inf, list(p_arr), new_p)
-                new_s = _select(found_inf, opt_state, new_s)
-                # ... and adjust the scale in-graph (update_loss_scaling)
-                good, bad = aux["good"], aux["bad"]
-                if sc["dynamic"]:
-                    good = jnp.where(found_inf, 0, good + 1)
-                    bad = jnp.where(found_inf, bad + 1, 0)
-                    dec = bad >= sc["decr_every"]
-                    new_scale = jnp.where(
-                        dec, jnp.maximum(scale * sc["decr_ratio"], 1.0),
-                        scale)
-                    bad = jnp.where(dec, 0, bad)
-                    inc = good >= sc["incr_every"]
-                    new_scale = jnp.where(inc, new_scale * sc["incr_ratio"],
-                                          new_scale)
-                    good = jnp.where(inc, 0, good)
-                else:
-                    new_scale = scale
-                new_aux.update(scale=new_scale, good=good, bad=bad,
-                               found_inf=found_inf,
-                               step=jnp.where(found_inf, aux["step"],
-                                              attempt))
+                with jax.named_scope(scopes.SCALER):
+                    # skip the update on non-finite grads (reference:
+                    # check_finite_and_unscale) ...
+                    new_p = _select(found_inf, list(p_arr), new_p)
+                    new_s = _select(found_inf, opt_state, new_s)
+                    # ... and adjust the scale in-graph (update_loss_scaling)
+                    good, bad = aux["good"], aux["bad"]
+                    if sc["dynamic"]:
+                        good = jnp.where(found_inf, 0, good + 1)
+                        bad = jnp.where(found_inf, bad + 1, 0)
+                        dec = bad >= sc["decr_every"]
+                        new_scale = jnp.where(
+                            dec, jnp.maximum(scale * sc["decr_ratio"], 1.0),
+                            scale)
+                        bad = jnp.where(dec, 0, bad)
+                        inc = good >= sc["incr_every"]
+                        new_scale = jnp.where(
+                            inc, new_scale * sc["incr_ratio"], new_scale)
+                        good = jnp.where(inc, 0, good)
+                    else:
+                        new_scale = scale
+                    new_aux.update(scale=new_scale, good=good, bad=bad,
+                                   found_inf=found_inf,
+                                   step=jnp.where(found_inf, aux["step"],
+                                                  attempt))
             else:
                 new_aux["step"] = attempt
             return loss, tuple(new_p), new_b, new_s, new_aux
@@ -312,46 +319,61 @@ class TrainStep:
     def __call__(self, *batch):
         assert len(batch) >= self.n_inputs, (
             f"TrainStep expects at least {self.n_inputs} input(s)")
-        inputs = tuple(_as_arr(b) for b in batch[:self.n_inputs])
-        labels = tuple(_as_arr(b) for b in batch[self.n_inputs:])
-        if self.accumulate_steps > 1:
-            bs = inputs[0].shape[0]
-            if bs % self.accumulate_steps:
-                raise ValueError(
-                    f"batch size {bs} is not divisible by "
-                    f"accumulate_steps={self.accumulate_steps}")
-        p_arr = self._param_arrays()
-        b_arr = tuple(buffer_arrays(self.model))
         if self._opt_state is None:
-            self._opt_state = self.optimizer.functional_init(list(p_arr))
-        if self._scaler_state is None:
-            self._scaler_state = self._init_scaler_state()
-        training = self.model.training
-        compiled = self._compiled.get(training)
-        if compiled is None:
-            compiled = self._build(training)
-            self._compiled[training] = compiled
+            # one-off set-up (counted as setup.opt_state_init_s), kept out
+            # of the step's own python below
+            self._opt_state = self.optimizer.functional_init(
+                list(self._param_arrays()))
+        # always-on host-step counters: time in this method outside the
+        # compiled call (train_step.python_ns over train_step.calls)
+        t0 = time.perf_counter_ns()
+        with span("train_step.prepare"):
+            inputs = tuple(_as_arr(b) for b in batch[:self.n_inputs])
+            labels = tuple(_as_arr(b) for b in batch[self.n_inputs:])
+            if self.accumulate_steps > 1:
+                bs = inputs[0].shape[0]
+                if bs % self.accumulate_steps:
+                    raise ValueError(
+                        f"batch size {bs} is not divisible by "
+                        f"accumulate_steps={self.accumulate_steps}")
+            p_arr = self._param_arrays()
+            b_arr = tuple(buffer_arrays(self.model))
+            if self._scaler_state is None:
+                self._scaler_state = self._init_scaler_state()
+            training = self.model.training
+            compiled = self._compiled.get(training)
+            if compiled is None:
+                compiled = self._build(training)
+                self._compiled[training] = compiled
 
-        self.optimizer._step_count += 1
-        lr_val = float(self.optimizer.get_lr())
-        if lr_val != self._lr_value:
-            # upload the lr only when the schedule moves it (every
-            # host->device transfer stalls the dispatch pipeline)
-            self._lr_value = lr_val
-            self._lr_device = jnp.asarray(lr_val, jnp.float32)
-        loss, new_p, new_b, new_s, new_sc = compiled(
-            p_arr, b_arr, self._opt_state, self._scaler_state,
-            self._lr_device, inputs, labels)
-        # write back (device-side aliasing, no host copies)
-        self._writeback_params(new_p)
-        if self._buffer_objs is None:
-            buffers = dict(self.model.named_buffers())
-            self._buffer_objs = [buffers[n] for n in self._bnames]
-        for b, arr in zip(self._buffer_objs, new_b):
-            b.data = arr
-        self._opt_state = new_s
-        self._scaler_state = new_sc
-        return Tensor(loss)
+            self.optimizer._step_count += 1
+            lr_val = float(self.optimizer.get_lr())
+            if lr_val != self._lr_value:
+                # upload the lr only when the schedule moves it (every
+                # host->device transfer stalls the dispatch pipeline)
+                self._lr_value = lr_val
+                self._lr_device = jnp.asarray(lr_val, jnp.float32)
+        t1 = time.perf_counter_ns()
+        with span("train_step.execute"):
+            loss, new_p, new_b, new_s, new_sc = compiled(
+                p_arr, b_arr, self._opt_state, self._scaler_state,
+                self._lr_device, inputs, labels)
+        t2 = time.perf_counter_ns()
+        with span("train_step.writeback"):
+            # write back (device-side aliasing, no host copies)
+            self._writeback_params(new_p)
+            if self._buffer_objs is None:
+                buffers = dict(self.model.named_buffers())
+                self._buffer_objs = [buffers[n] for n in self._bnames]
+            for b, arr in zip(self._buffer_objs, new_b):
+                b.data = arr
+            self._opt_state = new_s
+            self._scaler_state = new_sc
+            out = Tensor(loss)
+        monitor.stat_add("train_step.python_ns",
+                         t1 - t0 + time.perf_counter_ns() - t2)
+        monitor.stat_add("train_step.calls")
+        return out
 
     def eval_step(self, *batch):
         """Forward-only compiled step (no param update)."""

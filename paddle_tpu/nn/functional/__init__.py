@@ -5,6 +5,11 @@ operators/activation_op.*, conv_op.*, pool_op.*, softmax_op.*, etc.).
 All functions are thin wrappers over pure jnp/lax implementations dispatched
 through the shared tape/trace point; convs and matmuls map directly onto the
 MXU via lax.conv_general_dilated / dot_general.
+
+The composite entry points that models call directly (attention, the fused
+head loss, gelu, layer_norm, embedding, dropout) run under a
+``jax.named_scope`` of their own name (observability/scopes.py), so a
+device trace can be split by component.
 """
 from __future__ import annotations
 
@@ -18,6 +23,7 @@ import numpy as np
 from ...core.dispatch import apply, as_array
 from ...core.rng import next_key, stable_draw
 from ...core.tensor import Tensor
+from ...observability import scopes
 from ...ops.manipulation import pad as _pad_op
 from ...ops.manipulation import squeeze, unsqueeze  # noqa: F401
 
@@ -45,6 +51,7 @@ tanhshrink = _act(lambda a: a - jnp.tanh(a), "tanhshrink")
 hardswish = _act(jax.nn.hard_swish, "hardswish")
 
 
+@jax.named_scope(scopes.GELU)
 def gelu(x, approximate=False, name=None):
     return apply(lambda a: jax.nn.gelu(a, approximate=approximate), x,
                  op_name="gelu")
@@ -177,6 +184,7 @@ def bilinear(x1, x2, weight, bias=None, name=None):
     return out
 
 
+@jax.named_scope(scopes.EMBEDDING)
 def embedding(x, weight, padding_idx=None, sparse=False, name=None):
     def _embedding(ids, w):
         out = jnp.take(w, ids, axis=0)
@@ -577,6 +585,7 @@ def _chan(v, a, ch_axis):
     return v.reshape(shape)
 
 
+@jax.named_scope(scopes.LAYER_NORM)
 def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5,
                name=None):
     if isinstance(normalized_shape, int):
@@ -696,6 +705,7 @@ def _u16_dropout_mask(key, shape, p, dtype, upscale=True):
     return keep
 
 
+@jax.named_scope(scopes.DROPOUT)
 def dropout(x, p=0.5, axis=None, training=True, mode="upscale_in_train",
             name=None):
     if not training or p == 0.0:
@@ -862,6 +872,7 @@ def _linear_ce_fn(h, w, b, lab, *, chunk, ignore_index):
     return total / jnp.maximum(count, 1).astype(jnp.float32)
 
 
+@jax.named_scope(scopes.LINEAR_CROSS_ENTROPY)
 def linear_cross_entropy(hidden, weight, bias, label, chunk: int = 1024,
                          ignore_index: int = -100, name=None):
     """Fused ``cross_entropy(hidden @ weight + bias, label)`` with chunked
@@ -1048,6 +1059,7 @@ def ctc_loss(log_probs, labels, input_lengths, label_lengths, blank=0,
 # attention (tier-1 jnp path; the Pallas flash kernel replaces it on TPU)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope(scopes.ATTENTION)
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
                                  training=True, name=None,

@@ -49,13 +49,32 @@ class LayerList(Layer):
 
     def __getitem__(self, idx):
         if isinstance(idx, slice):
-            return LayerList(list(self._sub_layers.values())[idx])
+            # a view: the items keep the scope names this list gave them
+            view = LayerList()
+            for i, layer in enumerate(list(self._sub_layers.values())[idx]):
+                view._sub_layers[str(i)] = layer
+            return view
         keys = list(self._sub_layers.keys())
         return self._sub_layers[keys[idx]]
 
     def __setitem__(self, idx, layer):
         keys = list(self._sub_layers.keys())
-        self._sub_layers[keys[idx]] = layer
+        self.add_sublayer(keys[idx], layer)
+
+    # a list is iterated, never called, so its items carry its name in
+    # their scope (``blocks.3:Block``), as ``named_parameters`` prints it
+    def _set_scope(self, attr):
+        super()._set_scope(attr)
+        for key, layer in self._sub_layers.items():
+            if layer is not None:
+                layer._set_scope(f"{attr}.{key}")
+
+    def add_sublayer(self, name, sublayer):
+        super().add_sublayer(name, sublayer)
+        scope = self.__dict__.get("_scope")
+        if scope is not None:       # already held: name the new item too
+            self._set_scope(scope.rsplit(":", 1)[0])
+        return sublayer
 
     def __len__(self):
         return len(self._sub_layers)
@@ -72,7 +91,7 @@ class LayerList(Layer):
         layers.insert(index, layer)
         self._sub_layers.clear()
         for i, l in enumerate(layers):
-            self._sub_layers[str(i)] = l
+            self.add_sublayer(str(i), l)
 
     def extend(self, layers):
         for l in layers:

@@ -13,13 +13,16 @@ Two execution paths share these Layers:
 from __future__ import annotations
 
 import collections
+import time
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..core.dtype import convert_dtype, get_default_dtype
 from ..core.tensor import Parameter, Tensor
+from ..utils import monitor
 from . import initializer as I
 
 
@@ -91,6 +94,7 @@ class Layer:
                 raise RuntimeError("call Layer.__init__ before assigning layers")
             params.pop(name, None) if params else None
             layers[name] = value
+            value._set_scope(name)
         elif params is not None and name in params:
             if value is None:
                 del params[name]
@@ -142,10 +146,14 @@ class Layer:
             k = self._param_suffix_counts.get(suffix, 0)
             self._param_suffix_counts[suffix] = k + 1
             name = f"{self._auto_name}.{suffix}_{k}"
+        t0 = time.perf_counter()
         p = Parameter(init(tuple(shape), dtype), name=name,
                       trainable=attr.trainable, regularizer=attr.regularizer,
                       need_clip=attr.need_clip)
         p.optimize_attr["learning_rate"] = attr.learning_rate
+        # always-on set-up counters (the eager initialiser is the cost)
+        monitor.stat_add("setup.param_init_s", time.perf_counter() - t0)
+        monitor.stat_add("setup.param_init_count")
         return p
 
     def add_parameter(self, name: str, parameter: Optional[Parameter]):
@@ -157,7 +165,23 @@ class Layer:
 
     def add_sublayer(self, name: str, sublayer: "Layer"):
         self._sub_layers[name] = sublayer
+        if sublayer is not None:
+            sublayer._set_scope(name)
         return sublayer
+
+    def _set_scope(self, attr: str):
+        """Called by the layer that holds this one as ``attr``: from now
+        on ``__call__`` runs ``forward`` under
+        ``jax.named_scope("<attr>:<ClassName>")`` (the name
+        ``named_parameters`` prints, then the class), which lands in the
+        ``op_name`` of every instruction it traces
+        (observability/scopes.py).  A layer nobody holds runs under its
+        class name alone."""
+        object.__setattr__(self, "_scope",
+                           f"{attr}:{type(self).__name__}")
+
+    def _scope_name(self) -> str:
+        return self.__dict__.get("_scope") or type(self).__name__
 
     def register_buffer(self, name: str, tensor, persistable=True):
         t = tensor if isinstance(tensor, Tensor) or tensor is None else Tensor(tensor)
@@ -343,7 +367,8 @@ class Layer:
             result = hook(self, inputs)
             if result is not None:
                 inputs = result if isinstance(result, tuple) else (result,)
-        out = self.forward(*inputs, **kwargs)
+        with jax.named_scope(self._scope_name()):
+            out = self.forward(*inputs, **kwargs)
         for hook in self._forward_post_hooks.values():
             result = hook(self, inputs, out)
             if result is not None:

@@ -4,6 +4,12 @@ The reproduction's four telemetry islands (profiler host spans,
 ``utils.monitor`` gauges/histograms, the serving ``/metrics`` endpoint,
 ``fault.fired.*`` counters) correlate here:
 
+- :func:`span` / :func:`begin_span` are the program's one host-span
+  primitive: always a ``jax.profiler.TraceAnnotation("pt:<name>")`` (on
+  the device trace's clock in any profiler capture), and an event in the
+  ring when that is enabled.  ``profiler.RecordEvent`` wraps it.
+  :mod:`.scopes` holds the names the program puts *inside* its
+  executables (``jax.named_scope``, kernel names).
 - :func:`enable` installs a process-wide :class:`Tracer` — a ring
   buffer of typed events (spans, eager op dispatches, compiles, worker
   restarts, checkpoint save/restore/fallback, serving dispatches,
@@ -45,6 +51,8 @@ from __future__ import annotations
 import contextlib
 from typing import Optional
 
+import jax
+
 from ..core import obs_hook
 from .compiles import (annotate_compile, explain_compiles,
                        record_compile, reset_compiles)
@@ -67,7 +75,8 @@ from .tracer import EVENT_KINDS, Tracer
 
 __all__ = [
     "Tracer", "EVENT_KINDS", "enable", "disable", "enabled",
-    "get_tracer", "emit", "span", "counter", "set_step",
+    "get_tracer", "emit", "span", "begin_span", "end_span", "counter",
+    "set_step",
     "record_compile", "explain_compiles", "reset_compiles",
     "annotate_compile",
     "prometheus_text", "metrics_snapshot", "dump_metrics", "build_info",
@@ -128,15 +137,32 @@ def set_step(step: int) -> None:
         t.set_step(step)
 
 
+def begin_span(name: str, **args):
+    """Open the program's one kind of host span; returns the token
+    :func:`end_span` takes.  The span always enters
+    ``jax.profiler.TraceAnnotation("pt:" + name)``, so it is in any
+    profiler trace, on the device trace's clock, whether or not the
+    ring is on (outside a capture that costs about a microsecond); with
+    the ring enabled it is also recorded there, with its parent."""
+    ann = jax.profiler.TraceAnnotation("pt:" + name)
+    ann.__enter__()
+    t = obs_hook._tracer
+    return ann, t, (t.begin_span(name, **args) if t is not None else None)
+
+
+def end_span(token) -> None:
+    ann, t, sid = token
+    if sid is not None:
+        t.end_span(sid)
+    ann.__exit__(None, None, None)
+
+
 @contextlib.contextmanager
 def span(name: str, **args):
-    """Span context manager; a no-op (still yields) when disabled."""
-    t = obs_hook._tracer
-    if t is None:
-        yield None
-        return
-    sid = t.begin_span(name, **args)
+    """:func:`begin_span` / :func:`end_span` as a context manager;
+    yields the ring's span id (None with the ring off)."""
+    token = begin_span(name, **args)
     try:
-        yield sid
+        yield token[2]
     finally:
-        t.end_span(sid)
+        end_span(token)

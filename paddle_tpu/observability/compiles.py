@@ -18,6 +18,14 @@ gated behind ``observability.enable()`` — only the tracer *event* per
 compile is.  Each record also counts ``compiles.<component>.<cause>``
 and ``compiles.total`` in monitor, so bench/CI trajectories explain
 perf deltas per cause.
+
+Also always on, for the same reason: what set-up spends before the
+backend compile.  jax reports each trace and each lowering
+(``jax.monitoring``); the listener registered here when the package is
+imported sums them into ``setup.trace_s`` and ``setup.lower_s`` in
+monitor, beside the program's own ``setup.import_s``,
+``setup.param_init_s`` / ``setup.param_init_count`` and
+``setup.opt_state_init_s`` (counted where that work happens).
 """
 from __future__ import annotations
 
@@ -26,11 +34,27 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+import jax.monitoring
+
 from ..core import obs_hook
 from ..utils import monitor
 
 __all__ = ["record_compile", "explain_compiles", "reset_compiles",
            "annotate_compile"]
+
+_SETUP_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "setup.trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "setup.lower_s",
+}
+
+
+def _on_jax_duration(event: str, secs: float, **_kw) -> None:
+    stat = _SETUP_EVENTS.get(event)
+    if stat is not None:
+        monitor.stat_add(stat, secs)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 _MAX_RECORDS = 2048          # ring of full records; totals never drop
 

@@ -1,0 +1,52 @@
+"""The fixed vocabulary of ``jax.named_scope`` names the program puts into
+its executables' ``op_name`` metadata (constants only).
+
+Three kinds, all short (a scope string is repeated on every instruction
+under it, and the step executables are tens of megabytes):
+
+- phases of a compiled train step, set by ``jit.TrainStep`` and the
+  static ``Executor`` around the code that is there.  jax itself wraps
+  the differentiated ``loss`` phase as ``jvp(loss)`` (forward) and
+  ``transpose(jvp(loss))`` (backward), and marks what ``jax.checkpoint``
+  replays as ``rematted_computation``;
+- components: every ``nn.Layer`` runs its ``forward`` under
+  ``"<attribute name>:<ClassName>"`` (``blocks.3:Block``, ``q:Linear``),
+  and the composite functional entry points under their own name;
+- kernels: every ``pl.pallas_call`` carries ``name=`` and sits under a
+  scope of the same name.
+
+PERF.md (section 3) lists which benchmark metric reads which name.
+"""
+from __future__ import annotations
+
+# -- phases ----------------------------------------------------------------
+LOSS = "loss"                 # model forward + loss_fn
+UNSCALE = "unscale"           # loss-scaling: unscale grads, find non-finite
+GRAD_CLIP = "grad_clip"       # gradient transform and clip
+OPTIMIZER = "optimizer"       # the parameter update
+SCALER = "scaler"             # loss-scaling: skip the update, move the scale
+PHASES = (LOSS, UNSCALE, GRAD_CLIP, OPTIMIZER, SCALER)
+
+# -- composite functional entry points --------------------------------------
+ATTENTION = "scaled_dot_product_attention"
+LINEAR_CROSS_ENTROPY = "linear_cross_entropy"
+GELU = "gelu"
+LAYER_NORM = "layer_norm"
+EMBEDDING = "embedding"
+DROPOUT = "dropout"
+FUNCTIONALS = (ATTENTION, LINEAR_CROSS_ENTROPY, GELU, LAYER_NORM, EMBEDDING,
+               DROPOUT)
+
+# -- Pallas kernels ----------------------------------------------------------
+FLASH_FWD = "flash_fwd"
+FLASH_BWD_DQ = "flash_bwd_dq"
+FLASH_BWD_DKV = "flash_bwd_dkv"
+EPILOGUE_FWD = "epilogue_fwd"
+EPILOGUE_BWD = "epilogue_bwd"
+FUSED_ADAM = "fused_adam"
+PAGED_ATTENTION = "paged_attention"
+COLLECTIVE_MATMUL_CHUNK = "collective_matmul_chunk"
+KERNELS = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV, EPILOGUE_FWD,
+           EPILOGUE_BWD, FUSED_ADAM, PAGED_ATTENTION,
+           COLLECTIVE_MATMUL_CHUNK)
+

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ...observability import scopes
 from .support import block_rows, dot, dtype_ok, \
     interpret_mode as _interpret_mode
 
@@ -74,6 +75,7 @@ def chunk_matmul(x, w, *, interpret=None):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, c: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, nc), jnp.float32),
         interpret=interpret,
+        name=scopes.COLLECTIVE_MATMUL_CHUNK,
     )(x, w)
     from .support import count_kernel_selection
     count_kernel_selection("collective_matmul")

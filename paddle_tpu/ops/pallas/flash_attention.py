@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ...observability import scopes
 from .support import (NEG_INF, dot as _dot, interpret_mode as _interpret,
                       pltpu, smem_scalar_spec as _smem_scalar_spec)
 
@@ -207,6 +208,7 @@ def _fwd(q, k, v, q_off, k_off, seed, scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct((B, H, 8, Lq), jnp.float32),
         ],
         interpret=_interpret(),
+        name=scopes.FLASH_FWD,
     )(q_off, k_off, seed, q, k, v)
     # compact [B, H, Lq] is the residual / public lse shape; the 8-sublane
     # replication exists only at the kernel boundary
@@ -321,6 +323,7 @@ def _bwd(q, k, v, q_off, k_off, seed, out, lse, do, dlse, scale, causal,
                                lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Lq, D), q.dtype),
         interpret=_interpret(),
+        name=scopes.FLASH_BWD_DQ,
     )(q_off, k_off, seed, q, k, v, do, lse8, delta8)
 
     dk, dv = pl.pallas_call(
@@ -348,6 +351,7 @@ def _bwd(q, k, v, q_off, k_off, seed, out, lse, do, dlse, scale, causal,
             jax.ShapeDtypeStruct((B, H, Lk, D), v.dtype),
         ],
         interpret=_interpret(),
+        name=scopes.FLASH_BWD_DKV,
     )(q_off, k_off, seed, q, k, v, do, lse8, delta8)
     return dq, dk, dv
 

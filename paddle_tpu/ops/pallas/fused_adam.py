@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ...observability import scopes
 from .support import block_rows, interpret_mode as _interpret_mode, \
     smem_scalar_spec
 
@@ -94,6 +95,7 @@ def fused_adam_update(p, g, m, v, lr, step, *, beta1=0.9, beta2=0.999,
         out_specs=[blk, blk, blk],
         out_shape=[jax.ShapeDtypeStruct((rows, _LANES), jnp.float32)] * 3,
         interpret=interpret,
+        name=scopes.FUSED_ADAM,
     )(lr2, step2, flat(p), flat(g), flat(m), flat(v))
 
     def unflat(a):

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ...observability import scopes
 from .support import block_rows, dot as _dot, dtype_ok, \
     interpret_mode as _interpret_mode, pltpu
 
@@ -256,6 +257,7 @@ def _fwd(stages, interpret, x2, w, ops):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
+        name=scopes.EPILOGUE_FWD,
     )(x2, w, *ops)
     return out
 
@@ -362,6 +364,7 @@ def _bwd_call(stages, interpret, x2, w, ops, dy):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
+        name=scopes.EPILOGUE_BWD,
     )(x2, w, dy, *ops)
     dx = outs[0]
     dw = outs[1].astype(w.dtype)

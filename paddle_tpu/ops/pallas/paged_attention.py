@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ...observability import scopes
 from .support import NEG_INF, dot as _dot, dtype_ok, \
     interpret_mode as _interpret_mode, pltpu
 
@@ -167,6 +168,7 @@ def paged_attention_decode(q, k_pool, v_pool, page_table, lengths,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
         interpret=interpret,
+        name=scopes.PAGED_ATTENTION,
     )
     out = call(table, lens, q, k_pool, v_pool)
     from .support import count_kernel_selection

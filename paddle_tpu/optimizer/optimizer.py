@@ -13,6 +13,7 @@ Design: every optimizer defines two PURE functions over arrays —
 """
 from __future__ import annotations
 
+import time
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
@@ -21,6 +22,8 @@ import numpy as np
 
 from ..core import autograd
 from ..core.tensor import Parameter, Tensor
+from ..observability import scopes
+from ..utils import monitor
 from .clip import ClipGradBase
 from .lr import LRScheduler
 from .regularizer import L1Decay, L2Decay, WeightDecayRegularizer
@@ -176,6 +179,7 @@ class Optimizer:
 
     # -- functional interface (used by jit.TrainStep) ----------------------
     def functional_init(self, param_arrays: Sequence[jnp.ndarray]):
+        t0 = time.perf_counter()
         states = []
         for p in param_arrays:
             s = self.init_slots(p)
@@ -183,6 +187,9 @@ class Optimizer:
                     and p.dtype in (jnp.bfloat16, jnp.float16)):
                 s["master"] = p.astype(jnp.float32)
             states.append(s)
+        # always-on set-up counter (eager ops, one or more a leaf)
+        monitor.stat_add("setup.opt_state_init_s",
+                         time.perf_counter() - t0)
         return states
 
     def functional_update(self, param_arrays, grad_arrays, states, lr,
@@ -192,7 +199,8 @@ class Optimizer:
         regularizer / per-param lr metadata."""
         meta = params_meta or [None] * len(param_arrays)
         if self._grad_clip is not None:
-            pg = self._grad_clip(list(zip(meta, grad_arrays)))
+            with jax.named_scope(scopes.GRAD_CLIP):
+                pg = self._grad_clip(list(zip(meta, grad_arrays)))
             grad_arrays = [g for _, g in pg]
         new_ps, new_ss = [], []
         for p, g, s, m in zip(param_arrays, grad_arrays, states, meta):

@@ -48,7 +48,10 @@ def recompute(function, *args, **kwargs):
                    for a in args]
         with autograd.no_grad():
             if layer is not None:
-                with bind(layer, p_arr):
+                # ``forward`` is called, not ``__call__``, so the layer's
+                # named scope is entered here
+                with bind(layer, p_arr), \
+                        jax.named_scope(layer._scope_name()):
                     out = fn(*rebuilt, **kwargs)
             else:
                 out = fn(*rebuilt, **kwargs)

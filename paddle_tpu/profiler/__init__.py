@@ -7,9 +7,9 @@ trace export).
 
 TPU-native design: device-side timing belongs to XLA — ``Profiler``
 drives ``jax.profiler`` traces (viewable in TensorBoard/Perfetto, the
-timeline.py analog), and :class:`RecordEvent` spans emit
-``jax.profiler.TraceAnnotation`` so framework phases appear as named
-spans on the host track of the same trace.  Host-side per-op timing for
+timeline.py analog), and :class:`RecordEvent` spans go through
+``observability.begin_span`` so framework phases appear as named
+``pt:`` spans on the host track of the same trace.  Host-side per-op timing for
 eager mode hooks the single dispatch point (core/dispatch.apply) — the
 analog of the reference's RecordEvent inside Tracer::TraceOp — and
 ``summary()`` prints the top-k table the reference prints on
@@ -25,7 +25,8 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 
-from ..core import obs_hook, profiler_hook
+from ..core import profiler_hook
+from ..observability import begin_span, end_span
 
 __all__ = [
     "Profiler", "ProfilerTarget", "ProfilerState", "RecordEvent",
@@ -51,11 +52,11 @@ class ProfilerState(Enum):
 class RecordEvent:
     """Named span (reference: platform/profiler.h:127 RecordEvent).
 
-    Context manager or ``begin()``/``end()`` pair.  Emits a
-    jax.profiler.TraceAnnotation (shows on the trace's host track),
-    accumulates host time under ``name`` when a Profiler is active, and
-    lands on the observability tracer as a nested span (correct parent
-    attribution) when tracing is enabled.
+    Context manager or ``begin()``/``end()`` pair: a thin wrapper over
+    the program's one span primitive (``observability.begin_span``: a
+    ``pt:<name>`` annotation on the trace's host track, and a nested
+    span in the observability ring when that is enabled) that also
+    accumulates host time under ``name`` when a Profiler is active.
 
     Robustness contract: ``end()`` without a prior ``begin()`` is a
     no-op (not a TypeError), ``end()`` is idempotent, and the context
@@ -63,16 +64,11 @@ class RecordEvent:
 
     def __init__(self, name: str, event_type=None):
         self.name = name
-        self._ann = None
-        self._t0 = None
         self._span = None
+        self._t0 = None
 
     def begin(self):
-        self._ann = jax.profiler.TraceAnnotation(self.name)
-        self._ann.__enter__()
-        trc = obs_hook._tracer
-        if trc is not None:
-            self._span = trc.begin_span(self.name)
+        self._span = begin_span(self.name)
         self._t0 = time.perf_counter()
         return self
 
@@ -81,17 +77,11 @@ class RecordEvent:
         if t0 is None:      # begin() never ran, or end() ran already
             return
         dt = time.perf_counter() - t0
-        ann, self._ann = self._ann, None
-        if ann is not None:
-            ann.__exit__(None, None, None)
+        span, self._span = self._span, None
+        end_span(span)
         prof = profiler_hook.current()
         if prof is not None:
             prof._record(self.name, dt, kind="span")
-        span, self._span = self._span, None
-        if span is not None:
-            trc = obs_hook._tracer
-            if trc is not None:
-                trc.end_span(span)
 
     __enter__ = begin
 

@@ -46,6 +46,7 @@ import numpy as np
 from ..core import obs_hook
 from ..core.flags import get_flag
 from ..core.tensor import Tensor
+from ..observability import begin_span, end_span, scopes, span
 from .program import Program, Variable, default_main_program
 
 __all__ = ["Executor", "global_scope"]
@@ -91,7 +92,9 @@ def _interp(nodes, env, pmap):
                     args.append(pmap[id(v)])
                 else:  # const / literal
                     args.append(v)
-            outs = node.fn(*args, **node.kw)
+            # each node under a scope of its op type (scopes.py)
+            with jax.named_scope(node.op_name):
+                outs = node.fn(*args, **node.kw)
             outs = list(outs) if node.multi else [outs]
             for var, o in zip(node.out_vars, outs):
                 env[var.name] = o
@@ -623,13 +626,11 @@ class Executor:
             return program._run_loaded(feed, fetch_list, return_numpy)
         if program is None:
             program = default_main_program()
-        # observability: a span per run when tracing is on (one
-        # module-attribute None-check when off), and any exception
+        # observability: a span per run (a trace annotation always, a
+        # ring event when tracing is on), and any exception
         # escaping the step feeds the crash flight recorder before
         # propagating — the executor is where a training step dies
-        trc = obs_hook._tracer
-        sid = (trc.begin_span("executor.run", program=program._serial)
-               if trc is not None else None)
+        sid = begin_span("executor.run", program=program._serial)
         try:
             return self._run(program, feed, fetch_list, return_numpy,
                              seed)
@@ -639,8 +640,7 @@ class Executor:
                 h(e, f"executor.run(program#{program._serial})")
             raise
         finally:
-            if sid is not None:
-                trc.end_span(sid)
+            end_span(sid)
 
     def _run(self, program, feed, fetch_list, return_numpy, seed):
         # chaos hook: lets fault specs crash a training step on demand
@@ -668,7 +668,8 @@ class Executor:
         # host-side anatomy stamps around feed conversion and dispatch
         perf = obs_hook._perf
         t_h0 = time.perf_counter() if perf is not None else 0.0
-        feed_arrays = [self._feed_array(a) for _, a in feed_items]
+        with span("executor.feed"):
+            feed_arrays = [self._feed_array(a) for _, a in feed_items]
         t_h1 = time.perf_counter() if perf is not None else 0.0
 
         self._track(program)
@@ -903,9 +904,11 @@ class Executor:
                         (state.p_arrays, state.opt_state, state.aux),
                         st_sh)
             t_d0 = time.perf_counter() if perf is not None else 0.0
-            fetches, new_p, new_s, new_aux = compiled(
-                state.p_arrays, state.opt_state, state.aux,
-                state.lr_device, state.base_key, *seed_args, *feed_arrays)
+            with span("executor.execute"):
+                fetches, new_p, new_s, new_aux = compiled(
+                    state.p_arrays, state.opt_state, state.aux,
+                    state.lr_device, state.base_key, *seed_args,
+                    *feed_arrays)
             state.p_arrays = list(new_p)
             state.opt_state = new_s
             state.aux = new_aux
@@ -940,7 +943,8 @@ class Executor:
             rng_key = jax.random.fold_in(
                 state.base_key, run_i if seed is None else int(seed))
             t_d0 = time.perf_counter() if perf is not None else 0.0
-            fetches = compiled(state.p_arrays, rng_key, *feed_arrays)
+            with span("executor.execute"):
+                fetches = compiled(state.p_arrays, rng_key, *feed_arrays)
 
         # step anatomy: host lane every run, device fence + memory
         # sample on the observatory's cadence.  The run that compiled
@@ -1252,7 +1256,8 @@ class Executor:
                     full = list(p_arrays)
                     for j, a in zip(t_idx, tlist):
                         full[j] = a
-                    with _rng.seed_scope(k_local):
+                    with _rng.seed_scope(k_local), \
+                            jax.named_scope(scopes.LOSS):
                         env = forward_env(full, local_feeds)
                     return env[loss_var.name], env
 
@@ -1325,9 +1330,10 @@ class Executor:
                 check_vma=False)(tuple(t_arrays), residuals,
                                  *feed_arrays)
 
-            new_t, new_s = opt.functional_update(
-                t_arrays, list(grads), opt_state, lr, step_i,
-                params_meta=params_meta)
+            with jax.named_scope(scopes.OPTIMIZER):
+                new_t, new_s = opt.functional_update(
+                    t_arrays, list(grads), opt_state, lr, step_i,
+                    params_meta=params_meta)
             if sentry:
                 anom_i, nf_bucket, nf_extra, norm2 = sleaves
                 # the select is elementwise, so an un-flagged step is
@@ -1596,7 +1602,8 @@ class Executor:
                 full = list(p_arrays)
                 for j, a in zip(t_idx, tlist):
                     full[j] = a
-                with _rng.seed_scope(rng_key):
+                with _rng.seed_scope(rng_key), \
+                        jax.named_scope(scopes.LOSS):
                     env = forward_env(full, feed_arrays)
                 return env[loss_var.name], env
 
@@ -1610,9 +1617,10 @@ class Executor:
                 for g, p in zip(grads, params_meta)]
             update = (fused_update if fused_update is not None
                       else opt.functional_update)
-            new_t, new_s = update(
-                t_arrays, grads, opt_state, lr, step_i,
-                params_meta=params_meta)
+            with jax.named_scope(scopes.OPTIMIZER):
+                new_t, new_s = update(
+                    t_arrays, grads, opt_state, lr, step_i,
+                    params_meta=params_meta)
             new_aux = {"run": run_i, "step": aux["step"] + 1}
             fetch_out = [env[n] for n in fetch_names]
             if sentry:
@@ -1791,16 +1799,18 @@ class Executor:
                 full = list(p_arrays)
                 for j, a in zip(t_idx, tlist):
                     full[j] = a
-                with _rng.seed_scope(rng_key):
+                with _rng.seed_scope(rng_key), \
+                        jax.named_scope(scopes.LOSS):
                     env = forward_env(full, feed_arrays)
                 return env[loss_var.name], env
 
             t_arrays = [p_arrays[i] for i in t_idx]
             (loss, env), grads = jax.value_and_grad(
                 loss_of, has_aux=True)(t_arrays)
-            new_t, new_s = opt.functional_update(
-                t_arrays, grads, opt_state, lr, step_i,
-                params_meta=params_meta)
+            with jax.named_scope(scopes.OPTIMIZER):
+                new_t, new_s = opt.functional_update(
+                    t_arrays, grads, opt_state, lr, step_i,
+                    params_meta=params_meta)
             new_p = list(p_arrays)
             for j, a in zip(t_idx, new_t):
                 new_p[j] = a

@@ -23,7 +23,7 @@ from paddle_tpu.utils import monitor
 # the PR-9 text exposition grammar gate (tools/obs_smoke.py keeps the
 # same regex): proc-labelled fleet samples must still parse under it
 PROM_LINE = re.compile(
-    r"^[a-zA-Z_:][a-zA-Z0-9_:]*(\{[^{}]*\})? -?[0-9.eE+naif]+$")
+    r"^[a-zA-Z_:][a-zA-Z0-9_:]*(\{[^{}]*\})? -?[0-9.eE+\-naif]+$")
 
 
 @pytest.fixture

@@ -304,7 +304,7 @@ def test_jit_compile_attribution():
 
 # ------------------------------------------------------ metrics export ---
 PROM_LINE = re.compile(
-    r"^[a-zA-Z_:][a-zA-Z0-9_:]*(\{[^{}]*\})? -?[0-9.eE+naif]+$")
+    r"^[a-zA-Z_:][a-zA-Z0-9_:]*(\{[^{}]*\})? -?[0-9.eE+\-naif]+$")
 
 
 def test_prometheus_text_parses_and_covers_registry():

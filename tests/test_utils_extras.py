@@ -240,22 +240,27 @@ def test_bench_peak_flops_by_device_kind(kind, platform, want):
 
 
 def test_place_compile_cache(monkeypatch):
-    """The environment variable wins and nothing is set in code;
-    without it the cache goes to the fixed <checkout>/.jax_cache."""
+    """The environment variable wins and no directory is set in code;
+    without it the cache goes to the fixed <checkout>/.jax_cache.  Either
+    way the key takes the program's metadata in (its scope names)."""
     import jax
     from paddle_tpu.core.xla_env import place_compile_cache
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     before = jax.config.jax_compilation_cache_dir
+    in_key = "jax_compilation_cache_include_metadata_in_key"
+    assert not getattr(jax.config, in_key)
     try:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
         assert place_compile_cache() == "/somewhere/else"
         assert jax.config.jax_compilation_cache_dir == before
+        assert getattr(jax.config, in_key)
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         assert place_compile_cache() == os.path.join(repo, ".jax_cache")
         assert jax.config.jax_compilation_cache_dir == os.path.join(
             repo, ".jax_cache")
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update(in_key, False)
 
 
 def test_import_creates_no_backend():

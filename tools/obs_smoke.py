@@ -65,7 +65,7 @@ if REPO not in sys.path:
 
 # metric_name{labels} value  — the text exposition grammar subset we emit
 PROM_LINE = re.compile(
-    r"^[a-zA-Z_:][a-zA-Z0-9_:]*(\{[^{}]*\})? -?[0-9.eE+naif]+$")
+    r"^[a-zA-Z_:][a-zA-Z0-9_:]*(\{[^{}]*\})? -?[0-9.eE+\-naif]+$")
 
 _CHROME_PH = {"X", "i", "C", "B", "E", "M"}
 
