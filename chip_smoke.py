@@ -31,6 +31,7 @@ The last line of stdout is exactly
 """
 import argparse
 import contextlib
+import functools
 import gc
 import io
 import json
@@ -48,6 +49,8 @@ if REPO not in sys.path:
 # ---------------------------------------------------------------- widths --
 BERT_BASE = dict(vocab=30522, hidden=768, layers=12, heads=12, ffn=3072,
                  seq=512, batch=64, dropout=0.1)
+# attention of the benchmark's GPT cell: gpt3_large at context 2048
+GPT_CELL = dict(seq=2048, heads=16, head_dim=96)
 # The f32 static program keeps the full batch: compiled for a described
 # v5e it has 14.86 GiB live of the chip's 15.75 (PERF.md, Findings), so
 # it fits — as long as the phase before it left nothing on the device.
@@ -432,22 +435,30 @@ def _sq(fn):
     return lambda *a: jnp.sum(fn(*a).astype(jnp.float32) ** 2)
 
 
-def check_flash(errs, bert):
-    """Flash attention fwd+bwd at BERT-base heads, a slice of the batch."""
+def check_flash(errs, bert, gpt=GPT_CELL):
+    """Flash attention fwd+bwd at BERT-base heads and, causal, at the
+    benchmark's GPT cell's, a slice of the batch each: the oracle holds
+    the scores."""
     import jax
     import jax.numpy as jnp
 
     from paddle_tpu.ops.pallas.flash_attention import (flash_attention,
                                                        mha_reference)
-    shape = (4, bert["seq"], bert["heads"], bert["hidden"] // bert["heads"])
-    q, k, v = (_rnd(i, shape, jnp.bfloat16) for i in (1, 2, 3))
-    errs["flash_fwd"] = _close(jax.jit(flash_attention)(q, k, v),
-                               mha_reference(q, k, v), BF16_TOL,
-                               "flash fwd")
-    got = jax.jit(jax.grad(_sq(flash_attention), (0, 1, 2)))(q, k, v)
-    ref = jax.jit(jax.grad(_sq(mha_reference), (0, 1, 2)))(q, k, v)
-    for n, a, b in zip("qkv", got, ref):
-        errs[f"flash_d{n}"] = _close(a, b, 4 * BF16_TOL, f"flash d{n}")
+    heads = (bert["seq"], bert["heads"], bert["hidden"] // bert["heads"])
+    for tag, shape, causal in (("flash", (4,) + heads, False),
+                               ("flash_causal",
+                                (2, gpt["seq"], gpt["heads"],
+                                 gpt["head_dim"]), True)):
+        q, k, v = (_rnd(i, shape, jnp.bfloat16) for i in (1, 2, 3))
+        flash, plain = (functools.partial(f, causal=causal)
+                        for f in (flash_attention, mha_reference))
+        errs[f"{tag}_fwd"] = _close(jax.jit(flash)(q, k, v),
+                                    jax.jit(plain)(q, k, v), BF16_TOL,
+                                    f"{tag} fwd")
+        got = jax.jit(jax.grad(_sq(flash), (0, 1, 2)))(q, k, v)
+        ref = jax.jit(jax.grad(_sq(plain), (0, 1, 2)))(q, k, v)
+        for n, a, b in zip("qkv", got, ref):
+            errs[f"{tag}_d{n}"] = _close(a, b, 4 * BF16_TOL, f"{tag} d{n}")
 
 
 def check_flash_dropout(errs, bert):

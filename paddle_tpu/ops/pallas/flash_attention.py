@@ -9,7 +9,16 @@ score matrix never touches HBM.
 
 Layout: [B, L, H, D] in (paddle layout), transposed once to [B, H, L, D]
 around the kernel.  Forward saves per-row logsumexp for the
-recompute-based backward (standard FlashAttention-2 dataflow).
+recompute-based backward (standard FlashAttention-2 dataflow).  Inside,
+every score tile is held transposed ([block_k, block_q], see the note
+above the kernels).
+
+Per call on one v5e (PERF.md, PR 25, where the sweep and the per-block
+times are): [8, 16, 2048, 96] bf16 causal takes 1.48 ms forward, 1.73 ms
+dq and 2.46 ms dk/dv; [64, 12, 512, 64] bf16 non-causal 0.85, 1.00 and
+1.20 ms.  Whether XLA's attention or this kernel is taken at a length is
+the FLAGS_pallas_attention_min_seqlen gate's; it has not been re-measured
+against these times (ROADMAP.md S6).
 
 Causal masking supports traced *global position offsets* for Q and K
 (`q_off`/`k_off`, float32 [1,1] scalars): a Q/K pair is visible when
@@ -37,11 +46,12 @@ from .support import (NEG_INF, dot as _dot, interpret_mode as _interpret,
 
 def flash_attention_supported(q_shape, k_shape, dtype, attn_mask=None,
                               dropout_p: float = 0.0,
-                              block_q: int = 512, block_k: int = 512) -> bool:
+                              block_q: int | None = None,
+                              block_k: int | None = None) -> bool:
     """Capability + profitability check: shapes/dtype the kernel handles
-    AND where it beats XLA's fused attention (measured on v5e: flash wins
-    ~30% at seq>=2048, XLA wins ~2% at seq 512 — the crossover is the
-    FLAGS_pallas_attention_min_seqlen knob).  Attention dropout runs
+    AND where it is taken over XLA's fused attention (the
+    FLAGS_pallas_attention_min_seqlen gate; the module docstring has the
+    per-call times it is to be re-measured against).  Attention dropout runs
     IN-KERNEL via the Pallas TPU PRNG (tile-seeded, regenerated in the
     backward) — but only on real TPUs (interpret mode has no PRNG)."""
     from ...core.flags import get_flag
@@ -61,7 +71,8 @@ def flash_attention_supported(q_shape, k_shape, dtype, attn_mask=None,
     if max(Lq, Lk) < min_len:
         return False
     # blocks must tile the sequence
-    if Lq % min(block_q, Lq) or Lk % min(block_k, Lk):
+    bq, bk = _resolve_blocks(block_q, block_k, Lq, Lk)
+    if Lq % bq or Lk % bk:
         return False
     if D % 8:  # lane alignment of the head dim
         return False
@@ -73,26 +84,120 @@ def flash_attention_supported(q_shape, k_shape, dtype, attn_mask=None,
     return True
 
 
-def _mask_scores(s, causal, qi, j, q_off_ref, k_off_ref, block_q, block_k,
-                 bq):
+# ---------------------------------------------------------------------------
+# blocks from the shape
+# ---------------------------------------------------------------------------
+
+# Measured on one v5e (PERF.md, PR 25): 512 x 512 is the fastest of
+# {256, 512, 1024}^2 for each of the three kernels at [8, 16, 2048, 96]
+# bf16 causal and at [64, 12, 512, 64] bf16: smaller tiles reload the
+# MXU's weights for fewer rows (256 x 256 takes 1.9x as long), larger
+# ones spill more.  The largest shapes flash_attention_supported admits
+# compile within Mosaic's default scoped VMEM at this size.
+_BLOCK = 512
+
+
+def _resolve_blocks(block_q, block_k, Lq, Lk):
+    """(block_q, block_k) of all three kernels, from the static shapes:
+    ``_BLOCK`` where left at None (explicit ones are for tests and the
+    ring path), the whole of a shorter sequence.  One pair for the three
+    kernels, because the dropout tile seeds are block indices."""
+    return min(block_q or _BLOCK, Lq), min(block_k or _BLOCK, Lk)
+
+
+def _count_blocks(Lq, Lk, block_q, block_k, causal, aligned):
+    """Trace-time counters, once per kernel traced: block iterations per
+    (batch, head) that run without a mask (``blocks_full``) and with one
+    (``blocks_masked``).  The dkv kernel walks the same (q block, k block)
+    pairs as the other two, by columns."""
+    from ...utils import monitor
+    num_q, num_kv = Lq // block_q, Lk // block_k
     if not causal:
-        return s
+        full, masked = num_q * num_kv, 0
+    elif not aligned:
+        full, masked = 0, num_q * num_kv
+    else:
+        bounds = [_kv_bounds(qi, block_q, block_k, num_kv, minimum=min)
+                  for qi in range(num_q)]
+        full = sum(f for f, _ in bounds)
+        masked = sum(e - f for f, e in bounds)
+    monitor.stat_add("pallas.flash.blocks_full", full)
+    monitor.stat_add("pallas.flash.blocks_masked", masked)
+
+
+# Every kernel holds its score tile as [block_k, block_q]: k positions on
+# the sublanes, q positions on the lanes.  The per-query softmax state
+# (m, l, lse, delta) is then a [1, block_q] row of a few vregs instead of
+# a [block_q, 1] column of block_q / 8, its reductions run down the
+# sublanes on the VALU, and the (8, block_q) lse blocks need no relayout.
+# Operands and results keep the natural [L, D] layout: the MXU takes the
+# transposed operand itself and the [D, block_q] accumulators are
+# transposed once per grid step.
+
+def _mask_scores(s, q_off_ref, k_off_ref, qi, j, block_q, block_k):
+    """Position mask of the ring path: visibility depends on the traced
+    global offsets, so every block is masked."""
     q_off = q_off_ref[0, 0]
     k_off = k_off_ref[0, 0]
     # int32 iota + cast: Mosaic's tpu.iota only produces integer vectors
-    q_pos = (q_off + qi * block_q
-             + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k),
-                                        0).astype(jnp.float32))
     k_pos = (k_off + j * block_k
-             + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k),
+             + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                        0).astype(jnp.float32))
+    q_pos = (q_off + qi * block_q
+             + jax.lax.broadcasted_iota(jnp.int32, s.shape,
                                         1).astype(jnp.float32))
     return jnp.where(q_pos >= k_pos, s, NEG_INF)
+
+
+def _mask_diagonal(s, qi, j, block_q, block_k):
+    """Causal mask of a block that straddles the diagonal (aligned path:
+    both sequences start at position 0): query c of q block ``qi`` sees
+    key r of k block ``j`` iff ``c - r >= j*block_k - qi*block_q``."""
+    rel = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+           - jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
+    return jnp.where(rel >= j * block_k - qi * block_q, s, NEG_INF)
+
+
+def _kv_bounds(qi, block_q, block_k, num_kv, minimum=jnp.minimum):
+    """Aligned causal schedule of q block ``qi``: k blocks [0, full) lie
+    wholly below the diagonal, [full, end) straddle it, the rest are
+    invisible.  ``minimum=min`` gives Python ints for the counters."""
+    full = minimum((qi * block_q + 1) // block_k, num_kv)
+    end = minimum(pl.cdiv((qi + 1) * block_q, block_k), num_kv)
+    return full, end
+
+
+def _q_bounds(kj, block_q, block_k, num_q, minimum=jnp.minimum):
+    """The same schedule seen from k block ``kj``: q blocks
+    [start, full) straddle the diagonal, [full, num_q) lie wholly below."""
+    start = minimum((kj * block_k) // block_q, num_q)
+    full = minimum(pl.cdiv((kj + 1) * block_k - 1, block_q), num_q)
+    return start, full
+
+
+def _block_loops(body, carry, num_blocks, causal, aligned, causal_spans):
+    """Run ``body(i, carry, mask)`` over one grid step's blocks with the
+    mask each needs: none without ``causal``, the position mask on every
+    block of the ring path, and in the aligned causal path the
+    ``(lo, hi, mask)`` runs of ``causal_spans``: the diagonal mask only
+    where a block straddles the diagonal, invisible blocks skipped."""
+    if not causal:
+        spans = ((0, num_blocks, None),)
+    elif not aligned:
+        spans = ((0, num_blocks, "positions"),)
+    else:
+        spans = causal_spans
+    for lo, hi, mask in spans:
+        carry = jax.lax.fori_loop(
+            lo, hi, functools.partial(body, mask=mask), carry)
+    return carry
 
 
 def _dropout_keep(seed_ref, qi, j, shape, dropout_p):
     """Tile keep-mask from the Pallas TPU PRNG, seeded on
     (user seed, b, h, q-block, k-block) so the backward kernels reproduce
-    the forward's mask exactly.  prng_random_bits has int32 semantics on
+    the forward's mask exactly (all three hold the tile as
+    [block_k, block_q]).  prng_random_bits has int32 semantics on
     TPU: an arithmetic >>16 yields uniform [-32768, 32767], compared
     against the p-quantile threshold."""
     b = pl.program_id(0)
@@ -122,6 +227,30 @@ def _apply_dropout(p, seed_ref, qi, j, dropout_p):
     return jnp.where(keep, p * inv_keep, 0.0)
 
 
+def _prescale(x, scale):
+    """``x * scale`` rounded back to x's dtype: every kernel scores with
+    the same pre-scaled q, so the backward's recomputed p is the
+    forward's."""
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
+def _scores(k, q, mask, qi, j, q_off_ref, k_off_ref, block_q, block_k):
+    """[BK, BQ] f32 scores of (pre-scaled) q block ``qi`` against k block
+    ``j`` under the block's mask."""
+    s = _dot(k, q, ((1,), (1,)))
+    if mask == "diagonal":
+        return _mask_diagonal(s, qi, j, block_q, block_k)
+    if mask == "positions":
+        return _mask_scores(s, q_off_ref, k_off_ref, qi, j, block_q,
+                            block_k)
+    return s
+
+
+def _rows(ref, j, block):
+    """Rows [j*block, (j+1)*block) of a sequence staged whole."""
+    return ref[0, 0, pl.ds(pl.multiple_of(j * block, block), block), :]
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -130,49 +259,48 @@ def _fwd_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
                 o_ref, lse_ref, *, scale, block_k, seq_k, causal, block_q,
                 aligned, dropout_p):
     qi = pl.program_id(2)
-    q_raw = q_ref[0, 0]
-    q = (q_raw.astype(jnp.float32) * scale).astype(q_raw.dtype)  # [BQ, D]
+    q = _prescale(q_ref[0, 0], scale)                     # [BQ, D]
     bq, d = q.shape
-    m = jnp.full((bq, 1), NEG_INF, jnp.float32)
-    l = jnp.zeros((bq, 1), jnp.float32)
-    acc = jnp.zeros((bq, d), jnp.float32)
-
+    m = jnp.full((1, bq), NEG_INF, jnp.float32)
+    l = jnp.zeros((1, bq), jnp.float32)
+    acc = jnp.zeros((d, bq), jnp.float32)                 # out^T
     num_kv = seq_k // block_k
-    if causal and aligned:
-        # only blocks overlapping the causal triangle of this Q block
-        num_kv = jnp.minimum(num_kv,
-                             pl.cdiv((qi + 1) * block_q, block_k))
 
-    def body(j, carry):
+    def body(j, carry, mask):
         m, l, acc = carry
-        k = k_ref[0, 0, pl.ds(j * block_k, block_k), :]   # [BK, D]
-        v = v_ref[0, 0, pl.ds(j * block_k, block_k), :]
-        s = _dot(q, k, ((1,), (1,)))                      # [BQ, BK] f32
-        s = _mask_scores(s, causal, qi, j, q_off_ref, k_off_ref, block_q,
-                         block_k, bq)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        k = _rows(k_ref, j, block_k)                      # [BK, D]
+        v = _rows(v_ref, j, block_k)
+        s = _scores(k, q, mask, qi, j, q_off_ref, k_off_ref, block_q,
+                    block_k)                              # [BK, BQ]
+        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_new)
-        # fully-masked rows: all s == NEG_INF makes s - m_new == 0; zero
-        # those probabilities instead of attending uniformly
-        p = jnp.where(s > 0.5 * NEG_INF, p, 0.0)
+        if mask == "positions":
+            # a query the offsets hide entirely has s - m_new == 0: zero
+            # it instead of attending uniformly.  The aligned path needs
+            # no guard: key 0 is visible to every query and is in the
+            # first block, so m is finite before any masked score
+            p = jnp.where(s > 0.5 * NEG_INF, p, 0.0)
         alpha = jnp.exp(m - m_new)
         # denominator uses the UNdropped sum; only the value aggregation
         # sees the dropout mask (== dropout on normalized weights)
-        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
         u = _apply_dropout(p, seed_ref, qi, j, dropout_p)
-        acc = acc * alpha + _dot(u.astype(v.dtype), v, ((1,), (0,)))
+        acc = acc * alpha + _dot(v, u.astype(v.dtype), ((0,), (0,)))
         return m_new, l, acc
 
-    m, l, acc = jax.lax.fori_loop(0, num_kv, body, (m, l, acc))
+    full, end = _kv_bounds(qi, block_q, block_k, num_kv)
+    m, l, acc = _block_loops(
+        body, (m, l, acc), num_kv, causal, aligned,
+        ((0, full, None), (full, end, "diagonal")))
     l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0, 0] = (acc / l_safe).astype(o_ref.dtype)
+    o_ref[0, 0] = (acc / l_safe).T.astype(o_ref.dtype)
     # lse block is (8, bq): positions on the LANE dim, replicated over 8
     # sublanes — the minimal Mosaic-legal tile.  A trailing unit dim
     # ([..., Lq, 1]) would make XLA tile-pad the HBM buffer 1 -> 128
     # lanes (128x memory — measured ~200 MB/layer residual at BERT-base
-    # scale); the (bq, 1) -> (1, bq) relayout is a few hundred f32/block
+    # scale)
     lse = jnp.where(l > 0, m + jnp.log(l_safe), NEG_INF)
-    lse_ref[0, 0] = jnp.broadcast_to(lse.reshape(1, -1), (8, lse.shape[0]))
+    lse_ref[0, 0] = jnp.broadcast_to(lse, (8, bq))
 
 
 def _qkv_fwd_specs(block_q, Lk, D):
@@ -186,18 +314,19 @@ def _qkv_fwd_specs(block_q, Lk, D):
     ]
 
 
-def _fwd(q, k, v, q_off, k_off, seed, scale, causal, block_q, block_k,
-         aligned, dropout_p=0.0):
+def _fwd(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
+         dropout_p=0.0):
     """q/k/v: [B, H, L, D] → (out [B,H,Lq,D], lse [B,H,Lq])."""
     B, H, Lq, D = q.shape
     Lk = k.shape[2]
-    grid = (B, H, Lq // block_q)
+    block_q, block_k = blocks
+    _count_blocks(Lq, Lk, block_q, block_k, causal, aligned)
     kernel = functools.partial(_fwd_kernel, scale=scale, block_k=block_k,
                                seq_k=Lk, causal=causal, block_q=block_q,
                                aligned=aligned, dropout_p=dropout_p)
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(B, H, Lq // block_q),
         in_specs=_qkv_fwd_specs(block_q, Lk, D),
         out_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
@@ -219,39 +348,47 @@ def _fwd(q, k, v, q_off, k_off, seed, scale, causal, block_q, block_k,
 # backward (recompute-based, FlashAttention-2 style)
 # ---------------------------------------------------------------------------
 
+def _p_ds(s, mask, lse, do, v, delta, seed_ref, qi, j, dropout_p):
+    """(u, dS / scale) of one [BK, BQ] block from its scores.  With
+    dropout off u is p and dS = p * (dP - delta); with it on,
+    dS = u * dP - p * delta (the denominator is undropped, see
+    _apply_dropout)."""
+    p = jnp.exp(s - lse)
+    if mask == "positions":
+        p = jnp.where(s > 0.5 * NEG_INF, p, 0.0)
+    dp = _dot(v, do, ((1,), (1,)))                        # [BK, BQ]
+    if dropout_p <= 0.0:
+        return p, p * (dp - delta)
+    u = _apply_dropout(p, seed_ref, qi, j, dropout_p)
+    return u, u * dp - p * delta
+
+
 def _bwd_dq_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
                    do_ref, lse_ref, delta_ref, dq_ref, *, scale, block_k,
                    seq_k, causal, block_q, aligned, dropout_p):
     qi = pl.program_id(2)
-    q = q_ref[0, 0]                                       # [BQ, D]
+    q = _prescale(q_ref[0, 0], scale)                     # [BQ, D]
     do = do_ref[0, 0]
-    lse = lse_ref[0, 0][0:1, :].reshape(-1, 1)            # [BQ, 1]
-    delta = delta_ref[0, 0][0:1, :].reshape(-1, 1)
+    lse = lse_ref[0, 0][0:1, :]                           # [1, BQ]
+    delta = delta_ref[0, 0][0:1, :]
     bq, d = q.shape
-    dq = jnp.zeros((bq, d), jnp.float32)
-
+    dq = jnp.zeros((d, bq), jnp.float32)                  # dq^T
     num_kv = seq_k // block_k
-    if causal and aligned:
-        num_kv = jnp.minimum(num_kv,
-                             pl.cdiv((qi + 1) * block_q, block_k))
 
-    def body(j, dq):
-        k = k_ref[0, 0, pl.ds(j * block_k, block_k), :]
-        v = v_ref[0, 0, pl.ds(j * block_k, block_k), :]
-        s = _dot(q, k, ((1,), (1,))) * scale
-        s = _mask_scores(s, causal, qi, j, q_off_ref, k_off_ref, block_q,
-                         block_k, bq)
-        p = jnp.exp(s - lse)                              # [BQ, BK]
-        p = jnp.where(s > 0.5 * NEG_INF, p, 0.0)
-        u = _apply_dropout(p, seed_ref, qi, j, dropout_p)
-        dp = _dot(do, v, ((1,), (1,)))
-        # d s = p_norm * (keep_scale * dP - delta)  (see derivation in
-        # _apply_dropout: the denominator is undropped)
-        ds = (u * dp - p * delta) * scale
-        return dq + _dot(ds.astype(k.dtype), k, ((1,), (0,)))
+    def body(j, dq, mask):
+        k = _rows(k_ref, j, block_k)
+        v = _rows(v_ref, j, block_k)
+        s = _scores(k, q, mask, qi, j, q_off_ref, k_off_ref, block_q,
+                    block_k)
+        _, ds = _p_ds(s, mask, lse, do, v, delta, seed_ref, qi, j,
+                      dropout_p)
+        return dq + _dot(k, ds.astype(k.dtype), ((0,), (0,)))
 
-    dq = jax.lax.fori_loop(0, num_kv, body, dq)
-    dq_ref[0, 0] = dq.astype(dq_ref.dtype)
+    full, end = _kv_bounds(qi, block_q, block_k, num_kv)
+    dq = _block_loops(body, dq, num_kv, causal, aligned,
+                      ((0, full, None), (full, end, "diagonal")))
+    # s was taken against scale * q: the chain rule's scale, once
+    dq_ref[0, 0] = (dq * scale).T.astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
@@ -263,53 +400,40 @@ def _bwd_dkv_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
     bk, d = k.shape
     dk = jnp.zeros((bk, d), jnp.float32)
     dv = jnp.zeros((bk, d), jnp.float32)
-
     num_q = seq_q // block_q
-    start = (kj * block_k) // block_q if (causal and aligned) else 0
 
-    def body(i, carry):
+    def body(i, carry, mask):
         dk, dv = carry
-        q = q_ref[0, 0, pl.ds(i * block_q, block_q), :]
-        do = do_ref[0, 0, pl.ds(i * block_q, block_q), :]
-        lse = lse_ref[0, 0, 0:1,
-                      pl.ds(i * block_q, block_q)].reshape(-1, 1)
-        delta = delta_ref[0, 0, 0:1,
-                          pl.ds(i * block_q, block_q)].reshape(-1, 1)
-        s = _dot(q, k, ((1,), (1,))) * scale
-        # rows are q positions (loop index i), cols are this k block (kj)
-        s = _mask_scores(s, causal, i, kj, q_off_ref, k_off_ref, block_q,
-                         block_k, block_q)
-        p = jnp.exp(s - lse)                              # [BQ, BK]
-        p = jnp.where(s > 0.5 * NEG_INF, p, 0.0)
+        q = _prescale(_rows(q_ref, i, block_q), scale)    # [BQ, D]
+        do = _rows(do_ref, i, block_q)
+        cols = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        lse = lse_ref[0, 0, 0:1, cols]                    # [1, BQ]
+        delta = delta_ref[0, 0, 0:1, cols]
+        s = _scores(k, q, mask, i, kj, q_off_ref, k_off_ref, block_q,
+                    block_k)
         # fwd tile (qi=i, j=kj): identical seed -> identical mask
-        u = _apply_dropout(p, seed_ref, i, kj, dropout_p)
-        dv = dv + _dot(u.astype(do.dtype), do, ((0,), (0,)))
-        dp = _dot(do, v, ((1,), (1,)))
-        ds = (u * dp - p * delta) * scale                 # [BQ, BK]
-        dk = dk + _dot(ds.astype(q.dtype), q, ((0,), (0,)))
+        u, ds = _p_ds(s, mask, lse, do, v, delta, seed_ref, i, kj,
+                      dropout_p)
+        dv = dv + _dot(u.astype(do.dtype), do, ((1,), (0,)))
+        # against the pre-scaled q: dk needs no scale of its own
+        dk = dk + _dot(ds.astype(q.dtype), q, ((1,), (0,)))
         return dk, dv
 
-    dk, dv = jax.lax.fori_loop(start, num_q, body, (dk, dv))
+    start, full = _q_bounds(kj, block_q, block_k, num_q)
+    dk, dv = _block_loops(
+        body, (dk, dv), num_q, causal, aligned,
+        ((start, full, "diagonal"), (full, num_q, None)))
     dk_ref[0, 0] = dk.astype(dk_ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
 
-def _bwd(q, k, v, q_off, k_off, seed, out, lse, do, dlse, scale, causal,
-         block_q, block_k, aligned, dropout_p=0.0):
-    """Full backward.  The lse cotangent folds into delta: with
-    P = exp(S - lse) row-normalized, dS = P * (dP_rows - delta + dlse)
-    since d lse / dS = P."""
+def _bwd_dq(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale, causal,
+            blocks, aligned, dropout_p):
     B, H, Lq, D = q.shape
     Lk = k.shape[2]
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)                              # [B, H, Lq]
-    if dlse is not None:
-        delta = delta - dlse.astype(jnp.float32)
-    # 8-sublane replication at the kernel boundary (see _fwd_kernel note)
-    lse8 = jnp.broadcast_to(lse[:, :, None, :], (B, H, 8, Lq))
-    delta8 = jnp.broadcast_to(delta[:, :, None, :], (B, H, 8, Lq))
-
-    dq = pl.pallas_call(
+    block_q, block_k = blocks
+    _count_blocks(Lq, Lk, block_q, block_k, causal, aligned)
+    return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, block_k=block_k,
                           seq_k=Lk, causal=causal, block_q=block_q,
                           aligned=aligned, dropout_p=dropout_p),
@@ -326,7 +450,14 @@ def _bwd(q, k, v, q_off, k_off, seed, out, lse, do, dlse, scale, causal,
         name=scopes.FLASH_BWD_DQ,
     )(q_off, k_off, seed, q, k, v, do, lse8, delta8)
 
-    dk, dv = pl.pallas_call(
+
+def _bwd_dkv(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale, causal,
+             blocks, aligned, dropout_p):
+    B, H, Lq, D = q.shape
+    Lk = k.shape[2]
+    block_q, block_k = blocks
+    _count_blocks(Lq, Lk, block_q, block_k, causal, aligned)
+    return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
                           seq_q=Lq, causal=causal, block_k=block_k,
                           aligned=aligned, dropout_p=dropout_p),
@@ -353,6 +484,25 @@ def _bwd(q, k, v, q_off, k_off, seed, out, lse, do, dlse, scale, causal,
         interpret=_interpret(),
         name=scopes.FLASH_BWD_DKV,
     )(q_off, k_off, seed, q, k, v, do, lse8, delta8)
+
+
+def _bwd(q, k, v, q_off, k_off, seed, out, lse, do, dlse, scale, causal,
+         blocks, aligned, dropout_p=0.0):
+    """Full backward.  The lse cotangent folds into delta: with
+    P = exp(S - lse) row-normalized, dS = P * (dP_rows - delta + dlse)
+    since d lse / dS = P."""
+    B, H, Lq, _ = q.shape
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)                              # [B, H, Lq]
+    if dlse is not None:
+        delta = delta - dlse.astype(jnp.float32)
+    # 8-sublane replication at the kernel boundary (see _fwd_kernel note)
+    lse8 = jnp.broadcast_to(lse[:, :, None, :], (B, H, 8, Lq))
+    delta8 = jnp.broadcast_to(delta[:, :, None, :], (B, H, 8, Lq))
+    args = (q, k, v, q_off, k_off, seed, do, lse8, delta8, scale, causal,
+            blocks, aligned, dropout_p)
+    dq = _bwd_dq(*args)
+    dk, dv = _bwd_dkv(*args)
     return dq, dk, dv
 
 
@@ -360,26 +510,25 @@ def _bwd(q, k, v, q_off, k_off, seed, out, lse, do, dlse, scale, causal,
 # custom-vjp cores over [B, H, L, D]
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
-def _flash(q, k, v, q_off, k_off, seed, scale, causal, block_q, block_k,
-           aligned, dropout_p):
-    out, _ = _fwd(q, k, v, q_off, k_off, seed, scale, causal, block_q,
-                  block_k, aligned, dropout_p)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+def _flash(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
+           dropout_p):
+    out, _ = _fwd(q, k, v, q_off, k_off, seed, scale, causal, blocks,
+                  aligned, dropout_p)
     return out
 
 
-def _flash_fwd(q, k, v, q_off, k_off, seed, scale, causal, block_q,
-               block_k, aligned, dropout_p):
-    out, lse = _fwd(q, k, v, q_off, k_off, seed, scale, causal, block_q,
-                    block_k, aligned, dropout_p)
+def _flash_fwd(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
+               dropout_p):
+    out, lse = _fwd(q, k, v, q_off, k_off, seed, scale, causal, blocks,
+                    aligned, dropout_p)
     return out, (q, k, v, q_off, k_off, seed, out, lse)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, aligned, dropout_p, res,
-               do):
+def _flash_bwd(scale, causal, blocks, aligned, dropout_p, res, do):
     q, k, v, q_off, k_off, seed, out, lse = res
     dq, dk, dv = _bwd(q, k, v, q_off, k_off, seed, out, lse, do, None,
-                      scale, causal, block_q, block_k, aligned, dropout_p)
+                      scale, causal, blocks, aligned, dropout_p)
     return (dq, dk, dv, jnp.zeros_like(q_off), jnp.zeros_like(k_off),
             None)
 
@@ -387,26 +536,26 @@ def _flash_bwd(scale, causal, block_q, block_k, aligned, dropout_p, res,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _flash_with_lse(q, k, v, q_off, k_off, scale, block_q, block_k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _flash_with_lse(q, k, v, q_off, k_off, scale, blocks):
     """Position-masked attention returning (out, lse) — the ring-attention
     building block (both outputs differentiable; no dropout: ring rounds
     merge via logsumexp, which requires undropped weights)."""
-    return _fwd(q, k, v, q_off, k_off, _zero_seed(), scale, True, block_q,
-                block_k, False)
+    return _fwd(q, k, v, q_off, k_off, _zero_seed(), scale, True, blocks,
+                False)
 
 
-def _flash_with_lse_fwd(q, k, v, q_off, k_off, scale, block_q, block_k):
+def _flash_with_lse_fwd(q, k, v, q_off, k_off, scale, blocks):
     out, lse = _fwd(q, k, v, q_off, k_off, _zero_seed(), scale, True,
-                    block_q, block_k, False)
+                    blocks, False)
     return (out, lse), (q, k, v, q_off, k_off, out, lse)
 
 
-def _flash_with_lse_bwd(scale, block_q, block_k, res, cts):
+def _flash_with_lse_bwd(scale, blocks, res, cts):
     q, k, v, q_off, k_off, out, lse = res
     do, dlse = cts
     dq, dk, dv = _bwd(q, k, v, q_off, k_off, _zero_seed(), out, lse, do,
-                      dlse, scale, True, block_q, block_k, False)
+                      dlse, scale, True, blocks, False)
     return dq, dk, dv, jnp.zeros_like(q_off), jnp.zeros_like(k_off)
 
 
@@ -426,9 +575,12 @@ def _zero_seed():
 
 
 def flash_attention(q, k, v, causal: bool = False, scale=None,
-                    block_q: int = 512, block_k: int = 512,
+                    block_q: int | None = None, block_k: int | None = None,
                     dropout_p: float = 0.0, seed=None):
     """q/k/v: [B, L, H, D] arrays → [B, Lq, H, D] attention output.
+
+    ``block_q`` / ``block_k`` left at None are chosen from the static
+    shapes (`_resolve_blocks`).
 
     ``dropout_p > 0`` applies attention-probability dropout IN-KERNEL
     (Pallas TPU PRNG, tile-seeded from ``seed`` so the backward
@@ -436,8 +588,7 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     ([1, 1]) per training step."""
     D = q.shape[-1]
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(D)
-    block_q = min(block_q, q.shape[1])
-    block_k = min(block_k, k.shape[1])
+    blocks = _resolve_blocks(block_q, block_k, q.shape[1], k.shape[1])
     if dropout_p > 0.0 and _interpret():
         raise NotImplementedError(
             "flash_attention dropout needs the Pallas TPU PRNG (real TPU "
@@ -451,21 +602,20 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
     out = _flash(qt, kt, vt, _zero_off(), _zero_off(), seed, scale,
-                 bool(causal), block_q, block_k, True,
-                 float(dropout_p))
+                 bool(causal), blocks, True, float(dropout_p))
     return jnp.swapaxes(out, 1, 2)
 
 
 def flash_attention_block(q_bhld, k_bhld, v_bhld, q_off, k_off, scale,
-                          block_q: int = 512, block_k: int = 512):
+                          block_q: int = _BLOCK, block_k: int = _BLOCK):
     """Ring-attention building block: [B, H, L, D] layout, traced global
     position offsets (float32 [1,1] arrays), always position-masked.
     Returns (out normalized [B,H,L,D], lse [B,H,L]); fully-masked rows
     give out=0, lse≈-inf — ready for logsumexp merging across rounds."""
-    block_q = min(block_q, q_bhld.shape[2])
-    block_k = min(block_k, k_bhld.shape[2])
+    blocks = _resolve_blocks(block_q, block_k, q_bhld.shape[2],
+                             k_bhld.shape[2])
     return _flash_with_lse(q_bhld, k_bhld, v_bhld, q_off, k_off, scale,
-                           block_q, block_k)
+                           blocks)
 
 
 def mha_reference(q, k, v, causal=False, scale=None):

@@ -51,7 +51,8 @@ def _compile(fn, *args):
 @pytest.mark.parametrize("shape,causal", [
     ((4, 512, 12, 64), False),      # BERT-base
     ((4, 1024, 16, 96), True),      # GPT-760M
-], ids=["bert", "gpt"])
+    ((8, 2048, 16, 96), True),      # the gpt3_large.train_bf16_b8_s2048 cell
+], ids=["bert", "gpt", "gpt_cell"])
 def test_flash_attention_fwd_bwd(one_chip, monkeypatch, shape, causal,
                                  dropout):
     # the package re-exports a function under the module's name

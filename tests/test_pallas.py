@@ -60,6 +60,97 @@ def test_flash_grad_parity(causal):
                                    rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("L,block_q,block_k", [
+    (256, 64, 64),      # full loop, diagonal loop and their boundary
+    (256, 128, 32),     # a q block's diagonal spans several k blocks
+    (256, 32, 128),     # several q blocks share one diagonal k block
+    (128, 128, 128),    # one block: the diagonal loop alone
+])
+def test_flash_causal_block_schedule_parity(L, block_q, block_k, dtype,
+                                            tol):
+    """Forward and gradients of the aligned causal schedule (unmasked
+    blocks below the diagonal, masked blocks on it) against the oracle,
+    with block_q != block_k too."""
+    r = np.random.RandomState(7)
+    q, k, v = (jnp.asarray(r.randn(2, L, 2, 32), dtype) for _ in range(3))
+
+    def sq(fn):
+        return lambda q, k, v: jnp.sum(
+            fn(q, k, v).astype(jnp.float32) ** 2)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=block_q,
+                               block_k=block_k)
+
+    def oracle(q, k, v):
+        return mha_reference(q, k, v, causal=True)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v), np.float32),
+                               np.asarray(oracle(q, k, v), np.float32),
+                               atol=tol)
+    got = jax.grad(sq(flash), (0, 1, 2))(q, k, v)
+    ref = jax.grad(sq(oracle), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, ref):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= tol * max(1.0, np.abs(b).max())
+
+
+@pytest.mark.parametrize("L,D", [(512, 64), (1024, 96), (2048, 96)],
+                         ids=["bert", "gpt1024", "gpt2048"])
+def test_flash_chosen_blocks_tile_the_sequence(L, D):
+    from paddle_tpu.ops.pallas.flash_attention import _resolve_blocks
+    block_q, block_k = _resolve_blocks(None, None, L, L)
+    assert L % block_q == 0 and L % block_k == 0
+    assert block_q % 128 == 0 and block_k % 128 == 0
+    shape = (4, L, 12, D)
+    old = get_flag("pallas_attention_min_seqlen")
+    set_flags({"pallas_attention_min_seqlen": 512})  # admit BERT's 512
+    try:
+        assert flash_attention_supported(shape, shape, jnp.bfloat16)
+        # what is chosen is what an explicit caller would have to pass
+        assert flash_attention_supported(shape, shape, jnp.bfloat16,
+                                         block_q=block_q, block_k=block_k)
+    finally:
+        set_flags({"pallas_attention_min_seqlen": old})
+
+
+def test_flash_block_counters():
+    """pallas.flash.blocks_full / blocks_masked: block iterations per
+    (batch, head) of each kernel traced, by whether they run a mask."""
+    from paddle_tpu.utils import monitor
+
+    def counts(fn, *args):
+        before = monitor.all_stats()
+        fn(*args)
+        after = monitor.all_stats()
+        return tuple(after.get(n, 0) - before.get(n, 0)
+                     for n in ("pallas.flash.blocks_full",
+                               "pallas.flash.blocks_masked"))
+
+    r = np.random.RandomState(8)
+    q, k, v = (jnp.asarray(r.randn(1, 256, 1, 16), jnp.float32)
+               for _ in range(3))
+
+    def fwd(causal):
+        return lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, block_q=64, block_k=64)
+
+    # 4 x 4 blocks: 6 below the diagonal, 4 on it, 6 skipped
+    assert counts(fwd(True), q, k, v) == (6, 4)
+    assert counts(fwd(False), q, k, v) == (16, 0)
+    # differentiated: forward, dq and dkv kernels, the same pairs each
+    grad = jax.grad(lambda q, k, v: jnp.sum(fwd(True)(q, k, v)), (0, 1, 2))
+    assert counts(grad, q, k, v) == (18, 12)
+    # the cell's shape under the chosen blocks (PERF.md): 2048 / 512
+    from paddle_tpu.ops.pallas.flash_attention import _count_blocks
+    assert counts(_count_blocks, 2048, 2048, 512, 512, True, True) == (6, 4)
+    # the ring path masks every block
+    assert counts(_count_blocks, 256, 256, 64, 64, True, False) == (0, 16)
+
+
 def test_flash_cross_attention_shapes():
     r = np.random.RandomState(2)
     q = jnp.asarray(r.randn(2, 64, 2, 16), jnp.float32)
