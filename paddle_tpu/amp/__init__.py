@@ -26,7 +26,7 @@ from ..core.tensor import Tensor
 WHITE_LIST = {
     "matmul", "mm", "bmm", "linear", "conv1d", "conv2d", "conv3d",
     "conv2d_transpose", "einsum", "scaled_dot_product_attention",
-    "flash_attention",
+    "flash_attention", "eva_attention",
 }
 BLACK_LIST = {
     "exp", "log", "square", "mean", "sum", "softmax", "log_softmax",
@@ -34,6 +34,7 @@ BLACK_LIST = {
     "layer_norm", "batch_norm", "group_norm", "instance_norm", "norm",
     "logsumexp", "softmax_with_cross_entropy", "cosine_similarity",
     "kl_div", "sigmoid_focal_loss", "erf", "erfinv", "pow", "cumsum",
+    "rms_norm",
 }
 
 _tls = threading.local()

@@ -23,7 +23,7 @@ from .layer.loss import (BCELoss, BCEWithLogitsLoss, CosineEmbeddingLoss,  # noq
 from .layer.norm import (BatchNorm, BatchNorm1D, BatchNorm2D, BatchNorm3D,  # noqa
                          GroupNorm, InstanceNorm1D, InstanceNorm2D,
                          InstanceNorm3D, LayerNorm, LocalResponseNorm,
-                         SpectralNorm, SyncBatchNorm)
+                         RMSNorm, SpectralNorm, SyncBatchNorm)
 from .layer.pooling import (AdaptiveAvgPool1D, AdaptiveAvgPool2D,  # noqa
                             AdaptiveMaxPool2D, AvgPool1D, AvgPool2D,
                             AvgPool3D, MaxPool1D, MaxPool2D, MaxPool3D)

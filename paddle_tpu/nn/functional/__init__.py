@@ -1614,6 +1614,80 @@ def np_asarray(x):
     return _np.asarray(x.data if hasattr(x, "data") else x)
 
 
+# ---------------------------------------------------------------------------
+# RMSNorm, rotary positions, EVA attention (appended: the functions above
+# keep their source lines, which the compile cache's key holds)
+# ---------------------------------------------------------------------------
+
+@jax.named_scope(scopes.RMS_NORM)
+def rms_norm(x, weight=None, epsilon=1e-6, unit_offset=False, name=None):
+    """``x / sqrt(mean(x^2) + epsilon) * weight`` over the last axis
+    (Zhang & Sennrich 2019); ``unit_offset`` applies the weight as
+    ``1 + weight``.  The mean of squares is taken in float32 whatever the
+    input's type; the result has the weight's type (the input's without
+    one), so that bfloat16 weights hand bfloat16 to the matmul that
+    follows a float32 residual stream."""
+    def _rms(a, *w):
+        out = a.astype(jnp.float32)
+        out = out * jax.lax.rsqrt(
+            jnp.mean(jnp.square(out), axis=-1, keepdims=True) + epsilon)
+        if not w:
+            return out.astype(a.dtype)
+        g = w[0].astype(jnp.float32)
+        return (out * (1.0 + g if unit_offset else g)).astype(w[0].dtype)
+
+    args = [x] if weight is None else [x, weight]
+    return apply(_rms, *args, op_name="rms_norm")
+
+
+@jax.named_scope(scopes.ROPE)
+def rotary_embedding(x, theta=10000.0, position_ids=None, name=None):
+    """Rotary position embedding (Su et al. 2021), rotate-half pairing:
+    ``x`` [B, S, H, D]; the pair (i, i + D/2) of the vector at position p
+    turns by ``p * theta^(-2i/D)``.  ``position_ids`` [S] or [B, S]
+    defaults to 0..S-1.  Angles, cos and sin in float32; the result has
+    the input's type."""
+    def _rope(a, *pos):
+        D = a.shape[-1]
+        p = (pos[0] if pos else jnp.arange(a.shape[1])).astype(jnp.float32)
+        freq = float(theta) ** (
+            -jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+        ang = p[..., None] * freq                         # [(B,) S, D/2]
+        cos = jnp.cos(ang)[..., None, :]
+        sin = jnp.sin(ang)[..., None, :]
+        a1 = a[..., :D // 2].astype(jnp.float32)
+        a2 = a[..., D // 2:].astype(jnp.float32)
+        return jnp.concatenate([a1 * cos - a2 * sin, a2 * cos + a1 * sin],
+                               axis=-1).astype(a.dtype)
+
+    args = [x] if position_ids is None else [x, position_ids]
+    return apply(_rope, *args, op_name="rotary_embedding")
+
+
+@jax.named_scope(scopes.EVA_ATTENTION)
+def eva_attention(query, key, value, mu, phi, window_size, chunk_size,
+                  scale=None, name=None):
+    """EVA attention (Zheng et al. 2023) in EvaByte's deterministic
+    chunked form, [B, S, H, D] -> [B, S, H, D]: under one softmax, a query
+    sees the exact keys of its own window of ``window_size`` up to itself
+    and, for every ``chunk_size`` chunk of every earlier window, one
+    summary pair pooled by a softmax inside the chunk against the per-head
+    vectors ``mu`` / ``phi`` [H, D].  A row no longer than one window is
+    causal attention.  The Pallas kernels (ops/pallas/eva_attention.py)
+    run where the kernel tier is on and the shapes allow, the windowed XLA
+    path elsewhere."""
+    from ...ops.pallas import eva_attention as _eva
+    from ...ops.pallas.support import tier_enabled
+    dtype = as_array(query).dtype
+    use_kernels = tier_enabled() and _eva.eva_attention_supported(
+        tuple(query.shape), dtype, window_size, chunk_size)
+    fn = _eva.eva_attention if use_kernels else _eva.eva_attention_xla
+    return apply(
+        lambda q, k, v, m, f: fn(q, k, v, m, f, window_size, chunk_size,
+                                 scale),
+        query, key, value, mu, phi, op_name="eva_attention")
+
+
 from ..decode import gather_tree  # noqa: F401,E402
 
 from . import activation, common, conv, extension, loss, pooling  # noqa

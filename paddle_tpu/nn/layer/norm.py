@@ -236,3 +236,27 @@ class SpectralNorm(Layer):
             return w / sigma
         return apply(_sn, weight, self.weight_u, self.weight_v,
                      op_name="spectral_norm")
+
+
+class RMSNorm(Layer):
+    """Root-mean-square norm over the last axis (Zhang & Sennrich 2019):
+    ``x / sqrt(mean(x^2) + epsilon) * weight``.  ``unit_offset`` stores the
+    gain as an offset from one (initialised 0, applied as ``1 + weight``),
+    as EvaByte's ``norm_add_unit_offset`` does."""
+
+    def __init__(self, hidden_size, epsilon=1e-6, unit_offset=False,
+                 weight_attr=None, name=None):
+        super().__init__()
+        self._hidden_size = int(hidden_size)
+        self._epsilon = epsilon
+        self._unit_offset = bool(unit_offset)
+        self.weight = self.create_parameter(
+            [self._hidden_size], attr=weight_attr,
+            default_initializer=I.Constant(0.0 if unit_offset else 1.0))
+
+    def forward(self, x):
+        return F.rms_norm(x, self.weight, self._epsilon, self._unit_offset)
+
+    def extra_repr(self):
+        return (f"hidden_size={self._hidden_size}, epsilon={self._epsilon}, "
+                f"unit_offset={self._unit_offset}")

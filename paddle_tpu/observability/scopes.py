@@ -34,8 +34,12 @@ GELU = "gelu"
 LAYER_NORM = "layer_norm"
 EMBEDDING = "embedding"
 DROPOUT = "dropout"
+EVA_ATTENTION = "eva_attention"
+EVA_POOL = "eva_pool"         # chunk summaries, inside eva_attention
+RMS_NORM = "rms_norm"
+ROPE = "rope"
 FUNCTIONALS = (ATTENTION, LINEAR_CROSS_ENTROPY, GELU, LAYER_NORM, EMBEDDING,
-               DROPOUT)
+               DROPOUT, EVA_ATTENTION, EVA_POOL, RMS_NORM, ROPE)
 
 # -- Pallas kernels ----------------------------------------------------------
 FLASH_FWD = "flash_fwd"
@@ -46,7 +50,9 @@ EPILOGUE_BWD = "epilogue_bwd"
 FUSED_ADAM = "fused_adam"
 PAGED_ATTENTION = "paged_attention"
 COLLECTIVE_MATMUL_CHUNK = "collective_matmul_chunk"
+EVA_FWD = "eva_fwd"
+EVA_BWD_DQ = "eva_bwd_dq"     # dq, and the summaries' dk~ / dv~
 KERNELS = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV, EPILOGUE_FWD,
            EPILOGUE_BWD, FUSED_ADAM, PAGED_ATTENTION,
-           COLLECTIVE_MATMUL_CHUNK)
+           COLLECTIVE_MATMUL_CHUNK, EVA_FWD, EVA_BWD_DQ)
 

@@ -13,6 +13,9 @@ interpret-mode path so the same code runs (slowly) on CPU in tests.
 The tier:
 
 - ``flash_attention``        — online-softmax attention, fwd + bwd;
+- ``eva_attention`` (module) — EVA's windowed attention over exact keys
+  and chunk summaries under one softmax, fwd + bwd, behind
+  ``F.eva_attention``;
 - ``fused_linear_epilogue``  — matmul + bias/gelu/relu/residual/
   layer_norm epilogues off the cost model's ranked fusion candidates
   (selected by the static Executor's fusion pass);

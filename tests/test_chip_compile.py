@@ -14,6 +14,7 @@ xdist every worker imports this file.  Compiles happen in the test's
 own process; keep all of them in this one file.
 """
 import importlib
+import math
 import os
 
 import jax
@@ -72,6 +73,99 @@ def test_flash_attention_fwd_bwd(one_chip, monkeypatch, shape, causal,
     qkv = _sds(one_chip, shape, jnp.bfloat16)
     _compile(jax.value_and_grad(loss, (0, 1, 2)), qkv, qkv, qkv,
              _sds(one_chip, (1, 1), jnp.int32))
+
+
+# ------------------------------------------------------------------ EVA --
+def test_eva_attention_fwd_bwd(one_chip, monkeypatch):
+    """The evabyte.train_bf16_b1_s8192 cell's attention: four windows of
+    2048, chunks of 16, 32 heads of 128."""
+    eva = importlib.import_module("paddle_tpu.ops.pallas.eva_attention")
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(eva, "_interpret", lambda: False)
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    shape = (1, 8192, 32, 128)
+    assert eva.eva_attention_supported(shape, jnp.bfloat16, 2048, 16)
+    # summary blocks of 8 rows do not fill a bfloat16 tile
+    assert not eva.eva_attention_supported(shape, jnp.bfloat16, 2048, 256)
+    assert not eva.eva_attention_supported((1, 8192, 32, 64), jnp.bfloat16,
+                                           2048, 16)
+
+    def loss(q, k, v, mu, phi):
+        out = eva.eva_attention(q, k, v, mu, phi, 2048, 16)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    qkv = _sds(one_chip, shape, jnp.bfloat16)
+    vec = _sds(one_chip, (32, 128), jnp.bfloat16)
+    compiled = _compile(jax.value_and_grad(loss, (0, 1, 2, 3, 4)),
+                        qkv, qkv, qkv, vec, vec)
+    # eva_fwd, eva_bwd_dq and the flash dk/dv kernel over folded windows
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_evabyte_cell_step_fits_the_chip(one_chip, monkeypatch):
+    """The cell's whole step as the train_step runner builds it (model ->
+    amp O2 -> AdamW with clip -> TrainStep(donate=True)), at the
+    published widths, for the described v5e: it compiles, holds the
+    kernels, and its footprint (as benchmark/run.py counts it) is under
+    the chip's 15.75 GiB."""
+    import json
+    import os
+    import sys
+    import paddle_tpu as paddle
+    from paddle_tpu import amp, nn, optimizer
+    from paddle_tpu.jit import TrainStep
+    from paddle_tpu.optimizer.clip import ClipGradByGlobalNorm
+    from paddle_tpu.ops.pallas import support
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, bench)
+    import run as harness
+    cell, cfg, mix, model_mod, _, _ = harness.load_parts(
+        "evabyte.train_bf16_b1_s8192")
+    assert cfg["hidden_size"] == 4096 and mix["seq"] == 8192
+    # the code under the jit asks jax for the backend; here that is the CPU
+    monkeypatch.setattr(support, "interpret_mode", lambda: False)
+    for name in ("eva_attention", "flash_attention"):
+        mod = importlib.import_module("paddle_tpu.ops.pallas." + name)
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+    monkeypatch.setattr(support, "tier_enabled", lambda: True)
+    # no seeded weights are needed to compile: 821 M zeros are quick
+    monkeypatch.setattr(nn.initializer.XavierNormal, "__call__",
+                        lambda self, shape, dtype="float32":
+                        jnp.zeros(shape, dtype))
+    model, loss_fn = model_mod.build(cfg, cell["model_args"])
+    o = cell["optimizer"]
+    opt = optimizer.AdamW(
+        learning_rate=o["lr"], beta1=o["beta1"], beta2=o["beta2"],
+        epsilon=o["eps"], parameters=model.parameters(),
+        weight_decay=o["weight_decay"],
+        grad_clip=ClipGradByGlobalNorm(o["clip_global_norm"]),
+        multi_precision=True)
+    model, opt = amp.decorate(model, opt, level="O2", dtype=cell["dtype"])
+    step = TrainStep(model, loss_fn, opt, n_inputs=1, donate=True)
+
+    def sds(a):
+        return _sds(one_chip, a.shape, a.dtype)
+
+    params = tuple(sds(p.data) for p in step._params)
+    n = sum(math.prod(p.shape) for p in params)
+    assert 821e6 < n < 822e6
+    opt_state = jax.tree.map(sds, jax.eval_shape(
+        opt.functional_init, list(params)))
+    ids = _sds(one_chip, (mix["batch"], mix["seq"]), jnp.int32)
+    compiled = step._build(True).lower(
+        params, (), opt_state, jax.tree.map(sds, step._init_scaler_state()),
+        _sds(one_chip, (), jnp.float32), (ids,), (ids,)).compile()
+    text = compiled.as_text()
+    for kernel in ("eva_fwd", "eva_bwd_dq", "flash_bwd_dkv"):
+        assert kernel in text, kernel
+    m = compiled.memory_analysis()
+    footprint = (m.argument_size_in_bytes + m.output_size_in_bytes
+                 - m.alias_size_in_bytes + m.temp_size_in_bytes
+                 + m.generated_code_size_in_bytes)
+    print(f"evabyte cell step: {n} parameters, footprint {footprint} bytes "
+          f"({json.dumps({k: getattr(m, k) for k in dir(m) if k.endswith('_in_bytes') and not k.startswith('host')})})")
+    assert footprint < 15.75 * 2 ** 30
 
 
 # ------------------------------------------------------- fused epilogue --

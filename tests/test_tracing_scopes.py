@@ -178,7 +178,7 @@ def _pallas_calls(jaxpr, out):
 
 @pytest.fixture(scope="module")
 def kernel_calls():
-    """(name, name stack) of every ``pallas_call`` equation the eight
+    """(name, name stack) of every ``pallas_call`` equation the ten
     sites trace, in interpret mode: no chip needed."""
     from paddle_tpu.ops.pallas.collective_matmul import chunk_matmul
     from paddle_tpu.ops.pallas.fused_adam import fused_adam_update
@@ -195,6 +195,14 @@ def kernel_calls():
             lambda q, k, v: jnp.sum(fa.flash_attention(q, k, v,
                                                        causal=True)),
             (0, 1, 2)))(q, q, q).jaxpr, [])
+        # eva_fwd, eva_bwd_dq, and flash_bwd_dkv once more (the exact
+        # keys' gradients, windows folded into the heads)
+        eva = importlib.import_module("paddle_tpu.ops.pallas.eva_attention")
+        q, vec = jnp.ones((1, 64, 2, 16), jnp.float32), jnp.ones((2, 16))
+        found += _pallas_calls(jax.make_jaxpr(jax.grad(
+            lambda q, k, v, mu, phi: jnp.sum(eva.eva_attention(
+                q, k, v, mu, phi, 16, 4)),
+            (0, 1, 2, 3, 4)))(q, q, q, vec, vec).jaxpr, [])
         x, w = jnp.ones((16, 16)), jnp.ones((16, 128))
         found += _pallas_calls(jax.make_jaxpr(jax.grad(
             lambda x, w, b: jnp.sum(fused_linear_epilogue(
@@ -229,7 +237,7 @@ def test_every_pallas_call_site_carries_its_name(kernel_calls, kernel):
 
 
 def test_no_pallas_call_is_left_without_a_name(kernel_calls):
-    assert len(kernel_calls) == 8
+    assert len(kernel_calls) == 11
     assert {name for name, _ in kernel_calls} == set(scopes.KERNELS)
     src = os.path.join(os.path.dirname(paddle.__file__), "ops", "pallas")
     for path in glob.glob(os.path.join(src, "*.py")):
