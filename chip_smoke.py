@@ -206,16 +206,15 @@ def phase_train(clog, cfg=BERT_BASE, steps=6):
         raise AssertionError(
             f"train step compiled {n_step} times (want 1); compiles "
             f"after step 1: {late}")
-    # q/k/v dropout>0 at seq 512 meets the flash gate: fwd + 2 bwd
-    # kernels per layer would be 36; one is enough to prove the tier
+    # attention at seq 512 takes the flash kernel: fwd + 2 bwd kernels
+    # per layer would be 36; one is enough to prove the tier
     n_kernels = mosaic_kernels(executable_text("jit_step_fn"), 3,
                                "train step")
     report("train", clog, mark, {
         "losses": [round(v, 4) for v in losses],
         "first_step_ms(compile incl.)": round(ms[0]),
         "smoke_step_ms": [round(v, 1) for v in ms[2:]],
-        # flash attention is the only kernel TrainStep can select, and
-        # it does not count its selections
+        # flash attention is the only kernel TrainStep can select
         "mosaic_kernels(flash fwd+bwd)": n_kernels})
 
 
@@ -459,6 +458,21 @@ def check_flash(errs, bert, gpt=GPT_CELL):
         ref = jax.jit(jax.grad(_sq(plain), (0, 1, 2)))(q, k, v)
         for n, a, b in zip("qkv", got, ref):
             errs[f"{tag}_d{n}"] = _close(a, b, 4 * BF16_TOL, f"{tag} d{n}")
+    # the choice, not only the kernel: at BERT's shape sdpa must take it
+    # (PERF.md, PR 27: 238.6 -> 230.2 ms a step in the BERT cell)
+    import paddle_tpu.nn.functional as F
+    from paddle_tpu.utils import monitor
+    names = ("pallas.selected.flash_attention", "attention.xla_path")
+    before = monitor.all_stats()
+    q = _rnd(1, (4,) + heads, jnp.bfloat16)
+    F.scaled_dot_product_attention(q, q, q)
+    after = monitor.all_stats()
+    took = tuple(after.get(n, 0) - before.get(n, 0) for n in names)
+    if took != (1, 0):
+        raise AssertionError(
+            f"sdpa at BERT's shape {(4,) + heads} took {dict(zip(names, took))}: "
+            "the kernel is expected (flash_attention.py::_KERNEL_FROM)")
+    errs["sdpa_bert_path"] = "flash kernel"
 
 
 def check_flash_dropout(errs, bert):

@@ -90,9 +90,6 @@ define_flag("allocator_strategy", "xla",
             "Accepted for parity; XLA/TPU runtime owns allocation.")
 define_flag("profile_dir", "",
             "If set, profiler traces are written here.")
-define_flag("pallas_attention_min_seqlen", 1024,
-            "Use the Pallas flash-attention kernel at/above this sequence "
-            "length (below it XLA's fused attention is faster on-chip).")
 define_flag("static_verify", False,
             "Run static.analysis verification (def-use, cross-program "
             "leaks, shape/dtype drift, name collisions, dead code) on "
@@ -334,8 +331,3 @@ define_flag("obs_export_interval_s", 5.0,
             "it so a busy process that dies between timer fires still "
             "leaves a recent spool.  Ticks inside the interval are "
             "rate-limited to one time check.")
-define_flag("pallas_attention_dropout_min_seqlen", 512,
-            "Flash threshold when attention dropout is active: the XLA "
-            "path must materialize [B,H,L,L] dropout masks in HBM, so "
-            "the in-kernel-PRNG flash path wins from shorter sequences "
-            "(measured v5e, BERT-base seq 512: 325 -> 288 ms/step).")

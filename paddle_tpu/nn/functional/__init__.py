@@ -1066,8 +1066,12 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  return_weights=False):
     """[B, L, H, D] attention (paddle incubate layout).  The Pallas
     flash-attention kernel (paddle_tpu.ops.pallas) replaces the jnp path
-    when FLAGS_use_pallas_kernels is on and shapes allow (reference analog:
-    operators/math/bert_encoder_functor.cu fused attention).
+    when FLAGS_use_pallas_kernels is on, there is no ``attn_mask`` and the
+    longer sequence has 512 positions or more, the crossover measured on
+    the chip (``flash_attention_supported``; reference analog:
+    operators/math/bert_encoder_functor.cu fused attention).  Which path
+    a program took is counted at trace time:
+    ``pallas.selected.flash_attention`` / ``attention.xla_path``.
 
     ``return_weights=True`` forces the unfused path and returns
     ``(out, weights [B, H, Lq, Lk])`` — post-softmax probabilities, with
@@ -1130,6 +1134,8 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
             return out, w_used
         return out
 
+    from ...utils import monitor
+    monitor.stat_add("attention.xla_path")
     args = [query, key, value] + ([attn_mask] if attn_mask is not None else [])
     return apply(_sdpa, *args, op_name="scaled_dot_product_attention")
 

@@ -17,8 +17,9 @@ Per call on one v5e (PERF.md, PR 25, where the sweep and the per-block
 times are): [8, 16, 2048, 96] bf16 causal takes 1.48 ms forward, 1.73 ms
 dq and 2.46 ms dk/dv; [64, 12, 512, 64] bf16 non-causal 0.85, 1.00 and
 1.20 ms.  Whether XLA's attention or this kernel is taken at a length is
-the FLAGS_pallas_attention_min_seqlen gate's; it has not been re-measured
-against these times (ROADMAP.md S6).
+a measured constant, `_KERNEL_FROM` below, beside the chip table it came
+from: the kernel from 512 positions up, for every head size, mask and
+dropout rate measured (PERF.md, PR 27).
 
 Causal masking supports traced *global position offsets* for Q and K
 (`q_off`/`k_off`, float32 [1,1] scalars): a Q/K pair is visible when
@@ -40,8 +41,36 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ...observability import scopes
-from .support import (NEG_INF, dot as _dot, interpret_mode as _interpret,
-                      pltpu, smem_scalar_spec as _smem_scalar_spec)
+from .support import (NEG_INF, count_kernel_selection, dot as _dot,
+                      interpret_mode as _interpret, pltpu,
+                      smem_scalar_spec as _smem_scalar_spec)
+
+
+# XLA's attention or the kernel?  One attention call, forward + backward
+# under jax.jit, [B, L, H, D] in and out (the layout changes around the
+# kernel and the delta reduction inside the timing), bf16, non-causal,
+# 32,768 tokens x 768 hidden on one v5e (PERF.md, PR 27); ms, XLA / kernel:
+#
+#      L   D = 64         D = 96        D = 128       D = 64, dropout 0.1
+#    128   2.21 / 7.08    1.22 / 4.95   1.26 / 2.87   2.88 / 7.11
+#    256   3.67 / 5.32    2.75 / 3.84   2.42 / 2.04   5.09 / 5.41
+#    384   5.08 / 5.17    3.69 / 3.71   3.12 / 1.88   7.10 / 5.39
+#    512   6.41 / 4.94    4.61 / 3.57   3.83 / 1.89   9.26 / 5.74
+#   1024  13.14 / 8.18    9.06 / 5.88   6.88 / 3.70
+#
+# A causal mask costs the kernel 0.1 to 0.5 ms more below 1024, XLA
+# nothing, and moves no row to the other side.  From 512 up the kernel
+# wins in every column, at 128 XLA does; between them it depends on the
+# head size.  A step is harder on the kernel than this sweep: in the
+# BERT cell ([64, 512, 12, 64], 12 layers) the step fell by 8.4 ms where
+# the sweep's 1.47 ms a layer promise 17.6, because XLA lays the
+# projections around the kernel out less well (0.7 ms a layer that no
+# sweep of one call sees).  That margin takes D = 64 and 96 at 384 and
+# D = 128 at 256 to XLA's side and leaves D = 128 at 384 and dropout at
+# 384 as the only entries under 512 on the kernel's, lengths no cell and
+# hardly a model runs: one length decides, with dropout (XLA writes its
+# masks to HBM) and without.
+_KERNEL_FROM = 512
 
 
 def flash_attention_supported(q_shape, k_shape, dtype, attn_mask=None,
@@ -49,12 +78,10 @@ def flash_attention_supported(q_shape, k_shape, dtype, attn_mask=None,
                               block_q: int | None = None,
                               block_k: int | None = None) -> bool:
     """Capability + profitability check: shapes/dtype the kernel handles
-    AND where it is taken over XLA's fused attention (the
-    FLAGS_pallas_attention_min_seqlen gate; the module docstring has the
-    per-call times it is to be re-measured against).  Attention dropout runs
+    AND where it is taken over XLA's fused attention (from `_KERNEL_FROM`
+    positions up, the crossover measured above).  Attention dropout runs
     IN-KERNEL via the Pallas TPU PRNG (tile-seeded, regenerated in the
     backward) — but only on real TPUs (interpret mode has no PRNG)."""
-    from ...core.flags import get_flag
     if attn_mask is not None:
         return False
     if dropout_p > 0.0 and _interpret():
@@ -65,10 +92,7 @@ def flash_attention_supported(q_shape, k_shape, dtype, attn_mask=None,
     Lk = k_shape[1]
     if dtype not in (jnp.float32, jnp.bfloat16):
         return False
-    min_len = get_flag("pallas_attention_dropout_min_seqlen"
-                       if dropout_p > 0.0
-                       else "pallas_attention_min_seqlen")
-    if max(Lq, Lk) < min_len:
+    if max(Lq, Lk) < _KERNEL_FROM:
         return False
     # blocks must tile the sequence
     bq, bk = _resolve_blocks(block_q, block_k, Lq, Lk)
@@ -251,6 +275,19 @@ def _rows(ref, j, block):
     return ref[0, 0, pl.ds(pl.multiple_of(j * block, block), block), :]
 
 
+def _once_a_shape(*static_argnums):
+    """Decorator of the three kernel launchers: jax's tracing cache
+    serves every call after the first with the same static shape, so a
+    model's identical layers trace each kernel body once (36 kernel
+    calls of the BERT cell cost its set-up 3.8 s of tracing and lowering
+    without this: PERF.md, PR 27).  ``inline=True`` leaves no call in the
+    program: every call site gets the equations under its own scope
+    names.  The interpreter switch is an argument, and so part of the
+    cache's key."""
+    return functools.partial(jax.jit, static_argnums=static_argnums,
+                             inline=True)
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -317,10 +354,17 @@ def _qkv_fwd_specs(block_q, Lk, D):
 def _fwd(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
          dropout_p=0.0):
     """q/k/v: [B, H, L, D] → (out [B,H,Lq,D], lse [B,H,Lq])."""
+    _count_blocks(q.shape[2], k.shape[2], *blocks, causal, aligned)
+    return _fwd_call(q, k, v, q_off, k_off, seed, scale, causal, blocks,
+                     aligned, dropout_p, _interpret())
+
+
+@_once_a_shape(6, 7, 8, 9, 10, 11)
+def _fwd_call(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
+              dropout_p, interpret):
     B, H, Lq, D = q.shape
     Lk = k.shape[2]
     block_q, block_k = blocks
-    _count_blocks(Lq, Lk, block_q, block_k, causal, aligned)
     kernel = functools.partial(_fwd_kernel, scale=scale, block_k=block_k,
                                seq_k=Lk, causal=causal, block_q=block_q,
                                aligned=aligned, dropout_p=dropout_p)
@@ -336,7 +380,7 @@ def _fwd(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
             jax.ShapeDtypeStruct((B, H, Lq, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, 8, Lq), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=interpret,
         name=scopes.FLASH_FWD,
     )(q_off, k_off, seed, q, k, v)
     # compact [B, H, Lq] is the residual / public lse shape; the 8-sublane
@@ -429,10 +473,18 @@ def _bwd_dkv_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
 
 def _bwd_dq(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale, causal,
             blocks, aligned, dropout_p):
+    _count_blocks(q.shape[2], k.shape[2], *blocks, causal, aligned)
+    return _bwd_dq_call(q, k, v, q_off, k_off, seed, do, lse8, delta8,
+                        scale, causal, blocks, aligned, dropout_p,
+                        _interpret())
+
+
+@_once_a_shape(9, 10, 11, 12, 13, 14)
+def _bwd_dq_call(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale,
+                 causal, blocks, aligned, dropout_p, interpret):
     B, H, Lq, D = q.shape
     Lk = k.shape[2]
     block_q, block_k = blocks
-    _count_blocks(Lq, Lk, block_q, block_k, causal, aligned)
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, block_k=block_k,
                           seq_k=Lk, causal=causal, block_q=block_q,
@@ -446,17 +498,25 @@ def _bwd_dq(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale, causal,
         out_specs=pl.BlockSpec((1, 1, block_q, D),
                                lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Lq, D), q.dtype),
-        interpret=_interpret(),
+        interpret=interpret,
         name=scopes.FLASH_BWD_DQ,
     )(q_off, k_off, seed, q, k, v, do, lse8, delta8)
 
 
 def _bwd_dkv(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale, causal,
              blocks, aligned, dropout_p):
+    _count_blocks(q.shape[2], k.shape[2], *blocks, causal, aligned)
+    return _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8,
+                         scale, causal, blocks, aligned, dropout_p,
+                         _interpret())
+
+
+@_once_a_shape(9, 10, 11, 12, 13, 14)
+def _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale,
+                  causal, blocks, aligned, dropout_p, interpret):
     B, H, Lq, D = q.shape
     Lk = k.shape[2]
     block_q, block_k = blocks
-    _count_blocks(Lq, Lk, block_q, block_k, causal, aligned)
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
                           seq_q=Lq, causal=causal, block_k=block_k,
@@ -481,7 +541,7 @@ def _bwd_dkv(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale, causal,
             jax.ShapeDtypeStruct((B, H, Lk, D), k.dtype),
             jax.ShapeDtypeStruct((B, H, Lk, D), v.dtype),
         ],
-        interpret=_interpret(),
+        interpret=interpret,
         name=scopes.FLASH_BWD_DKV,
     )(q_off, k_off, seed, q, k, v, do, lse8, delta8)
 
@@ -598,6 +658,7 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
         seed = _zero_seed()
     else:
         seed = jnp.asarray(seed, jnp.int32).reshape(1, 1)
+    count_kernel_selection("flash_attention")
     qt = jnp.swapaxes(q, 1, 2)      # [B, H, L, D]
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)

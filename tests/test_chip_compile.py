@@ -59,11 +59,10 @@ def test_flash_attention_fwd_bwd(one_chip, monkeypatch, shape, causal,
     # the package re-exports a function under the module's name
     fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
     monkeypatch.setattr(fa, "_interpret", lambda: False)
-    # capability + profitability: at seq 512 the dispatcher takes the
-    # kernel only with dropout (FLAGS_pallas_attention_min_seqlen)
-    assert fa.flash_attention_supported(
-        shape, shape, jnp.bfloat16,
-        dropout_p=dropout) == (shape[1] >= 1024 or dropout > 0)
+    # capability + profitability: the dispatcher takes the kernel at all
+    # three, BERT's 512 included, with dropout or without
+    assert fa.flash_attention_supported(shape, shape, jnp.bfloat16,
+                                        dropout_p=dropout)
 
     def loss(q, k, v, seed):
         out = fa.flash_attention(q, k, v, causal=causal,

@@ -3,6 +3,7 @@ compile on TPU — parity there was measured during bring-up).
 
 Modelled on the reference's fused-op tests (test_fused_attention_op.py
 pattern: fused output vs composed-op oracle, fwd + grad)."""
+import importlib
 import os
 
 import jax
@@ -11,17 +12,20 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
+from paddle_tpu.core import flags as flags_mod
 from paddle_tpu.core.flags import get_flag, set_flags
 from paddle_tpu.ops.pallas import (flash_attention,
                                    flash_attention_supported, mha_reference)
 
+# the package re-exports a function under the module's name
+fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+
 
 @pytest.fixture
-def low_seq_threshold():
-    old = get_flag("pallas_attention_min_seqlen")
-    set_flags({"pallas_attention_min_seqlen": 16})
-    yield
-    set_flags({"pallas_attention_min_seqlen": old})
+def kernel_from_16(monkeypatch):
+    """The kernel at the tests' small shapes, which the measured
+    crossover leaves to XLA."""
+    monkeypatch.setattr(fa, "_KERNEL_FROM", 16)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -106,29 +110,27 @@ def test_flash_chosen_blocks_tile_the_sequence(L, D):
     assert L % block_q == 0 and L % block_k == 0
     assert block_q % 128 == 0 and block_k % 128 == 0
     shape = (4, L, 12, D)
-    old = get_flag("pallas_attention_min_seqlen")
-    set_flags({"pallas_attention_min_seqlen": 512})  # admit BERT's 512
-    try:
-        assert flash_attention_supported(shape, shape, jnp.bfloat16)
-        # what is chosen is what an explicit caller would have to pass
-        assert flash_attention_supported(shape, shape, jnp.bfloat16,
-                                         block_q=block_q, block_k=block_k)
-    finally:
-        set_flags({"pallas_attention_min_seqlen": old})
+    assert flash_attention_supported(shape, shape, jnp.bfloat16)
+    # what is chosen is what an explicit caller would have to pass
+    assert flash_attention_supported(shape, shape, jnp.bfloat16,
+                                     block_q=block_q, block_k=block_k)
+
+
+def _counted(names, fn, *args):
+    """By how much ``fn(*args)`` moved each of the program's counters."""
+    from paddle_tpu.utils import monitor
+    before = monitor.all_stats()
+    fn(*args)
+    after = monitor.all_stats()
+    return tuple(after.get(n, 0) - before.get(n, 0) for n in names)
 
 
 def test_flash_block_counters():
     """pallas.flash.blocks_full / blocks_masked: block iterations per
     (batch, head) of each kernel traced, by whether they run a mask."""
-    from paddle_tpu.utils import monitor
-
     def counts(fn, *args):
-        before = monitor.all_stats()
-        fn(*args)
-        after = monitor.all_stats()
-        return tuple(after.get(n, 0) - before.get(n, 0)
-                     for n in ("pallas.flash.blocks_full",
-                               "pallas.flash.blocks_masked"))
+        return _counted(("pallas.flash.blocks_full",
+                         "pallas.flash.blocks_masked"), fn, *args)
 
     r = np.random.RandomState(8)
     q, k, v = (jnp.asarray(r.randn(1, 256, 1, 16), jnp.float32)
@@ -161,7 +163,7 @@ def test_flash_cross_attention_shapes():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
-def test_supported_capability_checks(low_seq_threshold):
+def test_supported_capability_checks(kernel_from_16):
     shape = (2, 128, 2, 32)
     assert flash_attention_supported(shape, shape, jnp.float32)
     assert not flash_attention_supported(shape, shape, jnp.float16)
@@ -170,12 +172,98 @@ def test_supported_capability_checks(low_seq_threshold):
     assert not flash_attention_supported(shape, shape, jnp.float32,
                                          dropout_p=0.1)
     assert not flash_attention_supported((2, 128, 2, 30), shape, jnp.float32)
-    # below the profitability threshold -> jnp path
-    set_flags({"pallas_attention_min_seqlen": 100000})
-    assert not flash_attention_supported(shape, shape, jnp.float32)
 
 
-def test_sdpa_dispatches_to_flash(low_seq_threshold):
+# ---- which attention sdpa takes: the measured crossover (PERF.md, PR 27)
+
+def _check_rule(monkeypatch, shape, taken):
+    assert flash_attention_supported(shape, shape, jnp.bfloat16) == taken
+
+
+def _check_incapable(monkeypatch, q_shape, dtype, kwargs):
+    # long enough for the kernel to pay: only the capability says no
+    assert q_shape[1] >= fa._KERNEL_FROM
+    assert not flash_attention_supported(q_shape, q_shape, dtype, **kwargs)
+
+
+def _check_sdpa_counts(monkeypatch, length, tier_on, want):
+    import paddle_tpu.nn.functional as F
+    if not tier_on:
+        monkeypatch.setitem(flags_mod._values, "use_pallas_kernels", False)
+    q = paddle.to_tensor(np.ones((2, length, 4, 64), np.float32))
+    assert _counted(("pallas.selected.flash_attention",
+                     "attention.xla_path"),
+                    F.scaled_dot_product_attention, q, q, q) == want
+
+
+def _check_sdpa_paths_agree(monkeypatch, differentiate):
+    """The kernel (interpret mode) against XLA's path, through sdpa."""
+    import paddle_tpu.nn.functional as F
+    r = np.random.RandomState(11)
+    qkv = [jnp.asarray(r.randn(1, 512, 2, 64), jnp.float32)
+           for _ in range(3)]
+
+    def run():
+        def f(q, k, v):
+            out = F.scaled_dot_product_attention(q, k, v).data
+            return jnp.sum(out * out) if differentiate else out
+        return jax.grad(f, (0, 1, 2))(*qkv) if differentiate else (f(*qkv),)
+
+    kernel = run()
+    monkeypatch.setitem(flags_mod._values, "use_pallas_kernels", False)
+    for a, b in zip(kernel, run()):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)
+
+
+def _check_flag_is_gone(monkeypatch, which):
+    # the two thresholds a user could set before the crossover was measured
+    name = "pallas_attention_" + which
+    with pytest.raises(KeyError, match="Unknown flag"):
+        get_flag(name)
+    with pytest.raises(KeyError, match="Unknown flag"):
+        set_flags({name: 16})
+
+
+@pytest.mark.parametrize("check,args", [
+    # the rule at the three cells' attention shapes and on XLA's side
+    pytest.param(_check_rule, ((64, 512, 12, 64), True), id="rule-bert_cell"),
+    pytest.param(_check_rule, ((8, 2048, 16, 96), True), id="rule-gpt_cell"),
+    pytest.param(_check_rule, ((1, 8192, 32, 128), True),
+                 id="rule-evabyte_cell"),
+    pytest.param(_check_rule, ((85, 384, 12, 64), False), id="rule-384"),
+    pytest.param(_check_rule, ((128, 256, 6, 128), False), id="rule-256"),
+    # the capability checks, unchanged
+    pytest.param(_check_incapable,
+                 ((2, 512, 4, 64), jnp.bfloat16, {"attn_mask": object()}),
+                 id="incapable-attn_mask"),
+    pytest.param(_check_incapable, ((2, 512, 4, 60), jnp.bfloat16, {}),
+                 id="incapable-head_dim_60"),
+    pytest.param(_check_incapable, ((1, 32768, 1, 64), jnp.float32, {}),
+                 id="incapable-over_2MiB"),
+    pytest.param(_check_incapable,
+                 ((2, 512, 4, 64), jnp.bfloat16, {"dropout_p": 0.1}),
+                 id="incapable-dropout_interpreted"),
+    pytest.param(_check_incapable, ((2, 520, 4, 64), jnp.bfloat16, {}),
+                 id="incapable-blocks_do_not_tile"),
+    # (kernel, XLA) as sdpa counts its choice at [2, L, 4, 64]
+    pytest.param(_check_sdpa_counts, (512, True, (1, 0)),
+                 id="sdpa-512-kernel"),
+    pytest.param(_check_sdpa_counts, (256, True, (0, 1)), id="sdpa-256-xla"),
+    pytest.param(_check_sdpa_counts, (512, False, (0, 1)),
+                 id="sdpa-512-tier_off-xla"),
+    pytest.param(_check_sdpa_paths_agree, (False,), id="paths-forward"),
+    pytest.param(_check_sdpa_paths_agree, (True,), id="paths-gradient"),
+    pytest.param(_check_flag_is_gone, ("min_seqlen",),
+                 id="flag_gone-min_seqlen"),
+    pytest.param(_check_flag_is_gone, ("dropout_min_seqlen",),
+                 id="flag_gone-dropout_min_seqlen"),
+])
+def test_attention_choice(monkeypatch, check, args):
+    check(monkeypatch, *args)
+
+
+def test_sdpa_dispatches_to_flash(kernel_from_16):
     import paddle_tpu.nn.functional as F
     r = np.random.RandomState(3)
     q = paddle.to_tensor(r.randn(1, 64, 2, 16).astype(np.float32),
@@ -193,7 +281,7 @@ def test_sdpa_dispatches_to_flash(low_seq_threshold):
     assert q.grad is not None and np.isfinite(q.grad.numpy()).all()
 
 
-def test_ring_attention_flash_path(low_seq_threshold):
+def test_ring_attention_flash_path(kernel_from_16):
     from paddle_tpu.distributed.mesh import init_mesh
     from paddle_tpu.parallel.ring_attention import (reference_attention,
                                                     ring_attention)
@@ -211,7 +299,7 @@ def test_ring_attention_flash_path(low_seq_threshold):
                                    rtol=1e-4, atol=1e-5)
 
 
-def test_ring_attention_flash_grads(low_seq_threshold):
+def test_ring_attention_flash_grads(kernel_from_16):
     from paddle_tpu.distributed.mesh import init_mesh
     from paddle_tpu.parallel.ring_attention import (
         reference_attention, ring_attention_per_device_flash)
@@ -243,7 +331,7 @@ def test_ring_attention_flash_grads(low_seq_threshold):
                                    rtol=1e-3, atol=1e-5)
 
 
-def test_ring_attention_non_block_multiple_falls_back(low_seq_threshold):
+def test_ring_attention_non_block_multiple_falls_back(kernel_from_16):
     # local shard 520 is not a multiple of the 512 block: eligibility must
     # reject it and the jnp ring path must produce exact results
     from paddle_tpu.distributed.mesh import init_mesh
