@@ -47,14 +47,21 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 # ---------------------------------------------------------------- widths --
-BERT_BASE = dict(vocab=30522, hidden=768, layers=12, heads=12, ffn=3072,
-                 seq=512, batch=64, dropout=0.1)
+# The two BERT phases build what the benchmark's cells build: the widths,
+# the optimizer and the batch come from the cell's own files, the model
+# from the cell's own builder (benchmark/models/), so what is proved here
+# is what the ledger's numbers come from.
+TRAIN_CELL = "bert_base.train_bf16_b64_s512"
+STATIC_CELL = "bert_base.static_f32_b64_s512"
+# the train cell runs without dropout (its reference cannot follow the
+# masks); this phase keeps the published 0.1, the flash kernel's PRNG path
+TRAIN_DROPOUT = 0.1
+# the shapes the kernels phase hands each kernel alone: BERT-base's, at
+# the two cells' batch
+BERT_BASE = dict(vocab=30522, hidden=768, heads=12, ffn=3072, seq=512,
+                 batch=64, dropout=TRAIN_DROPOUT)
 # attention of the benchmark's GPT cell: gpt3_large at context 2048
 GPT_CELL = dict(seq=2048, heads=16, head_dim=96)
-# The f32 static program keeps the full batch: compiled for a described
-# v5e it has 14.86 GiB live of the chip's 15.75 (PERF.md, Findings), so
-# it fits — as long as the phase before it left nothing on the device.
-STATIC_BATCH = 64
 SERVE = dict(vocab_size=32000, hidden=2048, num_layers=8, num_heads=16,
              num_kv_heads=4, ffn=8192, seed=0, dyadic=True)
 SERVE_ENGINE = dict(num_slots=8, page_size=16, max_context=2048,
@@ -65,6 +72,17 @@ SERVE_NEW_TOKENS = 32
 
 def log(msg):
     print(msg, flush=True)
+
+
+def load_parts(cell_name, rehearse=False):
+    """(cell, cfg, mix, model module) of one benchmark cell, through
+    benchmark/run.py::load_parts; ``rehearse`` takes the cell's tiny
+    rehearsal sizes (for a dry run on the CPU)."""
+    bench_dir = os.path.join(REPO, "benchmark")
+    if bench_dir not in sys.path:
+        sys.path.insert(0, bench_dir)
+    import run as harness
+    return harness.load_parts(cell_name, rehearse=rehearse)[:4]
 
 
 # ------------------------------------------------- what jax compiled/ran --
@@ -158,38 +176,35 @@ def finite(values, what):
 
 
 # ----------------------------------------------------------------- train --
-def phase_train(clog, cfg=BERT_BASE, steps=6):
-    """bench.bench_bert's construction: Layer -> O2 decorate -> TrainStep."""
+def phase_train(clog, steps=6, rehearse=False):
+    """The BERT cell's construction (benchmark/runners/train_step.py):
+    the cell's model and loss -> O2 decorate -> TrainStep, dropout on."""
     import jax.numpy as jnp
 
-    import bench
     import paddle_tpu as paddle
-    import paddle_tpu.nn.functional as F
     from paddle_tpu import amp, optimizer
     from paddle_tpu.jit import TrainStep
     from paddle_tpu.optimizer.clip import ClipGradByGlobalNorm
 
+    cell, cfg, mix, model_mod = load_parts(TRAIN_CELL, rehearse)
+    cfg = {**cfg, "hidden_dropout_prob": TRAIN_DROPOUT,
+           "attention_probs_dropout_prob": TRAIN_DROPOUT}
+    o = cell["optimizer"]
     mark = clog.mark()
     paddle.seed(2024)
-    model = bench.build_model(cfg["vocab"], cfg["hidden"], cfg["layers"],
-                              cfg["heads"], cfg["ffn"], cfg["seq"],
-                              cfg["dropout"])
-    opt = optimizer.AdamW(learning_rate=1e-4,
-                          parameters=model.parameters(), weight_decay=0.01,
-                          grad_clip=ClipGradByGlobalNorm(1.0),
-                          multi_precision=True)
-    model, opt = amp.decorate(model, opt, level="O2", dtype="bfloat16")
-
-    def loss_fn(out, labels):
-        return F.linear_cross_entropy(
-            out.reshape([-1, cfg["hidden"]]), model.head.weight,
-            model.head.bias, labels.reshape([-1]))
-
+    model, loss_fn = model_mod.build(cfg, cell["model_args"])
+    opt = optimizer.AdamW(
+        learning_rate=o["lr"], beta1=o["beta1"], beta2=o["beta2"],
+        epsilon=o["eps"], parameters=model.parameters(),
+        weight_decay=o["weight_decay"],
+        grad_clip=ClipGradByGlobalNorm(o["clip_global_norm"]),
+        multi_precision=True)
+    model, opt = amp.decorate(model, opt, level="O2", dtype=cell["dtype"])
     step = TrainStep(model, loss_fn, opt, n_inputs=1, donate=True)
     rng = np.random.RandomState(0)
-    shape = (cfg["batch"], cfg["seq"])
-    x = jnp.asarray(rng.randint(0, cfg["vocab"], shape, dtype=np.int32))
-    y = jnp.asarray(rng.randint(0, cfg["vocab"], shape, dtype=np.int32))
+    shape, vocab = (mix["batch"], mix["seq"]), cfg["vocab_size"]
+    x = jnp.asarray(rng.randint(0, vocab, shape, dtype=np.int32))
+    y = jnp.asarray(rng.randint(0, vocab, shape, dtype=np.int32))
 
     losses, ms, marks = [], [], []
     for _ in range(steps):
@@ -219,24 +234,43 @@ def phase_train(clog, cfg=BERT_BASE, steps=6):
 
 
 # ---------------------------------------------------------------- static --
-def phase_static(clog, cfg=BERT_BASE, batch=STATIC_BATCH, steps=3):
-    """bench.build_bert_static through the Executor, default flags."""
-    import bench
+def build_static(cell, cfg, mix, model_mod):
+    """A static cell's program, seeded, as the cell's own builder records
+    it (benchmark/models/bert_static.py::build), and one seeded feed.
+    Takes what ``load_parts`` returns; call under
+    ``paddle.enable_static()``.  -> (program, loss variable, feed)."""
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+
+    paddle.seed(2024)
+    prog, loss, _, constant_feeds = model_mod.build(
+        cfg, cell["model_args"], mix["batch"], mix["seq"],
+        cell["optimizer"])
+    rng = np.random.RandomState(0)
+    shape = (mix["batch"], mix["seq"])
+    feed = {name: jnp.asarray(rng.randint(0, cfg["vocab_size"], shape,
+                                          dtype=np.int64))
+            for name in ("ids", "labels")}
+    feed.update({k: jnp.asarray(v) for k, v in constant_feeds.items()})
+    return prog, loss, feed
+
+
+def phase_static(clog, steps=3, rehearse=False):
+    """The static cell's program through the Executor, default flags.
+    Compiled for a described v5e it has 14.86 GiB live of the chip's
+    15.75 (PERF.md, Findings), so it fits — as long as the phase before
+    it left nothing on the device."""
     import paddle_tpu as paddle
     from paddle_tpu.observability import explain_compiles
     from paddle_tpu.utils import monitor
 
     mark, sel0 = clog.mark(), selections()
-    if batch != cfg["batch"]:
-        log(f"[static] batch cut {cfg['batch']} -> {batch} (f32 program, "
-            f"16 GB chip); widths and seq {cfg['seq']} unchanged")
     paddle.enable_static()
     try:
-        prog, loss, feeds = bench.build_bert_static(
-            cfg["vocab"], cfg["hidden"], cfg["layers"], cfg["heads"],
-            cfg["ffn"], cfg["seq"], batch)
+        cell, cfg, mix, model_mod = load_parts(STATIC_CELL, rehearse)
+        prog, loss, feed = build_static(cell, cfg, mix, model_mod)
         exe = paddle.static.Executor()
-        feed = feeds(np.random.RandomState(0))
         losses, ms = [], []
         for _ in range(steps):
             t0 = time.perf_counter()
@@ -253,9 +287,10 @@ def phase_static(clog, cfg=BERT_BASE, batch=STATIC_BATCH, steps=3):
         n_epi = sum(k.startswith("fused_epilogue[") for k in kernels)
         # BERT-base: each block's proj+residual+LayerNorm fuses (the FFN
         # weights are past the kernel's gate), so one per layer
-        if n_epi < cfg["layers"] or "fused_adam" not in kernels:
+        layers = cfg["num_hidden_layers"]
+        if n_epi < layers or "fused_adam" not in kernels:
             raise AssertionError(
-                f"compile record should name >= {cfg['layers']} fused "
+                f"compile record should name >= {layers} fused "
                 f"epilogues and fused_adam, has {kernels}")
         if monitor.get_stat("predicted.executor.errors"):
             raise AssertionError("the cost model failed on this program "
@@ -506,20 +541,18 @@ def check_flash_dropout(errs, bert):
     errs["flash_dropout"] = "deterministic per seed"
 
 
-def check_epilogue(errs, bert, f32_rows):
+def check_epilogue(errs, bert):
     """The recipe the Executor realises on BERT-base (proj + bias +
-    residual + LayerNorm): f32 at the static phase's rows, bf16 at the
-    train phase's."""
+    residual + LayerNorm) at the two phases' rows: f32 as the static
+    phase runs it, bf16 as the train phase would."""
     import jax
     import jax.numpy as jnp
 
     from paddle_tpu.ops.pallas.fused_epilogue import (
         fused_linear_epilogue, reference_epilogue)
-    H = bert["hidden"]
+    H, rows = bert["hidden"], bert["batch"] * bert["seq"]
     stages = (("add",), ("layer_norm", 1e-5, True, True))
-    for dtype, rows, tol in (
-            (jnp.float32, f32_rows, F32_TOL),
-            (jnp.bfloat16, bert["batch"] * bert["seq"], BF16_TOL)):
+    for dtype, tol in ((jnp.float32, F32_TOL), (jnp.bfloat16, BF16_TOL)):
         tag = jnp.dtype(dtype).name
         args = (_rnd(10, (rows, H), dtype),
                 _rnd(11, (H, H), dtype, H ** -0.5),
@@ -610,8 +643,7 @@ def check_chunk_matmul(errs, bert):
             f"chunk matmul {tag}")
 
 
-def phase_kernels(clog, bert=BERT_BASE, static_batch=STATIC_BATCH,
-                  serve=SERVE, engine=SERVE_ENGINE):
+def phase_kernels(clog, bert=BERT_BASE, serve=SERVE, engine=SERVE_ENGINE):
     import jax
 
     from paddle_tpu.ops.pallas.support import interpret_mode
@@ -621,7 +653,7 @@ def phase_kernels(clog, bert=BERT_BASE, static_batch=STATIC_BATCH,
     with jax.default_matmul_precision("highest"):
         check_flash(errs, bert)
         check_flash_dropout(errs, bert)
-        check_epilogue(errs, bert, static_batch * bert["seq"])
+        check_epilogue(errs, bert)
         check_adam(errs, bert)
         check_paged(errs, serve, engine)
         check_chunk_matmul(errs, bert)
@@ -674,11 +706,10 @@ def phase_callbacks(clog):
 
 
 # ------------------------------------------------------------- multichip --
-def phase_multichip(clog, cfg=BERT_BASE, batch=STATIC_BATCH, steps=3):
+def phase_multichip(clog, steps=3, rehearse=False):
     """README "Sharded training": fleet.init + sharding_rules on a
     {dp: 2, mp: 2} mesh through the Executor, against the same seeded
     program on one device of this host."""
-    import bench
     import paddle_tpu as paddle
     import paddle_tpu.distributed as dist
     from paddle_tpu.distributed.mesh import get_mesh
@@ -686,26 +717,26 @@ def phase_multichip(clog, cfg=BERT_BASE, batch=STATIC_BATCH, steps=3):
     mark = clog.mark()
 
     def build(sharded):
-        """bench.build_bert_static, with the optimizer routed through
-        fleet when sharded (the README recipe)."""
-        wrap = None
+        """The static cell's program; when sharded, its optimizer goes
+        through fleet (the README recipe).  The builder has minimised
+        already, and the Executor reads the strategy off the program's
+        optimizer at its first run, so fleet takes that optimizer."""
         if sharded:
             strategy = dist.DistributedStrategy()
             strategy.tensor_parallel = True
             strategy.tensor_parallel_configs = {"tensor_parallel_degree": 2}
             strategy.sharding_rules = MP_RULES
             dist.fleet.init(is_collective=True, strategy=strategy)
-            wrap = dist.fleet.distributed_optimizer
-        return bench.build_bert_static(
-            cfg["vocab"], cfg["hidden"], cfg["layers"], cfg["heads"],
-            cfg["ffn"], cfg["seq"], batch, wrap_optimizer=wrap)
+        prog, loss, feed = build_static(*load_parts(STATIC_CELL, rehearse))
+        if sharded:
+            dist.fleet.distributed_optimizer(prog._optimizer[0])
+        return prog, loss, feed
 
     def run(sharded):
         paddle.enable_static()
         try:
-            prog, loss, feeds = build(sharded)
+            prog, loss, feed = build(sharded)
             exe = paddle.static.Executor()
-            feed = feeds(np.random.RandomState(0))
             losses, ms = [], []
             for i in range(steps):
                 if i == 1:
@@ -740,7 +771,7 @@ def phase_multichip(clog, cfg=BERT_BASE, batch=STATIC_BATCH, steps=3):
     if dict(get_mesh().shape) != {"dp": 2, "mp": 2}:
         raise AssertionError("mesh is not {dp: 2, mp: 2}")
     report("multichip", clog, mark, {
-        "mesh": "{dp: 2, mp: 2}", "batch": batch,
+        "mesh": "{dp: 2, mp: 2}", "cell": STATIC_CELL,
         "one_device_losses": [round(v, 4) for v in one],
         "four_chip_losses": [round(v, 4) for v in four],
         "smoke_step_ms_one_device": [round(v, 1) for v in one_ms[1:]],

@@ -78,16 +78,8 @@ def get_flags(names=None) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 define_flag("check_nan_inf", False,
             "Scan every op output for NaN/Inf (reference: flags.cc:44).")
-define_flag("benchmark", False,
-            "Synchronise after each op and log timings (reference: flags.cc:38).")
-define_flag("eager_delete_tensor_gb", 0.0,
-            "Accepted for parity; XLA owns buffer lifetimes on TPU.")
 define_flag("use_pallas_kernels", True,
             "Use Pallas fused kernels (flash attention etc.) when on TPU.")
-define_flag("matmul_precision", "default",
-            "jax matmul precision: default | float32 | tensorfloat32 | highest.")
-define_flag("allocator_strategy", "xla",
-            "Accepted for parity; XLA/TPU runtime owns allocation.")
 define_flag("profile_dir", "",
             "If set, profiler traces are written here.")
 define_flag("static_verify", False,
@@ -223,7 +215,7 @@ define_flag("pallas_interpret", False,
             "the paged-attention decode hook) pick Pallas kernels OFF "
             "TPU, running them in interpret mode.  Interpret mode is "
             "orders of magnitude slower than jnp — this exists so "
-            "tests, bench and tools/kernel_smoke.py exercise the exact "
+            "tests and tools/kernel_smoke.py exercise the exact "
             "TPU kernel dataflow under JAX_PLATFORMS=cpu, never as a "
             "CPU performance path.  On a real TPU backend the tier "
             "needs only FLAGS_use_pallas_kernels.")
@@ -257,8 +249,7 @@ define_flag("anomaly_sentry", False,
             "analog of the reference's FLAGS_check_nan_inf (also "
             "opt-in), but one reduction per existing bucket view "
             "instead of per kernel launch: negligible next to real "
-            "model math, measurable on micro-benchmarks (bench.py's "
-            "static suite reports the measured overhead_pct).  "
+            "model math, measurable on micro-benchmarks.  "
             "Supervised production training should run with it on.  "
             "Flipping it recompiles (the executable either carries the "
             "sentry or it doesn't; attribution names the flip).")

@@ -132,7 +132,7 @@ def apply_latency_hiding_flags(platform: Optional[str] = None
 def place_compile_cache() -> str:
     """Decide where jax's persistent compilation cache lives, and return
     the directory.  For the entry points (``chip_smoke.py``,
-    ``bench.py``, ``tools/serve.py``) to call before their first
+    ``benchmark/run.py``, ``tools/serve.py``) to call before their first
     compile — never ``import paddle_tpu`` itself, a library does not
     start writing into its user's disk.
 

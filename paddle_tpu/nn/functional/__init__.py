@@ -1066,10 +1066,10 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  return_weights=False):
     """[B, L, H, D] attention (paddle incubate layout).  The Pallas
     flash-attention kernel (paddle_tpu.ops.pallas) replaces the jnp path
-    when FLAGS_use_pallas_kernels is on, there is no ``attn_mask`` and the
-    longer sequence has 512 positions or more, the crossover measured on
-    the chip (``flash_attention_supported``; reference analog:
-    operators/math/bert_encoder_functor.cu fused attention).  Which path
+    when the tier is on (``ops.pallas.support.tier_enabled``), there is
+    no ``attn_mask`` and the longer sequence has 512 positions or more,
+    the crossover measured on the chip (``flash_attention_supported``;
+    reference analog: bert_encoder_functor.cu fused attention).  Which path
     a program took is counted at trace time:
     ``pallas.selected.flash_attention`` / ``attention.xla_path``.
 
@@ -1077,8 +1077,8 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     ``(out, weights [B, H, Lq, Lk])`` — post-softmax probabilities, with
     dropout applied in training mode (matching the reference, which
     returns the dropped weights: nn/layer/transformer.py:412-431)."""
-    from ...core.flags import get_flag
-    if get_flag("use_pallas_kernels") and not return_weights:
+    from ...ops.pallas.support import tier_enabled
+    if tier_enabled() and not return_weights:
         from ...ops.pallas import flash_attention, flash_attention_supported
         q_shape = tuple(query.shape)
         k_shape = tuple(key.shape)

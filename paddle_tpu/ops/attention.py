@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 
 from ..core.dispatch import apply
-from ..core.flags import get_flag
 from ..core.tensor import Tensor
 
 __all__ = ["paged_attention", "paged_attention_reference",
@@ -70,15 +69,15 @@ def paged_attention_supported(q_shape, kv_pool_shape, dtype,
     dispatch the serving decode step uses.  Off TPU, a kernel that
     declares ``interpret_ok`` may still dispatch when the process opts
     into interpret-mode execution with ``FLAGS_pallas_interpret``
-    (tests/bench only — interpret mode is not a performance path)."""
+    (tests only — interpret mode is not a performance path)."""
     if _PALLAS_KERNEL is None:
         return False
-    if not get_flag("use_pallas_kernels"):
+    from .pallas import support
+    if not support.tier_enabled():
         return False
-    if jax.default_backend() != "tpu":
-        if not (getattr(_PALLAS_KERNEL, "interpret_ok", False)
-                and get_flag("pallas_interpret")):
-            return False
+    if (jax.default_backend() != "tpu"
+            and not getattr(_PALLAS_KERNEL, "interpret_ok", False)):
+        return False
     if dtype not in (jnp.float32, jnp.bfloat16):
         return False
     if len(q_shape) != 3 or len(kv_pool_shape) not in (4, 5):

@@ -9,10 +9,11 @@ the matmul so chunk transfers overlap chunk compute):
   contracting) dim: ``y = x @ all_gather(w)``.  Because the gather dim
   never enters the contraction, the fused per-chunk form — rotate the
   shards around the ring with ``ppermute``, matmul each chunk as it
-  arrives, place its column block — is **bitwise identical** to the
-  unfused gather-then-matmul sequence: each output column block is the
-  very same ``x @ w_j`` dot, same contraction order over K.  That makes
-  the composite correct on every backend and oracle-testable.
+  arrives, place its column block — equals the unfused
+  gather-then-matmul sequence to the rounding of one K-term sum: each
+  output column block is the same ``x @ w_j`` contraction, but a dot of
+  another shape, and the backend picks a dot's inner order by its shape.
+  That makes the composite correct on every backend and oracle-testable.
 - **row-parallel** — the weight is sharded on its INPUT (contracting)
   dim: each rank holds a partial product and the results
   reduce-scatter: ``y_mine = my rows of psum(x_part @ w_part)``.  The
@@ -72,8 +73,8 @@ def all_gather_matmul(x, w, axis_name: str, axis_size: int, *,
                       ring: bool = True):
     """Column-parallel fused all_gather+matmul: ``w`` is this rank's
     ``[K, N/size]`` shard of a weight sharded on its output dim over
-    ``axis_name``; returns the full ``x @ W`` (``[..., N]``), bitwise
-    equal to ``jnp.matmul(x, gather_param(w, ...))``.
+    ``axis_name``; returns the full ``x @ W`` (``[..., N]``), equal to
+    ``jnp.matmul(x, gather_param(w, ...))`` to a dot's rounding.
 
     ``ring=True`` (default) rotates the shards with ``size-1``
     single-chunk ppermutes and matmuls each chunk as it arrives — the

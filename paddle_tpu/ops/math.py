@@ -12,8 +12,6 @@ import jax.numpy as jnp
 from ..core.dispatch import apply, as_array
 from ..core.tensor import Tensor
 
-_prec = None  # set via flags/matmul_precision if needed
-
 
 def _binop(jfn, name):
     def op(x, y, name=None):

@@ -8,8 +8,8 @@ paged_attention) needs the same four decisions made the same way:
 - **activation**: the tier is ON when ``FLAGS_use_pallas_kernels`` is
   set AND either the backend is TPU or ``FLAGS_pallas_interpret``
   explicitly opts a CPU process into interpret-mode execution (tests,
-  bench, kernel_smoke — interpret mode is orders of magnitude slower
-  than jnp, so it must never be the silent CPU default);
+  kernel_smoke — interpret mode is orders of magnitude slower than jnp,
+  so it must never be the silent CPU default);
 - **gates**: dtype and tile-alignment checks against the f32 (8, 128)
   sublane/lane tile;
 - **observability**: every kernel SELECTION counts
@@ -84,7 +84,7 @@ def smem_scalar_spec():
 
 # selection counter: {kernel name: trace-time selections} (see module
 # docstring — compiles, not executions).  Tests assert the OFF contract
-# (flag off => no entry moves); bench embeds the delta per suite.
+# (flag off => no entry moves); chip_smoke.py prints the delta per phase.
 kernel_selections: dict = {}
 
 

@@ -95,9 +95,9 @@ def ring_attention_per_device(q, k, v, axis_name: str, is_causal: bool,
 
 
 def _flash_eligible(q) -> bool:
-    from ..core.flags import get_flag
     from ..ops.pallas.flash_attention import flash_attention_supported
-    if not get_flag("use_pallas_kernels"):
+    from ..ops.pallas.support import tier_enabled
+    if not tier_enabled():
         return False
     shape = tuple(q.shape)  # the per-device local shard shape
     return flash_attention_supported(shape, shape, q.dtype)

@@ -1690,13 +1690,13 @@ class Executor:
             [("executor.grads", p.name) for p in params_meta])
         return compiled
 
-    # -- pre-change reference path (bench comparison + oracle) -------------
+    # -- pre-change reference path (oracle) --------------------------------
     # The hot loop below is the Executor.run/_build pair as it stood
     # BEFORE the donated device-resident redesign: feeds bounce through
     # NumPy, every Parameter is read and written back per step, lr and
     # step scalars are re-uploaded per run, and fetches always sync.
-    # bench.py's static suite measures the speedup against it and tests
-    # use it as a numerical oracle.  Not part of the public API.
+    # tests/test_static_fastpath.py uses it as the numerical oracle of the
+    # fast path.  Not part of the public API.
 
     def _run_legacy(self, program, feed=None, fetch_list=None,
                     return_numpy=True, seed=None):

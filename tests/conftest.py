@@ -38,6 +38,17 @@ def _seed_all():
     yield
 
 
+@pytest.fixture
+def kernels_on():
+    """The Pallas tier in interpret mode: off a TPU
+    ``ops.pallas.support.tier_enabled`` wants the opt-in."""
+    from paddle_tpu.core.flags import get_flag, set_flags
+    old = get_flag("pallas_interpret")
+    set_flags({"pallas_interpret": True})
+    yield
+    set_flags({"pallas_interpret": old})
+
+
 def pytest_configure(config):
     # tier-1 runs `-m 'not slow'`: mark tests that duplicate a tools/
     # smoke gate (chaos_smoke, serve_smoke) so they stay runnable

@@ -1,6 +1,7 @@
 """Round-4 API-parity additions, audited against the reference's public
 alias lists (python/paddle/__init__.py, nn/__init__.py,
 nn/functional/__init__.py)."""
+import os
 import re
 
 import numpy as np
@@ -11,10 +12,17 @@ from paddle_tpu import nn
 import paddle_tpu.nn.functional as F
 
 
+# the reference's source tree, where the machine has it
+REFERENCE = "/root/reference/python/paddle"
+needs_reference = pytest.mark.skipif(
+    not os.path.isdir(REFERENCE), reason="reference tree not mounted")
+
+
+@needs_reference
 def test_top_level_alias_audit():
     """Every alias the reference re-exports at top level must exist
     (whitelist: monkey-patch internals)."""
-    src = open("/root/reference/python/paddle/__init__.py").read()
+    src = open(f"{REFERENCE}/__init__.py").read()
     names = set(re.findall(r"^from \.\S+ import (\w+)", src, re.M))
     names -= {"monkey_patch_variable", "monkey_patch_math_varbase",
               "VarBase"}
@@ -22,17 +30,18 @@ def test_top_level_alias_audit():
     assert not missing, missing
 
 
+@needs_reference
 def test_nn_alias_audit():
-    src = open("/root/reference/python/paddle/nn/__init__.py").read()
+    src = open(f"{REFERENCE}/nn/__init__.py").read()
     names = set(re.findall(r"^from \.[\w.]* import (\w+)", src, re.M))
     names = {n for n in names if not n.startswith("_")}
     missing = sorted(n for n in names if not hasattr(nn, n))
     assert not missing, missing
 
 
+@needs_reference
 def test_functional_alias_audit():
-    src = open(
-        "/root/reference/python/paddle/nn/functional/__init__.py").read()
+    src = open(f"{REFERENCE}/nn/functional/__init__.py").read()
     names = set(re.findall(r"^from \.[\w.]* import (\w+)", src, re.M))
     names = {n for n in names if not n.startswith("_")}
     missing = sorted(n for n in names if not hasattr(F, n))

@@ -17,22 +17,13 @@ import pytest
 import paddle_tpu as paddle
 import paddle_tpu.nn.functional as F
 from paddle_tpu import nn
-from paddle_tpu.core.flags import get_flag, set_flags
+from paddle_tpu.core.flags import set_flags
 from paddle_tpu.utils import monitor
 
 eva = importlib.import_module("paddle_tpu.ops.pallas.eva_attention")
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
-
-
-@pytest.fixture
-def kernels_on():
-    """The kernel tier in interpret mode, as the CPU may run it."""
-    old = get_flag("pallas_interpret")
-    set_flags({"pallas_interpret": True})
-    yield
-    set_flags({"pallas_interpret": old})
 
 
 # (window, chunk, seq, kernel block): four windows of one block; three

@@ -3,8 +3,10 @@ compile on TPU — parity there was measured during bring-up).
 
 Modelled on the reference's fused-op tests (test_fused_attention_op.py
 pattern: fused output vs composed-op oracle, fwd + grad)."""
+import glob
 import importlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +24,7 @@ fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 
 
 @pytest.fixture
-def kernel_from_16(monkeypatch):
+def kernel_from_16(monkeypatch, kernels_on):
     """The kernel at the tests' small shapes, which the measured
     crossover leaves to XLA."""
     monkeypatch.setattr(fa, "_KERNEL_FROM", 16)
@@ -259,8 +261,80 @@ def _check_flag_is_gone(monkeypatch, which):
     pytest.param(_check_flag_is_gone, ("dropout_min_seqlen",),
                  id="flag_gone-dropout_min_seqlen"),
 ])
-def test_attention_choice(monkeypatch, check, args):
+def test_attention_choice(monkeypatch, kernels_on, check, args):
     check(monkeypatch, *args)
+
+
+# ---- one gate: every call site asks support.tier_enabled --------------
+
+def _sdpa_takes_kernel():
+    import paddle_tpu.nn.functional as F
+    q = paddle.to_tensor(np.ones((1, 512, 1, 64), np.float32))
+    took = _counted(("pallas.selected.flash_attention",
+                     "attention.xla_path"),
+                    F.scaled_dot_product_attention, q, q, q)
+    assert took in ((1, 0), (0, 1))
+    return took == (1, 0)
+
+
+def _ring_takes_kernel():
+    from paddle_tpu.parallel.ring_attention import _flash_eligible
+    return _flash_eligible(jnp.ones((1, 512, 1, 64), jnp.float32))
+
+
+def _paged_takes_kernel():
+    from paddle_tpu.ops import attention as attn
+
+    def kernel(*a, **k):
+        raise AssertionError("only the gate is asked")
+    kernel.interpret_ok = True
+    attn.register_paged_attention_kernel(kernel)
+    try:
+        return attn.paged_attention_supported(
+            (2, 4, 128), (8, 8, 1, 128), jnp.float32, 8)
+    finally:
+        attn.register_paged_attention_kernel(None)
+
+
+def _eva_takes_kernel():
+    import paddle_tpu.nn.functional as F
+    r = np.random.RandomState(0)
+    qkv = [paddle.to_tensor(r.randn(1, 64, 2, 16).astype(np.float32))
+           for _ in range(3)]
+    vec = [paddle.to_tensor(r.randn(2, 16).astype(np.float32))
+           for _ in range(2)]
+    # the kernels count their blocks where they are traced; XLA's path
+    # (eva_attention_xla) counts nothing
+    return _counted(("pallas.eva.blocks_local",), F.eva_attention,
+                    *qkv, *vec, 16, 4) != (0,)
+
+
+@pytest.mark.parametrize("takes_kernel", [
+    _sdpa_takes_kernel, _ring_takes_kernel, _paged_takes_kernel,
+    _eva_takes_kernel], ids=["sdpa", "ring", "paged", "eva"])
+def test_call_sites_share_the_tier_gate(takes_kernel):
+    """``use_pallas_kernels`` on, CPU backend: XLA's path, unless
+    ``pallas_interpret`` opts the process into interpret mode."""
+    assert get_flag("use_pallas_kernels")
+    assert not get_flag("pallas_interpret")
+    assert not takes_kernel()
+    set_flags({"pallas_interpret": True})
+    try:
+        assert takes_kernel()
+    finally:
+        set_flags({"pallas_interpret": False})
+
+
+def test_tier_flags_are_read_in_one_module():
+    root = os.path.dirname(os.path.abspath(paddle.__file__))
+    read = re.compile(r"get_flags?\(\s*\[?\s*[\"'](use_pallas_kernels|"
+                      r"pallas_interpret)[\"']")
+    readers = set()
+    for path in glob.glob(os.path.join(root, "**", "*.py"), recursive=True):
+        with open(path) as f:
+            if read.search(f.read()):
+                readers.add(os.path.relpath(path, root))
+    assert readers == {os.path.join("ops", "pallas", "support.py")}
 
 
 def test_sdpa_dispatches_to_flash(kernel_from_16):

@@ -220,25 +220,6 @@ def test_set_device_never_substitutes_the_cpu():
         paddle.device._current_device = None
 
 
-@pytest.mark.parametrize("kind,platform,want", [
-    ("TPU v5 lite", "tpu", 197e12),
-    ("cpu", "cpu", 0.0),                    # CPU smoke path: no MFU
-    ("TPU v99", "tpu", None),               # unknown TPU: an error
-], ids=["v5e", "cpu", "unknown_tpu"])
-def test_bench_peak_flops_by_device_kind(kind, platform, want):
-    import sys
-    import types
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    dev = types.SimpleNamespace(device_kind=kind, platform=platform)
-    if want is None:
-        with pytest.raises(ValueError, match="TPU v99"):
-            bench._peak_flops(dev)
-    else:
-        assert bench._peak_flops(dev) == want
-
-
 def test_place_compile_cache(monkeypatch):
     """The environment variable wins and no directory is set in code;
     without it the cache goes to the fixed <checkout>/.jax_cache.  Either

@@ -1,14 +1,14 @@
 #!/usr/bin/env python
-"""CI gate: the static cost model must stay honest on the bench programs.
+"""CI gate: the static cost model must stay honest on two small programs.
 
-Builds the exact `bench.py --suite static` model configs (the MLP
-hot-path micro and LeNet) as static Programs and asserts, in order:
+Builds an MLP (the Executor hot-path micro) and LeNet as static
+Programs and asserts, in order:
 
 1. predicted forward FLOPs within 20% of an INDEPENDENT hand count
    (per-layer 2*M*K*N matmuls + bias/activation terms, conv im2col
    dots — written out below, not derived from the analyzer's tables);
 2. zero `unmodeled` ops/bytes on these programs — the op tables cover
-   the whole bench surface;
+   both programs whole;
 3. liveness: peak memory with donation strictly below the no-donation
    bound (what PR 2's donation buys must be visible statically);
 4. at least one ranked fusion candidate (the MPK-style selection the
@@ -36,7 +36,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-# bench.py --small static-suite configs (bench_static)
+# the two programs' sizes
 MLP_HIDDEN, MLP_DEPTH, MLP_BATCH = 128, 8, 32
 LENET_BATCH = 16
 
@@ -67,7 +67,7 @@ def _fail(msg: str) -> int:
 
 
 def _mlp_hand_flops(batch: int) -> int:
-    """Forward FLOPs of the bench MLP, counted from the layer algebra:
+    """Forward FLOPs of the MLP, counted from the layer algebra:
     each fc is a [B,K]x[K,N] matmul (2*B*K*N) + bias add (B*N); relu is
     one op per element; mse is a handful per output element."""
     h, fl = MLP_HIDDEN, 0

@@ -46,9 +46,8 @@ Usage::
 
     python tools/comm_smoke.py [--steps 8] [--json] [--verbose]
 
-``--json`` prints one JSON line (consumed by ``bench.py --suite
-multichip``, which embeds the exposed-vs-hidden split next to the
-wire-byte ratio).  CI treats a non-zero exit as a regression.
+``--json`` prints one JSON line (the exposed-vs-hidden split next to
+the wire-byte ratio).  CI treats a non-zero exit as a regression.
 """
 from __future__ import annotations
 

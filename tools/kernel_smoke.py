@@ -8,12 +8,12 @@ Asserts, in order:
 1.  **Fused Adam trajectory** — the one-pass kernel tracks the unfused
     ``Adam.update_param`` within 1e-6 over a multi-step trajectory on
     ragged (pad-exercising) shapes;
-2.  **MLP train parity + engagement** — the bench MLP trains with the
+2.  **MLP train parity + engagement** — a small MLP trains with the
     tier ON vs OFF to matching loss trajectories (1e-4 relative), the
     compile record names the selected kernels (fused epilogues + fused
     Adam), and 0 recompiles happen after warmup with the tier on;
-3.  **BERT-tiny realization** — ``Program.analyze()`` on the bench
-    BERT-tiny static training program marks >= 1 fusion candidate
+3.  **BERT-tiny realization** — ``Program.analyze()`` on the benchmark's
+    static BERT program at tiny widths marks >= 1 fusion candidate
     ``realized`` with a kernel name, and the executor's record agrees;
 4.  **Clean composite fallback** — a program whose shapes fail the
     kernel gates (non-tile-aligned widths, AdamW) realizes NOTHING and
@@ -37,8 +37,12 @@ if REPO not in sys.path:
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-BERT_TINY = dict(vocab=1000, hidden=128, layers=2, heads=4, ffn=512,
-                 seq=128, batch=8)
+# BERT-tiny: laid over the benchmark's static BERT cell, whose own builder
+# (benchmark/models/bert_static.py) records the program
+BERT_TINY = dict(vocab_size=1000, hidden_size=128, num_hidden_layers=2,
+                 num_attention_heads=4, intermediate_size=512,
+                 max_position_embeddings=128)
+BERT_TINY_MIX = dict(seq=128, batch=8)
 
 
 def _build_mlp(hidden=128, depth=3, activation="relu", out_width=128):
@@ -106,8 +110,8 @@ def run_checks():
     import jax.numpy as jnp
     import numpy as np
 
-    import bench
     import paddle_tpu as paddle
+    from chip_smoke import STATIC_CELL, build_static, load_parts
     from paddle_tpu import serving
     from paddle_tpu.core.flags import get_flag, set_flags
     from paddle_tpu.observability import explain_compiles
@@ -163,8 +167,9 @@ def run_checks():
                 f"{kernels}")
 
         # -- 3. BERT-tiny: >= 1 candidate realized --------------------
-        bmain, bloss, bfeeds = bench.build_bert_static(**BERT_TINY)
-        bfeed = bfeeds(np.random.RandomState(1))
+        cell, cfg, mix, model_mod = load_parts(STATIC_CELL)
+        bmain, bloss, bfeed = build_static(
+            cell, {**cfg, **BERT_TINY}, {**mix, **BERT_TINY_MIX}, model_mod)
         rep = bmain.analyze(fetch_list=[bloss], top_k=None)
         realized = [c for c in rep.fusion_candidates if c.get("realized")]
         if not realized:
