@@ -16,6 +16,10 @@ under it, and the step executables are tens of megabytes):
   scope of the same name.
 
 PERF.md (section 3) lists which benchmark metric reads which name.
+
+One more vocabulary lives here because the same tools read it: the
+``jax.ad_checkpoint.checkpoint_name`` names of values a rematerialisation
+policy may keep (``RESIDUALS``).  They are names of values, not scopes.
 """
 from __future__ import annotations
 
@@ -56,3 +60,10 @@ KERNELS = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV, EPILOGUE_FWD,
            EPILOGUE_BWD, FUSED_ADAM, PAGED_ATTENTION,
            COLLECTIVE_MATMUL_CHUNK, EVA_FWD, EVA_BWD_DQ)
 
+
+# -- values named for a rematerialisation policy -----------------------------
+# What an attention kernel's backward takes and only its forward kernel can
+# regenerate; ``parallel.recompute`` keeps these across its replay.
+ATTN_OUT = "attn_out"         # the kernel's output, [B, H, L, D]
+ATTN_LSE = "attn_lse"         # its log-sum-exp rows, [B, H, L] float32
+RESIDUALS = (ATTN_OUT, ATTN_LSE)

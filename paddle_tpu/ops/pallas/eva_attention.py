@@ -42,7 +42,7 @@ from .flash_attention import (_BLOCK, _block_loops, _bwd_dkv, _kv_bounds,
                               _p_ds, _prescale, _rows, _scores, _zero_off,
                               _zero_seed)
 from .support import (NEG_INF, dot as _dot, interpret_mode as _interpret,
-                      pltpu)
+                      name_residuals, pltpu)
 
 
 # ---------------------------------------------------------------------------
@@ -342,7 +342,7 @@ def _eva(q, k, v, ks, vs, scale, window, block):
 
 
 def _eva_fwd(q, k, v, ks, vs, scale, window, block):
-    out, lse = _fwd(q, k, v, ks, vs, scale, window, block)
+    out, lse = name_residuals(*_fwd(q, k, v, ks, vs, scale, window, block))
     return out, (q, k, v, ks, vs, out, lse)
 
 

@@ -42,7 +42,7 @@ from jax.experimental import pallas as pl
 
 from ...observability import scopes
 from .support import (NEG_INF, count_kernel_selection, dot as _dot,
-                      interpret_mode as _interpret, pltpu,
+                      interpret_mode as _interpret, name_residuals, pltpu,
                       smem_scalar_spec as _smem_scalar_spec)
 
 
@@ -580,8 +580,8 @@ def _flash(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
 
 def _flash_fwd(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
                dropout_p):
-    out, lse = _fwd(q, k, v, q_off, k_off, seed, scale, causal, blocks,
-                    aligned, dropout_p)
+    out, lse = name_residuals(*_fwd(q, k, v, q_off, k_off, seed, scale,
+                                    causal, blocks, aligned, dropout_p))
     return out, (q, k, v, q_off, k_off, seed, out, lse)
 
 
@@ -606,8 +606,8 @@ def _flash_with_lse(q, k, v, q_off, k_off, scale, blocks):
 
 
 def _flash_with_lse_fwd(q, k, v, q_off, k_off, scale, blocks):
-    out, lse = _fwd(q, k, v, q_off, k_off, _zero_seed(), scale, True,
-                    blocks, False)
+    out, lse = name_residuals(*_fwd(q, k, v, q_off, k_off, _zero_seed(),
+                                    scale, True, blocks, False))
     return (out, lse), (q, k, v, q_off, k_off, out, lse)
 
 

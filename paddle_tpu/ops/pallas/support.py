@@ -27,12 +27,15 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...observability import scopes
+
 __all__ = ["pltpu", "interpret_mode", "tier_enabled", "dtype_ok",
            "smem_scalar_spec", "count_kernel_selection",
-           "kernel_selections", "block_rows", "NEG_INF"]
+           "kernel_selections", "block_rows", "name_residuals", "NEG_INF"]
 
 NEG_INF = -1e30
 
@@ -47,6 +50,18 @@ def dot(a, b, dims):
     return jax.lax.dot_general(a, b, (dims, ((), ())),
                                preferred_element_type=jnp.float32,
                                precision=prec)
+
+
+def name_residuals(out, lse):
+    """(out, lse) of an attention forward kernel under their
+    ``scopes.RESIDUALS`` names, for the forward rule of its custom VJP to
+    return both as primal output and as residuals: one named value, so a
+    checkpoint whose policy keeps the names (``parallel.recompute``) hands
+    the backward kernels what the forward pass produced and its replay
+    runs no forward kernel.  Under no such policy a name is the identity
+    and lowers to nothing."""
+    return (checkpoint_name(out, scopes.ATTN_OUT),
+            checkpoint_name(lse, scopes.ATTN_LSE))
 
 
 def interpret_mode() -> bool:

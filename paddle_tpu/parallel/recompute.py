@@ -6,14 +6,48 @@ re-emits forward ops in the backward program.
 
 TPU-native: ``jax.checkpoint`` (rematerialisation) on the wrapped segment —
 XLA re-runs the segment in the backward pass, trading FLOPs for HBM
-exactly like the reference's checkpoint mechanism."""
+exactly like the reference's checkpoint mechanism.
+
+**What is kept across the replay.**  The segment's arguments, as under any
+checkpoint, and the two values ``observability.scopes.RESIDUALS`` names:
+the ``out`` and ``lse`` of an attention kernel (``flash_fwd``, ``eva_fwd``)
+inside the segment.  The kernel's backward takes both and only the forward
+kernel can regenerate them, and that kernel is the one part of a block
+whose replay costs about three times its FLOPs' worth of time (it runs at a
+third of its roofline where the matmuls around it run at 60-87 % of the
+MXU's peak: PERF.md, PR 29).  ``out`` is exactly as large as the block
+input the checkpoint keeps anyway and ``lse`` a 2 D-th of it, so the policy
+at most doubles what a block pins.  It is not free: jax pins a kept value
+that the forward pass also consumes with a ``reduce_precision``, which
+XLA:TPU executes as one copy of ``out`` a layer, about a fifth of the
+forward kernel it saves.  A segment with no named value inside is the bare
+checkpoint.  This is the only behaviour: no argument selects it."""
 from __future__ import annotations
+
+import functools
 
 import jax
 
 from ..core import autograd, dispatch
 from ..core.tensor import Tensor
 from ..jit.bind import bind, param_list
+from ..observability import scopes
+from ..utils import monitor
+
+_keeps_named = jax.checkpoint_policies.save_only_these_names(
+    *scopes.RESIDUALS)
+
+
+def _keep_attention_residuals(prim, *avals, **params):
+    """The checkpoint's policy: jax's ``save_only_these_names`` over
+    ``scopes.RESIDUALS``, counting ``recompute.kept.<name>`` for each value
+    it keeps (trace time, like ``pallas.selected.*``: once a named value a
+    differentiated segment, so a program's count says how many forward
+    kernels its replay does without)."""
+    keep = _keeps_named(prim, *avals, **params)
+    if keep:
+        monitor.stat_add(f"recompute.kept.{params['name']}")
+    return keep
 
 
 def recompute(function, *args, **kwargs):
@@ -21,7 +55,8 @@ def recompute(function, *args, **kwargs):
 
     ``function`` may be a Layer or a Tensor-level callable; its forward is
     evaluated under jax.checkpoint so residuals are rematerialised in the
-    backward sweep."""
+    backward sweep, all but an attention kernel's ``out`` and ``lse`` (see
+    the module's docstring)."""
     from ..nn.layer_base import Layer
 
     preserve = kwargs.pop("preserve_rng_state", True)
@@ -39,7 +74,7 @@ def recompute(function, *args, **kwargs):
     statics = [a for a in args if not isinstance(a, Tensor)]
     n_p = len(params)
 
-    @jax.checkpoint
+    @functools.partial(jax.checkpoint, policy=_keep_attention_residuals)
     def pure_fn(*arrays):
         p_arr = list(arrays[:n_p])
         in_arr = arrays[n_p:]
