@@ -24,6 +24,7 @@ from .layer.norm import (BatchNorm, BatchNorm1D, BatchNorm2D, BatchNorm3D,  # no
                          GroupNorm, InstanceNorm1D, InstanceNorm2D,
                          InstanceNorm3D, LayerNorm, LocalResponseNorm,
                          RMSNorm, SpectralNorm, SyncBatchNorm)
+from .layer.moe import MoELayer  # noqa: F401
 from .layer.pooling import (AdaptiveAvgPool1D, AdaptiveAvgPool2D,  # noqa
                             AdaptiveMaxPool2D, AvgPool1D, AvgPool2D,
                             AvgPool3D, MaxPool1D, MaxPool2D, MaxPool3D)

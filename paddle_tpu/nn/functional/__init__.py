@@ -1694,6 +1694,105 @@ def eva_attention(query, key, value, mu, phi, window_size, chunk_size,
         query, key, value, mu, phi, op_name="eva_attention")
 
 
+# ---------------------------------------------------------------------------
+# a routed mixture of experts; attention over a learned selection of keys
+# ---------------------------------------------------------------------------
+
+def moe_experts(x, router_weight, w_gate, w_up, w_down, top_k, first_expert=0,
+                norm_topk_prob=True, name=None):
+    """The part of a mixture-of-experts layer that the held experts give
+    (ops/moe.py).  ``x`` [..., H] is the float32 normed stream; the router
+    ``router_weight`` [H, E] spans all E experts and runs in float32; the
+    SwiGLU expert weights are the held slice, stacked: ``w_gate`` /
+    ``w_up`` [held, H, F], ``w_down`` [held, F, H], experts
+    ``first_expert ..`` of the E.  Every assignment of a token to a held
+    expert is computed (no capacity, no dropped token) through grouped
+    matmuls; what the absent experts would add is left out.  Returns
+    float32 of x's shape."""
+    from ...ops.moe import moe_forward
+    return apply(
+        lambda a, r, g, u, d: moe_forward(
+            a, r, g, u, d, top_k=int(top_k), first=int(first_expert),
+            norm_topk_prob=bool(norm_topk_prob)),
+        x, router_weight, w_gate, w_up, w_down, op_name="moe_experts")
+
+
+def _sparse_kernels(query=None, key=None, index_query=None):
+    """Whether the Pallas kernels of ops/pallas/sparse_attention.py take
+    the main attention's shapes and / or the indexer's."""
+    from ...ops.pallas import sparse_attention as _sa
+    from ...ops.pallas.support import tier_enabled
+    ok = tier_enabled()
+    if ok and query is not None:
+        ok = _sa.sparse_attention_supported(
+            tuple(query.shape), tuple(key.shape), as_array(query).dtype)
+    if ok and index_query is not None:
+        ok = _sa.dsa_indexer_supported(tuple(index_query.shape),
+                                       as_array(index_query).dtype)
+    return ok
+
+
+def dsa_indexer(index_query, index_key, index_weight, topk, name=None):
+    """The selection of DeepSeek Sparse Attention's indexer over one row
+    a batch entry: ``index_query`` [B, T, J, d], ``index_key`` [B, T, d]
+    (one key shared by the J heads), ``index_weight`` [B, T, J].  The
+    score of key s for query t is ``sum_j w[t, j] relu(qI[t, j] . kI[s])``
+    in float32; the ``topk`` largest among s <= t are kept (equal scores:
+    the lower position; all of them while there are no more than
+    ``topk``).  Returns ``(mask, lse)``: the selection as int8
+    [B, keys, queries] for ``sparse_attention``, and the logsumexp of each
+    query's selected scores [B, T] for ``dsa_indexer_loss``.  Neither
+    carries a gradient: the indexer learns from its loss."""
+    from ...ops.pallas import sparse_attention as _sa
+    from ...utils import monitor
+    kernels = _sparse_kernels(index_query=index_query)
+    monitor.stat_add("pallas.selected.dsa_indexer" if kernels
+                     else "dsa_indexer.xla_path")
+    fn = _sa.dsa_select if kernels else _sa.dsa_select_xla
+    return apply(lambda q, w, k: fn(q, w, k, int(topk)), index_query,
+                 index_weight, index_key, op_name="dsa_indexer",
+                 nondiff=True)
+
+
+@jax.named_scope(scopes.SPARSE_ATTENTION)
+def sparse_attention(query, key, value, mask, return_lse=False, name=None):
+    """Grouped-query attention over a per-query selection of keys:
+    ``query`` [B, T, A, D], ``key`` / ``value`` [B, T, KV, D] with A a
+    multiple of KV (the repeat is never materialised), ``mask`` int8
+    [B, keys, queries] from ``dsa_indexer`` (it holds the causal rule);
+    scores are scaled by D^-1/2.  Returns [B, T, A, D], and with ``return_lse`` also the softmax's
+    log-sum-exp rows [B, A, T] float32, detached.  Pallas kernels on a
+    TPU for the shapes they support, a blocked XLA path elsewhere."""
+    from ...ops.pallas import sparse_attention as _sa
+    from ...utils import monitor
+    if _sparse_kernels(query, key):
+        fn = _sa.sparse_attention       # counts pallas.selected.* itself
+    else:
+        monitor.stat_add("sparse_attention.xla_path")
+        fn = _sa.sparse_attention_xla
+    out, lse = apply(fn, query, key, value, mask, op_name="sparse_attention")
+    return (out, lse) if return_lse else out
+
+
+@jax.named_scope(scopes.DSA_INDEXER)
+def dsa_indexer_loss(index_query, index_key, index_weight, mask, index_lse,
+                     query, key, lse, name=None):
+    """The indexer's training loss (the sparse training stage of the
+    DeepSeek-V3.2-Exp report): mean over every query of the KL divergence
+    from the main attention's probabilities over the selected keys,
+    summed over the A heads, divided by A and detached, to the softmax of
+    the index scores over the same keys.  ``mask`` and ``index_lse`` are
+    ``dsa_indexer``'s, ``lse`` is ``sparse_attention``'s.  Gradients
+    reach ``index_query``, ``index_key`` and ``index_weight`` only."""
+    from ...ops.pallas import sparse_attention as _sa
+    kernels = _sparse_kernels(query, key, index_query)
+    fn = _sa.dsa_kl if kernels else _sa.dsa_kl_xla
+    return apply(
+        lambda qi, ki, wi, m, li, q, k, l: fn(qi, wi, ki, m, li, q, k, l),
+        index_query, index_key, index_weight, mask, index_lse, query, key,
+        lse, op_name="dsa_indexer_loss")
+
+
 from ..decode import gather_tree  # noqa: F401,E402
 
 from . import activation, common, conv, extension, loss, pooling  # noqa

@@ -42,8 +42,18 @@ EVA_ATTENTION = "eva_attention"
 EVA_POOL = "eva_pool"         # chunk summaries, inside eva_attention
 RMS_NORM = "rms_norm"
 ROPE = "rope"
+MOE = "moe"                   # the routed expert layer, every part of it
+MOE_ROUTER = "moe_router"     # router matmul, softmax, top-k (float32)
+MOE_DISPATCH = "moe_dispatch"  # sort, gather to the experts, combine back
+MOE_EXPERTS = "moe_experts"   # the grouped products over the held experts
+DSA_INDEXER = "dsa_indexer"   # index scores and the indexer's KL loss
+DSA_SELECT = "dsa_select"     # the top-k threshold a query and the mask
+SPARSE_ATTENTION = "sparse_attention"
+QK_NORM = "qk_norm"           # RMSNorm per head on q and k
 FUNCTIONALS = (ATTENTION, LINEAR_CROSS_ENTROPY, GELU, LAYER_NORM, EMBEDDING,
-               DROPOUT, EVA_ATTENTION, EVA_POOL, RMS_NORM, ROPE)
+               DROPOUT, EVA_ATTENTION, EVA_POOL, RMS_NORM, ROPE, MOE,
+               MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, DSA_INDEXER, DSA_SELECT,
+               SPARSE_ATTENTION, QK_NORM)
 
 # -- Pallas kernels ----------------------------------------------------------
 FLASH_FWD = "flash_fwd"
@@ -56,9 +66,18 @@ PAGED_ATTENTION = "paged_attention"
 COLLECTIVE_MATMUL_CHUNK = "collective_matmul_chunk"
 EVA_FWD = "eva_fwd"
 EVA_BWD_DQ = "eva_bwd_dq"     # dq, and the summaries' dk~ / dv~
+DSA_SCORES = "dsa_scores"     # index scores, [keys, queries] tiles
+DSA_THRESHOLD = "dsa_threshold"  # the topk-th largest score a query
+DSA_KL = "dsa_kl"             # head-summed probabilities and the KL term
+DSA_KL_BWD = "dsa_kl_bwd"     # its gradient to qI, kI and the head weights
+SPARSE_FWD = "sparse_fwd"
+SPARSE_BWD_DQ = "sparse_bwd_dq"
+SPARSE_BWD_DKV = "sparse_bwd_dkv"
 KERNELS = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV, EPILOGUE_FWD,
            EPILOGUE_BWD, FUSED_ADAM, PAGED_ATTENTION,
-           COLLECTIVE_MATMUL_CHUNK, EVA_FWD, EVA_BWD_DQ)
+           COLLECTIVE_MATMUL_CHUNK, EVA_FWD, EVA_BWD_DQ, DSA_SCORES,
+           DSA_THRESHOLD, DSA_KL, DSA_KL_BWD, SPARSE_FWD, SPARSE_BWD_DQ,
+           SPARSE_BWD_DKV)
 
 
 # -- values named for a rematerialisation policy -----------------------------

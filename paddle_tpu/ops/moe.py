@@ -1,0 +1,247 @@
+"""A routed mixture of experts, told which experts it holds.
+
+The router spans ALL experts of the layer (``router_w`` [H, E]): logits,
+softmax and top-k in float32, because a routing decision is discrete.
+The expert weights are the slice this device holds, stacked
+``[held, ...]``: experts ``first .. first + held - 1`` of the E.  A
+token's gates are renormalised over its ``top_k`` experts whatever is
+held (``norm_topk_prob``); the layer returns the part of the result that
+the held experts give, and what the absent ones would add is left out
+(the caller of an expert-parallel group sums the parts; on one chip
+nothing stands in for the absent chips).
+
+No capacity, no dropped token.  The (token, slot) assignments are sorted
+by held expert, the absent ones last; the tokens of the held ones are
+gathered into one [rows, H] buffer, the three SwiGLU products run as
+grouped matmuls over it (``jax.lax.ragged_dot``: on a TPU XLA lowers it
+to a Mosaic kernel that walks only the tiles the group sizes cover, so
+its cost follows the load, not the buffer), and the result goes back by
+a gather and a gate-weighted sum over a token's slots.  The buffer has
+to take every assignment of every token (imbalance may send them all
+here), so the tokens are walked in chunks of one sequence of the batch;
+a chunk's buffer is a little over twice what an even router would send
+here where its assignments fit that, and ``tokens * top_k`` rows where
+they do not (``_chunk``: a ``lax.cond`` on the load, in the forward and
+in the backward pass alike).  Both gathers have hand-written
+transposes: a permutation's transpose is the gather by its inverse,
+where autodiff would emit a scatter-add of rows, which a TPU runs a row
+at a time.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from ..observability import scopes
+from ..utils import monitor
+
+__all__ = ["moe_route", "moe_experts", "moe_forward"]
+
+
+@jax.named_scope(scopes.MOE_ROUTER)
+def moe_route(x32, router_w, top_k, norm_topk_prob=True):
+    """x32 [N, H] float32 -> (gates [N, top_k] float32, expert ids
+    [N, top_k] int32 over all E).  The matmul at full float32 precision:
+    a TPU's default for float32 operands is one bfloat16 pass."""
+    logits = jax.lax.dot_general(
+        x32.astype(jnp.float32), router_w.astype(jnp.float32),
+        (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, ids = jax.lax.top_k(probs, top_k)
+    if norm_topk_prob:
+        gates = gates / jnp.sum(gates, -1, keepdims=True)
+    return gates, ids.astype(jnp.int32)
+
+
+# -- the two gathers, with gathers for transposes ---------------------------
+
+@jax.custom_vjp
+def _dispatch(x, tok, pos, live):
+    """x [n, H] -> rows [P, H]: row r is the token of sorted assignment
+    r.  ``pos`` [n, K] is the row of assignment (token, slot), ``live``
+    [n, K] whether its expert is held."""
+    return x[tok]
+
+
+def _dispatch_fwd(x, tok, pos, live):
+    return x[tok], (pos, live)
+
+
+def _dispatch_bwd(res, d_rows):
+    pos, live = res
+    picked = jnp.where(live[..., None], d_rows[pos], 0)
+    return jnp.sum(picked, 1, dtype=jnp.float32).astype(d_rows.dtype), \
+        None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(y, c, tok, slot, pos):
+    """out[n] = sum_k c[n, k] * y[pos[n, k]]; ``c`` [n, K] float32 is the
+    gate, zero where the slot's expert is not held."""
+    return jnp.sum(c[..., None] * y[pos].astype(jnp.float32), 1)
+
+
+def _combine_fwd(y, c, tok, slot, pos):
+    return _combine(y, c, tok, slot, pos), (y, c, tok, slot, pos)
+
+
+def _combine_bwd(res, d_out):
+    y, c, tok, slot, pos = res
+    d_tok = d_out[tok]                                    # [P, H] f32
+    dy = (c[tok, slot][:, None] * d_tok).astype(y.dtype)
+    # the gate's gradient, through the held slots only
+    dc = jnp.where(c != 0, jnp.sum(
+        d_out[:, None, :] * y[pos].astype(jnp.float32), -1), 0.0)
+    return dy, dc, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _grouped(rows, w, sizes):
+    """rows [P, K] @ w [held, K, N] by groups of ``sizes`` rows.  The
+    precision is explicit, as for the Pallas tier's own matmuls
+    (support.dot): XLA's TPU kernel for this takes bfloat16 operands in
+    one pass only, whatever ``jax_default_matmul_precision`` says."""
+    prec = (jax.lax.Precision.DEFAULT if rows.dtype == jnp.bfloat16
+            else jax.lax.Precision.HIGHEST)
+    return jax.lax.ragged_dot(rows, w, sizes, precision=prec,
+                              preferred_element_type=rows.dtype)
+
+
+def moe_experts(x, gates, local, w_gate, w_up, w_down, rows=None):
+    """One chunk.  x [n, H]; gates [n, K] float32; ``local`` [n, K]: the
+    slot's expert as an index into the held stack, ``held`` (one past
+    the last) where it is not held.  ``rows``: the size of the experts'
+    buffer, which must take every held assignment of the chunk (n * K,
+    the default, always does).  -> [n, H] float32."""
+    n, K = local.shape
+    held = w_gate.shape[0]
+    P = n * K
+    R = P if rows is None else rows
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        flat = local.reshape(P)
+        order = jnp.argsort(flat, stable=True)            # held ones first
+        # the row of assignment (token, slot); the absent ones' rows lie
+        # past the held ones' and are never read
+        pos = jnp.minimum(jnp.zeros(P, jnp.int32).at[order].set(
+            jnp.arange(P, dtype=jnp.int32)), R - 1).reshape(n, K)
+        tok, slot = order[:R] // K, order[:R] % K
+        sizes = jnp.sum(flat[:, None] == jnp.arange(held)[None, :], 0,
+                        dtype=jnp.int32)
+        live = local < held
+        x_rows = _dispatch(x, tok, pos, live)
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        g = _grouped(x_rows, w_gate, sizes)
+        u = _grouped(x_rows, w_up, sizes)
+        a = (jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+             ).astype(x_rows.dtype)
+        y = _grouped(a, w_down, sizes)
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        # rows past the last held assignment belong to no group: whatever
+        # the grouped product left there is not read
+        y = jnp.where((jnp.arange(R) < jnp.sum(sizes))[:, None], y, 0)
+        return _combine(y, jnp.where(live, gates, 0.0), tok, slot, pos)
+
+
+# A chunk's buffer for the load it has.  The two gathers cost by the
+# buffer's rows, live or not (one v5e, 8192 tokens of 2048, PERF.md PR 30:
+# the gate-weighted gather back takes 1.07 ms from 16,384 rows and 2.82 ms
+# from 65,536), so the common case should not pay for the worst: a small
+# buffer of ``_ROOM`` times the rows a router that spreads its tokens
+# evenly over all experts sends to the held ones, and every assignment of
+# every token (always enough) where the chunk's load is larger.
+_ROOM = 2.25
+
+
+def _small_buffer(n, top_k, held, total):
+    """Rows of the small buffer of a chunk of n tokens, None where it
+    would not be smaller than the full one (most experts held)."""
+    rows = int(_ROOM * n * top_k * held / total)
+    return rows if rows < n * top_k else None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _chunk(small, x, gates, local, w_gate, w_up, w_down):
+    """``moe_experts`` with the buffer chosen by the chunk's load, and no
+    residual but its arguments: the backward pass runs the forward again
+    inside the branch it takes, so no [rows, H] buffer outlives a chunk
+    and the branch not taken leaves nothing behind."""
+    return _by_load(small, local, w_gate.shape[0], lambda rows: moe_experts(
+        x, gates, local, w_gate, w_up, w_down, rows))
+
+
+def _by_load(small, local, held, fn):
+    """``fn(small)`` where the chunk's held assignments fit ``small``
+    rows, ``fn(None)`` (the full buffer) where they do not."""
+    if small is None:
+        return fn(None)
+    return jax.lax.cond(jnp.sum(local < held) <= small,
+                        functools.partial(fn, small),
+                        functools.partial(fn, None))
+
+
+def _chunk_fwd(small, x, gates, local, w_gate, w_up, w_down):
+    return (_chunk(small, x, gates, local, w_gate, w_up, w_down),
+            (x, gates, local, w_gate, w_up, w_down))
+
+
+def _chunk_bwd(small, res, d_out):
+    x, gates, local, w_gate, w_up, w_down = res
+
+    def grads(rows):
+        _, pull = jax.vjp(
+            lambda x, gates, wg, wu, wd: moe_experts(x, gates, local, wg, wu,
+                                                     wd, rows),
+            x, gates, w_gate, w_up, w_down)
+        return pull(d_out)
+
+    # XLA names the fusions inside a branch after the branch: without a
+    # scope here the backward's gathers would read as plain ``moe`` time
+    # (the grouped products are kernels with names of their own)
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        dx, dgates, dwg, dwu, dwd = _by_load(small, local, w_gate.shape[0],
+                                             grads)
+    return dx, dgates, None, dwg, dwu, dwd
+
+
+_chunk.defvjp(_chunk_fwd, _chunk_bwd)
+
+
+@jax.named_scope(scopes.MOE)
+def moe_forward(x32, router_w, w_gate, w_up, w_down, *, top_k, first,
+                norm_topk_prob=True):
+    """x32 [..., H], the float32 normed stream -> the held experts' part
+    of the layer's result, float32, same shape.  The experts take x in
+    the weights' type."""
+    held, total = w_gate.shape[0], router_w.shape[1]
+    if not 0 <= first <= total - held:
+        raise ValueError(f"experts {first}..{first + held - 1} are not "
+                         f"among the router's {total}")
+    # trace time, as pallas.selected.*: what the last layer traced holds,
+    # and how many layers took the grouped product through ragged_dot
+    monitor.stat_set("moe.experts_held", held)
+    monitor.stat_set("moe.experts_total", total)
+    monitor.stat_set("moe.top_k", top_k)
+    monitor.stat_add("moe.ragged_dot_path")
+    shape = x32.shape
+    H = shape[-1]
+    flat = x32.reshape(-1, H)
+    N = flat.shape[0]
+    chunk = shape[-2] if x32.ndim > 2 else N      # one sequence of the batch
+    gates, ids = moe_route(flat, router_w, top_k, norm_topk_prob)
+    local = ids - first
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    x = flat.astype(w_gate.dtype)
+    small = _small_buffer(chunk, top_k, held, total)
+    out = jax.lax.map(lambda c: _chunk(small, *c, w_gate, w_up, w_down), (
+        x.reshape(N // chunk, chunk, H),
+        gates.reshape(N // chunk, chunk, top_k),
+        local.reshape(N // chunk, chunk, top_k)))
+    return out.reshape(shape)

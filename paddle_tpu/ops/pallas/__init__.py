@@ -16,6 +16,10 @@ The tier:
 - ``eva_attention`` (module) — EVA's windowed attention over exact keys
   and chunk summaries under one softmax, fwd + bwd, behind
   ``F.eva_attention``;
+- ``sparse_attention`` (module) — grouped-query attention over a learned
+  per-query selection of keys (a mask shared by the heads), the
+  indexer's scores, top-k threshold and KL loss, fwd + bwd, behind
+  ``F.dsa_indexer`` / ``F.sparse_attention`` / ``F.dsa_indexer_loss``;
 - ``fused_linear_epilogue``  — matmul + bias/gelu/relu/residual/
   layer_norm epilogues off the cost model's ranked fusion candidates
   (selected by the static Executor's fusion pass);

@@ -102,16 +102,13 @@ def test_eva_attention_fwd_bwd(one_chip, monkeypatch):
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
-def test_evabyte_cell_step_fits_the_chip(one_chip, monkeypatch):
-    """The cell's whole step as the train_step runner builds it (model ->
-    amp O2 -> AdamW with clip -> TrainStep(donate=True)), at the
-    published widths, for the described v5e: it compiles, holds the
-    kernels, and its footprint (as benchmark/run.py counts it) is under
-    the chip's 15.75 GiB."""
+def _cell_step(one_chip, monkeypatch, cell_name, kernel_modules):
+    """A benchmark cell's whole step as the train_step runner builds it
+    (model -> amp O2 -> AdamW with clip -> TrainStep(donate=True)), at the
+    published widths, compiled for the described v5e.  -> (compiled,
+    parameters, cfg, mix, footprint as benchmark/run.py counts it)."""
     import json
-    import os
     import sys
-    import paddle_tpu as paddle
     from paddle_tpu import amp, nn, optimizer
     from paddle_tpu.jit import TrainStep
     from paddle_tpu.optimizer.clip import ClipGradByGlobalNorm
@@ -120,19 +117,18 @@ def test_evabyte_cell_step_fits_the_chip(one_chip, monkeypatch):
         os.path.abspath(__file__))), "benchmark")
     sys.path.insert(0, bench)
     import run as harness
-    cell, cfg, mix, model_mod, _, _ = harness.load_parts(
-        "evabyte.train_bf16_b1_s8192")
-    assert cfg["hidden_size"] == 4096 and mix["seq"] == 8192
+    cell, cfg, mix, model_mod, _, _ = harness.load_parts(cell_name)
     # the code under the jit asks jax for the backend; here that is the CPU
     monkeypatch.setattr(support, "interpret_mode", lambda: False)
-    for name in ("eva_attention", "flash_attention"):
+    for name in kernel_modules:
         mod = importlib.import_module("paddle_tpu.ops.pallas." + name)
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     monkeypatch.setattr(support, "tier_enabled", lambda: True)
-    # no seeded weights are needed to compile: 821 M zeros are quick
-    monkeypatch.setattr(nn.initializer.XavierNormal, "__call__",
-                        lambda self, shape, dtype="float32":
-                        jnp.zeros(shape, dtype))
+    # no seeded weights are needed to compile: zeros are quick
+    for init in (nn.initializer.XavierNormal, nn.initializer.Normal):
+        monkeypatch.setattr(init, "__call__",
+                            lambda self, shape, dtype="float32":
+                            jnp.zeros(shape, dtype))
     model, loss_fn = model_mod.build(cfg, cell["model_args"])
     o = cell["optimizer"]
     opt = optimizer.AdamW(
@@ -149,25 +145,66 @@ def test_evabyte_cell_step_fits_the_chip(one_chip, monkeypatch):
 
     params = tuple(sds(p.data) for p in step._params)
     n = sum(math.prod(p.shape) for p in params)
-    assert 821e6 < n < 822e6
     opt_state = jax.tree.map(sds, jax.eval_shape(
         opt.functional_init, list(params)))
     ids = _sds(one_chip, (mix["batch"], mix["seq"]), jnp.int32)
     compiled = step._build(True).lower(
         params, (), opt_state, jax.tree.map(sds, step._init_scaler_state()),
         _sds(one_chip, (), jnp.float32), (ids,), (ids,)).compile()
-    text = compiled.as_text()
-    # once a layer each: ``parallel.recompute`` keeps ``out`` and ``lse``,
-    # so the replay holds no second ``eva_fwd``
-    for kernel in ("eva_fwd", "eva_bwd_dq", "flash_bwd_dkv"):
-        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) \
-            == cfg["num_hidden_layers"], kernel
     m = compiled.memory_analysis()
     footprint = (m.argument_size_in_bytes + m.output_size_in_bytes
                  - m.alias_size_in_bytes + m.temp_size_in_bytes
                  + m.generated_code_size_in_bytes)
-    print(f"evabyte cell step: {n} parameters, footprint {footprint} bytes "
+    print(f"{cell_name} step: {n} parameters, footprint {footprint} bytes "
           f"({json.dumps({k: getattr(m, k) for k in dir(m) if k.endswith('_in_bytes') and not k.startswith('host')})})")
+    return compiled, n, cfg, mix, footprint
+
+
+def _kernel_count(text, kernel):
+    return len(re.findall(rf"%{kernel}[.\d]* = ", text))
+
+
+def test_evabyte_cell_step_fits_the_chip(one_chip, monkeypatch):
+    """The cell's whole step at the published widths, for the described
+    v5e: it compiles, holds the kernels, and its footprint is under the
+    chip's 15.75 GiB."""
+    compiled, n, cfg, mix, footprint = _cell_step(
+        one_chip, monkeypatch, "evabyte.train_bf16_b1_s8192",
+        ("eva_attention", "flash_attention"))
+    assert cfg["hidden_size"] == 4096 and mix["seq"] == 8192
+    assert 821e6 < n < 822e6
+    text = compiled.as_text()
+    # once a layer each: ``parallel.recompute`` keeps ``out`` and ``lse``,
+    # so the replay holds no second ``eva_fwd``
+    for kernel in ("eva_fwd", "eva_bwd_dq", "flash_bwd_dkv"):
+        assert _kernel_count(text, kernel) == cfg["num_hidden_layers"], kernel
+    assert footprint < 15.75 * 2 ** 30
+
+
+def test_keye_vl2_cell_step_fits_the_chip(one_chip, monkeypatch):
+    """The keye_vl2_30b_a3b.train_bf16_b4_s8192 cell's whole step (16 of
+    128 experts, 4 layers, an eighth of the vocabulary; four rows of
+    8192) for the described v5e: it compiles, holds the sparse-attention
+    and indexer kernels and XLA's own grouped-matmul kernel, and fits."""
+    from paddle_tpu.utils import monitor
+    monitor.stat_reset()
+    compiled, n, cfg, mix, footprint = _cell_step(
+        one_chip, monkeypatch, "keye_vl2_30b_a3b.train_bf16_b4_s8192",
+        ("sparse_attention", "flash_attention"))
+    assert cfg["hidden_size"] == 2048 and mix["seq"] == 8192
+    assert 465e6 < n < 466e6
+    text = compiled.as_text()
+    L = cfg["num_hidden_layers"]
+    # the replay keeps the attention kernel's out and lse: one forward a
+    # layer; the indexer's kernels run in the forward pass and the replay
+    for kernel, calls in (("sparse_fwd", L), ("sparse_bwd_dq", L),
+                          ("sparse_bwd_dkv", L), ("dsa_kl_bwd", L),
+                          ("dsa_scores", 2 * L), ("dsa_threshold", 2 * L)):
+        assert _kernel_count(text, kernel) == calls, kernel
+    assert "ragged-dot" in text
+    stats = monitor.all_stats()
+    assert (stats["recompute.kept.attn_out"], stats["recompute.kept.attn_lse"]
+            ) == (L, L)
     assert footprint < 15.75 * 2 ** 30
 
 

@@ -8,6 +8,7 @@ import glob
 import importlib
 import os
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -178,8 +179,8 @@ def _pallas_calls(jaxpr, out):
 
 @pytest.fixture(scope="module")
 def kernel_calls():
-    """(name, name stack) of every ``pallas_call`` equation the ten
-    sites trace, in interpret mode: no chip needed."""
+    """(name, name stack) of every ``pallas_call`` equation the
+    seventeen sites trace, in interpret mode: no chip needed."""
     from paddle_tpu.ops.pallas.collective_matmul import chunk_matmul
     from paddle_tpu.ops.pallas.fused_adam import fused_adam_update
     from paddle_tpu.ops.pallas.fused_epilogue import fused_linear_epilogue
@@ -203,6 +204,22 @@ def kernel_calls():
             lambda q, k, v, mu, phi: jnp.sum(eva.eva_attention(
                 q, k, v, mu, phi, 16, 4)),
             (0, 1, 2, 3, 4)))(q, q, q, vec, vec).jaxpr, [])
+        # the indexer's two selection kernels, the three of the attention
+        # under its mask, the KL term and its gradient
+        sa = importlib.import_module(
+            "paddle_tpu.ops.pallas.sparse_attention")
+        mp.setattr(sa, "_interpret", lambda: True)
+        q, kv = jnp.ones((1, 32, 4, 16)), jnp.ones((1, 32, 2, 16))
+        qi, ki, wi = (jnp.ones((1, 32, 2, 8)), jnp.ones((1, 32, 8)),
+                      jnp.ones((1, 32, 2)))
+
+        def sparse(q, k, v, qi, ki, wi):
+            mask, idx_lse = sa.dsa_select(qi, wi, ki, 8)
+            out, lse = sa.sparse_attention(q, k, v, mask)
+            return jnp.sum(out) + sa.dsa_kl(qi, wi, ki, mask, idx_lse, q, k,
+                                            lse)
+        found += _pallas_calls(jax.make_jaxpr(jax.grad(
+            sparse, (0, 1, 2, 3, 4, 5)))(q, kv, kv, qi, ki, wi).jaxpr, [])
         x, w = jnp.ones((16, 16)), jnp.ones((16, 128))
         found += _pallas_calls(jax.make_jaxpr(jax.grad(
             lambda x, w, b: jnp.sum(fused_linear_epilogue(
@@ -237,7 +254,7 @@ def test_every_pallas_call_site_carries_its_name(kernel_calls, kernel):
 
 
 def test_no_pallas_call_is_left_without_a_name(kernel_calls):
-    assert len(kernel_calls) == 11
+    assert len(kernel_calls) == 18
     assert {name for name, _ in kernel_calls} == set(scopes.KERNELS)
     src = os.path.join(os.path.dirname(paddle.__file__), "ops", "pallas")
     for path in glob.glob(os.path.join(src, "*.py")):
@@ -438,3 +455,53 @@ def test_import_counter_is_set_by_the_package():
         cwd=os.path.dirname(os.path.dirname(paddle.__file__)))
     assert out.returncode == 0, out.stderr
     assert 0 < float(out.stdout.strip().splitlines()[-1]) < 300
+
+
+# ------------------------- a mixture of experts under sparse attention --
+@pytest.fixture(scope="module")
+def keye_step():
+    """``op_name``s and counters of the keye_vl2 cell's model at its
+    rehearsal widths, through the train_step runner, on the XLA paths."""
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, bench)
+    import run as harness
+    cell, cfg, mix, model_mod, ref, runner = harness.load_parts(
+        "keye_vl2_30b_a3b.train_bf16_b4_s8192", rehearse=True)
+    ring, theta0 = harness.seeded_inputs(cell, cfg, mix, ref, seed=3)
+    monitor.stat_reset()
+    state = runner.build(cell, cfg, model_mod, theta0(), mix)
+    try:
+        step = state["step"]
+        runner.dispatch(state, runner.feed(state, *ring[0]))
+        ids = jnp.asarray(ring[0][0])
+        lowered = step._compiled[True].lower(
+            step._param_arrays(), (), step._opt_state, step._scaler_state,
+            step._lr_device, (ids,), (ids,))
+        return (_op_names(lowered.compile().as_text()),
+                dict(monitor.all_stats()), cfg)
+    finally:
+        runner.close(state)
+
+
+@pytest.mark.parametrize("scope", [
+    scopes.MOE, scopes.MOE_ROUTER, scopes.MOE_DISPATCH, scopes.MOE_EXPERTS,
+    scopes.DSA_INDEXER, scopes.DSA_SELECT, scopes.SPARSE_ATTENTION,
+    scopes.QK_NORM, scopes.RMS_NORM, scopes.ROPE, "moe:MoELayer",
+    "blocks.1:Block"])
+def test_the_expert_and_sparse_attention_scopes_are_in_the_step(keye_step,
+                                                                scope):
+    assert scope in _segments(keye_step[0])
+
+
+def test_the_expert_and_sparse_attention_counters(keye_step):
+    _, stats, cfg = keye_step
+    L = cfg["num_hidden_layers"]
+    assert stats["moe.experts_held"] == cfg["num_experts"]
+    assert stats["moe.experts_total"] == cfg["published"]["num_experts"]
+    assert stats["moe.top_k"] == cfg["num_experts_per_tok"]
+    # once a layer in the forward pass and once in its replay
+    assert stats["moe.ragged_dot_path"] >= L
+    assert stats["sparse_attention.xla_path"] >= L
+    assert stats["dsa_indexer.xla_path"] >= L
+    assert "pallas.selected.sparse_attention" not in stats
