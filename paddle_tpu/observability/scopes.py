@@ -68,21 +68,26 @@ EVA_FWD = "eva_fwd"
 EVA_BWD_DQ = "eva_bwd_dq"     # dq, and the summaries' dk~ / dv~
 DSA_SCORES = "dsa_scores"     # index scores, [keys, queries] tiles
 DSA_THRESHOLD = "dsa_threshold"  # the topk-th largest score a query
-DSA_KL = "dsa_kl"             # head-summed probabilities and the KL term
-DSA_KL_BWD = "dsa_kl_bwd"     # its gradient to qI, kI and the head weights
+DSA_KL = "dsa_kl"             # head-summed probabilities, the KL term and,
+                              # differentiated, its gradient to qI, w and kI
 SPARSE_FWD = "sparse_fwd"
 SPARSE_BWD_DQ = "sparse_bwd_dq"
 SPARSE_BWD_DKV = "sparse_bwd_dkv"
 KERNELS = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV, EPILOGUE_FWD,
            EPILOGUE_BWD, FUSED_ADAM, PAGED_ATTENTION,
            COLLECTIVE_MATMUL_CHUNK, EVA_FWD, EVA_BWD_DQ, DSA_SCORES,
-           DSA_THRESHOLD, DSA_KL, DSA_KL_BWD, SPARSE_FWD, SPARSE_BWD_DQ,
-           SPARSE_BWD_DKV)
+           DSA_THRESHOLD, DSA_KL, SPARSE_FWD, SPARSE_BWD_DQ, SPARSE_BWD_DKV)
 
 
 # -- values named for a rematerialisation policy -----------------------------
-# What an attention kernel's backward takes and only its forward kernel can
+# What a kernel's backward rule takes and only its forward kernel can
 # regenerate; ``parallel.recompute`` keeps these across its replay.
-ATTN_OUT = "attn_out"         # the kernel's output, [B, H, L, D]
+ATTN_OUT = "attn_out"         # an attention kernel's output, [B, H, L, D]
 ATTN_LSE = "attn_lse"         # its log-sum-exp rows, [B, H, L] float32
-RESIDUALS = (ATTN_OUT, ATTN_LSE)
+# dsa_kl's gradient up to the loss's cotangent, float32: the forward pass
+# makes it with the value, and nothing but the backward rule reads it
+DSA_KL_DQ = "dsa_kl_dq"       # to the index queries, [B, J, d, T]
+DSA_KL_DW = "dsa_kl_dw"       # to the head weights, [B, J, T]
+DSA_KL_DK = "dsa_kl_dk"       # to the index key, [B, T, d]
+DSA_KL_GRADS = (DSA_KL_DQ, DSA_KL_DW, DSA_KL_DK)
+RESIDUALS = (ATTN_OUT, ATTN_LSE) + DSA_KL_GRADS

@@ -18,7 +18,8 @@ The tier:
   ``F.eva_attention``;
 - ``sparse_attention`` (module) — grouped-query attention over a learned
   per-query selection of keys (a mask shared by the heads), the
-  indexer's scores, top-k threshold and KL loss, fwd + bwd, behind
+  indexer's scores, top-k threshold and KL loss (six kernels; the loss
+  makes its gradient with its value, in one), fwd + bwd, behind
   ``F.dsa_indexer`` / ``F.sparse_attention`` / ``F.dsa_indexer_loss``;
 - ``fused_linear_epilogue``  — matmul + bias/gelu/relu/residual/
   layer_norm epilogues off the cost model's ranked fusion candidates

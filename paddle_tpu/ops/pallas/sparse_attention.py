@@ -35,9 +35,13 @@ Kernels, all [block, block] tiles of 512:
   whose innermost axis walks the G query heads of one key/value head,
   so that k, v and the mask column are fetched once a group; dk/dv sum
   over the group in VMEM;
-- ``dsa_kl`` / ``dsa_kl_bwd``: the head-summed probabilities (one more
-  QK pass over all A heads), the KL term, and its gradient to qI, kI
-  and w (the index scores are recomputed tile by tile, never read back).
+- ``dsa_kl``: the head-summed probabilities (one more QK pass over all
+  A heads), the KL term and, in the same pass where the call is
+  differentiated, its gradient to qI, kI and w up to the loss's own
+  cotangent: the target is detached, so the gradient is known the moment
+  the value is (the index scores are recomputed tile by tile, never read
+  back).  The three gradients carry ``scopes.RESIDUALS`` names:
+  ``parallel.recompute`` keeps them, and its replay runs no ``dsa_kl``.
 
 The mask itself is one elementwise XLA pass over the scores: above the
 threshold, or equal to it at a position up to the last one kept.
@@ -669,7 +673,7 @@ def sparse_attention(q, k, v, mask, block=None):
 
 
 # ---------------------------------------------------------------------------
-# kernels: the indexer's loss and its gradient
+# kernel: the indexer's loss with its gradient
 # ---------------------------------------------------------------------------
 
 def _kl_tile(j, q_ref, k_ref, lse_ref, qI_ref, w_ref, kI_ref, lseI,
@@ -698,39 +702,28 @@ def _kl_tile(j, q_ref, k_ref, lse_ref, qI_ref, w_ref, kI_ref, lseI,
 
 
 def _kl_kernel(q_ref, k_ref, lse_ref, qI_ref, w_ref, kI_ref, lseI_ref,
-               mask_ref, kl_ref, **statics):
-    i = pl.program_id(1)
-    lseI = lseI_ref[0][0:1, :]
-    bq = lseI.shape[1]
-
-    def body(j, kl):
-        return kl + _kl_tile(j, q_ref, k_ref, lse_ref, qI_ref, w_ref,
-                             kI_ref, lseI, mask_ref, **statics)[0]
-
-    kl = jax.lax.fori_loop(0, i + 1, body, jnp.zeros((1, bq), jnp.float32))
-    kl_ref[0] = jnp.broadcast_to(kl, (8, bq))
-
-
-def _kl_bwd_kernel(q_ref, k_ref, lse_ref, qI_ref, w_ref, kI_ref, lseI_ref,
-                   mask_ref, dqI_ref, dw_ref, dkI_ref, dqI_acc, dw_acc,
-                   dkI_acc, **statics):
-    """d KL / d (qI, w, kI) of one query block, up to the loss's own
-    cotangent: dI = pI - ph on the selected pairs, then the index
-    scores' chain rule head by head."""
+               mask_ref, kl_ref, *grad_refs, **statics):
+    """One query block's KL terms and, where ``grad_refs`` (dqI, dw, dkI
+    and a VMEM accumulator for each) are handed in, d KL / d (qI, w, kI)
+    of the block from the same tiles, up to the loss's own cotangent:
+    dI = pI - ph on the selected pairs, then the index scores' chain
+    rule head by head."""
     i = pl.program_id(1)
     block, J = statics["block"], statics["idx_heads"]
     lseI = lseI_ref[0][0:1, :]
+    bq = lseI.shape[1]
 
-    @pl.when(i == 0)
-    def _():
-        dkI_acc[...] = jnp.zeros_like(dkI_acc)
+    if grad_refs:
+        dqI_ref, dw_ref, dkI_ref, dqI_acc, dw_acc, dkI_acc = grad_refs
 
-    dqI_acc[...] = jnp.zeros_like(dqI_acc)
-    dw_acc[...] = jnp.zeros_like(dw_acc)
+        @pl.when(i == 0)
+        def _():
+            dkI_acc[...] = jnp.zeros_like(dkI_acc)
 
-    def body(j, _):
-        _, dI, kI = _kl_tile(j, q_ref, k_ref, lse_ref, qI_ref, w_ref,
-                             kI_ref, lseI, mask_ref, **statics)
+        dqI_acc[...] = jnp.zeros_like(dqI_acc)
+        dw_acc[...] = jnp.zeros_like(dw_acc)
+
+    def accumulate(j, dI, kI):
         rows = pl.ds(pl.multiple_of(j * block, block), block)
 
         def head(n, dkI):
@@ -746,103 +739,104 @@ def _kl_bwd_kernel(q_ref, k_ref, lse_ref, qI_ref, w_ref, kI_ref, lseI_ref,
 
         dkI_acc[rows, :] += jax.lax.fori_loop(
             0, J, head, jnp.zeros(kI.shape, jnp.float32))
-        return 0
 
-    jax.lax.fori_loop(0, i + 1, body, 0)
-    dqI_ref[0] = dqI_acc[...]
-    dw_ref[0] = dw_acc[...]
+    def body(j, kl):
+        tile_kl, dI, kI = _kl_tile(j, q_ref, k_ref, lse_ref, qI_ref, w_ref,
+                                   kI_ref, lseI, mask_ref, **statics)
+        if grad_refs:
+            accumulate(j, dI, kI)
+        return kl + tile_kl
 
-    @pl.when(i == pl.num_programs(1) - 1)
-    def _():
-        dkI_ref[0] = dkI_acc[...]
+    kl = jax.lax.fori_loop(0, i + 1, body, jnp.zeros((1, bq), jnp.float32))
+    kl_ref[0] = jnp.broadcast_to(kl, (8, bq))
+    if grad_refs:
+        dqI_ref[0] = dqI_acc[...]
+        dw_ref[0] = dw_acc[...]
+
+        @pl.when(i == pl.num_programs(1) - 1)
+        def _():
+            dkI_ref[0] = dkI_acc[...]
 
 
-def _kl_plan(q, k, qIt, block):
+def _kl_call(q, k, lse, qIt, wt, kI, lseI, mask, scale, block, grads):
+    """-> (the KL term of every query [B, T], gradients): with ``grads``
+    (static: the differentiated call) the latter are (dqI^T [B, J, d, T],
+    dw [B, J, T], dkI [B, T, d]), float32, of the KL terms summed over
+    every query; without, the kernel has neither those outputs nor their
+    scratch, and the tuple is empty."""
     B, A, T, D = q.shape
     KV = k.shape[1]
     J, d = qIt.shape[1], qIt.shape[3]
-    statics = dict(block=block, heads=A, idx_heads=J, group=A // KV)
-    in_specs = [
-        pl.BlockSpec((1, A, block, D), lambda b, i: (b, 0, i, 0)),
-        pl.BlockSpec((1, KV, T, D), lambda b, i: (b, 0, 0, 0)),
-        pl.BlockSpec((1, A, 8, block), lambda b, i: (b, 0, 0, i)),
-        pl.BlockSpec((1, J, block, d), lambda b, i: (b, 0, i, 0)),
-        pl.BlockSpec((1, J, 8, block), lambda b, i: (b, 0, 0, i)),
-        pl.BlockSpec((1, T, d), lambda b, i: (b, 0, 0)),
-        pl.BlockSpec((1, 8, block), lambda b, i: (b, 0, i)),
-        pl.BlockSpec((1, T, block), lambda b, i: (b, 0, i)),
-    ]
-    return statics, (B, T // block), in_specs
-
-
-def _kl_operands(q, k, lse, qIt, wt, kI, lseI, mask):
-    B, T = lseI.shape
-    return (q, k, _rows8(lse), qIt, wt, kI,
-            jnp.broadcast_to(lseI[:, None, :], (B, 8, T)), mask)
-
-
-def _kl_rows(q, k, lse, qIt, wt, kI, lseI, mask, scale, block):
-    """-> the KL term of every query [B, T]."""
-    B, T = lseI.shape
-    statics, grid, in_specs = _kl_plan(q, k, qIt, block)
-    kl = pl.pallas_call(
-        functools.partial(_kl_kernel, scale=scale, **statics),
-        grid=grid, in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 8, block), lambda b, i: (b, 0, i)),
-        out_shape=jax.ShapeDtypeStruct((B, 8, T), jnp.float32),
-        compiler_params=_params("parallel", "parallel"),
+    row = pl.BlockSpec((1, 8, block), lambda b, i: (b, 0, i))
+    head_rows = pl.BlockSpec((1, J, 8, block), lambda b, i: (b, 0, 0, i))
+    kI_whole = pl.BlockSpec((1, T, d), lambda b, i: (b, 0, 0))
+    out_specs = [row]
+    out_shape = [jax.ShapeDtypeStruct((B, 8, T), jnp.float32)]
+    scratch = []
+    if grads:
+        out_specs += [
+            pl.BlockSpec((1, J, d, block), lambda b, i: (b, 0, 0, i)),
+            head_rows, kI_whole]
+        out_shape += [jax.ShapeDtypeStruct((B, J, d, T), jnp.float32),
+                      jax.ShapeDtypeStruct((B, J, 8, T), jnp.float32),
+                      jax.ShapeDtypeStruct((B, T, d), jnp.float32)]
+        scratch = [pltpu.VMEM((J, d, block), jnp.float32),
+                   pltpu.VMEM((J, 8, block), jnp.float32),
+                   pltpu.VMEM((T, d), jnp.float32)]
+    kl, *grad = pl.pallas_call(
+        functools.partial(_kl_kernel, scale=scale, block=block, heads=A,
+                          idx_heads=J, group=A // KV),
+        grid=(B, T // block),
+        in_specs=[
+            pl.BlockSpec((1, A, block, D), lambda b, i: (b, 0, i, 0)),
+            pl.BlockSpec((1, KV, T, D), lambda b, i: (b, 0, 0, 0)),
+            pl.BlockSpec((1, A, 8, block), lambda b, i: (b, 0, 0, i)),
+            pl.BlockSpec((1, J, block, d), lambda b, i: (b, 0, i, 0)),
+            head_rows, kI_whole, row,
+            pl.BlockSpec((1, T, block), lambda b, i: (b, 0, i)),
+        ],
+        out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
+        # kI's gradient is summed over the query blocks
+        compiler_params=_params("parallel",
+                                "arbitrary" if grads else "parallel"),
         interpret=_interpret(),
         name=scopes.DSA_KL,
-    )(*_kl_operands(q, k, lse, qIt, wt, kI, lseI, mask))
-    return kl[:, 0]
-
-
-def _kl_grads(q, k, lse, qIt, wt, kI, lseI, mask, scale, block):
-    """-> (dqI^T [B, J, d, T], dw [B, J, 8, T], dkI [B, T, d]), float32, of
-    the KL terms summed over every query."""
-    B, T = lseI.shape
-    statics, grid, in_specs = _kl_plan(q, k, qIt, block)
-    J, d = qIt.shape[1], qIt.shape[3]
-    return pl.pallas_call(
-        functools.partial(_kl_bwd_kernel, scale=scale, **statics),
-        grid=grid, in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, J, d, block), lambda b, i: (b, 0, 0, i)),
-                   pl.BlockSpec((1, J, 8, block), lambda b, i: (b, 0, 0, i)),
-                   pl.BlockSpec((1, T, d), lambda b, i: (b, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((B, J, d, T), jnp.float32),
-                   jax.ShapeDtypeStruct((B, J, 8, T), jnp.float32),
-                   jax.ShapeDtypeStruct((B, T, d), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((J, d, block), jnp.float32),
-                        pltpu.VMEM((J, 8, block), jnp.float32),
-                        pltpu.VMEM((T, d), jnp.float32)],
-        # kI's gradient is summed over the query blocks
-        compiler_params=_params("parallel", "arbitrary"),
-        interpret=_interpret(),
-        name=scopes.DSA_KL_BWD,
-    )(*_kl_operands(q, k, lse, qIt, wt, kI, lseI, mask))
+    )(q, k, _rows8(lse), qIt, wt, kI,
+      jnp.broadcast_to(lseI[:, None, :], (B, 8, T)), mask)
+    if grads:
+        dqIt, dw, dkI = grad
+        grad = (dqIt, dw[:, :, 0], dkI)
+    return kl[:, 0], tuple(grad)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
 def _kl(qIt, wt, kI, q, k, lse, lseI, mask, scale, block):
-    return jnp.mean(_kl_rows(q, k, lse, qIt, wt, kI, lseI, mask, scale,
-                             block))
+    kl, _ = _kl_call(q, k, lse, qIt, wt, kI, lseI, mask, scale, block,
+                     grads=False)
+    return jnp.mean(kl)
 
 
 def _kl_fwd(qIt, wt, kI, q, k, lse, lseI, mask, scale, block):
-    return (_kl(qIt, wt, kI, q, k, lse, lseI, mask, scale, block),
-            (qIt, wt, kI, q, k, lse, lseI, mask))
+    """The value and, from the same pass, the gradient up to the loss's
+    cotangent (the target is detached, so it is known with the value).
+    The three are named, so that a checkpoint whose policy keeps
+    ``scopes.RESIDUALS`` replays no kernel for them; beside them a scalar
+    of each input's type, for the cotangents' casts."""
+    kl, grads = _kl_call(q, k, lse, qIt, wt, kI, lseI, mask, scale, block,
+                         grads=True)
+    grads = name_residuals(*grads, names=scopes.DSA_KL_GRADS)
+    return jnp.mean(kl), (grads, tuple(
+        jnp.zeros((), a.dtype) for a in (qIt, wt, kI)))
 
 
 def _kl_bwd(scale, block, res, g):
-    qIt, wt, kI, q, k, lse, lseI, mask = res
-    dqIt, dw, dkI = _kl_grads(q, k, lse, qIt, wt, kI, lseI, mask, scale,
-                              block)
-    g = g / lseI.size
+    (dqIt, dw, dkI), like = res
+    B, _, T = dw.shape
+    g = g / (B * T)
     # wt is w's rows on 8 sublanes: the whole gradient goes to the first
-    dw = jnp.pad(dw[:, :, :1], ((0, 0), (0, 0), (0, 7), (0, 0)))
-    return ((g * jnp.swapaxes(dqIt, 2, 3)).astype(qIt.dtype),
-            (g * dw).astype(wt.dtype), (g * dkI).astype(kI.dtype),
-            None, None, None, None, None)
+    dw = jnp.pad(dw[:, :, None], ((0, 0), (0, 0), (0, 7), (0, 0)))
+    return tuple((g * x).astype(a.dtype) for x, a in zip(
+        (jnp.swapaxes(dqIt, 2, 3), dw, dkI), like)) + (None,) * 5
 
 
 _kl.defvjp(_kl_fwd, _kl_bwd)

@@ -52,16 +52,16 @@ def dot(a, b, dims):
                                precision=prec)
 
 
-def name_residuals(out, lse):
-    """(out, lse) of an attention forward kernel under their
-    ``scopes.RESIDUALS`` names, for the forward rule of its custom VJP to
-    return both as primal output and as residuals: one named value, so a
+def name_residuals(*values, names=(scopes.ATTN_OUT, scopes.ATTN_LSE)):
+    """``values`` under their ``scopes.RESIDUALS`` names (by default the
+    (out, lse) of an attention forward kernel), for the forward rule of a
+    custom VJP to hand on as residuals: one named value each, so a
     checkpoint whose policy keeps the names (``parallel.recompute``) hands
-    the backward kernels what the forward pass produced and its replay
-    runs no forward kernel.  Under no such policy a name is the identity
-    and lowers to nothing."""
-    return (checkpoint_name(out, scopes.ATTN_OUT),
-            checkpoint_name(lse, scopes.ATTN_LSE))
+    the backward rule what the forward pass produced and its replay runs
+    no kernel for them.  Under no such policy a name is the identity and
+    lowers to nothing."""
+    return tuple(checkpoint_name(v, n) for v, n in zip(values, names,
+                                                       strict=True))
 
 
 def interpret_mode() -> bool:

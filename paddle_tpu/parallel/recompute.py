@@ -9,19 +9,31 @@ XLA re-runs the segment in the backward pass, trading FLOPs for HBM
 exactly like the reference's checkpoint mechanism.
 
 **What is kept across the replay.**  The segment's arguments, as under any
-checkpoint, and the two values ``observability.scopes.RESIDUALS`` names:
-the ``out`` and ``lse`` of an attention kernel (``flash_fwd``, ``eva_fwd``)
-inside the segment.  The kernel's backward takes both and only the forward
-kernel can regenerate them, and that kernel is the one part of a block
-whose replay costs about three times its FLOPs' worth of time (it runs at a
-third of its roofline where the matmuls around it run at 60-87 % of the
-MXU's peak: PERF.md, PR 29).  ``out`` is exactly as large as the block
-input the checkpoint keeps anyway and ``lse`` a 2 D-th of it, so the policy
-at most doubles what a block pins.  It is not free: jax pins a kept value
-that the forward pass also consumes with a ``reduce_precision``, which
-XLA:TPU executes as one copy of ``out`` a layer, about a fifth of the
-forward kernel it saves.  A segment with no named value inside is the bare
-checkpoint.  This is the only behaviour: no argument selects it."""
+checkpoint, and the values ``observability.scopes.RESIDUALS`` names:
+
+- the ``out`` and ``lse`` of an attention kernel (``flash_fwd``, ``eva_fwd``,
+  ``sparse_fwd``) inside the segment.  The kernel's backward takes both and
+  only the forward kernel can regenerate them, and that kernel is the one
+  part of a block whose replay costs about three times its FLOPs' worth of
+  time (it runs at a third of its roofline where the matmuls around it run
+  at 60-87 % of the MXU's peak: PERF.md, PR 29).  ``out`` is exactly as
+  large as the block input the checkpoint keeps anyway and ``lse`` a 2 D-th
+  of it.  It is not free: jax pins a kept value that the forward pass also
+  consumes with a ``reduce_precision``, which XLA:TPU executes as one copy
+  of ``out`` a layer, about a fifth of the forward kernel it saves;
+- the three gradients ``dsa_kl`` makes with the indexer's loss, in one pass
+  (its target is detached, so they are known with the value): 145 MB a Keye
+  layer in float32 against a block input of 268 MB.  Unkept, the replay
+  would run the whole kernel again for them (PERF.md, PR 31).
+
+A segment with no named value inside is the bare checkpoint.  This is the
+only behaviour: no argument selects it.
+
+**Several outputs leave a segment together**, through one
+``optimization_barrier``.  A block that returns its stream and a loss term
+would otherwise have the term's kernel put off by XLA:TPU's scheduler until
+the backward pass needs what it keeps, its operands held all the while
+(1.6 GB in the Keye cell).  A segment with one output has no barrier."""
 from __future__ import annotations
 
 import functools
@@ -42,8 +54,8 @@ def _keep_attention_residuals(prim, *avals, **params):
     """The checkpoint's policy: jax's ``save_only_these_names`` over
     ``scopes.RESIDUALS``, counting ``recompute.kept.<name>`` for each value
     it keeps (trace time, like ``pallas.selected.*``: once a named value a
-    differentiated segment, so a program's count says how many forward
-    kernels its replay does without)."""
+    differentiated segment, so a program's count says how many kernel calls
+    its replay does without)."""
     keep = _keeps_named(prim, *avals, **params)
     if keep:
         monitor.stat_add(f"recompute.kept.{params['name']}")
@@ -55,8 +67,8 @@ def recompute(function, *args, **kwargs):
 
     ``function`` may be a Layer or a Tensor-level callable; its forward is
     evaluated under jax.checkpoint so residuals are rematerialised in the
-    backward sweep, all but an attention kernel's ``out`` and ``lse`` (see
-    the module's docstring)."""
+    backward sweep, all but the values ``scopes.RESIDUALS`` names (see the
+    module's docstring)."""
     from ..nn.layer_base import Layer
 
     preserve = kwargs.pop("preserve_rng_state", True)
@@ -94,7 +106,15 @@ def recompute(function, *args, **kwargs):
             lambda t: t.data if isinstance(t, Tensor) else t, out,
             is_leaf=lambda x: isinstance(x, Tensor))
 
-    return dispatch.apply(pure_fn, *params, *tensors, op_name="recompute")
+    def segment(*arrays):
+        out = pure_fn(*arrays)
+        # see the module's docstring: the output the next segment does not
+        # read is not put off past it
+        if len(jax.tree.leaves(out)) > 1:
+            out = jax.lax.optimization_barrier(out)
+        return out
+
+    return dispatch.apply(segment, *params, *tensors, op_name="recompute")
 
 
 def recompute_sequential(ctx, functions, *args):

@@ -49,6 +49,29 @@ def kernels_on():
     set_flags({"pallas_interpret": old})
 
 
+def _pallas_eqns(jaxpr, within=None):
+    """Every ``pallas_call`` equation of a jaxpr and of the jaxprs in its
+    equations' parameters; with ``within``, only those under an equation
+    of that primitive (``"remat2"``: what ``jax.checkpoint`` replays)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call" and within is None:
+            found.append(eqn)
+        inner_within = None if eqn.primitive.name == within else within
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    found += _pallas_eqns(inner, inner_within)
+    return found
+
+
+@pytest.fixture
+def pallas_eqns():
+    """``pallas_eqns(jaxpr, within=None)``: see ``_pallas_eqns``."""
+    return _pallas_eqns
+
+
 def pytest_configure(config):
     # tier-1 runs `-m 'not slow'`: mark tests that duplicate a tools/
     # smoke gate (chaos_smoke, serve_smoke) so they stay runnable

@@ -168,6 +168,9 @@ def test_evabyte_cell_step_fits_the_chip(one_chip, monkeypatch):
     """The cell's whole step at the published widths, for the described
     v5e: it compiles, holds the kernels, and its footprint is under the
     chip's 15.75 GiB."""
+    from paddle_tpu.observability import scopes
+    from paddle_tpu.utils import monitor
+    monitor.stat_reset()
     compiled, n, cfg, mix, footprint = _cell_step(
         one_chip, monkeypatch, "evabyte.train_bf16_b1_s8192",
         ("eva_attention", "flash_attention"))
@@ -176,8 +179,13 @@ def test_evabyte_cell_step_fits_the_chip(one_chip, monkeypatch):
     text = compiled.as_text()
     # once a layer each: ``parallel.recompute`` keeps ``out`` and ``lse``,
     # so the replay holds no second ``eva_fwd``
+    L = cfg["num_hidden_layers"]
     for kernel in ("eva_fwd", "eva_bwd_dq", "flash_bwd_dkv"):
-        assert _kernel_count(text, kernel) == cfg["num_hidden_layers"], kernel
+        assert _kernel_count(text, kernel) == L, kernel
+    # the policy keeps by name: nothing here carries the indexer's names
+    stats = monitor.all_stats()
+    assert [stats.get(f"recompute.kept.{name}", 0)
+            for name in scopes.RESIDUALS] == [L, L, 0, 0, 0]
     assert footprint < 15.75 * 2 ** 30
 
 
@@ -186,6 +194,7 @@ def test_keye_vl2_cell_step_fits_the_chip(one_chip, monkeypatch):
     128 experts, 4 layers, an eighth of the vocabulary; four rows of
     8192) for the described v5e: it compiles, holds the sparse-attention
     and indexer kernels and XLA's own grouped-matmul kernel, and fits."""
+    from paddle_tpu.observability import scopes
     from paddle_tpu.utils import monitor
     monitor.stat_reset()
     compiled, n, cfg, mix, footprint = _cell_step(
@@ -195,16 +204,19 @@ def test_keye_vl2_cell_step_fits_the_chip(one_chip, monkeypatch):
     assert 465e6 < n < 466e6
     text = compiled.as_text()
     L = cfg["num_hidden_layers"]
-    # the replay keeps the attention kernel's out and lse: one forward a
-    # layer; the indexer's kernels run in the forward pass and the replay
+    # the replay keeps the attention kernel's out and lse and the three
+    # gradients the loss's kernel makes with its value: one forward and
+    # one ``dsa_kl`` a layer, the latter in the forward pass only; the
+    # selection's kernels run in the forward pass and the replay
     for kernel, calls in (("sparse_fwd", L), ("sparse_bwd_dq", L),
-                          ("sparse_bwd_dkv", L), ("dsa_kl_bwd", L),
+                          ("sparse_bwd_dkv", L), ("dsa_kl", L),
+                          ("dsa_kl_bwd", 0),
                           ("dsa_scores", 2 * L), ("dsa_threshold", 2 * L)):
         assert _kernel_count(text, kernel) == calls, kernel
     assert "ragged-dot" in text
     stats = monitor.all_stats()
-    assert (stats["recompute.kept.attn_out"], stats["recompute.kept.attn_lse"]
-            ) == (L, L)
+    assert [stats[f"recompute.kept.{name}"] for name in scopes.RESIDUALS] \
+        == [L] * len(scopes.RESIDUALS)
     assert footprint < 15.75 * 2 ** 30
 
 

@@ -1,6 +1,7 @@
 """``parallel.recompute`` keeps an attention kernel's ``out`` and ``lse``
-across its replay (``observability.scopes.RESIDUALS``): the gradient of a
-recomputed block runs its forward attention kernel once a layer, not
+and the three gradients the indexer's loss makes with its value across its
+replay (``observability.scopes.RESIDUALS``): the gradient of a recomputed
+block runs its forward attention kernel and ``dsa_kl`` once a layer, not
 twice, and is bit for bit the bare checkpoint's.  CPU, interpret mode,
 tiny shapes."""
 import importlib
@@ -28,6 +29,10 @@ recompute_mod = importlib.import_module("paddle_tpu.parallel.recompute")
 
 HID, HEADS, SEQ, LAYERS = 32, 2, 32, 2
 KEPT = tuple(f"recompute.kept.{name}" for name in scopes.RESIDUALS)
+# what a segment counts: nothing, an attention kernel's two, the indexer's too
+NOTHING, ALL = (0,) * len(KEPT), (LAYERS,) * len(KEPT)
+ATTENTION = (LAYERS, LAYERS) + NOTHING[2:]
+IDX_HEADS, IDX_DIM, TOPK = 2, 8, 12
 
 
 class _AttentionBlock(nn.Layer):
@@ -71,6 +76,31 @@ class EvaBlock(_AttentionBlock):
     def attend(self, q, k, v):
         return F.eva_attention(q, k, v, self.mu, self.phi, window_size=16,
                                chunk_size=8)
+
+
+class SparseBlock(_AttentionBlock):
+    """Over ``F.dsa_indexer`` / ``F.sparse_attention``, the indexer's loss
+    (``F.dsa_indexer_loss``) added to the stream: top 12 of rows of 32."""
+    FORWARD = scopes.SPARSE_FWD
+    BACKWARD = (scopes.SPARSE_BWD_DQ, scopes.SPARSE_BWD_DKV)
+
+    def __init__(self):
+        super().__init__()
+        self.idx_q = nn.Linear(HID, IDX_HEADS * IDX_DIM)
+        self.idx_k = nn.Linear(HID, IDX_DIM)
+        self.idx_w = nn.Linear(HID, IDX_HEADS)
+
+    def forward(self, x):
+        B, S = x.shape[0], x.shape[1]
+        h = self.ln(x)
+        qkv = self.qkv(h).reshape([B, S, 3, HEADS, HID // HEADS])
+        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        qI = self.idx_q(h).reshape([B, S, IDX_HEADS, IDX_DIM])
+        kI, w = self.idx_k(h), self.idx_w(h)
+        mask, idx_lse = F.dsa_indexer(qI, kI, w, TOPK)
+        a, lse = F.sparse_attention(q, k, v, mask, return_lse=True)
+        kl = F.dsa_indexer_loss(qI, kI, w, mask, idx_lse, q, k, lse)
+        return x + self.proj(a.reshape([B, S, HID])) + kl
 
 
 class PlainBlock(nn.Layer):
@@ -148,17 +178,45 @@ def _counted(names, fn, *args):
     pytest.param(FlashBlock, _bare_checkpoint, 2, id="flash-bare_checkpoint"),
     pytest.param(EvaBlock, _bare_checkpoint, 2, id="eva-bare_checkpoint"),
     pytest.param(FlashBlock, _no_checkpoint, 1, id="flash-no_checkpoint"),
+    pytest.param(SparseBlock, recompute, 1, id="sparse-recompute"),
+    pytest.param(SparseBlock, _bare_checkpoint, 2,
+                 id="sparse-bare_checkpoint"),
+    pytest.param(SparseBlock, _no_checkpoint, 1, id="sparse-no_checkpoint"),
 ])
 def test_forward_kernels_a_layer(kernels, block, wrap, forwards):
     text = _grad_jaxpr(block, wrap)
     assert _kernel_calls(text, block.FORWARD) == forwards * LAYERS
     for kernel in block.BACKWARD:
         assert _kernel_calls(text, kernel) == LAYERS, kernel
+    if block is SparseBlock:
+        # the loss's kernel makes its gradient with its value: no second
+        # call for the backward, and none in the replay where it is kept
+        assert _kernel_calls(text, scopes.DSA_KL) == forwards * LAYERS
+
+
+@pytest.mark.parametrize("wrap,replayed", [
+    pytest.param(recompute, {scopes.DSA_SCORES, scopes.DSA_THRESHOLD},
+                 id="recompute"),
+    pytest.param(_bare_checkpoint, {
+        scopes.DSA_SCORES, scopes.DSA_THRESHOLD, scopes.SPARSE_FWD,
+        scopes.DSA_KL}, id="bare_checkpoint"),
+])
+def test_the_replayed_segment_holds_no_loss_kernel(kernels, pallas_eqns, wrap,
+                                                   replayed):
+    """What a sparse block's replay (the gradient program's ``remat2``
+    equations) runs besides the backward kernels: the selection (no
+    gradient, nothing named) and, with nothing kept, the attention's
+    forward and ``dsa_kl``."""
+    loss, arrays = _loss_of(SparseBlock, wrap)
+    ran = [eqn.params["name"] for eqn in pallas_eqns(
+        jax.make_jaxpr(jax.grad(loss))(arrays).jaxpr, within="remat2")]
+    assert set(ran) - set(SparseBlock.BACKWARD) == replayed
+    assert len(ran) == LAYERS * (len(replayed) + 2)
 
 
 # (3): the kept values are the ones the replay would have recomputed
-@pytest.mark.parametrize("block", [FlashBlock, EvaBlock],
-                         ids=["flash", "eva"])
+@pytest.mark.parametrize("block", [FlashBlock, EvaBlock, SparseBlock],
+                         ids=["flash", "eva", "sparse"])
 def test_gradients_are_the_bare_checkpoints_bit_for_bit(
         kernels, monkeypatch, block):
     """Op by op, where no compiler fuses the two programs differently
@@ -215,25 +273,54 @@ def test_a_flash_block_keeps_out_and_lse_beside_its_arguments(kernels):
     assert any(f"named '{scopes.ATTN_LSE}'" in why for _, why in res), res
 
 
+# (4) once more: the loss's three gradients, and not the mask
+def test_a_sparse_block_keeps_the_loss_gradients_too(kernels):
+    paddle.seed(0)
+    blk = SparseBlock()
+    x = jnp.ones((2, SEQ, HID), jnp.float32)
+
+    def fn(arrays, x):
+        with bind(blk, arrays), autograd.no_grad():
+            return recompute(blk, Tensor(x)).data.sum()
+
+    res = saved_residuals(fn, param_arrays(blk), x)
+    kept = sorted(aval.shape for aval, why in res if "argument" not in why)
+    assert kept == sorted([
+        (2, HEADS, SEQ), (2, HEADS, SEQ, HID // HEADS),     # lse, out
+        (2, IDX_HEADS, IDX_DIM, SEQ), (2, IDX_HEADS, SEQ),  # dqI^T, dw
+        (2, SEQ, IDX_DIM)]), res                            # dkI
+    for name in scopes.DSA_KL_GRADS:
+        assert any(f"named '{name}'" in why for _, why in res), (name, res)
+
+
 # (6): the counter that says the mechanism engaged
 @pytest.mark.parametrize("block,wrap,expected", [
-    pytest.param(FlashBlock, recompute, (LAYERS, LAYERS),
-                 id="flash-recompute"),
-    pytest.param(EvaBlock, recompute, (LAYERS, LAYERS), id="eva-recompute"),
-    pytest.param(PlainBlock, recompute, (0, 0), id="plain-recompute"),
-    pytest.param(FlashBlock, _bare_checkpoint, (0, 0),
+    pytest.param(FlashBlock, recompute, ATTENTION, id="flash-recompute"),
+    pytest.param(EvaBlock, recompute, ATTENTION, id="eva-recompute"),
+    pytest.param(SparseBlock, recompute, ALL, id="sparse-recompute"),
+    pytest.param(PlainBlock, recompute, NOTHING, id="plain-recompute"),
+    pytest.param(FlashBlock, _bare_checkpoint, NOTHING,
                  id="flash-bare_checkpoint"),
-    pytest.param(FlashBlock, _no_checkpoint, (0, 0),
+    pytest.param(SparseBlock, _bare_checkpoint, NOTHING,
+                 id="sparse-bare_checkpoint"),
+    pytest.param(FlashBlock, _no_checkpoint, NOTHING,
                  id="flash-no_checkpoint"),
 ])
 def test_kept_counters(kernels, block, wrap, expected):
     assert _counted(KEPT, _grad_jaxpr, block, wrap) == expected
 
 
-def test_the_forward_alone_keeps_nothing(kernels):
-    """Nothing is differentiated, so nothing is kept or counted."""
-    loss, arrays = _loss_of(FlashBlock, recompute)
-    assert _counted(KEPT, jax.make_jaxpr(loss), arrays) == (0, 0)
+@pytest.mark.parametrize("block", [FlashBlock, SparseBlock],
+                         ids=["flash", "sparse"])
+def test_the_forward_alone_keeps_nothing(kernels, block):
+    """Nothing is differentiated, so nothing is kept or counted; the
+    loss's kernel is the value-only one (one output)."""
+    loss, arrays = _loss_of(block, recompute)
+    assert _counted(KEPT, jax.make_jaxpr(loss), arrays) == NOTHING
+    if block is SparseBlock:
+        text = str(jax.make_jaxpr(loss)(arrays))
+        assert _kernel_calls(text, scopes.DSA_KL) == LAYERS
+        assert not any(name in text for name in scopes.DSA_KL_GRADS)
 
 
 def test_the_ring_block_names_its_residuals(kernels_on):
@@ -257,6 +344,30 @@ def test_the_ring_block_names_its_residuals(kernels_on):
                        [np.asarray(a).tobytes() for a in grad(q, k, v)])
     assert grads["kept"][0] == 1 and grads["bare"][0] == 2
     assert grads["kept"][1] == grads["bare"][1]
+
+
+@pytest.mark.parametrize("outputs,barriers", [(1, 0), (2, 1)],
+                         ids=["one_output", "two_outputs"])
+def test_several_outputs_leave_a_segment_together(outputs, barriers):
+    """A block's stream and its loss term go through one
+    ``optimization_barrier`` (the term's kernel may not be put off past the
+    next block); a segment with one output has none; gradients are the
+    unwrapped function's bit for bit."""
+    def fn(x):
+        return (F.gelu(x), (x ** 2).mean())[:outputs]
+
+    def loss(wrap):
+        def f(a):
+            out = wrap(Tensor(a))
+            return sum(o.data.sum() for o in out)
+        return f
+
+    a = jnp.asarray(np.random.RandomState(1).randn(4, 8), jnp.float32)
+    wrapped = loss(lambda x: recompute(fn, x))
+    assert str(jax.make_jaxpr(wrapped)(a)).count(
+        "optimization_barrier") == barriers
+    assert np.asarray(jax.grad(wrapped)(a)).tobytes() \
+        == np.asarray(jax.grad(loss(fn))(a)).tobytes()
 
 
 def test_recompute_takes_no_switch():

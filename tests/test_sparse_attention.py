@@ -8,6 +8,7 @@ model through ``TrainStep`` against the reference's loss and gradients.
 import copy
 import importlib
 import os
+import re
 import sys
 
 import jax
@@ -150,6 +151,68 @@ def test_kernels_match_the_xla_path_in_bfloat16(kernels_on):
     kl_x = sa.dsa_kl_xla(x["qI"], x["w"], x["kI"], m_x, l_x, x["q"], x["k"],
                          lse_x)
     assert float(kl_k) == pytest.approx(float(kl_x), rel=2e-2)
+
+
+def _kl_operands(dtype, seq=256, topk=48):
+    """Inputs of the loss over rows of four blocks of 64, the selection
+    and the attention's lse from the XLA path."""
+    x = {n: a.astype(dtype) if n != "w" else a
+         for n, a in _inputs(6, batch=2, seq=seq).items()}
+    mask, idx_lse = sa.dsa_select_xla(x["qI"], x["w"], x["kI"], topk)
+    _, lse = sa.sparse_attention_xla(x["q"], x["k"], x["v"], mask)
+    return x, mask, idx_lse, lse
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["float32", "bfloat16"])
+def test_the_loss_kernel_makes_its_gradients_with_its_value(kernels_on,
+                                                            dtype, tol):
+    """One ``dsa_kl`` call in the differentiated program, none in its
+    backward; value and the three gradients against ``jax.grad`` of the
+    XLA path under an upstream cotangent of 2.5; nothing reaches q or k."""
+    x, mask, idx_lse, lse = _kl_operands(dtype)
+
+    def through(fn, **kw):
+        def loss(qI, w, kI, q, k):
+            return 2.5 * fn(qI, w, kI, mask, idx_lse, q, k, lse, **kw)
+        return jax.value_and_grad(loss, (0, 1, 2, 3, 4))
+
+    args = [x[n] for n in ("qI", "w", "kI", "q", "k")]
+    kernels = through(sa.dsa_kl, block=64)
+    text = str(jax.make_jaxpr(kernels)(*args))
+    assert len(re.findall(r"\bname=dsa_kl\b", text)) == 1
+    assert "dsa_kl_bwd" not in text
+    (got, got_g), (want, want_g) = kernels(*args), through(sa.dsa_kl_xla)(
+        *args)
+    assert float(got) == pytest.approx(float(want), rel=tol)
+    for name, g, r in zip(("qI", "w", "kI"), got_g, want_g):
+        assert g.dtype == r.dtype == x[name].dtype
+        g, r = g.astype(jnp.float32), r.astype(jnp.float32)
+        np.testing.assert_allclose(
+            g, r, rtol=10 * tol, atol=tol * float(jnp.abs(r).max()),
+            err_msg=name)
+    assert not any(bool(jnp.any(g != 0)) for g in got_g[3:])
+
+
+def test_the_undifferentiated_loss_runs_the_value_only_kernel(kernels_on,
+                                                              pallas_eqns):
+    """``_kl`` itself: the same body without the gradient outputs, and the
+    same number bit for bit as the differentiated call's."""
+    x, mask, idx_lse, lse = _kl_operands(jnp.float32)
+
+    def loss(qI):
+        return sa.dsa_kl(qI, x["w"], x["kI"], mask, idx_lse, x["q"], x["k"],
+                         lse, block=64)
+
+    def outputs(fn):
+        return [len(e.outvars)
+                for e in pallas_eqns(jax.make_jaxpr(fn)(x["qI"]).jaxpr)]
+
+    assert outputs(loss) == [1]
+    assert outputs(jax.grad(loss)) == [4]
+    assert np.asarray(loss(x["qI"])).tobytes() == np.asarray(
+        jax.value_and_grad(loss)(x["qI"])[0]).tobytes()
 
 
 def test_the_functionals_take_the_kernels_only_where_the_tier_is_on(
