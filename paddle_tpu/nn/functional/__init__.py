@@ -1085,7 +1085,8 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         dtype = (query.data if hasattr(query, "data") else query).dtype
         eff_dropout = dropout_p if training else 0.0
         if flash_attention_supported(q_shape, k_shape, dtype, attn_mask,
-                                     eff_dropout):
+                                     eff_dropout,
+                                     v_head_dim=value.shape[-1]):
             if eff_dropout > 0.0:
                 fdraw = stable_draw()  # in-trace + replay-stable seed
                 return apply(
@@ -1647,12 +1648,14 @@ def rms_norm(x, weight=None, epsilon=1e-6, unit_offset=False, name=None):
 
 
 @jax.named_scope(scopes.ROPE)
-def rotary_embedding(x, theta=10000.0, position_ids=None, name=None):
-    """Rotary position embedding (Su et al. 2021), rotate-half pairing:
-    ``x`` [B, S, H, D]; the pair (i, i + D/2) of the vector at position p
-    turns by ``p * theta^(-2i/D)``.  ``position_ids`` [S] or [B, S]
-    defaults to 0..S-1.  Angles, cos and sin in float32; the result has
-    the input's type."""
+def rotary_embedding(x, theta=10000.0, position_ids=None, name=None,
+                     interleaved=False):
+    """Rotary position embedding (Su et al. 2021) of ``x`` [B, S, H, D]:
+    pair i of the vector at position p turns by ``p * theta^(-2i/D)``.
+    The pairing is rotate-half, dims (i, i + D/2), or with ``interleaved``
+    neighbours, dims (2i, 2i + 1) (the GPT-J / DeepSeek ``rope_interleave``
+    layout).  ``position_ids`` [S] or [B, S] defaults to 0..S-1.  Angles,
+    cos and sin in float32; the result has the input's type."""
     def _rope(a, *pos):
         D = a.shape[-1]
         p = (pos[0] if pos else jnp.arange(a.shape[1])).astype(jnp.float32)
@@ -1661,6 +1664,11 @@ def rotary_embedding(x, theta=10000.0, position_ids=None, name=None):
         ang = p[..., None] * freq                         # [(B,) S, D/2]
         cos = jnp.cos(ang)[..., None, :]
         sin = jnp.sin(ang)[..., None, :]
+        if interleaved:
+            a1 = a[..., 0::2].astype(jnp.float32)
+            a2 = a[..., 1::2].astype(jnp.float32)
+            return jnp.stack([a1 * cos - a2 * sin, a2 * cos + a1 * sin],
+                             axis=-1).reshape(a.shape).astype(a.dtype)
         a1 = a[..., :D // 2].astype(jnp.float32)
         a2 = a[..., D // 2:].astype(jnp.float32)
         return jnp.concatenate([a1 * cos - a2 * sin, a2 * cos + a1 * sin],
@@ -1699,7 +1707,9 @@ def eva_attention(query, key, value, mu, phi, window_size, chunk_size,
 # ---------------------------------------------------------------------------
 
 def moe_experts(x, router_weight, w_gate, w_up, w_down, top_k, first_expert=0,
-                norm_topk_prob=True, name=None):
+                norm_topk_prob=True, name=None, scoring="softmax",
+                router_bias=None, routed_scaling_factor=1.0, shared=None,
+                train_router=True):
     """The part of a mixture-of-experts layer that the held experts give
     (ops/moe.py).  ``x`` [..., H] is the float32 normed stream; the router
     ``router_weight`` [H, E] spans all E experts and runs in float32; the
@@ -1707,14 +1717,35 @@ def moe_experts(x, router_weight, w_gate, w_up, w_down, top_k, first_expert=0,
     ``w_up`` [held, H, F], ``w_down`` [held, F, H], experts
     ``first_expert ..`` of the E.  Every assignment of a token to a held
     expert is computed (no capacity, no dropped token) through grouped
-    matmuls; what the absent experts would add is left out.  Returns
-    float32 of x's shape."""
+    matmuls; what the absent experts would add is left out.
+
+    ``scoring``: ``"softmax"`` over the experts or ``"sigmoid"`` of each
+    logit.  ``router_bias`` [E]: added to the scores for the SELECTION
+    only, the gates are the chosen scores' (DeepSeek-V3's bias-corrected
+    ``noaux_tc`` selection); it gets no gradient.  The gates are
+    multiplied by ``routed_scaling_factor``.  ``shared``: the three
+    weights ``(gate [H, Fs], up [H, Fs], down [Fs, H])`` of a shared
+    expert, a plain SwiGLU over every token whose result is added
+    unscaled: every member of an expert-parallel group computes it alike,
+    so a sum of the members' parts counts it once a member.
+    ``train_router`` False holds the router still: the gates carry no
+    gradient to ``router_weight`` or, through the router, to ``x`` (for a
+    member that runs without its group: ops/moe.py).  Returns float32 of
+    x's shape."""
     from ...ops.moe import moe_forward
-    return apply(
-        lambda a, r, g, u, d: moe_forward(
+    extras = ([] if router_bias is None else [router_bias]) + list(shared or ())
+
+    def fn(a, r, g, u, d, *rest):
+        rest = list(rest)
+        bias = rest.pop(0) if router_bias is not None else None
+        return moe_forward(
             a, r, g, u, d, top_k=int(top_k), first=int(first_expert),
-            norm_topk_prob=bool(norm_topk_prob)),
-        x, router_weight, w_gate, w_up, w_down, op_name="moe_experts")
+            norm_topk_prob=bool(norm_topk_prob), scoring=scoring,
+            router_bias=bias, scaling=float(routed_scaling_factor),
+            shared=tuple(rest) or None, train_router=bool(train_router))
+
+    return apply(fn, x, router_weight, w_gate, w_up, w_down, *extras,
+                 op_name="moe_experts")
 
 
 def _sparse_kernels(query=None, key=None, index_query=None):
@@ -1791,6 +1822,57 @@ def dsa_indexer_loss(index_query, index_key, index_weight, mask, index_lse,
         lambda qi, ki, wi, m, li, q, k, l: fn(qi, wi, ki, m, li, q, k, l),
         index_query, index_key, index_weight, mask, index_lse, query, key,
         lse, op_name="dsa_indexer_loss")
+
+
+# ---------------------------------------------------------------------------
+# latent attention (appended, as above: what precedes keeps its lines)
+# ---------------------------------------------------------------------------
+
+@jax.named_scope(scopes.MLA_ATTENTION)
+def mla_attention(q_nope, q_rope, k_nope, k_rope, value, name=None):
+    """Causal multi-head latent attention (DeepSeek-V2, section 2.1) over
+    its up-projected training form: a head's key is its own ``k_nope``
+    [B, S, A, Dn] beside ONE rotated key a position that all heads share,
+    ``k_rope`` [B, S, Dr]; ``q_nope`` [B, S, A, Dn], ``q_rope``
+    [B, S, A, Dr] (rotated); ``value`` [B, S, A, Dv], whose width need not
+    be the keys'.  Scores are scaled by ``(Dn + Dr)^-1/2``.  Returns
+    [B, S, A, Dv].
+
+    Where the kernel tier is on and the shapes allow, the flash kernels
+    run over the two key parts (``flash_attention_shared_key``: the shared
+    key is staged once a batch entry, never broadcast to the heads or
+    joined to their keys in HBM); elsewhere XLA's path, which scores the
+    two parts apart as well.  Counted at trace time:
+    ``pallas.selected.mla_attention`` / ``mla_attention.xla_path``."""
+    from ...ops.pallas import flash_attention_supported
+    from ...ops.pallas.flash_attention import flash_attention_shared_key
+    from ...ops.pallas.support import count_kernel_selection, tier_enabled
+    from ...utils import monitor
+    B, S, A, Dn = tuple(q_nope.shape)
+    Dr, Dv = q_rope.shape[-1], value.shape[-1]
+    dtype = as_array(q_nope).dtype
+    if tier_enabled() and flash_attention_supported(
+            (B, S, A, Dn), (B, S, A, Dn), dtype, v_head_dim=Dv,
+            shared_key_dim=Dr):
+        count_kernel_selection("mla_attention")
+        return apply(flash_attention_shared_key, q_nope, q_rope, k_nope,
+                     k_rope, value, op_name="mla_attention")
+
+    scale = float(Dn + Dr) ** -0.5
+
+    def _xla(qn, qr, kn, kr, v):
+        s = (jnp.einsum("blhd,bshd->bhls", qn, kn,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("blhd,bsd->bhls", qr, kr,
+                          preferred_element_type=jnp.float32)) * scale
+        s = jnp.where(jnp.tril(jnp.ones((S, S), bool))[None, None], s,
+                      -jnp.inf)
+        w = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        return jnp.einsum("bhls,bshd->blhd", w, v)
+
+    monitor.stat_add("mla_attention.xla_path")
+    return apply(_xla, q_nope, q_rope, k_nope, k_rope, value,
+                 op_name="mla_attention")
 
 
 from ..decode import gather_tree  # noqa: F401,E402

@@ -50,10 +50,14 @@ DSA_INDEXER = "dsa_indexer"   # index scores and the indexer's KL loss
 DSA_SELECT = "dsa_select"     # the top-k threshold a query and the mask
 SPARSE_ATTENTION = "sparse_attention"
 QK_NORM = "qk_norm"           # RMSNorm per head on q and k
+MOE_SHARED = "moe_shared"     # the shared expert: a SwiGLU over every token
+MLA_ATTENTION = "mla_attention"  # latent attention: one rotated key a row
+                              # beside the heads' own, the flash kernels
+MTP = "mtp"                   # a multi-token-prediction module, its head too
 FUNCTIONALS = (ATTENTION, LINEAR_CROSS_ENTROPY, GELU, LAYER_NORM, EMBEDDING,
                DROPOUT, EVA_ATTENTION, EVA_POOL, RMS_NORM, ROPE, MOE,
                MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, DSA_INDEXER, DSA_SELECT,
-               SPARSE_ATTENTION, QK_NORM)
+               SPARSE_ATTENTION, QK_NORM, MOE_SHARED, MLA_ATTENTION, MTP)
 
 # -- Pallas kernels ----------------------------------------------------------
 FLASH_FWD = "flash_fwd"

@@ -1,14 +1,30 @@
 """A routed mixture of experts, told which experts it holds.
 
 The router spans ALL experts of the layer (``router_w`` [H, E]): logits,
-softmax and top-k in float32, because a routing decision is discrete.
+scores and top-k in float32, because a routing decision is discrete.  The
+scores are a softmax over the experts or a sigmoid of each logit
+(``scoring``); a per-expert bias may be added for the selection alone
+(the gates stay the chosen scores': DeepSeek-V3's bias-corrected
+selection), and the gates may carry a scaling factor.
 The expert weights are the slice this device holds, stacked
 ``[held, ...]``: experts ``first .. first + held - 1`` of the E.  A
 token's gates are renormalised over its ``top_k`` experts whatever is
 held (``norm_topk_prob``); the layer returns the part of the result that
 the held experts give, and what the absent ones would add is left out
 (the caller of an expert-parallel group sums the parts; on one chip
-nothing stands in for the absent chips).
+nothing stands in for the absent chips).  A shared expert, where the
+layer has one, is a plain SwiGLU over every token added to that part:
+every member of a group computes it alike.
+
+A member that runs WITHOUT its group can hold its routers still
+(``train_router=False``: the gates are constants of the backward pass).
+The gates' gradient says which of a token's chosen experts to prefer,
+and needs every chosen expert's <dL/dy, expert(x)>.  One member has its
+own experts' terms; the absent experts' read as zero, so every step
+says "prefer the held ones", and Adam turns that into a held load that
+grows several-fold within tens of steps (PERF.md section 6, PR 32).
+What a member could learn alone is the preference among held experts
+that one token chose together.
 
 No capacity, no dropped token.  The (token, slot) assignments are sorted
 by held expert, the absent ones last; the tokens of the held ones are
@@ -41,19 +57,50 @@ __all__ = ["moe_route", "moe_experts", "moe_forward"]
 
 
 @jax.named_scope(scopes.MOE_ROUTER)
-def moe_route(x32, router_w, top_k, norm_topk_prob=True):
+def moe_route(x32, router_w, top_k, norm_topk_prob=True, scoring="softmax",
+              bias=None, scaling=1.0):
     """x32 [N, H] float32 -> (gates [N, top_k] float32, expert ids
     [N, top_k] int32 over all E).  The matmul at full float32 precision:
-    a TPU's default for float32 operands is one bfloat16 pass."""
+    a TPU's default for float32 operands is one bfloat16 pass.
+    ``scoring``: "softmax" over the experts or "sigmoid" of each logit.
+    ``bias`` [E] joins the scores for the selection only (no gradient
+    reaches it); the gates are the chosen experts' scores, renormalised
+    under ``norm_topk_prob`` and multiplied by ``scaling``.  Equal
+    selection values: the lower expert index."""
     logits = jax.lax.dot_general(
         x32.astype(jnp.float32), router_w.astype(jnp.float32),
         (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, ids = jax.lax.top_k(probs, top_k)
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"scoring {scoring!r}: 'softmax' or 'sigmoid'")
+    if bias is None:
+        gates, ids = jax.lax.top_k(scores, top_k)
+    else:
+        _, ids = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        gates = jnp.take_along_axis(scores, ids, -1)
     if norm_topk_prob:
-        gates = gates / jnp.sum(gates, -1, keepdims=True)
+        total = jnp.sum(gates, -1, keepdims=True)
+        if scoring == "sigmoid":
+            total = total + 1e-20   # DeepSeek-V3's floor: eight may vanish
+        gates = gates / total
+    if scaling != 1.0:
+        gates = gates * scaling
     return gates, ids.astype(jnp.int32)
+
+
+@jax.named_scope(scopes.MOE_SHARED)
+def _shared_expert(x, w_gate, w_up, w_down):
+    """x [N, H] in the weights' type -> a SwiGLU over every token,
+    float32."""
+    def dot(a, w):
+        return jnp.dot(a, w, preferred_element_type=jnp.float32)
+
+    a = (jax.nn.silu(dot(x, w_gate)) * dot(x, w_up)).astype(x.dtype)
+    return dot(a, w_down)
 
 
 # -- the two gathers, with gathers for transposes ---------------------------
@@ -216,10 +263,15 @@ _chunk.defvjp(_chunk_fwd, _chunk_bwd)
 
 @jax.named_scope(scopes.MOE)
 def moe_forward(x32, router_w, w_gate, w_up, w_down, *, top_k, first,
-                norm_topk_prob=True):
+                norm_topk_prob=True, scoring="softmax", router_bias=None,
+                scaling=1.0, shared=None, train_router=True):
     """x32 [..., H], the float32 normed stream -> the held experts' part
     of the layer's result, float32, same shape.  The experts take x in
-    the weights' type."""
+    the weights' type.  ``scoring``, ``router_bias`` and ``scaling`` are
+    ``moe_route``'s; ``shared`` the (gate, up, down) weights of a shared
+    expert, whose result over every token is added.  ``train_router``
+    False: no gradient reaches the router's weight or, through the
+    router, the stream (the header says when)."""
     held, total = w_gate.shape[0], router_w.shape[1]
     if not 0 <= first <= total - held:
         raise ValueError(f"experts {first}..{first + held - 1} are not "
@@ -230,12 +282,19 @@ def moe_forward(x32, router_w, w_gate, w_up, w_down, *, top_k, first,
     monitor.stat_set("moe.experts_total", total)
     monitor.stat_set("moe.top_k", top_k)
     monitor.stat_add("moe.ragged_dot_path")
+    if scoring == "sigmoid":
+        monitor.stat_add("moe.scoring_sigmoid")
+    if shared is not None:
+        monitor.stat_add("moe.shared_experts")
     shape = x32.shape
     H = shape[-1]
     flat = x32.reshape(-1, H)
     N = flat.shape[0]
     chunk = shape[-2] if x32.ndim > 2 else N      # one sequence of the batch
-    gates, ids = moe_route(flat, router_w, top_k, norm_topk_prob)
+    gates, ids = moe_route(flat, router_w, top_k, norm_topk_prob, scoring,
+                           router_bias, scaling)
+    if not train_router:
+        gates = jax.lax.stop_gradient(gates)
     local = ids - first
     local = jnp.where((local >= 0) & (local < held), local, held)
     x = flat.astype(w_gate.dtype)
@@ -244,4 +303,6 @@ def moe_forward(x32, router_w, w_gate, w_up, w_down, *, top_k, first,
         x.reshape(N // chunk, chunk, H),
         gates.reshape(N // chunk, chunk, top_k),
         local.reshape(N // chunk, chunk, top_k)))
+    if shared is not None:
+        out = out.reshape(N, H) + _shared_expert(x, *shared)
     return out.reshape(shape)

@@ -12,7 +12,11 @@ interpret-mode path so the same code runs (slowly) on CPU in tests.
 
 The tier:
 
-- ``flash_attention``        — online-softmax attention, fwd + bwd;
+- ``flash_attention``        — online-softmax attention, fwd + bwd; the
+  values may be narrower than the keys and a part of the key may be one
+  for all heads, staged once a row (latent attention's 128 + 64 / 128,
+  behind ``F.mla_attention``); a head's K and V are staged whole, up to
+  6 MiB a head (over 4 MiB under a stated scoped-VMEM limit);
 - ``eva_attention`` (module) — EVA's windowed attention over exact keys
   and chunk summaries under one softmax, fwd + bwd, behind
   ``F.eva_attention``;

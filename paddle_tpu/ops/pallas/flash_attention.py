@@ -8,7 +8,15 @@ streams KV, and carries the online-softmax running max/sum so the [L, L]
 score matrix never touches HBM.
 
 Layout: [B, L, H, D] in (paddle layout), transposed once to [B, H, L, D]
-around the kernel.  Forward saves per-row logsumexp for the
+around the kernel.  The values may have a width of their own (``Dv``):
+q, k, dq and dk are ``D`` wide, v, out, dO and dv ``Dv``.  A part of the
+key may be one for all heads (``flash_attention_shared_key``: latent
+attention's rotated key, [B, 1, L, Dr]); it is staged once a batch entry
+and never broadcast.  JoyAI-LLM-Flash (the benchmark's) has D 128 + Dr
+64 and Dv 128; published shapes that need ``Dv`` apart from ``D``: plain
+heads of 192 with values of 128 (MiMo-V2-Flash), latent heads of 192 +
+64 with values of 256 (GLM-5), 128 + 64 with 192 (GigaChat3.1), 64 + 64
+with 128 (Mistral-Small-4).  Forward saves per-row logsumexp for the
 recompute-based backward (standard FlashAttention-2 dataflow).  Inside,
 every score tile is held transposed ([block_k, block_q], see the note
 above the kernels).
@@ -76,12 +84,17 @@ _KERNEL_FROM = 512
 def flash_attention_supported(q_shape, k_shape, dtype, attn_mask=None,
                               dropout_p: float = 0.0,
                               block_q: int | None = None,
-                              block_k: int | None = None) -> bool:
+                              block_k: int | None = None,
+                              v_head_dim: int | None = None,
+                              shared_key_dim: int = 0) -> bool:
     """Capability + profitability check: shapes/dtype the kernel handles
     AND where it is taken over XLA's fused attention (from `_KERNEL_FROM`
     positions up, the crossover measured above).  Attention dropout runs
     IN-KERNEL via the Pallas TPU PRNG (tile-seeded, regenerated in the
-    backward) — but only on real TPUs (interpret mode has no PRNG)."""
+    backward) — but only on real TPUs (interpret mode has no PRNG).
+    ``v_head_dim``: the values' width where it is not the keys';
+    ``shared_key_dim``: the width of a key part all heads share, beside
+    the ``D`` of the shapes (`flash_attention_shared_key`)."""
     if attn_mask is not None:
         return False
     if dropout_p > 0.0 and _interpret():
@@ -90,6 +103,7 @@ def flash_attention_supported(q_shape, k_shape, dtype, attn_mask=None,
         return False
     B, Lq, H, D = q_shape
     Lk = k_shape[1]
+    Dv = D if v_head_dim is None else v_head_dim
     if dtype not in (jnp.float32, jnp.bfloat16):
         return False
     if max(Lq, Lk) < _KERNEL_FROM:
@@ -98,14 +112,46 @@ def flash_attention_supported(q_shape, k_shape, dtype, attn_mask=None,
     bq, bk = _resolve_blocks(block_q, block_k, Lq, Lk)
     if Lq % bq or Lk % bk:
         return False
-    if D % 8:  # lane alignment of the head dim
+    if D % 8 or Dv % 8 or shared_key_dim % 8:  # lane alignment
         return False
-    # whole-KV (and, in the dK/dV kernel, whole-Q) staging must fit VMEM
-    # (~16 MB/core); beyond this the sequence belongs on the 'sp' ring
-    itemsize = jnp.dtype(dtype).itemsize
-    if max(Lq, Lk) * D * itemsize > 2 * 1024 * 1024:
-        return False
-    return True
+    # A head's K and V are staged whole (in the dK/dV kernel its Q and dO):
+    # 4 MiB a head (`_STAGED_DEFAULT`: K and V of 2 MiB each, the rule this
+    # gate has always had) inside Mosaic's default scoped VMEM.  Only the
+    # shared-key call goes further, to the 5 MiB that latent attention
+    # stages at 8192 (`_STAGED_SHARED_KEY`), under a stated larger limit
+    # (`_staging`); beyond that the sequence belongs on the 'sp' ring
+    staged = _staged_bytes(max(Lq, Lk), D + shared_key_dim, Dv, dtype)
+    return staged <= (_STAGED_SHARED_KEY if shared_key_dim
+                      else _STAGED_DEFAULT)
+
+
+# Bytes of one head's K and V (or Q and dO) as staged: both at ``D`` = 128
+# fill the 2 MiB each that the gate has always admitted (8192 x 128 bf16),
+# inside Mosaic's default scoped VMEM of 16 MiB with their double buffers.
+# Latent attention at 8192 stages k 2 MiB + the 64 lanes all heads share
+# 1 MiB (2 as laid out) + v 2 MiB a head, 12 MiB double-buffered before the
+# tiles: those calls state a limit of their own, of the chip's 128 MiB.
+# That one shape is what was compiled for the chip and measured there
+# (tests/test_chip_compile.py; PERF.md, PR 32), so the gate admits more
+# than the default for the shared-key call alone and up to its bytes; a
+# plain call of that size (8192 x 192, 12288 x 128 bf16) stays XLA's or
+# the ring's until its crossover is measured.
+_STAGED_DEFAULT = 4 * 1024 * 1024
+_STAGED_SHARED_KEY = 5 * 1024 * 1024
+_VMEM_LIMIT_STAGED = 48 * 1024 * 1024
+
+
+def _staged_bytes(L, D, Dv, dtype):
+    return L * (D + Dv) * jnp.dtype(dtype).itemsize
+
+
+def _staging(L, D, Dv, dtype):
+    """``compiler_params`` of a call that stages ``L`` rows a head: None
+    (Mosaic's defaults, the path every shape up to ``_STAGED_DEFAULT``
+    has always taken) or the larger scoped-VMEM limit."""
+    if _staged_bytes(L, D, Dv, dtype) <= _STAGED_DEFAULT:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT_STAGED)
 
 
 # ---------------------------------------------------------------------------
@@ -258,10 +304,15 @@ def _prescale(x, scale):
     return (x.astype(jnp.float32) * scale).astype(x.dtype)
 
 
-def _scores(k, q, mask, qi, j, q_off_ref, k_off_ref, block_q, block_k):
+def _scores(k, q, mask, qi, j, q_off_ref, k_off_ref, block_q, block_k,
+            shared=None):
     """[BK, BQ] f32 scores of (pre-scaled) q block ``qi`` against k block
-    ``j`` under the block's mask."""
+    ``j`` under the block's mask.  ``shared``: the block's rows of the
+    key part all heads share and the (pre-scaled) query part that meets
+    it, ``(kr [BK, Dr], qr [BQ, Dr])``; their product joins the heads'."""
     s = _dot(k, q, ((1,), (1,)))
+    if shared is not None:
+        s = s + _dot(shared[0], shared[1], ((1,), (1,)))
     if mask == "diagonal":
         return _mask_diagonal(s, qi, j, block_q, block_k)
     if mask == "positions":
@@ -292,15 +343,20 @@ def _once_a_shape(*static_argnums):
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
-                o_ref, lse_ref, *, scale, block_k, seq_k, causal, block_q,
-                aligned, dropout_p):
+def _fwd_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref, *rest,
+                scale, block_k, seq_k, causal, block_q, aligned, dropout_p,
+                shared):
+    if shared:
+        qr_ref, kr_ref, o_ref, lse_ref = rest
+        qr = _prescale(qr_ref[0, 0], scale)               # [BQ, Dr]
+    else:
+        o_ref, lse_ref = rest
     qi = pl.program_id(2)
     q = _prescale(q_ref[0, 0], scale)                     # [BQ, D]
-    bq, d = q.shape
+    bq = q.shape[0]
     m = jnp.full((1, bq), NEG_INF, jnp.float32)
     l = jnp.zeros((1, bq), jnp.float32)
-    acc = jnp.zeros((d, bq), jnp.float32)                 # out^T
+    acc = jnp.zeros((v_ref.shape[-1], bq), jnp.float32)   # out^T
     num_kv = seq_k // block_k
 
     def body(j, carry, mask):
@@ -308,7 +364,8 @@ def _fwd_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
         k = _rows(k_ref, j, block_k)                      # [BK, D]
         v = _rows(v_ref, j, block_k)
         s = _scores(k, q, mask, qi, j, q_off_ref, k_off_ref, block_q,
-                    block_k)                              # [BK, BQ]
+                    block_k, (_rows(kr_ref, j, block_k), qr)
+                    if shared else None)                  # [BK, BQ]
         m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_new)
         if mask == "positions":
@@ -340,49 +397,65 @@ def _fwd_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
     lse_ref[0, 0] = jnp.broadcast_to(lse, (8, bq))
 
 
-def _qkv_fwd_specs(block_q, Lk, D):
+def _qkv_fwd_specs(block_q, Lk, D, Dv, Dr=0):
+    """In-specs of the forward and dq kernels; with ``Dr`` also the
+    query part [B, H, Lq, Dr] that meets the shared key [B, 1, Lk, Dr],
+    which is staged once a batch entry: its block index does not move
+    with the head."""
+    shared = [
+        pl.BlockSpec((1, 1, block_q, Dr), lambda b, h, i: (b, h, i, 0)),
+        pl.BlockSpec((1, 1, Lk, Dr), lambda b, h, i: (b, 0, 0, 0)),
+    ] if Dr else []
     return [
         _smem_scalar_spec(),
         _smem_scalar_spec(),
         _smem_scalar_spec(),
         pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
         pl.BlockSpec((1, 1, Lk, D), lambda b, h, i: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, Lk, D), lambda b, h, i: (b, h, 0, 0)),
-    ]
+        pl.BlockSpec((1, 1, Lk, Dv), lambda b, h, i: (b, h, 0, 0)),
+    ] + shared
+
+
+def _shared_width(shared):
+    return shared[0].shape[-1] if shared else 0
 
 
 def _fwd(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
-         dropout_p=0.0):
-    """q/k/v: [B, H, L, D] → (out [B,H,Lq,D], lse [B,H,Lq])."""
+         dropout_p=0.0, shared=None):
+    """q/k [B, H, L, D], v [B, H, Lk, Dv] → (out [B,H,Lq,Dv], lse
+    [B,H,Lq]).  ``shared``: ``(qr [B, H, Lq, Dr], kr [B, 1, Lk, Dr])``,
+    a key part all heads share and the query part that meets it."""
     _count_blocks(q.shape[2], k.shape[2], *blocks, causal, aligned)
     return _fwd_call(q, k, v, q_off, k_off, seed, scale, causal, blocks,
-                     aligned, dropout_p, _interpret())
+                     aligned, dropout_p, _interpret(), shared=shared)
 
 
 @_once_a_shape(6, 7, 8, 9, 10, 11)
 def _fwd_call(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
-              dropout_p, interpret):
+              dropout_p, interpret, shared=None):
     B, H, Lq, D = q.shape
-    Lk = k.shape[2]
+    Lk, Dv, Dr = k.shape[2], v.shape[3], _shared_width(shared)
     block_q, block_k = blocks
     kernel = functools.partial(_fwd_kernel, scale=scale, block_k=block_k,
                                seq_k=Lk, causal=causal, block_q=block_q,
-                               aligned=aligned, dropout_p=dropout_p)
+                               aligned=aligned, dropout_p=dropout_p,
+                               shared=bool(shared))
     out, lse = pl.pallas_call(
         kernel,
         grid=(B, H, Lq // block_q),
-        in_specs=_qkv_fwd_specs(block_q, Lk, D),
+        in_specs=_qkv_fwd_specs(block_q, Lk, D, Dv, Dr),
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, 8, block_q), lambda b, h, i: (b, h, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Lq, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Lq, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, 8, Lq), jnp.float32),
         ],
         interpret=interpret,
+        compiler_params=_staging(Lk, D + Dr, Dv, q.dtype),
         name=scopes.FLASH_FWD,
-    )(q_off, k_off, seed, q, k, v)
+    )(q_off, k_off, seed, q, k, v, *(shared or ()))
     # compact [B, H, Lq] is the residual / public lse shape; the 8-sublane
     # replication exists only at the kernel boundary
     return out, lse[:, :, 0, :]
@@ -408,149 +481,200 @@ def _p_ds(s, mask, lse, do, v, delta, seed_ref, qi, j, dropout_p):
 
 
 def _bwd_dq_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
-                   do_ref, lse_ref, delta_ref, dq_ref, *, scale, block_k,
-                   seq_k, causal, block_q, aligned, dropout_p):
+                   *rest, scale, block_k, seq_k, causal, block_q, aligned,
+                   dropout_p, shared):
+    if shared:
+        (qr_ref, kr_ref, do_ref, lse_ref, delta_ref, dq_ref,
+         dqr_ref) = rest
+        qr = _prescale(qr_ref[0, 0], scale)               # [BQ, Dr]
+    else:
+        do_ref, lse_ref, delta_ref, dq_ref = rest
     qi = pl.program_id(2)
     q = _prescale(q_ref[0, 0], scale)                     # [BQ, D]
     do = do_ref[0, 0]
     lse = lse_ref[0, 0][0:1, :]                           # [1, BQ]
     delta = delta_ref[0, 0][0:1, :]
     bq, d = q.shape
-    dq = jnp.zeros((d, bq), jnp.float32)                  # dq^T
+    # dq^T, and under it the transposed gradient of the shared part
+    dq = (jnp.zeros((d, bq), jnp.float32),
+          jnp.zeros((qr.shape[1], bq), jnp.float32) if shared else None)
     num_kv = seq_k // block_k
 
     def body(j, dq, mask):
         k = _rows(k_ref, j, block_k)
         v = _rows(v_ref, j, block_k)
+        kr = _rows(kr_ref, j, block_k) if shared else None
         s = _scores(k, q, mask, qi, j, q_off_ref, k_off_ref, block_q,
-                    block_k)
+                    block_k, (kr, qr) if shared else None)
         _, ds = _p_ds(s, mask, lse, do, v, delta, seed_ref, qi, j,
                       dropout_p)
-        return dq + _dot(k, ds.astype(k.dtype), ((0,), (0,)))
+        ds = ds.astype(k.dtype)
+        return (dq[0] + _dot(k, ds, ((0,), (0,))),
+                dq[1] + _dot(kr, ds, ((0,), (0,))) if shared else None)
 
     full, end = _kv_bounds(qi, block_q, block_k, num_kv)
     dq = _block_loops(body, dq, num_kv, causal, aligned,
                       ((0, full, None), (full, end, "diagonal")))
     # s was taken against scale * q: the chain rule's scale, once
-    dq_ref[0, 0] = (dq * scale).T.astype(dq_ref.dtype)
+    dq_ref[0, 0] = (dq[0] * scale).T.astype(dq_ref.dtype)
+    if shared:
+        dqr_ref[0, 0] = (dq[1] * scale).T.astype(dqr_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
-                    do_ref, lse_ref, delta_ref, dk_ref, dv_ref, *, scale,
-                    block_q, seq_q, causal, block_k, aligned, dropout_p):
+                    *rest, scale, block_q, seq_q, causal, block_k, aligned,
+                    dropout_p, shared):
+    if shared:
+        (qr_ref, kr_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+         dkr_ref) = rest
+        kr = kr_ref[0, 0]                                 # [BK, Dr]
+    else:
+        do_ref, lse_ref, delta_ref, dk_ref, dv_ref = rest
     kj = pl.program_id(2)
     k = k_ref[0, 0]                                       # [BK, D]
     v = v_ref[0, 0]
-    bk, d = k.shape
-    dk = jnp.zeros((bk, d), jnp.float32)
-    dv = jnp.zeros((bk, d), jnp.float32)
+    dk = jnp.zeros(k.shape, jnp.float32)
+    dv = jnp.zeros(v.shape, jnp.float32)
+    # this head's part of the shared key's gradient
+    dkr = jnp.zeros(kr.shape, jnp.float32) if shared else None
     num_q = seq_q // block_q
 
     def body(i, carry, mask):
-        dk, dv = carry
+        dk, dv, dkr = carry
         q = _prescale(_rows(q_ref, i, block_q), scale)    # [BQ, D]
+        qr = _prescale(_rows(qr_ref, i, block_q), scale) if shared else None
         do = _rows(do_ref, i, block_q)
         cols = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
         lse = lse_ref[0, 0, 0:1, cols]                    # [1, BQ]
         delta = delta_ref[0, 0, 0:1, cols]
         s = _scores(k, q, mask, i, kj, q_off_ref, k_off_ref, block_q,
-                    block_k)
+                    block_k, (kr, qr) if shared else None)
         # fwd tile (qi=i, j=kj): identical seed -> identical mask
         u, ds = _p_ds(s, mask, lse, do, v, delta, seed_ref, i, kj,
                       dropout_p)
         dv = dv + _dot(u.astype(do.dtype), do, ((1,), (0,)))
         # against the pre-scaled q: dk needs no scale of its own
-        dk = dk + _dot(ds.astype(q.dtype), q, ((1,), (0,)))
-        return dk, dv
+        ds = ds.astype(q.dtype)
+        dk = dk + _dot(ds, q, ((1,), (0,)))
+        if shared:
+            dkr = dkr + _dot(ds, qr, ((1,), (0,)))
+        return dk, dv, dkr
 
     start, full = _q_bounds(kj, block_q, block_k, num_q)
-    dk, dv = _block_loops(
-        body, (dk, dv), num_q, causal, aligned,
+    dk, dv, dkr = _block_loops(
+        body, (dk, dv, dkr), num_q, causal, aligned,
         ((start, full, "diagonal"), (full, num_q, None)))
     dk_ref[0, 0] = dk.astype(dk_ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+    if shared:
+        dkr_ref[0, 0] = dkr
 
 
 def _bwd_dq(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale, causal,
-            blocks, aligned, dropout_p):
+            blocks, aligned, dropout_p, shared=None):
     _count_blocks(q.shape[2], k.shape[2], *blocks, causal, aligned)
     return _bwd_dq_call(q, k, v, q_off, k_off, seed, do, lse8, delta8,
                         scale, causal, blocks, aligned, dropout_p,
-                        _interpret())
+                        _interpret(), shared=shared)
 
 
 @_once_a_shape(9, 10, 11, 12, 13, 14)
 def _bwd_dq_call(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale,
-                 causal, blocks, aligned, dropout_p, interpret):
+                 causal, blocks, aligned, dropout_p, interpret, shared=None):
+    """-> dq, or with ``shared`` (dq, dqr)."""
     B, H, Lq, D = q.shape
-    Lk = k.shape[2]
+    Lk, Dv, Dr = k.shape[2], v.shape[3], _shared_width(shared)
+
+    def rows(width):
+        return pl.BlockSpec((1, 1, block_q, width),
+                            lambda b, h, i: (b, h, i, 0))
+
     block_q, block_k = blocks
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, block_k=block_k,
                           seq_k=Lk, causal=causal, block_q=block_q,
-                          aligned=aligned, dropout_p=dropout_p),
+                          aligned=aligned, dropout_p=dropout_p,
+                          shared=bool(shared)),
         grid=(B, H, Lq // block_q),
-        in_specs=_qkv_fwd_specs(block_q, Lk, D) + [
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
+        in_specs=_qkv_fwd_specs(block_q, Lk, D, Dv, Dr) + [
+            rows(Dv),
             pl.BlockSpec((1, 1, 8, block_q), lambda b, h, i: (b, h, 0, i)),
             pl.BlockSpec((1, 1, 8, block_q), lambda b, h, i: (b, h, 0, i)),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, D),
-                               lambda b, h, i: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Lq, D), q.dtype),
+        out_specs=[rows(D)] + ([rows(Dr)] if shared else []),
+        out_shape=[jax.ShapeDtypeStruct((B, H, Lq, D), q.dtype)] + (
+            [jax.ShapeDtypeStruct((B, H, Lq, Dr), q.dtype)] if shared
+            else []),
         interpret=interpret,
+        compiler_params=_staging(Lk, D + Dr, Dv, q.dtype),
         name=scopes.FLASH_BWD_DQ,
-    )(q_off, k_off, seed, q, k, v, do, lse8, delta8)
+    )(q_off, k_off, seed, q, k, v, *(shared or ()), do, lse8, delta8)
+    return tuple(out) if shared else out[0]
 
 
 def _bwd_dkv(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale, causal,
-             blocks, aligned, dropout_p):
+             blocks, aligned, dropout_p, shared=None):
     _count_blocks(q.shape[2], k.shape[2], *blocks, causal, aligned)
     return _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8,
                          scale, causal, blocks, aligned, dropout_p,
-                         _interpret())
+                         _interpret(), shared=shared)
 
 
 @_once_a_shape(9, 10, 11, 12, 13, 14)
 def _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale,
-                  causal, blocks, aligned, dropout_p, interpret):
+                  causal, blocks, aligned, dropout_p, interpret, shared=None):
+    """-> (dk, dv), or with ``shared`` (dk, dv, each head's float32 part
+    of the shared key's gradient [B, H, Lk, Dr])."""
     B, H, Lq, D = q.shape
-    Lk = k.shape[2]
+    Lk, Dv, Dr = k.shape[2], v.shape[3], _shared_width(shared)
     block_q, block_k = blocks
-    return pl.pallas_call(
+
+    def whole(width):
+        return pl.BlockSpec((1, 1, Lq, width), lambda b, h, j: (b, h, 0, 0))
+
+    def rows(width):
+        return pl.BlockSpec((1, 1, block_k, width),
+                            lambda b, h, j: (b, h, j, 0))
+
+    out = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
                           seq_q=Lq, causal=causal, block_k=block_k,
-                          aligned=aligned, dropout_p=dropout_p),
+                          aligned=aligned, dropout_p=dropout_p,
+                          shared=bool(shared)),
         grid=(B, H, Lk // block_k),
         in_specs=[
             _smem_scalar_spec(),
             _smem_scalar_spec(),
             _smem_scalar_spec(),
-            pl.BlockSpec((1, 1, Lq, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, Lq, D), lambda b, h, j: (b, h, 0, 0)),
+            whole(D),
+            rows(D),
+            rows(Dv),
+        ] + ([whole(Dr), pl.BlockSpec((1, 1, block_k, Dr),
+                                      lambda b, h, j: (b, 0, j, 0))]
+             if shared else []) + [
+            whole(Dv),
             pl.BlockSpec((1, 1, 8, Lq), lambda b, h, j: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, 8, Lq), lambda b, h, j: (b, h, 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, j: (b, h, j, 0)),
-        ],
+        out_specs=[rows(D), rows(Dv)] + ([rows(Dr)] if shared else []),
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Lk, D), k.dtype),
-            jax.ShapeDtypeStruct((B, H, Lk, D), v.dtype),
-        ],
+            jax.ShapeDtypeStruct((B, H, Lk, Dv), v.dtype),
+        ] + ([jax.ShapeDtypeStruct((B, H, Lk, Dr), jnp.float32)]
+             if shared else []),
         interpret=interpret,
+        compiler_params=_staging(Lq, D + Dr, Dv, q.dtype),
         name=scopes.FLASH_BWD_DKV,
-    )(q_off, k_off, seed, q, k, v, do, lse8, delta8)
+    )(q_off, k_off, seed, q, k, v, *(shared or ()), do, lse8, delta8)
+    return tuple(out)
 
 
 def _bwd(q, k, v, q_off, k_off, seed, out, lse, do, dlse, scale, causal,
-         blocks, aligned, dropout_p=0.0):
-    """Full backward.  The lse cotangent folds into delta: with
-    P = exp(S - lse) row-normalized, dS = P * (dP_rows - delta + dlse)
-    since d lse / dS = P."""
+         blocks, aligned, dropout_p=0.0, shared=None):
+    """Full backward -> (dq, dk, dv), or with ``shared`` ((dq, dqr),
+    (dk, each head's part of dkr), dv).  The lse cotangent folds into
+    delta: with P = exp(S - lse) row-normalized,
+    dS = P * (dP_rows - delta + dlse) since d lse / dS = P."""
     B, H, Lq, _ = q.shape
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)                              # [B, H, Lq]
@@ -561,9 +685,9 @@ def _bwd(q, k, v, q_off, k_off, seed, out, lse, do, dlse, scale, causal,
     delta8 = jnp.broadcast_to(delta[:, :, None, :], (B, H, 8, Lq))
     args = (q, k, v, q_off, k_off, seed, do, lse8, delta8, scale, causal,
             blocks, aligned, dropout_p)
-    dq = _bwd_dq(*args)
-    dk, dv = _bwd_dkv(*args)
-    return dq, dk, dv
+    dq = _bwd_dq(*args, shared=shared)
+    dk, dv, *dkr = _bwd_dkv(*args, shared=shared)
+    return dq, ((dk, *dkr) if shared else dk), dv
 
 
 # ---------------------------------------------------------------------------
@@ -622,6 +746,36 @@ def _flash_with_lse_bwd(scale, blocks, res, cts):
 _flash_with_lse.defvjp(_flash_with_lse_fwd, _flash_with_lse_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _flash_shared_key(q, qr, k, kr, v, scale, blocks):
+    """Causal attention whose key is a head's own ``k`` [B, H, L, D]
+    beside ``kr`` [B, 1, L, Dr], one for all heads; ``qr`` [B, H, L, Dr]
+    meets it.  The shared part enters the kernels once a batch entry."""
+    out, _ = _fwd(q, k, v, _zero_off(), _zero_off(), _zero_seed(), scale,
+                  True, blocks, True, shared=(qr, kr))
+    return out
+
+
+def _flash_shared_key_fwd(q, qr, k, kr, v, scale, blocks):
+    out, lse = name_residuals(*_fwd(
+        q, k, v, _zero_off(), _zero_off(), _zero_seed(), scale, True,
+        blocks, True, shared=(qr, kr)))
+    return out, (q, qr, k, kr, v, out, lse)
+
+
+def _flash_shared_key_bwd(scale, blocks, res, do):
+    q, qr, k, kr, v, out, lse = res
+    (dq, dqr), (dk, dkr), dv = _bwd(
+        q, k, v, _zero_off(), _zero_off(), _zero_seed(), out, lse, do, None,
+        scale, True, blocks, True, shared=(qr, kr))
+    # the heads' parts add up in float32
+    dkr = jnp.sum(dkr, axis=1, keepdims=True).astype(kr.dtype)
+    return dq, dqr, dk, dkr, dv
+
+
+_flash_shared_key.defvjp(_flash_shared_key_fwd, _flash_shared_key_bwd)
+
+
 # ---------------------------------------------------------------------------
 # public entries
 # ---------------------------------------------------------------------------
@@ -637,7 +791,9 @@ def _zero_seed():
 def flash_attention(q, k, v, causal: bool = False, scale=None,
                     block_q: int | None = None, block_k: int | None = None,
                     dropout_p: float = 0.0, seed=None):
-    """q/k/v: [B, L, H, D] arrays → [B, Lq, H, D] attention output.
+    """q/k: [B, L, H, D], v: [B, Lk, H, Dv] (``Dv`` may differ from
+    ``D``) → [B, Lq, H, Dv] attention output; the default ``scale`` is
+    ``D ** -0.5``.
 
     ``block_q`` / ``block_k`` left at None are chosen from the static
     shapes (`_resolve_blocks`).
@@ -667,6 +823,28 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     return jnp.swapaxes(out, 1, 2)
 
 
+def flash_attention_shared_key(q, q_shared, k, k_shared, v, scale=None,
+                               block_q: int | None = None,
+                               block_k: int | None = None):
+    """Causal attention over keys in two parts: a head's own ``k``
+    [B, L, H, D] and ``k_shared`` [B, L, Dr], one key part a position for
+    all heads (latent attention's rotated key); ``q`` [B, L, H, D] and
+    ``q_shared`` [B, L, H, Dr] meet them, ``v`` is [B, L, H, Dv].  The
+    score is ``scale * (q . k + q_shared . k_shared)``, ``scale`` by
+    default ``(D + Dr) ** -0.5``.  The shared part is never broadcast to
+    the heads: the kernels stage it once a batch entry.  -> [B, L, H, Dv].
+    """
+    D, Dr = q.shape[-1], q_shared.shape[-1]
+    scale = float(scale) if scale is not None else 1.0 / math.sqrt(D + Dr)
+    blocks = _resolve_blocks(block_q, block_k, q.shape[1], k.shape[1])
+    count_kernel_selection("flash_attention")
+    out = _flash_shared_key(
+        jnp.swapaxes(q, 1, 2), jnp.swapaxes(q_shared, 1, 2),
+        jnp.swapaxes(k, 1, 2), k_shared[:, None], jnp.swapaxes(v, 1, 2),
+        scale, blocks)
+    return jnp.swapaxes(out, 1, 2)
+
+
 def flash_attention_block(q_bhld, k_bhld, v_bhld, q_off, k_off, scale,
                           block_q: int = _BLOCK, block_k: int = _BLOCK):
     """Ring-attention building block: [B, H, L, D] layout, traced global
@@ -680,7 +858,7 @@ def flash_attention_block(q_bhld, k_bhld, v_bhld, q_off, k_off, scale,
 
 
 def mha_reference(q, k, v, causal=False, scale=None):
-    """jnp oracle for tests ([B, L, H, D] layout)."""
+    """jnp oracle for tests ([B, L, H, D] layout; v may be [.., Dv])."""
     D = q.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     s = jnp.einsum("blhd,bshd->bhls", q.astype(jnp.float32),

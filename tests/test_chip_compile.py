@@ -220,6 +220,94 @@ def test_keye_vl2_cell_step_fits_the_chip(one_chip, monkeypatch):
     assert footprint < 15.75 * 2 ** 30
 
 
+def test_mla_flash_attention_fwd_bwd(one_chip, monkeypatch):
+    """The joyai_llm_flash cell's attention: keys 128 + 64 wide (the 64
+    one rotated key a position for all 32 heads), values 128, two rows of
+    8192.  A head's K and V staged whole are 3 MiB + 2 MiB, over what
+    Mosaic's default scoped VMEM takes with their double buffers: the
+    calls state their own limit (`_staging`), and the gate admits that
+    size for the shared-key call alone."""
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    qk, v = (2, 8192, 32, 192), (2, 8192, 32, 128)
+    assert fa.flash_attention_supported(v, v, jnp.bfloat16, v_head_dim=128,
+                                        shared_key_dim=64)
+    assert fa._staging(8192, 128 + 64, 128, jnp.bfloat16) is not None
+    # the shapes the cells had before keep Mosaic's defaults
+    assert fa._staging(2048, 96, 96, jnp.bfloat16) is None
+    assert fa._staging(8192, 128, 128, jnp.bfloat16) is None
+    # a plain call of the cell's size or more is not the kernels'
+    assert not fa.flash_attention_supported(qk, qk, jnp.bfloat16,
+                                            v_head_dim=128)
+    assert not fa.flash_attention_supported(qk, qk, jnp.bfloat16)
+
+    # a value width of its own on the plain entry, at the most the gate
+    # admits for it: heads of 192 with values of 128 at 6144 positions
+    # (3.75 MiB a head), inside Mosaic's default scoped VMEM
+    qk, vv = (1, 6144, 8, 192), (1, 6144, 8, 128)
+    assert fa.flash_attention_supported(qk, qk, jnp.bfloat16, v_head_dim=128)
+    assert fa._staging(6144, 192, 128, jnp.bfloat16) is None
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = _compile(jax.value_and_grad(loss, (0, 1, 2)),
+                        _sds(one_chip, qk, jnp.bfloat16),
+                        _sds(one_chip, qk, jnp.bfloat16),
+                        _sds(one_chip, vv, jnp.bfloat16))
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+    # as the cell runs them: the rotated 64 of the key once a row, not
+    # broadcast to the 32 heads and joined to their 128
+    def loss_shared(q, qr, k, kr, v):
+        out = fa.flash_attention_shared_key(q, qr, k, kr, v)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = _compile(jax.value_and_grad(loss_shared, (0, 1, 2, 3, 4)),
+                        _sds(one_chip, v, jnp.bfloat16),
+                        _sds(one_chip, (2, 8192, 32, 64), jnp.bfloat16),
+                        _sds(one_chip, v, jnp.bfloat16),
+                        _sds(one_chip, (2, 8192, 64), jnp.bfloat16),
+                        _sds(one_chip, v, jnp.bfloat16))
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_joyai_llm_flash_cell_step_fits_the_chip(one_chip, monkeypatch):
+    """The joyai_llm_flash.train_bf16_b2_s8192 cell's whole step (one
+    dense and four expert layers with 16 of 256 experts, the MTP module,
+    an eighth of the vocabulary; two rows of 8192) for the described v5e:
+    it compiles, holds the flash kernels over 192-wide keys and XLA's own
+    grouped-matmul kernel, and fits."""
+    from paddle_tpu.observability import scopes
+    from paddle_tpu.utils import monitor
+    monitor.stat_reset()
+    compiled, n, cfg, mix, footprint = _cell_step(
+        one_chip, monkeypatch, "joyai_llm_flash.train_bf16_b2_s8192",
+        ("flash_attention",))
+    assert cfg["hidden_size"] == 2048 and mix["seq"] == 8192
+    assert cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"] == 192
+    assert 680.3e6 < n < 680.5e6
+    text = compiled.as_text()
+    # five layers and the module's block; the replay keeps the kernel's
+    # out and lse, so each block holds one forward kernel
+    blocks = cfg["num_hidden_layers"] + cfg["num_nextn_predict_layers"]
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert _kernel_count(text, kernel) == blocks, kernel
+    assert "ragged-dot" in text
+    stats = monitor.all_stats()
+    assert [stats.get(f"recompute.kept.{name}", 0)
+            for name in scopes.RESIDUALS] == [blocks, blocks, 0, 0, 0]
+    assert stats["pallas.selected.mla_attention"] >= blocks
+    assert "mla_attention.xla_path" not in stats
+    assert (stats["moe.experts_held"], stats["moe.experts_total"],
+            stats["moe.top_k"]) == (16, 256, 8)
+    assert stats["moe.scoring_sigmoid"] >= blocks - 1
+    assert stats["moe.shared_experts"] >= blocks - 1
+    assert stats["mtp.modules"] == 1
+    assert footprint < 15.75 * 2 ** 30
+
+
 # ------------------------------------------------------- fused epilogue --
 _LN = ("layer_norm", 1e-5, True, True)
 # (M, K, N, stages): the recipe the Executor realises on BERT-base

@@ -505,3 +505,82 @@ def test_the_expert_and_sparse_attention_counters(keye_step):
     assert stats["sparse_attention.xla_path"] >= L
     assert stats["dsa_indexer.xla_path"] >= L
     assert "pallas.selected.sparse_attention" not in stats
+
+
+# ------------- latent attention, a sigmoid router, an MTP module (PR 32) --
+@pytest.fixture(scope="module")
+def joyai_step():
+    """``op_name``s and counters of the joyai_llm_flash cell's model at
+    its rehearsal widths, through the train_step runner, on the XLA
+    paths."""
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, bench)
+    import run as harness
+    cell, cfg, mix, model_mod, ref, runner = harness.load_parts(
+        "joyai_llm_flash.train_bf16_b2_s8192", rehearse=True)
+    ring, theta0 = harness.seeded_inputs(cell, cfg, mix, ref, seed=3)
+    monitor.stat_reset()
+    state = runner.build(cell, cfg, model_mod, theta0(), mix)
+    try:
+        step = state["step"]
+        runner.dispatch(state, runner.feed(state, *ring[0]))
+        ids = jnp.asarray(ring[0][0])
+        lowered = step._compiled[True].lower(
+            step._param_arrays(), (), step._opt_state, step._scaler_state,
+            step._lr_device, (ids,), (ids,))
+        return (_op_names(lowered.compile().as_text()),
+                dict(monitor.all_stats()), cfg)
+    finally:
+        runner.close(state)
+
+
+@pytest.mark.parametrize("scope", [
+    scopes.MLA_ATTENTION, scopes.MTP, scopes.MOE, scopes.MOE_ROUTER,
+    scopes.MOE_DISPATCH, scopes.MOE_EXPERTS, scopes.MOE_SHARED,
+    scopes.RMS_NORM, scopes.ROPE, scopes.LINEAR_CROSS_ENTROPY,
+    "attn:MLAttention", "moe:MoELayer", "dense:Block", "blocks.1:Block",
+    "mtp:MTPModule", "block:Block"])
+def test_the_latent_attention_and_mtp_scopes_are_in_the_step(joyai_step,
+                                                             scope):
+    assert scope in _segments(joyai_step[0])
+
+
+def test_the_mtp_scope_holds_its_block_and_its_head_in_every_pass(joyai_step):
+    """``mtp_ms`` reads whole path segments named ``mtp``: the module's
+    block (under ``parallel.recompute``) and its pass through the shared
+    head must carry one in the forward, the replay and the backward."""
+    names = joyai_step[0]
+    for inner in (scopes.MLA_ATTENTION, scopes.MOE_SHARED,
+                  scopes.LINEAR_CROSS_ENTROPY):
+        under = [n.split("/") for n in names
+                 if scopes.MTP in n.split("/") and inner in n.split("/")]
+        assert any("jvp(loss)" in s and "rematted_computation" not in s
+                   for s in under), inner
+        assert any("transpose(jvp(loss))" in s for s in under), inner
+    replay = [n for n in names if "/mtp/" in n and "rematted_computation" in n]
+    assert any("/" + scopes.MLA_ATTENTION + "/" in n for n in replay)
+    # the main model's blocks and its head are not under it
+    assert not any("/mtp/" in n for n in names if "/blocks.0:Block/" in n)
+    assert any(scopes.LINEAR_CROSS_ENTROPY in n.split("/")
+               and scopes.MTP not in n.split("/") for n in names)
+
+
+def test_the_latent_attention_and_mtp_counters(joyai_step):
+    _, stats, cfg = joyai_step
+    blocks = cfg["num_hidden_layers"] + cfg["num_nextn_predict_layers"]
+    assert stats["moe.experts_held"] == cfg["n_routed_experts"]
+    assert stats["moe.experts_total"] == cfg["published"]["n_routed_experts"]
+    assert stats["moe.top_k"] == cfg["num_experts_per_tok"]
+    assert stats["mtp.modules"] == 1
+    # once a block in the forward pass and once in its replay
+    assert stats["mla_attention.xla_path"] >= blocks
+    assert stats["moe.scoring_sigmoid"] >= blocks - 1
+    assert stats["moe.shared_experts"] >= blocks - 1
+    assert "pallas.selected.mla_attention" not in stats
+
+
+def test_keyes_expert_layer_counts_no_sigmoid_and_no_shared_expert(keye_step):
+    _, stats, _ = keye_step
+    assert "moe.scoring_sigmoid" not in stats
+    assert "moe.shared_experts" not in stats
