@@ -22,8 +22,9 @@ w summary blocks before it (W / c rows each), carrying one running
 max / sum.  ``eva_bwd_dq`` makes the same walk and, since it holds each
 summary tile's p and dS anyway, also accumulates the summaries' dk~ and
 dv~ over the query blocks.  dk / dv of the exact keys need nothing new:
-with the joint ``lse`` and ``delta`` they are the flash dk/dv kernel's,
-run with the windows folded into the head axis.  The pooling and its
+with the joint ``lse`` and ``delta`` they are the flash backward walk's
+(asked for dk and dv alone: its dq would lack the summaries' part), run
+with the windows folded into the head axis.  The pooling and its
 backward are XLA fusions (two passes over k and v; ``eva_pool`` scope).
 
 Layout [B, S, H, D] in, [B, H, S, D] inside, as flash attention.

@@ -21,10 +21,17 @@ recompute-based backward (standard FlashAttention-2 dataflow).  Inside,
 every score tile is held transposed ([block_k, block_q], see the note
 above the kernels).
 
-Per call on one v5e (PERF.md, PR 25, where the sweep and the per-block
-times are): [8, 16, 2048, 96] bf16 causal takes 1.48 ms forward, 1.73 ms
-dq and 2.46 ms dk/dv; [64, 12, 512, 64] bf16 non-causal 0.85, 1.00 and
-1.20 ms.  Whether XLA's attention or this kernel is taken at a length is
+The backward is one kernel (PR 33): the dK/dV walk holds each pair's
+``ds`` and its k block, so it makes dQ too, into a float32 VMEM
+accumulator that holds the head's whole dQ; no second walk recomputes
+the scores, p and dP (5 products a pair where two kernels ran 7).
+
+Per call on one v5e, in the cells' steps (PERF.md, PR 33; the block
+sweep is PR 25's): [8, 16, 2048, 96] bf16 causal takes 1.48 ms forward
+and 2.90 ms backward (1.73 dq + 2.46 dk/dv before); [64, 12, 512, 64]
+bf16 non-causal 0.90 and 1.46 ms (1.06 + 1.25); [2, 32, 8192, 128 + 64
+shared] with 128-wide values, causal, 13.89 and 27.01 ms (16.74 +
+22.39).  Whether XLA's attention or this kernel is taken at a length is
 a measured constant, `_KERNEL_FROM` below, beside the chip table it came
 from: the kernel from 512 positions up, for every head size, mask and
 dropout rate measured (PERF.md, PR 27).
@@ -135,7 +142,15 @@ def flash_attention_supported(q_shape, k_shape, dtype, attn_mask=None,
 # (tests/test_chip_compile.py; PERF.md, PR 32), so the gate admits more
 # than the default for the shared-key call alone and up to its bytes; a
 # plain call of that size (8192 x 192, 12288 x 128 bf16) stays XLA's or
-# the ring's until its crossover is measured.
+# the ring's until its crossover is measured.  The backward walk that
+# makes dQ too holds a head's dQ block and its float32 accumulator
+# besides, and `_staging` counts that call as VMEM lays it out (a width
+# under 128 lanes takes 128): at 8192 x 128, q and dO 8 MiB and dQ 4
+# double-buffered, the accumulator 4, lse and delta 1, about 17 MiB
+# before the tiles, and at 8192 x 64 still 15 (64 lanes as 128), so both
+# state the limit; 4096 x 128 (8.5 MiB) and the cells' plain shapes stay
+# on the defaults; the shared-key walk about 30 MiB of the 48 (q 4 + qr 4
+# + dO 4, dQ 4 + dQr 4, the accumulators 4 + 2, lse and delta 1).
 _STAGED_DEFAULT = 4 * 1024 * 1024
 _STAGED_SHARED_KEY = 5 * 1024 * 1024
 _VMEM_LIMIT_STAGED = 48 * 1024 * 1024
@@ -145,13 +160,30 @@ def _staged_bytes(L, D, Dv, dtype):
     return L * (D + Dv) * jnp.dtype(dtype).itemsize
 
 
-def _staging(L, D, Dv, dtype):
+def _staging(L, D, Dv, dtype, dq_widths=()):
     """``compiler_params`` of a call that stages ``L`` rows a head: None
     (Mosaic's defaults, the path every shape up to ``_STAGED_DEFAULT``
-    has always taken) or the larger scoped-VMEM limit."""
-    if _staged_bytes(L, D, Dv, dtype) <= _STAGED_DEFAULT:
+    has always taken) or the larger scoped-VMEM limit.  ``dq_widths``: of
+    the backward walk that makes dQ too, the widths of its dQ blocks (the
+    staged q's: ``D``, and a shared part's beside it).  That walk holds
+    q, dO and the head's dQ blocks [L, width], double-buffered alike and
+    each width laid out in whole 128-lane tiles, and the float32
+    accumulators once (four bytes an element, so two beside blocks
+    that are held twice)."""
+    if dq_widths:
+        lanes = 2 * sum(map(_lanes, dq_widths)) + _lanes(Dv)
+        held = (L * lanes * jnp.dtype(dtype).itemsize
+                + L * sum(dq_widths) * 2)
+    else:
+        held = _staged_bytes(L, D, Dv, dtype)
+    if held <= _STAGED_DEFAULT:
         return None
     return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT_STAGED)
+
+
+def _lanes(width):
+    """``width`` as VMEM lays a block's last dimension out."""
+    return -(-width // 128) * 128
 
 
 # ---------------------------------------------------------------------------
@@ -159,7 +191,7 @@ def _staging(L, D, Dv, dtype):
 # ---------------------------------------------------------------------------
 
 # Measured on one v5e (PERF.md, PR 25): 512 x 512 is the fastest of
-# {256, 512, 1024}^2 for each of the three kernels at [8, 16, 2048, 96]
+# {256, 512, 1024}^2 for each of the kernels at [8, 16, 2048, 96]
 # bf16 causal and at [64, 12, 512, 64] bf16: smaller tiles reload the
 # MXU's weights for fewer rows (256 x 256 takes 1.9x as long), larger
 # ones spill more.  The largest shapes flash_attention_supported admits
@@ -168,18 +200,18 @@ _BLOCK = 512
 
 
 def _resolve_blocks(block_q, block_k, Lq, Lk):
-    """(block_q, block_k) of all three kernels, from the static shapes:
+    """(block_q, block_k) of both kernels, from the static shapes:
     ``_BLOCK`` where left at None (explicit ones are for tests and the
-    ring path), the whole of a shorter sequence.  One pair for the three
-    kernels, because the dropout tile seeds are block indices."""
+    ring path), the whole of a shorter sequence.  One pair for both,
+    because the dropout tile seeds are block indices."""
     return min(block_q or _BLOCK, Lq), min(block_k or _BLOCK, Lk)
 
 
 def _count_blocks(Lq, Lk, block_q, block_k, causal, aligned):
     """Trace-time counters, once per kernel traced: block iterations per
     (batch, head) that run without a mask (``blocks_full``) and with one
-    (``blocks_masked``).  The dkv kernel walks the same (q block, k block)
-    pairs as the other two, by columns."""
+    (``blocks_masked``).  The backward kernel walks the forward's (q
+    block, k block) pairs, by columns."""
     from ...utils import monitor
     num_q, num_kv = Lq // block_q, Lk // block_k
     if not causal:
@@ -265,8 +297,8 @@ def _block_loops(body, carry, num_blocks, causal, aligned, causal_spans):
 
 def _dropout_keep(seed_ref, qi, j, shape, dropout_p):
     """Tile keep-mask from the Pallas TPU PRNG, seeded on
-    (user seed, b, h, q-block, k-block) so the backward kernels reproduce
-    the forward's mask exactly (all three hold the tile as
+    (user seed, b, h, q-block, k-block) so the backward kernel reproduces
+    the forward's mask exactly (both hold the tile as
     [block_k, block_q]).  prng_random_bits has int32 semantics on
     TPU: an arithmetic >>16 yields uniform [-32768, 32767], compared
     against the p-quantile threshold."""
@@ -327,7 +359,7 @@ def _rows(ref, j, block):
 
 
 def _once_a_shape(*static_argnums):
-    """Decorator of the three kernel launchers: jax's tracing cache
+    """Decorator of the kernel launchers: jax's tracing cache
     serves every call after the first with the same static shape, so a
     model's identical layers trace each kernel body once (36 kernel
     calls of the BERT cell cost its set-up 3.8 s of tracing and lowering
@@ -398,7 +430,7 @@ def _fwd_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref, *rest,
 
 
 def _qkv_fwd_specs(block_q, Lk, D, Dv, Dr=0):
-    """In-specs of the forward and dq kernels; with ``Dr`` also the
+    """In-specs of the forward kernel; with ``Dr`` also the
     query part [B, H, Lq, Dr] that meets the shared key [B, 1, Lk, Dr],
     which is staged once a batch entry: its block index does not move
     with the head."""
@@ -480,56 +512,22 @@ def _p_ds(s, mask, lse, do, v, delta, seed_ref, qi, j, dropout_p):
     return u, u * dp - p * delta
 
 
-def _bwd_dq_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
-                   *rest, scale, block_k, seq_k, causal, block_q, aligned,
-                   dropout_p, shared):
-    if shared:
-        (qr_ref, kr_ref, do_ref, lse_ref, delta_ref, dq_ref,
-         dqr_ref) = rest
-        qr = _prescale(qr_ref[0, 0], scale)               # [BQ, Dr]
-    else:
-        do_ref, lse_ref, delta_ref, dq_ref = rest
-    qi = pl.program_id(2)
-    q = _prescale(q_ref[0, 0], scale)                     # [BQ, D]
-    do = do_ref[0, 0]
-    lse = lse_ref[0, 0][0:1, :]                           # [1, BQ]
-    delta = delta_ref[0, 0][0:1, :]
-    bq, d = q.shape
-    # dq^T, and under it the transposed gradient of the shared part
-    dq = (jnp.zeros((d, bq), jnp.float32),
-          jnp.zeros((qr.shape[1], bq), jnp.float32) if shared else None)
-    num_kv = seq_k // block_k
-
-    def body(j, dq, mask):
-        k = _rows(k_ref, j, block_k)
-        v = _rows(v_ref, j, block_k)
-        kr = _rows(kr_ref, j, block_k) if shared else None
-        s = _scores(k, q, mask, qi, j, q_off_ref, k_off_ref, block_q,
-                    block_k, (kr, qr) if shared else None)
-        _, ds = _p_ds(s, mask, lse, do, v, delta, seed_ref, qi, j,
-                      dropout_p)
-        ds = ds.astype(k.dtype)
-        return (dq[0] + _dot(k, ds, ((0,), (0,))),
-                dq[1] + _dot(kr, ds, ((0,), (0,))) if shared else None)
-
-    full, end = _kv_bounds(qi, block_q, block_k, num_kv)
-    dq = _block_loops(body, dq, num_kv, causal, aligned,
-                      ((0, full, None), (full, end, "diagonal")))
-    # s was taken against scale * q: the chain rule's scale, once
-    dq_ref[0, 0] = (dq[0] * scale).T.astype(dq_ref.dtype)
-    if shared:
-        dqr_ref[0, 0] = (dq[1] * scale).T.astype(dqr_ref.dtype)
-
-
 def _bwd_dkv_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
                     *rest, scale, block_q, seq_q, causal, block_k, aligned,
-                    dropout_p, shared):
+                    dropout_p, shared, n_dq):
+    """The backward walk: k block ``kj`` of a head against the q blocks it
+    sees, for its dK and dV.  Where the call asks for dQ too (``rest``
+    then ends in ``n_dq`` dq outputs and as many accumulators, see
+    `_bwd_dkv_call`), each pair's ``ds`` also meets the k block, and the
+    head's whole dQ^T adds up in float32 over the k blocks in VMEM: no
+    second walk recomputes the scores, p and dP for it."""
     if shared:
-        (qr_ref, kr_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-         dkr_ref) = rest
+        qr_ref, kr_ref, *rest = rest
         kr = kr_ref[0, 0]                                 # [BK, Dr]
-    else:
-        do_ref, lse_ref, delta_ref, dk_ref, dv_ref = rest
+    do_ref, lse_ref, delta_ref, dk_ref, dv_ref, *rest = rest
+    if shared:
+        dkr_ref, *rest = rest
+    dq_refs, dq_accs = rest[:n_dq], rest[n_dq:]
     kj = pl.program_id(2)
     k = k_ref[0, 0]                                       # [BK, D]
     v = v_ref[0, 0]
@@ -538,6 +536,12 @@ def _bwd_dkv_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
     # this head's part of the shared key's gradient
     dkr = jnp.zeros(kr.shape, jnp.float32) if shared else None
     num_q = seq_q // block_q
+
+    if dq_accs:
+        @pl.when(kj == 0)
+        def _():
+            for acc in dq_accs:
+                acc[...] = jnp.zeros_like(acc)
 
     def body(i, carry, mask):
         dk, dv, dkr = carry
@@ -558,6 +562,9 @@ def _bwd_dkv_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
         dk = dk + _dot(ds, q, ((1,), (0,)))
         if shared:
             dkr = dkr + _dot(ds, qr, ((1,), (0,)))
+        # q block i's dq^T [D, BQ], and under it the shared part's
+        for acc, key in zip(dq_accs, (k, kr) if shared else (k,)):
+            acc[i] += _dot(key, ds, ((0,), (0,)))
         return dk, dv, dkr
 
     start, full = _q_bounds(kj, block_q, block_k, num_q)
@@ -569,65 +576,45 @@ def _bwd_dkv_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
     if shared:
         dkr_ref[0, 0] = dkr
 
+    if dq_accs:
+        @pl.when(kj == pl.num_programs(2) - 1)
+        def _():
+            def emit(i, carry):
+                rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+                # s was taken against scale * q: the chain rule's scale,
+                # once, and each [D, BQ] block transposed once a head
+                for ref, acc in zip(dq_refs, dq_accs):
+                    ref[0, 0, rows, :] = (acc[i] * scale).T.astype(ref.dtype)
+                return carry
 
-def _bwd_dq(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale, causal,
-            blocks, aligned, dropout_p, shared=None):
-    _count_blocks(q.shape[2], k.shape[2], *blocks, causal, aligned)
-    return _bwd_dq_call(q, k, v, q_off, k_off, seed, do, lse8, delta8,
-                        scale, causal, blocks, aligned, dropout_p,
-                        _interpret(), shared=shared)
-
-
-@_once_a_shape(9, 10, 11, 12, 13, 14)
-def _bwd_dq_call(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale,
-                 causal, blocks, aligned, dropout_p, interpret, shared=None):
-    """-> dq, or with ``shared`` (dq, dqr)."""
-    B, H, Lq, D = q.shape
-    Lk, Dv, Dr = k.shape[2], v.shape[3], _shared_width(shared)
-
-    def rows(width):
-        return pl.BlockSpec((1, 1, block_q, width),
-                            lambda b, h, i: (b, h, i, 0))
-
-    block_q, block_k = blocks
-    out = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, block_k=block_k,
-                          seq_k=Lk, causal=causal, block_q=block_q,
-                          aligned=aligned, dropout_p=dropout_p,
-                          shared=bool(shared)),
-        grid=(B, H, Lq // block_q),
-        in_specs=_qkv_fwd_specs(block_q, Lk, D, Dv, Dr) + [
-            rows(Dv),
-            pl.BlockSpec((1, 1, 8, block_q), lambda b, h, i: (b, h, 0, i)),
-            pl.BlockSpec((1, 1, 8, block_q), lambda b, h, i: (b, h, 0, i)),
-        ],
-        out_specs=[rows(D)] + ([rows(Dr)] if shared else []),
-        out_shape=[jax.ShapeDtypeStruct((B, H, Lq, D), q.dtype)] + (
-            [jax.ShapeDtypeStruct((B, H, Lq, Dr), q.dtype)] if shared
-            else []),
-        interpret=interpret,
-        compiler_params=_staging(Lk, D + Dr, Dv, q.dtype),
-        name=scopes.FLASH_BWD_DQ,
-    )(q_off, k_off, seed, q, k, v, *(shared or ()), do, lse8, delta8)
-    return tuple(out) if shared else out[0]
+            jax.lax.fori_loop(0, num_q, emit, 0)
 
 
 def _bwd_dkv(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale, causal,
-             blocks, aligned, dropout_p, shared=None):
+             blocks, aligned, dropout_p, shared=None, with_dq=False):
+    """``with_dq``: what the caller needs of the walk.  `_bwd` takes dQ
+    from it; EVA's windows (eva_attention.py) take dK and dV alone, their
+    dQ comes with the summaries' gradients from a kernel of their own."""
     _count_blocks(q.shape[2], k.shape[2], *blocks, causal, aligned)
     return _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8,
                          scale, causal, blocks, aligned, dropout_p,
-                         _interpret(), shared=shared)
+                         _interpret(), with_dq, shared=shared)
 
 
-@_once_a_shape(9, 10, 11, 12, 13, 14)
+@_once_a_shape(9, 10, 11, 12, 13, 14, 15)
 def _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale,
-                  causal, blocks, aligned, dropout_p, interpret, shared=None):
-    """-> (dk, dv), or with ``shared`` (dk, dv, each head's float32 part
-    of the shared key's gradient [B, H, Lk, Dr])."""
+                  causal, blocks, aligned, dropout_p, interpret, with_dq,
+                  shared=None):
+    """-> (dk, dv), with ``shared`` also each head's float32 part of the
+    shared key's gradient [B, H, Lk, Dr], with ``with_dq`` then dq (and
+    with both dqr).  A head's dQ block is its whole [Lq, D] under an index
+    that does not move with the k block: it goes to HBM once a (batch,
+    head), from float32 accumulators [Lq / block_q, D, block_q] that are
+    zeroed at the head's first k block."""
     B, H, Lq, D = q.shape
     Lk, Dv, Dr = k.shape[2], v.shape[3], _shared_width(shared)
     block_q, block_k = blocks
+    dq_widths = ([D] + ([Dr] if shared else [])) if with_dq else []
 
     def whole(width):
         return pl.BlockSpec((1, 1, Lq, width), lambda b, h, j: (b, h, 0, 0))
@@ -640,7 +627,7 @@ def _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale,
         functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
                           seq_q=Lq, causal=causal, block_k=block_k,
                           aligned=aligned, dropout_p=dropout_p,
-                          shared=bool(shared)),
+                          shared=bool(shared), n_dq=len(dq_widths)),
         grid=(B, H, Lk // block_k),
         in_specs=[
             _smem_scalar_spec(),
@@ -656,14 +643,18 @@ def _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale,
             pl.BlockSpec((1, 1, 8, Lq), lambda b, h, j: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, 8, Lq), lambda b, h, j: (b, h, 0, 0)),
         ],
-        out_specs=[rows(D), rows(Dv)] + ([rows(Dr)] if shared else []),
+        out_specs=[rows(D), rows(Dv)] + ([rows(Dr)] if shared else [])
+        + [whole(w) for w in dq_widths],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Lk, D), k.dtype),
             jax.ShapeDtypeStruct((B, H, Lk, Dv), v.dtype),
         ] + ([jax.ShapeDtypeStruct((B, H, Lk, Dr), jnp.float32)]
-             if shared else []),
+             if shared else [])
+        + [jax.ShapeDtypeStruct((B, H, Lq, w), q.dtype) for w in dq_widths],
+        scratch_shapes=[pltpu.VMEM((Lq // block_q, w, block_q), jnp.float32)
+                        for w in dq_widths],
         interpret=interpret,
-        compiler_params=_staging(Lq, D + Dr, Dv, q.dtype),
+        compiler_params=_staging(Lq, D + Dr, Dv, q.dtype, dq_widths),
         name=scopes.FLASH_BWD_DKV,
     )(q_off, k_off, seed, q, k, v, *(shared or ()), do, lse8, delta8)
     return tuple(out)
@@ -671,10 +662,11 @@ def _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale,
 
 def _bwd(q, k, v, q_off, k_off, seed, out, lse, do, dlse, scale, causal,
          blocks, aligned, dropout_p=0.0, shared=None):
-    """Full backward -> (dq, dk, dv), or with ``shared`` ((dq, dqr),
-    (dk, each head's part of dkr), dv).  The lse cotangent folds into
-    delta: with P = exp(S - lse) row-normalized,
+    """Full backward, one kernel -> (dq, dk, dv), or with ``shared``
+    ((dq, dqr), (dk, each head's part of dkr), dv).  The lse cotangent
+    folds into delta: with P = exp(S - lse) row-normalized,
     dS = P * (dP_rows - delta + dlse) since d lse / dS = P."""
+    from ...utils import monitor
     B, H, Lq, _ = q.shape
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)                              # [B, H, Lq]
@@ -683,11 +675,14 @@ def _bwd(q, k, v, q_off, k_off, seed, out, lse, do, dlse, scale, causal,
     # 8-sublane replication at the kernel boundary (see _fwd_kernel note)
     lse8 = jnp.broadcast_to(lse[:, :, None, :], (B, H, 8, Lq))
     delta8 = jnp.broadcast_to(delta[:, :, None, :], (B, H, 8, Lq))
-    args = (q, k, v, q_off, k_off, seed, do, lse8, delta8, scale, causal,
-            blocks, aligned, dropout_p)
-    dq = _bwd_dq(*args, shared=shared)
-    dk, dv, *dkr = _bwd_dkv(*args, shared=shared)
-    return dq, ((dk, *dkr) if shared else dk), dv
+    monitor.stat_add("pallas.flash.bwd_fused")
+    dk, dv, *rest = _bwd_dkv(q, k, v, q_off, k_off, seed, do, lse8, delta8,
+                             scale, causal, blocks, aligned, dropout_p,
+                             shared=shared, with_dq=True)
+    if not shared:
+        return rest[0], dk, dv
+    dkr, dq, dqr = rest
+    return (dq, dqr), (dk, dkr), dv
 
 
 # ---------------------------------------------------------------------------

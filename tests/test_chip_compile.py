@@ -71,8 +71,18 @@ def test_flash_attention_fwd_bwd(one_chip, monkeypatch, shape, causal,
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     qkv = _sds(one_chip, shape, jnp.bfloat16)
-    _compile(jax.value_and_grad(loss, (0, 1, 2)), qkv, qkv, qkv,
-             _sds(one_chip, (1, 1), jnp.int32))
+    compiled = _compile(jax.value_and_grad(loss, (0, 1, 2)), qkv, qkv, qkv,
+                        _sds(one_chip, (1, 1), jnp.int32))
+    _one_backward_kernel(compiled)
+
+
+def _one_backward_kernel(compiled):
+    """The forward kernel and the dK/dV walk that makes dQ too."""
+    kernels = re.findall(
+        r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"',
+        compiled.as_text())
+    assert len(kernels) == 2 and "flash_fwd" in kernels[0], kernels
+    assert "flash_bwd_dkv" in kernels[1], kernels
 
 
 # ------------------------------------------------------------------ EVA --
@@ -182,6 +192,8 @@ def test_evabyte_cell_step_fits_the_chip(one_chip, monkeypatch):
     L = cfg["num_hidden_layers"]
     for kernel in ("eva_fwd", "eva_bwd_dq", "flash_bwd_dkv"):
         assert _kernel_count(text, kernel) == L, kernel
+    # the windows ask the flash walk for dK and dV alone
+    assert "pallas.flash.bwd_fused" not in monitor.all_stats()
     # the policy keeps by name: nothing here carries the indexer's names
     stats = monitor.all_stats()
     assert [stats.get(f"recompute.kept.{name}", 0)
@@ -217,6 +229,7 @@ def test_keye_vl2_cell_step_fits_the_chip(one_chip, monkeypatch):
     stats = monitor.all_stats()
     assert [stats[f"recompute.kept.{name}"] for name in scopes.RESIDUALS] \
         == [L] * len(scopes.RESIDUALS)
+    assert "pallas.flash.bwd_fused" not in stats
     assert footprint < 15.75 * 2 ** 30
 
 
@@ -233,9 +246,19 @@ def test_mla_flash_attention_fwd_bwd(one_chip, monkeypatch):
     assert fa.flash_attention_supported(v, v, jnp.bfloat16, v_head_dim=128,
                                         shared_key_dim=64)
     assert fa._staging(8192, 128 + 64, 128, jnp.bfloat16) is not None
-    # the shapes the cells had before keep Mosaic's defaults
-    assert fa._staging(2048, 96, 96, jnp.bfloat16) is None
-    assert fa._staging(8192, 128, 128, jnp.bfloat16) is None
+    # the shapes the cells had before keep Mosaic's defaults, in the
+    # backward walk with a head's dQ block and accumulator too
+    for dq_widths in ((), (96,)):
+        assert fa._staging(2048, 96, 96, jnp.bfloat16, dq_widths) is None
+    for dq_widths in ((), (64,)):
+        assert fa._staging(512, 64, 64, jnp.bfloat16, dq_widths) is None
+    # the largest plain shapes of the gate: the forward kernel (and EVA's
+    # dK/dV-only call) as ever, the walk that holds dQ under a stated
+    # limit, narrow heads too (64 or 32 lanes are laid out as 128)
+    for D in (128, 64, 32):
+        assert fa._staging(8192, D, D, jnp.bfloat16) is None
+        assert fa._staging(8192, D, D, jnp.bfloat16, (D,)) is not None
+    assert fa._staging(4096, 128, 128, jnp.bfloat16, (128,)) is None
     # a plain call of the cell's size or more is not the kernels'
     assert not fa.flash_attention_supported(qk, qk, jnp.bfloat16,
                                             v_head_dim=128)
@@ -252,11 +275,16 @@ def test_mla_flash_attention_fwd_bwd(one_chip, monkeypatch):
         out = fa.flash_attention(q, k, v, causal=True)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
-    compiled = _compile(jax.value_and_grad(loss, (0, 1, 2)),
-                        _sds(one_chip, qk, jnp.bfloat16),
-                        _sds(one_chip, qk, jnp.bfloat16),
-                        _sds(one_chip, vv, jnp.bfloat16))
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    for qk, vv in ((qk, vv), ((1, 8192, 8, 128),) * 2,
+                   ((1, 4096, 8, 128),) * 2, ((1, 8192, 8, 64),) * 2,
+                   ((1, 8192, 8, 32),) * 2):
+        assert fa.flash_attention_supported(qk, qk, jnp.bfloat16,
+                                            v_head_dim=vv[-1])
+        _one_backward_kernel(_compile(
+            jax.value_and_grad(loss, (0, 1, 2)),
+            _sds(one_chip, qk, jnp.bfloat16),
+            _sds(one_chip, qk, jnp.bfloat16),
+            _sds(one_chip, vv, jnp.bfloat16)))
 
     # as the cell runs them: the rotated 64 of the key once a row, not
     # broadcast to the 32 heads and joined to their 128
@@ -270,7 +298,43 @@ def test_mla_flash_attention_fwd_bwd(one_chip, monkeypatch):
                         _sds(one_chip, v, jnp.bfloat16),
                         _sds(one_chip, (2, 8192, 64), jnp.bfloat16),
                         _sds(one_chip, v, jnp.bfloat16))
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    _one_backward_kernel(compiled)
+
+
+def _one_backward_kernel_a_block(text, blocks):
+    """A step over the flash family: a forward kernel a block (the replay
+    keeps its ``out`` and ``lse``) and one backward kernel, the dK/dV walk
+    that makes dQ too, counted where it was traced."""
+    from paddle_tpu.utils import monitor
+    for kernel, calls in (("flash_fwd", blocks), ("flash_bwd_dkv", blocks),
+                          ("flash_bwd_dq", 0)):
+        assert _kernel_count(text, kernel) == calls, kernel
+    assert monitor.get_stat("pallas.flash.bwd_fused") == blocks
+
+
+@pytest.mark.parametrize("cell_name,blocks,kept,parameters", [
+    ("gpt3_large.train_bf16_b8_s2048", 24, 24, 760e6),
+    ("bert_base.train_bf16_b64_s512", 12, 0, 132e6),
+], ids=["gpt", "bert"])
+def test_flash_cell_step_runs_one_backward_kernel(one_chip, monkeypatch,
+                                                  cell_name, blocks, kept,
+                                                  parameters):
+    """The GPT and BERT cells' whole steps for the described v5e: causal
+    at 2048 x 96 under per-block recompute, non-causal at 512 x 64 in one
+    block a (batch, head)."""
+    from paddle_tpu.observability import scopes
+    from paddle_tpu.utils import monitor
+    monitor.stat_reset()
+    compiled, n, cfg, mix, footprint = _cell_step(
+        one_chip, monkeypatch, cell_name, ("flash_attention",))
+    assert 0.9 * parameters < n < 1.1 * parameters
+    _one_backward_kernel_a_block(compiled.as_text(), blocks)
+    stats = monitor.all_stats()
+    assert [stats.get(f"recompute.kept.{name}", 0)
+            for name in scopes.RESIDUALS] == [kept, kept, 0, 0, 0]
+    assert stats["pallas.selected.flash_attention"] >= blocks
+    assert "attention.xla_path" not in stats
+    assert footprint < 15.75 * 2 ** 30
 
 
 def test_joyai_llm_flash_cell_step_fits_the_chip(one_chip, monkeypatch):
@@ -292,8 +356,7 @@ def test_joyai_llm_flash_cell_step_fits_the_chip(one_chip, monkeypatch):
     # five layers and the module's block; the replay keeps the kernel's
     # out and lse, so each block holds one forward kernel
     blocks = cfg["num_hidden_layers"] + cfg["num_nextn_predict_layers"]
-    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
-        assert _kernel_count(text, kernel) == blocks, kernel
+    _one_backward_kernel_a_block(text, blocks)
     assert "ragged-dot" in text
     stats = monitor.all_stats()
     assert [stats.get(f"recompute.kept.{name}", 0)

@@ -7,6 +7,7 @@ against the benchmark's plain reference.
 import importlib
 import math
 import os
+import re
 import sys
 
 import jax
@@ -185,6 +186,52 @@ def test_block_counters_for_the_cell_shape():
     assert traced(jax.grad(
         lambda *a: jnp.sum(fwd(*a).astype(jnp.float32)),
         (0, 1, 2, 3, 4))) == [80, 48]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_the_windows_ask_the_flash_walk_for_dk_and_dv_alone(dtype):
+    """EVA's backward borrows the flash family's dK/dV walk over folded
+    windows and makes its dQ with the summaries' gradients: it never
+    counts ``pallas.flash.bwd_fused``, and the call it makes traces the
+    kernel without dQ (two outputs, no accumulator) and gets bit for bit
+    the dK and dV of the walk that makes dQ too."""
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    args = _inputs(64, dtype=dtype)
+    grad = jax.grad(lambda *a: jnp.sum(eva.eva_attention(
+        *a, 16, 4).astype(jnp.float32)), (0, 1, 2, 3, 4))
+    fused = monitor.all_stats().get("pallas.flash.bwd_fused", 0)
+    text = str(jax.make_jaxpr(grad)(*args))
+    assert monitor.all_stats().get("pallas.flash.bwd_fused", 0) == fused
+    from paddle_tpu.observability import scopes
+    assert [k for k in scopes.KERNELS
+            if re.search(rf"\bname={k}\b", text)] == [
+        "flash_bwd_dkv", "eva_fwd", "eva_bwd_dq"]
+
+    # the same walk over one folded window, asked both ways
+    q, k, v = (jnp.swapaxes(a, 1, 2)[:, :, :16] for a in args[:3])
+    do = (q * 0.5 + 0.25).astype(dtype)
+    zero, seed = fa._zero_off(), fa._zero_seed()
+    out, lse = fa._fwd(q, k, v, zero, zero, seed, 0.25, True, (8, 8), True)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), -1)
+    rows8 = [jnp.broadcast_to(a[:, :, None, :], a.shape[:2] + (8, 16))
+             for a in (lse, delta)]
+
+    def walk(with_dq):
+        return lambda q, k, v, do: fa._bwd_dkv(
+            q, k, v, zero, zero, seed, do, *rows8, 0.25, True, (8, 8), True,
+            0.0, with_dq=with_dq)
+
+    # the launcher is inlined: its one equation is the kernel's call
+    call, = (e for e in jax.make_jaxpr(walk(False))(q, k, v, do).eqns
+             if e.primitive.name == "pallas_call")
+    assert len(call.outvars) == 2
+    assert call.params["grid_mapping"].num_scratch_operands == 0
+    alone, with_dq = walk(False)(q, k, v, do), walk(True)(q, k, v, do)
+    assert len(alone) == 2 and len(with_dq) == 3
+    for a, b in zip(alone, with_dq):
+        assert a.dtype == dtype
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
 
 
 # ----------------------------------------------------- norm and rotary --

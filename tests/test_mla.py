@@ -85,19 +85,23 @@ def test_flash_kernels_in_bfloat16_at_the_cells_head_shape():
         assert err < 0.03 * jnp.linalg.norm(r.astype(jnp.float32))
 
 
-@pytest.mark.parametrize("blocks", [(64, 128), (128, 64), (256, 256)],
-                         ids=["q64k128", "q128k64", "one_block"])
-def test_flash_kernels_take_a_key_part_that_the_heads_share(blocks):
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("blocks", [(64, 128), (128, 64), (64, 64),
+                                    (256, 256)],
+                         ids=["q64k128", "q128k64", "q64k64", "one_block"])
+def test_flash_kernels_take_a_key_part_that_the_heads_share(blocks, dtype):
     """The rotated key enters once a batch entry, never broadcast: the
     forward and all five gradients against the joined, broadcast form in
-    plain jax.numpy; the shared part's gradient is the heads' sum."""
+    plain jax.numpy; the shared part's gradient is the heads' sum.  The
+    backward is one kernel: the dK/dV walk makes dQ and dQr too."""
     B, L, H, D, Dr, Dv = 2, 256, 3, 32, 16, 24
     ks = jax.random.split(jax.random.key(13), 6)
-    q = jax.random.normal(ks[0], (B, L, H, D))
-    qr = jax.random.normal(ks[1], (B, L, H, Dr))
-    k = jax.random.normal(ks[2], (B, L, H, D))
-    kr = jax.random.normal(ks[3], (B, L, Dr))
-    v = jax.random.normal(ks[4], (B, L, H, Dv))
+    q = jax.random.normal(ks[0], (B, L, H, D), dtype)
+    qr = jax.random.normal(ks[1], (B, L, H, Dr), dtype)
+    k = jax.random.normal(ks[2], (B, L, H, D), dtype)
+    kr = jax.random.normal(ks[3], (B, L, Dr), dtype)
+    v = jax.random.normal(ks[4], (B, L, H, Dv), dtype)
     w = jax.random.normal(ks[5], (B, L, H, Dv))
 
     def joined(q, qr, k, kr, v):
@@ -107,16 +111,28 @@ def test_flash_kernels_take_a_key_part_that_the_heads_share(blocks):
                                 causal=True)
 
     def both(fn):
-        return jax.value_and_grad(lambda *a: jnp.sum(fn(*a) * w),
-                                  (0, 1, 2, 3, 4))(q, qr, k, kr, v)
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w),
+            (0, 1, 2, 3, 4))
 
-    got = both(lambda *a: fa.flash_attention_shared_key(
+    kernels = both(lambda *a: fa.flash_attention_shared_key(
         *a, block_q=blocks[0], block_k=blocks[1]))
-    want = both(joined)
-    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    fused = monitor.get_stat("pallas.flash.bwd_fused")
+    text = str(jax.make_jaxpr(kernels)(q, qr, k, kr, v))
+    assert monitor.get_stat("pallas.flash.bwd_fused") == fused + 1
+    assert text.count("name=flash_bwd_dkv") == 1
+    assert "flash_bwd_dq" not in text
+    got, want = kernels(q, qr, k, kr, v), both(joined)(q, qr, k, kr, v)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
     for g, r, name in zip(got[1], want[1], ("q", "qr", "k", "kr", "v")):
-        assert g.shape == r.shape, name
-        np.testing.assert_allclose(g, r, rtol=2e-4, atol=2e-5, err_msg=name)
+        assert g.shape == r.shape and g.dtype == dtype, name
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(g, r, rtol=2e-4, atol=2e-5,
+                                       err_msg=name)
+        else:
+            err = jnp.linalg.norm((g - r).astype(jnp.float32))
+            assert err < 0.03 * jnp.linalg.norm(r.astype(jnp.float32)), name
 
 
 def test_the_gate_takes_the_value_width_and_the_staging_budget():

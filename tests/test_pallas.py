@@ -3,6 +3,7 @@ compile on TPU — parity there was measured during bring-up).
 
 Modelled on the reference's fused-op tests (test_fused_attention_op.py
 pattern: fused output vs composed-op oracle, fwd + grad)."""
+import functools
 import glob
 import importlib
 import os
@@ -145,14 +146,117 @@ def test_flash_block_counters():
     # 4 x 4 blocks: 6 below the diagonal, 4 on it, 6 skipped
     assert counts(fwd(True), q, k, v) == (6, 4)
     assert counts(fwd(False), q, k, v) == (16, 0)
-    # differentiated: forward, dq and dkv kernels, the same pairs each
+    # differentiated: the forward kernel and the one backward walk, the
+    # same pairs each
     grad = jax.grad(lambda q, k, v: jnp.sum(fwd(True)(q, k, v)), (0, 1, 2))
-    assert counts(grad, q, k, v) == (18, 12)
+    assert counts(grad, q, k, v) == (12, 8)
     # the cell's shape under the chosen blocks (PERF.md): 2048 / 512
     from paddle_tpu.ops.pallas.flash_attention import _count_blocks
     assert counts(_count_blocks, 2048, 2048, 512, 512, True, True) == (6, 4)
     # the ring path masks every block
     assert counts(_count_blocks, 256, 256, 64, 64, True, False) == (0, 16)
+
+
+def _traces_one_backward_kernel(grad, *args):
+    """The dK/dV walk, under the name the benchmark reads, and no other."""
+    text = str(jax.make_jaxpr(grad)(*args))
+    return (len(re.findall(r"\bname=flash_bwd_dkv\b", text)) == 1
+            and "flash_bwd_dq" not in text)
+
+
+def _ring_block(q, k, v, q_off, k_off):
+    """``flash_attention_block`` on [B, L, H, D]: (out, lse [B, L, H])."""
+    off = [jnp.full((1, 1), o, jnp.float32) for o in (q_off, k_off)]
+    out, lse = fa.flash_attention_block(
+        *(jnp.swapaxes(a, 1, 2) for a in (q, k, v)), *off,
+        q.shape[-1] ** -0.5, 32, 32)
+    return jnp.swapaxes(out, 1, 2), jnp.swapaxes(lse, 1, 2)
+
+
+def _ring_block_reference(q, k, v, q_off, k_off):
+    s = jnp.einsum("blhd,bshd->bhls", q, k) * q.shape[-1] ** -0.5
+    seen = (q_off + jnp.arange(q.shape[1])[:, None]
+            >= k_off + jnp.arange(k.shape[1])[None])
+    s = jnp.where(seen, s, -1e30)
+    lse = jax.nn.logsumexp(s, -1)
+    p = jnp.where(seen, jnp.exp(s - lse[..., None]), 0.0)
+    return jnp.einsum("bhls,bshd->blhd", p, v), jnp.swapaxes(lse, 1, 2)
+
+
+# (Lq, Lk, D, Dv, causal, block_q, block_k)
+_BACKWARD_WALKS = {
+    "causal_4_k_blocks": (256, 256, 32, 32, True, 64, 64),
+    "bert_one_block": (128, 128, 64, 64, False, 128, 128),
+    "full_4x2_blocks": (256, 128, 32, 32, False, 64, 64),
+    "cross_short_q": (64, 256, 16, 16, False, 32, 64),
+    "cross_long_q": (256, 64, 16, 16, False, 64, 32),
+    "causal_keys_past_the_queries": (128, 256, 16, 16, True, 64, 64),
+    "causal_queries_past_the_keys": (256, 128, 16, 16, True, 64, 64),
+    "values_narrower": (256, 256, 48, 32, True, 64, 128),
+    "values_wider": (128, 128, 16, 40, False, 32, 64),
+}
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("walk", sorted(_BACKWARD_WALKS))
+def test_flash_backward_is_one_kernel(walk, dtype, tol):
+    """The dK/dV walk makes dQ too: a differentiated call traces one
+    backward kernel, under the name the benchmark reads, and its three
+    gradients are the oracle's (float32 tight, bfloat16 at the forward's
+    tolerance)."""
+    Lq, Lk, D, Dv, causal, block_q, block_k = _BACKWARD_WALKS[walk]
+    r = np.random.RandomState(21)
+    q = jnp.asarray(r.randn(2, Lq, 2, D), dtype)
+    k = jnp.asarray(r.randn(2, Lk, 2, D), dtype)
+    v = jnp.asarray(r.randn(2, Lk, 2, Dv), dtype)
+    w = jnp.asarray(r.randn(2, Lq, 2, Dv), jnp.float32)
+
+    def grads(fn):
+        return jax.grad(lambda q, k, v: jnp.sum(
+            fn(q, k, v, causal=causal).astype(jnp.float32) * w), (0, 1, 2))
+
+    flash = grads(functools.partial(flash_attention, block_q=block_q,
+                                    block_k=block_k))
+    assert _traces_one_backward_kernel(flash, q, k, v)
+    assert _counted(("pallas.flash.bwd_fused",), flash, q, k, v) == (1,)
+    for got, want, name in zip(flash(q, k, v),
+                               grads(mha_reference)(q, k, v), "qkv"):
+        assert got.shape == want.shape and got.dtype == dtype
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        assert (np.abs(got - want).max()
+                <= tol * max(1.0, np.abs(want).max())), name
+
+
+@pytest.mark.parametrize("q_off,k_off", [(0, 0), (64, 0), (0, 64), (32, 64)],
+                         ids=["diagonal", "earlier_shard", "later_shard",
+                              "half_hidden"])
+def test_flash_backward_of_the_ring_block_takes_the_lse_cotangent(q_off,
+                                                                  k_off):
+    """The ring path: position mask on every block, ``dlse`` folded into
+    delta, queries that see no key (a later shard hides them all)."""
+    r = np.random.RandomState(22)
+    q, k, v = (jnp.asarray(r.randn(1, 64, 2, 16), jnp.float32)
+               for _ in range(3))
+    w = jnp.asarray(r.randn(1, 64, 2, 16), jnp.float32)
+    u = jnp.asarray(r.randn(1, 64, 2), jnp.float32)
+
+    def grads(fn):
+        def loss(q, k, v):
+            out, lse = fn(q, k, v, q_off, k_off)
+            # a hidden query's lse is about -1e30: no cotangent there,
+            # as the ring's merge gives it none
+            return jnp.sum(out * w) + jnp.sum(
+                jnp.where(lse > -1e29, lse, 0.0) * u)
+        return jax.grad(loss, (0, 1, 2))
+
+    flash = grads(_ring_block)
+    assert _traces_one_backward_kernel(flash, q, k, v)
+    for got, want, name in zip(flash(q, k, v),
+                               grads(_ring_block_reference)(q, k, v), "qkv"):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-4, atol=1e-5, err_msg=name)
 
 
 def test_flash_cross_attention_shapes():

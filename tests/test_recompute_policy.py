@@ -54,7 +54,7 @@ class _AttentionBlock(nn.Layer):
 class FlashBlock(_AttentionBlock):
     """Over ``sdpa`` (the flash kernel)."""
     FORWARD = scopes.FLASH_FWD
-    BACKWARD = (scopes.FLASH_BWD_DQ, scopes.FLASH_BWD_DKV)
+    BACKWARD = (scopes.FLASH_BWD_DKV,)
 
     def attend(self, q, k, v):
         return F.scaled_dot_product_attention(q, k, v, is_causal=True)
