@@ -18,6 +18,10 @@ Also compiled in-graph (zero host syncs per step):
   reference's gradient-merge meta-optimizer
   (fleet/meta_optimizers/gradient_merge_optimizer.py:18,
   grad_merge_all_reduce_op_handle.cc) without the extra memory pass.
+- **device counters** (``observability.device_counter``): what the model
+  emitted while the step ran stays in the carry (``aux["counters"]``),
+  summed from step to step, until ``device_counters()`` reads it.  A
+  step that emits nothing has no such entry and is the same program.
 """
 from __future__ import annotations
 
@@ -27,10 +31,11 @@ from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import jaxpr_as_fun
 
 from ..core import autograd, rng
 from ..core.tensor import Tensor
-from ..observability import scopes, span
+from ..observability import device_counters, scopes, span
 from ..utils import monitor
 from .bind import bind, buffer_arrays, buffer_names, param_list
 
@@ -60,6 +65,10 @@ class TrainStep:
     ``accumulate_steps``: microbatch gradient accumulation inside the step
     (the global batch you pass is split into this many microbatches).
     """
+
+    # whether the step collects ``observability.device_counter`` emissions
+    # (SpmdTrainStep fixes the carry's shardings by key and does not)
+    _collects_counters = True
 
     def __init__(self, model, loss_fn: Callable, optimizer,
                  n_inputs: int = 1, donate: bool = False, scaler=None,
@@ -93,6 +102,8 @@ class TrainStep:
         self._lr_value = None
         self._lr_device = None
         self._buffer_objs = None
+        # name -> what a step emits; None until the first build has looked
+        self._counter_spec = None
         if self.scaler is not None:
             # let scaler.state_dict()/load_state_dict() see the in-graph
             # state (checkpoint correctness)
@@ -114,13 +125,14 @@ class TrainStep:
 
     def _wrap_loss_and_grad(self, fn):
         """Wrap the per-microbatch (b_cur, inputs, labels, kidx) ->
-        (loss, new_buffers, grads) function.  SpmdTrainStep overrides this
-        for fp16_allreduce (shard_map with reduced-precision grad psum)."""
+        (loss, new_buffers, grads, counted) function.  SpmdTrainStep
+        overrides this for fp16_allreduce (shard_map with
+        reduced-precision grad psum)."""
         return fn
 
     def _value_and_grad(self, loss_of, p_list):
-        """Differentiate ``loss_of`` (returns (scaled_loss, (loss, new_b)))
-        w.r.t. the stored param list, honoring ``recompute``."""
+        """Differentiate ``loss_of`` (returns (scaled_loss, (loss, new_b,
+        counted))) w.r.t. the stored param list, honoring ``recompute``."""
         if self._recompute:
             loss_of = jax.checkpoint(loss_of)
         return jax.value_and_grad(loss_of, has_aux=True)(p_list)
@@ -147,6 +159,7 @@ class TrainStep:
         K = self.accumulate_steps
         scaler = self.scaler
         grad_transform = self._grad_transform
+        collects = self._collects_counters
         if scaler is not None:
             sc = dict(incr_ratio=scaler._incr_ratio,
                       decr_ratio=scaler._decr_ratio,
@@ -188,7 +201,8 @@ class TrainStep:
                     p_model = self._decode_params(p_list)
                     with autograd.no_grad(), rng.seed_scope(k_mb), \
                             amp_scope(), jax.named_scope(scopes.LOSS):
-                        with bind(model, p_model, list(b_cur)) as res:
+                        with bind(model, p_model, list(b_cur)) as res, \
+                                device_counters.collect(collects) as counted:
                             out = model(*[Tensor(a) for a in mb_inputs])
                             lab = [Tensor(a) for a in mb_labels]
                             loss_t = loss_fn(out, *lab)
@@ -196,19 +210,22 @@ class TrainStep:
                         new_b = tuple(
                             _as_arr(res.new_buffers.get(n, old))
                             for n, old in zip(bnames, b_cur))
+                        # what the model emitted leaves the differentiated
+                        # trace as an output, like the loss
+                        counted = counted.stacked()
                     loss = loss_t.data
                     scaled = loss * scale if scaler is not None else loss
-                    return scaled, (loss, new_b)
+                    return scaled, (loss, new_b, counted)
 
-                (_, (loss, new_b)), grads = self._value_and_grad(
+                (_, (loss, new_b, counted)), grads = self._value_and_grad(
                     loss_of, list(p_cur))
-                return loss, new_b, grads
+                return loss, new_b, grads, counted
 
             loss_and_grad = self._wrap_loss_and_grad(loss_and_grad)
 
             if K <= 1:
-                loss, new_b, grads = loss_and_grad(p_arr, b_arr, inputs,
-                                                   labels, 0)
+                loss, new_b, grads, counted = loss_and_grad(
+                    p_arr, b_arr, inputs, labels, 0)
             else:
                 # gradient merge: scan over K microbatches, f32 accumulators
                 mb_in = tuple(a.reshape(K, a.shape[0] // K, *a.shape[1:])
@@ -219,18 +236,22 @@ class TrainStep:
                 def mb_body(carry, xs):
                     b_cur, g_acc, l_acc = carry
                     idx, ins, labs = xs
-                    loss, new_b, grads = loss_and_grad(p_arr, b_cur, ins,
-                                                       labs, idx)
+                    loss, new_b, grads, counted = loss_and_grad(
+                        p_arr, b_cur, ins, labs, idx)
                     g_acc = [ga + g.astype(jnp.float32)
                              for ga, g in zip(g_acc, grads)]
-                    return (new_b, g_acc, l_acc + loss), None
+                    return (new_b, g_acc, l_acc + loss), counted
 
                 g0 = [jnp.zeros(p.shape, jnp.float32) for p in p_arr]
-                (new_b, g_acc, l_sum), _ = jax.lax.scan(
+                (new_b, g_acc, l_sum), counted = jax.lax.scan(
                     mb_body, (tuple(b_arr), g0, jnp.zeros((), jnp.float32)),
                     (jnp.arange(K), mb_in, mb_lab))
                 loss = l_sum / K
                 grads = [g / K for g in g_acc]
+                # a step's count is the sum over its micro-batches (what
+                # they emit is only known once the body is traced, so it
+                # leaves the scan stacked, not in the carry)
+                counted = {n: jnp.sum(v, 0) for n, v in counted.items()}
 
             if scaler is not None:
                 with jax.named_scope(scopes.UNSCALE):
@@ -249,6 +270,10 @@ class TrainStep:
 
             new_aux = dict(aux)
             new_aux["draw"] = draw
+            if counted:
+                # this step's own; ``_carry_counters`` folds it into the
+                # carry's sums
+                new_aux["counters"] = counted
             if scaler is not None:
                 with jax.named_scope(scopes.SCALER):
                     # skip the update on non-finite grads (reference:
@@ -285,6 +310,64 @@ class TrainStep:
         donate = (0, 1, 2, 3) if self._donate else ()
         return jax.jit(self._make_step_fn(), donate_argnums=donate)
 
+    def _carry_counters(self, jitted, args):
+        """The step that ``__call__`` runs, from ``_build``'s: where the
+        model emits device counters, ``aux["counters"]`` comes in as the
+        carry (``last`` / ``total`` / ``steps`` a name) and goes out with
+        this step's emissions folded in; where it emits none, ``jitted``
+        itself.
+
+        What a step emits is known once it is traced, and the carry has
+        to be an argument before that.  So ``jitted`` is traced here
+        without the carry (jit keeps that trace: where nothing is
+        emitted, the call that follows finds it), and the carrying step
+        is that trace's jaxpr with the fold behind it, under the same
+        name and with the same donation: the model's Python runs once
+        either way."""
+        if not self._collects_counters:
+            return jitted
+        aux = {k: v for k, v in args[3].items() if k != "counters"}
+        traced = jitted.trace(*args[:3], aux, *args[4:])
+        spec = {name: jax.ShapeDtypeStruct(v.shape, v.dtype) for name, v
+                in traced.out_info[4].get("counters", {}).items()}
+        if self._counter_spec not in (None, spec):
+            # the model emits something else in this mode: what the old
+            # carry holds goes to the registry, and both modes build anew
+            self.device_counters()
+            self._scaler_state.pop("counters", None)
+            self._compiled.clear()
+        self._counter_spec = spec
+        if not spec:
+            return jitted
+        device_counters.register(self)
+        body = jaxpr_as_fun(traced.jaxpr)
+        out_tree = jax.tree.structure(traced.out_info)
+
+        def step_fn(p_arr, b_arr, opt_state, aux, lr, inputs, labels):
+            aux = dict(aux)
+            carry = aux.pop("counters")
+            loss, new_p, new_b, new_s, new_aux = jax.tree.unflatten(
+                out_tree, body(*jax.tree.leaves(
+                    (p_arr, b_arr, opt_state, aux, lr, inputs, labels))))
+            new_aux["counters"] = device_counters.fold(
+                carry, new_aux["counters"])
+            return loss, new_p, new_b, new_s, new_aux
+
+        return jax.jit(step_fn,
+                       donate_argnums=(0, 1, 2, 3) if self._donate else ())
+
+    def counter_carry(self):
+        """The device counters' part of the carry (None before the first
+        step, or where the model emits none)."""
+        return (self._scaler_state or {}).get("counters")
+
+    def device_counters(self):
+        """Read this step's device counters into ``utils.monitor``
+        (``observability.read_device_counters`` for one step): one
+        ``device_get`` that waits for the newest dispatched step, as
+        reading the loss does.  Returns the registry's view."""
+        return device_counters.read([self])
+
     def _aux_keys(self):
         """Static key set of the aux carry (no side effects — used to
         build shardings without consuming RNG state)."""
@@ -307,6 +390,8 @@ class TrainStep:
                 good=jnp.asarray(self.scaler._good_steps, jnp.int32),
                 bad=jnp.asarray(self.scaler._bad_steps, jnp.int32),
                 found_inf=jnp.asarray(False))
+        if self._counter_spec:
+            aux["counters"] = device_counters.zero_carry(self._counter_spec)
         return aux
 
     @property
@@ -340,12 +425,6 @@ class TrainStep:
             b_arr = tuple(buffer_arrays(self.model))
             if self._scaler_state is None:
                 self._scaler_state = self._init_scaler_state()
-            training = self.model.training
-            compiled = self._compiled.get(training)
-            if compiled is None:
-                compiled = self._build(training)
-                self._compiled[training] = compiled
-
             self.optimizer._step_count += 1
             lr_val = float(self.optimizer.get_lr())
             if lr_val != self._lr_value:
@@ -353,7 +432,24 @@ class TrainStep:
                 # host->device transfer stalls the dispatch pipeline)
                 self._lr_value = lr_val
                 self._lr_device = jnp.asarray(lr_val, jnp.float32)
+            training = self.model.training
+            compiled = self._compiled.get(training)
         t1 = time.perf_counter_ns()
+        if compiled is None:
+            # one-off: the step is traced here (``_carry_counters``), where
+            # it used to be traced inside its first call below, so this is
+            # kept out of the step's own python as that was
+            compiled = self._carry_counters(
+                self._build(training),
+                (p_arr, b_arr, self._opt_state, self._scaler_state,
+                 self._lr_device, inputs, labels))
+            self._compiled[training] = compiled
+            if self._counter_spec:
+                self._scaler_state.setdefault(
+                    "counters",
+                    device_counters.zero_carry(self._counter_spec))
+            t0 += time.perf_counter_ns() - t1
+            t1 = time.perf_counter_ns()
         with span("train_step.execute"):
             loss, new_p, new_b, new_s, new_sc = compiled(
                 p_arr, b_arr, self._opt_state, self._scaler_state,

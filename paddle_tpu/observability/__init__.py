@@ -17,6 +17,12 @@ The reproduction's four telemetry islands (profiler host spans,
   chrome-trace JSON or JSONL.  Disabled (the default), every
   instrumented hot path pays one module-attribute None-check
   (``core.obs_hook``, same pattern as ``core.profiler_hook``).
+- :func:`device_counter` is the one way out for a value inside a compiled
+  step (an expert's load, the branch a ``cond`` took): ``jit.TrainStep``
+  keeps what a step emitted in its device-resident carry, and
+  :func:`read_device_counters` moves it into ``utils.monitor`` when
+  somebody asks (:mod:`.device_counters`).  Host counters are
+  ``utils.monitor``'s from the start; both are read from there.
 - :func:`explain_compiles` attributes every XLA compile the static
   Executor, the jit layer and the inference Predictor performed to a
   named cause (new program version, new feed signature, new bucket,
@@ -56,6 +62,8 @@ import jax
 from ..core import obs_hook
 from .compiles import (annotate_compile, explain_compiles,
                        record_compile, reset_compiles)
+from .device_counters import (collecting, device_counter,
+                              read_device_counters)
 from .export import (TelemetryExporter, get_exporter, install_exporter,
                      uninstall_exporter)
 from .fleet import (FleetView, assemble_trace, collect_fleet_bundle,
@@ -76,7 +84,7 @@ from .tracer import EVENT_KINDS, Tracer
 __all__ = [
     "Tracer", "EVENT_KINDS", "enable", "disable", "enabled",
     "get_tracer", "emit", "span", "begin_span", "end_span", "counter",
-    "set_step",
+    "set_step", "device_counter", "collecting", "read_device_counters",
     "record_compile", "explain_compiles", "reset_compiles",
     "annotate_compile",
     "prometheus_text", "metrics_snapshot", "dump_metrics", "build_info",

@@ -17,9 +17,11 @@ under it, and the step executables are tens of megabytes):
 
 PERF.md (section 3) lists which benchmark metric reads which name.
 
-One more vocabulary lives here because the same tools read it: the
+Two more vocabularies live here because the same tools read them: the
 ``jax.ad_checkpoint.checkpoint_name`` names of values a rematerialisation
-policy may keep (``RESIDUALS``).  They are names of values, not scopes.
+policy may keep (``RESIDUALS``), and the names of the counters a compiled
+step keeps on the device (``DEVICE_COUNTERS``; ``device_counters.py`` says
+how they reach ``utils.monitor``).  They are names of values, not scopes.
 """
 from __future__ import annotations
 
@@ -96,3 +98,18 @@ DSA_KL_DW = "dsa_kl_dw"       # to the head weights, [B, J, T]
 DSA_KL_DK = "dsa_kl_dk"       # to the index key, [B, T, d]
 DSA_KL_GRADS = (DSA_KL_DQ, DSA_KL_DW, DSA_KL_DK)
 RESIDUALS = (ATTN_OUT, ATTN_LSE) + DSA_KL_GRADS
+
+
+# -- counters the compiled step keeps on the device ----------------------------
+# Emitted a call of ``ops/moe.py::moe_forward`` (one expert layer), int32;
+# a step's value is the calls stacked in their order.
+MOE_EXPERT_LOAD = "moe.expert_load"   # [held]: assignments each held expert got
+MOE_CHUNK_ASSIGNMENTS = "moe.chunk_assignments"  # [chunks]: held assignments
+                              # of each chunk, what its buffer is chosen by
+MOE_FULL_BUFFER_CHUNKS = "moe.full_buffer_chunks"  # chunks that took the
+                              # full buffer (their load was over the small one)
+MOE_FULLEST_EXPERT_LOAD = "moe.fullest_expert_load"  # the largest of
+                              # MOE_EXPERT_LOAD: a maximum does not survive
+                              # the sum over steps that a read returns
+DEVICE_COUNTERS = (MOE_EXPERT_LOAD, MOE_CHUNK_ASSIGNMENTS,
+                   MOE_FULL_BUFFER_CHUNKS, MOE_FULLEST_EXPERT_LOAD)

@@ -42,6 +42,12 @@ in the backward pass alike).  Both gathers have hand-written
 transposes: a permutation's transpose is the gather by its inverse,
 where autodiff would emit a scatter-add of rows, which a TPU runs a row
 at a time.
+
+Inside a step that collects device counters (``jit.TrainStep``) a call
+reports its load: ``moe.expert_load``, ``moe.chunk_assignments``,
+``moe.full_buffer_chunks`` (``_count_load``; observability/scopes.py),
+from the group sizes the products are given.  Elsewhere nothing is
+counted and the program is what it was.
 """
 from __future__ import annotations
 
@@ -50,7 +56,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..observability import scopes
+from ..observability import device_counters, scopes
 from ..utils import monitor
 
 __all__ = ["moe_route", "moe_experts", "moe_forward"]
@@ -168,6 +174,13 @@ def moe_experts(x, gates, local, w_gate, w_up, w_down, rows=None):
     the last) where it is not held.  ``rows``: the size of the experts'
     buffer, which must take every held assignment of the chunk (n * K,
     the default, always does).  -> [n, H] float32."""
+    return _experts(x, gates, local, w_gate, w_up, w_down, rows)[0]
+
+
+def _experts(x, gates, local, w_gate, w_up, w_down, rows):
+    """``moe_experts`` with the groups' sizes, which the grouped products
+    need anyway: -> ([n, H] float32, [held] int32, the assignments each
+    held expert got)."""
     n, K = local.shape
     held = w_gate.shape[0]
     P = n * K
@@ -194,7 +207,8 @@ def moe_experts(x, gates, local, w_gate, w_up, w_down, rows=None):
         # rows past the last held assignment belong to no group: whatever
         # the grouped product left there is not read
         y = jnp.where((jnp.arange(R) < jnp.sum(sizes))[:, None], y, 0)
-        return _combine(y, jnp.where(live, gates, 0.0), tok, slot, pos)
+        return _combine(y, jnp.where(live, gates, 0.0), tok, slot,
+                        pos), sizes
 
 
 # A chunk's buffer for the load it has.  The two gathers cost by the
@@ -214,14 +228,18 @@ def _small_buffer(n, top_k, held, total):
     return rows if rows < n * top_k else None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _chunk(small, x, gates, local, w_gate, w_up, w_down):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 7))
+def _chunk(small, x, gates, local, w_gate, w_up, w_down, counting=False):
     """``moe_experts`` with the buffer chosen by the chunk's load, and no
     residual but its arguments: the backward pass runs the forward again
     inside the branch it takes, so no [rows, H] buffer outlives a chunk
-    and the branch not taken leaves nothing behind."""
-    return _by_load(small, local, w_gate.shape[0], lambda rows: moe_experts(
-        x, gates, local, w_gate, w_up, w_down, rows))
+    and the branch not taken leaves nothing behind.  ``counting``: the
+    groups' sizes [held] are a second result (for ``_count_load``)."""
+    def fn(rows):
+        out, sizes = _experts(x, gates, local, w_gate, w_up, w_down, rows)
+        return (out, sizes) if counting else out
+
+    return _by_load(small, local, w_gate.shape[0], fn)
 
 
 def _by_load(small, local, held, fn):
@@ -234,13 +252,15 @@ def _by_load(small, local, held, fn):
                         functools.partial(fn, None))
 
 
-def _chunk_fwd(small, x, gates, local, w_gate, w_up, w_down):
-    return (_chunk(small, x, gates, local, w_gate, w_up, w_down),
+def _chunk_fwd(small, x, gates, local, w_gate, w_up, w_down, counting):
+    return (_chunk(small, x, gates, local, w_gate, w_up, w_down, counting),
             (x, gates, local, w_gate, w_up, w_down))
 
 
-def _chunk_bwd(small, res, d_out):
+def _chunk_bwd(small, counting, res, d_out):
     x, gates, local, w_gate, w_up, w_down = res
+    if counting:
+        d_out = d_out[0]          # the sizes are counts: no cotangent
 
     def grads(rows):
         _, pull = jax.vjp(
@@ -259,6 +279,34 @@ def _chunk_bwd(small, res, d_out):
 
 
 _chunk.defvjp(_chunk_fwd, _chunk_bwd)
+
+
+@jax.named_scope(scopes.MOE_ROUTER)
+def _count_load(sizes, small):
+    """What this call's router did, for whoever reads the step's device
+    counters (scopes.py).  ``sizes`` [chunks, held]: the assignments each
+    held expert got from each chunk, the grouped products' own group
+    sizes, handed out of the loop over chunks as a second result: a value
+    cannot leave a loop's body any other way, and a result of the loop is
+    made where the loop runs (counted beside it from the ids, XLA put
+    the count off to the step's end and held the ids of every layer until
+    then: 136 MB in the Keye cell)."""
+    load = jnp.sum(sizes, 0)
+    assigned = jnp.sum(sizes, 1)
+    # a vector over more experts or chunks than a counter may hold (256)
+    # stays in: its sum and its largest, below, are emitted all the same
+    for name, vector in ((scopes.MOE_EXPERT_LOAD, load),
+                         (scopes.MOE_CHUNK_ASSIGNMENTS, assigned)):
+        if vector.size * 4 <= device_counters.MAX_VALUE_BYTES:
+            device_counters.device_counter(name, vector)
+    device_counters.device_counter(scopes.MOE_FULLEST_EXPERT_LOAD,
+                                   jnp.max(load))
+    # ``_by_load``'s predicate; without a small buffer every chunk takes
+    # the full one
+    device_counters.device_counter(
+        scopes.MOE_FULL_BUFFER_CHUNKS,
+        jnp.sum(assigned > small, dtype=jnp.int32) if small is not None
+        else jnp.int32(sizes.shape[0]))
 
 
 @jax.named_scope(scopes.MOE)
@@ -299,10 +347,19 @@ def moe_forward(x32, router_w, w_gate, w_up, w_down, *, top_k, first,
     local = jnp.where((local >= 0) & (local < held), local, held)
     x = flat.astype(w_gate.dtype)
     small = _small_buffer(chunk, top_k, held, total)
-    out = jax.lax.map(lambda c: _chunk(small, *c, w_gate, w_up, w_down), (
-        x.reshape(N // chunk, chunk, H),
-        gates.reshape(N // chunk, chunk, top_k),
-        local.reshape(N // chunk, chunk, top_k)))
+    # what a chunk's gathers walk, beside moe.experts_held: with the device
+    # counters below a reader turns counts into rows gathered
+    monitor.stat_set("moe.small_buffer_rows", small or chunk * top_k)
+    monitor.stat_set("moe.full_buffer_rows", chunk * top_k)
+    counting = device_counters.collecting()
+    out = jax.lax.map(
+        lambda c: _chunk(small, *c, w_gate, w_up, w_down, counting),
+        (x.reshape(N // chunk, chunk, H),
+         gates.reshape(N // chunk, chunk, top_k),
+         local.reshape(N // chunk, chunk, top_k)))
+    if counting:
+        out, sizes = out
+        _count_load(sizes, small)
     if shared is not None:
         out = out.reshape(N, H) + _shared_expert(x, *shared)
     return out.reshape(shape)

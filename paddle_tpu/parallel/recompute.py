@@ -33,7 +33,12 @@ only behaviour: no argument selects it.
 ``optimization_barrier``.  A block that returns its stream and a loss term
 would otherwise have the term's kernel put off by XLA:TPU's scheduler until
 the backward pass needs what it keeps, its operands held all the while
-(1.6 GB in the Keye cell).  A segment with one output has no barrier."""
+(1.6 GB in the Keye cell).  A segment with one output has no barrier.
+
+**Device counters** (``observability.device_counter``) emitted inside a
+segment leave its checkpoint as outputs and are emitted again outside.
+They are not the block's outputs: the barrier's count does not see them,
+and the replay's copies of them are dead code."""
 from __future__ import annotations
 
 import functools
@@ -43,7 +48,7 @@ import jax
 from ..core import autograd, dispatch
 from ..core.tensor import Tensor
 from ..jit.bind import bind, param_list
-from ..observability import scopes
+from ..observability import device_counters, scopes
 from ..utils import monitor
 
 _keeps_named = jax.checkpoint_policies.save_only_these_names(
@@ -85,6 +90,8 @@ def recompute(function, *args, **kwargs):
     tensors = [a for a in args if isinstance(a, Tensor)]
     statics = [a for a in args if not isinstance(a, Tensor)]
     n_p = len(params)
+    # a collector of the segment's own, where somebody collects outside
+    collects = device_counters.collecting()
 
     @functools.partial(jax.checkpoint, policy=_keep_attention_residuals)
     def pure_fn(*arrays):
@@ -93,7 +100,8 @@ def recompute(function, *args, **kwargs):
         it = iter(in_arr)
         rebuilt = [Tensor(next(it)) if isinstance(a, Tensor) else a
                    for a in args]
-        with autograd.no_grad():
+        with autograd.no_grad(), \
+                device_counters.collect(collects) as counted:
             if layer is not None:
                 # ``forward`` is called, not ``__call__``, so the layer's
                 # named scope is entered here
@@ -102,12 +110,14 @@ def recompute(function, *args, **kwargs):
                     out = fn(*rebuilt, **kwargs)
             else:
                 out = fn(*rebuilt, **kwargs)
+            counted = counted.stacked()
         return jax.tree.map(
             lambda t: t.data if isinstance(t, Tensor) else t, out,
-            is_leaf=lambda x: isinstance(x, Tensor))
+            is_leaf=lambda x: isinstance(x, Tensor)), counted
 
     def segment(*arrays):
-        out = pure_fn(*arrays)
+        out, counted = pure_fn(*arrays)
+        device_counters.re_emit(counted)
         # see the module's docstring: the output the next segment does not
         # read is not put off past it
         if len(jax.tree.leaves(out)) > 1:

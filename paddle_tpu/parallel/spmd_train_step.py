@@ -63,6 +63,10 @@ def _shardable(shape, n):
 class SpmdTrainStep(TrainStep):
     """TrainStep + mesh shardings.  ``strategy`` controls ZeRO stage etc."""
 
+    # the carry's shardings are laid out a key in ``_build``: device
+    # counters are not collected here (``device_counter`` is a no-op)
+    _collects_counters = False
+
     def __init__(self, model, loss_fn, optimizer, mesh=None,
                  strategy: Optional[DistributedStrategy] = None,
                  n_inputs: int = 1, donate: bool = True, scaler=None,
@@ -299,7 +303,7 @@ class SpmdTrainStep(TrainStep):
                 # invariant cotangent) so the ONLY reduction is ours below
                 p_var = [jax.lax.pcast(a, DP_AXIS, to="varying")
                          for a in p_cur]
-                loss, new_b, grads = fn(p_var, b_cur, ins, labs, k)
+                loss, new_b, grads, _ = fn(p_var, b_cur, ins, labs, k)
                 # bucketed quantize → reduce → dequantize: the wire
                 # carries the plan's dtype (bf16 subsumes the old
                 # fp16_allreduce cast/recast pair,
@@ -313,7 +317,7 @@ class SpmdTrainStep(TrainStep):
                 loss = jax.lax.pmean(loss, DP_AXIS)
                 new_b = jax.tree.map(
                     lambda a: jax.lax.pmean(a, DP_AXIS), new_b)
-                return loss, new_b, grads
+                return loss, new_b, grads, {}
 
             from jax import shard_map
             P = PartitionSpec

@@ -116,7 +116,8 @@ def _cell_step(one_chip, monkeypatch, cell_name, kernel_modules):
     """A benchmark cell's whole step as the train_step runner builds it
     (model -> amp O2 -> AdamW with clip -> TrainStep(donate=True)), at the
     published widths, compiled for the described v5e.  -> (compiled,
-    parameters, cfg, mix, footprint as benchmark/run.py counts it)."""
+    parameters, cfg, mix, footprint as benchmark/run.py counts it, the
+    step)."""
     import json
     import sys
     from paddle_tpu import amp, nn, optimizer
@@ -158,16 +159,49 @@ def _cell_step(one_chip, monkeypatch, cell_name, kernel_modules):
     opt_state = jax.tree.map(sds, jax.eval_shape(
         opt.functional_init, list(params)))
     ids = _sds(one_chip, (mix["batch"], mix["seq"]), jnp.int32)
-    compiled = step._build(True).lower(
-        params, (), opt_state, jax.tree.map(sds, step._init_scaler_state()),
-        _sds(one_chip, (), jnp.float32), (ids,), (ids,)).compile()
+    args = [params, (), opt_state,
+            jax.tree.map(sds, step._init_scaler_state()),
+            _sds(one_chip, (), jnp.float32), (ids,), (ids,)]
+    # as ``__call__`` builds it: where the model emits device counters the
+    # carry holds them from here on
+    jitted = step._carry_counters(step._build(True), tuple(args))
+    args[3] = jax.tree.map(sds, step._init_scaler_state())
+    compiled = jitted.lower(*args).compile()
     m = compiled.memory_analysis()
     footprint = (m.argument_size_in_bytes + m.output_size_in_bytes
                  - m.alias_size_in_bytes + m.temp_size_in_bytes
                  + m.generated_code_size_in_bytes)
-    print(f"{cell_name} step: {n} parameters, footprint {footprint} bytes "
+    print(f"{cell_name} step: {n} parameters, footprint {footprint} bytes, "
+          f"{len(jax.tree.leaves(compiled.out_info))} outputs "
           f"({json.dumps({k: getattr(m, k) for k in dir(m) if k.endswith('_in_bytes') and not k.startswith('host')})})")
-    return compiled, n, cfg, mix, footprint
+    return compiled, n, cfg, mix, footprint, step
+
+
+def _same_program_as_without_counters(compiled, step, footprint, on_record):
+    """A step whose model emits no device counter is the program it was
+    before there were any: no carry for them, and the (output count,
+    footprint) ``on_record`` (PR 33's tree, compiled by this test: under
+    conftest's matmul precision, so not the benchmark's program to the
+    byte; a PR that changes the cell's step reads the printed line and
+    moves them)."""
+    assert step._counter_spec == {}
+    assert (len(jax.tree.leaves(compiled.out_info)),
+            footprint) == on_record
+
+
+def _carries_the_moe_counters(compiled, step, calls, chunks, live_peak):
+    """The expert layers' counters are in the carry, a row a call, and
+    the step's live peak is what it was without them (PR 33's tree) to
+    1 MB.  The footprint as ``benchmark/run.py`` counts it is the heap as
+    XLA packed it, which moved by more (PERF.md, PR 34)."""
+    from paddle_tpu.observability import scopes
+    assert {k: v.shape for k, v in step._counter_spec.items()} == {
+        scopes.MOE_EXPERT_LOAD: (calls, 16),
+        scopes.MOE_CHUNK_ASSIGNMENTS: (calls, chunks),
+        scopes.MOE_FULL_BUFFER_CHUNKS: (calls,),
+        scopes.MOE_FULLEST_EXPERT_LOAD: (calls,)}
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert abs(peak - live_peak) < 2 ** 20, peak
 
 
 def _kernel_count(text, kernel):
@@ -181,9 +215,11 @@ def test_evabyte_cell_step_fits_the_chip(one_chip, monkeypatch):
     from paddle_tpu.observability import scopes
     from paddle_tpu.utils import monitor
     monitor.stat_reset()
-    compiled, n, cfg, mix, footprint = _cell_step(
+    compiled, n, cfg, mix, footprint, step = _cell_step(
         one_chip, monkeypatch, "evabyte.train_bf16_b1_s8192",
         ("eva_attention", "flash_attention"))
+    _same_program_as_without_counters(compiled, step, footprint,
+                                      (192, 14_658_201_088))
     assert cfg["hidden_size"] == 4096 and mix["seq"] == 8192
     assert 821e6 < n < 822e6
     text = compiled.as_text()
@@ -209,9 +245,13 @@ def test_keye_vl2_cell_step_fits_the_chip(one_chip, monkeypatch):
     from paddle_tpu.observability import scopes
     from paddle_tpu.utils import monitor
     monitor.stat_reset()
-    compiled, n, cfg, mix, footprint = _cell_step(
+    compiled, n, cfg, mix, footprint, step = _cell_step(
         one_chip, monkeypatch, "keye_vl2_30b_a3b.train_bf16_b4_s8192",
         ("sparse_attention", "flash_attention"))
+    _carries_the_moe_counters(compiled, step, calls=4, chunks=4,
+                              live_peak=12_420_812_800)
+    # 13,305,999,360 without the counters: the heap packs 68 MB worse
+    assert footprint < 13_305_999_360 + 80e6
     assert cfg["hidden_size"] == 2048 and mix["seq"] == 8192
     assert 465e6 < n < 466e6
     text = compiled.as_text()
@@ -312,21 +352,23 @@ def _one_backward_kernel_a_block(text, blocks):
     assert monitor.get_stat("pallas.flash.bwd_fused") == blocks
 
 
-@pytest.mark.parametrize("cell_name,blocks,kept,parameters", [
-    ("gpt3_large.train_bf16_b8_s2048", 24, 24, 760e6),
-    ("bert_base.train_bf16_b64_s512", 12, 0, 132e6),
+@pytest.mark.parametrize("cell_name,blocks,kept,parameters,on_record", [
+    ("gpt3_large.train_bf16_b8_s2048", 24, 24, 760e6,
+     (1556, 15_094_667_264)),
+    ("bert_base.train_bf16_b64_s512", 12, 0, 132e6, (796, 14_093_140_992)),
 ], ids=["gpt", "bert"])
 def test_flash_cell_step_runs_one_backward_kernel(one_chip, monkeypatch,
                                                   cell_name, blocks, kept,
-                                                  parameters):
+                                                  parameters, on_record):
     """The GPT and BERT cells' whole steps for the described v5e: causal
     at 2048 x 96 under per-block recompute, non-causal at 512 x 64 in one
     block a (batch, head)."""
     from paddle_tpu.observability import scopes
     from paddle_tpu.utils import monitor
     monitor.stat_reset()
-    compiled, n, cfg, mix, footprint = _cell_step(
+    compiled, n, cfg, mix, footprint, step = _cell_step(
         one_chip, monkeypatch, cell_name, ("flash_attention",))
+    _same_program_as_without_counters(compiled, step, footprint, on_record)
     assert 0.9 * parameters < n < 1.1 * parameters
     _one_backward_kernel_a_block(compiled.as_text(), blocks)
     stats = monitor.all_stats()
@@ -346,9 +388,14 @@ def test_joyai_llm_flash_cell_step_fits_the_chip(one_chip, monkeypatch):
     from paddle_tpu.observability import scopes
     from paddle_tpu.utils import monitor
     monitor.stat_reset()
-    compiled, n, cfg, mix, footprint = _cell_step(
+    compiled, n, cfg, mix, footprint, step = _cell_step(
         one_chip, monkeypatch, "joyai_llm_flash.train_bf16_b2_s8192",
         ("flash_attention",))
+    # four expert layers and the MTP block's
+    _carries_the_moe_counters(compiled, step, calls=5, chunks=2,
+                              live_peak=14_037_525_504)
+    # 14,736,134,656 without the counters
+    assert abs(footprint - 14_736_134_656) < 2 * 2 ** 20
     assert cfg["hidden_size"] == 2048 and mix["seq"] == 8192
     assert cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"] == 192
     assert 680.3e6 < n < 680.5e6
