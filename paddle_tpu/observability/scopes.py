@@ -78,12 +78,13 @@ DSA_THRESHOLD = "dsa_threshold"  # the topk-th largest score a query
 DSA_KL = "dsa_kl"             # head-summed probabilities, the KL term and,
                               # differentiated, its gradient to qI, w and kI
 SPARSE_FWD = "sparse_fwd"
-SPARSE_BWD_DQ = "sparse_bwd_dq"
-SPARSE_BWD_DKV = "sparse_bwd_dkv"
+SPARSE_BWD_DKV = "sparse_bwd_dkv"  # the backward walk: dk, dv and dq; the
+                              # name holds all of the backward, as
+                              # FLASH_BWD_DKV does
 KERNELS = (FLASH_FWD, FLASH_BWD_DKV, EPILOGUE_FWD, EPILOGUE_BWD,
            FUSED_ADAM, PAGED_ATTENTION, COLLECTIVE_MATMUL_CHUNK, EVA_FWD,
            EVA_BWD_DQ, DSA_SCORES, DSA_THRESHOLD, DSA_KL, SPARSE_FWD,
-           SPARSE_BWD_DQ, SPARSE_BWD_DKV)
+           SPARSE_BWD_DKV)
 
 
 # -- values named for a rematerialisation policy -----------------------------

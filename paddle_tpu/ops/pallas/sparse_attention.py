@@ -22,7 +22,7 @@ shared by all heads: ``[B, keys, queries]`` int8, keys on the major axis
 because every kernel here holds its score tile as [block_k, block_q]
 like the flash kernels (flash_attention.py, whose helpers these share).
 
-Kernels, all [block, block] tiles of 512:
+Kernels (five), all [block, block] tiles of 512:
 
 - ``dsa_scores``: I^T tiles, written below and on the diagonal only;
 - ``dsa_threshold``: the topk-th largest score a query by bisection over
@@ -30,11 +30,14 @@ Kernels, all [block, block] tiles of 512:
   counting passes); where the scores equal to it are more than fit, the
   position of the last one kept (a second bisection, over positions);
   and the logsumexp of the selected scores;
-- ``sparse_fwd`` / ``sparse_bwd_dq`` / ``sparse_bwd_dkv``: the flash
-  kernels with the mask tile in place of the causal rule and a grid
-  whose innermost axis walks the G query heads of one key/value head,
-  so that k, v and the mask column are fetched once a group; dk/dv sum
-  over the group in VMEM;
+- ``sparse_fwd``: the flash forward kernel with the mask tile in place
+  of the causal rule and a grid whose innermost axis walks the G query
+  heads of one key/value head, so that k, v and the mask column are
+  fetched once a group;
+- ``sparse_bwd_dkv``: all of the backward in one walk (key block by key
+  block inside a head, head by head inside a group): dk / dv sum over
+  the group in VMEM, and each pair's ``ds`` makes its part of the head's
+  dQ^T too, as the flash walk's does;
 - ``dsa_kl``: the head-summed probabilities (one more QK pass over all
   A heads), the KL term and, in the same pass where the call is
   differentiated, its gradient to qI, kI and w up to the loss's own
@@ -501,34 +504,25 @@ def _p_ds(s, lse, do, v, delta):
     return p, p * (dp - delta)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
-                   delta_ref, dq_ref, *, scale, block):
-    i = pl.program_id(2)
-    q = _prescale(q_ref[0, 0], scale)
-    do = do_ref[0, 0]
-    lse = lse_ref[0, 0][0:1, :]
-    delta = delta_ref[0, 0][0:1, :]
-    bq, d = q.shape
+def _bwd_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, scale,
+                block, num_q):
+    """The backward walk: key block ``j`` of one head against the query
+    blocks at or after it.  Each pair's ``ds`` meets q for dK, and k for
+    that query block's dQ^T, which adds up in float32 over the head's key
+    blocks (``dq_acc`` [T / block, D, block]); dK and dV add up over the
+    group's heads in ``dk_acc`` / ``dv_acc`` [T, D] and leave with the
+    group's last head.  No second walk recomputes the scores, p and dP."""
+    h, j = pl.program_id(2), pl.program_id(3)
 
-    def body(j, dq):
-        k = _rows(k_ref, j, block)
-        s = _masked_scores(k, q, _rows3(mask_ref, j, block))
-        _, ds = _p_ds(s, lse, do, _rows(v_ref, j, block), delta)
-        return dq + _dot(k, ds.astype(k.dtype), ((0,), (0,)))
-
-    dq = jax.lax.fori_loop(0, i + 1, body, jnp.zeros((d, bq), jnp.float32))
-    dq_ref[0, 0] = (dq * scale).T.astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
-                    delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
-                    block, num_q):
-    j, h = pl.program_id(2), pl.program_id(3)
-
-    @pl.when(h == 0)
+    @pl.when(j == 0)
     def _():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+        @pl.when(h == 0)
+        def _():
+            dk_acc[...] = jnp.zeros_like(dk_acc)
+            dv_acc[...] = jnp.zeros_like(dv_acc)
 
     k = k_ref[0, 0]                                       # [BK, D]
     v = v_ref[0, 0]
@@ -541,18 +535,32 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
         s = _masked_scores(k, q, mask_ref[0, :, cols])
         p, ds = _p_ds(s, lse_ref[0, 0, 0:1, cols], do, v,
                       delta_ref[0, 0, 0:1, cols])
-        return (dk + _dot(ds.astype(q.dtype), q, ((1,), (0,))),
+        ds = ds.astype(q.dtype)
+        dq_acc[i] += _dot(k, ds, ((0,), (0,)))            # [D, BQ]
+        return (dk + _dot(ds, q, ((1,), (0,))),
                 dv + _dot(p.astype(do.dtype), do, ((1,), (0,))))
 
     zero = jnp.zeros(k.shape, jnp.float32)
     dk, dv = jax.lax.fori_loop(j, num_q, body, (zero, zero))
-    dk_acc[...] += dk
-    dv_acc[...] += dv
+    rows = pl.ds(pl.multiple_of(j * block, block), block)
+    dk_acc[rows, :] += dk
+    dv_acc[rows, :] += dv
 
-    @pl.when(h == pl.num_programs(3) - 1)
+    @pl.when(h == pl.num_programs(2) - 1)
     def _():
-        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[0, 0, rows, :] = dk_acc[rows, :].astype(dk_ref.dtype)
+        dv_ref[0, 0, rows, :] = dv_acc[rows, :].astype(dv_ref.dtype)
+
+    @pl.when(j == num_q - 1)
+    def _():
+        def emit(i, carry):
+            at = pl.ds(pl.multiple_of(i * block, block), block)
+            # s was taken against scale * q: the chain rule's scale, once,
+            # and each [D, BQ] block transposed once a head
+            dq_ref[0, 0, at, :] = (dq_acc[i] * scale).T.astype(dq_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, num_q, emit, 0)
 
 
 def _group_specs(T, D, G, block):
@@ -595,49 +603,49 @@ def _rows8(x):
 
 
 def _bwd(q, k, v, mask, out, lse, do, scale, block):
+    """One kernel -> (dq, dk, dv), on the grid (batch, key/value head, head
+    of the group, key block).  A head's q, dO, lse and delta and its dQ
+    are whole [T, .] blocks whose index holds over its key blocks; dk / dv
+    are whole blocks whose index holds over the group; k, v and the
+    mask's [block, T] strip come in a step.  At [.., 8192, 128] bfloat16,
+    counted as ``flash_attention._staging`` counts (two buffers a block):
+    q, dO and dQ 3 x 2 x 2 MiB, dk and dv 2 x 2 x 2, the strip 2 x 4,
+    lse and delta 1, k and v 0.5, the float32 accumulators 4 + 4 + 4:
+    41.5 MiB before the [block, block] float32 tiles."""
+    from ...utils import monitor
     B, A, T, D = q.shape
     KV = k.shape[1]
     G = A // KV
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), -1)
-    lse8, delta8 = _rows8(lse), _rows8(delta)
-    q_block, kv_whole, mask_col, row = _group_specs(T, D, G, block)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, block=block),
-        grid=(B, KV, T // block, G),
-        in_specs=[q_block, kv_whole, kv_whole, mask_col, q_block, row, row],
-        out_specs=q_block,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_params("parallel", "parallel", "parallel",
-                                "arbitrary"),
-        interpret=_interpret(),
-        name=scopes.SPARSE_BWD_DQ,
-    )(q, k, v, mask, do, lse8, delta8)
 
-    q_whole = pl.BlockSpec((1, 1, T, D),
-                           lambda b, g, j, h: (b, g * G + h, 0, 0))
-    kv_block = pl.BlockSpec((1, 1, block, D),
-                            lambda b, g, j, h: (b, g, j, 0))
-    row_whole = pl.BlockSpec((1, 1, 8, T),
-                             lambda b, g, j, h: (b, g * G + h, 0, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, block=block,
+    def head(b, g, h, j):
+        return b, g * G + h, 0, 0
+
+    q_whole = pl.BlockSpec((1, 1, T, D), head)
+    row_whole = pl.BlockSpec((1, 1, 8, T), head)
+    kv_block = pl.BlockSpec((1, 1, block, D), lambda b, g, h, j: (b, g, j, 0))
+    kv_whole = pl.BlockSpec((1, 1, T, D), lambda b, g, h, j: (b, g, 0, 0))
+    monitor.stat_add("pallas.sparse.bwd_fused")
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, block=block,
                           num_q=T // block),
-        grid=(B, KV, T // block, G),
+        grid=(B, KV, G, T // block),
         in_specs=[q_whole, kv_block, kv_block,
-                  pl.BlockSpec((1, block, T), lambda b, g, j, h: (b, j, 0)),
+                  pl.BlockSpec((1, block, T), lambda b, g, h, j: (b, j, 0)),
                   q_whole, row_whole, row_whole],
-        out_specs=[kv_block, kv_block],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+        out_specs=[q_whole, kv_whole, kv_whole],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block, D), jnp.float32),
-                        pltpu.VMEM((block, D), jnp.float32)],
-        # dk / dv are summed over the heads of the group
-        compiler_params=_params("parallel", "parallel", "parallel",
+        scratch_shapes=[pltpu.VMEM((T // block, D, block), jnp.float32),
+                        pltpu.VMEM((T, D), jnp.float32),
+                        pltpu.VMEM((T, D), jnp.float32)],
+        # dq is summed over a head's key blocks, dk / dv over the group too
+        compiler_params=_params("parallel", "parallel", "arbitrary",
                                 "arbitrary"),
         interpret=_interpret(),
         name=scopes.SPARSE_BWD_DKV,
-    )(q, k, v, mask, do, lse8, delta8)
-    return dq, dk, dv
+    )(q, k, v, mask, do, _rows8(lse), _rows8(delta))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))

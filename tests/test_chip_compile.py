@@ -76,11 +76,16 @@ def test_flash_attention_fwd_bwd(one_chip, monkeypatch, shape, causal,
     _one_backward_kernel(compiled)
 
 
-def _one_backward_kernel(compiled):
-    """The forward kernel and the dK/dV walk that makes dQ too."""
-    kernels = re.findall(
+def _mosaic_kernels(compiled):
+    """The names of the executable's Mosaic kernel calls, in order."""
+    return re.findall(
         r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"',
         compiled.as_text())
+
+
+def _one_backward_kernel(compiled):
+    """The forward kernel and the dK/dV walk that makes dQ too."""
+    kernels = _mosaic_kernels(compiled)
     assert len(kernels) == 2 and "flash_fwd" in kernels[0], kernels
     assert "flash_bwd_dkv" in kernels[1], kernels
 
@@ -230,6 +235,7 @@ def test_evabyte_cell_step_fits_the_chip(one_chip, monkeypatch):
         assert _kernel_count(text, kernel) == L, kernel
     # the windows ask the flash walk for dK and dV alone
     assert "pallas.flash.bwd_fused" not in monitor.all_stats()
+    assert "pallas.sparse.bwd_fused" not in monitor.all_stats()
     # the policy keeps by name: nothing here carries the indexer's names
     stats = monitor.all_stats()
     assert [stats.get(f"recompute.kept.{name}", 0)
@@ -260,7 +266,8 @@ def test_keye_vl2_cell_step_fits_the_chip(one_chip, monkeypatch):
     # gradients the loss's kernel makes with its value: one forward and
     # one ``dsa_kl`` a layer, the latter in the forward pass only; the
     # selection's kernels run in the forward pass and the replay
-    for kernel, calls in (("sparse_fwd", L), ("sparse_bwd_dq", L),
+    # and one backward kernel: the dK/dV walk makes dQ too
+    for kernel, calls in (("sparse_fwd", L), ("sparse_bwd_dq", 0),
                           ("sparse_bwd_dkv", L), ("dsa_kl", L),
                           ("dsa_kl_bwd", 0),
                           ("dsa_scores", 2 * L), ("dsa_threshold", 2 * L)):
@@ -269,8 +276,44 @@ def test_keye_vl2_cell_step_fits_the_chip(one_chip, monkeypatch):
     stats = monitor.all_stats()
     assert [stats[f"recompute.kept.{name}"] for name in scopes.RESIDUALS] \
         == [L] * len(scopes.RESIDUALS)
+    assert stats["pallas.sparse.bwd_fused"] == L
     assert "pallas.flash.bwd_fused" not in stats
     assert footprint < 15.75 * 2 ** 30
+
+
+@pytest.mark.parametrize("q_shape,k_shape,dtype", [
+    ((4, 32, 8192, 128), (4, 4, 8192, 128), jnp.bfloat16),   # the Keye cell
+    ((1, 8, 4096, 128), (1, 8, 4096, 128), jnp.float32),     # a group of one
+], ids=["keye_cell", "float32_no_group"])
+def test_sparse_attention_backward_walk(one_chip, monkeypatch, q_shape,
+                                        k_shape, dtype):
+    """The sparse family's backward alone, one kernel for dq, dk and dv,
+    at the largest rows the gate admits (T x D x itemsize = 2 MiB), under
+    the file's stated ``_VMEM_LIMIT``.  What it holds at [.., 8192, 128]
+    bfloat16, blocks of 512, two buffers a block as ``_staging`` counts:
+    q, dO and the dQ block of a head 3 x 2 x 2 MiB; the dk and dv blocks
+    of a group 2 x 2 x 2 MiB; the mask's [512, 8192] int8 strip 2 x 4
+    MiB; lse and delta [8, 8192] float32 2 x 2 x 0.25 MiB; the k and v
+    blocks 2 x 2 x 0.125 MiB; the float32 accumulators, dQ^T
+    [16, 128, 512] 4 MiB and dk, dv [8192, 128] 4 + 4 MiB: 41.5 MiB
+    before the [512, 512] float32 tiles (the head outside the key block;
+    all 8 heads' accumulators resident with the head innermost would be
+    over 80)."""
+    sa = importlib.import_module("paddle_tpu.ops.pallas.sparse_attention")
+    monkeypatch.setattr(sa, "_interpret", lambda: False)
+    B, A, T, D = q_shape
+    assert sa.sparse_attention_supported((B, T, A, D),
+                                         (B, T, k_shape[1], D), dtype)
+    assert sa._VMEM_LIMIT == 100 * 2 ** 20
+    q, kv = _sds(one_chip, q_shape, dtype), _sds(one_chip, k_shape, dtype)
+
+    def bwd(q, k, v, mask, out, lse, do):
+        return sa._bwd(q, k, v, mask, out, lse, do, D ** -0.5, 512)
+
+    compiled = _compile(bwd, q, kv, kv, _sds(one_chip, (B, T, T), jnp.int8),
+                        q, _sds(one_chip, (B, A, T), jnp.float32), q)
+    kernels = _mosaic_kernels(compiled)
+    assert len(kernels) == 1 and "sparse_bwd_dkv" in kernels[0], kernels
 
 
 def test_mla_flash_attention_fwd_bwd(one_chip, monkeypatch):
@@ -350,6 +393,7 @@ def _one_backward_kernel_a_block(text, blocks):
                           ("flash_bwd_dq", 0)):
         assert _kernel_count(text, kernel) == calls, kernel
     assert monitor.get_stat("pallas.flash.bwd_fused") == blocks
+    assert "pallas.sparse.bwd_fused" not in monitor.all_stats()
 
 
 @pytest.mark.parametrize("cell_name,blocks,kept,parameters,on_record", [

@@ -82,7 +82,7 @@ class SparseBlock(_AttentionBlock):
     """Over ``F.dsa_indexer`` / ``F.sparse_attention``, the indexer's loss
     (``F.dsa_indexer_loss``) added to the stream: top 12 of rows of 32."""
     FORWARD = scopes.SPARSE_FWD
-    BACKWARD = (scopes.SPARSE_BWD_DQ, scopes.SPARSE_BWD_DKV)
+    BACKWARD = (scopes.SPARSE_BWD_DKV,)
 
     def __init__(self):
         super().__init__()
@@ -204,14 +204,14 @@ def test_forward_kernels_a_layer(kernels, block, wrap, forwards):
 def test_the_replayed_segment_holds_no_loss_kernel(kernels, pallas_eqns, wrap,
                                                    replayed):
     """What a sparse block's replay (the gradient program's ``remat2``
-    equations) runs besides the backward kernels: the selection (no
+    equations) runs besides the backward kernel: the selection (no
     gradient, nothing named) and, with nothing kept, the attention's
     forward and ``dsa_kl``."""
     loss, arrays = _loss_of(SparseBlock, wrap)
     ran = [eqn.params["name"] for eqn in pallas_eqns(
         jax.make_jaxpr(jax.grad(loss))(arrays).jaxpr, within="remat2")]
     assert set(ran) - set(SparseBlock.BACKWARD) == replayed
-    assert len(ran) == LAYERS * (len(replayed) + 2)
+    assert len(ran) == LAYERS * (len(replayed) + len(SparseBlock.BACKWARD))
 
 
 # (3): the kept values are the ones the replay would have recomputed

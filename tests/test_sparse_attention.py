@@ -153,6 +153,44 @@ def test_kernels_match_the_xla_path_in_bfloat16(kernels_on):
     assert float(kl_k) == pytest.approx(float(kl_x), rel=2e-2)
 
 
+@pytest.mark.parametrize("topk", [24, 96], ids=["top24", "causal"])
+@pytest.mark.parametrize("key_blocks", [1, 3])
+@pytest.mark.parametrize("group", [1, 4])
+def test_the_backward_walk_makes_dq_dk_and_dv(kernels_on, pallas_eqns, group,
+                                              key_blocks, topk):
+    """One kernel in the backward (``sparse_bwd_dkv``) and its dq, dk and
+    dv against the blocked XLA path in float32.  One block a row zeroes
+    and emits in the same grid step; three carry a head's dQ^T across its
+    key blocks and dk / dv across the heads of a group; ``topk`` 96 over
+    rows of 96 keeps every visible key (the causal case)."""
+    block, seq = 96 // key_blocks, 96
+    ks = jax.random.split(jax.random.key(7), 7)
+    q = jax.random.normal(ks[0], (2, seq, 4, D))
+    k, v = (jax.random.normal(a, (2, seq, 4 // group, D)) for a in ks[1:3])
+    mask, _ = sa.dsa_select_xla(
+        jax.random.normal(ks[3], (2, seq, J, DI)),
+        0.3 * jax.random.normal(ks[4], (2, seq, J)),
+        jax.random.normal(ks[5], (2, seq, DI)), topk)
+    ct = jax.random.normal(ks[6], q.shape)
+
+    def through(fn, **kw):
+        return jax.grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v, mask, **kw)[0] * ct),
+            (0, 1, 2))
+
+    kernels = through(sa.sparse_attention, block=block)
+    before = monitor.get_stat("pallas.sparse.bwd_fused")
+    names = [e.params["name"] for e in pallas_eqns(
+        jax.make_jaxpr(kernels)(q, k, v).jaxpr)]
+    assert names == ["sparse_fwd", "sparse_bwd_dkv"]
+    assert monitor.get_stat("pallas.sparse.bwd_fused") == before + 1
+    for name, g, r in zip("qkv", kernels(q, k, v),
+                          through(sa.sparse_attention_xla)(q, k, v)):
+        np.testing.assert_allclose(
+            g, r, rtol=2e-4, atol=2e-5 * float(jnp.abs(r).max()),
+            err_msg="d" + name)
+
+
 def _kl_operands(dtype, seq=256, topk=48):
     """Inputs of the loss over rows of four blocks of 64, the selection
     and the attention's lse from the XLA path."""
