@@ -56,3 +56,4 @@ from .layer import conv as vision  # noqa: F401,E402
 from .utils import remove_weight_norm, weight_norm  # noqa: F401,E402
 from . import utils as weight_norm_hook  # noqa: F401,E402
 from .layer.mla import MLAttention  # noqa: F401,E402
+from .layer.looped import LoopedStack, LoopExitGate  # noqa: F401,E402

@@ -833,12 +833,13 @@ def cross_entropy(input, label, weight=None, ignore_index=-100,
                  ignore_index=ignore_index, reduction=reduction)
 
 
-def _linear_ce_fn(h, w, b, lab, *, chunk, ignore_index):
+def _linear_ce_fn(h, w, b, lab, *tw, chunk, ignore_index):
     """Chunked fused head+CE: logits for one token chunk live only inside
     the rematerialized chunk body, so the [T, vocab] logits (and their
     cotangent) never hit HBM in full.  The matmul is recomputed in the
     chunk's backward — ~6% extra MXU FLOPs for ~4 GB less peak memory on
-    the BERT-base bench shape."""
+    the BERT-base bench shape.  With a token weight ``tw`` [T] the result
+    is the weighted sum of the kept tokens' losses, not their mean."""
     T = h.shape[0]
     n = max(1, -(-T // chunk))          # ceil: pad the tail chunk
     per = -(-T // n)
@@ -848,40 +849,62 @@ def _linear_ce_fn(h, w, b, lab, *, chunk, ignore_index):
             [h, jnp.zeros((pad, h.shape[-1]), h.dtype)], axis=0)
         lab = jnp.concatenate(
             [lab, jnp.full((pad,), ignore_index, lab.dtype)], axis=0)
+        tw = tuple(jnp.concatenate([t, jnp.zeros((pad,), t.dtype)])
+                   for t in tw)
     hs = h.reshape(n, per, h.shape[-1])
     ls = lab.reshape(n, per)
 
     @jax.checkpoint
-    def chunk_nll(hc, lc):
+    def chunk_nll(hc, lc, *wc):
         logits = (jnp.matmul(hc, w) + b).astype(jnp.float32)
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         safe = jnp.where(lc == ignore_index, 0, lc)
         tgt = jnp.take_along_axis(logits, safe[:, None], axis=-1)[:, 0]
         nll = lse - tgt
         keep = (lc != ignore_index)
+        if wc:
+            # where, not a product: an ignored token's weight may be
+            # anything, and its loss gives the weight no gradient
+            return jnp.sum(jnp.where(keep, nll * wc[0], 0.0)), jnp.sum(keep)
         return jnp.sum(nll * keep), jnp.sum(keep)
 
     def body(carry, xs):
         s, c = carry
-        hc, lc = xs
-        ds, dc = chunk_nll(hc, lc)
+        ds, dc = chunk_nll(*xs)
         return (s + ds, c + dc), None
 
     (total, count), _ = jax.lax.scan(
-        body, (jnp.float32(0.0), jnp.int32(0)), (hs, ls))
+        body, (jnp.float32(0.0), jnp.int32(0)),
+        (hs, ls) + tuple(t.reshape(n, per) for t in tw))
+    if tw:
+        return total
     return total / jnp.maximum(count, 1).astype(jnp.float32)
 
 
 @jax.named_scope(scopes.LINEAR_CROSS_ENTROPY)
 def linear_cross_entropy(hidden, weight, bias, label, chunk: int = 1024,
-                         ignore_index: int = -100, name=None):
+                         ignore_index: int = -100, name=None,
+                         token_weight=None):
     """Fused ``cross_entropy(hidden @ weight + bias, label)`` with chunked
     logits (mean reduction).  The TPU-native extension of the reference's
     fused softmax_with_cross_entropy op (operators/softmax_with_cross_
     entropy_op.cu) to include the vocab projection: the full-vocab logits
     tensor is never materialized.  ``hidden``: [T, H]; ``weight``:
-    [H, vocab]; ``label``: [T] int."""
-    return apply(_linear_ce_fn, hidden, weight, bias, label,
+    [H, vocab]; ``label``: [T] int.
+
+    ``token_weight`` [T] float32 turns the mean into the weighted sum
+    ``sum_i token_weight_i * nll_i`` over the tokens that are not
+    ``ignore_index`` (the caller's weights carry the normalisation).  The
+    weight may be traced and takes a gradient, ``nll_i``, out of the same
+    rematerialised chunk body: a loss that mixes per-token losses by a
+    learned distribution (``loop_exit_loss``) never holds them, or the
+    logits, in full.  Counted at trace time: ``linear_cross_entropy.calls``."""
+    from ...utils import monitor
+    monitor.stat_add("linear_cross_entropy.calls")
+    args = [hidden, weight, bias, label]
+    if token_weight is not None:
+        args.append(token_weight)
+    return apply(_linear_ce_fn, *args,
                  op_name="linear_cross_entropy", cacheable=True,
                  chunk=int(chunk), ignore_index=int(ignore_index))
 
@@ -1873,6 +1896,92 @@ def mla_attention(q_nope, q_rope, k_nope, k_rope, value, name=None):
     monitor.stat_add("mla_attention.xla_path")
     return apply(_xla, q_nope, q_rope, k_nope, k_rope, value,
                  op_name="mla_attention")
+
+
+# ---------------------------------------------------------------------------
+# a looped model's exit (appended, as above)
+# ---------------------------------------------------------------------------
+
+def _exit_log_probs(z):
+    """``z`` [T, ...]: the gate's logits after each of T passes (the
+    last row is not read: whoever has not left before the last pass
+    leaves there).  -> ``log p`` [T, ...] of the exit distribution
+    ``p_1 = l_1``, ``p_t = l_t prod_{j<t} (1 - l_j)``,
+    ``p_T = prod_{j<T} (1 - l_j)`` with ``l = sigmoid(z)``, from
+    ``log l = log_sigmoid(z)`` and ``log(1 - l) = log_sigmoid(-z)``: no
+    ``log 0`` while ``z`` is finite."""
+    z = z.astype(jnp.float32)
+    T = z.shape[0]
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-z[:T - 1]), axis=0)
+    before = jnp.concatenate([jnp.zeros_like(z[:1]), stay], axis=0)
+    leave = jnp.concatenate([jax.nn.log_sigmoid(z[:T - 1]),
+                             jnp.zeros_like(z[:1])], axis=0)
+    return before + leave
+
+
+@jax.named_scope(scopes.LOOP_EXIT)
+def loop_exit_distribution(gate_logits, name=None):
+    """The distribution over the pass a token leaves a looped model after
+    (the LoopLM family's exit gate): ``gate_logits`` [T, ...] float32, one
+    row a pass -> ``(p, log_p)``, each [T, ...] float32, ``p`` summing to 1
+    over the first axis.  With ``lambda_t = sigmoid(gate_logits[t])``:
+    ``p_1 = lambda_1``, ``p_t = lambda_t prod_{j<t} (1 - lambda_j)`` and
+    ``p_T = prod_{j<T} (1 - lambda_j)``; the last row of the logits is not
+    read.  The logarithms are sums of ``log_sigmoid``, so an entropy
+    ``-sum p log_p`` has no ``0 * log 0`` at a saturated gate."""
+    def _dist(z):
+        log_p = _exit_log_probs(z)
+        return jnp.exp(log_p), log_p
+    return apply(_dist, gate_logits, op_name="loop_exit_distribution")
+
+
+def loop_exit_loss(states, gate_logits, weight, bias, label, beta=0.1,
+                   chunk: int = 1024, ignore_index: int = -100, name=None):
+    """A looped model's training objective over its T exits (the LoopLM
+    family's pre-training stage: the expected loss under the exit
+    distribution, entropy-regularised towards a uniform prior over the
+    exit step), mean over the tokens that are not ``ignore_index``:
+
+        ``sum_t p_t * CE(states[t] @ weight + bias, label) - beta * H(p)``
+
+    ``states`` [T, N, H] (exit t's state of each token), ``gate_logits``
+    [T, N] (``loop_exit_distribution``'s input), ``weight`` [H, vocab] and
+    ``bias`` [vocab] the one head of every exit, ``label`` [N].  Gradients
+    reach ``p`` from the exits' losses and from the entropy, and the
+    states and the head weighted by ``p``.
+
+    The exits' losses come out of ONE pass of the chunked head over the T
+    exits stacked to [T * N, H] (``linear_cross_entropy`` with
+    ``token_weight`` = ``p_t`` over the number of kept tokens), so no
+    [N, vocab] logits and no vector of losses is held for the backward
+    pass, and the head's gradient is accumulated once: a pass an exit
+    holds T float32 partial gradients of the head (2.0 GB more at
+    2048 x 49,152 and T = 4, for the same step time; PERF.md, PR 37).
+    The distribution, the entropy and the weights sit under the scope
+    ``loop_exit``.  Device counters, float32: ``loop.exit_share`` [T] (the
+    mean of ``p_t``) and ``loop.exit_entropy`` (the mean of H)."""
+    from ...observability import device_counter
+    from ...ops.manipulation import tile
+
+    def _weights(z, lab):
+        with jax.named_scope(scopes.LOOP_EXIT):
+            log_p = _exit_log_probs(z)
+            p = jnp.exp(log_p)
+            keep = (lab != ignore_index)
+            per = 1.0 / jnp.maximum(jnp.sum(keep), 1).astype(jnp.float32)
+            kept = jnp.where(keep, per, 0.0)
+            entropy = -jnp.sum(kept * jnp.sum(p * log_p, axis=0))
+            device_counter(scopes.LOOP_EXIT_SHARE, jnp.sum(p * kept, axis=1))
+            device_counter(scopes.LOOP_EXIT_ENTROPY, entropy)
+            return p * kept, entropy
+
+    w, entropy = apply(_weights, gate_logits, label,
+                       op_name="loop_exit_weights")
+    T, N = states.shape[0], states.shape[1]
+    return linear_cross_entropy(
+        states.reshape([T * N, states.shape[2]]), weight, bias,
+        tile(label, [T]), chunk=chunk, ignore_index=ignore_index,
+        token_weight=w.reshape([T * N])) - float(beta) * entropy
 
 
 from ..decode import gather_tree  # noqa: F401,E402

@@ -56,10 +56,16 @@ MOE_SHARED = "moe_shared"     # the shared expert: a SwiGLU over every token
 MLA_ATTENTION = "mla_attention"  # latent attention: one rotated key a row
                               # beside the heads' own, the flash kernels
 MTP = "mtp"                   # a multi-token-prediction module, its head too
+LOOP_STACK = "loop_stack"     # one pass of a looped stack (nn.LoopedStack):
+                              # every block application sits under it
+LOOP_EXIT = "loop_exit"       # a looped model's exit: the gate, the exit
+                              # distribution, its entropy, the weighting
+                              # (the head stays under linear_cross_entropy)
 FUNCTIONALS = (ATTENTION, LINEAR_CROSS_ENTROPY, GELU, LAYER_NORM, EMBEDDING,
                DROPOUT, EVA_ATTENTION, EVA_POOL, RMS_NORM, ROPE, MOE,
                MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, DSA_INDEXER, DSA_SELECT,
-               SPARSE_ATTENTION, QK_NORM, MOE_SHARED, MLA_ATTENTION, MTP)
+               SPARSE_ATTENTION, QK_NORM, MOE_SHARED, MLA_ATTENTION, MTP,
+               LOOP_STACK, LOOP_EXIT)
 
 # -- Pallas kernels ----------------------------------------------------------
 FLASH_FWD = "flash_fwd"
@@ -112,5 +118,12 @@ MOE_FULL_BUFFER_CHUNKS = "moe.full_buffer_chunks"  # chunks that took the
 MOE_FULLEST_EXPERT_LOAD = "moe.fullest_expert_load"  # the largest of
                               # MOE_EXPERT_LOAD: a maximum does not survive
                               # the sum over steps that a read returns
+# Emitted a call of ``F.loop_exit_loss`` (one looped model's objective),
+# float32: parts of a loss, means over the step's kept tokens.
+LOOP_EXIT_SHARE = "loop.exit_share"   # [passes]: the mean of p_t, the
+                              # probability of leaving after pass t
+LOOP_EXIT_ENTROPY = "loop.exit_entropy"  # the mean entropy of that
+                              # distribution, in nats
 DEVICE_COUNTERS = (MOE_EXPERT_LOAD, MOE_CHUNK_ASSIGNMENTS,
-                   MOE_FULL_BUFFER_CHUNKS, MOE_FULLEST_EXPERT_LOAD)
+                   MOE_FULL_BUFFER_CHUNKS, MOE_FULLEST_EXPERT_LOAD,
+                   LOOP_EXIT_SHARE, LOOP_EXIT_ENTROPY)

@@ -462,6 +462,46 @@ def test_joyai_llm_flash_cell_step_fits_the_chip(one_chip, monkeypatch):
     assert footprint < 15.75 * 2 ** 30
 
 
+def test_ouro_cell_step_fits_the_chip(one_chip, monkeypatch):
+    """The ouro_2_6b.train_bf16_b2_s4096 cell's whole step (8 layers run
+    four times on shared weights, sandwich norms, the exit gate, the
+    expected loss over four exits of the whole 49,152-wide head; two rows
+    of 4096) for the described v5e: it compiles, holds a forward kernel
+    and a backward walk a block APPLICATION at a head shape no other cell
+    runs ([2, 4096, 16, 128] causal), and fits."""
+    from paddle_tpu.observability import scopes
+    from paddle_tpu.utils import monitor
+    monitor.stat_reset()
+    compiled, n, cfg, mix, footprint, step = _cell_step(
+        one_chip, monkeypatch, "ouro_2_6b.train_bf16_b2_s4096",
+        ("flash_attention",))
+    assert n == 612_438_017
+    assert (cfg["hidden_size"], cfg["head_dim"], mix["seq"]) == (2048, 128,
+                                                                 4096)
+    T, L = cfg["total_ut_steps"], cfg["num_hidden_layers"]
+    assert (T, L) == (4, 8)
+    # 8 blocks of parameters, 32 block bodies: each application keeps its
+    # own ``out`` and ``lse``, so the replay holds no forward kernel
+    _one_backward_kernel_a_block(compiled.as_text(), T * L)
+    stats = monitor.all_stats()
+    assert [stats.get(f"recompute.kept.{name}", 0)
+            for name in scopes.RESIDUALS] == [T * L, T * L, 0, 0, 0]
+    assert (stats["loop.steps"], stats["loop.block_calls"]) == (T, T * L)
+    # one pass of the chunked head over the four exits stacked
+    assert stats["linear_cross_entropy.calls"] == 1
+    assert stats["pallas.selected.flash_attention"] >= T * L
+    assert "attention.xla_path" not in stats
+    # the exit distribution and its entropy ride in the carry, float32
+    assert {k: (v.shape, v.dtype) for k, v in step._counter_spec.items()} \
+        == {scopes.LOOP_EXIT_SHARE: ((1, T), jnp.float32),
+            scopes.LOOP_EXIT_ENTROPY: ((1,), jnp.float32)}
+    # 14,592,852,992 bytes as this test compiled it in PR 37 (under
+    # conftest's matmul precision: not the benchmark's program to the
+    # byte, which the chip laid out in 14,463,649,792); live peak 13.43 GB
+    assert abs(footprint - 14_592_852_992) < 64 * 2 ** 20, footprint
+    assert footprint < 15.75 * 2 ** 30
+
+
 # ------------------------------------------------------- fused epilogue --
 _LN = ("layer_norm", 1e-5, True, True)
 # (M, K, N, stages): the recipe the Executor realises on BERT-base

@@ -310,6 +310,45 @@ def test_kept_counters(kernels, block, wrap, expected):
     assert _counted(KEPT, _grad_jaxpr, block, wrap) == expected
 
 
+@pytest.mark.parametrize("steps", [1, 3], ids=["once", "three_passes"])
+def test_a_block_replayed_T_times_keeps_T_of_each_residual(kernels,
+                                                           pallas_eqns,
+                                                           steps):
+    """``nn.LoopedStack`` binds and replays each block ``steps`` times in
+    one trace: the policy keeps (and counts) an ``out`` and an ``lse`` a
+    block APPLICATION, the gradient's program holds a forward kernel and
+    a backward walk an application and no second forward, and a shared
+    weight's gradient is the bare checkpoint's bit for bit."""
+    def looped(wrap):
+        paddle.seed(7)
+        net = nn.LoopedStack([FlashBlock() for _ in range(LAYERS)], steps,
+                             norm=nn.LayerNorm(HID), recompute=wrap)
+        x = jnp.asarray(np.random.RandomState(0).randn(2, SEQ, HID),
+                        jnp.float32)
+
+        def loss(arrays):
+            with bind(net, arrays), autograd.no_grad():
+                return (net(Tensor(x)).data ** 2).mean()
+
+        return loss, param_arrays(net)
+
+    loss, arrays = looped(True)
+    calls = steps * LAYERS
+    kept = _counted(KEPT, lambda: jax.make_jaxpr(jax.grad(loss))(arrays))
+    assert kept == (calls, calls) + NOTHING[2:]
+    text = str(jax.make_jaxpr(jax.grad(loss))(arrays))
+    assert _kernel_calls(text, scopes.FLASH_FWD) == calls
+    assert _kernel_calls(text, scopes.FLASH_BWD_DKV) == calls
+    replayed = [eqn.params["name"] for eqn in pallas_eqns(
+        jax.make_jaxpr(jax.grad(loss))(arrays).jaxpr, within="remat2")]
+    assert scopes.FLASH_FWD not in replayed
+    got = jax.grad(loss)(arrays)
+    plain, plain_arrays = looped(False)
+    want = jax.grad(plain)(plain_arrays)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-7)
+
+
 @pytest.mark.parametrize("block", [FlashBlock, SparseBlock],
                          ids=["flash", "sparse"])
 def test_the_forward_alone_keeps_nothing(kernels, block):

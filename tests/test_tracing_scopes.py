@@ -585,3 +585,86 @@ def test_keyes_expert_layer_counts_no_sigmoid_and_no_shared_expert(keye_step):
     _, stats, _ = keye_step
     assert "moe.scoring_sigmoid" not in stats
     assert "moe.shared_experts" not in stats
+
+
+# --------------- a looped stack, its exit gate and objective (PR 37) --
+@pytest.fixture(scope="module")
+def ouro_step():
+    """``op_name``s, trace-time counters and device counters of the
+    ouro_2_6b cell's model at its rehearsal widths, through the
+    train_step runner, on the XLA paths."""
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, bench)
+    import run as harness
+    cell, cfg, mix, model_mod, ref, runner = harness.load_parts(
+        "ouro_2_6b.train_bf16_b2_s4096", rehearse=True)
+    ring, theta0 = harness.seeded_inputs(cell, cfg, mix, ref, seed=3)
+    monitor.stat_reset()
+    state = runner.build(cell, cfg, model_mod, theta0(), mix)
+    try:
+        step = state["step"]
+        runner.dispatch(state, runner.feed(state, *ring[0]))
+        runner.dispatch(state, runner.feed(state, *ring[1]))
+        stats = dict(monitor.all_stats())
+        counted = step.device_counters()
+        ids = jnp.asarray(ring[0][0])
+        lowered = step._compiled[True].lower(
+            step._param_arrays(), (), step._opt_state, step._scaler_state,
+            step._lr_device, (ids,), (ids,))
+        return (_op_names(lowered.compile().as_text()), stats, cfg, counted)
+    finally:
+        runner.close(state)
+
+
+@pytest.mark.parametrize("scope", [
+    scopes.LOOP_STACK, scopes.LOOP_EXIT, scopes.LINEAR_CROSS_ENTROPY,
+    scopes.RMS_NORM, scopes.ROPE, scopes.ATTENTION, "stack:LoopedStack",
+    "exit_gate:LoopExitGate", "blocks.2:Block", "norm:StreamNorm"])
+def test_the_looped_stack_and_exit_scopes_are_in_the_step(ouro_step, scope):
+    assert scope in _segments(ouro_step[0])
+
+
+def test_every_block_application_sits_under_loop_stack_in_every_pass(
+        ouro_step):
+    """``loop_exit_ms`` and a cut by hand read whole path segments: a
+    block's instructions carry ``loop_stack`` in the forward, the replay
+    and the backward; the head and the exit's own work do not, and the
+    exit's scope holds the gate and the distribution in both directions."""
+    names = ouro_step[0]
+    blocks = [n.split("/") for n in names if "blocks.0:Block" in n.split("/")]
+    assert blocks and all(scopes.LOOP_STACK in s for s in blocks)
+    assert any("rematted_computation" in s for s in blocks)
+    assert any("transpose(jvp(loss))" in s for s in blocks)
+    head = [n.split("/") for n in names
+            if scopes.LINEAR_CROSS_ENTROPY in n.split("/")]
+    assert head and not any(scopes.LOOP_STACK in s or scopes.LOOP_EXIT in s
+                            for s in head)
+    exits = [n.split("/") for n in names if scopes.LOOP_EXIT in n.split("/")]
+    assert any("jvp(loss)" in s for s in exits)
+    assert any("transpose(jvp(loss))" in s for s in exits)
+    assert any("exit_gate:LoopExitGate" in s for s in exits)
+    assert not any(scopes.LOOP_STACK in s for s in exits)
+
+
+def test_the_looped_models_counters(ouro_step):
+    _, stats, cfg, counted = ouro_step
+    T, L = cfg["total_ut_steps"], cfg["num_hidden_layers"]
+    assert stats["loop.steps"] == T
+    assert stats["loop.block_calls"] == T * L
+    assert stats["linear_cross_entropy.calls"] == 1
+    # once a block application in the forward pass and once in its replay
+    assert stats["attention.xla_path"] >= T * L
+    # the exit distribution as the two compiled steps counted it
+    assert counted["loop.exit_share.steps"] == 2
+    share = [counted[f"loop.exit_share.total.0.{t}"] / 2 for t in range(T)]
+    assert sum(share) == pytest.approx(1.0, rel=1e-5)
+    assert all(0.0 < s < 1.0 for s in share)
+    last = [counted[f"loop.exit_share.last.0.{t}"] for t in range(T)]
+    assert sum(last) == pytest.approx(1.0, rel=1e-5)
+    # at a gate of 0.5 the distribution is 1/2, 1/4, 1/8, 1/8: 1.2130
+    # nats; uniform over four is ln 4 = 1.3863, the most there is
+    assert 1.0 < counted["loop.exit_entropy.last.0"] < 1.3863
+    assert 2.0 < counted["loop.exit_entropy.total.0"] < 2 * 1.3863
+    assert {scopes.LOOP_EXIT_SHARE, scopes.LOOP_EXIT_ENTROPY} <= set(
+        scopes.DEVICE_COUNTERS)
