@@ -13,6 +13,7 @@ device trace can be split by component.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Sequence
 
@@ -833,13 +834,114 @@ def cross_entropy(input, label, weight=None, ignore_index=-100,
                  ignore_index=ignore_index, reduction=reduction)
 
 
+def _chunk_nll(hc, w, b, lc, *wc, ignore_index):
+    """One chunk's summed (or weighted) loss and its count of kept
+    tokens; its [per, vocab] float32 logits live only here."""
+    logits = (jnp.matmul(hc, w) + b).astype(jnp.float32)
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    safe = jnp.where(lc == ignore_index, 0, lc)
+    tgt = jnp.take_along_axis(logits, safe[:, None], axis=-1)[:, 0]
+    nll = lse - tgt
+    keep = (lc != ignore_index)
+    if wc:
+        # where, not a product: an ignored token's weight may be
+        # anything, and its loss gives the weight no gradient
+        return jnp.sum(jnp.where(keep, nll * wc[0], 0.0)), jnp.sum(keep)
+    return jnp.sum(nll * keep), jnp.sum(keep)
+
+
+def _head_of(w, b, ignore_index):
+    """``_chunk_nll`` closed over the head, as a scan body calls it."""
+    return lambda hc, lc, *wc: _chunk_nll(hc, w, b, lc, *wc,
+                                          ignore_index=ignore_index)
+
+
+def _mean_or_sum(total, count, tws):
+    if tws:
+        return total
+    return total / jnp.maximum(count, 1).astype(jnp.float32)
+
+
+def _chunked_ce_value(chunk_nll, hs, ls, tws):
+    """The head's loss over chunks ``hs`` [n, per, H], ``ls`` [n, per] and,
+    where there are token weights, ``tws`` = ([n, per],) (else ()): the
+    mean over the kept tokens, or the weighted sum; one matmul a chunk.
+    ``chunk_nll(hc, lc, *wc)`` holds the head."""
+    def body(carry, xs):
+        s, c = carry
+        ds, dc = chunk_nll(*xs)
+        return (s + ds, c + dc), None
+
+    (total, count), _ = jax.lax.scan(
+        body, (jnp.float32(0.0), jnp.int32(0)), (hs, ls, *tws))
+    return _mean_or_sum(total, count, tws)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _chunked_ce(ignore_index, hs, w, b, ls, tws):
+    """``_chunked_ce_value`` where no gradient is asked; differentiated,
+    ``_chunked_ce_fwd`` runs in its place."""
+    return _chunked_ce_value(_head_of(w, b, ignore_index), hs, ls, tws)
+
+
+def _chunked_ce_fwd(ignore_index, hs, w, b, ls, tws):
+    """The loss AND its gradients at a cotangent of 1, chunk by chunk in
+    the one scan: each chunk's logits are differentiated where they are
+    made (the logits matmul, then the two gradient products) and never
+    made again.  A token's own factor is known before the scan, so the
+    chunk is pulled with it: 1 / the number of kept tokens for the mean
+    (counted over the labels beforehand, not in the carry), 1 for the
+    weighted sum.  ``dw`` and ``db`` accumulate in ``w``'s and ``b``'s own
+    types, last chunk first, which is how a scan's transpose accumulated
+    them: at a cotangent of 1 every gradient is that transpose's bit for
+    bit."""
+    from ...utils import monitor
+    monitor.stat_add("linear_cross_entropy.grads_in_forward")
+    count = jnp.sum(ls != ignore_index)
+    ct = _mean_or_sum(jnp.float32(1.0), count, tws)
+
+    def body(carry, xs):
+        hc, lc, *wc = xs
+        part, pull, _ = jax.vjp(
+            lambda hc, w, b, *wc: _chunk_nll(hc, w, b, lc, *wc,
+                                             ignore_index=ignore_index),
+            hc, w, b, *wc, has_aux=True)
+        dhc, dwc, dbc, *dwc_t = pull(ct)
+        return ((carry[0] + dwc, carry[1] + dbc), (part, dhc, *dwc_t))
+
+    (dw, db), (parts, dh, *dtw) = jax.lax.scan(
+        body, (jnp.zeros_like(w), jnp.zeros_like(b)), (hs, ls, *tws),
+        reverse=True)
+    # first chunk first, as the value's carry adds them: the same loss
+    total, _ = jax.lax.scan(lambda s, part: (s + part, None),
+                            jnp.float32(0.0), parts)
+    return _mean_or_sum(total, count, tws), (dh, dw, db, *dtw)
+
+
+def _chunked_ce_bwd(ignore_index, grads, g):
+    dh, dw, db, *dtw = [(g * d).astype(d.dtype) for d in grads]
+    # labels take no gradient
+    return dh, dw, db, None, tuple(dtw)
+
+
+_chunked_ce.defvjp(_chunked_ce_fwd, _chunked_ce_bwd)
+
+
 def _linear_ce_fn(h, w, b, lab, *tw, chunk, ignore_index):
-    """Chunked fused head+CE: logits for one token chunk live only inside
-    the rematerialized chunk body, so the [T, vocab] logits (and their
-    cotangent) never hit HBM in full.  The matmul is recomputed in the
-    chunk's backward — ~6% extra MXU FLOPs for ~4 GB less peak memory on
-    the BERT-base bench shape.  With a token weight ``tw`` [T] the result
-    is the weighted sum of the kept tokens' losses, not their mean."""
+    """Chunked fused head+CE: the [T, vocab] logits (and their cotangent)
+    never hit HBM in full, because a chunk's logits live only inside the
+    scan body that makes them.  Where gradients are asked that body turns
+    them into ``dh``, ``dw`` and ``db`` at once (``_chunked_ce``: three
+    matmul passes), and the backward pass only scales what the forward
+    pass kept.  A head narrower than its hidden width keeps the replay
+    instead (a checkpointed chunk body that jax differentiates: four
+    passes): what it would keep, ``dh`` [T, H], is larger than the logits
+    it would save making again, and a model with several such heads
+    (EvaByte: eight of 320 columns over 4096) would hold one ``dh`` a head
+    from the forward pass into the backward.  With a token weight ``tw``
+    [T] the result is the weighted sum of the kept tokens' losses, not
+    their mean.  The padding of a ragged tail stays outside either rule:
+    its gradient is jax's slice."""
     T = h.shape[0]
     n = max(1, -(-T // chunk))          # ceil: pad the tail chunk
     per = -(-T // n)
@@ -851,34 +953,12 @@ def _linear_ce_fn(h, w, b, lab, *tw, chunk, ignore_index):
             [lab, jnp.full((pad,), ignore_index, lab.dtype)], axis=0)
         tw = tuple(jnp.concatenate([t, jnp.zeros((pad,), t.dtype)])
                    for t in tw)
-    hs = h.reshape(n, per, h.shape[-1])
-    ls = lab.reshape(n, per)
-
-    @jax.checkpoint
-    def chunk_nll(hc, lc, *wc):
-        logits = (jnp.matmul(hc, w) + b).astype(jnp.float32)
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        safe = jnp.where(lc == ignore_index, 0, lc)
-        tgt = jnp.take_along_axis(logits, safe[:, None], axis=-1)[:, 0]
-        nll = lse - tgt
-        keep = (lc != ignore_index)
-        if wc:
-            # where, not a product: an ignored token's weight may be
-            # anything, and its loss gives the weight no gradient
-            return jnp.sum(jnp.where(keep, nll * wc[0], 0.0)), jnp.sum(keep)
-        return jnp.sum(nll * keep), jnp.sum(keep)
-
-    def body(carry, xs):
-        s, c = carry
-        ds, dc = chunk_nll(*xs)
-        return (s + ds, c + dc), None
-
-    (total, count), _ = jax.lax.scan(
-        body, (jnp.float32(0.0), jnp.int32(0)),
-        (hs, ls) + tuple(t.reshape(n, per) for t in tw))
-    if tw:
-        return total
-    return total / jnp.maximum(count, 1).astype(jnp.float32)
+    hs, ls = h.reshape(n, per, h.shape[-1]), lab.reshape(n, per)
+    tws = tuple(t.reshape(n, per) for t in tw)
+    if w.shape[-1] < h.shape[-1]:
+        return _chunked_ce_value(
+            jax.checkpoint(_head_of(w, b, ignore_index)), hs, ls, tws)
+    return _chunked_ce(ignore_index, hs, w, b, ls, tws)
 
 
 @jax.named_scope(scopes.LINEAR_CROSS_ENTROPY)
@@ -896,9 +976,28 @@ def linear_cross_entropy(hidden, weight, bias, label, chunk: int = 1024,
     ``sum_i token_weight_i * nll_i`` over the tokens that are not
     ``ignore_index`` (the caller's weights carry the normalisation).  The
     weight may be traced and takes a gradient, ``nll_i``, out of the same
-    rematerialised chunk body: a loss that mixes per-token losses by a
-    learned distribution (``loop_exit_loss``) never holds them, or the
-    logits, in full.  Counted at trace time: ``linear_cross_entropy.calls``."""
+    chunk body: a loss that mixes per-token losses by a learned
+    distribution (``loop_exit_loss``) never holds them, or the logits, in
+    full.
+
+    Differentiated, a head at least as wide as its hidden width makes its
+    gradients where it makes its loss (a ``jax.custom_vjp``: three matmul
+    passes, the backward pass scales what the forward pass kept; a
+    narrower head replays its logits, four passes: ``_linear_ce_fn``).
+    Two things follow.  A traced call with gradients enabled (a ``Tensor``
+    that wants one, inside somebody's ``jit``) takes ``jax.vjp`` at the
+    call, so a loss that is never differentiated pays three passes for
+    one there; ``no_grad``, ``TrainStep.eval_step`` and the eager tape
+    (whose compiled rules run the value at the call and the three passes
+    at ``backward()``) take the value path, one pass.  And forward-mode
+    differentiation (``jax.jvp``, ``jacfwd``) through the head is not
+    available, as it is not through the flash kernels.  Inside an outer
+    ``jax.checkpoint`` the forward pass runs the value and the replay
+    the three passes.
+
+    Counted at trace time: ``linear_cross_entropy.calls``, and
+    ``linear_cross_entropy.grads_in_forward`` for each call whose
+    gradients were made in its forward rule."""
     from ...utils import monitor
     monitor.stat_add("linear_cross_entropy.calls")
     args = [hidden, weight, bias, label]

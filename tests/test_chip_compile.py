@@ -213,6 +213,25 @@ def _kernel_count(text, kernel):
     return len(re.findall(rf"%{kernel}[.\d]* = ", text))
 
 
+def _head_made_its_gradients_in_the_forward_pass(text, calls):
+    """The chunked head of a compiled step, ``calls`` of them traced: each
+    took the forward rule (``linear_cross_entropy.grads_in_forward``), so
+    nothing under the scope is a replay, its products sit in the forward
+    pass's scan and the backward pass holds no loop of its own: at most
+    the scaling by the cotangent, which XLA folds away where that is 1
+    (BERT, GPT, Keye)."""
+    from paddle_tpu.observability import scopes
+    from paddle_tpu.utils import monitor
+    assert monitor.get_stat("linear_cross_entropy.calls") == calls
+    assert monitor.get_stat("linear_cross_entropy.grads_in_forward") == calls
+    head = [n.split("/") for n in set(re.findall(r'op_name="([^"]*)"', text))
+            if scopes.LINEAR_CROSS_ENTROPY in n.split("/")]
+    assert head and not any("rematted_computation" in s for s in head)
+    assert any("jvp(loss)" in s and "while" in s for s in head)
+    assert not any("transpose(jvp(loss))" in s and "while" in s
+                   for s in head)
+
+
 def test_evabyte_cell_step_fits_the_chip(one_chip, monkeypatch):
     """The cell's whole step at the published widths, for the described
     v5e: it compiles, holds the kernels, and its footprint is under the
@@ -223,6 +242,10 @@ def test_evabyte_cell_step_fits_the_chip(one_chip, monkeypatch):
     compiled, n, cfg, mix, footprint, step = _cell_step(
         one_chip, monkeypatch, "evabyte.train_bf16_b1_s8192",
         ("eva_attention", "flash_attention"))
+    # the parent's program to the byte (PR 38): eight heads of 320
+    # columns over a hidden width of 4096 keep the replay, because the
+    # forward rule would hold a float32 ``dh`` of 134 MB a head from the
+    # forward pass into the backward: 15,446,008,320 compiled that way
     _same_program_as_without_counters(compiled, step, footprint,
                                       (192, 14_658_201_088))
     assert cfg["hidden_size"] == 4096 and mix["seq"] == 8192
@@ -240,6 +263,10 @@ def test_evabyte_cell_step_fits_the_chip(one_chip, monkeypatch):
     stats = monitor.all_stats()
     assert [stats.get(f"recompute.kept.{name}", 0)
             for name in scopes.RESIDUALS] == [L, L, 0, 0, 0]
+    assert stats["linear_cross_entropy.calls"] == cfg["num_pred_heads"] == 8
+    assert "linear_cross_entropy.grads_in_forward" not in stats
+    assert any("rematted_computation" in n and "linear_cross_entropy" in n
+               for n in re.findall(r'op_name="([^"]*)"', text))
     assert footprint < 15.75 * 2 ** 30
 
 
@@ -256,7 +283,9 @@ def test_keye_vl2_cell_step_fits_the_chip(one_chip, monkeypatch):
         ("sparse_attention", "flash_attention"))
     _carries_the_moe_counters(compiled, step, calls=4, chunks=4,
                               live_peak=12_420_812_800)
-    # 13,305,999,360 without the counters: the heap packs 68 MB worse
+    # 13,305,999,360 without the counters: the heap packed 68 MB worse
+    # with them; 13,306,718,208 since the head makes its gradients in its
+    # forward rule (PR 38)
     assert footprint < 13_305_999_360 + 80e6
     assert cfg["hidden_size"] == 2048 and mix["seq"] == 8192
     assert 465e6 < n < 466e6
@@ -278,6 +307,7 @@ def test_keye_vl2_cell_step_fits_the_chip(one_chip, monkeypatch):
         == [L] * len(scopes.RESIDUALS)
     assert stats["pallas.sparse.bwd_fused"] == L
     assert "pallas.flash.bwd_fused" not in stats
+    _head_made_its_gradients_in_the_forward_pass(text, calls=1)
     assert footprint < 15.75 * 2 ** 30
 
 
@@ -397,9 +427,11 @@ def _one_backward_kernel_a_block(text, blocks):
 
 
 @pytest.mark.parametrize("cell_name,blocks,kept,parameters,on_record", [
+    # 15,094,667,264 and 14,093,140,992 before PR 38: ``dw`` and ``dh``
+    # are allocated as the forward pass closes, not as the backward opens
     ("gpt3_large.train_bf16_b8_s2048", 24, 24, 760e6,
-     (1556, 15_094_667_264)),
-    ("bert_base.train_bf16_b64_s512", 12, 0, 132e6, (796, 14_093_140_992)),
+     (1556, 15_109_924_352)),
+    ("bert_base.train_bf16_b64_s512", 12, 0, 132e6, (796, 14_193_097_728)),
 ], ids=["gpt", "bert"])
 def test_flash_cell_step_runs_one_backward_kernel(one_chip, monkeypatch,
                                                   cell_name, blocks, kept,
@@ -414,7 +446,9 @@ def test_flash_cell_step_runs_one_backward_kernel(one_chip, monkeypatch,
         one_chip, monkeypatch, cell_name, ("flash_attention",))
     _same_program_as_without_counters(compiled, step, footprint, on_record)
     assert 0.9 * parameters < n < 1.1 * parameters
-    _one_backward_kernel_a_block(compiled.as_text(), blocks)
+    text = compiled.as_text()
+    _one_backward_kernel_a_block(text, blocks)
+    _head_made_its_gradients_in_the_forward_pass(text, calls=1)
     stats = monitor.all_stats()
     assert [stats.get(f"recompute.kept.{name}", 0)
             for name in scopes.RESIDUALS] == [kept, kept, 0, 0, 0]
@@ -438,8 +472,8 @@ def test_joyai_llm_flash_cell_step_fits_the_chip(one_chip, monkeypatch):
     # four expert layers and the MTP block's
     _carries_the_moe_counters(compiled, step, calls=5, chunks=2,
                               live_peak=14_037_525_504)
-    # 14,736,134,656 without the counters
-    assert abs(footprint - 14_736_134_656) < 2 * 2 ** 20
+    # 14,736,134,656 without the counters; 14,736,962,560 since PR 38
+    assert abs(footprint - 14_736_962_560) < 2 * 2 ** 20
     assert cfg["hidden_size"] == 2048 and mix["seq"] == 8192
     assert cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"] == 192
     assert 680.3e6 < n < 680.5e6
@@ -448,6 +482,8 @@ def test_joyai_llm_flash_cell_step_fits_the_chip(one_chip, monkeypatch):
     # out and lse, so each block holds one forward kernel
     blocks = cfg["num_hidden_layers"] + cfg["num_nextn_predict_layers"]
     _one_backward_kernel_a_block(text, blocks)
+    # the main model's pass through the head and the MTP module's
+    _head_made_its_gradients_in_the_forward_pass(text, calls=2)
     assert "ragged-dot" in text
     stats = monitor.all_stats()
     assert [stats.get(f"recompute.kept.{name}", 0)
@@ -482,23 +518,25 @@ def test_ouro_cell_step_fits_the_chip(one_chip, monkeypatch):
     assert (T, L) == (4, 8)
     # 8 blocks of parameters, 32 block bodies: each application keeps its
     # own ``out`` and ``lse``, so the replay holds no forward kernel
-    _one_backward_kernel_a_block(compiled.as_text(), T * L)
+    text = compiled.as_text()
+    _one_backward_kernel_a_block(text, T * L)
+    # one pass of the chunked head over the four exits stacked
+    _head_made_its_gradients_in_the_forward_pass(text, calls=1)
     stats = monitor.all_stats()
     assert [stats.get(f"recompute.kept.{name}", 0)
             for name in scopes.RESIDUALS] == [T * L, T * L, 0, 0, 0]
     assert (stats["loop.steps"], stats["loop.block_calls"]) == (T, T * L)
-    # one pass of the chunked head over the four exits stacked
-    assert stats["linear_cross_entropy.calls"] == 1
     assert stats["pallas.selected.flash_attention"] >= T * L
     assert "attention.xla_path" not in stats
     # the exit distribution and its entropy ride in the carry, float32
     assert {k: (v.shape, v.dtype) for k, v in step._counter_spec.items()} \
         == {scopes.LOOP_EXIT_SHARE: ((1, T), jnp.float32),
             scopes.LOOP_EXIT_ENTROPY: ((1,), jnp.float32)}
-    # 14,592,852,992 bytes as this test compiled it in PR 37 (under
+    # 14,589,980,672 bytes as this test compiled it in PR 38 (under
     # conftest's matmul precision: not the benchmark's program to the
-    # byte, which the chip laid out in 14,463,649,792); live peak 13.43 GB
-    assert abs(footprint - 14_592_852_992) < 64 * 2 ** 20, footprint
+    # byte); 14,592,852,992 in PR 37, which the chip laid out in
+    # 14,463,649,792
+    assert abs(footprint - 14_589_980_672) < 64 * 2 ** 20, footprint
     assert footprint < 15.75 * 2 ** 30
 
 

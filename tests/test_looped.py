@@ -320,17 +320,20 @@ def test_the_mean_path_is_bit_for_bit_what_it_was(T, dtype):
     h, w, b, lab, _ = _head_case(T, seed=4)
     h, w, b = h.astype(dtype), w.astype(dtype), b.astype(dtype)
 
-    def now(h, w):
+    # the labels are an argument, as a step's are: over constant labels
+    # XLA folds the count of kept tokens, which is now taken before the
+    # scan, and divides by it as a product with its reciprocal
+    def now(h, w, lab):
         with paddle.no_grad():
             return F.linear_cross_entropy(Tensor(h), Tensor(w), Tensor(b),
                                           Tensor(lab), chunk=16).data
 
-    def was(h, w):
+    def was(h, w, lab):
         return _mean_path_as_it_was(h, w, b, lab, chunk=16,
                                     ignore_index=-100)
 
-    got = jax.jit(jax.value_and_grad(now, (0, 1)))(h, w)
-    want = jax.jit(jax.value_and_grad(was, (0, 1)))(h, w)
+    got = jax.jit(jax.value_and_grad(now, (0, 1)))(h, w, lab)
+    want = jax.jit(jax.value_and_grad(was, (0, 1)))(h, w, lab)
     np.testing.assert_array_equal(got[0], want[0])
     for g, r in zip(got[1], want[1]):
         np.testing.assert_array_equal(g, r)
@@ -372,6 +375,49 @@ def test_loop_exit_loss_is_the_reference_objective_with_ignored_tokens():
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
     for g, r, name in zip(got[1], want[1], ("states", "gate", "head")):
         np.testing.assert_allclose(g, r, rtol=2e-4, atol=1e-6, err_msg=name)
+
+
+def _count(jaxpr, primitive):
+    """How many ``primitive`` equations a jaxpr holds, its sub-jaxprs
+    (a scan's body, a custom rule's primal) counted once each."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        total += eqn.primitive.name == primitive
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    total += _count(sub, primitive)
+    return total
+
+
+@pytest.mark.parametrize("differentiated,products", [(False, 1), (True, 3)],
+                         ids=["value_only", "with_gradients"])
+def test_the_objectives_head_runs_no_matmul_twice(differentiated, products):
+    """``F.loop_exit_loss`` by the products its trace holds: the value
+    alone is one matmul a chunk; with gradients the one scan holds the
+    logits, ``dh`` and ``dw`` and nothing is replayed.  The gate still
+    gets its gradient through ``token_weight`` (the test above holds it
+    to the reference's)."""
+    T, N, H, V = 4, 37, 16, 50
+    ks = jax.random.split(jax.random.key(6), 3)
+    states = jax.random.normal(ks[0], (T, N, H))
+    z = jax.random.normal(ks[1], (T, N))
+    head = 0.3 * jax.random.normal(ks[2], (H, V))
+    lab = jnp.arange(N) % V
+
+    def loss(states, z, head):
+        with paddle.no_grad():
+            return F.loop_exit_loss(
+                Tensor(states), Tensor(z), Tensor(head),
+                Tensor(jnp.zeros((V,))), Tensor(lab), chunk=16).data
+
+    fn = jax.grad(loss, (0, 1, 2)) if differentiated else loss
+    jaxpr = jax.make_jaxpr(fn)(states, z, head).jaxpr
+    assert _count(jaxpr, "dot_general") == products
+    assert _count(jaxpr, "remat2") == 0     # jax.checkpoint's primitive
+    if differentiated:
+        assert all(bool(jnp.any(g != 0)) for g in fn(states, z, head))
 
 
 def test_looped_stack_runs_its_blocks_steps_times_on_one_set_of_weights():

@@ -355,6 +355,149 @@ def test_linear_cross_entropy_matches_unfused():
     np.testing.assert_allclose(gw, w2.grad.numpy(), rtol=1e-4, atol=1e-6)
 
 
+def _linear_ce_fn_as_it_was(h, w, b, lab, *tw, chunk, ignore_index):
+    """``_linear_ce_fn`` of the tree before the head made its gradients
+    in its forward rule (PR 37's), verbatim: a checkpointed chunk body in
+    a scan, differentiated by jax."""
+    import jax
+    import jax.numpy as jnp
+    T = h.shape[0]
+    n = max(1, -(-T // chunk))          # ceil: pad the tail chunk
+    per = -(-T // n)
+    if n * per != T:
+        pad = n * per - T
+        h = jnp.concatenate(
+            [h, jnp.zeros((pad, h.shape[-1]), h.dtype)], axis=0)
+        lab = jnp.concatenate(
+            [lab, jnp.full((pad,), ignore_index, lab.dtype)], axis=0)
+        tw = tuple(jnp.concatenate([t, jnp.zeros((pad,), t.dtype)])
+                   for t in tw)
+    hs = h.reshape(n, per, h.shape[-1])
+    ls = lab.reshape(n, per)
+
+    @jax.checkpoint
+    def chunk_nll(hc, lc, *wc):
+        logits = (jnp.matmul(hc, w) + b).astype(jnp.float32)
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        safe = jnp.where(lc == ignore_index, 0, lc)
+        tgt = jnp.take_along_axis(logits, safe[:, None], axis=-1)[:, 0]
+        nll = lse - tgt
+        keep = (lc != ignore_index)
+        if wc:
+            # where, not a product: an ignored token's weight may be
+            # anything, and its loss gives the weight no gradient
+            return jnp.sum(jnp.where(keep, nll * wc[0], 0.0)), jnp.sum(keep)
+        return jnp.sum(nll * keep), jnp.sum(keep)
+
+    def body(carry, xs):
+        s, c = carry
+        ds, dc = chunk_nll(*xs)
+        return (s + ds, c + dc), None
+
+    (total, count), _ = jax.lax.scan(
+        body, (jnp.float32(0.0), jnp.int32(0)),
+        (hs, ls) + tuple(t.reshape(n, per) for t in tw))
+    if tw:
+        return total
+    return total / jnp.maximum(count, 1).astype(jnp.float32)
+
+
+def _head_now_and_as_it_was(dtype, T, H, V, ignored, weighted, cotangent):
+    """One seeded case of the chunked head (chunks of 16) pulled at
+    ``cotangent`` through ``F.linear_cross_entropy`` and through
+    ``_linear_ce_fn_as_it_was``, each under one jit -> ((loss, gradients
+    of hidden, weight, bias and the token weights) now, the same as it
+    was, the loss of the value-only call).  The labels are an argument,
+    as a step's are: over constant labels XLA folds the count of kept
+    tokens and divides by its reciprocal."""
+    import functools
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.core.tensor import Tensor
+    ks = jax.random.split(jax.random.key(7), 5)
+    lab = jax.random.randint(ks[3], (T,), 0, V)
+    lab = (jnp.full((T,), -100) if ignored == "all"
+           else lab.at[jnp.arange(0, T, 5)].set(-100))
+    h = jax.random.normal(ks[0], (T, H)).astype(dtype)
+    w = (0.3 * jax.random.normal(ks[1], (H, V))).astype(dtype)
+    b = (0.1 * jax.random.normal(ks[2], (V,))).astype(dtype)
+    tw = (jax.random.uniform(ks[4], (T,)),) if weighted else ()
+
+    def now(lab, h, w, b, *tw):
+        with paddle.no_grad():
+            return F.linear_cross_entropy(
+                Tensor(h), Tensor(w), Tensor(b), Tensor(lab), chunk=16,
+                token_weight=Tensor(tw[0]) if tw else None).data
+
+    def was(lab, h, w, b, *tw):
+        return _linear_ce_fn_as_it_was(h, w, b, lab, *tw, chunk=16,
+                                       ignore_index=-100)
+
+    def pulled(fn):
+        def both(lab, *args):
+            loss, pull = jax.vjp(functools.partial(fn, lab), *args)
+            return loss, pull(jnp.float32(cotangent))
+        return jax.jit(both)(lab, h, w, b, *tw)
+
+    return pulled(now), pulled(was), jax.jit(now)(lab, h, w, b, *tw)
+
+
+@pytest.mark.parametrize("cotangent", [1.0, 0.3])
+@pytest.mark.parametrize("weighted", [False, True],
+                         ids=["mean", "token_weight"])
+@pytest.mark.parametrize("ignored", ["some", "all"])
+@pytest.mark.parametrize("T", [64, 61], ids=["whole_chunks", "ragged_tail"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_linear_cross_entropy_makes_the_gradients_it_made(
+        dtype, T, ignored, weighted, cotangent):
+    """The head's forward rule makes the loss and, in the same scan,
+    ``dh``, ``dw``, ``db`` and the token weights' gradient; against the
+    scan jax differentiated: the loss bit for bit, every gradient bit for
+    bit at a cotangent of 1 and to one rounding of its own type where the
+    cotangent multiplies after the products instead of before them."""
+    import jax.numpy as jnp
+    (loss, got), (loss_was, want), value = _head_now_and_as_it_was(
+        dtype, T, 16, 50, ignored, weighted, cotangent)
+    np.testing.assert_array_equal(loss, value)
+    np.testing.assert_array_equal(loss, loss_was)
+    names = ("hidden", "weight", "bias", "token_weight")
+    for g, r, name in zip(got, want, names):
+        assert (g.shape, g.dtype) == (r.shape, r.dtype), name
+        eps = float(jnp.finfo(r.dtype).eps)
+        g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
+        if cotangent == 1.0:
+            np.testing.assert_array_equal(g, r, err_msg=name)
+        else:
+            np.testing.assert_allclose(g, r, rtol=2 * eps,
+                                       atol=2 * eps * np.abs(r).max(),
+                                       err_msg=name)
+    if ignored == "all":
+        assert float(loss) == 0.0
+        assert all(not np.asarray(g, np.float32).any() for g in got)
+
+
+@pytest.mark.parametrize("weighted", [False, True],
+                         ids=["mean", "token_weight"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_head_narrower_than_its_hidden_width_keeps_the_replay(dtype,
+                                                                weighted):
+    """What the forward rule would keep for the backward pass, ``dh``
+    [T, H], is larger than the logits [T, vocab] of a head narrower than
+    its hidden width: that head stays the checkpointed scan that jax
+    differentiates, the program it was to the bit at any cotangent, and
+    counts no gradients in its forward pass."""
+    import jax
+    from paddle_tpu.utils import monitor
+    monitor.stat_reset()
+    got, want, _ = _head_now_and_as_it_was(dtype, 61, 64, 50, "some",
+                                           weighted, 0.3)
+    assert monitor.get_stat("linear_cross_entropy.calls") == 2
+    assert "linear_cross_entropy.grads_in_forward" not in monitor.all_stats()
+    for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(r, np.float32))
+
+
 def test_linear_cross_entropy_ignore_index():
     import numpy as np
     paddle.seed(34)

@@ -56,6 +56,14 @@ class Net(nn.Layer):
         return x
 
 
+def _head_loss(net):
+    def loss_fn(out, labels):
+        return F.linear_cross_entropy(
+            out.reshape([-1, H]), net.head.weight, net.head.bias,
+            labels.reshape([-1]))
+    return loss_fn
+
+
 def _op_names(compiled_text):
     return set(re.findall(r'op_name="([^"]*)"', compiled_text))
 
@@ -67,17 +75,11 @@ def step_op_names():
     clip."""
     paddle.seed(0)
     net = Net()
-
-    def loss_fn(out, labels):
-        return F.linear_cross_entropy(
-            out.reshape([-1, H]), net.head.weight, net.head.bias,
-            labels.reshape([-1]))
-
     opt = optimizer.AdamW(learning_rate=1e-3, parameters=net.parameters(),
                           grad_clip=ClipGradByGlobalNorm(1.0),
                           multi_precision=True)
     net, opt = amp.decorate(net, opt, level="O2", dtype="float16")
-    step = TrainStep(net, loss_fn, opt,
+    step = TrainStep(net, _head_loss(net), opt,
                      scaler=amp.GradScaler(init_loss_scaling=128.0))
     ids = jnp.zeros((2, 8), jnp.int32)
     step(ids, ids)
@@ -122,6 +124,44 @@ def test_recompute_enters_the_layers_scope_in_every_pass(step_op_names):
     assert any(n.startswith("jit(step_fn)/jvp(loss)/") for n in block)
     assert any(n.startswith("jit(step_fn)/transpose(jvp(loss))/")
                and "rematted_computation" not in n for n in block)
+
+
+def test_the_head_makes_its_gradients_in_the_forward_pass(step_op_names):
+    """No replay under ``linear_cross_entropy``: its three products (the
+    logits, ``dh``, ``dw``) sit in the forward pass's one scan, the two
+    gradient products under jax's name for the chunk's own transposition,
+    and the backward pass holds the scaling by the cotangent alone.  All
+    of it carries the scope, so ``head_loss_ms`` reads all of the head."""
+    head = [n.split("/") for n in step_op_names
+            if scopes.LINEAR_CROSS_ENTROPY in n.split("/")]
+    assert head and not any("rematted_computation" in s for s in head)
+    products = [s for s in head if s[-1] == "dot_general"]
+    assert products and all(
+        s[:3] == ["jit(step_fn)", "jvp(loss)", scopes.LINEAR_CROSS_ENTROPY]
+        and "while" in s for s in products)
+    assert any("transpose(jvp())" in s for s in products)
+    assert any("jvp()" in s for s in products)
+    backward = [s for s in head if s[1] == "transpose(jvp(loss))"]
+    assert backward and not any("while" in s for s in backward)
+    assert {s[-1] for s in backward} <= {"mul", "convert_element_type"}
+
+
+def test_the_head_counts_where_it_made_its_gradients():
+    """``linear_cross_entropy.grads_in_forward`` counts the forward rule
+    at trace time, beside ``.calls``: a ``TrainStep`` reads both the same,
+    ``eval_step`` takes the value path and counts no gradients."""
+    paddle.seed(0)
+    net = Net()
+    opt = optimizer.AdamW(learning_rate=1e-3, parameters=net.parameters())
+    step = TrainStep(net, _head_loss(net), opt)
+    ids = jnp.zeros((2, 8), jnp.int32)
+    monitor.stat_reset()
+    step.eval_step(ids, ids)
+    assert monitor.get_stat("linear_cross_entropy.calls") == 1
+    assert "linear_cross_entropy.grads_in_forward" not in monitor.all_stats()
+    step(ids, ids)
+    assert monitor.get_stat("linear_cross_entropy.calls") == 2
+    assert monitor.get_stat("linear_cross_entropy.grads_in_forward") == 1
 
 
 def test_layers_name_themselves_as_named_parameters_does():
@@ -653,6 +693,7 @@ def test_the_looped_models_counters(ouro_step):
     assert stats["loop.steps"] == T
     assert stats["loop.block_calls"] == T * L
     assert stats["linear_cross_entropy.calls"] == 1
+    assert stats["linear_cross_entropy.grads_in_forward"] == 1
     # once a block application in the forward pass and once in its replay
     assert stats["attention.xla_path"] >= T * L
     # the exit distribution as the two compiled steps counted it
