@@ -541,6 +541,77 @@ def check_flash_dropout(errs, bert):
     errs["flash_dropout"] = "deterministic per seed"
 
 
+def check_flash_grouped(errs, shape=(1, 2048, 32, 128), kv_heads=2):
+    """Grouped key/value heads through the kernels' index maps (the
+    Nemotron cell's 32 query heads on 2, a quarter of its length so that
+    the oracle's scores fit): forward and gradients against the oracle,
+    which repeats k and v."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas.flash_attention import (flash_attention,
+                                                       mha_reference)
+    kv = shape[:2] + (kv_heads, shape[3])
+    q, k, v = (_rnd(i, s, jnp.bfloat16)
+               for i, s in ((1, shape), (2, kv), (3, kv)))
+    flash, plain = (functools.partial(f, causal=True)
+                    for f in (flash_attention, mha_reference))
+    errs["flash_grouped_fwd"] = _close(
+        jax.jit(flash)(q, k, v), jax.jit(plain)(q, k, v), BF16_TOL,
+        "flash grouped fwd")
+    got = jax.jit(jax.grad(_sq(flash), (0, 1, 2)))(q, k, v)
+    ref = jax.jit(jax.grad(_sq(plain), (0, 1, 2)))(q, k, v)
+    for n, a, b in zip("qkv", got, ref):
+        assert a.shape == b.shape, (n, a.shape, b.shape)
+        errs[f"flash_grouped_d{n}"] = _close(a, b, 4 * BF16_TOL,
+                                             f"flash grouped d{n}")
+
+
+def check_ssd_scan(errs, shape=(1, 8192, 64, 64), groups=8, state=128,
+                   chunk=128):
+    """The chunked state-space scan (ops/ssm.py) at the Nemotron cell's
+    shape, one row: bfloat16 x, B and C, float32 dt and A, against the
+    recurrence run a position at a time in float32, forward and every
+    gradient."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.ssm import ssd_scan
+    B, T, H, P = shape
+    x = _rnd(51, shape, jnp.bfloat16, 0.3)
+    Bm, Cm = (_rnd(i, (B, T, groups, state), jnp.bfloat16, 0.3)
+              for i in (52, 53))
+    dt = jax.nn.softplus(_rnd(54, (B, T, H), jnp.float32) - 2.0)
+    # a decay of about 0.99 a position: what a chunk's state starts from
+    # is half of it a chunk later, so a scan that dropped it would show
+    A = -jnp.exp(_rnd(55, (H,), jnp.float32, 0.02) - 3.0)
+    D = 1.0 + _rnd(56, (H,), jnp.float32, 0.02)
+    # the plain reference's recurrence (benchmark/reference/nemotron_h.py:
+    # a two-level scan over positions, the inner one replayed), row by row
+    bench_dir = os.path.join(REPO, "benchmark")
+    if bench_dir not in sys.path:
+        sys.path.insert(0, bench_dir)
+    import run as harness
+    ref = harness.load_module("reference", "nemotron_h")
+    f32 = jnp.float32
+
+    def recurrence(x, dt, A, Bm, Cm, D):
+        return jax.vmap(lambda x, dt, Bm, Cm: ref._scan(
+            x.astype(f32), dt, A, Bm.astype(f32), Cm.astype(f32), D)[0])(
+                x, dt, Bm, Cm)
+
+    args = (x, dt, A, Bm, Cm, D)
+    chunked = functools.partial(ssd_scan, chunk=chunk)
+    errs["ssd_scan_fwd"] = _close(jax.jit(chunked)(*args),
+                                  jax.jit(recurrence)(*args), BF16_TOL,
+                                  "ssd scan fwd")
+    got = jax.jit(jax.grad(_sq(chunked), range(6)))(*args)
+    ref = jax.jit(jax.grad(_sq(recurrence), range(6)))(*args)
+    for n, a, b in zip(("x", "dt", "A", "B", "C", "D"), got, ref):
+        errs[f"ssd_scan_d{n}"] = _close(a, b, 4 * BF16_TOL,
+                                        f"ssd scan d{n}")
+
+
 def check_epilogue(errs, bert):
     """The recipe the Executor realises on BERT-base (proj + bias +
     residual + LayerNorm) at the two phases' rows: f32 as the static
@@ -653,6 +724,8 @@ def phase_kernels(clog, bert=BERT_BASE, serve=SERVE, engine=SERVE_ENGINE):
     with jax.default_matmul_precision("highest"):
         check_flash(errs, bert)
         check_flash_dropout(errs, bert)
+        check_flash_grouped(errs)
+        check_ssd_scan(errs)
         check_epilogue(errs, bert)
         check_adam(errs, bert)
         check_paged(errs, serve, engine)

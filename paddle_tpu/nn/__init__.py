@@ -57,3 +57,4 @@ from .utils import remove_weight_norm, weight_norm  # noqa: F401,E402
 from . import utils as weight_norm_hook  # noqa: F401,E402
 from .layer.mla import MLAttention  # noqa: F401,E402
 from .layer.looped import LoopedStack, LoopExitGate  # noqa: F401,E402
+from .layer.ssm import Mamba2Mixer  # noqa: F401,E402

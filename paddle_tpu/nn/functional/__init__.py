@@ -1186,7 +1186,11 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
                                  training=True, name=None,
                                  return_weights=False):
-    """[B, L, H, D] attention (paddle incubate layout).  The Pallas
+    """[B, L, H, D] attention (paddle incubate layout); ``key`` and
+    ``value`` may have fewer heads [B, Lk, Hk, D], ``Hk`` dividing ``H``
+    (grouped-query attention: query head h reads key/value head
+    ``h // (H / Hk)``; the kernel's index maps do it, XLA's path repeats
+    them).  The Pallas
     flash-attention kernel (paddle_tpu.ops.pallas) replaces the jnp path
     when the tier is on (``ops.pallas.support.tier_enabled``), there is
     no ``attn_mask`` and the longer sequence has 512 positions or more,
@@ -1233,6 +1237,12 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         dkey = sdpa_draw.key() if use_dropout else None
         mask = m[0] if m else None
         B, Lq, H, D = q.shape
+        if k.shape[2] != H:
+            if H % k.shape[2]:
+                raise ValueError(f"{k.shape[2]} key/value heads do not "
+                                 f"divide {H} query heads")
+            k = jnp.repeat(k, H // k.shape[2], axis=2)
+            v = jnp.repeat(v, H // v.shape[2], axis=2)
         scale = 1.0 / math.sqrt(D)
         qt = jnp.einsum("blhd,bshd->bhls", q, k) * scale
         if is_causal:
@@ -1835,9 +1845,11 @@ def moe_experts(x, router_weight, w_gate, w_up, w_down, top_k, first_expert=0,
     """The part of a mixture-of-experts layer that the held experts give
     (ops/moe.py).  ``x`` [..., H] is the float32 normed stream; the router
     ``router_weight`` [H, E] spans all E experts and runs in float32; the
-    SwiGLU expert weights are the held slice, stacked: ``w_gate`` /
-    ``w_up`` [held, H, F], ``w_down`` [held, F, H], experts
-    ``first_expert ..`` of the E.  Every assignment of a token to a held
+    expert weights are the held slice, stacked: ``w_gate`` / ``w_up``
+    [held, H, F], ``w_down`` [held, F, H], experts ``first_expert ..`` of
+    the E.  An expert is a SwiGLU, ``(silu(x Wg) * (x Wu)) Wd``, or, where
+    ``w_gate`` is None, ``relu(x Wu)^2 Wd``: two products and no gate (the
+    shared expert's gate is then None too).  Every assignment of a token to a held
     expert is computed (no capacity, no dropped token) through grouped
     matmuls; what the absent experts would add is left out.
 
@@ -1847,7 +1859,7 @@ def moe_experts(x, router_weight, w_gate, w_up, w_down, top_k, first_expert=0,
     ``noaux_tc`` selection); it gets no gradient.  The gates are
     multiplied by ``routed_scaling_factor``.  ``shared``: the three
     weights ``(gate [H, Fs], up [H, Fs], down [Fs, H])`` of a shared
-    expert, a plain SwiGLU over every token whose result is added
+    expert, one expert of the same form over every token whose result is added
     unscaled: every member of an expert-parallel group computes it alike,
     so a sum of the members' parts counts it once a member.
     ``train_router`` False holds the router still: the gates carry no
@@ -1855,19 +1867,27 @@ def moe_experts(x, router_weight, w_gate, w_up, w_down, top_k, first_expert=0,
     member that runs without its group: ops/moe.py).  Returns float32 of
     x's shape."""
     from ...ops.moe import moe_forward
-    extras = ([] if router_bias is None else [router_bias]) + list(shared or ())
+    if shared is not None and (shared[0] is None) != (w_gate is None):
+        raise ValueError("the shared expert has the experts' form: a gate "
+                         "where they have one, None where they have none")
+    s_gate, s_up, s_down = shared or (None, None, None)
+    # the tensors that are there, by name: ``apply`` takes no None
+    given = {k: v for k, v in dict(
+        w_gate=w_gate, w_up=w_up, w_down=w_down, bias=router_bias,
+        s_gate=s_gate, s_up=s_up, s_down=s_down).items() if v is not None}
 
-    def fn(a, r, g, u, d, *rest):
-        rest = list(rest)
-        bias = rest.pop(0) if router_bias is not None else None
+    def fn(a, r, *values):
+        t = dict(zip(given, values))
         return moe_forward(
-            a, r, g, u, d, top_k=int(top_k), first=int(first_expert),
-            norm_topk_prob=bool(norm_topk_prob), scoring=scoring,
-            router_bias=bias, scaling=float(routed_scaling_factor),
-            shared=tuple(rest) or None, train_router=bool(train_router))
+            a, r, t.get("w_gate"), t["w_up"], t["w_down"], top_k=int(top_k),
+            first=int(first_expert), norm_topk_prob=bool(norm_topk_prob),
+            scoring=scoring, router_bias=t.get("bias"),
+            scaling=float(routed_scaling_factor),
+            shared=None if shared is None else
+            (t.get("s_gate"), t["s_up"], t["s_down"]),
+            train_router=bool(train_router))
 
-    return apply(fn, x, router_weight, w_gate, w_up, w_down, *extras,
-                 op_name="moe_experts")
+    return apply(fn, x, router_weight, *given.values(), op_name="moe_experts")
 
 
 def _sparse_kernels(query=None, key=None, index_query=None):
@@ -2016,6 +2036,51 @@ def _exit_log_probs(z):
     leave = jnp.concatenate([jax.nn.log_sigmoid(z[:T - 1]),
                              jnp.zeros_like(z[:1])], axis=0)
     return before + leave
+
+
+# ---------------------------------------------------------------------------
+# the parts of a state-space mixer (ops/ssm.py)
+# ---------------------------------------------------------------------------
+
+@jax.named_scope(scopes.SSM_CONV)
+def causal_conv1d(x, weight, bias=None, activation=None, name=None):
+    """Depthwise causal convolution over time: x [B, T, C], ``weight``
+    [K, C] (tap K - 1 meets the position itself, tap 0 the one K - 1
+    back), ``bias`` [C]; ``activation`` None or ``"silu"``.  K shifted
+    multiply-adds in float32; the result has x's type."""
+    from ...ops.ssm import causal_conv1d as _conv
+    args = [x, weight] + ([] if bias is None else [bias])
+    return apply(lambda a, w, *b: _conv(a, w, b[0] if b else None,
+                                        activation),
+                 *args, op_name="causal_conv1d")
+
+
+@jax.named_scope(scopes.SSM_SCAN)
+def ssd_scan(x, dt, A, B, C, D, chunk_size=128, name=None):
+    """Mamba-2's selective state-space scan (Dao & Gu 2024) in its
+    chunked form.  Head h of group ``h // (H / G)`` keeps a state
+    [P, N], zero at the start of every row:
+    ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t C_t + D x_t``.
+    ``x`` [B, T, H, P]; ``dt`` [B, T, H] float32 and positive (after its
+    softplus); ``A`` [H] float32, negative; ``B`` and ``C`` [B, T, G, N]
+    in x's type; ``D`` [H] float32.  -> y [B, T, H, P] in x's type.
+    Matrix products over chunks of ``chunk_size`` positions, the decays
+    in float32, a hand-written backward that walks the chunks the other
+    way (ops/ssm.py); T need not be a multiple of ``chunk_size``."""
+    from ...ops.ssm import ssd_scan as _scan
+    return apply(lambda *a: _scan(*a, int(chunk_size)), x, dt, A, B, C, D,
+                 op_name="ssd_scan")
+
+
+@jax.named_scope(scopes.SSM_GATE_NORM)
+def gated_group_rms_norm(y, z, weight, groups, epsilon=1e-5, name=None):
+    """``RMSNorm(y * silu(z)) * weight`` with the mean of squares taken
+    over each of ``groups`` runs of the last axis (Mamba-2's gated norm,
+    the gate before the norm), in float32; the result has the weight's
+    type."""
+    from ...ops.ssm import gated_group_rms_norm as _norm
+    return apply(lambda a, g, w: _norm(a, g, w, int(groups), epsilon),
+                 y, z, weight, op_name="gated_group_rms_norm")
 
 
 @jax.named_scope(scopes.LOOP_EXIT)

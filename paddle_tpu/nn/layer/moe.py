@@ -1,5 +1,6 @@
 """A routed mixture of experts as a layer that is told which experts it
-holds (functional: ``F.moe_experts``, mathematics: ops/moe.py)."""
+holds and which form they have (functional: ``F.moe_experts``,
+mathematics: ops/moe.py)."""
 from __future__ import annotations
 
 from .. import functional as F
@@ -8,8 +9,12 @@ from ..layer_base import Layer
 
 
 class MoELayer(Layer):
-    """SwiGLU experts behind a top-k router that scores by a softmax over
-    the experts or by a sigmoid of each logit (``scoring``).
+    """Experts behind a top-k router that scores by a softmax over the
+    experts or by a sigmoid of each logit (``scoring``).  ``expert_form``:
+    ``"swiglu"``, ``(silu(x Wg) * (x Wu)) Wd`` (``w_gate``, ``w_up``,
+    ``w_down``), or ``"relu2"``, ``relu(x Wu)^2 Wd``: two matrices an
+    expert, no ``w_gate`` (and no ``shared_gate``) is created and two
+    products run where the SwiGLU runs three.
 
     The router spans all ``num_experts`` experts (``router_weight``
     [hidden, num_experts]; its matmul, scores and top-k run in float32);
@@ -26,9 +31,9 @@ class MoELayer(Layer):
     the gates stay the chosen scores', and no gradient reaches it (it is
     there for a balancing rule to move; none runs here).  The gates are
     multiplied by ``routed_scaling_factor``.  ``shared_width`` adds a
-    shared expert, a SwiGLU of that width over every token
-    (``shared_gate`` / ``shared_up`` / ``shared_down``), which every
-    member of a group computes alike.
+    shared expert, one expert of the layer's form and that width over
+    every token (``shared_gate`` / ``shared_up`` / ``shared_down``), which
+    every member of a group computes alike.
 
     ``train_router=False`` holds the router still: its weight gets no
     gradient and the stream none through it.  For a member that trains
@@ -39,10 +44,15 @@ class MoELayer(Layer):
                  held=None, norm_topk_prob=True, name=None,
                  scoring="softmax", selection_bias=False,
                  routed_scaling_factor=1.0, shared_width=None,
-                 train_router=True):
+                 train_router=True, expert_form="swiglu"):
         super().__init__()
         if scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"scoring {scoring!r}: 'softmax' or 'sigmoid'")
+        if expert_form not in ("swiglu", "relu2"):
+            raise ValueError(f"expert_form {expert_form!r}: 'swiglu' or "
+                             "'relu2'")
+        self.expert_form = expert_form
+        gated = expert_form == "swiglu"
         held = range(num_experts) if held is None else held
         if (held.step != 1 or not 0 <= held.start < held.stop <= num_experts):
             raise ValueError(f"held={held!r} is not a run of the "
@@ -57,7 +67,8 @@ class MoELayer(Layer):
         self.router_weight = self.create_parameter(
             [hidden_size, num_experts], default_initializer=init)
         self.w_gate = self.create_parameter(
-            [n, hidden_size, expert_width], default_initializer=init)
+            [n, hidden_size, expert_width],
+            default_initializer=init) if gated else None
         self.w_up = self.create_parameter(
             [n, hidden_size, expert_width], default_initializer=init)
         self.w_down = self.create_parameter(
@@ -69,7 +80,8 @@ class MoELayer(Layer):
                 [num_experts], default_initializer=I.Constant(0.0))
         if self.shared_width:
             self.shared_gate = self.create_parameter(
-                [hidden_size, shared_width], default_initializer=init)
+                [hidden_size, shared_width],
+                default_initializer=init) if gated else None
             self.shared_up = self.create_parameter(
                 [hidden_size, shared_width], default_initializer=init)
             self.shared_down = self.create_parameter(
@@ -89,4 +101,4 @@ class MoELayer(Layer):
     def extra_repr(self):
         return (f"experts {self.held.start}..{self.held.stop - 1} of "
                 f"{self.num_experts}, top_k={self.top_k}, "
-                f"scoring={self.scoring}")
+                f"scoring={self.scoring}, {self.expert_form} experts")

@@ -52,7 +52,8 @@ DSA_INDEXER = "dsa_indexer"   # index scores and the indexer's KL loss
 DSA_SELECT = "dsa_select"     # the top-k threshold a query and the mask
 SPARSE_ATTENTION = "sparse_attention"
 QK_NORM = "qk_norm"           # RMSNorm per head on q and k
-MOE_SHARED = "moe_shared"     # the shared expert: a SwiGLU over every token
+MOE_SHARED = "moe_shared"     # the shared expert: one expert of the layer's
+                              # form (SwiGLU or relu^2) over every token
 MLA_ATTENTION = "mla_attention"  # latent attention: one rotated key a row
                               # beside the heads' own, the flash kernels
 MTP = "mtp"                   # a multi-token-prediction module, its head too
@@ -61,11 +62,16 @@ LOOP_STACK = "loop_stack"     # one pass of a looped stack (nn.LoopedStack):
 LOOP_EXIT = "loop_exit"       # a looped model's exit: the gate, the exit
                               # distribution, its entropy, the weighting
                               # (the head stays under linear_cross_entropy)
+SSM = "ssm"                   # a state-space mixer (nn.Mamba2Mixer), every
+                              # part of it, its two projections too
+SSM_CONV = "ssm_conv"         # the causal convolution: taps, bias, silu
+SSM_SCAN = "ssm_scan"         # softplus, the decays, the chunked scan, D x
+SSM_GATE_NORM = "ssm_gate_norm"  # the gate and the group norm
 FUNCTIONALS = (ATTENTION, LINEAR_CROSS_ENTROPY, GELU, LAYER_NORM, EMBEDDING,
                DROPOUT, EVA_ATTENTION, EVA_POOL, RMS_NORM, ROPE, MOE,
                MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, DSA_INDEXER, DSA_SELECT,
                SPARSE_ATTENTION, QK_NORM, MOE_SHARED, MLA_ATTENTION, MTP,
-               LOOP_STACK, LOOP_EXIT)
+               LOOP_STACK, LOOP_EXIT, SSM, SSM_CONV, SSM_SCAN, SSM_GATE_NORM)
 
 # -- Pallas kernels ----------------------------------------------------------
 FLASH_FWD = "flash_fwd"
@@ -124,6 +130,15 @@ LOOP_EXIT_SHARE = "loop.exit_share"   # [passes]: the mean of p_t, the
                               # probability of leaving after pass t
 LOOP_EXIT_ENTROPY = "loop.exit_entropy"  # the mean entropy of that
                               # distribution, in nats
+# Emitted a call of ``nn.Mamba2Mixer`` (one state-space layer), float32
+# scalars: whether the recurrence carries anything.
+SSM_STATE_SHARE = "ssm.state_share"   # RMS of the state's part S_t C_t of
+                              # the scan's output over the RMS of all of it
+                              # (S_t C_t + D x_t): near 0 the scan is D x
+SSM_MEAN_DECAY = "ssm.mean_decay"     # the mean of exp(dt A) over tokens and
+                              # heads: near 1 the state never moves, near 0
+                              # it forgets inside a chunk
 DEVICE_COUNTERS = (MOE_EXPERT_LOAD, MOE_CHUNK_ASSIGNMENTS,
                    MOE_FULL_BUFFER_CHUNKS, MOE_FULLEST_EXPERT_LOAD,
-                   LOOP_EXIT_SHARE, LOOP_EXIT_ENTROPY)
+                   LOOP_EXIT_SHARE, LOOP_EXIT_ENTROPY, SSM_STATE_SHARE,
+                   SSM_MEAN_DECAY)

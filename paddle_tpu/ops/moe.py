@@ -13,8 +13,13 @@ held (``norm_topk_prob``); the layer returns the part of the result that
 the held experts give, and what the absent ones would add is left out
 (the caller of an expert-parallel group sums the parts; on one chip
 nothing stands in for the absent chips).  A shared expert, where the
-layer has one, is a plain SwiGLU over every token added to that part:
-every member of a group computes it alike.
+layer has one, is one expert of the layer's form over every token added
+to that part: every member of a group computes it alike.
+
+An expert has one of two forms, told by its weights: with a gate it is a
+SwiGLU, ``(silu(x Wg) * (x Wu)) Wd``; without one (``w_gate`` None) it is
+``relu(x Wu)^2 Wd`` (Nemotron-H's ``relu2``), which runs two products
+where the SwiGLU runs three and has no gate leaf at all.
 
 A member that runs WITHOUT its group can hold its routers still
 (``train_router=False``: the gates are constants of the backward pass).
@@ -28,7 +33,7 @@ that one token chose together.
 
 No capacity, no dropped token.  The (token, slot) assignments are sorted
 by held expert, the absent ones last; the tokens of the held ones are
-gathered into one [rows, H] buffer, the three SwiGLU products run as
+gathered into one [rows, H] buffer, the expert's products run as
 grouped matmuls over it (``jax.lax.ragged_dot``: on a TPU XLA lowers it
 to a Mosaic kernel that walks only the tiles the group sizes cover, so
 its cost follows the load, not the buffer), and the result goes back by
@@ -98,14 +103,24 @@ def moe_route(x32, router_w, top_k, norm_topk_prob=True, scoring="softmax",
     return gates, ids.astype(jnp.int32)
 
 
+def _hidden(gate, up):
+    """An expert's hidden state in float32 from its first products:
+    ``silu(gate) * up``, or ``relu(up)^2`` where it has no gate."""
+    up = up.astype(jnp.float32)
+    if gate is None:
+        return jnp.square(jax.nn.relu(up))
+    return jax.nn.silu(gate.astype(jnp.float32)) * up
+
+
 @jax.named_scope(scopes.MOE_SHARED)
 def _shared_expert(x, w_gate, w_up, w_down):
-    """x [N, H] in the weights' type -> a SwiGLU over every token,
-    float32."""
+    """x [N, H] in the weights' type -> one expert over every token,
+    float32: a SwiGLU, or ``relu(x Wu)^2 Wd`` where ``w_gate`` is None."""
     def dot(a, w):
         return jnp.dot(a, w, preferred_element_type=jnp.float32)
 
-    a = (jax.nn.silu(dot(x, w_gate)) * dot(x, w_up)).astype(x.dtype)
+    a = _hidden(None if w_gate is None else dot(x, w_gate),
+                dot(x, w_up)).astype(x.dtype)
     return dot(a, w_down)
 
 
@@ -171,7 +186,8 @@ def _grouped(rows, w, sizes):
 def moe_experts(x, gates, local, w_gate, w_up, w_down, rows=None):
     """One chunk.  x [n, H]; gates [n, K] float32; ``local`` [n, K]: the
     slot's expert as an index into the held stack, ``held`` (one past
-    the last) where it is not held.  ``rows``: the size of the experts'
+    the last) where it is not held; ``w_gate`` None: experts without a
+    gate (the header's second form).  ``rows``: the size of the experts'
     buffer, which must take every held assignment of the chunk (n * K,
     the default, always does).  -> [n, H] float32."""
     return _experts(x, gates, local, w_gate, w_up, w_down, rows)[0]
@@ -182,7 +198,7 @@ def _experts(x, gates, local, w_gate, w_up, w_down, rows):
     need anyway: -> ([n, H] float32, [held] int32, the assignments each
     held expert got)."""
     n, K = local.shape
-    held = w_gate.shape[0]
+    held = w_up.shape[0]
     P = n * K
     R = P if rows is None else rows
     with jax.named_scope(scopes.MOE_DISPATCH):
@@ -198,10 +214,8 @@ def _experts(x, gates, local, w_gate, w_up, w_down, rows):
         live = local < held
         x_rows = _dispatch(x, tok, pos, live)
     with jax.named_scope(scopes.MOE_EXPERTS):
-        g = _grouped(x_rows, w_gate, sizes)
-        u = _grouped(x_rows, w_up, sizes)
-        a = (jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
-             ).astype(x_rows.dtype)
+        g = None if w_gate is None else _grouped(x_rows, w_gate, sizes)
+        a = _hidden(g, _grouped(x_rows, w_up, sizes)).astype(x_rows.dtype)
         y = _grouped(a, w_down, sizes)
     with jax.named_scope(scopes.MOE_DISPATCH):
         # rows past the last held assignment belong to no group: whatever
@@ -239,7 +253,7 @@ def _chunk(small, x, gates, local, w_gate, w_up, w_down, counting=False):
         out, sizes = _experts(x, gates, local, w_gate, w_up, w_down, rows)
         return (out, sizes) if counting else out
 
-    return _by_load(small, local, w_gate.shape[0], fn)
+    return _by_load(small, local, w_up.shape[0], fn)
 
 
 def _by_load(small, local, held, fn):
@@ -273,12 +287,37 @@ def _chunk_bwd(small, counting, res, d_out):
     # scope here the backward's gathers would read as plain ``moe`` time
     # (the grouped products are kernels with names of their own)
     with jax.named_scope(scopes.MOE_DISPATCH):
-        dx, dgates, dwg, dwu, dwd = _by_load(small, local, w_gate.shape[0],
+        dx, dgates, dwg, dwu, dwd = _by_load(small, local, w_up.shape[0],
                                              grads)
     return dx, dgates, None, dwg, dwu, dwd
 
 
 _chunk.defvjp(_chunk_fwd, _chunk_bwd)
+
+
+# XLA's grouped-matmul kernel is slow at an expert width that is no
+# multiple of 256.  One v5e, 3,072 of a 6,912-row buffer live, 8 experts
+# of [2688, F], bfloat16, ms for the up and the down product and for the
+# gradients of each: F 1856 2.65 / 2.55 / 6.77 / 5.80, 1920 2.74 / 2.81 /
+# 6.23 / 7.06, 2048 1.41 / 1.66 / 3.75 / 3.68; the pad itself 0.9 ms a
+# weight.  In the step of the cell that has such a width (1856): 705.10 ->
+# 625.18 ms, the expert layers 272.77 -> 192.74, the footprint 15.39 ->
+# 15.27 GB (PERF.md section 6, PR 39).
+_WIDTH_MULTIPLE = 256
+
+
+def _padded_width(w_gate, w_up, w_down):
+    """The experts' weights with zero columns (``w_gate``, ``w_up``) and
+    zero rows (``w_down``) up to a width of ``_WIDTH_MULTIPLE``: a hidden
+    unit of zeros gives silu(0) * 0 or relu(0)^2, times a row of zeros,
+    so the result and every gradient are what they were.  Widths that are
+    such a multiple come back as they are."""
+    pad = -w_up.shape[-1] % _WIDTH_MULTIPLE
+    if not pad:
+        return w_gate, w_up, w_down
+    cols, rows = ((0, 0), (0, 0), (0, pad)), ((0, 0), (0, pad), (0, 0))
+    return (None if w_gate is None else jnp.pad(w_gate, cols),
+            jnp.pad(w_up, cols), jnp.pad(w_down, rows))
 
 
 @jax.named_scope(scopes.MOE_ROUTER)
@@ -315,12 +354,14 @@ def moe_forward(x32, router_w, w_gate, w_up, w_down, *, top_k, first,
                 scaling=1.0, shared=None, train_router=True):
     """x32 [..., H], the float32 normed stream -> the held experts' part
     of the layer's result, float32, same shape.  The experts take x in
-    the weights' type.  ``scoring``, ``router_bias`` and ``scaling`` are
+    the weights' type; ``w_gate`` None: experts of the form without a
+    gate.  ``scoring``, ``router_bias`` and ``scaling`` are
     ``moe_route``'s; ``shared`` the (gate, up, down) weights of a shared
-    expert, whose result over every token is added.  ``train_router``
+    expert of the same form (its gate None too), whose result over every
+    token is added.  ``train_router``
     False: no gradient reaches the router's weight or, through the
     router, the stream (the header says when)."""
-    held, total = w_gate.shape[0], router_w.shape[1]
+    held, total = w_up.shape[0], router_w.shape[1]
     if not 0 <= first <= total - held:
         raise ValueError(f"experts {first}..{first + held - 1} are not "
                          f"among the router's {total}")
@@ -334,6 +375,8 @@ def moe_forward(x32, router_w, w_gate, w_up, w_down, *, top_k, first,
         monitor.stat_add("moe.scoring_sigmoid")
     if shared is not None:
         monitor.stat_add("moe.shared_experts")
+    if w_gate is None:
+        monitor.stat_add("moe.gateless_experts")
     shape = x32.shape
     H = shape[-1]
     flat = x32.reshape(-1, H)
@@ -345,15 +388,16 @@ def moe_forward(x32, router_w, w_gate, w_up, w_down, *, top_k, first,
         gates = jax.lax.stop_gradient(gates)
     local = ids - first
     local = jnp.where((local >= 0) & (local < held), local, held)
-    x = flat.astype(w_gate.dtype)
+    x = flat.astype(w_up.dtype)
     small = _small_buffer(chunk, top_k, held, total)
     # what a chunk's gathers walk, beside moe.experts_held: with the device
     # counters below a reader turns counts into rows gathered
     monitor.stat_set("moe.small_buffer_rows", small or chunk * top_k)
     monitor.stat_set("moe.full_buffer_rows", chunk * top_k)
     counting = device_counters.collecting()
+    experts = _padded_width(w_gate, w_up, w_down)
     out = jax.lax.map(
-        lambda c: _chunk(small, *c, w_gate, w_up, w_down, counting),
+        lambda c: _chunk(small, *c, *experts, counting),
         (x.reshape(N // chunk, chunk, H),
          gates.reshape(N // chunk, chunk, top_k),
          local.reshape(N // chunk, chunk, top_k)))

@@ -8,7 +8,13 @@ streams KV, and carries the online-softmax running max/sum so the [L, L]
 score matrix never touches HBM.
 
 Layout: [B, L, H, D] in (paddle layout), transposed once to [B, H, L, D]
-around the kernel.  The values may have a width of their own (``Dv``):
+around the kernel.  Keys and values may have fewer heads than the
+queries (grouped-query attention: ``Hk`` divides ``H``, query head h
+reads key/value head ``h // (H / Hk)``): the kernels' index maps send a
+group's query heads to the one key/value head, which is never repeated
+in HBM, and the backward walk emits each query head's part of dK and dV,
+summed over the group in float32 outside (Nemotron-3-Nano, the
+benchmark's: 32 query heads on 2).  The values may have a width of their own (``Dv``):
 q, k, dq and dk are ``D`` wide, v, out, dO and dv ``Dv``.  A part of the
 key may be one for all heads (``flash_attention_shared_key``: latent
 attention's rotated key, [B, 1, L, Dr]); it is staged once a batch entry
@@ -101,7 +107,13 @@ def flash_attention_supported(q_shape, k_shape, dtype, attn_mask=None,
     backward) — but only on real TPUs (interpret mode has no PRNG).
     ``v_head_dim``: the values' width where it is not the keys';
     ``shared_key_dim``: the width of a key part all heads share, beside
-    the ``D`` of the shapes (`flash_attention_shared_key`)."""
+    the ``D`` of the shapes (`flash_attention_shared_key`).
+
+    Head counts: ``q_shape`` [B, Lq, H, D] and ``k_shape`` [B, Lk, Hk, D]
+    with ``Hk`` dividing ``H`` (``Hk == H``: plain multi-head attention;
+    fewer: grouped-query attention, query head h on key/value head
+    ``h // (H / Hk)``; the values have the keys' head count).  The
+    shared-key call (``shared_key_dim``) takes ``Hk == H`` only."""
     if attn_mask is not None:
         return False
     if dropout_p > 0.0 and _interpret():
@@ -109,7 +121,9 @@ def flash_attention_supported(q_shape, k_shape, dtype, attn_mask=None,
     if len(q_shape) != 4:
         return False
     B, Lq, H, D = q_shape
-    Lk = k_shape[1]
+    Lk, Hk = k_shape[1], k_shape[2]
+    if H % Hk or (shared_key_dim and Hk != H):
+        return False
     Dv = D if v_head_dim is None else v_head_dim
     if dtype not in (jnp.float32, jnp.bfloat16):
         return False
@@ -429,11 +443,19 @@ def _fwd_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref, *rest,
     lse_ref[0, 0] = jnp.broadcast_to(lse, (8, bq))
 
 
-def _qkv_fwd_specs(block_q, Lk, D, Dv, Dr=0):
+def _kv_head(h, group):
+    """The key/value head that query head ``h`` reads, ``group`` query
+    heads to one (1: its own, and the index map is what it always was)."""
+    return h if group == 1 else h // group
+
+
+def _qkv_fwd_specs(block_q, Lk, D, Dv, Dr=0, group=1):
     """In-specs of the forward kernel; with ``Dr`` also the
     query part [B, H, Lq, Dr] that meets the shared key [B, 1, Lk, Dr],
     which is staged once a batch entry: its block index does not move
-    with the head."""
+    with the head.  ``group`` query heads read one key/value head: its
+    block index does not move inside a group, so it is staged once a
+    group."""
     shared = [
         pl.BlockSpec((1, 1, block_q, Dr), lambda b, h, i: (b, h, i, 0)),
         pl.BlockSpec((1, 1, Lk, Dr), lambda b, h, i: (b, 0, 0, 0)),
@@ -443,8 +465,10 @@ def _qkv_fwd_specs(block_q, Lk, D, Dv, Dr=0):
         _smem_scalar_spec(),
         _smem_scalar_spec(),
         pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, Lk, D), lambda b, h, i: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, Lk, Dv), lambda b, h, i: (b, h, 0, 0)),
+        pl.BlockSpec((1, 1, Lk, D),
+                     lambda b, h, i: (b, _kv_head(h, group), 0, 0)),
+        pl.BlockSpec((1, 1, Lk, Dv),
+                     lambda b, h, i: (b, _kv_head(h, group), 0, 0)),
     ] + shared
 
 
@@ -454,8 +478,8 @@ def _shared_width(shared):
 
 def _fwd(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
          dropout_p=0.0, shared=None):
-    """q/k [B, H, L, D], v [B, H, Lk, Dv] → (out [B,H,Lq,Dv], lse
-    [B,H,Lq]).  ``shared``: ``(qr [B, H, Lq, Dr], kr [B, 1, Lk, Dr])``,
+    """q [B, H, L, D], k [B, Hk, Lk, D], v [B, Hk, Lk, Dv] → (out
+    [B,H,Lq,Dv], lse [B,H,Lq]).  ``shared``: ``(qr [B, H, Lq, Dr], kr [B, 1, Lk, Dr])``,
     a key part all heads share and the query part that meets it."""
     _count_blocks(q.shape[2], k.shape[2], *blocks, causal, aligned)
     return _fwd_call(q, k, v, q_off, k_off, seed, scale, causal, blocks,
@@ -475,7 +499,7 @@ def _fwd_call(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
     out, lse = pl.pallas_call(
         kernel,
         grid=(B, H, Lq // block_q),
-        in_specs=_qkv_fwd_specs(block_q, Lk, D, Dv, Dr),
+        in_specs=_qkv_fwd_specs(block_q, Lk, D, Dv, Dr, H // k.shape[1]),
         out_specs=[
             pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, 8, block_q), lambda b, h, i: (b, h, 0, i)),
@@ -605,7 +629,9 @@ def _bwd_dkv(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale, causal,
 def _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale,
                   causal, blocks, aligned, dropout_p, interpret, with_dq,
                   shared=None):
-    """-> (dk, dv), with ``shared`` also each head's float32 part of the
+    """-> (dk, dv), each QUERY head's part [B, H, Lk, ...] (k and v may
+    have fewer heads, a group of query heads on each: `_bwd` sums the
+    parts), with ``shared`` also each head's float32 part of the
     shared key's gradient [B, H, Lk, Dr], with ``with_dq`` then dq (and
     with both dqr).  A head's dQ block is its whole [Lq, D] under an index
     that does not move with the k block: it goes to HBM once a (batch,
@@ -623,6 +649,12 @@ def _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale,
         return pl.BlockSpec((1, 1, block_k, width),
                             lambda b, h, j: (b, h, j, 0))
 
+    group = H // k.shape[1]
+
+    def kv_rows(width):
+        return pl.BlockSpec((1, 1, block_k, width),
+                            lambda b, h, j: (b, _kv_head(h, group), j, 0))
+
     out = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
                           seq_q=Lq, causal=causal, block_k=block_k,
@@ -634,8 +666,8 @@ def _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale,
             _smem_scalar_spec(),
             _smem_scalar_spec(),
             whole(D),
-            rows(D),
-            rows(Dv),
+            kv_rows(D),
+            kv_rows(Dv),
         ] + ([whole(Dr), pl.BlockSpec((1, 1, block_k, Dr),
                                       lambda b, h, j: (b, 0, j, 0))]
              if shared else []) + [
@@ -680,9 +712,21 @@ def _bwd(q, k, v, q_off, k_off, seed, out, lse, do, dlse, scale, causal,
                              scale, causal, blocks, aligned, dropout_p,
                              shared=shared, with_dq=True)
     if not shared:
-        return rest[0], dk, dv
+        return rest[0], _sum_groups(dk, k), _sum_groups(dv, v)
     dkr, dq, dqr = rest
     return (dq, dqr), (dk, dkr), dv
+
+
+def _sum_groups(parts, like):
+    """The query heads' parts [B, H, Lk, D] of a key/value gradient, summed
+    in float32 over each group that shares a head of ``like``
+    [B, Hk, Lk, D]; with a head each they are the gradient."""
+    Hk = like.shape[1]
+    if parts.shape[1] == Hk:
+        return parts
+    B, H, Lk, D = parts.shape
+    return jnp.sum(parts.reshape(B, Hk, H // Hk, Lk, D), 2,
+                   dtype=jnp.float32).astype(parts.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -786,9 +830,10 @@ def _zero_seed():
 def flash_attention(q, k, v, causal: bool = False, scale=None,
                     block_q: int | None = None, block_k: int | None = None,
                     dropout_p: float = 0.0, seed=None):
-    """q/k: [B, L, H, D], v: [B, Lk, H, Dv] (``Dv`` may differ from
-    ``D``) → [B, Lq, H, Dv] attention output; the default ``scale`` is
-    ``D ** -0.5``.
+    """q: [B, L, H, D], k: [B, Lk, Hk, D], v: [B, Lk, Hk, Dv] (``Dv`` may
+    differ from ``D``; ``Hk`` divides ``H``, query head h reads key/value
+    head ``h // (H / Hk)``) → [B, Lq, H, Dv] attention output; the default
+    ``scale`` is ``D ** -0.5``.
 
     ``block_q`` / ``block_k`` left at None are chosen from the static
     shapes (`_resolve_blocks`).
@@ -853,8 +898,11 @@ def flash_attention_block(q_bhld, k_bhld, v_bhld, q_off, k_off, scale,
 
 
 def mha_reference(q, k, v, causal=False, scale=None):
-    """jnp oracle for tests ([B, L, H, D] layout; v may be [.., Dv])."""
+    """jnp oracle for tests ([B, L, H, D] layout; v may be [.., Dv]; k
+    and v may have fewer heads, each repeated over its group)."""
     D = q.shape[-1]
+    group = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     s = jnp.einsum("blhd,bshd->bhls", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale

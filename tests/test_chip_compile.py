@@ -540,6 +540,50 @@ def test_ouro_cell_step_fits_the_chip(one_chip, monkeypatch):
     assert footprint < 15.75 * 2 ** 30
 
 
+def test_nemotron_h_cell_step_fits_the_chip(one_chip, monkeypatch):
+    """The nemotron_3_nano_30b_a3b.train_bf16_b2_s8192 cell's whole step
+    (MEMEM*EME: four Mamba-2 mixers, four relu2 expert layers with 8 of
+    128 experts, one grouped-query attention block on 32 / 2 heads, an
+    eighth of the vocabulary; two rows of 8192) for the described v5e: it
+    compiles, holds ONE flash forward kernel and one backward walk at a
+    head shape no other cell runs ([2, 8192, 32, 128] on [2, 8192, 2,
+    128]), XLA's own grouped-matmul kernel, no gate product, and fits."""
+    from paddle_tpu.observability import scopes
+    from paddle_tpu.utils import monitor
+    monitor.stat_reset()
+    compiled, n, cfg, mix, footprint, step = _cell_step(
+        one_chip, monkeypatch,
+        "nemotron_3_nano_30b_a3b.train_bf16_b2_s8192", ("flash_attention",))
+    assert n == cfg["parameters"] == 666_963_456
+    assert (cfg["hidden_size"], mix["seq"]) == (2688, 8192)
+    pattern = cfg["hybrid_override_pattern"]
+    assert pattern == "MEMEM*EME"
+    text = compiled.as_text()
+    _one_backward_kernel_a_block(text, pattern.count("*"))
+    _head_made_its_gradients_in_the_forward_pass(text, calls=1)
+    assert "ragged-dot" in text
+    stats = monitor.all_stats()
+    experts, mixers = pattern.count("E"), pattern.count("M")
+    assert [stats.get(f"recompute.kept.{name}", 0)
+            for name in scopes.RESIDUALS] == [1, 1, 0, 0, 0]
+    assert stats["pallas.selected.flash_attention"] >= 1
+    assert "attention.xla_path" not in stats
+    assert (stats["moe.experts_held"], stats["moe.experts_total"],
+            stats["moe.top_k"]) == (8, 128, 6)
+    assert stats["moe.gateless_experts"] >= experts
+    assert stats["moe.scoring_sigmoid"] >= experts
+    # the experts' load and the mixers' two float32 readings ride in the
+    # carry, a row a layer
+    assert {k: (v.shape, v.dtype) for k, v in step._counter_spec.items()} \
+        == {scopes.MOE_EXPERT_LOAD: ((experts, 8), jnp.int32),
+            scopes.MOE_CHUNK_ASSIGNMENTS: ((experts, 2), jnp.int32),
+            scopes.MOE_FULL_BUFFER_CHUNKS: ((experts,), jnp.int32),
+            scopes.MOE_FULLEST_EXPERT_LOAD: ((experts,), jnp.int32),
+            scopes.SSM_STATE_SHARE: ((mixers,), jnp.float32),
+            scopes.SSM_MEAN_DECAY: ((mixers,), jnp.float32)}
+    assert 0.25 * 16 * 2 ** 30 < footprint < 15.75 * 2 ** 30, footprint
+
+
 # ------------------------------------------------------- fused epilogue --
 _LN = ("layer_norm", 1e-5, True, True)
 # (M, K, N, stages): the recipe the Executor realises on BERT-base
