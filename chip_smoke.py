@@ -567,15 +567,17 @@ def check_flash_grouped(errs, shape=(1, 2048, 32, 128), kv_heads=2):
                                              f"flash grouped d{n}")
 
 
-def check_ssd_scan(errs, shape=(1, 8192, 64, 64), groups=8, state=128,
+def check_ssd_scan(errs, shape=(2, 8192, 64, 64), groups=8, state=128,
                    chunk=128):
-    """The chunked state-space scan (ops/ssm.py) at the Nemotron cell's
-    shape, one row: bfloat16 x, B and C, float32 dt and A, against the
-    recurrence run a position at a time in float32, forward and every
-    gradient."""
+    """The state-space scan at the Nemotron cell's shape: bfloat16 x, B
+    and C, float32 dt and A.  The two kernels (ops/pallas/ssd_scan.py)
+    against the chunked form in XLA (ops/ssm.py), and that form's first
+    row against the recurrence run a position at a time in float32;
+    forward and every gradient each time."""
     import jax
     import jax.numpy as jnp
 
+    from paddle_tpu.ops.pallas import ssd_scan as kernels
     from paddle_tpu.ops.ssm import ssd_scan
     B, T, H, P = shape
     x = _rnd(51, shape, jnp.bfloat16, 0.3)
@@ -600,16 +602,20 @@ def check_ssd_scan(errs, shape=(1, 8192, 64, 64), groups=8, state=128,
             x.astype(f32), dt, A, Bm.astype(f32), Cm.astype(f32), D)[0])(
                 x, dt, Bm, Cm)
 
-    args = (x, dt, A, Bm, Cm, D)
+    assert kernels.ssd_scan_supported(shape, Bm.shape, x.dtype, chunk)
     chunked = functools.partial(ssd_scan, chunk=chunk)
-    errs["ssd_scan_fwd"] = _close(jax.jit(chunked)(*args),
-                                  jax.jit(recurrence)(*args), BF16_TOL,
-                                  "ssd scan fwd")
-    got = jax.jit(jax.grad(_sq(chunked), range(6)))(*args)
-    ref = jax.jit(jax.grad(_sq(recurrence), range(6)))(*args)
-    for n, a, b in zip(("x", "dt", "A", "B", "C", "D"), got, ref):
-        errs[f"ssd_scan_d{n}"] = _close(a, b, 4 * BF16_TOL,
-                                        f"ssd scan d{n}")
+    args = (x, dt, A, Bm, Cm, D)
+    row = (x[:1], dt[:1], A, Bm[:1], Cm[:1], D)
+    for tag, got, want, operands in (
+            ("ssd_kernels", kernels.ssd_scan, chunked, args),
+            ("ssd_scan", chunked, recurrence, row)):
+        errs[f"{tag}_fwd"] = _close(
+            jax.jit(got)(*operands), jax.jit(want)(*operands), BF16_TOL,
+            f"{tag} fwd")
+        ours = jax.jit(jax.grad(_sq(got), range(6)))(*operands)
+        theirs = jax.jit(jax.grad(_sq(want), range(6)))(*operands)
+        for n, a, b in zip(("x", "dt", "A", "B", "C", "D"), ours, theirs):
+            errs[f"{tag}_d{n}"] = _close(a, b, 4 * BF16_TOL, f"{tag} d{n}")
 
 
 def check_epilogue(errs, bert):

@@ -2066,10 +2066,23 @@ def ssd_scan(x, dt, A, B, C, D, chunk_size=128, name=None):
     in x's type; ``D`` [H] float32.  -> y [B, T, H, P] in x's type.
     Matrix products over chunks of ``chunk_size`` positions, the decays
     in float32, a hand-written backward that walks the chunks the other
-    way (ops/ssm.py); T need not be a multiple of ``chunk_size``."""
+    way (ops/ssm.py); T need not be a multiple of ``chunk_size``.  On a
+    TPU, for the shapes they take, two Pallas kernels that keep a chunk's
+    matrices in VMEM (ops/pallas/ssd_scan.py), the XLA form elsewhere;
+    counted at trace time as ``pallas.selected.ssd_scan`` /
+    ``ssd_scan.xla_path``."""
+    from ...ops.pallas import ssd_scan as _kernels
+    from ...ops.pallas.support import tier_enabled
     from ...ops.ssm import ssd_scan as _scan
-    return apply(lambda *a: _scan(*a, int(chunk_size)), x, dt, A, B, C, D,
-                 op_name="ssd_scan")
+    from ...utils import monitor
+    chunk = int(chunk_size)
+    if tier_enabled() and _kernels.ssd_scan_supported(
+            tuple(x.shape), tuple(B.shape), as_array(x).dtype, chunk):
+        fn = _kernels.ssd_scan          # counts pallas.selected.* itself
+    else:
+        monitor.stat_add("ssd_scan.xla_path")
+        fn = functools.partial(_scan, chunk=chunk)
+    return apply(fn, x, dt, A, B, C, D, op_name="ssd_scan")
 
 
 @jax.named_scope(scopes.SSM_GATE_NORM)

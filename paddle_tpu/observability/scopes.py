@@ -93,10 +93,13 @@ SPARSE_FWD = "sparse_fwd"
 SPARSE_BWD_DKV = "sparse_bwd_dkv"  # the backward walk: dk, dv and dq; the
                               # name holds all of the backward, as
                               # FLASH_BWD_DKV does
+SSD_FWD = "ssd_fwd"           # the state-space scan, chunk by chunk with
+                              # the state in VMEM (ops/pallas/ssd_scan.py)
+SSD_BWD = "ssd_bwd"           # its backward, the chunks the other way
 KERNELS = (FLASH_FWD, FLASH_BWD_DKV, EPILOGUE_FWD, EPILOGUE_BWD,
            FUSED_ADAM, PAGED_ATTENTION, COLLECTIVE_MATMUL_CHUNK, EVA_FWD,
            EVA_BWD_DQ, DSA_SCORES, DSA_THRESHOLD, DSA_KL, SPARSE_FWD,
-           SPARSE_BWD_DKV)
+           SPARSE_BWD_DKV, SSD_FWD, SSD_BWD)
 
 
 # -- values named for a rematerialisation policy -----------------------------

@@ -25,6 +25,9 @@ The tier:
   indexer's scores, top-k threshold and KL loss (six kernels; the loss
   makes its gradient with its value, in one), fwd + bwd, behind
   ``F.dsa_indexer`` / ``F.sparse_attention`` / ``F.dsa_indexer_loss``;
+- ``ssd_scan`` (module) — Mamba-2's chunked state-space scan, fwd + bwd:
+  a chunk's decay matrices stay in VMEM and the state rides a scratch
+  along the chunk axis, behind ``F.ssd_scan``;
 - ``fused_linear_epilogue``  — matmul + bias/gelu/relu/residual/
   layer_norm epilogues off the cost model's ranked fusion candidates
   (selected by the static Executor's fusion pass);

@@ -83,6 +83,14 @@ def _mosaic_kernels(compiled):
         compiled.as_text())
 
 
+def _kernel_vmem(compiled):
+    """(name, bytes of scoped VMEM Mosaic took) of each kernel call."""
+    return [(name, int(size)) for name, size in re.findall(
+        r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
+        r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+        compiled.as_text())]
+
+
 def _one_backward_kernel(compiled):
     """The forward kernel and the dK/dV walk that makes dQ too."""
     kernels = _mosaic_kernels(compiled)
@@ -547,13 +555,17 @@ def test_nemotron_h_cell_step_fits_the_chip(one_chip, monkeypatch):
     eighth of the vocabulary; two rows of 8192) for the described v5e: it
     compiles, holds ONE flash forward kernel and one backward walk at a
     head shape no other cell runs ([2, 8192, 32, 128] on [2, 8192, 2,
-    128]), XLA's own grouped-matmul kernel, no gate product, and fits."""
+    128]), XLA's own grouped-matmul kernel, no gate product, the scan as
+    its two kernels (a mixer's forward, its replay and its backward) with
+    no [chunk, chunk] decay matrix left in HBM, and fits in less than it
+    did with the scan in XLA."""
     from paddle_tpu.observability import scopes
     from paddle_tpu.utils import monitor
     monitor.stat_reset()
     compiled, n, cfg, mix, footprint, step = _cell_step(
         one_chip, monkeypatch,
-        "nemotron_3_nano_30b_a3b.train_bf16_b2_s8192", ("flash_attention",))
+        "nemotron_3_nano_30b_a3b.train_bf16_b2_s8192",
+        ("flash_attention", "ssd_scan"))
     assert n == cfg["parameters"] == 666_963_456
     assert (cfg["hidden_size"], mix["seq"]) == (2688, 8192)
     pattern = cfg["hybrid_override_pattern"]
@@ -568,6 +580,11 @@ def test_nemotron_h_cell_step_fits_the_chip(one_chip, monkeypatch):
             for name in scopes.RESIDUALS] == [1, 1, 0, 0, 0]
     assert stats["pallas.selected.flash_attention"] >= 1
     assert "attention.xla_path" not in stats
+    assert (_kernel_count(text, scopes.SSD_FWD),
+            _kernel_count(text, scopes.SSD_BWD)) == (2 * mixers, mixers)
+    assert stats["pallas.selected.ssd_scan"] >= mixers
+    assert "ssd_scan.xla_path" not in stats
+    assert "f32[2,64,8,8,128,128]" not in text
     assert (stats["moe.experts_held"], stats["moe.experts_total"],
             stats["moe.top_k"]) == (8, 128, 6)
     assert stats["moe.gateless_experts"] >= experts
@@ -581,7 +598,35 @@ def test_nemotron_h_cell_step_fits_the_chip(one_chip, monkeypatch):
             scopes.MOE_FULLEST_EXPERT_LOAD: ((experts,), jnp.int32),
             scopes.SSM_STATE_SHARE: ((mixers,), jnp.float32),
             scopes.SSM_MEAN_DECAY: ((mixers,), jnp.float32)}
-    assert 0.25 * 16 * 2 ** 30 < footprint < 15.75 * 2 ** 30, footprint
+    # PR 39's tree, the scan in XLA, compiled by this test: 15,272,153,600
+    assert 0.25 * 16 * 2 ** 30 < footprint < 15_272_153_600, footprint
+
+
+def test_ssd_scan_fwd_bwd(one_chip, monkeypatch):
+    """The scan's two kernels alone at the Nemotron cell's shape (two rows
+    of 8192, 64 heads of 64 in 8 groups on a state of 128): both compile
+    for the described v5e, inside Mosaic's default scoped VMEM."""
+    ssd = importlib.import_module("paddle_tpu.ops.pallas.ssd_scan")
+    monkeypatch.setattr(ssd, "_interpret", lambda: False)
+    x_shape, b_shape = (2, 8192, 64, 64), (2, 8192, 8, 128)
+    assert ssd.ssd_scan_supported(x_shape, b_shape, jnp.bfloat16, 128)
+    assert not ssd.ssd_scan_supported(x_shape, b_shape, jnp.bfloat16, 256)
+
+    def loss(*args):
+        return jnp.sum(ssd.ssd_scan(*args).astype(jnp.float32) ** 2)
+
+    args = [_sds(one_chip, shape, dtype) for shape, dtype in (
+        (x_shape, jnp.bfloat16), (x_shape[:3], jnp.float32),
+        ((64,), jnp.float32), (b_shape, jnp.bfloat16),
+        (b_shape, jnp.bfloat16), ((64,), jnp.float32))]
+    compiled = _compile(jax.value_and_grad(loss, range(6)), *args)
+    kernels = _mosaic_kernels(compiled)
+    assert len(kernels) == 2 and "ssd_fwd" in kernels[0], kernels
+    assert "ssd_bwd" in kernels[1], kernels
+    vmem = _kernel_vmem(compiled)
+    print("ssd_scan at [2, 8192, 64, 64] / [2, 8192, 8, 128]: "
+          + ", ".join(f"{name} {size} bytes of VMEM" for name, size in vmem))
+    assert len(vmem) == 2 and all(size < 16 * 2 ** 20 for _, size in vmem)
 
 
 # ------------------------------------------------------- fused epilogue --

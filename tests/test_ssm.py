@@ -16,6 +16,8 @@ import paddle_tpu.nn.functional as F
 from paddle_tpu import nn, observability
 from paddle_tpu.observability import device_counters, scopes
 from paddle_tpu.ops import ssm
+from paddle_tpu.ops.pallas import ssd_scan as kernels
+from paddle_tpu.utils import monitor
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark"))
@@ -106,6 +108,138 @@ def test_bfloat16_operands_keep_float32_decays():
     assert got.dtype == jnp.bfloat16
     err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)))
     assert err < 0.02 * float(jnp.max(jnp.abs(want))), err
+
+
+# ---------------------------------------- the kernels (interpret mode) --
+# (B, T, H, P, G, N) the gate of ops/pallas/ssd_scan.py takes, at chunk 128
+_KERNEL_SHAPES = {
+    "whole_chunks": (2, 256, 16, 64, 2, 128),   # G < H, two heads a slab
+    "padded": (1, 300, 16, 64, 2, 128),         # T no multiple of the chunk
+    "one_group": (1, 200, 8, 64, 1, 128),
+    "wide_heads": (1, 130, 8, 128, 1, 256),     # a head a slab, a state of
+}                                               # two lane tiles
+
+
+def _low(args, ct, dtype):
+    x, dt, A, Bm, Cm, D = args
+    return (x.astype(dtype), dt, A, 0.3 * Bm.astype(dtype),
+            0.3 * Cm.astype(dtype), D), ct.astype(dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", sorted(_KERNEL_SHAPES))
+def test_the_kernels_are_the_xla_form(kernels_on, shape, dtype):
+    """``ssd_fwd`` / ``ssd_bwd`` against the chunked form in ``jax.numpy``
+    on the same operands: the value and all six gradients.  float32: the
+    two differ in the order of their sums (``_close``'s 2e-4 of the
+    largest element).  bfloat16: each rounds ``M``, ``dCB`` and its
+    results to 8 bits once, on sums in another order: 0.02 of the largest
+    element, as ``test_bfloat16_operands_keep_float32_decays`` allows."""
+    size = _KERNEL_SHAPES[shape]
+    assert kernels.ssd_scan_supported(size[:4], (size[0], size[1]) + size[4:],
+                                      dtype, 128)
+    args, ct = _low(*_scan_inputs(*size, seed=11), dtype)
+
+    def both(fn):
+        def loss(*a):
+            y = fn(*a)
+            return jnp.sum((y * ct).astype(jnp.float32)), y
+        (_, y), grads = jax.jit(jax.value_and_grad(
+            loss, range(6), has_aux=True))(*args)
+        return (y,) + grads
+
+    got = both(kernels.ssd_scan)
+    want = both(lambda *a: ssm.ssd_scan(*a, 128))
+    for name, g, w in zip(("y", "dx", "ddt", "dA", "dB", "dC", "dD"),
+                          got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        if dtype == jnp.float32:
+            _close(g, w, name)
+        else:
+            err = float(jnp.max(jnp.abs(g - w)))
+            assert err < 0.02 * float(jnp.max(jnp.abs(w))), (name, err)
+
+
+def test_the_kernels_carry_the_state_across_chunks(kernels_on):
+    """What position 0 put into the state reaches a position two chunks
+    on through ``ssd_fwd``'s scratch, and its gradient comes back through
+    ``ssd_bwd``'s: with every other input's x zeroed, the output there and
+    the gradient to x at position 0 are the recurrence's, and not zero."""
+    (x, dt, A, Bm, Cm, D), _ = _scan_inputs(1, 300, 8, 64, 1, 128, seed=3)
+    x = x.at[:, 1:].set(0.0)
+    dt = 0.02 * dt                     # a state that lasts 300 positions
+
+    def at_the_end(fn):
+        return jax.value_and_grad(
+            lambda x: jnp.sum(fn(x, dt, A, Bm, Cm, D)[0, 299]))(x)
+
+    got, got_g = at_the_end(kernels.ssd_scan)
+    want, want_g = at_the_end(_recurrence)
+    assert abs(float(want)) > 1e-3
+    np.testing.assert_allclose(got, want, rtol=1e-4)
+    assert float(jnp.max(jnp.abs(want_g[0, 0]))) > 1e-3
+    _close(got_g[0, 0], want_g[0, 0], "dx at position 0")
+
+
+def _through_the_functional(size, chunk=128, dtype=jnp.float32):
+    """``F.ssd_scan`` on a draw of ``size`` -> (its result, the moves of
+    ``pallas.selected.ssd_scan`` and of ``ssd_scan.xla_path``)."""
+    args, _ = _scan_inputs(*size, seed=13)
+    args = (args[0].astype(dtype),) + args[1:3] + tuple(
+        a.astype(dtype) for a in args[3:5]) + args[5:]
+
+    def counts():
+        s = monitor.all_stats()
+        return (s.get("pallas.selected.ssd_scan", 0),
+                s.get("ssd_scan.xla_path", 0))
+
+    before = counts()
+    got = F.ssd_scan(*(paddle.to_tensor(a) for a in args), chunk).data
+    return got, args, tuple(a - b for a, b in zip(counts(), before))
+
+
+@pytest.mark.parametrize("size,chunk,dtype", [
+    ((1, 130, 8, 64, 1, 128), 64, jnp.float32),    # a chunk of half a tile
+    ((1, 130, 8, 8, 1, 128), 128, jnp.float32),    # heads of 8 lanes
+    ((1, 130, 8, 64, 1, 16), 128, jnp.float32),    # a state of 16 lanes
+    ((1, 130, 4, 64, 2, 128), 128, jnp.float32),   # two heads a group
+    ((1, 130, 8, 64, 1, 128), 128, jnp.float16),   # no kernel dtype
+], ids=["chunk", "head", "state", "heads_a_group", "dtype"])
+def test_a_shape_outside_the_gate_runs_the_xla_form(kernels_on, size, chunk,
+                                                    dtype):
+    assert not kernels.ssd_scan_supported(
+        size[:4], (size[0], size[1]) + size[4:], dtype, chunk)
+    got, args, moved = _through_the_functional(size, chunk, dtype)
+    assert moved == (0, 1)
+    np.testing.assert_array_equal(got, ssm.ssd_scan(*args, chunk))
+
+
+def test_the_functional_takes_the_kernels_where_the_tier_is_on(kernels_on):
+    size = (1, 130, 8, 64, 1, 128)
+    got, args, moved = _through_the_functional(size)
+    assert moved == (1, 0)
+    _close(got, ssm.ssd_scan(*args, 128), "y")
+
+
+@pytest.mark.parametrize("flags", [
+    {"use_pallas_kernels": False, "pallas_interpret": True},
+    {"use_pallas_kernels": True, "pallas_interpret": False},
+], ids=["tier_off", "no_interpret_opt_in"])
+def test_no_kernel_is_selected_where_the_tier_is_off(flags):
+    """The OFF contract: with ``FLAGS_use_pallas_kernels`` off, or off a
+    TPU without the interpret opt-in, a shape the gate takes runs the XLA
+    form to the bit and no ``pallas.selected.ssd_scan`` moves."""
+    from paddle_tpu.core.flags import get_flag, set_flags
+    old = {name: get_flag(name) for name in flags}
+    set_flags(flags)
+    try:
+        got, args, moved = _through_the_functional((1, 130, 8, 64, 1, 128))
+    finally:
+        set_flags(old)
+    assert moved == (0, 1)
+    np.testing.assert_array_equal(got, ssm.ssd_scan(*args, 128))
 
 
 def test_causal_conv_is_a_depthwise_convolution():
@@ -220,3 +354,35 @@ def test_the_mixer_counts_only_where_somebody_collects():
 def test_the_mixer_refuses_groups_that_do_not_divide_the_heads():
     with pytest.raises(ValueError, match="groups"):
         nn.Mamba2Mixer(32, 6, 8, 4, 16)
+
+
+def test_the_mixer_through_the_kernels_is_the_mixer(kernels_on):
+    """``nn.Mamba2Mixer`` at sizes the gate takes (8 heads of 64 in one
+    group on a state of 128, chunks of 128 over 200 positions): the branch
+    and its gradients to the input and to every parameter with the scan
+    on the kernels, against the same layer on the XLA form."""
+    from paddle_tpu.core.flags import set_flags
+    layer = nn.Mamba2Mixer(32, 8, 64, 1, 128, 4, 128, 1e-5)
+    ks = jax.random.split(jax.random.key(17), 9)
+    leaves = [0.3 * jax.random.normal(k, tuple(p.shape))
+              for k, (_, p) in zip(ks, layer.named_parameters())]
+    a = jax.random.normal(ks[-1], (1, 200, 32))
+
+    def program(a, *leaves):
+        for (_, p), leaf in zip(layer.named_parameters(), leaves):
+            p.data = leaf
+        with paddle.no_grad():          # jax differentiates, as TrainStep
+            return jnp.sum(layer(paddle.to_tensor(a)).data
+                           * jnp.cos(jnp.arange(32.0)))
+
+    grad = jax.value_and_grad(program, range(len(leaves) + 1))
+    monitor.stat_reset()
+    got, got_g = grad(a, *leaves)
+    assert monitor.all_stats()["pallas.selected.ssd_scan"] == 1
+    set_flags({"pallas_interpret": False})
+    want, want_g = grad(a, *leaves)
+    assert monitor.all_stats()["ssd_scan.xla_path"] == 1
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    names = ["a"] + [n for n, _ in layer.named_parameters()]
+    for name, g, w in zip(names, got_g, want_g):
+        _close(g, w, name)

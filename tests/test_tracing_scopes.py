@@ -220,7 +220,7 @@ def _pallas_calls(jaxpr, out):
 @pytest.fixture(scope="module")
 def kernel_calls():
     """(name, name stack) of every ``pallas_call`` equation the
-    fifteen sites trace, in interpret mode: no chip needed."""
+    seventeen sites trace, in interpret mode: no chip needed."""
     from paddle_tpu.ops.pallas.collective_matmul import chunk_matmul
     from paddle_tpu.ops.pallas.fused_adam import fused_adam_update
     from paddle_tpu.ops.pallas.fused_epilogue import fused_linear_epilogue
@@ -261,6 +261,13 @@ def kernel_calls():
                                             lse)
         found += _pallas_calls(jax.make_jaxpr(jax.grad(
             sparse, (0, 1, 2, 3, 4, 5)))(q, kv, kv, qi, ki, wi).jaxpr, [])
+        # the state-space scan: the forward rule's kernel and the backward's
+        ssd = importlib.import_module("paddle_tpu.ops.pallas.ssd_scan")
+        x, bc = jnp.ones((1, 128, 8, 64)), jnp.ones((1, 128, 1, 128))
+        found += _pallas_calls(jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(ssd.ssd_scan(*a)), range(6)))(
+            x, jnp.ones((1, 128, 8)), -jnp.ones((8,)), bc, bc,
+            jnp.ones((8,))).jaxpr, [])
         x, w = jnp.ones((16, 16)), jnp.ones((16, 128))
         found += _pallas_calls(jax.make_jaxpr(jax.grad(
             lambda x, w, b: jnp.sum(fused_linear_epilogue(
@@ -295,7 +302,7 @@ def test_every_pallas_call_site_carries_its_name(kernel_calls, kernel):
 
 
 def test_no_pallas_call_is_left_without_a_name(kernel_calls):
-    assert len(kernel_calls) == 15
+    assert len(kernel_calls) == 17
     assert {name for name, _ in kernel_calls} == set(scopes.KERNELS)
     src = os.path.join(os.path.dirname(paddle.__file__), "ops", "pallas")
     for path in glob.glob(os.path.join(src, "*.py")):
