@@ -113,7 +113,15 @@ DSA_KL_DQ = "dsa_kl_dq"       # to the index queries, [B, J, d, T]
 DSA_KL_DW = "dsa_kl_dw"       # to the head weights, [B, J, T]
 DSA_KL_DK = "dsa_kl_dk"       # to the index key, [B, T, d]
 DSA_KL_GRADS = (DSA_KL_DQ, DSA_KL_DW, DSA_KL_DK)
-RESIDUALS = (ATTN_OUT, ATTN_LSE) + DSA_KL_GRADS
+# what the sparse-attention kernels take besides: kept, a replayed block
+# runs nothing that only they read (the selection; v's projection, the
+# rotation of q and k, the layout copies)
+SPARSE_Q = "sparse_q"         # q as the kernels take it, [B, A, T, D]
+SPARSE_K = "sparse_k"         # k, [B, KV, T, D]
+SPARSE_V = "sparse_v"         # v, [B, KV, T, D]
+SPARSE_MASK = "sparse_mask"   # the selection, [B, keys, queries] int8
+SPARSE_OPERANDS = (SPARSE_Q, SPARSE_K, SPARSE_V, SPARSE_MASK)
+RESIDUALS = (ATTN_OUT, ATTN_LSE) + DSA_KL_GRADS + SPARSE_OPERANDS
 
 
 # -- counters the compiled step keeps on the device ----------------------------

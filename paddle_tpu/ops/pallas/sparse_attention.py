@@ -654,6 +654,12 @@ def _sparse(q, k, v, mask, scale, block):
 
 
 def _sparse_fwd(q, k, v, mask, scale, block):
+    # named before the kernel reads them: one buffer serves the forward
+    # kernel and the backward walk, and a replay that is handed all six
+    # runs nothing upstream of the call for them (the selection least of
+    # all)
+    q, k, v, mask = name_residuals(q, k, v, mask,
+                                   names=scopes.SPARSE_OPERANDS)
     out, lse = name_residuals(*_fwd(q, k, v, mask, scale, block))
     return (out, lse), (q, k, v, mask, out, lse)
 

@@ -24,7 +24,25 @@ checkpoint, and the values ``observability.scopes.RESIDUALS`` names:
 - the three gradients ``dsa_kl`` makes with the indexer's loss, in one pass
   (its target is detached, so they are known with the value): 145 MB a Keye
   layer in float32 against a block input of 268 MB.  Unkept, the replay
-  would run the whole kernel again for them (PERF.md, PR 31).
+  would run the whole kernel again for them (PERF.md, PR 31);
+- what the sparse-attention kernels take (``sparse_fwd`` and its backward
+  walk): the selection's mask, int8 [B, keys, queries], and q, k and v
+  as the kernels read them.  Nothing but the walk reads them in the
+  backward pass, and unkept the replay runs everything upstream of the
+  call to make them again: the indexer's projections, ``dsa_scores``,
+  ``dsa_threshold`` and the mask's pass for the first (64 ms of a Keye
+  step), ``v``'s projection, the rotation of q and k, the q / k norms'
+  scaled outputs and three layout copies for the others (20 ms; q's and
+  k's projections stay, a norm's backward reads its input).  Kept, they
+  are T^2 bytes of mask a layer (268 MB at [4, 8192, 8192]) and q, k and
+  v once more (335 MB at 32 / 4 heads of 128): 2.4 GB over Keye's four
+  layers, which compile to 3.0 GB more footprint and leave that cell
+  0.56 GB under the chip's limit (PERF.md, PR 41).  It is a choice by
+  shape, as the rest of this list is, and any caller of
+  ``F.sparse_attention`` under ``recompute`` makes it: a policy that
+  reads the chip's room (ROADMAP.md, S1) will own it.  The flash and EVA
+  rules do not name their operands (in GPT's cell the three would be
+  3.6 GB against 1.8 GB of room).
 
 A segment with no named value inside is the bare checkpoint.  This is the
 only behaviour: no argument selects it.

@@ -49,26 +49,28 @@ def kernels_on():
     set_flags({"pallas_interpret": old})
 
 
-def _pallas_eqns(jaxpr, within=None):
-    """Every ``pallas_call`` equation of a jaxpr and of the jaxprs in its
-    equations' parameters; with ``within``, only those under an equation
-    of that primitive (``"remat2"``: what ``jax.checkpoint`` replays)."""
+def _pallas_eqns(jaxpr, within=None, primitive="pallas_call"):
+    """Every ``pallas_call`` equation (or every one of ``primitive``) of a
+    jaxpr and of the jaxprs in its equations' parameters; with ``within``,
+    only those under an equation of that primitive (``"remat2"``: what
+    ``jax.checkpoint`` replays)."""
     found = []
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call" and within is None:
+        if eqn.primitive.name == primitive and within is None:
             found.append(eqn)
         inner_within = None if eqn.primitive.name == within else within
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (list, tuple)) else [v]):
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
-                    found += _pallas_eqns(inner, inner_within)
+                    found += _pallas_eqns(inner, inner_within, primitive)
     return found
 
 
 @pytest.fixture
 def pallas_eqns():
-    """``pallas_eqns(jaxpr, within=None)``: see ``_pallas_eqns``."""
+    """``pallas_eqns(jaxpr, within=None, primitive="pallas_call")``: see
+    ``_pallas_eqns``."""
     return _pallas_eqns
 
 

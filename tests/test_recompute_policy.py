@@ -1,9 +1,10 @@
-"""``parallel.recompute`` keeps an attention kernel's ``out`` and ``lse``
-and the three gradients the indexer's loss makes with its value across its
+"""``parallel.recompute`` keeps an attention kernel's ``out`` and ``lse``,
+the three gradients the indexer's loss makes with its value and what the
+sparse-attention kernels take (q, k, v and the selection's mask) across its
 replay (``observability.scopes.RESIDUALS``): the gradient of a recomputed
-block runs its forward attention kernel and ``dsa_kl`` once a layer, not
-twice, and is bit for bit the bare checkpoint's.  CPU, interpret mode,
-tiny shapes."""
+block runs its forward attention kernel, ``dsa_kl`` and the selection once
+a layer, not twice, and is bit for bit the bare checkpoint's.  CPU,
+interpret mode, tiny shapes."""
 import importlib
 import inspect
 import re
@@ -29,7 +30,8 @@ recompute_mod = importlib.import_module("paddle_tpu.parallel.recompute")
 
 HID, HEADS, SEQ, LAYERS = 32, 2, 32, 2
 KEPT = tuple(f"recompute.kept.{name}" for name in scopes.RESIDUALS)
-# what a segment counts: nothing, an attention kernel's two, the indexer's too
+# what a segment counts: nothing, an attention kernel's two, and with the
+# indexer's three and the sparse kernels' four operands every name
 NOTHING, ALL = (0,) * len(KEPT), (LAYERS,) * len(KEPT)
 ATTENTION = (LAYERS, LAYERS) + NOTHING[2:]
 IDX_HEADS, IDX_DIM, TOPK = 2, 8, 12
@@ -94,13 +96,24 @@ class SparseBlock(_AttentionBlock):
         B, S = x.shape[0], x.shape[1]
         h = self.ln(x)
         qkv = self.qkv(h).reshape([B, S, 3, HEADS, HID // HEADS])
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        q, k, v = self.normed(qkv[:, :, 0], qkv[:, :, 1]) + (qkv[:, :, 2],)
         qI = self.idx_q(h).reshape([B, S, IDX_HEADS, IDX_DIM])
         kI, w = self.idx_k(h), self.idx_w(h)
         mask, idx_lse = F.dsa_indexer(qI, kI, w, TOPK)
         a, lse = F.sparse_attention(q, k, v, mask, return_lse=True)
         kl = F.dsa_indexer_loss(qI, kI, w, mask, idx_lse, q, k, lse)
         return x + self.proj(a.reshape([B, S, HID])) + kl
+
+    def normed(self, q, k):
+        return q, k
+
+
+class NormedSparseBlock(SparseBlock):
+    """The same with q and k normed a head before the kernels, as Keye's
+    blocks norm theirs: a norm's backward reads the norm's input."""
+
+    def normed(self, q, k):
+        return F.rms_norm(q), F.rms_norm(k)
 
 
 class PlainBlock(nn.Layer):
@@ -195,8 +208,7 @@ def test_forward_kernels_a_layer(kernels, block, wrap, forwards):
 
 
 @pytest.mark.parametrize("wrap,replayed", [
-    pytest.param(recompute, {scopes.DSA_SCORES, scopes.DSA_THRESHOLD},
-                 id="recompute"),
+    pytest.param(recompute, set(), id="recompute"),
     pytest.param(_bare_checkpoint, {
         scopes.DSA_SCORES, scopes.DSA_THRESHOLD, scopes.SPARSE_FWD,
         scopes.DSA_KL}, id="bare_checkpoint"),
@@ -204,9 +216,9 @@ def test_forward_kernels_a_layer(kernels, block, wrap, forwards):
 def test_the_replayed_segment_holds_no_loss_kernel(kernels, pallas_eqns, wrap,
                                                    replayed):
     """What a sparse block's replay (the gradient program's ``remat2``
-    equations) runs besides the backward kernel: the selection (no
-    gradient, nothing named) and, with nothing kept, the attention's
-    forward and ``dsa_kl``."""
+    equations) runs besides the backward kernel: nothing, where the mask,
+    ``out``, ``lse`` and the loss's gradients are kept; with nothing kept,
+    the selection, the attention's forward and ``dsa_kl``."""
     loss, arrays = _loss_of(SparseBlock, wrap)
     ran = [eqn.params["name"] for eqn in pallas_eqns(
         jax.make_jaxpr(jax.grad(loss))(arrays).jaxpr, within="remat2")]
@@ -214,9 +226,37 @@ def test_the_replayed_segment_holds_no_loss_kernel(kernels, pallas_eqns, wrap,
     assert len(ran) == LAYERS * (len(replayed) + len(SparseBlock.BACKWARD))
 
 
+@pytest.mark.parametrize("block,wrap,projections", [
+    pytest.param(SparseBlock, recompute, 0, id="sparse-recompute"),
+    pytest.param(NormedSparseBlock, recompute, 1, id="normed-recompute"),
+    pytest.param(SparseBlock, _bare_checkpoint, 4,
+                 id="sparse-bare_checkpoint"),
+])
+def test_the_replayed_segment_holds_no_projection(kernels, pallas_eqns, block,
+                                                  wrap, projections):
+    """The q / k / v projection and the indexer's three in a sparse
+    block's replay (the [batch, row, width] results of those widths: a
+    kernel's products and a weight's gradient are 2-D): q, k and v as the
+    kernels take them are kept and the mask is, so nothing reads any of the
+    four a second time; the bare checkpoint replays them all.  Where q and
+    k are normed on their way to the kernels the projection stays, for the
+    norms' backward, and nothing after the norms does."""
+    widths = {3 * HID, IDX_HEADS * IDX_DIM, IDX_DIM, IDX_HEADS}
+    loss, arrays = _loss_of(block, wrap)
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(arrays).jaxpr
+    shapes = [eqn.outvars[0].aval.shape for eqn in pallas_eqns(
+        jaxpr, within="remat2", primitive="dot_general")]
+    assert len([s for s in shapes if len(s) == 3 and s[-1] in widths]) \
+        == projections * LAYERS
+    if wrap is recompute:
+        assert [eqn.params["name"] for eqn in pallas_eqns(
+            jaxpr, within="remat2")] == list(block.BACKWARD) * LAYERS
+
+
 # (3): the kept values are the ones the replay would have recomputed
-@pytest.mark.parametrize("block", [FlashBlock, EvaBlock, SparseBlock],
-                         ids=["flash", "eva", "sparse"])
+@pytest.mark.parametrize("block", [FlashBlock, EvaBlock, SparseBlock,
+                                   NormedSparseBlock],
+                         ids=["flash", "eva", "sparse", "sparse_normed"])
 def test_gradients_are_the_bare_checkpoints_bit_for_bit(
         kernels, monkeypatch, block):
     """Op by op, where no compiler fuses the two programs differently
@@ -273,7 +313,7 @@ def test_a_flash_block_keeps_out_and_lse_beside_its_arguments(kernels):
     assert any(f"named '{scopes.ATTN_LSE}'" in why for _, why in res), res
 
 
-# (4) once more: the loss's three gradients, and not the mask
+# (4) once more: the loss's three gradients and the kernels' four operands
 def test_a_sparse_block_keeps_the_loss_gradients_too(kernels):
     paddle.seed(0)
     blk = SparseBlock()
@@ -288,9 +328,16 @@ def test_a_sparse_block_keeps_the_loss_gradients_too(kernels):
     assert kept == sorted([
         (2, HEADS, SEQ), (2, HEADS, SEQ, HID // HEADS),     # lse, out
         (2, IDX_HEADS, IDX_DIM, SEQ), (2, IDX_HEADS, SEQ),  # dqI^T, dw
-        (2, SEQ, IDX_DIM)]), res                            # dkI
-    for name in scopes.DSA_KL_GRADS:
+        (2, SEQ, IDX_DIM)]                                  # dkI
+        + [(2, HEADS, SEQ, HID // HEADS)] * 3               # q, k, v
+        + [(2, SEQ, SEQ)]), res                             # the mask
+    assert [aval.dtype for aval, why in res if "argument" not in why
+            and aval.shape == (2, SEQ, SEQ)] == [jnp.int8]
+    # q, k and v, like ``out``, are listed by the ``reduce_precision`` jax
+    # pins a float with that the forward pass reads too; an int8 gets none
+    for name in scopes.DSA_KL_GRADS + (scopes.SPARSE_MASK,):
         assert any(f"named '{name}'" in why for _, why in res), (name, res)
+    assert sum("reduce_precision" in why for _, why in res) == 5, res
 
 
 # (6): the counter that says the mechanism engaged
@@ -298,6 +345,10 @@ def test_a_sparse_block_keeps_the_loss_gradients_too(kernels):
     pytest.param(FlashBlock, recompute, ATTENTION, id="flash-recompute"),
     pytest.param(EvaBlock, recompute, ATTENTION, id="eva-recompute"),
     pytest.param(SparseBlock, recompute, ALL, id="sparse-recompute"),
+    pytest.param(NormedSparseBlock, recompute, ALL,
+                 id="sparse_normed-recompute"),
+    pytest.param(SparseBlock, _no_checkpoint, NOTHING,
+                 id="sparse-no_checkpoint"),
     pytest.param(PlainBlock, recompute, NOTHING, id="plain-recompute"),
     pytest.param(FlashBlock, _bare_checkpoint, NOTHING,
                  id="flash-bare_checkpoint"),
@@ -349,14 +400,15 @@ def test_a_block_replayed_T_times_keeps_T_of_each_residual(kernels,
         np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-7)
 
 
-@pytest.mark.parametrize("block", [FlashBlock, SparseBlock],
-                         ids=["flash", "sparse"])
+@pytest.mark.parametrize("block", [FlashBlock, SparseBlock,
+                                   NormedSparseBlock],
+                         ids=["flash", "sparse", "sparse_normed"])
 def test_the_forward_alone_keeps_nothing(kernels, block):
     """Nothing is differentiated, so nothing is kept or counted; the
     loss's kernel is the value-only one (one output)."""
     loss, arrays = _loss_of(block, recompute)
     assert _counted(KEPT, jax.make_jaxpr(loss), arrays) == NOTHING
-    if block is SparseBlock:
+    if issubclass(block, SparseBlock):
         text = str(jax.make_jaxpr(loss)(arrays))
         assert _kernel_calls(text, scopes.DSA_KL) == LAYERS
         assert not any(name in text for name in scopes.DSA_KL_GRADS)
