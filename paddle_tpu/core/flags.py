@@ -80,8 +80,6 @@ define_flag("check_nan_inf", False,
             "Scan every op output for NaN/Inf (reference: flags.cc:44).")
 define_flag("use_pallas_kernels", True,
             "Use Pallas fused kernels (flash attention etc.) when on TPU.")
-define_flag("profile_dir", "",
-            "If set, profiler traces are written here.")
 define_flag("static_verify", False,
             "Run static.analysis verification (def-use, cross-program "
             "leaks, shape/dtype drift, name collisions, dead code) on "

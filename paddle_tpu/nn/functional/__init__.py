@@ -1192,40 +1192,35 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     ``h // (H / Hk)``; the kernel's index maps do it, XLA's path repeats
     them).  The Pallas
     flash-attention kernel (paddle_tpu.ops.pallas) replaces the jnp path
-    when the tier is on (``ops.pallas.support.tier_enabled``), there is
+    when the tier is on (``ops.pallas.support.choose_kernel``), there is
     no ``attn_mask`` and the longer sequence has 512 positions or more,
     the crossover measured on the chip (``flash_attention_supported``;
-    reference analog: bert_encoder_functor.cu fused attention).  Which path
-    a program took is counted at trace time:
-    ``pallas.selected.flash_attention`` / ``attention.xla_path``.
+    reference analog: bert_encoder_functor.cu fused attention).
+    Counted at trace time: ``pallas.selected.flash_attention`` /
+    ``attention.xla_path``.
 
     ``return_weights=True`` forces the unfused path and returns
     ``(out, weights [B, H, Lq, Lk])`` — post-softmax probabilities, with
     dropout applied in training mode (matching the reference, which
     returns the dropped weights: nn/layer/transformer.py:412-431)."""
-    from ...ops.pallas.support import tier_enabled
-    if tier_enabled() and not return_weights:
-        from ...ops.pallas import flash_attention, flash_attention_supported
-        q_shape = tuple(query.shape)
-        k_shape = tuple(key.shape)
-        dtype = (query.data if hasattr(query, "data") else query).dtype
-        eff_dropout = dropout_p if training else 0.0
-        if flash_attention_supported(q_shape, k_shape, dtype, attn_mask,
-                                     eff_dropout,
-                                     v_head_dim=value.shape[-1]):
-            if eff_dropout > 0.0:
-                fdraw = stable_draw()  # in-trace + replay-stable seed
-                return apply(
-                    lambda q, k, v: flash_attention(
-                        q, k, v, causal=is_causal, dropout_p=eff_dropout,
-                        seed=jax.random.bits(fdraw.key(), (1, 1),
-                                             jnp.uint32)
-                        .astype(jnp.int32)),
-                    query, key, value,
-                    op_name="flash_attention")
+    from ...ops.pallas import flash_attention, flash_attention_supported
+    from ...ops.pallas.support import choose_kernel
+    eff_dropout = dropout_p if training else 0.0
+    supported = not return_weights and flash_attention_supported(
+        tuple(query.shape), tuple(key.shape), as_array(query).dtype,
+        attn_mask, eff_dropout, v_head_dim=value.shape[-1])
+    if choose_kernel("attention", supported):
+        if eff_dropout > 0.0:
+            fdraw = stable_draw()  # in-trace + replay-stable seed
             return apply(
-                lambda q, k, v: flash_attention(q, k, v, causal=is_causal),
+                lambda q, k, v: flash_attention(
+                    q, k, v, causal=is_causal, dropout_p=eff_dropout,
+                    seed=jax.random.bits(fdraw.key(), (1, 1), jnp.uint32)
+                    .astype(jnp.int32)),
                 query, key, value, op_name="flash_attention")
+        return apply(
+            lambda q, k, v: flash_attention(q, k, v, causal=is_causal),
+            query, key, value, op_name="flash_attention")
 
     use_dropout = dropout_p > 0.0 and training
 
@@ -1267,8 +1262,6 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
             return out, w_used
         return out
 
-    from ...utils import monitor
-    monitor.stat_add("attention.xla_path")
     args = [query, key, value] + ([attn_mask] if attn_mask is not None else [])
     return apply(_sdpa, *args, op_name="scaled_dot_product_attention")
 
@@ -1821,13 +1814,13 @@ def eva_attention(query, key, value, mu, phi, window_size, chunk_size,
     vectors ``mu`` / ``phi`` [H, D].  A row no longer than one window is
     causal attention.  The Pallas kernels (ops/pallas/eva_attention.py)
     run where the kernel tier is on and the shapes allow, the windowed XLA
-    path elsewhere."""
+    path elsewhere.  Counted at trace time:
+    ``pallas.selected.eva_attention`` / ``eva_attention.xla_path``."""
     from ...ops.pallas import eva_attention as _eva
-    from ...ops.pallas.support import tier_enabled
-    dtype = as_array(query).dtype
-    use_kernels = tier_enabled() and _eva.eva_attention_supported(
-        tuple(query.shape), dtype, window_size, chunk_size)
-    fn = _eva.eva_attention if use_kernels else _eva.eva_attention_xla
+    from ...ops.pallas.support import choose_kernel
+    kernels = choose_kernel("eva_attention", _eva.eva_attention_supported(
+        tuple(query.shape), as_array(query).dtype, window_size, chunk_size))
+    fn = _eva.eva_attention if kernels else _eva.eva_attention_xla
     return apply(
         lambda q, k, v, m, f: fn(q, k, v, m, f, window_size, chunk_size,
                                  scale),
@@ -1890,21 +1883,6 @@ def moe_experts(x, router_weight, w_gate, w_up, w_down, top_k, first_expert=0,
     return apply(fn, x, router_weight, *given.values(), op_name="moe_experts")
 
 
-def _sparse_kernels(query=None, key=None, index_query=None):
-    """Whether the Pallas kernels of ops/pallas/sparse_attention.py take
-    the main attention's shapes and / or the indexer's."""
-    from ...ops.pallas import sparse_attention as _sa
-    from ...ops.pallas.support import tier_enabled
-    ok = tier_enabled()
-    if ok and query is not None:
-        ok = _sa.sparse_attention_supported(
-            tuple(query.shape), tuple(key.shape), as_array(query).dtype)
-    if ok and index_query is not None:
-        ok = _sa.dsa_indexer_supported(tuple(index_query.shape),
-                                       as_array(index_query).dtype)
-    return ok
-
-
 def dsa_indexer(index_query, index_key, index_weight, topk, name=None):
     """The selection of DeepSeek Sparse Attention's indexer over one row
     a batch entry: ``index_query`` [B, T, J, d], ``index_key`` [B, T, d]
@@ -1915,12 +1893,13 @@ def dsa_indexer(index_query, index_key, index_weight, topk, name=None):
     ``topk``).  Returns ``(mask, lse)``: the selection as int8
     [B, keys, queries] for ``sparse_attention``, and the logsumexp of each
     query's selected scores [B, T] for ``dsa_indexer_loss``.  Neither
-    carries a gradient: the indexer learns from its loss."""
+    carries a gradient: the indexer learns from its loss.
+    Counted at trace time: ``pallas.selected.dsa_indexer`` /
+    ``dsa_indexer.xla_path``."""
     from ...ops.pallas import sparse_attention as _sa
-    from ...utils import monitor
-    kernels = _sparse_kernels(index_query=index_query)
-    monitor.stat_add("pallas.selected.dsa_indexer" if kernels
-                     else "dsa_indexer.xla_path")
+    from ...ops.pallas.support import choose_kernel
+    kernels = choose_kernel("dsa_indexer", _sa.dsa_indexer_supported(
+        tuple(index_query.shape), as_array(index_query).dtype))
     fn = _sa.dsa_select if kernels else _sa.dsa_select_xla
     return apply(lambda q, w, k: fn(q, w, k, int(topk)), index_query,
                  index_weight, index_key, op_name="dsa_indexer",
@@ -1935,14 +1914,14 @@ def sparse_attention(query, key, value, mask, return_lse=False, name=None):
     [B, keys, queries] from ``dsa_indexer`` (it holds the causal rule);
     scores are scaled by D^-1/2.  Returns [B, T, A, D], and with ``return_lse`` also the softmax's
     log-sum-exp rows [B, A, T] float32, detached.  Pallas kernels on a
-    TPU for the shapes they support, a blocked XLA path elsewhere."""
+    TPU for the shapes they support, a blocked XLA path elsewhere.
+    Counted at trace time: ``pallas.selected.sparse_attention`` /
+    ``sparse_attention.xla_path``."""
     from ...ops.pallas import sparse_attention as _sa
-    from ...utils import monitor
-    if _sparse_kernels(query, key):
-        fn = _sa.sparse_attention       # counts pallas.selected.* itself
-    else:
-        monitor.stat_add("sparse_attention.xla_path")
-        fn = _sa.sparse_attention_xla
+    from ...ops.pallas.support import choose_kernel
+    kernels = choose_kernel("sparse_attention", _sa.sparse_attention_supported(
+        tuple(query.shape), tuple(key.shape), as_array(query).dtype))
+    fn = _sa.sparse_attention if kernels else _sa.sparse_attention_xla
     out, lse = apply(fn, query, key, value, mask, op_name="sparse_attention")
     return (out, lse) if return_lse else out
 
@@ -1956,9 +1935,17 @@ def dsa_indexer_loss(index_query, index_key, index_weight, mask, index_lse,
     summed over the A heads, divided by A and detached, to the softmax of
     the index scores over the same keys.  ``mask`` and ``index_lse`` are
     ``dsa_indexer``'s, ``lse`` is ``sparse_attention``'s.  Gradients
-    reach ``index_query``, ``index_key`` and ``index_weight`` only."""
+    reach ``index_query``, ``index_key`` and ``index_weight`` only.
+    Counted at trace time: ``pallas.selected.dsa_kl`` /
+    ``dsa_indexer_loss.xla_path``."""
     from ...ops.pallas import sparse_attention as _sa
-    kernels = _sparse_kernels(query, key, index_query)
+    from ...ops.pallas.support import choose_kernel
+    kernels = choose_kernel(
+        "dsa_indexer_loss",
+        _sa.sparse_attention_supported(
+            tuple(query.shape), tuple(key.shape), as_array(query).dtype)
+        and _sa.dsa_indexer_supported(
+            tuple(index_query.shape), as_array(index_query).dtype))
     fn = _sa.dsa_kl if kernels else _sa.dsa_kl_xla
     return apply(
         lambda qi, ki, wi, m, li, q, k, l: fn(qi, wi, ki, m, li, q, k, l),
@@ -1967,7 +1954,7 @@ def dsa_indexer_loss(index_query, index_key, index_weight, mask, index_lse,
 
 
 # ---------------------------------------------------------------------------
-# latent attention (appended, as above: what precedes keeps its lines)
+# latent attention
 # ---------------------------------------------------------------------------
 
 @jax.named_scope(scopes.MLA_ATTENTION)
@@ -1988,15 +1975,12 @@ def mla_attention(q_nope, q_rope, k_nope, k_rope, value, name=None):
     ``pallas.selected.mla_attention`` / ``mla_attention.xla_path``."""
     from ...ops.pallas import flash_attention_supported
     from ...ops.pallas.flash_attention import flash_attention_shared_key
-    from ...ops.pallas.support import count_kernel_selection, tier_enabled
-    from ...utils import monitor
+    from ...ops.pallas.support import choose_kernel
     B, S, A, Dn = tuple(q_nope.shape)
     Dr, Dv = q_rope.shape[-1], value.shape[-1]
-    dtype = as_array(q_nope).dtype
-    if tier_enabled() and flash_attention_supported(
-            (B, S, A, Dn), (B, S, A, Dn), dtype, v_head_dim=Dv,
-            shared_key_dim=Dr):
-        count_kernel_selection("mla_attention")
+    if choose_kernel("mla_attention", flash_attention_supported(
+            (B, S, A, Dn), (B, S, A, Dn), as_array(q_nope).dtype,
+            v_head_dim=Dv, shared_key_dim=Dr)):
         return apply(flash_attention_shared_key, q_nope, q_rope, k_nope,
                      k_rope, value, op_name="mla_attention")
 
@@ -2012,13 +1996,12 @@ def mla_attention(q_nope, q_rope, k_nope, k_rope, value, name=None):
         w = jax.nn.softmax(s, axis=-1).astype(v.dtype)
         return jnp.einsum("bhls,bshd->blhd", w, v)
 
-    monitor.stat_add("mla_attention.xla_path")
     return apply(_xla, q_nope, q_rope, k_nope, k_rope, value,
                  op_name="mla_attention")
 
 
 # ---------------------------------------------------------------------------
-# a looped model's exit (appended, as above)
+# a looped model's exit
 # ---------------------------------------------------------------------------
 
 def _exit_log_probs(z):
@@ -2068,19 +2051,17 @@ def ssd_scan(x, dt, A, B, C, D, chunk_size=128, name=None):
     in float32, a hand-written backward that walks the chunks the other
     way (ops/ssm.py); T need not be a multiple of ``chunk_size``.  On a
     TPU, for the shapes they take, two Pallas kernels that keep a chunk's
-    matrices in VMEM (ops/pallas/ssd_scan.py), the XLA form elsewhere;
-    counted at trace time as ``pallas.selected.ssd_scan`` /
+    matrices in VMEM (ops/pallas/ssd_scan.py), the XLA form elsewhere.
+    Counted at trace time: ``pallas.selected.ssd_scan`` /
     ``ssd_scan.xla_path``."""
     from ...ops.pallas import ssd_scan as _kernels
-    from ...ops.pallas.support import tier_enabled
+    from ...ops.pallas.support import choose_kernel
     from ...ops.ssm import ssd_scan as _scan
-    from ...utils import monitor
     chunk = int(chunk_size)
-    if tier_enabled() and _kernels.ssd_scan_supported(
-            tuple(x.shape), tuple(B.shape), as_array(x).dtype, chunk):
-        fn = _kernels.ssd_scan          # counts pallas.selected.* itself
+    if choose_kernel("ssd_scan", _kernels.ssd_scan_supported(
+            tuple(x.shape), tuple(B.shape), as_array(x).dtype, chunk)):
+        fn = _kernels.ssd_scan
     else:
-        monitor.stat_add("ssd_scan.xla_path")
         fn = functools.partial(_scan, chunk=chunk)
     return apply(fn, x, dt, A, B, C, D, op_name="ssd_scan")
 

@@ -22,7 +22,7 @@ The tier:
   ``F.eva_attention``;
 - ``sparse_attention`` (module) — grouped-query attention over a learned
   per-query selection of keys (a mask shared by the heads), the
-  indexer's scores, top-k threshold and KL loss (six kernels; the loss
+  indexer's scores, top-k threshold and KL loss (five kernels; the loss
   makes its gradient with its value, in one), fwd + bwd, behind
   ``F.dsa_indexer`` / ``F.sparse_attention`` / ``F.dsa_indexer_loss``;
 - ``ssd_scan`` (module) — Mamba-2's chunked state-space scan, fwd + bwd:
@@ -36,7 +36,12 @@ The tier:
 - ``paged_attention_decode`` — gather-free paged decode attention
   behind ``ops.attention.register_paged_attention_kernel``.
 
-Shared backend/gate/counter plumbing lives in ``support.py``.
+The three attention modules share their tile mathematics (the
+online-softmax step, a pair's p and dS, the dQ walk's accumulators, the
+8-sublane rows of lse and delta) through ``attention_tiles.py``, which
+launches nothing.  Shared backend/gate/counter plumbing lives in
+``support.py``, and so does the one choice between a kernel and its XLA
+form (``choose_kernel``) that every kernel-backed functional makes.
 """
 from .flash_attention import (flash_attention, flash_attention_supported,
                               mha_reference)

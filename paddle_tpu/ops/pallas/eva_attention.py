@@ -15,8 +15,8 @@ the chunk's keys).  A query then attends, under ONE softmax, to
 A row no longer than one window is plain causal attention.  The plain
 form would hold [H, S, S] scores; this one holds a [block, block] tile.
 
-The kernels are the flash kernels (flash_attention.py, whose tile
-orientation, block size and helpers they share) with a second loop: a
+The kernels are the flash kernels (their tile orientation, block size
+and tile mathematics: attention_tiles.py) with a second loop: a
 query block of window w walks its window's causal key blocks, then the
 w summary blocks before it (W / c rows each), carrying one running
 max / sum.  ``eva_bwd_dq`` makes the same walk and, since it holds each
@@ -39,11 +39,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ...observability import scopes
-from .flash_attention import (_BLOCK, _block_loops, _bwd_dkv, _kv_bounds,
-                              _p_ds, _prescale, _rows, _scores, _zero_off,
-                              _zero_seed)
-from .support import (NEG_INF, dot as _dot, interpret_mode as _interpret,
-                      name_residuals, pltpu)
+from .attention_tiles import (BLOCK, block_loops, delta as _delta, kv_spans,
+                              online_step, p_ds, prescale, rows, rows8,
+                              write_row8)
+from .flash_attention import bwd_dkv, scores, zero_off, zero_seed
+from .support import (NEG_INF, count_kernel_selection, dot as _dot,
+                      interpret_mode as _interpret, name_residuals, pltpu)
 
 
 # ---------------------------------------------------------------------------
@@ -74,7 +75,7 @@ def eva_attention_supported(q_shape, dtype, window_size, chunk_size) -> bool:
         window, windows = eva_windows(S, window_size, chunk_size)
     except ValueError:
         return False
-    if window % min(_BLOCK, window):
+    if window % min(BLOCK, window):
         return False
     if _interpret():
         return True
@@ -161,52 +162,33 @@ def eva_attention_xla(q, k, v, mu, phi, window_size, chunk_size, scale=None):
 # kernels ([B, H, S, D]; score tiles [keys, block] as the flash kernels')
 # ---------------------------------------------------------------------------
 
-def _online_step(carry, s, v):
-    """One online-softmax step over a [keys, block] score tile."""
-    m, l, acc = carry
-    m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m - m_new)
-    l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
-    acc = acc * alpha + _dot(v, p.astype(v.dtype), ((0,), (0,)))
-    return m_new, l, acc
-
-
-def _local_spans(qi, block, blocks_per_window):
-    """A query block's walk over its window: key blocks wholly below the
-    diagonal unmasked, its own under the diagonal mask."""
-    full, end = _kv_bounds(qi, block, block, blocks_per_window)
-    return (0, full, None), (full, end, "diagonal")
-
-
 def _fwd_kernel(q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, lse_ref, *,
                 scale, block, blocks_per_window, summary_rows):
     i = pl.program_id(2)
     w, qi = i // blocks_per_window, i % blocks_per_window
-    q = _prescale(q_ref[0, 0], scale)                     # [BQ, D]
+    q = prescale(q_ref[0, 0], scale)                      # [BQ, D]
     bq, d = q.shape
     carry = (jnp.full((1, bq), NEG_INF, jnp.float32),
              jnp.zeros((1, bq), jnp.float32),
              jnp.zeros((d, bq), jnp.float32))             # m, l, out^T
 
     def local(j, carry, mask):
-        s = _scores(_rows(k_ref, j, block), q, mask, qi, j, None, None,
-                    block, block)
-        return _online_step(carry, s, _rows(v_ref, j, block))
+        s = scores(rows(k_ref, j, block), q, mask, qi, j, None, None,
+                   block, block)
+        return online_step(carry, s, rows(v_ref, j, block))
 
     # the exact keys first: key 0 of the window is visible to every query
     # of it, so m is finite before any masked score
-    carry = _block_loops(local, carry, blocks_per_window, True, True,
-                         _local_spans(qi, block, blocks_per_window))
+    carry = block_loops(local, carry, blocks_per_window, True, True,
+                        kv_spans(qi, block, block, blocks_per_window))
 
     def summary(u, carry):
-        s = _dot(_rows(ks_ref, u, summary_rows), q, ((1,), (1,)))
-        return _online_step(carry, s, _rows(vs_ref, u, summary_rows))
+        s = _dot(rows(ks_ref, u, summary_rows), q, ((1,), (1,)))
+        return online_step(carry, s, rows(vs_ref, u, summary_rows))
 
     m, l, acc = jax.lax.fori_loop(0, w, summary, carry)
     o_ref[0, 0] = (acc / l).T.astype(o_ref.dtype)
-    # (8, bq) lse block: see flash_attention._fwd_kernel
-    lse_ref[0, 0] = jnp.broadcast_to(m + jnp.log(l), (8, bq))
+    write_row8(lse_ref, m + jnp.log(l))
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, ks_ref, vs_ref, do_ref, lse_ref,
@@ -220,33 +202,31 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, ks_ref, vs_ref, do_ref, lse_ref,
         dks_acc[...] = jnp.zeros_like(dks_acc)
         dvs_acc[...] = jnp.zeros_like(dvs_acc)
 
-    q = _prescale(q_ref[0, 0], scale)                     # [BQ, D]
+    q = prescale(q_ref[0, 0], scale)                      # [BQ, D]
     do = do_ref[0, 0]
     lse = lse_ref[0, 0][0:1, :]                           # [1, BQ]
     delta = delta_ref[0, 0][0:1, :]
     bq, d = q.shape
 
     def local(j, dq, mask):
-        k = _rows(k_ref, j, block)
-        s = _scores(k, q, mask, qi, j, None, None, block, block)
-        _, ds = _p_ds(s, mask, lse, do, _rows(v_ref, j, block), delta,
-                      None, qi, j, 0.0)
+        k = rows(k_ref, j, block)
+        s = scores(k, q, mask, qi, j, None, None, block, block)
+        _, ds = p_ds(s, lse, do, rows(v_ref, j, block), delta)
         return dq + _dot(k, ds.astype(k.dtype), ((0,), (0,)))
 
-    dq = _block_loops(local, jnp.zeros((d, bq), jnp.float32),
-                      blocks_per_window, True, True,
-                      _local_spans(qi, block, blocks_per_window))
+    dq = block_loops(local, jnp.zeros((d, bq), jnp.float32),
+                     blocks_per_window, True, True,
+                     kv_spans(qi, block, block, blocks_per_window))
 
     def summary(u, dq):
-        ks = _rows(ks_ref, u, summary_rows)
+        ks = rows(ks_ref, u, summary_rows)
         s = _dot(ks, q, ((1,), (1,)))
-        p, ds = _p_ds(s, None, lse, do, _rows(vs_ref, u, summary_rows),
-                      delta, None, qi, u, 0.0)
-        rows = pl.ds(pl.multiple_of(u * summary_rows, summary_rows),
-                     summary_rows)
-        dvs_acc[rows, :] += _dot(p.astype(do.dtype), do, ((1,), (0,)))
+        p, ds = p_ds(s, lse, do, rows(vs_ref, u, summary_rows), delta)
+        at = pl.ds(pl.multiple_of(u * summary_rows, summary_rows),
+                   summary_rows)
+        dvs_acc[at, :] += _dot(p.astype(do.dtype), do, ((1,), (0,)))
         # against the pre-scaled q: dk~ needs no scale of its own
-        dks_acc[rows, :] += _dot(ds.astype(q.dtype), q, ((1,), (0,)))
+        dks_acc[at, :] += _dot(ds.astype(q.dtype), q, ((1,), (0,)))
         return dq + _dot(ks, ds.astype(ks.dtype), ((0,), (0,)))
 
     dq = jax.lax.fori_loop(0, w, summary, dq)
@@ -263,7 +243,7 @@ def _plan(q, ks, window, block):
     block specs of a query block, of its window's keys or values staged
     whole, of every summary staged whole, and of an (8, block) lse row."""
     B, H, S, D = q.shape
-    block = min(block or _BLOCK, window)
+    block = min(block or BLOCK, window)
     per_window, windows = window // block, S // window
     _count_blocks(per_window, windows)
     # one summary block per earlier window; a single window reads none
@@ -301,9 +281,7 @@ def _bwd(q, k, v, ks, vs, out, lse, do, scale, window, block):
     statics, grid, q_block, kv_window, whole, lse_row = _plan(
         q, ks, window, block)
     block, windows = statics["block"], S // window
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), -1)
-    lse8 = jnp.broadcast_to(lse[:, :, None, :], (B, H, 8, S))
-    delta8 = jnp.broadcast_to(delta[:, :, None, :], (B, H, 8, S))
+    delta = _delta(do, out)
     dq, dks, dvs = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, **statics),
         grid=grid,
@@ -320,20 +298,17 @@ def _bwd(q, k, v, ks, vs, out, lse, do, scale, window, block):
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
         name=scopes.EVA_BWD_DQ,
-    )(q, k, v, ks, vs, do, lse8, delta8)
+    )(q, k, v, ks, vs, do, rows8(lse), rows8(delta))
 
     # the exact keys' dk, dv: p = exp(s - lse) under the joint lse is what
     # the flash dk/dv kernel computes, window by window
     def fold(a):
         return a.reshape(B, H * windows, window, *a.shape[3:])
 
-    def fold8(a):
-        return jnp.broadcast_to(fold(a)[:, :, None, :],
-                                (B, H * windows, 8, window))
-
-    dk, dv = _bwd_dkv(fold(q), fold(k), fold(v), _zero_off(), _zero_off(),
-                      _zero_seed(), fold(do), fold8(lse), fold8(delta),
-                      scale, True, (block, block), True, 0.0)
+    dk, dv = bwd_dkv(fold(q), fold(k), fold(v), zero_off(), zero_off(),
+                     zero_seed(), fold(do), rows8(fold(lse)),
+                     rows8(fold(delta)), scale, True, (block, block), True,
+                     0.0)
     return dq, dk.reshape(k.shape), dv.reshape(v.shape), dks, dvs
 
 
@@ -363,6 +338,7 @@ def eva_attention(q, k, v, mu, phi, window_size, chunk_size, scale=None,
     B, S, H, D = q.shape
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(D)
     window, windows = eva_windows(S, window_size, chunk_size)
+    count_kernel_selection("eva_attention")
     qt, kt, vt = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))
     if windows > 1:
         ks, vs = eva_pool(kt, vt, mu, phi, chunk_size, scale, seq_axis=2)

@@ -24,8 +24,8 @@ heads of 192 with values of 128 (MiMo-V2-Flash), latent heads of 192 +
 64 with values of 256 (GLM-5), 128 + 64 with 192 (GigaChat3.1), 64 + 64
 with 128 (Mistral-Small-4).  Forward saves per-row logsumexp for the
 recompute-based backward (standard FlashAttention-2 dataflow).  Inside,
-every score tile is held transposed ([block_k, block_q], see the note
-above the kernels).
+every score tile is held transposed ([block_k, block_q]): the note and
+the tile mathematics the family shares are in attention_tiles.py.
 
 The backward is one kernel (PR 33): the dK/dV walk holds each pair's
 ``ds`` and its k block, so it makes dQ too, into a float32 VMEM
@@ -62,6 +62,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ...observability import scopes
+from .attention_tiles import (BLOCK, block_loops, delta as _delta, dq_add,
+                              dq_emit, dq_zero, kv_spans, mask_diagonal,
+                              online_step, p_ds, prescale, q_spans, rows,
+                              rows8, write_row8)
 from .support import (NEG_INF, count_kernel_selection, dot as _dot,
                       interpret_mode as _interpret, name_residuals, pltpu,
                       smem_scalar_spec as _smem_scalar_spec)
@@ -204,21 +208,12 @@ def _lanes(width):
 # blocks from the shape
 # ---------------------------------------------------------------------------
 
-# Measured on one v5e (PERF.md, PR 25): 512 x 512 is the fastest of
-# {256, 512, 1024}^2 for each of the kernels at [8, 16, 2048, 96]
-# bf16 causal and at [64, 12, 512, 64] bf16: smaller tiles reload the
-# MXU's weights for fewer rows (256 x 256 takes 1.9x as long), larger
-# ones spill more.  The largest shapes flash_attention_supported admits
-# compile within Mosaic's default scoped VMEM at this size.
-_BLOCK = 512
-
-
 def _resolve_blocks(block_q, block_k, Lq, Lk):
     """(block_q, block_k) of both kernels, from the static shapes:
-    ``_BLOCK`` where left at None (explicit ones are for tests and the
+    ``BLOCK`` where left at None (explicit ones are for tests and the
     ring path), the whole of a shorter sequence.  One pair for both,
     because the dropout tile seeds are block indices."""
-    return min(block_q or _BLOCK, Lq), min(block_k or _BLOCK, Lk)
+    return min(block_q or BLOCK, Lq), min(block_k or BLOCK, Lk)
 
 
 def _count_blocks(Lq, Lk, block_q, block_k, causal, aligned):
@@ -233,22 +228,13 @@ def _count_blocks(Lq, Lk, block_q, block_k, causal, aligned):
     elif not aligned:
         full, masked = 0, num_q * num_kv
     else:
-        bounds = [_kv_bounds(qi, block_q, block_k, num_kv, minimum=min)
-                  for qi in range(num_q)]
-        full = sum(f for f, _ in bounds)
-        masked = sum(e - f for f, e in bounds)
+        spans = [span for qi in range(num_q) for span in kv_spans(
+            qi, block_q, block_k, num_kv, minimum=min)]
+        full = sum(hi - lo for lo, hi, mask in spans if mask is None)
+        masked = sum(hi - lo for lo, hi, mask in spans if mask)
     monitor.stat_add("pallas.flash.blocks_full", full)
     monitor.stat_add("pallas.flash.blocks_masked", masked)
 
-
-# Every kernel holds its score tile as [block_k, block_q]: k positions on
-# the sublanes, q positions on the lanes.  The per-query softmax state
-# (m, l, lse, delta) is then a [1, block_q] row of a few vregs instead of
-# a [block_q, 1] column of block_q / 8, its reductions run down the
-# sublanes on the VALU, and the (8, block_q) lse blocks need no relayout.
-# Operands and results keep the natural [L, D] layout: the MXU takes the
-# transposed operand itself and the [D, block_q] accumulators are
-# transposed once per grid step.
 
 def _mask_scores(s, q_off_ref, k_off_ref, qi, j, block_q, block_k):
     """Position mask of the ring path: visibility depends on the traced
@@ -263,50 +249,6 @@ def _mask_scores(s, q_off_ref, k_off_ref, qi, j, block_q, block_k):
              + jax.lax.broadcasted_iota(jnp.int32, s.shape,
                                         1).astype(jnp.float32))
     return jnp.where(q_pos >= k_pos, s, NEG_INF)
-
-
-def _mask_diagonal(s, qi, j, block_q, block_k):
-    """Causal mask of a block that straddles the diagonal (aligned path:
-    both sequences start at position 0): query c of q block ``qi`` sees
-    key r of k block ``j`` iff ``c - r >= j*block_k - qi*block_q``."""
-    rel = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-           - jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
-    return jnp.where(rel >= j * block_k - qi * block_q, s, NEG_INF)
-
-
-def _kv_bounds(qi, block_q, block_k, num_kv, minimum=jnp.minimum):
-    """Aligned causal schedule of q block ``qi``: k blocks [0, full) lie
-    wholly below the diagonal, [full, end) straddle it, the rest are
-    invisible.  ``minimum=min`` gives Python ints for the counters."""
-    full = minimum((qi * block_q + 1) // block_k, num_kv)
-    end = minimum(pl.cdiv((qi + 1) * block_q, block_k), num_kv)
-    return full, end
-
-
-def _q_bounds(kj, block_q, block_k, num_q, minimum=jnp.minimum):
-    """The same schedule seen from k block ``kj``: q blocks
-    [start, full) straddle the diagonal, [full, num_q) lie wholly below."""
-    start = minimum((kj * block_k) // block_q, num_q)
-    full = minimum(pl.cdiv((kj + 1) * block_k - 1, block_q), num_q)
-    return start, full
-
-
-def _block_loops(body, carry, num_blocks, causal, aligned, causal_spans):
-    """Run ``body(i, carry, mask)`` over one grid step's blocks with the
-    mask each needs: none without ``causal``, the position mask on every
-    block of the ring path, and in the aligned causal path the
-    ``(lo, hi, mask)`` runs of ``causal_spans``: the diagonal mask only
-    where a block straddles the diagonal, invisible blocks skipped."""
-    if not causal:
-        spans = ((0, num_blocks, None),)
-    elif not aligned:
-        spans = ((0, num_blocks, "positions"),)
-    else:
-        spans = causal_spans
-    for lo, hi, mask in spans:
-        carry = jax.lax.fori_loop(
-            lo, hi, functools.partial(body, mask=mask), carry)
-    return carry
 
 
 def _dropout_keep(seed_ref, qi, j, shape, dropout_p):
@@ -329,29 +271,25 @@ def _dropout_keep(seed_ref, qi, j, shape, dropout_p):
     return v >= t
 
 
-def _apply_dropout(p, seed_ref, qi, j, dropout_p):
-    """p (unnormalized probs) -> p * keep / (1 - p_q).  The softmax
-    denominator keeps the UNdropped sum, which reproduces dropout applied
-    to the normalized weights (out = sum(drop(w) v), w = p / l)."""
+def _dropout(seed_ref, qi, j, dropout_p):
+    """Tile (``qi``, ``j``)'s hook for `online_step` and `p_ds`, None with
+    dropout off: p (unnormalized probs) -> p * keep / (1 - p_q).  The
+    softmax denominator keeps the UNdropped sum, which reproduces dropout
+    applied to the normalized weights (out = sum(drop(w) v), w = p / l)."""
     if dropout_p <= 0.0:
-        return p
+        return None
     t = int(round(dropout_p * 65536.0))
     if t >= 65536:  # p ~ 1.0: everything drops
-        return jnp.zeros_like(p)
-    keep = _dropout_keep(seed_ref, qi, j, p.shape, dropout_p)
-    inv_keep = 65536.0 / (65536 - t)
-    return jnp.where(keep, p * inv_keep, 0.0)
+        return jnp.zeros_like
+
+    def drop(p):
+        keep = _dropout_keep(seed_ref, qi, j, p.shape, dropout_p)
+        return jnp.where(keep, p * (65536.0 / (65536 - t)), 0.0)
+    return drop
 
 
-def _prescale(x, scale):
-    """``x * scale`` rounded back to x's dtype: every kernel scores with
-    the same pre-scaled q, so the backward's recomputed p is the
-    forward's."""
-    return (x.astype(jnp.float32) * scale).astype(x.dtype)
-
-
-def _scores(k, q, mask, qi, j, q_off_ref, k_off_ref, block_q, block_k,
-            shared=None):
+def scores(k, q, mask, qi, j, q_off_ref, k_off_ref, block_q, block_k,
+           shared=None):
     """[BK, BQ] f32 scores of (pre-scaled) q block ``qi`` against k block
     ``j`` under the block's mask.  ``shared``: the block's rows of the
     key part all heads share and the (pre-scaled) query part that meets
@@ -360,16 +298,11 @@ def _scores(k, q, mask, qi, j, q_off_ref, k_off_ref, block_q, block_k,
     if shared is not None:
         s = s + _dot(shared[0], shared[1], ((1,), (1,)))
     if mask == "diagonal":
-        return _mask_diagonal(s, qi, j, block_q, block_k)
+        return mask_diagonal(s, qi, j, block_q, block_k)
     if mask == "positions":
         return _mask_scores(s, q_off_ref, k_off_ref, qi, j, block_q,
                             block_k)
     return s
-
-
-def _rows(ref, j, block):
-    """Rows [j*block, (j+1)*block) of a sequence staged whole."""
-    return ref[0, 0, pl.ds(pl.multiple_of(j * block, block), block), :]
 
 
 def _once_a_shape(*static_argnums):
@@ -394,11 +327,11 @@ def _fwd_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref, *rest,
                 shared):
     if shared:
         qr_ref, kr_ref, o_ref, lse_ref = rest
-        qr = _prescale(qr_ref[0, 0], scale)               # [BQ, Dr]
+        qr = prescale(qr_ref[0, 0], scale)                # [BQ, Dr]
     else:
         o_ref, lse_ref = rest
     qi = pl.program_id(2)
-    q = _prescale(q_ref[0, 0], scale)                     # [BQ, D]
+    q = prescale(q_ref[0, 0], scale)                      # [BQ, D]
     bq = q.shape[0]
     m = jnp.full((1, bq), NEG_INF, jnp.float32)
     l = jnp.zeros((1, bq), jnp.float32)
@@ -406,41 +339,19 @@ def _fwd_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref, *rest,
     num_kv = seq_k // block_k
 
     def body(j, carry, mask):
-        m, l, acc = carry
-        k = _rows(k_ref, j, block_k)                      # [BK, D]
-        v = _rows(v_ref, j, block_k)
-        s = _scores(k, q, mask, qi, j, q_off_ref, k_off_ref, block_q,
-                    block_k, (_rows(kr_ref, j, block_k), qr)
-                    if shared else None)                  # [BK, BQ]
-        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
-        p = jnp.exp(s - m_new)
-        if mask == "positions":
-            # a query the offsets hide entirely has s - m_new == 0: zero
-            # it instead of attending uniformly.  The aligned path needs
-            # no guard: key 0 is visible to every query and is in the
-            # first block, so m is finite before any masked score
-            p = jnp.where(s > 0.5 * NEG_INF, p, 0.0)
-        alpha = jnp.exp(m - m_new)
-        # denominator uses the UNdropped sum; only the value aggregation
-        # sees the dropout mask (== dropout on normalized weights)
-        l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
-        u = _apply_dropout(p, seed_ref, qi, j, dropout_p)
-        acc = acc * alpha + _dot(v, u.astype(v.dtype), ((0,), (0,)))
-        return m_new, l, acc
+        k = rows(k_ref, j, block_k)                       # [BK, D]
+        v = rows(v_ref, j, block_k)
+        s = scores(k, q, mask, qi, j, q_off_ref, k_off_ref, block_q,
+                   block_k, (rows(kr_ref, j, block_k), qr)
+                   if shared else None)                   # [BK, BQ]
+        return online_step(carry, s, v, may_hide_query=mask == "positions",
+                           drop=_dropout(seed_ref, qi, j, dropout_p))
 
-    full, end = _kv_bounds(qi, block_q, block_k, num_kv)
-    m, l, acc = _block_loops(
-        body, (m, l, acc), num_kv, causal, aligned,
-        ((0, full, None), (full, end, "diagonal")))
+    m, l, acc = block_loops(body, (m, l, acc), num_kv, causal, aligned,
+                            kv_spans(qi, block_q, block_k, num_kv))
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0, 0] = (acc / l_safe).T.astype(o_ref.dtype)
-    # lse block is (8, bq): positions on the LANE dim, replicated over 8
-    # sublanes — the minimal Mosaic-legal tile.  A trailing unit dim
-    # ([..., Lq, 1]) would make XLA tile-pad the HBM buffer 1 -> 128
-    # lanes (128x memory — measured ~200 MB/layer residual at BERT-base
-    # scale)
-    lse = jnp.where(l > 0, m + jnp.log(l_safe), NEG_INF)
-    lse_ref[0, 0] = jnp.broadcast_to(lse, (8, bq))
+    write_row8(lse_ref, jnp.where(l > 0, m + jnp.log(l_safe), NEG_INF))
 
 
 def _kv_head(h, group):
@@ -512,29 +423,12 @@ def _fwd_call(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
         compiler_params=_staging(Lk, D + Dr, Dv, q.dtype),
         name=scopes.FLASH_FWD,
     )(q_off, k_off, seed, q, k, v, *(shared or ()))
-    # compact [B, H, Lq] is the residual / public lse shape; the 8-sublane
-    # replication exists only at the kernel boundary
-    return out, lse[:, :, 0, :]
+    return out, lse[:, :, 0, :]       # the compact [B, H, Lq]
 
 
 # ---------------------------------------------------------------------------
 # backward (recompute-based, FlashAttention-2 style)
 # ---------------------------------------------------------------------------
-
-def _p_ds(s, mask, lse, do, v, delta, seed_ref, qi, j, dropout_p):
-    """(u, dS / scale) of one [BK, BQ] block from its scores.  With
-    dropout off u is p and dS = p * (dP - delta); with it on,
-    dS = u * dP - p * delta (the denominator is undropped, see
-    _apply_dropout)."""
-    p = jnp.exp(s - lse)
-    if mask == "positions":
-        p = jnp.where(s > 0.5 * NEG_INF, p, 0.0)
-    dp = _dot(v, do, ((1,), (1,)))                        # [BK, BQ]
-    if dropout_p <= 0.0:
-        return p, p * (dp - delta)
-    u = _apply_dropout(p, seed_ref, qi, j, dropout_p)
-    return u, u * dp - p * delta
-
 
 def _bwd_dkv_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
                     *rest, scale, block_q, seq_q, causal, block_k, aligned,
@@ -564,22 +458,21 @@ def _bwd_dkv_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
     if dq_accs:
         @pl.when(kj == 0)
         def _():
-            for acc in dq_accs:
-                acc[...] = jnp.zeros_like(acc)
+            dq_zero(dq_accs)
 
     def body(i, carry, mask):
         dk, dv, dkr = carry
-        q = _prescale(_rows(q_ref, i, block_q), scale)    # [BQ, D]
-        qr = _prescale(_rows(qr_ref, i, block_q), scale) if shared else None
-        do = _rows(do_ref, i, block_q)
+        q = prescale(rows(q_ref, i, block_q), scale)      # [BQ, D]
+        qr = prescale(rows(qr_ref, i, block_q), scale) if shared else None
+        do = rows(do_ref, i, block_q)
         cols = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
         lse = lse_ref[0, 0, 0:1, cols]                    # [1, BQ]
         delta = delta_ref[0, 0, 0:1, cols]
-        s = _scores(k, q, mask, i, kj, q_off_ref, k_off_ref, block_q,
-                    block_k, (kr, qr) if shared else None)
+        s = scores(k, q, mask, i, kj, q_off_ref, k_off_ref, block_q,
+                   block_k, (kr, qr) if shared else None)
         # fwd tile (qi=i, j=kj): identical seed -> identical mask
-        u, ds = _p_ds(s, mask, lse, do, v, delta, seed_ref, i, kj,
-                      dropout_p)
+        u, ds = p_ds(s, lse, do, v, delta, may_hide_query=mask == "positions",
+                     drop=_dropout(seed_ref, i, kj, dropout_p))
         dv = dv + _dot(u.astype(do.dtype), do, ((1,), (0,)))
         # against the pre-scaled q: dk needs no scale of its own
         ds = ds.astype(q.dtype)
@@ -587,14 +480,11 @@ def _bwd_dkv_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
         if shared:
             dkr = dkr + _dot(ds, qr, ((1,), (0,)))
         # q block i's dq^T [D, BQ], and under it the shared part's
-        for acc, key in zip(dq_accs, (k, kr) if shared else (k,)):
-            acc[i] += _dot(key, ds, ((0,), (0,)))
+        dq_add(dq_accs, (k, kr) if shared else (k,), i, ds)
         return dk, dv, dkr
 
-    start, full = _q_bounds(kj, block_q, block_k, num_q)
-    dk, dv, dkr = _block_loops(
-        body, (dk, dv, dkr), num_q, causal, aligned,
-        ((start, full, "diagonal"), (full, num_q, None)))
+    dk, dv, dkr = block_loops(body, (dk, dv, dkr), num_q, causal, aligned,
+                              q_spans(kj, block_q, block_k, num_q))
     dk_ref[0, 0] = dk.astype(dk_ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
     if shared:
@@ -603,19 +493,11 @@ def _bwd_dkv_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
     if dq_accs:
         @pl.when(kj == pl.num_programs(2) - 1)
         def _():
-            def emit(i, carry):
-                rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
-                # s was taken against scale * q: the chain rule's scale,
-                # once, and each [D, BQ] block transposed once a head
-                for ref, acc in zip(dq_refs, dq_accs):
-                    ref[0, 0, rows, :] = (acc[i] * scale).T.astype(ref.dtype)
-                return carry
-
-            jax.lax.fori_loop(0, num_q, emit, 0)
+            dq_emit(dq_refs, dq_accs, num_q, block_q, scale)
 
 
-def _bwd_dkv(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale, causal,
-             blocks, aligned, dropout_p, shared=None, with_dq=False):
+def bwd_dkv(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale, causal,
+            blocks, aligned, dropout_p, shared=None, with_dq=False):
     """``with_dq``: what the caller needs of the walk.  `_bwd` takes dQ
     from it; EVA's windows (eva_attention.py) take dK and dV alone, their
     dQ comes with the summaries' gradients from a kernel of their own."""
@@ -645,7 +527,7 @@ def _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale,
     def whole(width):
         return pl.BlockSpec((1, 1, Lq, width), lambda b, h, j: (b, h, 0, 0))
 
-    def rows(width):
+    def part(width):
         return pl.BlockSpec((1, 1, block_k, width),
                             lambda b, h, j: (b, h, j, 0))
 
@@ -675,7 +557,7 @@ def _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale,
             pl.BlockSpec((1, 1, 8, Lq), lambda b, h, j: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, 8, Lq), lambda b, h, j: (b, h, 0, 0)),
         ],
-        out_specs=[rows(D), rows(Dv)] + ([rows(Dr)] if shared else [])
+        out_specs=[part(D), part(Dv)] + ([part(Dr)] if shared else [])
         + [whole(w) for w in dq_widths],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Lk, D), k.dtype),
@@ -699,18 +581,13 @@ def _bwd(q, k, v, q_off, k_off, seed, out, lse, do, dlse, scale, causal,
     folds into delta: with P = exp(S - lse) row-normalized,
     dS = P * (dP_rows - delta + dlse) since d lse / dS = P."""
     from ...utils import monitor
-    B, H, Lq, _ = q.shape
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)                              # [B, H, Lq]
+    delta = _delta(do, out)                               # [B, H, Lq]
     if dlse is not None:
         delta = delta - dlse.astype(jnp.float32)
-    # 8-sublane replication at the kernel boundary (see _fwd_kernel note)
-    lse8 = jnp.broadcast_to(lse[:, :, None, :], (B, H, 8, Lq))
-    delta8 = jnp.broadcast_to(delta[:, :, None, :], (B, H, 8, Lq))
     monitor.stat_add("pallas.flash.bwd_fused")
-    dk, dv, *rest = _bwd_dkv(q, k, v, q_off, k_off, seed, do, lse8, delta8,
-                             scale, causal, blocks, aligned, dropout_p,
-                             shared=shared, with_dq=True)
+    dk, dv, *rest = bwd_dkv(q, k, v, q_off, k_off, seed, do, rows8(lse),
+                            rows8(delta), scale, causal, blocks, aligned,
+                            dropout_p, shared=shared, with_dq=True)
     if not shared:
         return rest[0], _sum_groups(dk, k), _sum_groups(dv, v)
     dkr, dq, dqr = rest
@@ -764,12 +641,12 @@ def _flash_with_lse(q, k, v, q_off, k_off, scale, blocks):
     """Position-masked attention returning (out, lse) — the ring-attention
     building block (both outputs differentiable; no dropout: ring rounds
     merge via logsumexp, which requires undropped weights)."""
-    return _fwd(q, k, v, q_off, k_off, _zero_seed(), scale, True, blocks,
+    return _fwd(q, k, v, q_off, k_off, zero_seed(), scale, True, blocks,
                 False)
 
 
 def _flash_with_lse_fwd(q, k, v, q_off, k_off, scale, blocks):
-    out, lse = name_residuals(*_fwd(q, k, v, q_off, k_off, _zero_seed(),
+    out, lse = name_residuals(*_fwd(q, k, v, q_off, k_off, zero_seed(),
                                     scale, True, blocks, False))
     return (out, lse), (q, k, v, q_off, k_off, out, lse)
 
@@ -777,7 +654,7 @@ def _flash_with_lse_fwd(q, k, v, q_off, k_off, scale, blocks):
 def _flash_with_lse_bwd(scale, blocks, res, cts):
     q, k, v, q_off, k_off, out, lse = res
     do, dlse = cts
-    dq, dk, dv = _bwd(q, k, v, q_off, k_off, _zero_seed(), out, lse, do,
+    dq, dk, dv = _bwd(q, k, v, q_off, k_off, zero_seed(), out, lse, do,
                       dlse, scale, True, blocks, False)
     return dq, dk, dv, jnp.zeros_like(q_off), jnp.zeros_like(k_off)
 
@@ -790,14 +667,14 @@ def _flash_shared_key(q, qr, k, kr, v, scale, blocks):
     """Causal attention whose key is a head's own ``k`` [B, H, L, D]
     beside ``kr`` [B, 1, L, Dr], one for all heads; ``qr`` [B, H, L, Dr]
     meets it.  The shared part enters the kernels once a batch entry."""
-    out, _ = _fwd(q, k, v, _zero_off(), _zero_off(), _zero_seed(), scale,
+    out, _ = _fwd(q, k, v, zero_off(), zero_off(), zero_seed(), scale,
                   True, blocks, True, shared=(qr, kr))
     return out
 
 
 def _flash_shared_key_fwd(q, qr, k, kr, v, scale, blocks):
     out, lse = name_residuals(*_fwd(
-        q, k, v, _zero_off(), _zero_off(), _zero_seed(), scale, True,
+        q, k, v, zero_off(), zero_off(), zero_seed(), scale, True,
         blocks, True, shared=(qr, kr)))
     return out, (q, qr, k, kr, v, out, lse)
 
@@ -805,7 +682,7 @@ def _flash_shared_key_fwd(q, qr, k, kr, v, scale, blocks):
 def _flash_shared_key_bwd(scale, blocks, res, do):
     q, qr, k, kr, v, out, lse = res
     (dq, dqr), (dk, dkr), dv = _bwd(
-        q, k, v, _zero_off(), _zero_off(), _zero_seed(), out, lse, do, None,
+        q, k, v, zero_off(), zero_off(), zero_seed(), out, lse, do, None,
         scale, True, blocks, True, shared=(qr, kr))
     # the heads' parts add up in float32
     dkr = jnp.sum(dkr, axis=1, keepdims=True).astype(kr.dtype)
@@ -819,11 +696,11 @@ _flash_shared_key.defvjp(_flash_shared_key_fwd, _flash_shared_key_bwd)
 # public entries
 # ---------------------------------------------------------------------------
 
-def _zero_off():
+def zero_off():
     return jnp.zeros((1, 1), jnp.float32)
 
 
-def _zero_seed():
+def zero_seed():
     return jnp.zeros((1, 1), jnp.int32)
 
 
@@ -851,14 +728,14 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
             "only); use scaled_dot_product_attention, whose dispatch "
             "falls back to the unfused path off-TPU")
     if seed is None:
-        seed = _zero_seed()
+        seed = zero_seed()
     else:
         seed = jnp.asarray(seed, jnp.int32).reshape(1, 1)
     count_kernel_selection("flash_attention")
     qt = jnp.swapaxes(q, 1, 2)      # [B, H, L, D]
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
-    out = _flash(qt, kt, vt, _zero_off(), _zero_off(), seed, scale,
+    out = _flash(qt, kt, vt, zero_off(), zero_off(), seed, scale,
                  bool(causal), blocks, True, float(dropout_p))
     return jnp.swapaxes(out, 1, 2)
 
@@ -877,6 +754,7 @@ def flash_attention_shared_key(q, q_shared, k, k_shared, v, scale=None,
     D, Dr = q.shape[-1], q_shared.shape[-1]
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(D + Dr)
     blocks = _resolve_blocks(block_q, block_k, q.shape[1], k.shape[1])
+    count_kernel_selection("mla_attention")     # and the kernels it runs on
     count_kernel_selection("flash_attention")
     out = _flash_shared_key(
         jnp.swapaxes(q, 1, 2), jnp.swapaxes(q_shared, 1, 2),
@@ -886,7 +764,7 @@ def flash_attention_shared_key(q, q_shared, k, k_shared, v, scale=None,
 
 
 def flash_attention_block(q_bhld, k_bhld, v_bhld, q_off, k_off, scale,
-                          block_q: int = _BLOCK, block_k: int = _BLOCK):
+                          block_q: int = BLOCK, block_k: int = BLOCK):
     """Ring-attention building block: [B, H, L, D] layout, traced global
     position offsets (float32 [1,1] arrays), always position-masked.
     Returns (out normalized [B,H,L,D], lse [B,H,L]); fully-masked rows

@@ -20,7 +20,7 @@ token; the masked dense causal pass over the same row is cheaper by an
 order of magnitude at these lengths, so the selection is a **mask**
 shared by all heads: ``[B, keys, queries]`` int8, keys on the major axis
 because every kernel here holds its score tile as [block_k, block_q]
-like the flash kernels (flash_attention.py, whose helpers these share).
+like the flash kernels (attention_tiles.py has what the family shares).
 
 Kernels (five), all [block, block] tiles of 512:
 
@@ -62,7 +62,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ...observability import scopes
-from .flash_attention import _BLOCK, _prescale, _rows
+from .attention_tiles import (BLOCK, delta as _delta, dq_add, dq_emit,
+                              dq_zero, online_step, p_ds, prescale, rows,
+                              rows8, write_row8)
 from .support import (NEG_INF, count_kernel_selection, dot as _dot,
                       interpret_mode as _interpret, name_residuals, pltpu)
 
@@ -78,11 +80,11 @@ def sparse_attention_supported(q_shape, k_shape, dtype) -> bool:
     if len(q_shape) != 4 or dtype not in (jnp.float32, jnp.bfloat16):
         return False
     _, T, A, D = q_shape
-    if A % k_shape[2] or T % min(_BLOCK, T):
+    if A % k_shape[2] or T % min(BLOCK, T):
         return False
     if _interpret():
         return True
-    return (D % 128 == 0 and T % _BLOCK == 0
+    return (D % 128 == 0 and T % BLOCK == 0
             and T * D * jnp.dtype(dtype).itemsize <= 2 * 1024 * 1024)
 
 
@@ -91,9 +93,9 @@ def dsa_indexer_supported(index_q_shape, dtype) -> bool:
     if len(index_q_shape) != 4 or dtype not in (jnp.float32, jnp.bfloat16):
         return False
     _, T, _, d = index_q_shape
-    if T % min(_BLOCK, T):
+    if T % min(BLOCK, T):
         return False
-    return _interpret() or (d % 64 == 0 and T % _BLOCK == 0)
+    return _interpret() or (d % 64 == 0 and T % BLOCK == 0)
 
 
 # ---------------------------------------------------------------------------
@@ -389,10 +391,9 @@ def _threshold_kernel(it_ref, thr_ref, cut_ref, lse_ref, keys_ref, *, topk,
                          jnp.zeros((1, bq), jnp.float32)))
     # back to the float
     bits = prefix ^ (jax.lax.shift_right_arithmetic(prefix, 31) & 0x7FFFFFFF)
-    thr_ref[0] = jnp.broadcast_to(
-        jax.lax.bitcast_convert_type(bits, jnp.float32), (8, bq))
-    cut_ref[0] = jnp.broadcast_to(cut, (8, bq))
-    lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), (8, bq))
+    write_row8(thr_ref, jax.lax.bitcast_convert_type(bits, jnp.float32))
+    write_row8(cut_ref, cut)
+    write_row8(lse_ref, m + jnp.log(l))
 
 
 def _dsa_threshold(IT, topk, block, interpret):
@@ -423,10 +424,7 @@ def _dsa_threshold(IT, topk, block, interpret):
 def _head_rows(w):
     """w [B, T, J] -> [B, J, 8, T] float32: a head's weights as a row
     the kernels index by head."""
-    B, T, J = w.shape
-    return jnp.broadcast_to(
-        jnp.swapaxes(w.astype(jnp.float32), 1, 2)[:, :, None, :],
-        (B, J, 8, T))
+    return rows8(jnp.swapaxes(w.astype(jnp.float32), 1, 2))
 
 
 def dsa_select(qI, w, kI, topk, block=None):
@@ -435,8 +433,9 @@ def dsa_select(qI, w, kI, topk, block=None):
     a time: a row's float32 scores (0.27 GB at 8192) live only until its
     mask is made, in one elementwise pass."""
     T = qI.shape[1]
-    block = min(block or _BLOCK, T)
+    block = min(block or BLOCK, T)
     interpret = _interpret()
+    count_kernel_selection("dsa_indexer")
     # the selection carries no gradient, and a kernel has no rule to skip
     qI, w, kI = (jax.lax.stop_gradient(a) for a in (qI, w, kI))
     key_pos = jnp.arange(T)[:, None]
@@ -467,41 +466,24 @@ def _masked_scores(k, q, mask_tile):
     return jnp.where(mask_tile.astype(jnp.int32) != 0, s, NEG_INF)
 
 
-def _rows3(ref, j, block):
-    """Rows [j*block, (j+1)*block) of a [1, T, .] block staged whole."""
-    return ref[0, pl.ds(pl.multiple_of(j * block, block), block), :]
-
-
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, scale,
                 block):
     i = pl.program_id(2)
-    q = _prescale(q_ref[0, 0], scale)                     # [BQ, D]
+    q = prescale(q_ref[0, 0], scale)                      # [BQ, D]
     bq, d = q.shape
 
     def body(j, carry):
-        m, l, acc = carry
-        s = _masked_scores(_rows(k_ref, j, block), q,
-                           _rows3(mask_ref, j, block))
-        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
-        # a tile may hold none of a query's keys: s - m_new is 0 there
-        p = jnp.where(s > 0.5 * NEG_INF, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m - m_new)
-        v = _rows(v_ref, j, block)
-        return (m_new, l * alpha + jnp.sum(p, axis=0, keepdims=True),
-                acc * alpha + _dot(v, p.astype(v.dtype), ((0,), (0,))))
+        s = _masked_scores(rows(k_ref, j, block), q,
+                           rows(mask_ref, j, block))
+        return online_step(carry, s, rows(v_ref, j, block),
+                           may_hide_query=True)
 
     m, l, acc = jax.lax.fori_loop(0, i + 1, body, (
         jnp.full((1, bq), NEG_INF, jnp.float32),
         jnp.zeros((1, bq), jnp.float32), jnp.zeros((d, bq), jnp.float32)))
     # every query keeps at least one key (itself or better): l > 0
     o_ref[0, 0] = (acc / l).T.astype(o_ref.dtype)
-    lse_ref[0, 0] = jnp.broadcast_to(m + jnp.log(l), (8, bq))
-
-
-def _p_ds(s, lse, do, v, delta):
-    p = jnp.where(s > 0.5 * NEG_INF, jnp.exp(s - lse), 0.0)
-    dp = _dot(v, do, ((1,), (1,)))                        # [BK, BQ]
-    return p, p * (dp - delta)
+    write_row8(lse_ref, m + jnp.log(l))
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
@@ -517,7 +499,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(j == 0)
     def _():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
+        dq_zero([dq_acc])
 
         @pl.when(h == 0)
         def _():
@@ -529,38 +511,31 @@ def _bwd_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
 
     def body(i, carry):
         dk, dv = carry
-        q = _prescale(_rows(q_ref, i, block), scale)
-        do = _rows(do_ref, i, block)
+        q = prescale(rows(q_ref, i, block), scale)
+        do = rows(do_ref, i, block)
         cols = pl.ds(pl.multiple_of(i * block, block), block)
         s = _masked_scores(k, q, mask_ref[0, :, cols])
-        p, ds = _p_ds(s, lse_ref[0, 0, 0:1, cols], do, v,
-                      delta_ref[0, 0, 0:1, cols])
+        p, ds = p_ds(s, lse_ref[0, 0, 0:1, cols], do, v,
+                     delta_ref[0, 0, 0:1, cols], may_hide_query=True)
         ds = ds.astype(q.dtype)
-        dq_acc[i] += _dot(k, ds, ((0,), (0,)))            # [D, BQ]
+        dq_add([dq_acc], (k,), i, ds)                     # [D, BQ]
         return (dk + _dot(ds, q, ((1,), (0,))),
                 dv + _dot(p.astype(do.dtype), do, ((1,), (0,))))
 
     zero = jnp.zeros(k.shape, jnp.float32)
     dk, dv = jax.lax.fori_loop(j, num_q, body, (zero, zero))
-    rows = pl.ds(pl.multiple_of(j * block, block), block)
-    dk_acc[rows, :] += dk
-    dv_acc[rows, :] += dv
+    at = pl.ds(pl.multiple_of(j * block, block), block)
+    dk_acc[at, :] += dk
+    dv_acc[at, :] += dv
 
     @pl.when(h == pl.num_programs(2) - 1)
     def _():
-        dk_ref[0, 0, rows, :] = dk_acc[rows, :].astype(dk_ref.dtype)
-        dv_ref[0, 0, rows, :] = dv_acc[rows, :].astype(dv_ref.dtype)
+        dk_ref[0, 0, at, :] = dk_acc[at, :].astype(dk_ref.dtype)
+        dv_ref[0, 0, at, :] = dv_acc[at, :].astype(dv_ref.dtype)
 
     @pl.when(j == num_q - 1)
     def _():
-        def emit(i, carry):
-            at = pl.ds(pl.multiple_of(i * block, block), block)
-            # s was taken against scale * q: the chain rule's scale, once,
-            # and each [D, BQ] block transposed once a head
-            dq_ref[0, 0, at, :] = (dq_acc[i] * scale).T.astype(dq_ref.dtype)
-            return carry
-
-        jax.lax.fori_loop(0, num_q, emit, 0)
+        dq_emit([dq_ref], [dq_acc], num_q, block, scale)
 
 
 def _group_specs(T, D, G, block):
@@ -597,11 +572,6 @@ def _fwd(q, k, v, mask, scale, block):
     return out, lse[:, :, 0, :]
 
 
-def _rows8(x):
-    B, A, T = x.shape
-    return jnp.broadcast_to(x[:, :, None, :], (B, A, 8, T))
-
-
 def _bwd(q, k, v, mask, out, lse, do, scale, block):
     """One kernel -> (dq, dk, dv), on the grid (batch, key/value head, head
     of the group, key block).  A head's q, dO, lse and delta and its dQ
@@ -616,7 +586,7 @@ def _bwd(q, k, v, mask, out, lse, do, scale, block):
     B, A, T, D = q.shape
     KV = k.shape[1]
     G = A // KV
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), -1)
+    delta = _delta(do, out)
 
     def head(b, g, h, j):
         return b, g * G + h, 0, 0
@@ -645,7 +615,7 @@ def _bwd(q, k, v, mask, out, lse, do, scale, block):
                                 "arbitrary"),
         interpret=_interpret(),
         name=scopes.SPARSE_BWD_DKV,
-    )(q, k, v, mask, do, _rows8(lse), _rows8(delta))
+    )(q, k, v, mask, do, rows8(lse), rows8(delta))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
@@ -682,7 +652,7 @@ def sparse_attention(q, k, v, mask, block=None):
     scale = 1.0 / math.sqrt(D)
     count_kernel_selection("sparse_attention")
     qt, kt, vt = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))
-    out, lse = _sparse(qt, kt, vt, mask, scale, min(block or _BLOCK, T))
+    out, lse = _sparse(qt, kt, vt, mask, scale, min(block or BLOCK, T))
     return jnp.swapaxes(out, 1, 2), jax.lax.stop_gradient(lse)
 
 
@@ -695,12 +665,12 @@ def _kl_tile(j, q_ref, k_ref, lse_ref, qI_ref, w_ref, kI_ref, lseI,
     """For key block ``j`` of the query block the refs hold: (the tile's
     KL summed over keys [1, BQ], pI - ph on the selected pairs [BK, BQ],
     kI's rows [BK, d])."""
-    sel = _rows3(mask_ref, j, block).astype(jnp.int32) != 0
-    kI = _rows3(kI_ref, j, block)
+    sel = rows(mask_ref, j, block).astype(jnp.int32) != 0
+    kI = rows(kI_ref, j, block)
     logpI = _index_tile(kI, qI_ref, w_ref, idx_heads) - lseI
 
     def head(h, acc):
-        q = _prescale(q_ref[0, h], scale)
+        q = prescale(q_ref[0, h], scale)
         k = k_ref[0, h // group,
                   pl.ds(pl.multiple_of(j * block, block), block), :]
         s = _dot(k, q, ((1,), (1,)))
@@ -738,7 +708,7 @@ def _kl_kernel(q_ref, k_ref, lse_ref, qI_ref, w_ref, kI_ref, lseI_ref,
         dw_acc[...] = jnp.zeros_like(dw_acc)
 
     def accumulate(j, dI, kI):
-        rows = pl.ds(pl.multiple_of(j * block, block), block)
+        at = pl.ds(pl.multiple_of(j * block, block), block)
 
         def head(n, dkI):
             qI = qI_ref[0, n]                             # [BQ, d]
@@ -751,7 +721,7 @@ def _kl_kernel(q_ref, k_ref, lse_ref, qI_ref, w_ref, kI_ref, lseI_ref,
             dqI_acc[n] += _dot(kI, ds, ((0,), (0,)))      # [d, BQ]
             return dkI + _dot(ds, qI, ((1,), (0,)))       # [BK, d]
 
-        dkI_acc[rows, :] += jax.lax.fori_loop(
+        dkI_acc[at, :] += jax.lax.fori_loop(
             0, J, head, jnp.zeros(kI.shape, jnp.float32))
 
     def body(j, kl):
@@ -762,7 +732,7 @@ def _kl_kernel(q_ref, k_ref, lse_ref, qI_ref, w_ref, kI_ref, lseI_ref,
         return kl + tile_kl
 
     kl = jax.lax.fori_loop(0, i + 1, body, jnp.zeros((1, bq), jnp.float32))
-    kl_ref[0] = jnp.broadcast_to(kl, (8, bq))
+    write_row8(kl_ref, kl)
     if grad_refs:
         dqI_ref[0] = dqI_acc[...]
         dw_ref[0] = dw_acc[...]
@@ -815,8 +785,7 @@ def _kl_call(q, k, lse, qIt, wt, kI, lseI, mask, scale, block, grads):
                                 "arbitrary" if grads else "parallel"),
         interpret=_interpret(),
         name=scopes.DSA_KL,
-    )(q, k, _rows8(lse), qIt, wt, kI,
-      jnp.broadcast_to(lseI[:, None, :], (B, 8, T)), mask)
+    )(q, k, rows8(lse), qIt, wt, kI, rows8(lseI), mask)
     if grads:
         dqIt, dw, dkI = grad
         grad = (dqIt, dw[:, :, 0], dkI)
@@ -862,8 +831,9 @@ def dsa_kl(qI, w, kI, mask, lseI, q, k, lse, block=None):
     ``dsa_select``'s; gradients reach qI, w and kI only."""
     T, D = q.shape[1], q.shape[3]
     scale = 1.0 / math.sqrt(D)
+    count_kernel_selection("dsa_kl")
     qt, kt = jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2)
     return _kl(jnp.swapaxes(qI, 1, 2), _head_rows(w), kI,
                *(jax.lax.stop_gradient(a) for a in (qt, kt, lse)),
                jax.lax.stop_gradient(lseI), mask, scale,
-               min(block or _BLOCK, T))
+               min(block or BLOCK, T))

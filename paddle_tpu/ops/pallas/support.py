@@ -1,7 +1,10 @@
 """Shared plumbing for the Pallas kernel tier.
 
-Every kernel file (flash_attention, fused_epilogue, fused_adam,
-paged_attention) needs the same four decisions made the same way:
+Every kernel file (flash_attention, eva_attention, sparse_attention,
+ssd_scan, fused_epilogue, fused_adam, paged_attention,
+collective_matmul; attention_tiles holds the attention family's shared
+tile mathematics and launches nothing) needs the same five decisions
+made the same way:
 
 - **backend**: the ``pltpu`` import, interpret mode when not on a real
   TPU;
@@ -12,6 +15,9 @@ paged_attention) needs the same four decisions made the same way:
   so it must never be the silent CPU default);
 - **gates**: dtype and tile-alignment checks against the f32 (8, 128)
   sublane/lane tile;
+- **the choice** between a kernel and its XLA form, for the functionals
+  of ``nn.functional`` that have both: `choose_kernel`, the one caller
+  of `tier_enabled` on a benchmark cell's path;
 - **observability**: every kernel SELECTION counts
   ``pallas.selected.<kernel>`` in monitor.  Selections happen at trace
   time (the kernel entry points run inside jitted programs, once per
@@ -21,7 +27,7 @@ paged_attention) needs the same four decisions made the same way:
   to the perf observatory.  "FLAGS off => zero selections" is the
   testable contract.
 
-One place decides all four; the kernel files keep only their math.
+One place decides all five; the kernel files keep only their math.
 """
 from __future__ import annotations
 
@@ -33,8 +39,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...observability import scopes
 
-__all__ = ["pltpu", "interpret_mode", "tier_enabled", "dtype_ok",
-           "smem_scalar_spec", "count_kernel_selection",
+__all__ = ["pltpu", "interpret_mode", "tier_enabled", "choose_kernel",
+           "dtype_ok", "smem_scalar_spec", "count_kernel_selection",
            "kernel_selections", "block_rows", "name_residuals", "NEG_INF"]
 
 NEG_INF = -1e30
@@ -107,6 +113,24 @@ def count_kernel_selection(name: str) -> None:
     kernel_selections[name] = kernel_selections.get(name, 0) + 1
     from ...utils import monitor
     monitor.stat_add(f"pallas.selected.{name}")
+
+
+def choose_kernel(functional: str, supported: bool) -> bool:
+    """Kernel or XLA form, for one traced call of a functional that has
+    both: the kernel where the tier is on (`tier_enabled`) and the
+    mechanism's own gate took the call's shapes and dtype (``supported``:
+    its ``*_supported``).  Who counts, one rule: a kernel's public entry
+    counts its own selection (``pallas.selected.<kernel>``, as
+    `flash_attention`, `sparse_attention`, `ssd_scan`, `fused_adam`,
+    `fused_epilogue`, `paged_attention` and `collective_matmul` always
+    have), so a direct call of it is counted too; the chooser counts the
+    other side, ``<functional>.xla_path``.  Exactly one of the two moves
+    a traced call."""
+    if supported and tier_enabled():
+        return True
+    from ...utils import monitor
+    monitor.stat_add(f"{functional}.xla_path")
+    return False
 
 
 def block_rows(m: int, preferred: int = 512) -> int:

@@ -278,6 +278,9 @@ def test_evabyte_cell_step_fits_the_chip(one_chip, monkeypatch):
     # the policy keeps by name: nothing here carries the indexer's names
     stats = monitor.all_stats()
     assert _kept(stats) == {scopes.ATTN_OUT: L, scopes.ATTN_LSE: L}
+    # every layer's call chose the kernels, and the program says so
+    assert stats["pallas.selected.eva_attention"] >= L
+    assert "eva_attention.xla_path" not in stats
     assert stats["linear_cross_entropy.calls"] == cfg["num_pred_heads"] == 8
     assert "linear_cross_entropy.grads_in_forward" not in stats
     assert any("rematted_computation" in n and "linear_cross_entropy" in n
@@ -332,6 +335,13 @@ def test_keye_vl2_cell_step_fits_the_chip(one_chip, monkeypatch):
     assert _kept(stats) == dict.fromkeys(scopes.RESIDUALS, L)
     assert stats["pallas.sparse.bwd_fused"] == L
     assert "pallas.flash.bwd_fused" not in stats
+    # each of a layer's three functionals chose its kernels: the loss
+    # over [4, 8192, 8192] would cost most if it fell to the XLA form
+    for kernel, functional in (("dsa_indexer", "dsa_indexer"),
+                               ("sparse_attention", "sparse_attention"),
+                               ("dsa_kl", "dsa_indexer_loss")):
+        assert stats[f"pallas.selected.{kernel}"] >= L, kernel
+        assert f"{functional}.xla_path" not in stats, functional
     _head_made_its_gradients_in_the_forward_pass(text, calls=1)
     assert footprint < 15.75 * 2 ** 30
 

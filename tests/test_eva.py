@@ -211,14 +211,14 @@ def test_the_windows_ask_the_flash_walk_for_dk_and_dv_alone(dtype):
     # the same walk over one folded window, asked both ways
     q, k, v = (jnp.swapaxes(a, 1, 2)[:, :, :16] for a in args[:3])
     do = (q * 0.5 + 0.25).astype(dtype)
-    zero, seed = fa._zero_off(), fa._zero_seed()
+    zero, seed = fa.zero_off(), fa.zero_seed()
     out, lse = fa._fwd(q, k, v, zero, zero, seed, 0.25, True, (8, 8), True)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), -1)
     rows8 = [jnp.broadcast_to(a[:, :, None, :], a.shape[:2] + (8, 16))
              for a in (lse, delta)]
 
     def walk(with_dq):
-        return lambda q, k, v, do: fa._bwd_dkv(
+        return lambda q, k, v, do: fa.bwd_dkv(
             q, k, v, zero, zero, seed, do, *rows8, 0.25, True, (8, 8), True,
             0.0, with_dq=with_dq)
 
