@@ -541,35 +541,50 @@ def check_flash_dropout(errs, bert):
     errs["flash_dropout"] = "deterministic per seed"
 
 
-def check_flash_grouped(errs, shape=(1, 2048, 32, 128), kv_heads=2):
-    """Grouped key/value heads through the kernels' index maps (the
-    Nemotron cell's 32 query heads on 2, a quarter of its length so that
-    the oracle's scores fit): forward and gradients against the oracle,
-    which repeats k and v."""
+def check_flash_grouped(errs, shape=(1, 2048, 32, 128), kv_heads=2,
+                        scale=None, tag="flash_grouped"):
+    """Grouped key/value heads through the kernels' index maps: the
+    Nemotron cell's 32 query heads on 2 (a quarter of its length), and
+    the Granite cell's own call, [1, 8192, 32 on 8, 64] at the family's
+    ``scale`` 1/64.  Forward and gradients against the oracle, which
+    repeats k and v and runs a key/value head's query heads at a time so
+    that its scores fit."""
     import jax
     import jax.numpy as jnp
 
     from paddle_tpu.ops.pallas.flash_attention import (flash_attention,
                                                        mha_reference)
-    kv = shape[:2] + (kv_heads, shape[3])
+    B, L, H, D = shape
+    kv = (B, L, kv_heads, D)
     q, k, v = (_rnd(i, s, jnp.bfloat16)
                for i, s in ((1, shape), (2, kv), (3, kv)))
-    flash, plain = (functools.partial(f, causal=True)
-                    for f in (flash_attention, mha_reference))
-    errs["flash_grouped_fwd"] = _close(
+    flash = functools.partial(flash_attention, causal=True, scale=scale)
+
+    def plain(q, k, v):
+        group = jax.checkpoint(lambda args: mha_reference(
+            args[0], *(jnp.repeat(a, H // kv_heads, 2) for a in args[1:]),
+            causal=True, scale=scale))
+        out = jax.lax.map(group, (
+            jnp.moveaxis(q.reshape(B, L, kv_heads, H // kv_heads, D), 2, 0),
+            jnp.moveaxis(k[:, :, :, None], 2, 0),
+            jnp.moveaxis(v[:, :, :, None], 2, 0)))
+        return jnp.moveaxis(out, 0, 2).reshape(B, L, H, D)
+
+    errs[f"{tag}_fwd"] = _close(
         jax.jit(flash)(q, k, v), jax.jit(plain)(q, k, v), BF16_TOL,
-        "flash grouped fwd")
+        f"{tag} fwd")
     got = jax.jit(jax.grad(_sq(flash), (0, 1, 2)))(q, k, v)
     ref = jax.jit(jax.grad(_sq(plain), (0, 1, 2)))(q, k, v)
     for n, a, b in zip("qkv", got, ref):
         assert a.shape == b.shape, (n, a.shape, b.shape)
-        errs[f"flash_grouped_d{n}"] = _close(a, b, 4 * BF16_TOL,
-                                             f"flash grouped d{n}")
+        errs[f"{tag}_d{n}"] = _close(a, b, 4 * BF16_TOL, f"{tag} d{n}")
 
 
 def check_ssd_scan(errs, shape=(2, 8192, 64, 64), groups=8, state=128,
-                   chunk=128):
-    """The state-space scan at the Nemotron cell's shape: bfloat16 x, B
+                   chunk=128, tag="ssd"):
+    """The state-space scan at the two state-space cells' shapes (the
+    Nemotron cell's 8 groups of 8 heads, a group a kernel step; the
+    Granite cell's ONE group of 64, walked in head blocks): bfloat16 x, B
     and C, float32 dt and A.  The two kernels (ops/pallas/ssd_scan.py)
     against the chunked form in XLA (ops/ssm.py), and that form's first
     row against the recurrence run a position at a time in float32;
@@ -607,8 +622,8 @@ def check_ssd_scan(errs, shape=(2, 8192, 64, 64), groups=8, state=128,
     args = (x, dt, A, Bm, Cm, D)
     row = (x[:1], dt[:1], A, Bm[:1], Cm[:1], D)
     for tag, got, want, operands in (
-            ("ssd_kernels", kernels.ssd_scan, chunked, args),
-            ("ssd_scan", chunked, recurrence, row)):
+            (f"{tag}_kernels", kernels.ssd_scan, chunked, args),
+            (f"{tag}_scan", chunked, recurrence, row)):
         errs[f"{tag}_fwd"] = _close(
             jax.jit(got)(*operands), jax.jit(want)(*operands), BF16_TOL,
             f"{tag} fwd")
@@ -731,7 +746,10 @@ def phase_kernels(clog, bert=BERT_BASE, serve=SERVE, engine=SERVE_ENGINE):
         check_flash(errs, bert)
         check_flash_dropout(errs, bert)
         check_flash_grouped(errs)
+        check_flash_grouped(errs, (1, 8192, 32, 64), kv_heads=8,
+                            scale=1 / 64, tag="flash_grouped_d64")
         check_ssd_scan(errs)
+        check_ssd_scan(errs, (1, 8192, 64, 64), groups=1, tag="ssd_one_group")
         check_epilogue(errs, bert)
         check_adam(errs, bert)
         check_paged(errs, serve, engine)

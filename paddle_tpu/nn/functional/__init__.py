@@ -1185,7 +1185,7 @@ def ctc_loss(log_probs, labels, input_lengths, label_lengths, blank=0,
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
                                  training=True, name=None,
-                                 return_weights=False):
+                                 return_weights=False, scale=None):
     """[B, L, H, D] attention (paddle incubate layout); ``key`` and
     ``value`` may have fewer heads [B, Lk, Hk, D], ``Hk`` dividing ``H``
     (grouped-query attention: query head h reads key/value head
@@ -1197,7 +1197,9 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     the crossover measured on the chip (``flash_attention_supported``;
     reference analog: bert_encoder_functor.cu fused attention).
     Counted at trace time: ``pallas.selected.flash_attention`` /
-    ``attention.xla_path``.
+    ``attention.xla_path``.  ``scale`` multiplies the scores; left at
+    None it is ``D ** -0.5`` (a family whose attention multiplier is
+    another number hands it in, on either path).
 
     ``return_weights=True`` forces the unfused path and returns
     ``(out, weights [B, H, Lq, Lk])`` — post-softmax probabilities, with
@@ -1214,12 +1216,14 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
             fdraw = stable_draw()  # in-trace + replay-stable seed
             return apply(
                 lambda q, k, v: flash_attention(
-                    q, k, v, causal=is_causal, dropout_p=eff_dropout,
+                    q, k, v, causal=is_causal, scale=scale,
+                    dropout_p=eff_dropout,
                     seed=jax.random.bits(fdraw.key(), (1, 1), jnp.uint32)
                     .astype(jnp.int32)),
                 query, key, value, op_name="flash_attention")
         return apply(
-            lambda q, k, v: flash_attention(q, k, v, causal=is_causal),
+            lambda q, k, v: flash_attention(q, k, v, causal=is_causal,
+                                            scale=scale),
             query, key, value, op_name="flash_attention")
 
     use_dropout = dropout_p > 0.0 and training
@@ -1238,8 +1242,8 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  f"divide {H} query heads")
             k = jnp.repeat(k, H // k.shape[2], axis=2)
             v = jnp.repeat(v, H // v.shape[2], axis=2)
-        scale = 1.0 / math.sqrt(D)
-        qt = jnp.einsum("blhd,bshd->bhls", q, k) * scale
+        qt = jnp.einsum("blhd,bshd->bhls", q, k) * (
+            1.0 / math.sqrt(D) if scale is None else float(scale))
         if is_causal:
             causal = jnp.tril(jnp.ones((Lq, k.shape[1]), bool))
             qt = jnp.where(causal[None, None], qt, -jnp.inf)
@@ -2050,8 +2054,12 @@ def ssd_scan(x, dt, A, B, C, D, chunk_size=128, name=None):
     Matrix products over chunks of ``chunk_size`` positions, the decays
     in float32, a hand-written backward that walks the chunks the other
     way (ops/ssm.py); T need not be a multiple of ``chunk_size``.  On a
-    TPU, for the shapes they take, two Pallas kernels that keep a chunk's
-    matrices in VMEM (ops/pallas/ssd_scan.py), the XLA form elsewhere.
+    TPU, for the shapes they take (``chunk_size`` 128, a state of whole
+    lane tiles, heads of 64 lanes or whole tiles, groups of a multiple of
+    8 heads, taken 16 or 8 a grid step: a larger group, such as ONE group
+    of 64, is walked in blocks whose dB and dC are summed after the
+    kernel), two Pallas kernels that keep a chunk's matrices in VMEM
+    (ops/pallas/ssd_scan.py), the XLA form elsewhere.
     Counted at trace time: ``pallas.selected.ssd_scan`` /
     ``ssd_scan.xla_path``."""
     from ...ops.pallas import ssd_scan as _kernels

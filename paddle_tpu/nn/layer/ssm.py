@@ -15,8 +15,11 @@ from .common import Linear
 
 
 class Mamba2Mixer(Layer):
-    """Mamba-2's mixer (Dao & Gu 2024; the form of the ``nemotron_h``
-    family's modelling code), without biases in its projections.
+    """Mamba-2's mixer (Dao & Gu 2024), without biases in its
+    projections: the form of two families' modelling code, ``nemotron_h``
+    (8 groups of 8 heads, the norm over each group's channels) and
+    ``granitemoehybrid`` (Mamba-2's published default, ``n_groups=1``: all
+    heads read ONE B and C, and the gated norm runs over all of d_inner).
 
     ``[z ; xBC ; dt] = h W_in`` (hidden -> d_inner + (d_inner + 2 G N) +
     heads, with d_inner = ``num_heads * head_dim``);
@@ -27,7 +30,9 @@ class Mamba2Mixer(Layer):
     in float32; the scan ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``,
     ``y_t = S_t C_t + D x_t`` with a state [head_dim, state_size] a head,
     zero at the start of every row, ``n_groups`` groups of heads sharing
-    B and C (``F.ssd_scan``: chunks of ``chunk_size``);
+    B and C (``F.ssd_scan``: chunks of ``chunk_size``; on a TPU at chunk
+    128 its two kernels, 16 or 8 heads a grid step: a group of more,
+    ``n_groups=1`` at 64 heads, is walked in blocks of heads);
     ``y = RMSNorm_group(y * silu(z)) * norm_weight`` over each group's
     channels; ``out = y W_out``.  ``forward`` takes the normed hidden
     state [B, T, hidden] in the weights' type and returns the branch

@@ -67,11 +67,15 @@ SSM = "ssm"                   # a state-space mixer (nn.Mamba2Mixer), every
 SSM_CONV = "ssm_conv"         # the causal convolution: taps, bias, silu
 SSM_SCAN = "ssm_scan"         # softplus, the decays, the chunked scan, D x
 SSM_GATE_NORM = "ssm_gate_norm"  # the gate and the group norm
+FFN = "ffn"                   # a gated feed-forward layer (nn.GatedFFN):
+                              # its two projections, the gate's activation
+                              # and the product
 FUNCTIONALS = (ATTENTION, LINEAR_CROSS_ENTROPY, GELU, LAYER_NORM, EMBEDDING,
                DROPOUT, EVA_ATTENTION, EVA_POOL, RMS_NORM, ROPE, MOE,
                MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, DSA_INDEXER, DSA_SELECT,
                SPARSE_ATTENTION, QK_NORM, MOE_SHARED, MLA_ATTENTION, MTP,
-               LOOP_STACK, LOOP_EXIT, SSM, SSM_CONV, SSM_SCAN, SSM_GATE_NORM)
+               LOOP_STACK, LOOP_EXIT, SSM, SSM_CONV, SSM_SCAN, SSM_GATE_NORM,
+               FFN)
 
 # -- Pallas kernels ----------------------------------------------------------
 FLASH_FWD = "flash_fwd"

@@ -166,9 +166,14 @@ def flash_attention_supported(q_shape, k_shape, dtype, attn_mask=None,
 # under 128 lanes takes 128): at 8192 x 128, q and dO 8 MiB and dQ 4
 # double-buffered, the accumulator 4, lse and delta 1, about 17 MiB
 # before the tiles, and at 8192 x 64 still 15 (64 lanes as 128), so both
-# state the limit; 4096 x 128 (8.5 MiB) and the cells' plain shapes stay
-# on the defaults; the shared-key walk about 30 MiB of the 48 (q 4 + qr 4
-# + dO 4, dQ 4 + dQr 4, the accumulators 4 + 2, lse and delta 1).
+# state the limit.  8192 x 64 is a cell's shape since PR 43 (granite-4.0-h:
+# [1, 8192, 32 heads on 8, 64], causal, scale 1/64): compiled for a v5e the
+# walk takes 15,888,384 bytes of scoped VMEM, the 15 MiB of this budget,
+# and the forward 5,869,568 on the defaults; on the chip the layer's pair
+# reads 4.05 + 8.27 ms in the step (PERF.md section 5).  4096 x 128 (8.5
+# MiB) and the other cells' plain shapes stay on the defaults; the
+# shared-key walk about 30 MiB of the 48 (q 4 + qr 4 + dO 4, dQ 4 + dQr 4,
+# the accumulators 4 + 2, lse and delta 1).
 _STAGED_DEFAULT = 4 * 1024 * 1024
 _STAGED_SHARED_KEY = 5 * 1024 * 1024
 _VMEM_LIMIT_STAGED = 48 * 1024 * 1024

@@ -6,31 +6,48 @@ states it, term for term: operands in x's type, float32 accumulation in
 every product, the decays in float32 with the mask before the ``exp``,
 ``M`` and ``dCB`` cast to x's type where the XLA form casts them.
 
-Both kernels walk the grid (batch, group, chunk) with the chunk axis
-sequential, and take x [B, T, H*P] and B, C [B, T, G*N] as they lie: a
-group's R = H / G heads are one R*P-lane block of x, its state one
-N-lane block of B and C, so nothing is transposed or copied around a
-call but ``dt`` ([B, T, H] float32, 1 / P of x), which comes in as
-[B, G, R, T]: a group's R rows of Q positions are one float32 tile, on
-which the running sum ``cs`` and the weights to the chunk's end are a few
-operations.  One [128, Q] transpose a step turns those rows into the
-columns the [Q, Q] tiles and x's rows are scaled by; in the backward a
+Both kernels walk the grid (batch, head block, chunk) with the chunk axis
+sequential, and take x [B, T, H*P] and B, C [B, T, G*N] as they lie.  A
+head block is the R heads one step takes (``_heads_a_step``): a whole
+group of 8 or 16 (Nemotron's 8 groups of 8 heads), else 16 or 8 heads of a
+larger group, which is walked in blocks (Mamba-2's published default, ONE
+group for all heads, as granite-4.0-h's 64, is 4 blocks of 16; the
+backward's three per-position sums a head share one [Q, 128] transpose a
+step, and 16 heads are what its VMEM holds).  A block is one R*P-lane
+block of x and reads its group's one N-lane block of B and C (the index
+map sends a group's blocks to the same one), so nothing is transposed or
+copied around a call but ``dt`` ([B, T, H] float32, 1 / P of x), which
+comes in as [B, H / R, R, T]: a block's R rows of Q positions are one
+float32 tile, on which the running sum ``cs`` and the weights to the
+chunk's end are a few operations.  One [128, Q] transpose a step turns
+those rows into the columns the [Q, Q] tiles and x's rows are scaled by; in the backward a
 second one brings the per-position sums back to rows.  Heads of 64 lanes
 go two to a slab of 128 (``_Slabs``): every elementwise pass and store is
 over whole vregs, and a head's operand of a product is its slab with the
 neighbour's lanes zeroed.
 
-- ``ssd_fwd`` carries the state, transposed ([N, R*P] float32: 256 KB a
-  group at 8 heads of 64 on a state of 128), in a VMEM scratch along the
-  chunk axis: a step adds ``exp(cs_t) C_t S`` and ``D x`` to ``y = M x``,
+- ``ssd_fwd`` carries a block's state, transposed ([N, R*P] float32: 256
+  KB at 8 heads of 64 on a state of 128, 512 KB at 16), in a VMEM scratch
+  along the chunk axis: a step adds ``exp(cs_t) C_t S`` and ``D x`` to ``y = M x``,
   then moves the state on by the chunk's own ``B^T (x w)``, one product a
-  group.  Where a backward will follow (the forward rule) it also writes
+  block.  Where a backward will follow (the forward rule) it also writes
   the state each chunk starts from, which is all the backward keeps of
   the forward.
 - ``ssd_bwd`` walks the chunks the other way with the state's gradient in
   the scratch, recomputes ``cs``, ``C B^T``, ``L`` and ``M``, and emits dx,
   ddt (``dcs`` folded through the running sum), dB, dC, and dA and dD
-  summed over a (batch, group)'s chunks; the wrapper sums the rest.
+  summed over a (batch, block)'s chunks; the wrapper sums the rest.
+
+What is summed where.  Over a block's heads, in the kernel: ``dCB`` (the
+gradient to ``C B^T``, float32, cast to x's type once) and through it the
+block's dB and dC.  Over a group's blocks, in the wrapper: dB and dC, which
+the kernel writes a block in float32 ([B, T, H / R, N]; with a group a
+step they ARE the group's and come out in B's type, as the XLA form gives
+them); each block recomputes the group's ``C B^T`` (2 Q^2 N = 4.2 MFLOP a
+step, nothing beside the vector work that bounds these kernels) and reads
+B and C again.  Over the batch and the chunks: dA and dD, as before.  A
+group of 8 heads compiles to the kernels it compiled to before head
+blocks existed (the same jaxpr at the Nemotron cell's shape, PR 43).
 
 With the state pass between two kernel passes in XLA instead, every
 chunk's own [R*P, N] float32 contribution would travel to HBM and back
@@ -59,12 +76,23 @@ _NN = ((1,), (0,))
 _TN = ((0,), (0,))       # a^T . b
 
 
+def _heads_a_step(R):
+    """Heads of a group of R (a multiple of 8) that one grid step takes:
+    16 where that divides the group, else 8.  At [1, 8192, 64 heads of
+    64] in one group on a v5e a layer's forward and forward + backward
+    read 1.24 / 3.38 ms in blocks of 8 and 0.95 / 2.65 in blocks of 16
+    (what a step does once, ``C B^T``, the two transposes, the masks, is
+    shared by twice the heads); 32 heads a step want more than Mosaic's
+    default scoped VMEM in the backward, which is why a group of 32 or 40
+    is walked in blocks too (PERF.md section 6, PR 43)."""
+    return 16 if R % 16 == 0 else 8
+
+
 def ssd_scan_supported(x_shape, b_shape, dtype, chunk) -> bool:
     """Shapes the kernels take: x [B, T, H, P], B / C [B, T, G, N].  The
     chunk is one lane tile; a state fills whole lane tiles and a head a
-    half tile or whole ones; a group's heads fill sublane tiles, and the
-    backward's three sums a head fit the one [Q, 128] transpose of a
-    step."""
+    half tile or whole ones; a group's heads fill sublane tiles, taken
+    a block of 8 or 16 a grid step (``_heads_a_step``)."""
     if len(x_shape) != 4 or len(b_shape) != 4 or not dtype_ok(dtype):
         return False
     H, P = x_shape[2:]
@@ -72,8 +100,7 @@ def ssd_scan_supported(x_shape, b_shape, dtype, chunk) -> bool:
     if chunk != _Q or H % G:
         return False
     R = H // G
-    return (N % _LANES == 0 and (P == 64 or P % _LANES == 0) and R % 8 == 0
-            and 3 * R <= _LANES)
+    return N % _LANES == 0 and (P == 64 or P % _LANES == 0) and R % 8 == 0
 
 
 def _running_sum(a, reverse=False):
@@ -129,7 +156,7 @@ def _seen():
 
 
 class _Slabs:
-    """A group's R heads of P lanes as slabs of whole lane tiles: a head
+    """A block's R heads of P lanes as slabs of whole lane tiles: a head
     of 64 shares its slab with its neighbour, so that every elementwise
     pass and every store is over full vregs; a head's operand of a product
     is the slab with the neighbour's lanes zeroed."""
@@ -295,10 +322,12 @@ def _bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, dy_ref,
 def _operands(x, dt, A, Bm, Cm, D):
     """Pad T to whole chunks (a padded position has dt = 0: it decays
     nothing and adds nothing) and lay the arguments as the kernels' blocks
-    take them, x, B and C as they are."""
+    take them, x, B and C as they are; what is a head's goes by head
+    block: ``dt`` [B, H / R, R, T], ``A`` [H / R, R, 1], ``D`` along the
+    lanes [H / R, 1, R*P], with R the heads a step takes."""
     B, T, H, P = x.shape
     G, N = Bm.shape[2:]
-    R = H // G
+    R = _heads_a_step(H // G)
     pad = -T % _Q
     if pad:
         x, dt, Bm, Cm = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) *
@@ -306,26 +335,32 @@ def _operands(x, dt, A, Bm, Cm, D):
     Tp = T + pad
     f32 = jnp.float32
     return (x.reshape(B, Tp, H * P),
-            jnp.swapaxes(dt.astype(f32), 1, 2).reshape(B, G, R, Tp),
-            A.astype(f32).reshape(G, R, 1), Bm.reshape(B, Tp, G * N),
+            jnp.swapaxes(dt.astype(f32), 1, 2).reshape(B, H // R, R, Tp),
+            A.astype(f32).reshape(H // R, R, 1), Bm.reshape(B, Tp, G * N),
             Cm.reshape(B, Tp, G * N),
-            jnp.repeat(D.astype(f32), P).reshape(G, 1, R * P))
+            jnp.repeat(D.astype(f32), P).reshape(H // R, 1, R * P))
 
 
-def _specs(R, P, N, chunk_of):
-    """Block specs on the grid (batch, group, step) for x-like, dt, A,
-    B-like, D and the states; ``chunk_of`` maps a step to its chunk."""
-    def cols(width):
+def _specs(R, P, N, blocks, chunk_of):
+    """Block specs on the grid (batch, head block, step) for x-like, dt,
+    A, B and C, D, the states, and dB and dC; ``chunk_of`` maps a step to
+    its chunk.  A group's ``blocks`` head blocks read the group's one
+    block of B and C, and each writes a dB and dC of its own."""
+    def cols(width, of=lambda g: g):
         return pl.BlockSpec((1, _Q, width),
-                            lambda b, g, c: (b, chunk_of(c), g))
+                            lambda b, g, c: (b, chunk_of(c), of(g)))
     return (cols(R * P),
             pl.BlockSpec((1, 1, R, _Q),
                          lambda b, g, c: (b, g, 0, chunk_of(c))),
             pl.BlockSpec((1, R, 1), lambda b, g, c: (g, 0, 0)),
-            cols(N),
+            # (``g // 1`` is the same map but not the same jaxpr: a group
+            # a step keeps the map it had, so that its kernels stay the
+            # ones on record, tests/test_chip_compile.py)
+            cols(N) if blocks == 1 else cols(N, lambda g: g // blocks),
             pl.BlockSpec((1, 1, R * P), lambda b, g, c: (g, 0, 0)),
             pl.BlockSpec((1, 1, 1, N, R * P),
-                         lambda b, g, c: (b, chunk_of(c), g, 0, 0)))
+                         lambda b, g, c: (b, chunk_of(c), g, 0, 0)),
+            cols(N))
 
 
 _PARAMS = pltpu.CompilerParams(
@@ -334,23 +369,24 @@ _PARAMS = pltpu.CompilerParams(
 
 def _fwd(x, dt, A, Bm, Cm, D, with_starts):
     """-> (y [B, T, H, P] in x's type, the state each chunk starts from
-    [B, c, G, N, R*P] float32 or None)."""
+    [B, c, H / R, N, R*P] float32 or None)."""
     B, T, H, P = x.shape
     G, N = Bm.shape[2:]
-    R = H // G
+    R = _heads_a_step(H // G)
     args = _operands(x, dt, A, Bm, Cm, D)
     Tp = args[0].shape[1]
     nc = Tp // _Q
-    xs, dts, As, bs, Ds, states = _specs(R, P, N, lambda c: c)
+    xs, dts, As, bs, Ds, states, _ = _specs(R, P, N, H // G // R,
+                                            lambda c: c)
     out_specs = [xs]
     out_shape = [jax.ShapeDtypeStruct((B, Tp, H * P), x.dtype)]
     if with_starts:
         out_specs.append(states)
         out_shape.append(
-            jax.ShapeDtypeStruct((B, nc, G, N, R * P), jnp.float32))
+            jax.ShapeDtypeStruct((B, nc, H // R, N, R * P), jnp.float32))
     out = pl.pallas_call(
         functools.partial(_fwd_kernel, R=R, P=P),
-        grid=(B, G, nc),
+        grid=(B, H // R, nc),
         in_specs=[xs, dts, As, bs, bs, Ds],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -366,38 +402,50 @@ def _fwd(x, dt, A, Bm, Cm, D, with_starts):
 def _bwd(x, dt, A, Bm, Cm, D, starts, dy):
     B, T, H, P = x.shape
     G, N = Bm.shape[2:]
-    R = H // G
+    R = _heads_a_step(H // G)
+    blocks = H // G // R
     f32 = jnp.float32
     args = _operands(x, dt, A, Bm, Cm, D)
     Tp = args[0].shape[1]
     nc = Tp // _Q
     dy = jnp.pad(dy, ((0, 0), (0, Tp - T), (0, 0), (0, 0))) if Tp > T else dy
-    xs, dts, As, bs, Ds, states = _specs(R, P, N, lambda c: nc - 1 - c)
+    xs, dts, As, bs, Ds, states, dbs = _specs(R, P, N, blocks,
+                                              lambda c: nc - 1 - c)
 
-    def group(*block):
-        return pl.BlockSpec((1, 1) + block, lambda b, g, c: (b, g, 0, 0))
+    def block(*shape):
+        return pl.BlockSpec((1, 1) + shape, lambda b, g, c: (b, g, 0, 0))
 
+    # a group in one step: dB and dC in their operands' type, as the XLA
+    # form gives them; in blocks: a block's part in float32, summed here
+    part = Bm.dtype if blocks == 1 else f32
     dx, ddt, dB, dC, dA, dD = pl.pallas_call(
         functools.partial(_bwd_kernel, R=R, P=P),
-        grid=(B, G, nc),
+        grid=(B, H // R, nc),
         in_specs=[xs, dts, As, bs, bs, Ds, xs, states],
-        out_specs=[xs, dts, bs, bs, group(R, _Q), group(1, R * P)],
+        out_specs=[xs, dts, dbs, dbs, block(R, _Q), block(1, R * P)],
         out_shape=[jax.ShapeDtypeStruct((B, Tp, H * P), x.dtype),
-                   jax.ShapeDtypeStruct((B, G, R, Tp), f32),
-                   jax.ShapeDtypeStruct((B, Tp, G * N), Bm.dtype),
-                   jax.ShapeDtypeStruct((B, Tp, G * N), Cm.dtype),
-                   jax.ShapeDtypeStruct((B, G, R, _Q), f32),
-                   jax.ShapeDtypeStruct((B, G, 1, R * P), f32)],
+                   jax.ShapeDtypeStruct((B, H // R, R, Tp), f32),
+                   jax.ShapeDtypeStruct((B, Tp, H // R * N), part),
+                   jax.ShapeDtypeStruct((B, Tp, H // R * N), part),
+                   jax.ShapeDtypeStruct((B, H // R, R, _Q), f32),
+                   jax.ShapeDtypeStruct((B, H // R, 1, R * P), f32)],
         scratch_shapes=[pltpu.VMEM((N, R * P), f32)],
         compiler_params=_PARAMS,
         interpret=_interpret(),
         name=scopes.SSD_BWD,
     )(*args, dy.reshape(B, Tp, H * P), starts)
+
+    def of_group(d, like):
+        if blocks == 1:
+            return d.reshape(B, Tp, G, N)[:, :T]
+        return jnp.sum(d.reshape(B, Tp, G, blocks, N)[:, :T], 3
+                       ).astype(like.dtype)
+
     return (dx.reshape(B, Tp, H, P)[:, :T],
             jnp.swapaxes(ddt.reshape(B, H, Tp), 1, 2)[:, :T].astype(dt.dtype),
             jnp.sum(dA, (0, 3)).reshape(H).astype(A.dtype),
-            dB.reshape(B, Tp, G, N)[:, :T], dC.reshape(B, Tp, G, N)[:, :T],
-            jnp.sum(dD.reshape(B, G, R, P), (0, 3)).reshape(H)
+            of_group(dB, Bm), of_group(dC, Cm),
+            jnp.sum(dD.reshape(B, H // R, R, P), (0, 3)).reshape(H)
             .astype(D.dtype))
 
 

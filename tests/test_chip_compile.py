@@ -625,15 +625,84 @@ def test_nemotron_h_cell_step_fits_the_chip(one_chip, monkeypatch):
             scopes.SSM_MEAN_DECAY: ((mixers,), jnp.float32)}
     # PR 39's tree, the scan in XLA, compiled by this test: 15,272,153,600
     assert 0.25 * 16 * 2 ** 30 < footprint < 15_272_153_600, footprint
+    # the kernels as on record (PR 40's, compiled by this test at PR 42):
+    # head blocks (PR 43) leave a group of 8 heads one step's, the same
+    # jaxpr and, source lines dropped, the same optimized step
+    vmem = dict(_kernel_vmem(compiled))
+    assert {size for name, size in vmem.items()
+            if name.startswith(scopes.SSD_FWD)} == {2_560_000}
+    assert {size for name, size in vmem.items()
+            if name.startswith(scopes.SSD_BWD)} == {4_997_120}
+    # under conftest's matmul precision; the parent reads the same here
+    assert footprint == 15_083_288_064
 
 
-def test_ssd_scan_fwd_bwd(one_chip, monkeypatch):
-    """The scan's two kernels alone at the Nemotron cell's shape (two rows
-    of 8192, 64 heads of 64 in 8 groups on a state of 128): both compile
-    for the described v5e, inside Mosaic's default scoped VMEM."""
+def test_granite_cell_step_fits_the_chip(one_chip, monkeypatch):
+    """The granite_4_0_h_micro.train_bf16_b1_s8192 cell's whole step
+    (MMMMM*MMMM: nine Mamba-2 mixers whose 64 heads share ONE group of B
+    and C, one grouped-query attention layer on 32 / 8 heads of 64 at the
+    family's multiplier, a SwiGLU of 8192 after every mixer, the tied
+    head over an eighth of the vocabulary; one row of 8192) for the
+    described v5e: it compiles, every scan runs as ``ssd_fwd`` /
+    ``ssd_bwd`` (a mixer's forward, its replay and its backward; none
+    falls to the XLA form), the attention as ONE flash forward kernel and
+    one backward walk at a head shape no other cell runs ([1, 8192, 32,
+    64] on [1, 8192, 8, 64]), and the footprint is the one on record,
+    under 15.75 GiB."""
+    from paddle_tpu.observability import scopes
+    from paddle_tpu.utils import monitor
+    monitor.stat_reset()
+    compiled, n, cfg, mix, footprint, step = _cell_step(
+        one_chip, monkeypatch, "granite_4_0_h_micro.train_bf16_b1_s8192",
+        ("flash_attention", "ssd_scan"))
+    assert n == cfg["parameters"] == 772_160_448
+    assert (cfg["hidden_size"], mix["batch"], mix["seq"]) == (2048, 1, 8192)
+    assert (cfg["mamba_n_heads"], cfg["mamba_n_groups"]) == (64, 1)
+    kinds = cfg["layer_types"]
+    mixers, attention = kinds.count("mamba"), kinds.count("attention")
+    assert (mixers, attention) == (9, 1)
+    text = compiled.as_text()
+    _one_backward_kernel_a_block(text, attention)
+    _head_made_its_gradients_in_the_forward_pass(text, calls=1)
+    stats = monitor.all_stats()
+    assert _kept(stats) == {scopes.ATTN_OUT: 1, scopes.ATTN_LSE: 1}
+    assert stats["pallas.selected.flash_attention"] >= 1
+    assert "attention.xla_path" not in stats
+    assert (_kernel_count(text, scopes.SSD_FWD),
+            _kernel_count(text, scopes.SSD_BWD)) == (2 * mixers, mixers)
+    assert stats["pallas.selected.ssd_scan"] >= mixers
+    assert "ssd_scan.xla_path" not in stats
+    # the feed-forward layers run under their scope, in every phase
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    under = [nm for nm in names if scopes.FFN in nm.split("/")]
+    assert any("rematted_computation" in nm for nm in under)
+    assert any("transpose(" in nm for nm in under)
+    assert {k: (v.shape, v.dtype) for k, v in step._counter_spec.items()} \
+        == {scopes.SSM_STATE_SHARE: ((mixers,), jnp.float32),
+            scopes.SSM_MEAN_DECAY: ((mixers,), jnp.float32)}
+    # 13,298,342,912 bytes as this test compiled it in PR 43 (under
+    # conftest's matmul precision: not the benchmark's program to the
+    # byte), 10.81 GB of it the state at 14 bytes a parameter
+    assert abs(footprint - 13_298_342_912) < 64 * 2 ** 20, footprint
+    assert 0.25 * 16 * 2 ** 30 < footprint < 15.75 * 2 ** 30
+    # a block of 16 heads of the one group: twice what a group of 8 takes
+    vmem = dict(_kernel_vmem(compiled))
+    assert all(size < 16 * 2 ** 20 for name, size in vmem.items()
+               if name.startswith("ssd_")), vmem
+
+
+@pytest.mark.parametrize("x_shape,groups", [
+    ((2, 8192, 64, 64), 8),     # the Nemotron cell's: a group a step
+    ((1, 8192, 64, 64), 1),     # the Granite cell's: 4 head blocks a group
+], ids=["nemotron_cell", "granite_cell"])
+def test_ssd_scan_fwd_bwd(one_chip, monkeypatch, x_shape, groups):
+    """The scan's two kernels alone at the two state-space cells' shapes
+    (rows of 8192, 64 heads of 64 on a state of 128; 8 groups of 8 heads,
+    and ONE group of 64 walked in blocks of 16): both compile for the
+    described v5e, inside Mosaic's default scoped VMEM."""
     ssd = importlib.import_module("paddle_tpu.ops.pallas.ssd_scan")
     monkeypatch.setattr(ssd, "_interpret", lambda: False)
-    x_shape, b_shape = (2, 8192, 64, 64), (2, 8192, 8, 128)
+    b_shape = x_shape[:2] + (groups, 128)
     assert ssd.ssd_scan_supported(x_shape, b_shape, jnp.bfloat16, 128)
     assert not ssd.ssd_scan_supported(x_shape, b_shape, jnp.bfloat16, 256)
 
@@ -649,7 +718,7 @@ def test_ssd_scan_fwd_bwd(one_chip, monkeypatch):
     assert len(kernels) == 2 and "ssd_fwd" in kernels[0], kernels
     assert "ssd_bwd" in kernels[1], kernels
     vmem = _kernel_vmem(compiled)
-    print("ssd_scan at [2, 8192, 64, 64] / [2, 8192, 8, 128]: "
+    print(f"ssd_scan at {list(x_shape)} / {list(b_shape)}: "
           + ", ".join(f"{name} {size} bytes of VMEM" for name, size in vmem))
     assert len(vmem) == 2 and all(size < 16 * 2 ** 20 for _, size in vmem)
 

@@ -117,7 +117,18 @@ _KERNEL_SHAPES = {
     "padded": (1, 300, 16, 64, 2, 128),         # T no multiple of the chunk
     "one_group": (1, 200, 8, 64, 1, 128),
     "wide_heads": (1, 130, 8, 128, 1, 256),     # a head a slab, a state of
-}                                               # two lane tiles
+                                                # two lane tiles
+    # a group of more heads than a step takes is walked in head blocks:
+    # one step's at 16 heads, blocks of 16 at 32, at 64 (granite-4.0-h's
+    # one group of 64) and at two groups of 48, of 8 at one group of 24,
+    # dB and dC summed over a group's blocks
+    "one_group_of_16": (1, 200, 16, 64, 1, 128),
+    "one_group_of_24": (1, 130, 24, 64, 1, 128),
+    "one_group_of_32": (1, 200, 32, 64, 1, 128),
+    "one_group_of_64": (2, 200, 64, 64, 1, 128),
+    "two_groups_of_48": (1, 130, 96, 64, 2, 128),
+    "eight_groups_of_8": (1, 200, 64, 64, 8, 128),  # Nemotron's: one step
+}
 
 
 def _low(args, ct, dtype):
@@ -160,6 +171,26 @@ def test_the_kernels_are_the_xla_form(kernels_on, shape, dtype):
         else:
             err = float(jnp.max(jnp.abs(g - w)))
             assert err < 0.02 * float(jnp.max(jnp.abs(w))), (name, err)
+
+
+@pytest.mark.parametrize("heads,groups,a_step,takes", [
+    (64, 8, 8, True),       # Nemotron's: a group a step
+    (16, 1, 16, True),      # the largest group one step takes
+    (40, 1, 8, True),       # 16 does not divide it: blocks of 8
+    (64, 1, 16, True),      # Mamba-2's default, granite-4.0-h's
+    (96, 2, 16, True),
+    (44, 1, 8, False),      # no whole sublane tiles of heads
+    (4, 1, 8, False),
+], ids=lambda v: str(v))
+def test_a_group_is_one_step_or_blocks_of_sixteen_heads(heads, groups, a_step,
+                                                       takes):
+    """The gate and the wrapper agree on the heads a grid step takes: 16
+    where that divides a group, else 8; a group of more is walked in
+    blocks."""
+    assert kernels._heads_a_step(heads // groups) == a_step
+    assert kernels.ssd_scan_supported(
+        (1, 256, heads, 64), (1, 256, groups, 128), jnp.bfloat16,
+        128) is takes
 
 
 def test_the_kernels_carry_the_state_across_chunks(kernels_on):
