@@ -38,11 +38,17 @@ def prescale(x, scale):
     return (x.astype(jnp.float32) * scale).astype(x.dtype)
 
 
+def lead(ref):
+    """The index of a block's leading unit dimensions: a kernel body sees
+    [rows, width] under as many as its launcher's addressing leaves (two
+    of a [B, H, L, D] array, one of [B, L, H*D])."""
+    return (0,) * (len(ref.shape) - 2)
+
+
 def rows(ref, j, block):
     """Rows [j*block, (j+1)*block) of a sequence staged whole, under any
     number of leading unit dimensions."""
-    lead = (0,) * (len(ref.shape) - 2)
-    return ref[(*lead, pl.ds(pl.multiple_of(j * block, block), block),
+    return ref[(*lead(ref), pl.ds(pl.multiple_of(j * block, block), block),
                 slice(None))]
 
 
@@ -131,7 +137,7 @@ def dq_zero(accs):
     block] a dQ output: zeroed at the head's first key block, added to
     a pair, emitted at its last (the walk's ``pl.when``s say where)."""
     for acc in accs:
-        acc[...] = jnp.zeros_like(acc)
+        acc[...] = jnp.zeros(acc.shape, acc.dtype)
 
 
 def dq_add(accs, keys, i, ds):
@@ -142,13 +148,14 @@ def dq_add(accs, keys, i, ds):
 
 
 def dq_emit(refs, accs, num_q, block_q, scale):
-    """The head's dQ blocks [1, 1, L, width] from their accumulators."""
+    """The head's dQ blocks [..., L, width] from their accumulators."""
     def emit(i, carry):
         at = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
         # s was taken against scale * q: the chain rule's scale, once,
         # and each [D, BQ] block transposed once a head
         for ref, acc in zip(refs, accs, strict=True):
-            ref[0, 0, at, :] = (acc[i] * scale).T.astype(ref.dtype)
+            ref[(*lead(ref), at, slice(None))] = (
+                acc[i] * scale).T.astype(ref.dtype)
         return carry
 
     jax.lax.fori_loop(0, num_q, emit, 0)
@@ -172,5 +179,4 @@ def rows8(x):
 
 def write_row8(ref, row):
     """A kernel's [1, block] row into its (8, block) block."""
-    ref[(0,) * (len(ref.shape) - 2)] = jnp.broadcast_to(
-        row, (8, row.shape[-1]))
+    ref[lead(ref)] = jnp.broadcast_to(row, (8, row.shape[-1]))

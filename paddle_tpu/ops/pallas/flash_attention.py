@@ -7,8 +7,30 @@ ir/multihead_matmul_fuse_pass.cc): one kernel keeps Q/K/V blocks in VMEM,
 streams KV, and carries the online-softmax running max/sum so the [L, L]
 score matrix never touches HBM.
 
-Layout: [B, L, H, D] in (paddle layout), transposed once to [B, H, L, D]
-around the kernel.  Keys and values may have fewer heads than the
+Layout: [B, L, H, D] in and out (paddle layout).  The kernels take an
+operand AS IT LIES, [B, L, H, D] under the name [B, L, H*D] with a head
+chosen by the index map along the lane axis, or in a [B, H, L, D] copy
+made once around the call (`_as_it_lies`):
+- heads of 64 whose keys have a head each, in even number: everything as
+  it lies, two neighbouring heads to a 128-lane block and a grid step,
+  the body over each head's 64 lanes of its blocks in turn
+  (`_each_of_a_pair`);
+- heads whose D and Dv are multiples of 128, a key head each, through
+  `flash_attention`: v, out, dO and dv as they lie (projections write
+  and read them untouched), q, k, dq and dk in their copies: where an
+  XLA fusion WRITES a [B, L, H, D] value (a rotation, a slice of a
+  wider projection) its tiles hold a position's
+  heads along the sublanes and want a `reshape` pass of their own to
+  become [B, L, H*D], while the same fusion writes [B, H, L, D] order at
+  no cost (`_QK_LIE_AT_128`, with the chip's table);
+- every other call (96-wide heads, heads in groups, 64-wide heads in odd
+  number, the shared-key entry, the ring's blocks, EVA's folded windows):
+  everything in copies, as before, the parent's program.
+One body and one arithmetic whatever the addressing (a body sees
+[rows, width] under one or two unit dimensions); lse and delta are
+[B, H, 8, L] throughout.
+
+Keys and values may have fewer heads than the
 queries (grouped-query attention: ``Hk`` divides ``H``, query head h
 reads key/value head ``h // (H / Hk)``): the kernels' index maps send a
 group's query heads to the one key/value head, which is never repeated
@@ -37,8 +59,12 @@ sweep is PR 25's): [8, 16, 2048, 96] bf16 causal takes 1.48 ms forward
 and 2.90 ms backward (1.73 dq + 2.46 dk/dv before); [64, 12, 512, 64]
 bf16 non-causal 0.90 and 1.46 ms (1.06 + 1.25); [2, 32, 8192, 128 + 64
 shared] with 128-wide values, causal, 13.89 and 27.01 ms (16.74 +
-22.39).  Whether XLA's attention or this kernel is taken at a length is
-a measured constant, `_KERNEL_FROM` below, beside the chip table it came
+22.39); [2, 16, 4096, 128] causal 1.381 and 2.487 ms with every operand
+in a [B, H, L, D] copy, 1.392 and 2.499 with v, out, dO and dv as they
+lie (a staged V column is 4,096 rows of 256 bytes at a stride of 4,096:
++0.6 %), 1.404 and 2.526 with q and k lying too (PERF.md section 6,
+PR 44: 32 calls a step).  Whether XLA's attention or this kernel is
+taken at a length is a measured constant, `_KERNEL_FROM` below, beside the chip table it came
 from: the kernel from 512 positions up, for every head size, mask and
 dropout rate measured (PERF.md, PR 27).
 
@@ -63,9 +89,9 @@ from jax.experimental import pallas as pl
 
 from ...observability import scopes
 from .attention_tiles import (BLOCK, block_loops, delta as _delta, dq_add,
-                              dq_emit, dq_zero, kv_spans, mask_diagonal,
-                              online_step, p_ds, prescale, q_spans, rows,
-                              rows8, write_row8)
+                              dq_emit, dq_zero, kv_spans, lead,
+                              mask_diagonal, online_step, p_ds, prescale,
+                              q_spans, rows, rows8, write_row8)
 from .support import (NEG_INF, count_kernel_selection, dot as _dot,
                       interpret_mode as _interpret, name_residuals, pltpu,
                       smem_scalar_spec as _smem_scalar_spec)
@@ -256,7 +282,7 @@ def _mask_scores(s, q_off_ref, k_off_ref, qi, j, block_q, block_k):
     return jnp.where(q_pos >= k_pos, s, NEG_INF)
 
 
-def _dropout_keep(seed_ref, qi, j, shape, dropout_p):
+def _dropout_keep(seed_ref, qi, j, shape, dropout_p, of_pair=None):
     """Tile keep-mask from the Pallas TPU PRNG, seeded on
     (user seed, b, h, q-block, k-block) so the backward kernel reproduces
     the forward's mask exactly (both hold the tile as
@@ -265,6 +291,8 @@ def _dropout_keep(seed_ref, qi, j, shape, dropout_p):
     against the p-quantile threshold."""
     b = pl.program_id(0)
     h = pl.program_id(1)
+    if of_pair is not None:         # the grid's second axis counts pairs
+        h = 2 * h + of_pair
     # Mosaic accepts at most 2 seed words: fold (b,h) and (qi,j) — the
     # 65599 strides keep tile seeds distinct for any h, j < 65599
     s1 = seed_ref[0, 0] ^ (b * 65599 + h)
@@ -276,7 +304,7 @@ def _dropout_keep(seed_ref, qi, j, shape, dropout_p):
     return v >= t
 
 
-def _dropout(seed_ref, qi, j, dropout_p):
+def _dropout(seed_ref, qi, j, dropout_p, of_pair=None):
     """Tile (``qi``, ``j``)'s hook for `online_step` and `p_ds`, None with
     dropout off: p (unnormalized probs) -> p * keep / (1 - p_q).  The
     softmax denominator keeps the UNdropped sum, which reproduces dropout
@@ -288,7 +316,7 @@ def _dropout(seed_ref, qi, j, dropout_p):
         return jnp.zeros_like
 
     def drop(p):
-        keep = _dropout_keep(seed_ref, qi, j, p.shape, dropout_p)
+        keep = _dropout_keep(seed_ref, qi, j, p.shape, dropout_p, of_pair)
         return jnp.where(keep, p * (65536.0 / (65536 - t)), 0.0)
     return drop
 
@@ -329,14 +357,14 @@ def _once_a_shape(*static_argnums):
 
 def _fwd_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref, *rest,
                 scale, block_k, seq_k, causal, block_q, aligned, dropout_p,
-                shared):
+                shared, of_pair=None):
     if shared:
         qr_ref, kr_ref, o_ref, lse_ref = rest
-        qr = prescale(qr_ref[0, 0], scale)                # [BQ, Dr]
+        qr = prescale(qr_ref[lead(qr_ref)], scale)        # [BQ, Dr]
     else:
         o_ref, lse_ref = rest
     qi = pl.program_id(2)
-    q = prescale(q_ref[0, 0], scale)                      # [BQ, D]
+    q = prescale(q_ref[lead(q_ref)], scale)               # [BQ, D]
     bq = q.shape[0]
     m = jnp.full((1, bq), NEG_INF, jnp.float32)
     l = jnp.zeros((1, bq), jnp.float32)
@@ -350,12 +378,13 @@ def _fwd_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref, *rest,
                    block_k, (rows(kr_ref, j, block_k), qr)
                    if shared else None)                   # [BK, BQ]
         return online_step(carry, s, v, may_hide_query=mask == "positions",
-                           drop=_dropout(seed_ref, qi, j, dropout_p))
+                           drop=_dropout(seed_ref, qi, j, dropout_p,
+                                         of_pair))
 
     m, l, acc = block_loops(body, (m, l, acc), num_kv, causal, aligned,
                             kv_spans(qi, block_q, block_k, num_kv))
     l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0, 0] = (acc / l_safe).T.astype(o_ref.dtype)
+    o_ref[lead(o_ref)] = (acc / l_safe).T.astype(o_ref.dtype)
     write_row8(lse_ref, jnp.where(l > 0, m + jnp.log(l_safe), NEG_INF))
 
 
@@ -365,7 +394,149 @@ def _kv_head(h, group):
     return h if group == 1 else h // group
 
 
-def _qkv_fwd_specs(block_q, Lk, D, Dv, Dr=0, group=1):
+# How a kernel finds a head's rows, from the static shapes alone (the
+# body sees [rows, width] either way, `attention_tiles.lead`):
+# - as it lies: the operand is [B, L, H * width], which is
+#   [B, L, H, width] as the projections leave it under another name; a
+#   head is the lane block ``h``: block (1, rows, width) at
+#   (b, row block, h).  A lane block is whole lane tiles: a head whose D
+#   and Dv are multiples of 128, or two neighbouring heads of 64 where
+#   keys and queries have a head each, one grid step a pair
+#   (`_each_of_a_pair`).  Such an operand keeps that one form from the
+#   public entry to the kernels (the custom-vjp cores, their residuals,
+#   `delta`, a group's sum), so no [B, L, H, width] value is laid out for
+#   the kernels' sake.
+# - transposed: the operand is a [B, H, L, width] copy, block
+#   (1, 1, rows, width) at (b, h, row block, 0).
+# ``lies`` = ``(H, whether q and k lie too)`` says which: None, every
+# operand transposed (every other width, heads in groups, the shared-key
+# call, what a caller builds itself: EVA's folded windows, the ring's
+# blocks); else v, out, dO and dv lie and `_qk(lies)` answers for q, k,
+# dq and dk.
+
+# Do q and k (and dq, dk) of 128-wide heads lie too?  Not where something
+# rotates or slices them on their way here: XLA writes such a fusion's
+# result straight into [B, H, L, D] order (the transposed addressing then
+# costs q and k nothing), while its [B, L, H, D] tiles want a `reshape`
+# pass of their own to become [B, L, H*D].  v, out, dO and dv come from
+# and go to projections untouched, in every cell.  The Ouro cell's step
+# ([2, 4096, 16, 128] causal, 32 calls, q and k out of a rotation), ms on
+# one v5e (PERF.md section 6, PR 44):
+#
+#                            step      attention scope   rope    kernels
+#   all transposed (PR 43)   1,024.13  149.11            28.90   123.78
+#   all as they lie          1,032.00  130.06            45.16   125.74
+#   q, k in their copies     1,007.05  138.26            26.20   124.49
+#
+# A call cannot see who wrote its operands, so one answer serves every
+# 128-wide call.  Heads of 64 in pairs lie whole: BERT's come straight
+# from projections, and a [B, H, L, 64] copy is laid out as 128 lanes,
+# twice its bytes.
+_QK_LIE_AT_128 = False
+
+
+def _as_it_lies(q_shape, k_shape, v_shape):
+    """``(H, whether q and k lie too)`` where a call's [B, L, H, ...]
+    operands are taken as they lie, else None.  Keys with a head each
+    only: where a group of query heads shares one, k and v are a fraction
+    of q and only out and dO could lie, and the chip read Nemotron's step
+    (32 on 2 at 8192) 1.1 ms SLOWER for it (PERF.md section 6, PR 44)."""
+    H, Hk, D, Dv = q_shape[2], k_shape[2], q_shape[3], v_shape[3]
+    if H != Hk:
+        return None
+    if D == Dv == 64 and H % 2 == 0:
+        return H, True
+    if D % 128 == 0 and Dv % 128 == 0:
+        return H, _QK_LIE_AT_128
+    return None
+
+
+def _qk(lies):
+    """``lies`` as it holds for q, k, dq and dk."""
+    return lies if lies and lies[1] else None
+
+
+def _heads_a_step(lies, D):
+    """Heads whose lanes are one block: two of 64 as they lie."""
+    return 2 if lies and D == 64 else 1
+
+
+class _Part:
+    """``size`` entries from ``start`` along ``axis`` of a kernel's ref, as
+    the body indexes a ref: the part's place joins the index of each load
+    and store (a ref sliced ahead of them would have to be whole tiles;
+    a load or a store at half a lane tile need not)."""
+
+    def __init__(self, ref, axis, start, size):
+        self.ref, self.axis, self.start, self.size = ref, axis, start, size
+        self.dtype = ref.dtype
+        self.shape = ref.shape[:axis] + (size,) + ref.shape[axis + 1:]
+
+    def _at(self, index):
+        index = index if isinstance(index, tuple) else (index,)
+        if index == (Ellipsis,):
+            index = ()
+        index = list(index) + [slice(None)] * (len(self.shape) - len(index))
+        along = index[self.axis]
+        index[self.axis] = (pl.ds(self.start, self.size)
+                            if along == slice(None) else self.start + along)
+        return tuple(index)
+
+    def __getitem__(self, index):
+        return self.ref[self._at(index)]
+
+    def __setitem__(self, index, value):
+        self.ref[self._at(index)] = value
+
+
+def _each_of_a_pair(kernel, scratch=0):
+    """``kernel`` for a grid step that holds two neighbouring 64-wide heads
+    in its 128-lane blocks: the body over each head in turn, on its part
+    of the step's refs: the head's lanes of a [1, rows, 128] block (of the
+    ``scratch`` accumulators [.., 128, block] at the end, where they are
+    sublanes), its row of a [1, 2, 8, L] block.  The same arithmetic on
+    the same 64-wide tiles as a head a step."""
+    def both(*refs, **static):
+        blocks = len(refs) - scratch
+        for r in range(2):
+            def part(ref):
+                if len(ref.shape) == 2:                   # a scalar in SMEM
+                    return ref
+                if len(ref.shape) == 3:
+                    return _Part(ref, 2, 64 * r, 64)
+                return _Part(ref, 1, r, 1)
+            kernel(*map(part, refs[:blocks]),
+                   *(_Part(acc, 1, 64 * r, 64) for acc in refs[blocks:]),
+                   of_pair=r, **static)
+    return both
+
+
+def _head_rows(lies, rows, width, at):
+    """The block of ``rows`` positions of one head, ``width`` wide;
+    ``at(b, h, j)`` -> (batch entry, head, row block)."""
+    if not lies:
+        return pl.BlockSpec((1, 1, rows, width),
+                            lambda b, h, j: (*at(b, h, j), 0))
+
+    def along_lanes(b, h, j):
+        entry, head, block = at(b, h, j)
+        return entry, block, head
+    return pl.BlockSpec((1, rows, width), along_lanes)
+
+
+def _dims(q, k, v, lies):
+    """-> (B, H, Hk, Lq, Lk, D, Dv) of operands in either form."""
+    if _qk(lies):
+        H, Hk, Lq, Lk = lies[0], lies[0], q.shape[1], k.shape[1]
+        D = q.shape[2] // H
+    else:
+        H, Hk, Lq, Lk, D = (q.shape[1], k.shape[1], q.shape[2], k.shape[2],
+                            q.shape[3])
+    return (q.shape[0], H, Hk, Lq, Lk, D,
+            v.shape[2] // H if lies else v.shape[3])
+
+
+def _qkv_fwd_specs(block_q, Lk, D, Dv, Dr=0, group=1, lies=None):
     """In-specs of the forward kernel; with ``Dr`` also the
     query part [B, H, Lq, Dr] that meets the shared key [B, 1, Lk, Dr],
     which is staged once a batch entry: its block index does not move
@@ -376,15 +547,16 @@ def _qkv_fwd_specs(block_q, Lk, D, Dv, Dr=0, group=1):
         pl.BlockSpec((1, 1, block_q, Dr), lambda b, h, i: (b, h, i, 0)),
         pl.BlockSpec((1, 1, Lk, Dr), lambda b, h, i: (b, 0, 0, 0)),
     ] if Dr else []
+
+    def kv_head(b, h, i):
+        return b, _kv_head(h, group), 0
     return [
         _smem_scalar_spec(),
         _smem_scalar_spec(),
         _smem_scalar_spec(),
-        pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, Lk, D),
-                     lambda b, h, i: (b, _kv_head(h, group), 0, 0)),
-        pl.BlockSpec((1, 1, Lk, Dv),
-                     lambda b, h, i: (b, _kv_head(h, group), 0, 0)),
+        _head_rows(_qk(lies), block_q, D, lambda b, h, i: (b, h, i)),
+        _head_rows(_qk(lies), Lk, D, kv_head),
+        _head_rows(lies, Lk, Dv, kv_head),
     ] + shared
 
 
@@ -393,36 +565,43 @@ def _shared_width(shared):
 
 
 def _fwd(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
-         dropout_p=0.0, shared=None):
+         dropout_p=0.0, shared=None, lies=None):
     """q [B, H, L, D], k [B, Hk, Lk, D], v [B, Hk, Lk, Dv] → (out
-    [B,H,Lq,Dv], lse [B,H,Lq]).  ``shared``: ``(qr [B, H, Lq, Dr], kr [B, 1, Lk, Dr])``,
-    a key part all heads share and the query part that meets it."""
-    _count_blocks(q.shape[2], k.shape[2], *blocks, causal, aligned)
+    [B,H,Lq,Dv], lse [B,H,Lq]); with ``lies`` q, k, v and out are
+    [B, L, H * ...] (the lse [B, H, Lq] either way).  ``shared``:
+    ``(qr [B, H, Lq, Dr], kr [B, 1, Lk, Dr])``, a key part all heads
+    share and the query part that meets it."""
+    _, _, _, Lq, Lk, _, _ = _dims(q, k, v, lies)
+    _count_blocks(Lq, Lk, *blocks, causal, aligned)
     return _fwd_call(q, k, v, q_off, k_off, seed, scale, causal, blocks,
-                     aligned, dropout_p, _interpret(), shared=shared)
+                     aligned, dropout_p, _interpret(), lies, shared=shared)
 
 
-@_once_a_shape(6, 7, 8, 9, 10, 11)
+@_once_a_shape(6, 7, 8, 9, 10, 11, 12)
 def _fwd_call(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
-              dropout_p, interpret, shared=None):
-    B, H, Lq, D = q.shape
-    Lk, Dv, Dr = k.shape[2], v.shape[3], _shared_width(shared)
+              dropout_p, interpret, lies, shared=None):
+    B, H, Hk, Lq, Lk, D, Dv = _dims(q, k, v, lies)
+    Dr = _shared_width(shared)
     block_q, block_k = blocks
     kernel = functools.partial(_fwd_kernel, scale=scale, block_k=block_k,
                                seq_k=Lk, causal=causal, block_q=block_q,
                                aligned=aligned, dropout_p=dropout_p,
                                shared=bool(shared))
+    group, n = H // Hk, _heads_a_step(lies, D)
+    if n == 2:
+        kernel, H, D, Dv = _each_of_a_pair(kernel), H // 2, 2 * D, 2 * Dv
     out, lse = pl.pallas_call(
         kernel,
         grid=(B, H, Lq // block_q),
-        in_specs=_qkv_fwd_specs(block_q, Lk, D, Dv, Dr, H // k.shape[1]),
+        in_specs=_qkv_fwd_specs(block_q, Lk, D, Dv, Dr, group, lies),
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, 8, block_q), lambda b, h, i: (b, h, 0, i)),
+            _head_rows(lies, block_q, Dv, lambda b, h, i: (b, h, i)),
+            pl.BlockSpec((1, n, 8, block_q), lambda b, h, i: (b, h, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Lq, Dv), q.dtype),
-            jax.ShapeDtypeStruct((B, H, 8, Lq), jnp.float32),
+            jax.ShapeDtypeStruct((B, Lq, H * Dv) if lies
+                                 else (B, H, Lq, Dv), q.dtype),
+            jax.ShapeDtypeStruct((B, H * n, 8, Lq), jnp.float32),
         ],
         interpret=interpret,
         compiler_params=_staging(Lk, D + Dr, Dv, q.dtype),
@@ -437,7 +616,7 @@ def _fwd_call(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
 
 def _bwd_dkv_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
                     *rest, scale, block_q, seq_q, causal, block_k, aligned,
-                    dropout_p, shared, n_dq):
+                    dropout_p, shared, n_dq, of_pair=None):
     """The backward walk: k block ``kj`` of a head against the q blocks it
     sees, for its dK and dV.  Where the call asks for dQ too (``rest``
     then ends in ``n_dq`` dq outputs and as many accumulators, see
@@ -446,14 +625,14 @@ def _bwd_dkv_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
     second walk recomputes the scores, p and dP for it."""
     if shared:
         qr_ref, kr_ref, *rest = rest
-        kr = kr_ref[0, 0]                                 # [BK, Dr]
+        kr = kr_ref[lead(kr_ref)]                         # [BK, Dr]
     do_ref, lse_ref, delta_ref, dk_ref, dv_ref, *rest = rest
     if shared:
         dkr_ref, *rest = rest
     dq_refs, dq_accs = rest[:n_dq], rest[n_dq:]
     kj = pl.program_id(2)
-    k = k_ref[0, 0]                                       # [BK, D]
-    v = v_ref[0, 0]
+    k = k_ref[lead(k_ref)]                                # [BK, D]
+    v = v_ref[lead(v_ref)]
     dk = jnp.zeros(k.shape, jnp.float32)
     dv = jnp.zeros(v.shape, jnp.float32)
     # this head's part of the shared key's gradient
@@ -477,7 +656,7 @@ def _bwd_dkv_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
                    block_k, (kr, qr) if shared else None)
         # fwd tile (qi=i, j=kj): identical seed -> identical mask
         u, ds = p_ds(s, lse, do, v, delta, may_hide_query=mask == "positions",
-                     drop=_dropout(seed_ref, i, kj, dropout_p))
+                     drop=_dropout(seed_ref, i, kj, dropout_p, of_pair))
         dv = dv + _dot(u.astype(do.dtype), do, ((1,), (0,)))
         # against the pre-scaled q: dk needs no scale of its own
         ds = ds.astype(q.dtype)
@@ -490,10 +669,10 @@ def _bwd_dkv_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
 
     dk, dv, dkr = block_loops(body, (dk, dv, dkr), num_q, causal, aligned,
                               q_spans(kj, block_q, block_k, num_q))
-    dk_ref[0, 0] = dk.astype(dk_ref.dtype)
-    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+    dk_ref[lead(dk_ref)] = dk.astype(dk_ref.dtype)
+    dv_ref[lead(dv_ref)] = dv.astype(dv_ref.dtype)
     if shared:
-        dkr_ref[0, 0] = dkr
+        dkr_ref[lead(dkr_ref)] = dkr
 
     if dq_accs:
         @pl.when(kj == pl.num_programs(2) - 1)
@@ -502,20 +681,22 @@ def _bwd_dkv_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
 
 
 def bwd_dkv(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale, causal,
-            blocks, aligned, dropout_p, shared=None, with_dq=False):
+            blocks, aligned, dropout_p, shared=None, with_dq=False,
+            lies=None):
     """``with_dq``: what the caller needs of the walk.  `_bwd` takes dQ
     from it; EVA's windows (eva_attention.py) take dK and dV alone, their
     dQ comes with the summaries' gradients from a kernel of their own."""
-    _count_blocks(q.shape[2], k.shape[2], *blocks, causal, aligned)
+    _, _, _, Lq, Lk, _, _ = _dims(q, k, v, lies)
+    _count_blocks(Lq, Lk, *blocks, causal, aligned)
     return _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8,
                          scale, causal, blocks, aligned, dropout_p,
-                         _interpret(), with_dq, shared=shared)
+                         _interpret(), with_dq, lies, shared=shared)
 
 
-@_once_a_shape(9, 10, 11, 12, 13, 14, 15)
+@_once_a_shape(9, 10, 11, 12, 13, 14, 15, 16)
 def _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale,
                   causal, blocks, aligned, dropout_p, interpret, with_dq,
-                  shared=None):
+                  lies, shared=None):
     """-> (dk, dv), each QUERY head's part [B, H, Lk, ...] (k and v may
     have fewer heads, a group of query heads on each: `_bwd` sums the
     parts), with ``shared`` also each head's float32 part of the
@@ -523,53 +704,66 @@ def _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale,
     with both dqr).  A head's dQ block is its whole [Lq, D] under an index
     that does not move with the k block: it goes to HBM once a (batch,
     head), from float32 accumulators [Lq / block_q, D, block_q] that are
-    zeroed at the head's first k block."""
-    B, H, Lq, D = q.shape
-    Lk, Dv, Dr = k.shape[2], v.shape[3], _shared_width(shared)
+    zeroed at the head's first k block.  With ``lies`` q, k, v and dO come
+    and dk, dv and dq go [B, L, H * ...]; what belongs to a shared part
+    keeps [B, H, L, Dr]."""
+    B, H, Hk, Lq, Lk, D, Dv = _dims(q, k, v, lies)
+    Dr = _shared_width(shared)
     block_q, block_k = blocks
-    dq_widths = ([D] + ([Dr] if shared else [])) if with_dq else []
+    group, n = H // Hk, _heads_a_step(lies, D)
+    if n == 2:
+        H, D, Dv = H // 2, 2 * D, 2 * Dv
+    # (width, as it lies) of the dQ blocks: a shared part's stays [B, H, L, Dr]
+    dq_blocks = ([(D, _qk(lies))] + ([(Dr, None)] if shared else [])
+                 if with_dq else [])
+    dq_widths = [w for w, _ in dq_blocks]
+    kernel = functools.partial(
+        _bwd_dkv_kernel, scale=scale, block_q=block_q, seq_q=Lq,
+        causal=causal, block_k=block_k, aligned=aligned,
+        dropout_p=dropout_p, shared=bool(shared), n_dq=len(dq_widths))
+    if n == 2:
+        kernel = _each_of_a_pair(kernel, scratch=len(dq_widths))
 
-    def whole(width):
-        return pl.BlockSpec((1, 1, Lq, width), lambda b, h, j: (b, h, 0, 0))
+    def whole(width, lies=lies):
+        return _head_rows(lies, Lq, width, lambda b, h, j: (b, h, 0))
 
-    def part(width):
-        return pl.BlockSpec((1, 1, block_k, width),
-                            lambda b, h, j: (b, h, j, 0))
+    def part(width, lies=lies):
+        return _head_rows(lies, block_k, width, lambda b, h, j: (b, h, j))
 
-    group = H // k.shape[1]
+    def kv_rows(width, lies=lies):
+        return _head_rows(lies, block_k, width,
+                          lambda b, h, j: (b, _kv_head(h, group), j))
 
-    def kv_rows(width):
-        return pl.BlockSpec((1, 1, block_k, width),
-                            lambda b, h, j: (b, _kv_head(h, group), j, 0))
+    def shape(L, width, lies=lies):
+        return (B, L, H * width) if lies else (B, H, L, width)
 
     out = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
-                          seq_q=Lq, causal=causal, block_k=block_k,
-                          aligned=aligned, dropout_p=dropout_p,
-                          shared=bool(shared), n_dq=len(dq_widths)),
+        kernel,
         grid=(B, H, Lk // block_k),
         in_specs=[
             _smem_scalar_spec(),
             _smem_scalar_spec(),
             _smem_scalar_spec(),
-            whole(D),
-            kv_rows(D),
+            whole(D, _qk(lies)),
+            kv_rows(D, _qk(lies)),
             kv_rows(Dv),
-        ] + ([whole(Dr), pl.BlockSpec((1, 1, block_k, Dr),
-                                      lambda b, h, j: (b, 0, j, 0))]
+        ] + ([whole(Dr, None), pl.BlockSpec((1, 1, block_k, Dr),
+                                             lambda b, h, j: (b, 0, j, 0))]
              if shared else []) + [
             whole(Dv),
-            pl.BlockSpec((1, 1, 8, Lq), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, 8, Lq), lambda b, h, j: (b, h, 0, 0)),
+            pl.BlockSpec((1, n, 8, Lq), lambda b, h, j: (b, h, 0, 0)),
+            pl.BlockSpec((1, n, 8, Lq), lambda b, h, j: (b, h, 0, 0)),
         ],
-        out_specs=[part(D), part(Dv)] + ([part(Dr)] if shared else [])
-        + [whole(w) for w in dq_widths],
+        out_specs=[part(D, _qk(lies)), part(Dv)]
+        + ([part(Dr, None)] if shared else [])
+        + [whole(*block) for block in dq_blocks],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Lk, D), k.dtype),
-            jax.ShapeDtypeStruct((B, H, Lk, Dv), v.dtype),
+            jax.ShapeDtypeStruct(shape(Lk, D, _qk(lies)), k.dtype),
+            jax.ShapeDtypeStruct(shape(Lk, Dv), v.dtype),
         ] + ([jax.ShapeDtypeStruct((B, H, Lk, Dr), jnp.float32)]
              if shared else [])
-        + [jax.ShapeDtypeStruct((B, H, Lq, w), q.dtype) for w in dq_widths],
+        + [jax.ShapeDtypeStruct(shape(Lq, *block), q.dtype)
+           for block in dq_blocks],
         scratch_shapes=[pltpu.VMEM((Lq // block_q, w, block_q), jnp.float32)
                         for w in dq_widths],
         interpret=interpret,
@@ -580,20 +774,28 @@ def _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale,
 
 
 def _bwd(q, k, v, q_off, k_off, seed, out, lse, do, dlse, scale, causal,
-         blocks, aligned, dropout_p=0.0, shared=None):
+         blocks, aligned, dropout_p=0.0, shared=None, lies=None):
     """Full backward, one kernel -> (dq, dk, dv), or with ``shared``
     ((dq, dqr), (dk, each head's part of dkr), dv).  The lse cotangent
     folds into delta: with P = exp(S - lse) row-normalized,
     dS = P * (dP_rows - delta + dlse) since d lse / dS = P."""
     from ...utils import monitor
-    delta = _delta(do, out)                               # [B, H, Lq]
+    if lies:        # [B, Lq, H] -> [B, H, Lq]: 1 / Dv of a tensor moves
+        by_head = do.shape[:2] + (lies[0], -1)
+        delta = jnp.swapaxes(
+            _delta(do.reshape(by_head), out.reshape(by_head)), 1, 2)
+    else:
+        delta = _delta(do, out)                           # [B, H, Lq]
     if dlse is not None:
         delta = delta - dlse.astype(jnp.float32)
     monitor.stat_add("pallas.flash.bwd_fused")
     dk, dv, *rest = bwd_dkv(q, k, v, q_off, k_off, seed, do, rows8(lse),
                             rows8(delta), scale, causal, blocks, aligned,
-                            dropout_p, shared=shared, with_dq=True)
+                            dropout_p, shared=shared, with_dq=True,
+                            lies=lies)
     if not shared:
+        if lies:                # keys with a head each: the parts are it
+            return rest[0], dk, dv
         return rest[0], _sum_groups(dk, k), _sum_groups(dv, v)
     dkr, dq, dqr = rest
     return (dq, dqr), (dk, dkr), dv
@@ -612,28 +814,30 @@ def _sum_groups(parts, like):
 
 
 # ---------------------------------------------------------------------------
-# custom-vjp cores over [B, H, L, D]
+# custom-vjp cores over [B, H, L, D], or [B, L, H*D] as it lies
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
 def _flash(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
-           dropout_p):
+           dropout_p, lies):
+    """q, k, v and out [B, H, L, ...], or with ``lies`` [B, L, H * ...]."""
     out, _ = _fwd(q, k, v, q_off, k_off, seed, scale, causal, blocks,
-                  aligned, dropout_p)
+                  aligned, dropout_p, lies=lies)
     return out
 
 
 def _flash_fwd(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
-               dropout_p):
+               dropout_p, lies):
     out, lse = name_residuals(*_fwd(q, k, v, q_off, k_off, seed, scale,
-                                    causal, blocks, aligned, dropout_p))
+                                    causal, blocks, aligned, dropout_p,
+                                    lies=lies))
     return out, (q, k, v, q_off, k_off, seed, out, lse)
 
 
-def _flash_bwd(scale, causal, blocks, aligned, dropout_p, res, do):
+def _flash_bwd(scale, causal, blocks, aligned, dropout_p, lies, res, do):
     q, k, v, q_off, k_off, seed, out, lse = res
     dq, dk, dv = _bwd(q, k, v, q_off, k_off, seed, out, lse, do, None,
-                      scale, causal, blocks, aligned, dropout_p)
+                      scale, causal, blocks, aligned, dropout_p, lies=lies)
     return (dq, dk, dv, jnp.zeros_like(q_off), jnp.zeros_like(k_off),
             None)
 
@@ -709,6 +913,29 @@ def zero_seed():
     return jnp.zeros((1, 1), jnp.int32)
 
 
+def _count_addressing(lies):
+    """One count a call of a public entry, at trace time: which way its
+    kernels find a head (``flash_attention.as_it_lies`` /
+    ``flash_attention.transposed``)."""
+    from ...utils import monitor
+    monitor.stat_add("flash_attention.as_it_lies" if lies
+                     else "flash_attention.transposed")
+    return lies
+
+
+def _to_kernels(x, lies):
+    """[B, L, H, D] as the kernels take it: the same bytes under the name
+    [B, L, H * D], or a [B, H, L, D] copy."""
+    return x.reshape(*x.shape[:2], -1) if lies else jnp.swapaxes(x, 1, 2)
+
+
+def _from_kernels(out, lies):
+    """The kernels' result as [B, L, H, Dv]."""
+    if lies:
+        return out.reshape(*out.shape[:2], lies[0], -1)
+    return jnp.swapaxes(out, 1, 2)
+
+
 def flash_attention(q, k, v, causal: bool = False, scale=None,
                     block_q: int | None = None, block_k: int | None = None,
                     dropout_p: float = 0.0, seed=None):
@@ -737,12 +964,12 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     else:
         seed = jnp.asarray(seed, jnp.int32).reshape(1, 1)
     count_kernel_selection("flash_attention")
-    qt = jnp.swapaxes(q, 1, 2)      # [B, H, L, D]
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    out = _flash(qt, kt, vt, zero_off(), zero_off(), seed, scale,
-                 bool(causal), blocks, True, float(dropout_p))
-    return jnp.swapaxes(out, 1, 2)
+    lies = _count_addressing(_as_it_lies(q.shape, k.shape, v.shape))
+    q, k, v = (_to_kernels(q, _qk(lies)), _to_kernels(k, _qk(lies)),
+               _to_kernels(v, lies))
+    out = _flash(q, k, v, zero_off(), zero_off(), seed, scale,
+                 bool(causal), blocks, True, float(dropout_p), lies)
+    return _from_kernels(out, lies)
 
 
 def flash_attention_shared_key(q, q_shared, k, k_shared, v, scale=None,
@@ -761,6 +988,10 @@ def flash_attention_shared_key(q, q_shared, k, k_shared, v, scale=None,
     blocks = _resolve_blocks(block_q, block_k, q.shape[1], k.shape[1])
     count_kernel_selection("mla_attention")     # and the kernels it runs on
     count_kernel_selection("flash_attention")
+    # [B, H, L, D] copies throughout: v is a slice that a fusion writes,
+    # and with v, out, dO and dv lying the JoyAI cell's step read 1.1 ms
+    # slower and 304 MB larger (PERF.md section 6, PR 44)
+    _count_addressing(None)
     out = _flash_shared_key(
         jnp.swapaxes(q, 1, 2), jnp.swapaxes(q_shared, 1, 2),
         jnp.swapaxes(k, 1, 2), k_shared[:, None], jnp.swapaxes(v, 1, 2),
@@ -776,6 +1007,7 @@ def flash_attention_block(q_bhld, k_bhld, v_bhld, q_off, k_off, scale,
     give out=0, lse≈-inf — ready for logsumexp merging across rounds."""
     blocks = _resolve_blocks(block_q, block_k, q_bhld.shape[2],
                              k_bhld.shape[2])
+    _count_addressing(None)
     return _flash_with_lse(q_bhld, k_bhld, v_bhld, q_off, k_off, scale,
                            blocks)
 
