@@ -633,6 +633,40 @@ def check_ssd_scan(errs, shape=(2, 8192, 64, 64), groups=8, state=128,
             errs[f"{tag}_d{n}"] = _close(a, b, 4 * BF16_TOL, f"{tag} d{n}")
 
 
+def check_moe_combine(errs, n=8192, K=8, H=2048, F=768, held=16, total=128):
+    """One chunk of the Keye cell's expert layers (8192 tokens, 8 of 128
+    experts a token, 16 held, bfloat16 weights): with the small buffer the
+    sums over a token's slots run through the ``moe_combine`` kernel over
+    the rows in token order; with the full buffer they are XLA's gathers,
+    a row a slot.  The result and the five gradients, one against the
+    other."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import moe
+    x32 = _rnd(61, (n, H), jnp.float32)
+    gates, ids = jax.jit(lambda a, b: moe.moe_route(a, b, K))(
+        x32, _rnd(62, (H, total), jnp.float32, H ** -0.5))
+    local = jnp.where(ids < held, ids, held)
+    small = moe._small_buffer(n, K, held, total)
+    assert int(jnp.sum(local < held)) <= small < n * K
+    args = (x32.astype(jnp.bfloat16), gates,
+            *(_rnd(i, shape, jnp.bfloat16, shape[1] ** -0.5)
+              for i, shape in ((63, (held, H, F)), (64, (held, H, F)),
+                               (65, (held, F, H)))))
+    sel0 = selections()
+    got, want = (jax.jit(jax.value_and_grad(_sq(
+        lambda x, g, *w, rows=rows: moe.moe_experts(x, g, local, *w, rows)),
+        range(5)))(*args) for rows in (small, None))
+    assert selected_since(sel0).get("moe_combine"), selected_since(sel0)
+    errs["moe_combine_fwd"] = _close(got[0], want[0], BF16_TOL,
+                                     "moe_combine fwd")
+    for name, a, b in zip(("x", "gates", "w_gate", "w_up", "w_down"),
+                          got[1], want[1]):
+        errs[f"moe_combine_d{name}"] = _close(a, b, 4 * BF16_TOL,
+                                              f"moe_combine d{name}")
+
+
 def check_epilogue(errs, bert):
     """The recipe the Executor realises on BERT-base (proj + bias +
     residual + LayerNorm) at the two phases' rows: f32 as the static
@@ -750,6 +784,7 @@ def phase_kernels(clog, bert=BERT_BASE, serve=SERVE, engine=SERVE_ENGINE):
                             scale=1 / 64, tag="flash_grouped_d64")
         check_ssd_scan(errs)
         check_ssd_scan(errs, (1, 8192, 64, 64), groups=1, tag="ssd_one_group")
+        check_moe_combine(errs)
         check_epilogue(errs, bert)
         check_adam(errs, bert)
         check_paged(errs, serve, engine)

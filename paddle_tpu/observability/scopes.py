@@ -100,10 +100,13 @@ SPARSE_BWD_DKV = "sparse_bwd_dkv"  # the backward walk: dk, dv and dq; the
 SSD_FWD = "ssd_fwd"           # the state-space scan, chunk by chunk with
                               # the state in VMEM (ops/pallas/ssd_scan.py)
 SSD_BWD = "ssd_bwd"           # its backward, the chunks the other way
+MOE_COMBINE = "moe_combine"   # an expert layer's sums over a token's held
+                              # slots, rows in token order (under
+                              # MOE_DISPATCH, forward and backward)
 KERNELS = (FLASH_FWD, FLASH_BWD_DKV, EPILOGUE_FWD, EPILOGUE_BWD,
            FUSED_ADAM, PAGED_ATTENTION, COLLECTIVE_MATMUL_CHUNK, EVA_FWD,
            EVA_BWD_DQ, DSA_SCORES, DSA_THRESHOLD, DSA_KL, SPARSE_FWD,
-           SPARSE_BWD_DKV, SSD_FWD, SSD_BWD)
+           SPARSE_BWD_DKV, SSD_FWD, SSD_BWD, MOE_COMBINE)
 
 
 # -- values named for a rematerialisation policy -----------------------------

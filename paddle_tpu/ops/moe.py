@@ -37,16 +37,37 @@ gathered into one [rows, H] buffer, the expert's products run as
 grouped matmuls over it (``jax.lax.ragged_dot``: on a TPU XLA lowers it
 to a Mosaic kernel that walks only the tiles the group sizes cover, so
 its cost follows the load, not the buffer), and the result goes back by
-a gather and a gate-weighted sum over a token's slots.  The buffer has
-to take every assignment of every token (imbalance may send them all
-here), so the tokens are walked in chunks of one sequence of the batch;
-a chunk's buffer is a little over twice what an even router would send
-here where its assignments fit that, and ``tokens * top_k`` rows where
-they do not (``_chunk``: a ``lax.cond`` on the load, in the forward and
-in the backward pass alike).  Both gathers have hand-written
-transposes: a permutation's transpose is the gather by its inverse,
-where autodiff would emit a scatter-add of rows, which a TPU runs a row
-at a time.
+a gate-weighted sum over a token's slots.  The buffer has to take every
+assignment of every token (imbalance may send them all here), so the
+tokens are walked in chunks of one sequence of the batch; a chunk's
+buffer is a little over twice what an even router would send here where
+its assignments fit that, and ``tokens * top_k`` rows where they do not
+(``_chunk``: a ``lax.cond`` on the load, in the forward and in the
+backward pass alike).
+
+What each pass of a chunk costs by (n tokens, K = ``top_k``, a buffer of
+R rows of width H).  From tokens to rows, R gathered rows each: the
+tokens of the rows (``_dispatch``, run again in the backward pass) and
+the result's gradient of the rows, which ``dy`` and the gates' gradient
+are made from in one pass (``_combine_bwd``: one float32 number a row;
+only numbers go back to [n, K]).  From rows to tokens, the two sums over
+a token's slots (``_token_sums``: the gate-weighted one of the result,
+the plain one that is the transpose of ``_dispatch``; autodiff would emit
+a scatter-add of rows, which a TPU runs a row at a time): with a small
+buffer on a TPU the rows are put in token order, one more gather of R
+rows, and summed by a kernel that streams them once
+(ops/pallas/moe_combine.py), so **no pass of such a chunk reads or
+writes n * K rows of H**.  XLA's form of the sums gathers a row for
+every slot, held or not, and multiplies the absent ones by zero: n * K
+rows of which at most R are ever used.  It stays where the kernel does
+not (off a TPU, and the tests' reference) and for the full buffer, where
+R = n * K and the gather by ``pos`` IS the rows in token order (the
+kernel would be work on top of it, and a second [n * K, H] array).
+Numbers cost as rows do, about 7 ns each gathered on a v5e, so the
+kernel's form moves as few as it can: a row's gate rides the sort as a
+third operand, and again the sort into token order; ``pos`` (a scatter
+of n * K numbers) is not made; a row's ``dc`` is scattered to its own
+place (PERF.md section 6, PR 46, has every pass's time).
 
 Inside a step that collects device counters (``jit.TrainStep``) a call
 reports its load: ``moe.expert_load``, ``moe.chunk_assignments``,
@@ -57,12 +78,16 @@ counted and the program is what it was.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from ..observability import device_counters, scopes
 from ..utils import monitor
+from .pallas.moe_combine import (moe_combine, moe_combine_supported,
+                                 padded_rows)
+from .pallas.support import choose_kernel
 
 __all__ = ["moe_route", "moe_experts", "moe_forward"]
 
@@ -124,49 +149,106 @@ def _shared_expert(x, w_gate, w_up, w_down):
     return dot(a, w_down)
 
 
-# -- the two gathers, with gathers for transposes ---------------------------
+# -- between tokens and rows: two gathers, two sums ---------------------------
+
+class _Route(NamedTuple):
+    """Where a chunk's assignments lie in its buffer of R rows."""
+    at: jax.Array       # [R]: the assignment (token * K + slot) in row r
+    gate: jax.Array     # [R] float32: its gate, 0 where its expert is not
+    #                     held; a constant (``_combine`` makes the gradient)
+    live: jax.Array     # [n, K]: whether the slot's expert is held
+    pos: jax.Array | None    # [n, K]: the row of assignment (token, slot),
+    #                          where the sums run in XLA's form
+    order: tuple | None      # ``_token_order``'s, where through the kernel
+
+
+def _token_order(at, gate, held_rows, P):
+    """The rows of a buffer in token order, for the kernel's form of
+    ``_token_sums``.  ``at`` and ``gate`` [R] as in ``_Route``, the first
+    ``held_rows`` rows holding something.  An assignment's index IS
+    token-major, so sorting the rows by it puts them in token order, the
+    rows that hold nothing last, in whole tiles.  -> (the assignment in
+    each place [R'], P where none; its row [R']; its gate [R'])."""
+    R = at.shape[0]
+    pad = (0, padded_rows(R) - R)
+    r = jnp.arange(R + pad[1], dtype=jnp.int32)
+    at, by_tok, gate = jax.lax.sort(
+        (jnp.where(r < held_rows, jnp.pad(at, pad), P), r,
+         jnp.pad(gate, pad)), num_keys=1)
+    return at, jnp.minimum(by_tok, R - 1), gate
+
+
+def _token_sums(rows, c, route, dtype):
+    """rows [R, H] -> [n, H] of ``dtype``: each token's held slots' rows,
+    times the gates ``c`` [n, K] float32 where they are given, summed in
+    float32.  XLA's form gathers a row for every slot, n * K of them; the
+    kernel's walks the R rows in token order (ops/pallas/moe_combine.py)."""
+    if route.order is None:
+        picked = rows[route.pos]
+        picked = (jnp.where(route.live[..., None], picked, 0) if c is None
+                  else c[..., None] * picked.astype(jnp.float32))
+        return jnp.sum(picked, 1, dtype=jnp.float32).astype(dtype)
+    at, by_tok, gate = route.order
+    n, K = route.live.shape
+    # a place that holds nothing belongs to token n: the kernel skips it
+    return moe_combine(rows[by_tok], at // K, None if c is None else gate,
+                       n, dtype)
+
+
+def _tokens_of(route):
+    """[R]: the token whose slot row r holds."""
+    return route.at // route.live.shape[1]
+
 
 @jax.custom_vjp
-def _dispatch(x, tok, pos, live):
-    """x [n, H] -> rows [P, H]: row r is the token of sorted assignment
-    r.  ``pos`` [n, K] is the row of assignment (token, slot), ``live``
-    [n, K] whether its expert is held."""
-    return x[tok]
+def _dispatch(x, route):
+    """x [n, H] -> rows [R, H]: row r is the token of sorted assignment
+    r."""
+    return x[_tokens_of(route)]
 
 
-def _dispatch_fwd(x, tok, pos, live):
-    return x[tok], (pos, live)
+def _dispatch_fwd(x, route):
+    return _dispatch(x, route), route
 
 
-def _dispatch_bwd(res, d_rows):
-    pos, live = res
-    picked = jnp.where(live[..., None], d_rows[pos], 0)
-    return jnp.sum(picked, 1, dtype=jnp.float32).astype(d_rows.dtype), \
-        None, None, None
+def _dispatch_bwd(route, d_rows):
+    return _token_sums(d_rows, None, route, d_rows.dtype), None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _combine(y, c, tok, slot, pos):
-    """out[n] = sum_k c[n, k] * y[pos[n, k]]; ``c`` [n, K] float32 is the
+def _combine(y, c, route):
+    """out[t] = sum_k c[t, k] * y[pos[t, k]]; ``c`` [n, K] float32 is the
     gate, zero where the slot's expert is not held."""
-    return jnp.sum(c[..., None] * y[pos].astype(jnp.float32), 1)
+    return _token_sums(y, c, route, jnp.float32)
 
 
-def _combine_fwd(y, c, tok, slot, pos):
-    return _combine(y, c, tok, slot, pos), (y, c, tok, slot, pos)
+def _combine_fwd(y, c, route):
+    return _combine(y, c, route), (y, c, route)
 
 
 def _combine_bwd(res, d_out):
-    y, c, tok, slot, pos = res
-    d_tok = d_out[tok]                                    # [P, H] f32
-    dy = (c[tok, slot][:, None] * d_tok).astype(y.dtype)
+    y, c, route = res
+    d_tok = d_out[_tokens_of(route)]                      # [R, H] f32
+    dy = (route.gate[:, None] * d_tok).astype(y.dtype)
     # the gate's gradient, through the held slots only
-    dc = jnp.where(c != 0, jnp.sum(
-        d_out[:, None, :] * y[pos].astype(jnp.float32), -1), 0.0)
-    return dy, dc, None, None, None
+    if route.order is None:
+        dc = jnp.sum(d_out[:, None, :]
+                     * y[route.pos].astype(jnp.float32), -1)
+    else:
+        # one number a row, made in the pass that makes ``dy``, and only
+        # numbers go back to [n, K], each to its own place.  The barrier
+        # holds the two together: nothing else reads ``d_tok`` [R, H]
+        # float32, and left to itself XLA puts the sum off to the branch's
+        # end and keeps ``d_tok`` and ``y`` until then (the Keye step
+        # compiled 0.39 GB larger so)
+        dy, dc = jax.lax.optimization_barrier(
+            (dy, jnp.sum(d_tok * y.astype(jnp.float32), -1)))
+        dc = jnp.zeros(c.size, jnp.float32).at[route.at].set(
+            dc, unique_indices=True).reshape(c.shape)
+    return dy, jnp.where(c != 0, dc, 0.0), None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -201,37 +283,57 @@ def _experts(x, gates, local, w_gate, w_up, w_down, rows):
     held = w_up.shape[0]
     P = n * K
     R = P if rows is None else rows
+    # the full buffer keeps XLA's form: with every row in it a token's
+    # rows ARE the gather by ``pos``, and the kernel would be work on top
+    in_token_order = R < P and choose_kernel(
+        "moe_combine", moe_combine_supported(n, x.shape[1], x.dtype))
+    if R < P:
+        monitor.stat_set("moe.token_major_rows", R if in_token_order else P)
     with jax.named_scope(scopes.MOE_DISPATCH):
         flat = local.reshape(P)
-        order = jnp.argsort(flat, stable=True)            # held ones first
-        # the row of assignment (token, slot); the absent ones' rows lie
-        # past the held ones' and are never read
-        pos = jnp.minimum(jnp.zeros(P, jnp.int32).at[order].set(
-            jnp.arange(P, dtype=jnp.int32)), R - 1).reshape(n, K)
-        tok, slot = order[:R] // K, order[:R] % K
+        live = local < held
+        c = jnp.where(live, gates, 0.0)
+        # held ones first, a row's gate beside it
+        _, at, gate = jax.lax.sort(
+            (flat, jnp.arange(P, dtype=jnp.int32),
+             jax.lax.stop_gradient(c).reshape(P)),
+            num_keys=1, is_stable=True)
         sizes = jnp.sum(flat[:, None] == jnp.arange(held)[None, :], 0,
                         dtype=jnp.int32)
-        live = local < held
-        x_rows = _dispatch(x, tok, pos, live)
+        if in_token_order:
+            pos = None
+            order = _token_order(at[:R], gate[:R], jnp.sum(sizes), P)
+        else:
+            # the row of assignment (token, slot); the absent ones' rows
+            # lie past the held ones' and are never read
+            order = None
+            pos = jnp.minimum(jnp.zeros(P, jnp.int32).at[at].set(
+                jnp.arange(P, dtype=jnp.int32)), R - 1).reshape(n, K)
+        route = _Route(at[:R], gate[:R], live, pos, order)
+        x_rows = _dispatch(x, route)
     with jax.named_scope(scopes.MOE_EXPERTS):
         g = None if w_gate is None else _grouped(x_rows, w_gate, sizes)
         a = _hidden(g, _grouped(x_rows, w_up, sizes)).astype(x_rows.dtype)
         y = _grouped(a, w_down, sizes)
     with jax.named_scope(scopes.MOE_DISPATCH):
         # rows past the last held assignment belong to no group: whatever
-        # the grouped product left there is not read
-        y = jnp.where((jnp.arange(R) < jnp.sum(sizes))[:, None], y, 0)
-        return _combine(y, jnp.where(live, gates, 0.0), tok, slot,
-                        pos), sizes
+        # the grouped product left there is not read (XLA's form multiplies
+        # it by a gate of zero; the kernel's leaves such rows out itself)
+        if not in_token_order:
+            y = jnp.where((jnp.arange(R) < jnp.sum(sizes))[:, None], y, 0)
+        return _combine(y, c, route), sizes
 
 
-# A chunk's buffer for the load it has.  The two gathers cost by the
-# buffer's rows, live or not (one v5e, 8192 tokens of 2048, PERF.md PR 30:
-# the gate-weighted gather back takes 1.07 ms from 16,384 rows and 2.82 ms
-# from 65,536), so the common case should not pay for the worst: a small
-# buffer of ``_ROOM`` times the rows a router that spreads its tokens
-# evenly over all experts sends to the held ones, and every assignment of
-# every token (always enough) where the chunk's load is larger.
+# A chunk's buffer for the load it has.  Every gather of rows costs by the
+# buffer's rows, live or not (the header's list: with the kernel five
+# gathers of R rows a chunk, forward and backward, and none of n * K; in
+# XLA's form three of R and three of n * K, whatever R is.  One v5e, 8192
+# tokens of 2048, PERF.md PR 30: XLA's gate-weighted gather back takes
+# 1.07 ms from 16,384 rows and 2.82 ms from 65,536), so the common case
+# should not pay for the worst: a small buffer of ``_ROOM`` times the rows
+# a router that spreads its tokens evenly over all experts sends to the
+# held ones, and every assignment of every token (always enough) where
+# the chunk's load is larger.
 _ROOM = 2.25
 
 
@@ -394,6 +496,10 @@ def moe_forward(x32, router_w, w_gate, w_up, w_down, *, top_k, first,
     # counters below a reader turns counts into rows gathered
     monitor.stat_set("moe.small_buffer_rows", small or chunk * top_k)
     monitor.stat_set("moe.full_buffer_rows", chunk * top_k)
+    # rows of H that a pass from rows back to tokens walks a chunk: a row
+    # a slot in XLA's form; ``_experts`` says where a small buffer's are
+    # walked in token order instead
+    monitor.stat_set("moe.token_major_rows", chunk * top_k)
     counting = device_counters.collecting()
     experts = _padded_width(w_gate, w_up, w_down)
     out = jax.lax.map(

@@ -28,6 +28,11 @@ The tier:
 - ``ssd_scan`` (module) — Mamba-2's chunked state-space scan, fwd + bwd:
   a chunk's decay matrices stay in VMEM and the state rides a scratch
   along the chunk axis, behind ``F.ssd_scan``;
+- ``moe_combine`` (module)   — an expert layer's sums over a token's held
+  slots, the buffer's rows streamed once in token order and summed on the
+  MXU under scalar-prefetched (token block, row tile) pairs, behind
+  ``ops.moe`` (the forward's gate-weighted sum and the transpose of the
+  gather to the experts);
 - ``fused_linear_epilogue``  — matmul + bias/gelu/relu/residual/
   layer_norm epilogues off the cost model's ranked fusion candidates
   (selected by the static Executor's fusion pass);

@@ -1,7 +1,7 @@
 """Shared plumbing for the Pallas kernel tier.
 
 Every kernel file (flash_attention, eva_attention, sparse_attention,
-ssd_scan, fused_epilogue, fused_adam, paged_attention,
+ssd_scan, moe_combine, fused_epilogue, fused_adam, paged_attention,
 collective_matmul; attention_tiles holds the attention family's shared
 tile mathematics and launches nothing) needs the same five decisions
 made the same way:
@@ -16,8 +16,9 @@ made the same way:
 - **gates**: dtype and tile-alignment checks against the f32 (8, 128)
   sublane/lane tile;
 - **the choice** between a kernel and its XLA form, for the functionals
-  of ``nn.functional`` that have both: `choose_kernel`, the one caller
-  of `tier_enabled` on a benchmark cell's path;
+  of ``nn.functional`` that have both (and the expert layer's sums in
+  ``ops.moe``): `choose_kernel`, the one caller of `tier_enabled` on a
+  benchmark cell's path;
 - **observability**: every kernel SELECTION counts
   ``pallas.selected.<kernel>`` in monitor.  Selections happen at trace
   time (the kernel entry points run inside jitted programs, once per
@@ -121,9 +122,9 @@ def choose_kernel(functional: str, supported: bool) -> bool:
     mechanism's own gate took the call's shapes and dtype (``supported``:
     its ``*_supported``).  Who counts, one rule: a kernel's public entry
     counts its own selection (``pallas.selected.<kernel>``, as
-    `flash_attention`, `sparse_attention`, `ssd_scan`, `fused_adam`,
-    `fused_epilogue`, `paged_attention` and `collective_matmul` always
-    have), so a direct call of it is counted too; the chooser counts the
+    `flash_attention`, `sparse_attention`, `ssd_scan`, `moe_combine`,
+    `fused_adam`, `fused_epilogue`, `paged_attention` and
+    `collective_matmul` always have), so a direct call of it is counted too; the chooser counts the
     other side, ``<functional>.xla_path``.  Exactly one of the two moves
     a traced call."""
     if supported and tier_enabled():

@@ -217,6 +217,30 @@ def _carries_the_moe_counters(compiled, step, calls, chunks, live_peak):
     assert abs(peak - live_peak) < 2 ** 20, peak
 
 
+def _token_major_passes_walk_the_buffer(text, stats, layers, n, K, H):
+    """Where a chunk's load fits the small buffer (``lax.cond``'s branch 1)
+    nothing under ``moe`` holds a row of H for every slot, n * K of them:
+    the sums over a token's slots run through ``moe_combine`` over the
+    buffer's rows in token order, one call a layer in the forward pass
+    (the replay's is dead code) and one in the backward, and the gates'
+    gradient is made a row.  The overflow branch keeps XLA's form, and is
+    where this search finds what it looks for."""
+    a_slot = re.compile(rf"= (?:bf16|f32)\[(?:{n * K},{H}|{n},{K},{H})\]")
+    found = {"branch_0_fun": 0, "branch_1_fun": 0}
+    for line in text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        segs = name.group(1).split("/") if name else []
+        if "moe" in segs and a_slot.search(line):
+            found[next(s for s in segs if s.startswith("branch_"))] += 1
+    assert found["branch_0_fun"] and not found["branch_1_fun"], found
+    assert _kernel_count(text, "moe_combine") == 2 * layers
+    assert stats["pallas.selected.moe_combine"] >= 2 * layers
+    assert "moe_combine.xla_path" not in stats
+    assert stats["moe.token_major_rows"] == stats["moe.small_buffer_rows"] \
+        == int(2.25 * n * K * stats["moe.experts_held"]
+               / stats["moe.experts_total"]) < n * K
+
+
 def _kept(stats):
     """name -> ``recompute.kept.<name>``: the ``scopes.RESIDUALS`` a
     step's replays are handed, and how many of each."""
@@ -319,14 +343,16 @@ def test_keye_vl2_cell_step_fits_the_chip(one_chip, monkeypatch):
     monitor.stat_reset()
     compiled, n, cfg, mix, footprint, step = _cell_step(
         one_chip, monkeypatch, "keye_vl2_30b_a3b.train_bf16_b4_s8192",
-        ("sparse_attention", "flash_attention"))
+        ("sparse_attention", "flash_attention", "moe_combine"))
+    # 14,553,615,872 before the expert layers' sums walked rows (PR 46)
     _carries_the_moe_counters(compiled, step, calls=4, chunks=4,
-                              live_peak=14_553_615_872)
-    # 16,350,240,768 since the replay keeps the mask, q, k and v of the
-    # sparse kernels (PR 41: 2.42 GB kept; 15,589,112,320 with the mask
-    # alone); 13,306,718,208 before, 13,305,999,360 without the counters.
-    # The slack ends 0.5 GB under the compiler's ceiling of 16,911,433,728
-    assert footprint < 16_350_240_768 + 60e6
+                              live_peak=14_488_347_648)
+    # 15,950,521,344 since PR 46; 16,350,240,768 since the replay keeps the
+    # mask, q, k and v of the sparse kernels (PR 41: 2.42 GB kept;
+    # 15,589,112,320 with the mask alone); 13,306,718,208 before,
+    # 13,305,999,360 without the counters.  The slack ends 0.9 GB under
+    # the compiler's ceiling of 16,911,433,728
+    assert footprint < 15_950_521_344 + 60e6 < 16_350_240_768
     assert cfg["hidden_size"] == 2048 and mix["seq"] == 8192
     assert 465e6 < n < 466e6
     text = compiled.as_text()
@@ -364,6 +390,9 @@ def test_keye_vl2_cell_step_fits_the_chip(one_chip, monkeypatch):
         assert stats[f"pallas.selected.{kernel}"] >= L, kernel
         assert f"{functional}.xla_path" not in stats, functional
     _head_made_its_gradients_in_the_forward_pass(text, calls=1)
+    _token_major_passes_walk_the_buffer(
+        text, stats, L, mix["seq"], cfg["num_experts_per_tok"],
+        cfg["hidden_size"])
     assert footprint < 15.75 * 2 ** 30
 
 
@@ -535,14 +564,15 @@ def test_joyai_llm_flash_cell_step_fits_the_chip(one_chip, monkeypatch):
     monitor.stat_reset()
     compiled, n, cfg, mix, footprint, step = _cell_step(
         one_chip, monkeypatch, "joyai_llm_flash.train_bf16_b2_s8192",
-        ("flash_attention",))
-    # four expert layers and the MTP block's
+        ("flash_attention", "moe_combine"))
+    # four expert layers and the MTP block's; 14,037,525,504 before PR 46
     _carries_the_moe_counters(compiled, step, calls=5, chunks=2,
-                              live_peak=14_037_525_504)
+                              live_peak=14_021_294_080)
     # 14,736,134,656 without the counters; 14,736,962,560 since PR 38
     # (with v, out, dO and dv of the shared-key call lying it compiled to
-    # 14,848,201,728 and ran 1.1 ms slower on the chip: not shipped, PR 44)
-    assert abs(footprint - 14_736_962_560) < 2 * 2 ** 20
+    # 14,848,201,728 and ran 1.1 ms slower on the chip: not shipped, PR 44);
+    # 14,748,157,440 since the expert layers' sums walk rows (PR 46)
+    assert abs(footprint - 14_748_157_440) < 2 * 2 ** 20
     assert cfg["hidden_size"] == 2048 and mix["seq"] == 8192
     assert cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"] == 192
     assert 680.3e6 < n < 680.5e6
@@ -565,6 +595,9 @@ def test_joyai_llm_flash_cell_step_fits_the_chip(one_chip, monkeypatch):
     assert stats["moe.scoring_sigmoid"] >= blocks - 1
     assert stats["moe.shared_experts"] >= blocks - 1
     assert stats["mtp.modules"] == 1
+    _token_major_passes_walk_the_buffer(
+        text, stats, blocks - 1, mix["seq"], cfg["num_experts_per_tok"],
+        cfg["hidden_size"])
     assert footprint < 15.75 * 2 ** 30
 
 
@@ -628,7 +661,7 @@ def test_nemotron_h_cell_step_fits_the_chip(one_chip, monkeypatch):
     compiled, n, cfg, mix, footprint, step = _cell_step(
         one_chip, monkeypatch,
         "nemotron_3_nano_30b_a3b.train_bf16_b2_s8192",
-        ("flash_attention", "ssd_scan"))
+        ("flash_attention", "ssd_scan", "moe_combine"))
     assert n == cfg["parameters"] == 666_963_456
     assert (cfg["hidden_size"], mix["seq"]) == (2688, 8192)
     pattern = cfg["hybrid_override_pattern"]
@@ -653,6 +686,9 @@ def test_nemotron_h_cell_step_fits_the_chip(one_chip, monkeypatch):
             stats["moe.top_k"]) == (8, 128, 6)
     assert stats["moe.gateless_experts"] >= experts
     assert stats["moe.scoring_sigmoid"] >= experts
+    _token_major_passes_walk_the_buffer(
+        text, stats, experts, mix["seq"], cfg["num_experts_per_tok"],
+        cfg["hidden_size"])
     # the experts' load and the mixers' two float32 readings ride in the
     # carry, a row a layer
     assert {k: (v.shape, v.dtype) for k, v in step._counter_spec.items()} \
@@ -672,10 +708,11 @@ def test_nemotron_h_cell_step_fits_the_chip(one_chip, monkeypatch):
             if name.startswith(scopes.SSD_FWD)} == {2_560_000}
     assert {size for name, size in vmem.items()
             if name.startswith(scopes.SSD_BWD)} == {4_997_120}
-    # under conftest's matmul precision; the parent reads the same here
-    # (with out and dO lying it compiled to 15,083,398,144 and ran 1.1 ms
-    # slower on the chip: not shipped, PR 44)
-    assert footprint == 15_083_288_064
+    # under conftest's matmul precision; 15,083,288,064 before the expert
+    # layers' sums walked rows (PR 46; with out and dO lying it compiled
+    # to 15,083,398,144 and ran 1.1 ms slower on the chip: not shipped,
+    # PR 44)
+    assert footprint == 15_094_020_608
 
 
 def test_granite_cell_step_fits_the_chip(one_chip, monkeypatch):
@@ -764,6 +801,44 @@ def test_ssd_scan_fwd_bwd(one_chip, monkeypatch, x_shape, groups):
     print(f"ssd_scan at {list(x_shape)} / {list(b_shape)}: "
           + ", ".join(f"{name} {size} bytes of VMEM" for name, size in vmem))
     assert len(vmem) == 2 and all(size < 16 * 2 ** 20 for _, size in vmem)
+
+
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["weighted", "plain"])
+@pytest.mark.parametrize("H,rows", [(2048, 18432), (2688, 6912)],
+                         ids=["keye_cell", "nemotron_cell"])
+def test_moe_combine(one_chip, monkeypatch, H, rows, weighted):
+    """The expert layers' sum over a token's rows alone, at the widest and
+    the narrowest of the three expert cells' small buffers (8192 tokens a
+    chunk; 18,432 rows of 2048, 6,912 of 2688), as the forward pass calls
+    it (gate-weighted, float32 out) and as the dispatch's transpose does
+    (plain, out in the rows' type): one Mosaic kernel inside the default
+    scoped VMEM, the grid's pairs made beside it in XLA."""
+    mc = importlib.import_module("paddle_tpu.ops.pallas.moe_combine")
+    monkeypatch.setattr(mc, "_interpret", lambda: False)
+    n, dtype = 8192, jnp.bfloat16
+    assert mc.moe_combine_supported(n, H, dtype)
+    assert not mc.moe_combine_supported(n + 64, H, dtype)
+    assert not mc.moe_combine_supported(n, H + 64, dtype)
+    assert not mc.moe_combine_supported(n, H, jnp.float16)
+    padded = mc.padded_rows(rows)
+    assert padded >= rows and padded % 256 == 0
+    args = [_sds(one_chip, (padded, H), dtype),
+            _sds(one_chip, (padded,), jnp.int32)]
+    if weighted:
+        args.append(_sds(one_chip, (padded,), jnp.float32))
+        compiled = _compile(
+            lambda r, t, w: mc.moe_combine(r, t, w, n), *args)
+    else:
+        compiled = _compile(
+            lambda r, t: mc.moe_combine(r, t, None, n, dtype), *args)
+    (name, size), = _kernel_vmem(compiled)
+    print(f"moe_combine at [{padded}, {H}] -> [{n}, {H}]: {size} bytes of "
+          "VMEM")
+    assert name.startswith("moe_combine") and size < 16 * 2 ** 20
+    out, = jax.tree.leaves(compiled.out_info)
+    assert (out.shape, out.dtype) == (
+        (n, H), jnp.float32 if weighted else dtype)
 
 
 # ------------------------------------------------------- fused epilogue --
