@@ -542,13 +542,16 @@ def check_flash_dropout(errs, bert):
 
 
 def check_flash_grouped(errs, shape=(1, 2048, 32, 128), kv_heads=2,
-                        scale=None, tag="flash_grouped"):
+                        scale=None, tag="flash_grouped", window=None,
+                        heads_a_step=None):
     """Grouped key/value heads through the kernels' index maps: the
-    Nemotron cell's 32 query heads on 2 (a quarter of its length), and
-    the Granite cell's own call, [1, 8192, 32 on 8, 64] at the family's
-    ``scale`` 1/64.  Forward and gradients against the oracle, which
-    repeats k and v and runs a key/value head's query heads at a time so
-    that its scores fit."""
+    Nemotron cell's 32 query heads on 2 (a quarter of its length), the
+    Granite cell's own call, [1, 8192, 32 on 8, 64] at the family's
+    ``scale`` 1/64, and the Trinity cell's two, [1, 16384, 32 on 4, 128]
+    under a ``window`` of 2048 and full.  Forward and gradients against
+    the oracle, which repeats k and v and runs ``heads_a_step`` query
+    heads at a time (a key/value head's group by default) so that its
+    scores fit."""
     import jax
     import jax.numpy as jnp
 
@@ -558,16 +561,19 @@ def check_flash_grouped(errs, shape=(1, 2048, 32, 128), kv_heads=2,
     kv = (B, L, kv_heads, D)
     q, k, v = (_rnd(i, s, jnp.bfloat16)
                for i, s in ((1, shape), (2, kv), (3, kv)))
-    flash = functools.partial(flash_attention, causal=True, scale=scale)
+    flash = functools.partial(flash_attention, causal=True, scale=scale,
+                              window=window)
+    n = heads_a_step or H // kv_heads       # query heads an oracle step
+    steps = H // n
 
     def plain(q, k, v):
-        group = jax.checkpoint(lambda args: mha_reference(
-            args[0], *(jnp.repeat(a, H // kv_heads, 2) for a in args[1:]),
-            causal=True, scale=scale))
-        out = jax.lax.map(group, (
-            jnp.moveaxis(q.reshape(B, L, kv_heads, H // kv_heads, D), 2, 0),
-            jnp.moveaxis(k[:, :, :, None], 2, 0),
-            jnp.moveaxis(v[:, :, :, None], 2, 0)))
+        some = jax.checkpoint(lambda args: mha_reference(
+            *args, causal=True, scale=scale, window=window))
+
+        def by_step(a):                     # [B, L, H, D] -> [steps, .., n, D]
+            return jnp.moveaxis(a.reshape(B, L, steps, n, D), 2, 0)
+        out = jax.lax.map(some, (by_step(q), *(
+            by_step(jnp.repeat(a, H // kv_heads, 2)) for a in (k, v))))
         return jnp.moveaxis(out, 0, 2).reshape(B, L, H, D)
 
     errs[f"{tag}_fwd"] = _close(
@@ -782,6 +788,10 @@ def phase_kernels(clog, bert=BERT_BASE, serve=SERVE, engine=SERVE_ENGINE):
         check_flash_grouped(errs)
         check_flash_grouped(errs, (1, 8192, 32, 64), kv_heads=8,
                             scale=1 / 64, tag="flash_grouped_d64")
+        for window, tag in ((2048, "flash_window_16k"),
+                            (None, "flash_full_16k")):
+            check_flash_grouped(errs, (1, 16384, 32, 128), kv_heads=4,
+                                tag=tag, window=window, heads_a_step=1)
         check_ssd_scan(errs)
         check_ssd_scan(errs, (1, 8192, 64, 64), groups=1, tag="ssd_one_group")
         check_moe_combine(errs)

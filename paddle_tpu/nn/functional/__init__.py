@@ -1185,7 +1185,8 @@ def ctc_loss(log_probs, labels, input_lengths, label_lengths, blank=0,
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
                                  training=True, name=None,
-                                 return_weights=False, scale=None):
+                                 return_weights=False, scale=None,
+                                 window=None):
     """[B, L, H, D] attention (paddle incubate layout); ``key`` and
     ``value`` may have fewer heads [B, Lk, Hk, D], ``Hk`` dividing ``H``
     (grouped-query attention: query head h reads key/value head
@@ -1199,12 +1200,32 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     Counted at trace time: ``pallas.selected.flash_attention`` /
     ``attention.xla_path``.  ``scale`` multiplies the scores; left at
     None it is ``D ** -0.5`` (a family whose attention multiplier is
-    another number hands it in, on either path).
+    another number hands it in, on either path).  ``window`` (with
+    ``is_causal``): sliding-window attention, query t sees the keys s with
+    ``t - window < s <= t``; the kernels skip the blocks below the window,
+    XLA's path masks the band.  A windowed call runs under the scope
+    ``window_attention`` inside this functional's own.
 
     ``return_weights=True`` forces the unfused path and returns
     ``(out, weights [B, H, Lq, Lk])`` — post-softmax probabilities, with
     dropout applied in training mode (matching the reference, which
     returns the dropped weights: nn/layer/transformer.py:412-431)."""
+    if window is None:
+        return _sdpa_call(query, key, value, attn_mask, dropout_p,
+                          is_causal, training, return_weights, scale, None)
+    if not is_causal or int(window) < 1:
+        raise ValueError("scaled_dot_product_attention: window is a "
+                         "positive count of positions under is_causal, got "
+                         f"window={window!r}, is_causal={is_causal!r}")
+    with jax.named_scope(scopes.WINDOW_ATTENTION):
+        return _sdpa_call(query, key, value, attn_mask, dropout_p,
+                          is_causal, training, return_weights, scale,
+                          int(window))
+
+
+def _sdpa_call(query, key, value, attn_mask, dropout_p, is_causal, training,
+               return_weights, scale, window):
+    """`scaled_dot_product_attention` under its scopes."""
     from ...ops.pallas import flash_attention, flash_attention_supported
     from ...ops.pallas.support import choose_kernel
     eff_dropout = dropout_p if training else 0.0
@@ -1216,14 +1237,14 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
             fdraw = stable_draw()  # in-trace + replay-stable seed
             return apply(
                 lambda q, k, v: flash_attention(
-                    q, k, v, causal=is_causal, scale=scale,
+                    q, k, v, causal=is_causal, scale=scale, window=window,
                     dropout_p=eff_dropout,
                     seed=jax.random.bits(fdraw.key(), (1, 1), jnp.uint32)
                     .astype(jnp.int32)),
                 query, key, value, op_name="flash_attention")
         return apply(
             lambda q, k, v: flash_attention(q, k, v, causal=is_causal,
-                                            scale=scale),
+                                            scale=scale, window=window),
             query, key, value, op_name="flash_attention")
 
     use_dropout = dropout_p > 0.0 and training
@@ -1246,6 +1267,8 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
             1.0 / math.sqrt(D) if scale is None else float(scale))
         if is_causal:
             causal = jnp.tril(jnp.ones((Lq, k.shape[1]), bool))
+            if window is not None:      # the band: a query's last keys
+                causal &= ~jnp.tril(jnp.ones_like(causal), -window)
             qt = jnp.where(causal[None, None], qt, -jnp.inf)
         if mask is not None:
             if mask.dtype == jnp.bool_:
@@ -1268,6 +1291,20 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 
     args = [query, key, value] + ([attn_mask] if attn_mask is not None else [])
     return apply(_sdpa, *args, op_name="scaled_dot_product_attention")
+
+
+@jax.named_scope(scopes.ATTN_GATE)
+def attention_output_gate(out, gate, name=None):
+    """``out * sigmoid(gate)``, elementwise: a gate on attention's output
+    (Qiu et al. 2025, "Gated Attention for Large Language Models"), the
+    heads' outputs [..., heads * head_dim] under the sigmoid of a
+    projection of the same normed state, before the output projection.
+    The sigmoid in float32, the result in ``out``'s type; under the scope
+    ``attn_gate``."""
+    return apply(
+        lambda a, g: (a.astype(jnp.float32) * jax.nn.sigmoid(
+            g.astype(jnp.float32))).astype(a.dtype),
+        out, gate, op_name="attention_output_gate")
 
 
 # ---------------------------------------------------------------------------

@@ -70,12 +70,17 @@ SSM_GATE_NORM = "ssm_gate_norm"  # the gate and the group norm
 FFN = "ffn"                   # a gated feed-forward layer (nn.GatedFFN):
                               # its two projections, the gate's activation
                               # and the product
+WINDOW_ATTENTION = "window_attention"  # a sliding-window call of
+                              # scaled_dot_product_attention, inside its
+                              # scope: the kernels or the banded XLA form
+ATTN_GATE = "attn_gate"       # the sigmoid gate on attention's output
+                              # (F.attention_output_gate)
 FUNCTIONALS = (ATTENTION, LINEAR_CROSS_ENTROPY, GELU, LAYER_NORM, EMBEDDING,
                DROPOUT, EVA_ATTENTION, EVA_POOL, RMS_NORM, ROPE, MOE,
                MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, DSA_INDEXER, DSA_SELECT,
                SPARSE_ATTENTION, QK_NORM, MOE_SHARED, MLA_ATTENTION, MTP,
                LOOP_STACK, LOOP_EXIT, SSM, SSM_CONV, SSM_SCAN, SSM_GATE_NORM,
-               FFN)
+               FFN, WINDOW_ATTENTION, ATTN_GATE)
 
 # -- Pallas kernels ----------------------------------------------------------
 FLASH_FWD = "flash_fwd"

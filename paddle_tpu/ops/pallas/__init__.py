@@ -15,8 +15,10 @@ The tier:
 - ``flash_attention``        — online-softmax attention, fwd + bwd; the
   values may be narrower than the keys and a part of the key may be one
   for all heads, staged once a row (latent attention's 128 + 64 / 128,
-  behind ``F.mla_attention``); a head's K and V are staged whole, up to
-  6 MiB a head (over 4 MiB under a stated scoped-VMEM limit);
+  behind ``F.mla_attention``); a sliding ``window`` under the causal
+  mask skips the blocks below it in both walks; a head's K and V are
+  staged whole, up to 8 MiB a head (over 4 MiB under a stated scoped-VMEM
+  limit: the shared-key call to 5, 128-wide bfloat16 heads to 16,384 rows);
 - ``eva_attention`` (module) — EVA's windowed attention over exact keys
   and chunk summaries under one softmax, fwd + bwd, behind
   ``F.eva_attention``;

@@ -61,21 +61,62 @@ def mask_diagonal(s, qi, j, block_q, block_k):
     return jnp.where(rel >= j * block_k - qi * block_q, s, NEG_INF)
 
 
-def kv_spans(qi, block_q, block_k, num_kv, minimum=jnp.minimum):
+def mask_window(s, qi, j, block_q, block_k, window, diagonal):
+    """Mask of a block that straddles a sliding window's lower edge:
+    query c of q block ``qi`` sees key r of k block ``j`` iff the key is
+    one of the query's last ``window`` positions, ``c - r < j*block_k -
+    qi*block_q + window``; with ``diagonal`` the block straddles the
+    diagonal too and takes `mask_diagonal`'s test in the same pass."""
+    rel = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+           - jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
+    first = j * block_k - qi * block_q
+    keep = rel < first + window
+    if diagonal:
+        keep = keep & (rel >= first)
+    return jnp.where(keep, s, NEG_INF)
+
+
+def kv_spans(qi, block_q, block_k, num_kv, minimum=jnp.minimum,
+             window=None):
     """Aligned causal schedule of q block ``qi``: k blocks [0, full) lie
     wholly below the diagonal, [full, end) straddle it, the rest are
-    invisible.  ``minimum=min`` gives Python ints for the counters."""
+    invisible.  ``minimum=min`` gives Python ints for the counters.
+
+    ``window`` (a count of positions: query t sees keys s with
+    ``t - window < s <= t``) adds the runs below the window: k blocks
+    [0, start) hold no key any query of the block sees and are skipped,
+    [start, inside) straddle the window's lower edge ("window", or
+    "both" where they straddle the diagonal as well), and from
+    ``inside`` on every query sees every key a block holds as far as the
+    lower edge goes."""
     full = minimum((qi * block_q + 1) // block_k, num_kv)
     end = minimum(pl.cdiv((qi + 1) * block_q, block_k), num_kv)
-    return (0, full, None), (full, end, "diagonal")
+    if window is None:
+        return (0, full, None), (full, end, "diagonal")
+    maximum = max if minimum is min else jnp.maximum
+    start = minimum(maximum(qi * block_q - window + 1, 0) // block_k, num_kv)
+    inside = minimum(
+        pl.cdiv(maximum((qi + 1) * block_q - window, 0), block_k), num_kv)
+    edge, both = minimum(inside, full), maximum(full, minimum(inside, end))
+    return ((start, edge, "window"), (edge, full, None),
+            (full, both, "both"), (both, end, "diagonal"))
 
 
-def q_spans(kj, block_q, block_k, num_q):
+def q_spans(kj, block_q, block_k, num_q, window=None):
     """The same schedule seen from k block ``kj``: q blocks
-    [start, full) straddle the diagonal, [full, num_q) lie wholly below."""
+    [start, full) straddle the diagonal, [full, num_q) lie wholly below.
+    With ``window``, q blocks [inside, stop) straddle its lower edge and
+    those from ``stop`` on see nothing of the block: skipped."""
     start = jnp.minimum((kj * block_k) // block_q, num_q)
     full = jnp.minimum(pl.cdiv((kj + 1) * block_k - 1, block_q), num_q)
-    return (start, full, "diagonal"), (full, num_q, None)
+    if window is None:
+        return (start, full, "diagonal"), (full, num_q, None)
+    inside = jnp.minimum((kj * block_k + window) // block_q, num_q)
+    stop = jnp.minimum(
+        pl.cdiv((kj + 1) * block_k - 1 + window, block_q), num_q)
+    edge, clear = jnp.minimum(full, inside), jnp.maximum(full, inside)
+    return ((start, edge, "diagonal"), (edge, full, "both"),
+            (full, clear, None), (clear, stop, "window"))
 
 
 def block_loops(body, carry, num_blocks, causal, aligned, causal_spans):
@@ -83,7 +124,8 @@ def block_loops(body, carry, num_blocks, causal, aligned, causal_spans):
     mask each needs: none without ``causal``, the position mask on every
     block of the ring path, and in the aligned causal path the
     ``(lo, hi, mask)`` runs of ``causal_spans``: the diagonal mask only
-    where a block straddles the diagonal, invisible blocks skipped."""
+    where a block straddles the diagonal, invisible blocks skipped (a
+    windowed schedule's runs carry its masks the same way)."""
     if not causal:
         spans = ((0, num_blocks, None),)
     elif not aligned:
@@ -102,8 +144,11 @@ def online_step(carry, s, v, *, may_hide_query=False, drop=None):
     tile may hold none of a query's keys; s - m_new is 0 there, and it is
     zeroed instead of attending uniformly.  The aligned causal path needs
     no guard: key 0 is visible to every query and in the first block, so
-    m is finite before any masked score.  ``drop``: dropout's hook, p ->
-    dropped u for the values alone (the denominator stays UNdropped)."""
+    m is finite before any masked score.  Under a sliding window the walk
+    starts at the window's lower edge, where a block's later queries see
+    none of its keys: the blocks that straddle that edge take the guard.
+    ``drop``: dropout's hook, p -> dropped u for the values alone (the
+    denominator stays UNdropped)."""
     m, l, acc = carry
     m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
     seen = s > 0.5 * NEG_INF if may_hide_query else None

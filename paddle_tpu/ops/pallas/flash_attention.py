@@ -63,7 +63,10 @@ shared] with 128-wide values, causal, 13.89 and 27.01 ms (16.74 +
 in a [B, H, L, D] copy, 1.392 and 2.499 with v, out, dO and dv as they
 lie (a staged V column is 4,096 rows of 256 bytes at a stride of 4,096:
 +0.6 %), 1.404 and 2.526 with q and k lying too (PERF.md section 6,
-PR 44: 32 calls a step).  Whether XLA's attention or this kernel is
+PR 44: 32 calls a step); [1, 16384, 32 on 4, 128] causal 19.08 and 34.01
+ms (73.6 % of what the pair needs at the chip's peak), and under a
+window of 2048, which runs 150 of the triangle's 528 blocks, 6.09 and
+11.43 (52.4 % of the band's need; PERF.md section 5, PR 47).  Whether XLA's attention or this kernel is
 taken at a length is a measured constant, `_KERNEL_FROM` below, beside the chip table it came
 from: the kernel from 512 positions up, for every head size, mask and
 dropout rate measured (PERF.md, PR 27).
@@ -90,8 +93,8 @@ from jax.experimental import pallas as pl
 from ...observability import scopes
 from .attention_tiles import (BLOCK, block_loops, delta as _delta, dq_add,
                               dq_emit, dq_zero, kv_spans, lead,
-                              mask_diagonal, online_step, p_ds, prescale,
-                              q_spans, rows, rows8, write_row8)
+                              mask_diagonal, mask_window, online_step, p_ds,
+                              prescale, q_spans, rows, rows8, write_row8)
 from .support import (NEG_INF, count_kernel_selection, dot as _dot,
                       interpret_mode as _interpret, name_residuals, pltpu,
                       smem_scalar_spec as _smem_scalar_spec)
@@ -170,10 +173,18 @@ def flash_attention_supported(q_shape, k_shape, dtype, attn_mask=None,
     # gate has always had) inside Mosaic's default scoped VMEM.  Only the
     # shared-key call goes further, to the 5 MiB that latent attention
     # stages at 8192 (`_STAGED_SHARED_KEY`), under a stated larger limit
-    # (`_staging`); beyond that the sequence belongs on the 'sp' ring
-    staged = _staged_bytes(max(Lq, Lk), D + shared_key_dim, Dv, dtype)
-    return staged <= (_STAGED_SHARED_KEY if shared_key_dim
-                      else _STAGED_DEFAULT)
+    # (`_staging`), and the plain call to the 8 MiB of 16384 x 128 bf16
+    # (`_STAGED_PLAIN`); beyond that the sequence belongs on the 'sp' ring
+    L = max(Lq, Lk)
+    staged = _staged_bytes(L, D + shared_key_dim, Dv, dtype)
+    if staged <= _STAGED_DEFAULT:
+        return True
+    if shared_key_dim:
+        return staged <= _STAGED_SHARED_KEY
+    # a plain call over the default: the form that was compiled and
+    # measured, heads one lane tile wide in a two-byte type
+    return (D == Dv == 128 and jnp.dtype(dtype).itemsize == 2
+            and staged <= _STAGED_PLAIN)
 
 
 # Bytes of one head's K and V (or Q and dO) as staged: both at ``D`` = 128
@@ -184,9 +195,14 @@ def flash_attention_supported(q_shape, k_shape, dtype, attn_mask=None,
 # tiles: those calls state a limit of their own, of the chip's 128 MiB.
 # That one shape is what was compiled for the chip and measured there
 # (tests/test_chip_compile.py; PERF.md, PR 32), so the gate admits more
-# than the default for the shared-key call alone and up to its bytes; a
-# plain call of that size (8192 x 192, 12288 x 128 bf16) stays XLA's or
-# the ring's until its crossover is measured.  The backward walk that
+# than the default for the shared-key call up to its bytes, and (PR 47)
+# for the plain call of 128-wide two-byte heads up to 8 MiB
+# (`_STAGED_PLAIN`: 16384 x 128 bf16, Trinity-Mini's rows, and the
+# shorter ones of that form; the readings are at the end of this note).
+# A plain call of wider heads or of float32 over the default (8192 x 192
+# bf16, 6144 x 128 f32) stays XLA's or the ring's: compiled for a v5e
+# their walks take 55 to 76 MB of scoped VMEM, over the stated limit,
+# where 16384 x 128 bf16 takes 38.  The backward walk that
 # makes dQ too holds a head's dQ block and its float32 accumulator
 # besides, and `_staging` counts that call as VMEM lays it out (a width
 # under 128 lanes takes 128): at 8192 x 128, q and dO 8 MiB and dQ 4
@@ -199,9 +215,22 @@ def flash_attention_supported(q_shape, k_shape, dtype, attn_mask=None,
 # reads 4.05 + 8.27 ms in the step (PERF.md section 5).  4096 x 128 (8.5
 # MiB) and the other cells' plain shapes stay on the defaults; the
 # shared-key walk about 30 MiB of the 48 (q 4 + qr 4 + dO 4, dQ 4 + dQr 4,
-# the accumulators 4 + 2, lse and delta 1).
+# the accumulators 4 + 2, lse and delta 1).  16384 x 128 bf16 (PR 47,
+# [1, 16384, 32 on 4, 128] causal): by this count q and dO 16 MiB and dQ 8
+# double-buffered, the accumulator 8, lse and delta 2, about 34 MiB before
+# the tiles; compiled for a v5e (tests/test_chip_compile.py) the walk takes
+# 37,662,720 bytes of scoped VMEM and under a window of 2048 38,739,968
+# (the second mask's tiles), the forward 2,469,888 and 2,949,120: under
+# the 48 MiB both; on the chip the full pair reads 19.08 + 34.01 ms in the
+# step and a window pair 6.09 + 11.43 (PERF.md section 5).  A window call
+# stages the head's whole row as the full call does: it fits, a group's
+# eight query heads share one staging of K and V (a DMA a 256 grid
+# steps), and staging the `window + block` rows a step can see instead
+# would move 1.3 MB a step (ROADMAP R0 keeps that form for rows no chip
+# stages whole).
 _STAGED_DEFAULT = 4 * 1024 * 1024
 _STAGED_SHARED_KEY = 5 * 1024 * 1024
+_STAGED_PLAIN = 8 * 1024 * 1024
 _VMEM_LIMIT_STAGED = 48 * 1024 * 1024
 
 
@@ -247,24 +276,37 @@ def _resolve_blocks(block_q, block_k, Lq, Lk):
     return min(block_q or BLOCK, Lq), min(block_k or BLOCK, Lk)
 
 
-def _count_blocks(Lq, Lk, block_q, block_k, causal, aligned):
+def _count_blocks(Lq, Lk, block_q, block_k, causal, aligned, window=None):
     """Trace-time counters, once per kernel traced: block iterations per
     (batch, head) that run without a mask (``blocks_full``) and with one
     (``blocks_masked``).  The backward kernel walks the forward's (q
-    block, k block) pairs, by columns."""
+    block, k block) pairs, by columns.  A windowed call counts its own
+    beside them: ``window_blocks_full`` / ``window_blocks_masked`` (what
+    it runs, in the two counters above as well) and
+    ``window_blocks_skipped`` (the blocks of the causal triangle that lie
+    wholly below its window)."""
     from ...utils import monitor
     num_q, num_kv = Lq // block_q, Lk // block_k
+
+    def run(window):
+        spans = [span for qi in range(num_q) for span in kv_spans(
+            qi, block_q, block_k, num_kv, minimum=min, window=window)]
+        return (sum(hi - lo for lo, hi, mask in spans if mask is None),
+                sum(hi - lo for lo, hi, mask in spans if mask))
+
     if not causal:
         full, masked = num_q * num_kv, 0
     elif not aligned:
         full, masked = 0, num_q * num_kv
     else:
-        spans = [span for qi in range(num_q) for span in kv_spans(
-            qi, block_q, block_k, num_kv, minimum=min)]
-        full = sum(hi - lo for lo, hi, mask in spans if mask is None)
-        masked = sum(hi - lo for lo, hi, mask in spans if mask)
+        full, masked = run(window)
     monitor.stat_add("pallas.flash.blocks_full", full)
     monitor.stat_add("pallas.flash.blocks_masked", masked)
+    if window is not None:
+        monitor.stat_add("pallas.flash.window_blocks_full", full)
+        monitor.stat_add("pallas.flash.window_blocks_masked", masked)
+        monitor.stat_add("pallas.flash.window_blocks_skipped",
+                         sum(run(None)) - full - masked)
 
 
 def _mask_scores(s, q_off_ref, k_off_ref, qi, j, block_q, block_k):
@@ -321,8 +363,13 @@ def _dropout(seed_ref, qi, j, dropout_p, of_pair=None):
     return drop
 
 
+# the masks of `attention_tiles.kv_spans` under a window: a block at its
+# lower edge may hold none of a later query's keys
+_AT_THE_WINDOW = ("window", "both")
+
+
 def scores(k, q, mask, qi, j, q_off_ref, k_off_ref, block_q, block_k,
-           shared=None):
+           shared=None, window=None):
     """[BK, BQ] f32 scores of (pre-scaled) q block ``qi`` against k block
     ``j`` under the block's mask.  ``shared``: the block's rows of the
     key part all heads share and the (pre-scaled) query part that meets
@@ -332,6 +379,9 @@ def scores(k, q, mask, qi, j, q_off_ref, k_off_ref, block_q, block_k,
         s = s + _dot(shared[0], shared[1], ((1,), (1,)))
     if mask == "diagonal":
         return mask_diagonal(s, qi, j, block_q, block_k)
+    if mask in _AT_THE_WINDOW:
+        return mask_window(s, qi, j, block_q, block_k, window,
+                           diagonal=mask == "both")
     if mask == "positions":
         return _mask_scores(s, q_off_ref, k_off_ref, qi, j, block_q,
                             block_k)
@@ -357,7 +407,7 @@ def _once_a_shape(*static_argnums):
 
 def _fwd_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref, *rest,
                 scale, block_k, seq_k, causal, block_q, aligned, dropout_p,
-                shared, of_pair=None):
+                shared, window=None, of_pair=None):
     if shared:
         qr_ref, kr_ref, o_ref, lse_ref = rest
         qr = prescale(qr_ref[lead(qr_ref)], scale)        # [BQ, Dr]
@@ -376,16 +426,34 @@ def _fwd_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref, *rest,
         v = rows(v_ref, j, block_k)
         s = scores(k, q, mask, qi, j, q_off_ref, k_off_ref, block_q,
                    block_k, (rows(kr_ref, j, block_k), qr)
-                   if shared else None)                   # [BK, BQ]
-        return online_step(carry, s, v, may_hide_query=mask == "positions",
+                   if shared else None, window)           # [BK, BQ]
+        return online_step(carry, s, v,
+                           may_hide_query=(mask == "positions"
+                                           or mask in _AT_THE_WINDOW),
                            drop=_dropout(seed_ref, qi, j, dropout_p,
                                          of_pair))
 
     m, l, acc = block_loops(body, (m, l, acc), num_kv, causal, aligned,
-                            kv_spans(qi, block_q, block_k, num_kv))
+                            kv_spans(qi, block_q, block_k, num_kv,
+                                     window=window))
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[lead(o_ref)] = (acc / l_safe).T.astype(o_ref.dtype)
     write_row8(lse_ref, jnp.where(l > 0, m + jnp.log(l_safe), NEG_INF))
+
+
+def _windowed(window, causal, aligned):
+    """``window`` as the kernels' static: None, or a positive count of
+    positions under the aligned causal schedule."""
+    if window is None:
+        return None
+    if not causal or int(window) < 1:
+        raise ValueError("flash attention: a window is a positive count of "
+                         f"positions under causal=True, got {window!r}")
+    if not aligned:
+        raise NotImplementedError(
+            "a sliding window is part of the aligned causal schedule: the "
+            "ring's blocks (traced offsets) take none")
+    return int(window)
 
 
 def _kv_head(h, group):
@@ -565,28 +633,30 @@ def _shared_width(shared):
 
 
 def _fwd(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
-         dropout_p=0.0, shared=None, lies=None):
+         dropout_p=0.0, shared=None, lies=None, window=None):
     """q [B, H, L, D], k [B, Hk, Lk, D], v [B, Hk, Lk, Dv] → (out
     [B,H,Lq,Dv], lse [B,H,Lq]); with ``lies`` q, k, v and out are
     [B, L, H * ...] (the lse [B, H, Lq] either way).  ``shared``:
     ``(qr [B, H, Lq, Dr], kr [B, 1, Lk, Dr])``, a key part all heads
     share and the query part that meets it."""
     _, _, _, Lq, Lk, _, _ = _dims(q, k, v, lies)
-    _count_blocks(Lq, Lk, *blocks, causal, aligned)
+    window = _windowed(window, causal, aligned)
+    _count_blocks(Lq, Lk, *blocks, causal, aligned, window)
     return _fwd_call(q, k, v, q_off, k_off, seed, scale, causal, blocks,
-                     aligned, dropout_p, _interpret(), lies, shared=shared)
+                     aligned, dropout_p, _interpret(), lies, window,
+                     shared=shared)
 
 
-@_once_a_shape(6, 7, 8, 9, 10, 11, 12)
+@_once_a_shape(6, 7, 8, 9, 10, 11, 12, 13)
 def _fwd_call(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
-              dropout_p, interpret, lies, shared=None):
+              dropout_p, interpret, lies, window, shared=None):
     B, H, Hk, Lq, Lk, D, Dv = _dims(q, k, v, lies)
     Dr = _shared_width(shared)
     block_q, block_k = blocks
     kernel = functools.partial(_fwd_kernel, scale=scale, block_k=block_k,
                                seq_k=Lk, causal=causal, block_q=block_q,
                                aligned=aligned, dropout_p=dropout_p,
-                               shared=bool(shared))
+                               shared=bool(shared), window=window)
     group, n = H // Hk, _heads_a_step(lies, D)
     if n == 2:
         kernel, H, D, Dv = _each_of_a_pair(kernel), H // 2, 2 * D, 2 * Dv
@@ -616,7 +686,7 @@ def _fwd_call(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
 
 def _bwd_dkv_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
                     *rest, scale, block_q, seq_q, causal, block_k, aligned,
-                    dropout_p, shared, n_dq, of_pair=None):
+                    dropout_p, shared, n_dq, window=None, of_pair=None):
     """The backward walk: k block ``kj`` of a head against the q blocks it
     sees, for its dK and dV.  Where the call asks for dQ too (``rest``
     then ends in ``n_dq`` dq outputs and as many accumulators, see
@@ -653,8 +723,10 @@ def _bwd_dkv_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
         lse = lse_ref[0, 0, 0:1, cols]                    # [1, BQ]
         delta = delta_ref[0, 0, 0:1, cols]
         s = scores(k, q, mask, i, kj, q_off_ref, k_off_ref, block_q,
-                   block_k, (kr, qr) if shared else None)
-        # fwd tile (qi=i, j=kj): identical seed -> identical mask
+                   block_k, (kr, qr) if shared else None, window)
+        # fwd tile (qi=i, j=kj): identical seed -> identical mask; a
+        # windowed row sees its own key, so its lse is finite and a key
+        # outside the window gets p = exp(-1e30 - lse) = 0 unguarded
         u, ds = p_ds(s, lse, do, v, delta, may_hide_query=mask == "positions",
                      drop=_dropout(seed_ref, i, kj, dropout_p, of_pair))
         dv = dv + _dot(u.astype(do.dtype), do, ((1,), (0,)))
@@ -668,7 +740,7 @@ def _bwd_dkv_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
         return dk, dv, dkr
 
     dk, dv, dkr = block_loops(body, (dk, dv, dkr), num_q, causal, aligned,
-                              q_spans(kj, block_q, block_k, num_q))
+                              q_spans(kj, block_q, block_k, num_q, window))
     dk_ref[lead(dk_ref)] = dk.astype(dk_ref.dtype)
     dv_ref[lead(dv_ref)] = dv.astype(dv_ref.dtype)
     if shared:
@@ -682,21 +754,22 @@ def _bwd_dkv_kernel(q_off_ref, k_off_ref, seed_ref, q_ref, k_ref, v_ref,
 
 def bwd_dkv(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale, causal,
             blocks, aligned, dropout_p, shared=None, with_dq=False,
-            lies=None):
+            lies=None, window=None):
     """``with_dq``: what the caller needs of the walk.  `_bwd` takes dQ
     from it; EVA's windows (eva_attention.py) take dK and dV alone, their
     dQ comes with the summaries' gradients from a kernel of their own."""
     _, _, _, Lq, Lk, _, _ = _dims(q, k, v, lies)
-    _count_blocks(Lq, Lk, *blocks, causal, aligned)
+    window = _windowed(window, causal, aligned)
+    _count_blocks(Lq, Lk, *blocks, causal, aligned, window)
     return _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8,
                          scale, causal, blocks, aligned, dropout_p,
-                         _interpret(), with_dq, lies, shared=shared)
+                         _interpret(), with_dq, lies, window, shared=shared)
 
 
-@_once_a_shape(9, 10, 11, 12, 13, 14, 15, 16)
+@_once_a_shape(9, 10, 11, 12, 13, 14, 15, 16, 17)
 def _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale,
                   causal, blocks, aligned, dropout_p, interpret, with_dq,
-                  lies, shared=None):
+                  lies, window, shared=None):
     """-> (dk, dv), each QUERY head's part [B, H, Lk, ...] (k and v may
     have fewer heads, a group of query heads on each: `_bwd` sums the
     parts), with ``shared`` also each head's float32 part of the
@@ -720,7 +793,8 @@ def _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale,
     kernel = functools.partial(
         _bwd_dkv_kernel, scale=scale, block_q=block_q, seq_q=Lq,
         causal=causal, block_k=block_k, aligned=aligned,
-        dropout_p=dropout_p, shared=bool(shared), n_dq=len(dq_widths))
+        dropout_p=dropout_p, shared=bool(shared), n_dq=len(dq_widths),
+        window=window)
     if n == 2:
         kernel = _each_of_a_pair(kernel, scratch=len(dq_widths))
 
@@ -774,7 +848,7 @@ def _bwd_dkv_call(q, k, v, q_off, k_off, seed, do, lse8, delta8, scale,
 
 
 def _bwd(q, k, v, q_off, k_off, seed, out, lse, do, dlse, scale, causal,
-         blocks, aligned, dropout_p=0.0, shared=None, lies=None):
+         blocks, aligned, dropout_p=0.0, shared=None, lies=None, window=None):
     """Full backward, one kernel -> (dq, dk, dv), or with ``shared``
     ((dq, dqr), (dk, each head's part of dkr), dv).  The lse cotangent
     folds into delta: with P = exp(S - lse) row-normalized,
@@ -792,7 +866,7 @@ def _bwd(q, k, v, q_off, k_off, seed, out, lse, do, dlse, scale, causal,
     dk, dv, *rest = bwd_dkv(q, k, v, q_off, k_off, seed, do, rows8(lse),
                             rows8(delta), scale, causal, blocks, aligned,
                             dropout_p, shared=shared, with_dq=True,
-                            lies=lies)
+                            lies=lies, window=window)
     if not shared:
         if lies:                # keys with a head each: the parts are it
             return rest[0], dk, dv
@@ -817,27 +891,30 @@ def _sum_groups(parts, like):
 # custom-vjp cores over [B, H, L, D], or [B, L, H*D] as it lies
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(6, 7, 8, 9, 10, 11, 12))
 def _flash(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
-           dropout_p, lies):
+           dropout_p, lies, window):
     """q, k, v and out [B, H, L, ...], or with ``lies`` [B, L, H * ...]."""
     out, _ = _fwd(q, k, v, q_off, k_off, seed, scale, causal, blocks,
-                  aligned, dropout_p, lies=lies)
+                  aligned, dropout_p, lies=lies, window=window)
     return out
 
 
 def _flash_fwd(q, k, v, q_off, k_off, seed, scale, causal, blocks, aligned,
-               dropout_p, lies):
+               dropout_p, lies, window):
     out, lse = name_residuals(*_fwd(q, k, v, q_off, k_off, seed, scale,
                                     causal, blocks, aligned, dropout_p,
-                                    lies=lies))
+                                    lies=lies, window=window))
     return out, (q, k, v, q_off, k_off, seed, out, lse)
 
 
-def _flash_bwd(scale, causal, blocks, aligned, dropout_p, lies, res, do):
+def _flash_bwd(scale, causal, blocks, aligned, dropout_p, lies, window, res,
+               do):
     q, k, v, q_off, k_off, seed, out, lse = res
     dq, dk, dv = _bwd(q, k, v, q_off, k_off, seed, out, lse, do, None,
-                      scale, causal, blocks, aligned, dropout_p, lies=lies)
+                      scale, causal, blocks, aligned, dropout_p, lies=lies,
+                      window=window)
     return (dq, dk, dv, jnp.zeros_like(q_off), jnp.zeros_like(k_off),
             None)
 
@@ -938,7 +1015,8 @@ def _from_kernels(out, lies):
 
 def flash_attention(q, k, v, causal: bool = False, scale=None,
                     block_q: int | None = None, block_k: int | None = None,
-                    dropout_p: float = 0.0, seed=None):
+                    dropout_p: float = 0.0, seed=None,
+                    window: int | None = None):
     """q: [B, L, H, D], k: [B, Lk, Hk, D], v: [B, Lk, Hk, Dv] (``Dv`` may
     differ from ``D``; ``Hk`` divides ``H``, query head h reads key/value
     head ``h // (H / Hk)``) → [B, Lq, H, Dv] attention output; the default
@@ -946,6 +1024,13 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
 
     ``block_q`` / ``block_k`` left at None are chosen from the static
     shapes (`_resolve_blocks`).
+
+    ``window`` (with ``causal``): a sliding window, query t sees the keys
+    s with ``t - window < s <= t`` (its own position and the ``window -
+    1`` before it).  Blocks that lie wholly below a q block's window are
+    skipped in the forward and in the backward walk, those that straddle
+    its lower edge take a second mask; None is plain causal attention,
+    the same program as before the argument existed.
 
     ``dropout_p > 0`` applies attention-probability dropout IN-KERNEL
     (Pallas TPU PRNG, tile-seeded from ``seed`` so the backward
@@ -968,7 +1053,8 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     q, k, v = (_to_kernels(q, _qk(lies)), _to_kernels(k, _qk(lies)),
                _to_kernels(v, lies))
     out = _flash(q, k, v, zero_off(), zero_off(), seed, scale,
-                 bool(causal), blocks, True, float(dropout_p), lies)
+                 bool(causal), blocks, True, float(dropout_p), lies,
+                 _windowed(window, causal, True))
     return _from_kernels(out, lies)
 
 
@@ -1012,9 +1098,10 @@ def flash_attention_block(q_bhld, k_bhld, v_bhld, q_off, k_off, scale,
                            blocks)
 
 
-def mha_reference(q, k, v, causal=False, scale=None):
+def mha_reference(q, k, v, causal=False, scale=None, window=None):
     """jnp oracle for tests ([B, L, H, D] layout; v may be [.., Dv]; k
-    and v may have fewer heads, each repeated over its group)."""
+    and v may have fewer heads, each repeated over its group; ``window``:
+    of the causal keys, a query's last ``window`` positions)."""
     D = q.shape[-1]
     group = q.shape[2] // k.shape[2]
     k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
@@ -1024,6 +1111,8 @@ def mha_reference(q, k, v, causal=False, scale=None):
     if causal:
         Lq, Lk = q.shape[1], k.shape[1]
         mask = jnp.tril(jnp.ones((Lq, Lk), bool))
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((Lq, Lk), bool), -window)
         s = jnp.where(mask[None, None], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhls,bshd->blhd", w, v.astype(jnp.float32)

@@ -221,6 +221,63 @@ def test_the_causal_spans_cover_what_the_diagonal_mask_shows(qi):
             tile(kj, qi))
 
 
+@pytest.mark.parametrize("bq,bk,window", [
+    (8, 8, 8), (8, 8, 16), (8, 8, 5), (8, 8, 19), (8, 4, 6), (4, 8, 13),
+    (8, 8, 1), (8, 8, 64), (16, 8, 24),
+], ids=["one_block", "two_blocks", "under_a_block", "not_a_multiple",
+        "narrow_keys", "narrow_queries", "itself_alone", "the_whole_row",
+        "wide_queries"])
+def test_the_windowed_spans_cover_what_the_band_shows(bq, bk, window):
+    """Every (key block, query block) of a row of 64 under a sliding
+    window, against the band ``q - window < k <= q`` over the whole
+    square: a block in no span shows nothing; an unmasked span's blocks
+    hide nothing; a masked span's blocks are cut, and the span's mask
+    (`mask_diagonal`, or `mask_window` with or without the diagonal) is
+    the band on them.  Seen from the queries (`kv_spans`, traced and as
+    Python ints for the counters) and from the keys (`q_spans`)."""
+    L = 64
+    nq, nk = L // bq, L // bk
+    k, q = np.arange(L)[:, None], np.arange(L)[None, :]
+    band = (k <= q) & (k > q - window)
+
+    def tile(j, i):
+        return band[j * bk:(j + 1) * bk, i * bq:(i + 1) * bq]
+
+    def masked(mask, i, j):
+        zeros = jnp.zeros((bk, bq))
+        if mask == "diagonal":
+            return tiles.mask_diagonal(zeros, i, j, bq, bk) == 0
+        return tiles.mask_window(zeros, i, j, bq, bk, window,
+                                 diagonal=mask == "both") == 0
+
+    def check(spans, n, tile_of, at, from_queries):
+        kinds = ["skip"] * n
+        for lo, hi, mask in spans:
+            assert 0 <= int(lo) <= max(int(lo), int(hi)) <= n
+            kinds[int(lo):int(hi)] = [mask] * max(int(hi) - int(lo), 0)
+        for other, kind in enumerate(kinds):
+            want = tile_of(other)
+            if kind == "skip":
+                assert not want.any(), (at, other)
+            elif kind is None:
+                assert want.all(), (at, other)
+            else:
+                assert want.any(), (at, other, kind)
+                i, j = (at, other) if from_queries else (other, at)
+                np.testing.assert_array_equal(masked(kind, i, j), want)
+
+    for qi in range(nq):
+        def keys_of(j, qi=qi):
+            return tile(j, qi)
+        check(tiles.kv_spans(qi, bq, bk, nk, minimum=min, window=window),
+              nk, keys_of, qi, True)
+        check(tiles.kv_spans(jnp.int32(qi), bq, bk, nk, window=window), nk,
+              keys_of, qi, True)
+    for kj in range(nk):
+        check(tiles.q_spans(jnp.int32(kj), bq, bk, nq, window), nq,
+              lambda i, kj=kj: tile(kj, i), kj, False)
+
+
 def test_no_kernel_module_imports_a_private_name_of_a_sibling():
     """The boundary: what two kernel files share has a public name (in
     ``attention_tiles``, ``support`` or the file that launches it)."""

@@ -152,28 +152,47 @@ def test_the_gate_takes_the_value_width_and_the_staging_budget():
     for shape in ((8, 2048, 16, 96), (64, 512, 12, 64), (1, 8192, 32, 128)):
         assert fa.flash_attention_supported(shape, shape, bf)
         assert fa._staging(shape[1], shape[3], shape[3], bf) is None
+    # since PR 47 a plain call of 128-wide bfloat16 heads stages up to
+    # 8 MiB (16384 rows, Trinity-Mini's) under the stated limit; twice the
+    # row does not
     long = (1, 16384, 8, 128)
-    assert not fa.flash_attention_supported(long, long, bf)
+    assert fa.flash_attention_supported(long, long, bf)
+    assert fa._staging(16384, 128, 128, bf).vmem_limit_bytes > 16 * 2 ** 20
+    longer = (1, 32768, 8, 128)
+    assert not fa.flash_attention_supported(longer, longer, bf)
 
 
 @pytest.mark.parametrize("shape,dtype,kwargs", [
     ((1, 8192, 8, 192), jnp.bfloat16, {"v_head_dim": 128}),
     ((1, 8192, 8, 192), jnp.bfloat16, {}),
-    ((1, 12288, 8, 128), jnp.bfloat16, {}),
+    ((1, 24576, 8, 128), jnp.bfloat16, {}),
     ((1, 6144, 8, 128), jnp.float32, {}),
     ((1, 8192, 8, 192), jnp.bfloat16, {"v_head_dim": 256,
                                        "shared_key_dim": 64}),
-], ids=["keys192_values128", "heads192", "12288x128", "6144x128_f32",
+], ids=["keys192_values128", "heads192", "24576x128", "6144x128_f32",
         "glm5_at_8192"])
 def test_only_the_measured_shared_key_shape_stages_over_the_default(
         shape, dtype, kwargs):
-    """More than 4 MiB a head is admitted for the shared-key call alone
-    and up to the 5 MiB that was compiled and measured (the cell's): a
-    plain call of that size stays XLA's or the ring's, as before."""
+    """More than 4 MiB a head is admitted for the shared-key call up to
+    the 5 MiB that was compiled and measured (JoyAI's cell) and, since
+    PR 47, for a plain call of 128-wide two-byte heads up to 8 MiB
+    (Trinity-Mini's cell: 16384 rows): wider heads and float32 of that
+    size stay XLA's or the ring's, as before (compiled for a v5e their
+    walks take 55 to 76 MB of scoped VMEM, over the stated 48 MiB)."""
     assert fa._staged_bytes(shape[1], shape[3] + kwargs.get(
         "shared_key_dim", 0), kwargs.get("v_head_dim", shape[3]),
         dtype) > fa._STAGED_DEFAULT
     assert not fa.flash_attention_supported(shape, shape, dtype, **kwargs)
+
+
+@pytest.mark.parametrize("rows", [12288, 16384])
+def test_a_plain_call_of_128_wide_heads_stages_up_to_8_mib(rows):
+    shape = (1, rows, 32, 128)
+    kv = (1, rows, 4, 128)
+    assert fa._STAGED_DEFAULT < fa._staged_bytes(
+        rows, 128, 128, jnp.bfloat16) <= fa._STAGED_PLAIN
+    assert fa.flash_attention_supported(shape, kv, jnp.bfloat16)
+    assert not fa.flash_attention_supported(shape, kv, jnp.float32)
 
 
 def test_a_value_width_of_its_own_is_admitted_under_the_default():
