@@ -639,6 +639,73 @@ def check_ssd_scan(errs, shape=(2, 8192, 64, 64), groups=8, state=128,
             errs[f"{tag}_d{n}"] = _close(a, b, 4 * BF16_TOL, f"{tag} d{n}")
 
 
+def _call_ms(fn, *args, calls=10):
+    """Milliseconds a call of jitted ``fn``, on the host's clock over
+    ``calls`` back to back after one that compiles: a smoke timing."""
+    import jax
+    jax.block_until_ready(fn(*args))
+    t = time.perf_counter()
+    for _ in range(calls):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return round((time.perf_counter() - t) / calls * 1e3, 3)
+
+
+def check_causal_conv(errs, shape=(2, 8192, 10304), first=4096,
+                      parts=(4096, 1024, 1024), taps=4, tag="conv"):
+    """A mixer's causal convolution at the two state-space cells' shapes
+    (the in-projection's output whole in bfloat16, the channels from
+    ``first`` on, x, B and C back apart): the two kernels
+    (ops/pallas/causal_conv.py) and XLA's form over XLA's slices
+    (ops/ssm.py) each against that form in float32, the parts and the
+    gradients to the operand, the taps and the bias, worst element over
+    the largest; and what a forward and a forward + backward of each take
+    alone (PERF.md's table)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import causal_conv as kernels
+    from paddle_tpu.ops.ssm import causal_conv1d
+    C, f32 = sum(parts), jnp.float32
+    x = _rnd(71, shape, jnp.bfloat16)
+    w, b = _rnd(72, (taps, C), jnp.bfloat16, 0.5), _rnd(73, (C,),
+                                                        jnp.bfloat16, 0.5)
+    cts = [_rnd(74 + i, shape[:2] + (n,), f32) for i, n in enumerate(parts)]
+    assert kernels.causal_conv1d_supported(shape, w.shape, x.dtype, first,
+                                           parts, "silu")
+
+    def ours(x, w, b):
+        return kernels.causal_conv1d(x, w, b, "silu", first, parts)
+
+    def xla(x, w, b):
+        out = causal_conv1d(x[:, :, first:first + C], w, b, "silu")
+        return tuple(jnp.split(out, np.cumsum(parts)[:-1], axis=2))
+
+    def both(fn):
+        def loss(x, w, b):
+            outs = fn(x, w, b)
+            return sum(jnp.sum(o.astype(f32) * c)
+                       for o, c in zip(outs, cts)), outs
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))
+
+    (_, want), want_g = both(xla)(x.astype(f32), w.astype(f32),
+                                  b.astype(f32))
+    names = [f"part{i}" for i in range(len(parts))] + ["dx", "dw", "db"]
+    took = {}
+    for side, fn in (("kernels", ours), ("xla", xla)):
+        (_, got), got_g = both(fn)(x, w, b)
+        for n, a, ref in zip(names, got + got_g, want + want_g):
+            errs[f"{tag}_{side}_{n}"] = _close(
+                a, ref, BF16_TOL if n.startswith("part") else 4 * BF16_TOL,
+                f"{tag} {side} {n}")
+        took[side] = (_call_ms(jax.jit(fn), x, w, b),
+                      _call_ms(both(fn), x, w, b))
+    log(f"[kernels] {tag} at {list(shape)} from {first}, parts "
+        f"{list(parts)}: forward / forward + backward alone, ms a call: "
+        f"the kernels {took['kernels'][0]} / {took['kernels'][1]}, XLA's "
+        f"form {took['xla'][0]} / {took['xla'][1]}")
+
+
 def check_moe_combine(errs, n=8192, K=8, H=2048, F=768, held=16, total=128):
     """One chunk of the Keye cell's expert layers (8192 tokens, 8 of 128
     experts a token, 16 held, bfloat16 weights): with the small buffer the
@@ -794,6 +861,9 @@ def phase_kernels(clog, bert=BERT_BASE, serve=SERVE, engine=SERVE_ENGINE):
                                 tag=tag, window=window, heads_a_step=1)
         check_ssd_scan(errs)
         check_ssd_scan(errs, (1, 8192, 64, 64), groups=1, tag="ssd_one_group")
+        check_causal_conv(errs)
+        check_causal_conv(errs, (1, 8192, 8512), parts=(4096, 128, 128),
+                          tag="conv_one_group")
         check_moe_combine(errs)
         check_epilogue(errs, bert)
         check_adam(errs, bert)

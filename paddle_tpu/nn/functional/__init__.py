@@ -2067,16 +2067,46 @@ def _exit_log_probs(z):
 # ---------------------------------------------------------------------------
 
 @jax.named_scope(scopes.SSM_CONV)
-def causal_conv1d(x, weight, bias=None, activation=None, name=None):
+def causal_conv1d(x, weight, bias=None, activation=None, name=None, *,
+                  first_channel=0, parts=None):
     """Depthwise causal convolution over time: x [B, T, C], ``weight``
     [K, C] (tap K - 1 meets the position itself, tap 0 the one K - 1
     back), ``bias`` [C]; ``activation`` None or ``"silu"``.  K shifted
-    multiply-adds in float32; the result has x's type."""
+    multiply-adds in float32; the result has x's type.
+
+    By keyword, for a caller whose channels lie inside a wider array (a
+    mixer's in-projection): ``first_channel``, where the C channels start
+    in x [B, T, W >= first_channel + C], and ``parts``, the widths
+    (summing to C) to return them in -> a tuple, one [B, T, width] a
+    part.  On a TPU, for the shapes they take (the first channel and
+    every part whole lane tiles, a part starting on a multiple of its own
+    width, K <= 8, T a whole number of the kernel's blocks), two Pallas
+    kernels that read the channels where they lie and write each part in
+    rows (ops/pallas/causal_conv.py); XLA's slices and the form of
+    ops/ssm.py elsewhere.  Counted at trace time:
+    ``pallas.selected.causal_conv1d`` / ``causal_conv1d.xla_path``."""
+    from ...ops.pallas import causal_conv as _kernels
+    from ...ops.pallas.support import choose_kernel
     from ...ops.ssm import causal_conv1d as _conv
+    first, C = int(first_channel), int(weight.shape[1])
+    widths = None if parts is None else tuple(int(w) for w in parts)
     args = [x, weight] + ([] if bias is None else [bias])
-    return apply(lambda a, w, *b: _conv(a, w, b[0] if b else None,
-                                        activation),
-                 *args, op_name="causal_conv1d")
+    if choose_kernel("causal_conv1d", _kernels.causal_conv1d_supported(
+            tuple(x.shape), tuple(weight.shape), as_array(x).dtype, first,
+            widths or (C,), activation)):
+        def fn(a, w, *b):
+            out = _kernels.causal_conv1d(a, w, b[0] if b else None,
+                                         activation, first, widths)
+            return out if widths else out[0]
+    else:
+        def fn(a, w, *b):
+            if (first, C) != (0, a.shape[2]):
+                a = a[:, :, first:first + C]
+            out = _conv(a, w, b[0] if b else None, activation)
+            if widths is None:
+                return out
+            return tuple(jnp.split(out, np.cumsum(widths)[:-1], axis=2))
+    return apply(fn, *args, op_name="causal_conv1d")
 
 
 @jax.named_scope(scopes.SSM_SCAN)

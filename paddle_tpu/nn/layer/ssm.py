@@ -100,11 +100,13 @@ class Mamba2Mixer(Layer):
             di, G, N = self.d_inner, self.n_groups, self.state_size
             zxbcdt = self.in_proj(h)
             z = zxbcdt[:, :, :di]
-            xBC = F.causal_conv1d(zxbcdt[:, :, di:di + self.conv_dim],
-                                  self.conv_weight, self.conv_bias, "silu")
-            x = xBC[:, :, :di].reshape([Bt, T, self.num_heads, self.head_dim])
-            B = xBC[:, :, di:di + G * N].reshape([Bt, T, G, N])
-            C = xBC[:, :, di + G * N:].reshape([Bt, T, G, N])
+            # the projection whole: the convolution finds its channels
+            # where they lie and returns the scan's operands apart
+            x, B, C = F.causal_conv1d(
+                zxbcdt, self.conv_weight, self.conv_bias, "silu",
+                first_channel=di, parts=(di, G * N, G * N))
+            x = x.reshape([Bt, T, self.num_heads, self.head_dim])
+            B, C = B.reshape([Bt, T, G, N]), C.reshape([Bt, T, G, N])
             with jax.named_scope(scopes.SSM_SCAN):
                 y = self._scan(x, zxbcdt[:, :, di + self.conv_dim:], B, C)
             y = F.gated_group_rms_norm(y.reshape([Bt, T, di]), z,

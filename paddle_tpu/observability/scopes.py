@@ -65,6 +65,8 @@ LOOP_EXIT = "loop_exit"       # a looped model's exit: the gate, the exit
 SSM = "ssm"                   # a state-space mixer (nn.Mamba2Mixer), every
                               # part of it, its two projections too
 SSM_CONV = "ssm_conv"         # the causal convolution: taps, bias, silu
+                              # (the kernels conv_fwd / conv_bwd, or XLA's
+                              # slices and shifted multiply-adds)
 SSM_SCAN = "ssm_scan"         # softplus, the decays, the chunked scan, D x
 SSM_GATE_NORM = "ssm_gate_norm"  # the gate and the group norm
 FFN = "ffn"                   # a gated feed-forward layer (nn.GatedFFN):
@@ -105,13 +107,18 @@ SPARSE_BWD_DKV = "sparse_bwd_dkv"  # the backward walk: dk, dv and dq; the
 SSD_FWD = "ssd_fwd"           # the state-space scan, chunk by chunk with
                               # the state in VMEM (ops/pallas/ssd_scan.py)
 SSD_BWD = "ssd_bwd"           # its backward, the chunks the other way
+CONV_FWD = "conv_fwd"         # a mixer's causal convolution out of the
+                              # in-projection's rows, x, B and C written
+                              # apart (ops/pallas/causal_conv.py)
+CONV_BWD = "conv_bwd"         # its backward, the taps transposed
 MOE_COMBINE = "moe_combine"   # an expert layer's sums over a token's held
                               # slots, rows in token order (under
                               # MOE_DISPATCH, forward and backward)
 KERNELS = (FLASH_FWD, FLASH_BWD_DKV, EPILOGUE_FWD, EPILOGUE_BWD,
            FUSED_ADAM, PAGED_ATTENTION, COLLECTIVE_MATMUL_CHUNK, EVA_FWD,
            EVA_BWD_DQ, DSA_SCORES, DSA_THRESHOLD, DSA_KL, SPARSE_FWD,
-           SPARSE_BWD_DKV, SSD_FWD, SSD_BWD, MOE_COMBINE)
+           SPARSE_BWD_DKV, SSD_FWD, SSD_BWD, MOE_COMBINE, CONV_FWD,
+           CONV_BWD)
 
 
 # -- values named for a rematerialisation policy -----------------------------

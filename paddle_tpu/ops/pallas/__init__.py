@@ -30,6 +30,10 @@ The tier:
 - ``ssd_scan`` (module) — Mamba-2's chunked state-space scan, fwd + bwd:
   a chunk's decay matrices stay in VMEM and the state rides a scratch
   along the chunk axis, behind ``F.ssd_scan``;
+- ``causal_conv`` (module)   — a mixer's depthwise causal convolution,
+  fwd + bwd: its channels read out of the in-projection's rows where they
+  lie, x, B and C written apart as the scan's kernels take them, the
+  taps shifted along the sublanes in VMEM, behind ``F.causal_conv1d``;
 - ``moe_combine`` (module)   — an expert layer's sums over a token's held
   slots, the buffer's rows streamed once in token order and summed on the
   MXU under scalar-prefetched (token block, row tile) pairs, behind

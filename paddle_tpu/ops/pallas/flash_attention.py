@@ -96,7 +96,8 @@ from .attention_tiles import (BLOCK, block_loops, delta as _delta, dq_add,
                               mask_diagonal, mask_window, online_step, p_ds,
                               prescale, q_spans, rows, rows8, write_row8)
 from .support import (NEG_INF, count_kernel_selection, dot as _dot,
-                      interpret_mode as _interpret, name_residuals, pltpu,
+                      interpret_mode as _interpret, name_residuals,
+                      once_a_shape as _once_a_shape, pltpu,
                       smem_scalar_spec as _smem_scalar_spec)
 
 
@@ -386,19 +387,6 @@ def scores(k, q, mask, qi, j, q_off_ref, k_off_ref, block_q, block_k,
         return _mask_scores(s, q_off_ref, k_off_ref, qi, j, block_q,
                             block_k)
     return s
-
-
-def _once_a_shape(*static_argnums):
-    """Decorator of the kernel launchers: jax's tracing cache
-    serves every call after the first with the same static shape, so a
-    model's identical layers trace each kernel body once (36 kernel
-    calls of the BERT cell cost its set-up 3.8 s of tracing and lowering
-    without this: PERF.md, PR 27).  ``inline=True`` leaves no call in the
-    program: every call site gets the equations under its own scope
-    names.  The interpreter switch is an argument, and so part of the
-    cache's key."""
-    return functools.partial(jax.jit, static_argnums=static_argnums,
-                             inline=True)
 
 
 # ---------------------------------------------------------------------------

@@ -1,10 +1,10 @@
 """Shared plumbing for the Pallas kernel tier.
 
 Every kernel file (flash_attention, eva_attention, sparse_attention,
-ssd_scan, moe_combine, fused_epilogue, fused_adam, paged_attention,
-collective_matmul; attention_tiles holds the attention family's shared
-tile mathematics and launches nothing) needs the same five decisions
-made the same way:
+ssd_scan, causal_conv, moe_combine, fused_epilogue, fused_adam,
+paged_attention, collective_matmul; attention_tiles holds the attention
+family's shared tile mathematics and launches nothing) needs the same
+five decisions made the same way:
 
 - **backend**: the ``pltpu`` import, interpret mode when not on a real
   TPU;
@@ -32,6 +32,8 @@ One place decides all five; the kernel files keep only their math.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
@@ -42,7 +44,8 @@ from ...observability import scopes
 
 __all__ = ["pltpu", "interpret_mode", "tier_enabled", "choose_kernel",
            "dtype_ok", "smem_scalar_spec", "count_kernel_selection",
-           "kernel_selections", "block_rows", "name_residuals", "NEG_INF"]
+           "kernel_selections", "block_rows", "name_residuals",
+           "once_a_shape", "NEG_INF"]
 
 NEG_INF = -1e30
 
@@ -69,6 +72,19 @@ def name_residuals(*values, names=(scopes.ATTN_OUT, scopes.ATTN_LSE)):
     lowers to nothing."""
     return tuple(checkpoint_name(v, n) for v, n in zip(values, names,
                                                        strict=True))
+
+
+def once_a_shape(*static_argnums):
+    """Decorator of the kernel launchers: jax's tracing cache
+    serves every call after the first with the same static shape, so a
+    model's identical layers trace each kernel body once (36 kernel
+    calls of the BERT cell cost its set-up 3.8 s of tracing and lowering
+    without this: PERF.md, PR 27).  ``inline=True`` leaves no call in the
+    program: every call site gets the equations under its own scope
+    names.  The interpreter switch is an argument, and so part of the
+    cache's key."""
+    return functools.partial(jax.jit, static_argnums=static_argnums,
+                             inline=True)
 
 
 def interpret_mode() -> bool:
@@ -122,8 +138,8 @@ def choose_kernel(functional: str, supported: bool) -> bool:
     mechanism's own gate took the call's shapes and dtype (``supported``:
     its ``*_supported``).  Who counts, one rule: a kernel's public entry
     counts its own selection (``pallas.selected.<kernel>``, as
-    `flash_attention`, `sparse_attention`, `ssd_scan`, `moe_combine`,
-    `fused_adam`, `fused_epilogue`, `paged_attention` and
+    `flash_attention`, `sparse_attention`, `ssd_scan`, `causal_conv1d`,
+    `moe_combine`, `fused_adam`, `fused_epilogue`, `paged_attention` and
     `collective_matmul` always have), so a direct call of it is counted too; the chooser counts the
     other side, ``<functional>.xla_path``.  Exactly one of the two moves
     a traced call."""

@@ -244,6 +244,33 @@ def _token_major_passes_walk_the_buffer(text, stats, layers, n, K, H,
                / stats["moe.experts_total"]) < n * K
 
 
+def _conv_hands_the_scan_its_operands(text, stats, mixers, B, T, W,
+                                      conv_dim):
+    """The mixers' convolution as its two kernels (a mixer's forward, its
+    replay and its backward; the counter moves a traced call of the
+    public entry, at least once a mixer), none on XLA's form; the projection's output laid in rows, no [B, T, conv_dim] or
+    [B, T, W] array with T minor, no float32 [B, T, conv_dim] (the parent
+    kept the pre-activation's gradient as one, 805 MB in Nemotron) and
+    nothing between ``conv_fwd`` and ``ssd_fwd``: x, B and C reach the
+    scan's kernel as the convolution's wrote them."""
+    from paddle_tpu.observability import scopes
+    assert (_kernel_count(text, scopes.CONV_FWD),
+            _kernel_count(text, scopes.CONV_BWD)) == (2 * mixers, mixers)
+    assert stats["pallas.selected.causal_conv1d"] >= mixers
+    assert "causal_conv1d.xla_path" not in stats
+    assert f"bf16[{B},{T},{W}]{{2,1,0" in text
+    for gone in (f"[{B},{T},{conv_dim}]{{1,2,0", f"[{B},{T},{W}]{{1,2,0",
+                 f"f32[{B},{T},{conv_dim}]"):
+        assert gone not in text, gone
+    made = set(re.findall(
+        r"%([\w.-]+) = [^\n]*get-tuple-element\(%conv_fwd[.\d]*\)", text))
+    scans = re.findall(
+        r"%ssd_fwd[.\d]* = [^\n]*custom-call\(%([\w.-]+), %[\w.-]+, "
+        r"%[\w.-]+, %([\w.-]+), %([\w.-]+),", text)
+    assert len(scans) == 2 * mixers
+    assert all(op in made for call in scans for op in call), scans
+
+
 def _kept(stats):
     """name -> ``recompute.kept.<name>``: the ``scopes.RESIDUALS`` a
     step's replays are handed, and how many of each."""
@@ -664,7 +691,7 @@ def test_nemotron_h_cell_step_fits_the_chip(one_chip, monkeypatch):
     compiled, n, cfg, mix, footprint, step = _cell_step(
         one_chip, monkeypatch,
         "nemotron_3_nano_30b_a3b.train_bf16_b2_s8192",
-        ("flash_attention", "ssd_scan", "moe_combine"))
+        ("flash_attention", "ssd_scan", "causal_conv", "moe_combine"))
     assert n == cfg["parameters"] == 666_963_456
     assert (cfg["hidden_size"], mix["seq"]) == (2688, 8192)
     pattern = cfg["hybrid_override_pattern"]
@@ -685,6 +712,8 @@ def test_nemotron_h_cell_step_fits_the_chip(one_chip, monkeypatch):
     assert stats["pallas.selected.ssd_scan"] >= mixers
     assert "ssd_scan.xla_path" not in stats
     assert "f32[2,64,8,8,128,128]" not in text
+    _conv_hands_the_scan_its_operands(text, stats, mixers, 2, 8192, 10304,
+                                      6144)
     assert (stats["moe.experts_held"], stats["moe.experts_total"],
             stats["moe.top_k"]) == (8, 128, 6)
     assert stats["moe.gateless_experts"] >= experts
@@ -709,13 +738,19 @@ def test_nemotron_h_cell_step_fits_the_chip(one_chip, monkeypatch):
     vmem = dict(_kernel_vmem(compiled))
     assert {size for name, size in vmem.items()
             if name.startswith(scopes.SSD_FWD)} == {2_560_000}
+    # (the backward's 4,997,120 until its dB and dC went to the
+    # convolution's kernel and not to XLA's pads: 12 KB of staging, PR 48)
     assert {size for name, size in vmem.items()
-            if name.startswith(scopes.SSD_BWD)} == {4_997_120}
-    # under conftest's matmul precision; 15,083,288,064 before the expert
-    # layers' sums walked rows (PR 46; with out and dO lying it compiled
-    # to 15,083,398,144 and ran 1.1 ms slower on the chip: not shipped,
-    # PR 44)
-    assert footprint == 15_094_020_608
+            if name.startswith(scopes.SSD_BWD)} == {4_984_832}
+    assert all(size < 16 * 2 ** 20 for name, size in vmem.items()
+               if name.startswith("conv_")), vmem
+    # under conftest's matmul precision; 15,094,020,608 with the
+    # convolution in XLA (PR 46 and 47: its float32 [2, 8192, 6144] lived
+    # inside one mixer's backward, never at the step's peak; PR 48),
+    # 15,083,288,064 before the expert layers' sums walked rows (PR 46;
+    # with out and dO lying it compiled to 15,083,398,144 and ran 1.1 ms
+    # slower on the chip: not shipped, PR 44)
+    assert footprint == 15_087_344_640
 
 
 def test_granite_cell_step_fits_the_chip(one_chip, monkeypatch):
@@ -735,7 +770,7 @@ def test_granite_cell_step_fits_the_chip(one_chip, monkeypatch):
     monitor.stat_reset()
     compiled, n, cfg, mix, footprint, step = _cell_step(
         one_chip, monkeypatch, "granite_4_0_h_micro.train_bf16_b1_s8192",
-        ("flash_attention", "ssd_scan"))
+        ("flash_attention", "ssd_scan", "causal_conv"))
     assert n == cfg["parameters"] == 772_160_448
     assert (cfg["hidden_size"], mix["batch"], mix["seq"]) == (2048, 1, 8192)
     assert (cfg["mamba_n_heads"], cfg["mamba_n_groups"]) == (64, 1)
@@ -755,6 +790,8 @@ def test_granite_cell_step_fits_the_chip(one_chip, monkeypatch):
             _kernel_count(text, scopes.SSD_BWD)) == (2 * mixers, mixers)
     assert stats["pallas.selected.ssd_scan"] >= mixers
     assert "ssd_scan.xla_path" not in stats
+    _conv_hands_the_scan_its_operands(text, stats, mixers, 1, 8192, 8512,
+                                      4352)
     # the feed-forward layers run under their scope, in every phase
     names = set(re.findall(r'op_name="([^"]*)"', text))
     under = [nm for nm in names if scopes.FFN in nm.split("/")]
@@ -763,15 +800,17 @@ def test_granite_cell_step_fits_the_chip(one_chip, monkeypatch):
     assert {k: (v.shape, v.dtype) for k, v in step._counter_spec.items()} \
         == {scopes.SSM_STATE_SHARE: ((mixers,), jnp.float32),
             scopes.SSM_MEAN_DECAY: ((mixers,), jnp.float32)}
-    # 13,298,342,912 bytes as this test compiled it in PR 43 (under
+    # 13,209,466,880 bytes as this test compiled it in PR 48 (under
     # conftest's matmul precision: not the benchmark's program to the
-    # byte), 10.81 GB of it the state at 14 bytes a parameter
-    assert abs(footprint - 13_298_342_912) < 64 * 2 ** 20, footprint
+    # byte; 13,298,342,912 with the convolution in XLA, PR 43 to 47),
+    # 10.81 GB of it the state at 14 bytes a parameter
+    assert footprint <= 13_298_342_912
+    assert abs(footprint - 13_209_466_880) < 64 * 2 ** 20, footprint
     assert 0.25 * 16 * 2 ** 30 < footprint < 15.75 * 2 ** 30
     # a block of 16 heads of the one group: twice what a group of 8 takes
     vmem = dict(_kernel_vmem(compiled))
     assert all(size < 16 * 2 ** 20 for name, size in vmem.items()
-               if name.startswith("ssd_")), vmem
+               if name.startswith(("ssd_", "conv_"))), vmem
 
 
 def test_trinity_mini_cell_step_fits_the_chip(one_chip, monkeypatch):
@@ -905,6 +944,53 @@ def test_ssd_scan_fwd_bwd(one_chip, monkeypatch, x_shape, groups):
     print(f"ssd_scan at {list(x_shape)} / {list(b_shape)}: "
           + ", ".join(f"{name} {size} bytes of VMEM" for name, size in vmem))
     assert len(vmem) == 2 and all(size < 16 * 2 ** 20 for _, size in vmem)
+
+
+@pytest.mark.parametrize("x_shape,parts", [
+    ((2, 8192, 10304), (4096, 1024, 1024)),   # the Nemotron cell's mixers
+    ((1, 8192, 8512), (4096, 128, 128)),      # the Granite cell's
+], ids=["nemotron_cell", "granite_cell"])
+def test_causal_conv_fwd_bwd(one_chip, monkeypatch, x_shape, parts):
+    """The convolution's two kernels alone at the two state-space cells'
+    shapes (the in-projection's output whole, the channels from 4096 on,
+    x, B and C back apart): both compile for the described v5e inside
+    Mosaic's default scoped VMEM; what the gate refuses sits beside."""
+    conv = importlib.import_module("paddle_tpu.ops.pallas.causal_conv")
+    monkeypatch.setattr(conv, "_interpret", lambda: False)
+    C = sum(parts)
+    w_shape = (4, C)
+
+    def takes(x=x_shape, w=w_shape, dtype=jnp.bfloat16, first=4096, p=parts,
+              activation="silu"):
+        return conv.causal_conv1d_supported(x, w, dtype, first, p,
+                                            activation)
+    assert takes() and takes(activation=None) and takes(dtype=jnp.float32)
+    assert not takes(activation="gelu")
+    assert not takes(w=(9, C))
+    assert not takes(first=4096 + 64) and not takes(first=4096 + 128)
+    assert not takes(x=(x_shape[0], 8200, x_shape[2]))
+    assert not takes(p=parts[:2] + (parts[2] - 64, 64))
+    assert not takes(x=x_shape[:2] + (4096 + C - 128,))
+
+    def loss(x, w, b):
+        outs = conv.causal_conv1d(x, w, b, "silu", 4096, parts)
+        return sum(jnp.sum(o.astype(jnp.float32) ** 2) for o in outs)
+
+    compiled = _compile(jax.value_and_grad(loss, (0, 1, 2)),
+                        _sds(one_chip, x_shape, jnp.bfloat16),
+                        _sds(one_chip, w_shape, jnp.bfloat16),
+                        _sds(one_chip, (C,), jnp.bfloat16))
+    kernels = _mosaic_kernels(compiled)
+    assert len(kernels) == 2 and "conv_fwd" in kernels[0], kernels
+    assert "conv_bwd" in kernels[1], kernels
+    vmem = _kernel_vmem(compiled)
+    print(f"causal_conv at {list(x_shape)} / {list(parts)}: "
+          + ", ".join(f"{name} {size} bytes of VMEM" for name, size in vmem))
+    assert len(vmem) == 2 and all(size < 16 * 2 ** 20 for _, size in vmem)
+    # (alone, XLA lays the gradient it returns, the pad to the operand's
+    # width, with T minor and copies d(xBC) for it; in a cell's step the
+    # pad is an operand of the projection's products and stays in rows)
+    assert f"f32[{x_shape[0]},8192,{C}]" not in compiled.as_text()
 
 
 @pytest.mark.parametrize("weighted", [True, False],

@@ -3,6 +3,7 @@
 position-at-a-time recurrence and the mixer of
 benchmark/reference/nemotron_h.py: values and every gradient, in
 float32."""
+import functools
 import os
 import sys
 
@@ -16,6 +17,7 @@ import paddle_tpu.nn.functional as F
 from paddle_tpu import nn, observability
 from paddle_tpu.observability import device_counters, scopes
 from paddle_tpu.ops import ssm
+from paddle_tpu.ops.pallas import causal_conv as conv_kernels
 from paddle_tpu.ops.pallas import ssd_scan as kernels
 from paddle_tpu.utils import monitor
 
@@ -293,6 +295,166 @@ def test_causal_conv_is_a_depthwise_convolution():
         ssm.causal_conv1d(x, w, b, "gelu")
 
 
+# ------------------------------------------ the convolution's two kernels --
+def _conv_inputs(B, T, W, parts, K, dtype, seed=5):
+    ks = jax.random.split(jax.random.key(seed), 3 + len(parts))
+    C = sum(parts)
+    return ((jax.random.normal(ks[0], (B, T, W)).astype(dtype),
+             (0.5 * jax.random.normal(ks[1], (K, C))).astype(dtype),
+             (0.5 * jax.random.normal(ks[2], (C,))).astype(dtype)),
+            [jax.random.normal(k, (B, T, n)) for k, n in zip(ks[3:], parts)])
+
+
+def _conv_and_slices(x, w, b, activation, first, parts):
+    """``ops.ssm.causal_conv1d`` over XLA's slice of the channels, its
+    result sliced into the parts: what the kernels stand in for."""
+    out = ssm.causal_conv1d(x[:, :, first:first + w.shape[1]], w, b,
+                            activation)
+    starts = [sum(parts[:i]) for i in range(len(parts))]
+    return tuple(out[:, :, s:s + n] for s, n in zip(starts, parts))
+
+
+@pytest.mark.parametrize("B,T,W,first,parts,K,dtype,activation", [
+    # the two cells' widths at a short T: one T block of one chunk
+    (1, 32, 10304, 4096, (4096, 1024, 1024), 4, jnp.bfloat16, "silu"),
+    (1, 32, 8512, 4096, (4096, 128, 128), 4, jnp.bfloat16, "silu"),
+    # a first channel of 0; one T block of four chunks
+    (2, 256, 640, 0, (256, 128, 128), 4, jnp.float32, "silu"),
+    # a later lane tile; three T blocks of two chunks: the halo across a
+    # block's edge and across a chunk's, zeros at both rows' starts
+    (2, 384, 1024, 256, (256, 128, 128), 4, jnp.float32, "silu"),
+    # two T blocks of eight chunks
+    (2, 1024, 384, 128, (128,), 4, jnp.float32, "silu"),
+    # three T blocks of one chunk, bfloat16: a block is one tile of rows
+    (2, 48, 512, 128, (128, 128), 4, jnp.bfloat16, "silu"),
+    (2, 192, 512, 128, (128, 128), 3, jnp.float32, None),
+    (1, 64, 256, 0, (256,), 8, jnp.float32, "silu"),
+    (1, 32, 256, 128, (128,), 1, jnp.float32, "silu"),
+], ids=["nemotron", "granite", "first_0", "later_tile", "two_blocks",
+        "tile_blocks_bf16", "no_activation", "eight_taps", "one_tap"])
+def test_the_conv_kernels_are_the_convolution_and_its_slices(
+        kernels_on, B, T, W, first, parts, K, dtype, activation):
+    """``conv_fwd`` / ``conv_bwd`` in interpret mode against
+    ``ops.ssm.causal_conv1d`` plus slices in float32: every part, and the
+    gradients to the operand (zeros outside the convolved channels), the
+    taps and the bias."""
+    assert conv_kernels.causal_conv1d_supported(
+        (B, T, W), (K, sum(parts)), dtype, first, parts, activation)
+    args, cts = _conv_inputs(B, T, W, parts, K, dtype)
+
+    def loss(fn, *a):
+        outs = fn(*a, activation, first, parts)
+        return sum(jnp.sum(o.astype(jnp.float32) * c)
+                   for o, c in zip(outs, cts)), outs
+
+    (_, got), got_g = jax.value_and_grad(
+        functools.partial(loss, conv_kernels.causal_conv1d), (0, 1, 2),
+        has_aux=True)(*args)
+    (_, want), want_g = jax.value_and_grad(
+        functools.partial(loss, _conv_and_slices), (0, 1, 2),
+        has_aux=True)(*(a.astype(jnp.float32) for a in args))
+    # float32: the same products summed in another order; bfloat16: one
+    # rounding of the result (2 ** -9 of it) on the kernels' side
+    tol = 1e-5 if dtype == jnp.float32 else 6e-3
+    for name, g, w in zip(
+            [f"part {i}" for i in range(len(parts))] + ["dx", "dw", "db"],
+            got + got_g, want + want_g):
+        assert g.shape == w.shape and g.dtype == dtype, name
+        scale = float(jnp.max(jnp.abs(w)))
+        np.testing.assert_allclose(g.astype(jnp.float32), w, rtol=0,
+                                   atol=tol * scale, err_msg=name)
+    outside = jnp.concatenate([got_g[0][:, :, :first],
+                               got_g[0][:, :, first + sum(parts):]], 2)
+    assert not outside.size or float(jnp.max(jnp.abs(outside))) == 0.0
+
+
+def _conv_through_the_functional(B, T, W, first, parts, K, dtype,
+                                 activation="silu"):
+    """``F.causal_conv1d`` by keyword on a draw -> (its results, the
+    arguments, the moves of ``pallas.selected.causal_conv1d`` and of
+    ``causal_conv1d.xla_path``)."""
+    args, _ = _conv_inputs(B, T, W, parts, K, dtype, seed=11)
+
+    def counts():
+        s = monitor.all_stats()
+        return (s.get("pallas.selected.causal_conv1d", 0),
+                s.get("causal_conv1d.xla_path", 0))
+
+    before = counts()
+    got = F.causal_conv1d(*(paddle.to_tensor(a) for a in args), activation,
+                          first_channel=first, parts=parts)
+    return (tuple(t.data for t in got), args,
+            tuple(a - b for a, b in zip(counts(), before)))
+
+
+@pytest.mark.parametrize("B,T,W,first,parts,K,dtype", [
+    (1, 64, 512, 128, (128, 64, 64), 4, jnp.float32),   # parts of 64 lanes
+    (1, 100, 512, 128, (128, 128), 4, jnp.float32),     # no whole T blocks
+    (1, 64, 512, 128, (128, 128), 9, jnp.float32),      # nine taps
+    (1, 64, 512, 64, (128, 128), 4, jnp.float32),       # a first of 64
+    (1, 64, 512, 128, (256, 128), 4, jnp.float32),      # a part that does
+                                                        # not start on a
+                                                        # multiple of itself
+    (1, 64, 512, 128, (128, 128), 4, jnp.float16),      # no kernel dtype
+], ids=["part_of_64", "ragged_T", "nine_taps", "first_of_64",
+        "part_off_its_width", "dtype"])
+def test_a_conv_outside_the_gate_runs_the_xla_form(kernels_on, B, T, W,
+                                                   first, parts, K, dtype):
+    assert not conv_kernels.causal_conv1d_supported(
+        (B, T, W), (K, sum(parts)), dtype, first, parts, "silu")
+    got, args, moved = _conv_through_the_functional(B, T, W, first, parts, K,
+                                                    dtype)
+    assert moved == (0, 1)
+    for g, w in zip(got, _conv_and_slices(*args, "silu", first, parts),
+                    strict=True):
+        np.testing.assert_array_equal(g, w)
+
+
+def test_another_activation_still_raises(kernels_on):
+    assert not conv_kernels.causal_conv1d_supported(
+        (1, 64, 512), (4, 256), jnp.float32, 128, (128, 128), "gelu")
+    before = dict(monitor.all_stats())
+    with pytest.raises(ValueError, match="activation"):
+        _conv_through_the_functional(1, 64, 512, 128, (128, 128), 4,
+                                     jnp.float32, "gelu")
+    after = monitor.all_stats()
+    assert after.get("causal_conv1d.xla_path", 0) \
+        == before.get("causal_conv1d.xla_path", 0) + 1
+    assert after.get("pallas.selected.causal_conv1d", 0) \
+        == before.get("pallas.selected.causal_conv1d", 0)
+
+
+def test_the_conv_functional_takes_the_kernels_where_the_tier_is_on(
+        kernels_on):
+    size = (2, 128, 512, 128, (128, 128), 4, jnp.float32)
+    got, args, moved = _conv_through_the_functional(*size)
+    assert moved == (1, 0)
+    for g, w in zip(got, _conv_and_slices(*args, "silu", 128, (128, 128)),
+                    strict=True):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
+    # the plain call: every channel of x, one array back
+    x, w, b = args[0][:, :, 128:384], args[1], args[2]
+    before = monitor.all_stats().get("pallas.selected.causal_conv1d", 0)
+    one = F.causal_conv1d(paddle.to_tensor(x), paddle.to_tensor(w),
+                          paddle.to_tensor(b), "silu").data
+    assert monitor.all_stats()["pallas.selected.causal_conv1d"] == before + 1
+    np.testing.assert_allclose(one, ssm.causal_conv1d(x, w, b, "silu"),
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_no_conv_kernel_is_selected_off_a_tpu_without_the_opt_in():
+    """On the CPU, the tier's opt-in unset, a shape the gate takes runs
+    XLA's slices and form to the bit and the other counter moves."""
+    size = (2, 128, 512, 128, (128, 128), 4, jnp.float32)
+    assert conv_kernels.causal_conv1d_supported(
+        size[:3], (4, 256), jnp.float32, 128, (128, 128), "silu")
+    got, args, moved = _conv_through_the_functional(*size)
+    assert moved == (0, 1)
+    for g, w in zip(got, _conv_and_slices(*args, "silu", 128, (128, 128)),
+                    strict=True):
+        np.testing.assert_array_equal(g, w)
+
+
 def test_gated_group_norm_gates_before_it_norms():
     ks = jax.random.split(jax.random.key(2), 3)
     y, z = (jax.random.normal(k, (3, 5, 24)) for k in ks[:2])
@@ -387,17 +549,22 @@ def test_the_mixer_refuses_groups_that_do_not_divide_the_heads():
         nn.Mamba2Mixer(32, 6, 8, 4, 16)
 
 
-def test_the_mixer_through_the_kernels_is_the_mixer(kernels_on):
-    """``nn.Mamba2Mixer`` at sizes the gate takes (8 heads of 64 in one
-    group on a state of 128, chunks of 128 over 200 positions): the branch
-    and its gradients to the input and to every parameter with the scan
-    on the kernels, against the same layer on the XLA form."""
+@pytest.mark.parametrize("T,conv", [(200, (0, 1)), (256, (1, 0))],
+                         ids=["padded_scan_xla_conv", "both_on_kernels"])
+def test_the_mixer_through_the_kernels_is_the_mixer(kernels_on, T, conv):
+    """``nn.Mamba2Mixer`` at sizes the gates take (8 heads of 64 in one
+    group on a state of 128, chunks of 128): the branch and its gradients
+    to the input and to every parameter with the scan on the kernels,
+    against the same layer on the XLA form.  Over 200 positions the scan
+    pads its last chunk and the convolution, whose kernels want whole T
+    blocks, takes XLA's form; over 256 the convolution's kernels hand
+    the scan's their x, B and C."""
     from paddle_tpu.core.flags import set_flags
     layer = nn.Mamba2Mixer(32, 8, 64, 1, 128, 4, 128, 1e-5)
     ks = jax.random.split(jax.random.key(17), 9)
     leaves = [0.3 * jax.random.normal(k, tuple(p.shape))
               for k, (_, p) in zip(ks, layer.named_parameters())]
-    a = jax.random.normal(ks[-1], (1, 200, 32))
+    a = jax.random.normal(ks[-1], (1, T, 32))
 
     def program(a, *leaves):
         for (_, p), leaf in zip(layer.named_parameters(), leaves):
@@ -409,7 +576,10 @@ def test_the_mixer_through_the_kernels_is_the_mixer(kernels_on):
     grad = jax.value_and_grad(program, range(len(leaves) + 1))
     monitor.stat_reset()
     got, got_g = grad(a, *leaves)
-    assert monitor.all_stats()["pallas.selected.ssd_scan"] == 1
+    stats = monitor.all_stats()
+    assert stats["pallas.selected.ssd_scan"] == 1
+    assert (stats.get("pallas.selected.causal_conv1d", 0),
+            stats.get("causal_conv1d.xla_path", 0)) == conv
     set_flags({"pallas_interpret": False})
     want, want_g = grad(a, *leaves)
     assert monitor.all_stats()["ssd_scan.xla_path"] == 1

@@ -220,7 +220,7 @@ def _pallas_calls(jaxpr, out):
 @pytest.fixture(scope="module")
 def kernel_calls():
     """(name, name stack) of every ``pallas_call`` equation the
-    nineteen sites trace, in interpret mode: no chip needed."""
+    twenty-one sites trace, in interpret mode: no chip needed."""
     from paddle_tpu.ops.pallas.collective_matmul import chunk_matmul
     from paddle_tpu.ops.pallas.fused_adam import fused_adam_update
     from paddle_tpu.ops.pallas.fused_epilogue import fused_linear_epilogue
@@ -268,6 +268,13 @@ def kernel_calls():
             lambda *a: jnp.sum(ssd.ssd_scan(*a)), range(6)))(
             x, jnp.ones((1, 128, 8)), -jnp.ones((8,)), bc, bc,
             jnp.ones((8,))).jaxpr, [])
+        # a mixer's convolution: the forward kernel and the backward's
+        conv = importlib.import_module("paddle_tpu.ops.pallas.causal_conv")
+        found += _pallas_calls(jax.make_jaxpr(jax.grad(
+            lambda *a: sum(jnp.sum(o) for o in conv.causal_conv1d(
+                *a, "silu", 128, (128, 128))), (0, 1, 2)))(
+            jnp.ones((1, 32, 512)), jnp.ones((4, 256)),
+            jnp.ones((256,))).jaxpr, [])
         # an expert layer's two sums over a token's held slots: the
         # weighted one forward, the plain one in the dispatch's transpose
         moe = importlib.import_module("paddle_tpu.ops.moe")
@@ -313,7 +320,7 @@ def test_every_pallas_call_site_carries_its_name(kernel_calls, kernel):
 
 
 def test_no_pallas_call_is_left_without_a_name(kernel_calls):
-    assert len(kernel_calls) == 19
+    assert len(kernel_calls) == 21
     assert {name for name, _ in kernel_calls} == set(scopes.KERNELS)
     src = os.path.join(os.path.dirname(paddle.__file__), "ops", "pallas")
     for path in glob.glob(os.path.join(src, "*.py")):
