@@ -86,33 +86,33 @@ def load_parts(cell_name, rehearse=False):
 
 
 # ------------------------------------------------- what jax compiled/ran --
-class CompileLog:
-    """Every executable jax builds or loads, as jax itself reports it
-    (``jax.monitoring``): (function name, seconds) per compile, and how
-    many of them the persistent cache answered."""
+def compiled_so_far():
+    """Every executable jax has built or loaded, as the program's set-up
+    timeline has it (``observability.setup_report``, every owner):
+    ({function name: (count, seconds)}, how many the persistent cache
+    answered)."""
+    from paddle_tpu import observability
+    rep = observability.setup_report()
+    built = {}
+    for owner in rep["owners"].values():
+        for phase in ("load", "compile"):
+            fns = owner["phases"].get(phase, {}).get("functions", {})
+            for name, f in fns.items():
+                n, secs = built.get(name, (0, 0.0))
+                built[name] = (n + f["count"], secs + f["seconds"])
+    return built, sum(c["loads"] for c in rep["cache"].values())
 
-    def __init__(self):
-        import jax.monitoring as mon
-        self.compiles = []
-        self.cache_hits = 0
-        mon.register_event_duration_secs_listener(self._on_duration)
-        mon.register_event_listener(self._on_event)
 
-    def _on_duration(self, name, secs, **kw):
-        if name == "/jax/core/compile/backend_compile_duration":
-            self.compiles.append((kw.get("fun_name", "?"), float(secs)))
-
-    def _on_event(self, name, **kw):
-        if name == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def mark(self):
-        return len(self.compiles), self.cache_hits
-
-    def since(self, mark):
-        """(compiles, their seconds, cache hits) since ``mark``."""
-        new = self.compiles[mark[0]:]
-        return new, sum(s for _, s in new), self.cache_hits - mark[1]
+def compiled_since(mark):
+    """([(function name, count, seconds)], their seconds, cache hits)
+    since ``mark`` (a ``compiled_so_far()``)."""
+    built, hits = compiled_so_far()
+    new = []
+    for name, (n, secs) in built.items():
+        n0, secs0 = mark[0].get(name, (0, 0.0))
+        if n > n0:
+            new.append((name, n - n0, secs - secs0))
+    return new, sum(c[2] for c in new), hits - mark[1]
 
 
 def executable_text(module_name):
@@ -160,12 +160,12 @@ def peak_bytes():
     return jax.devices()[0].memory_stats()["peak_bytes_in_use"]
 
 
-def report(phase, clog, mark, extra):
-    compiles, secs, hits = clog.since(mark)
-    big = sorted(compiles, key=lambda c: -c[1])[:3]
-    log(f"[{phase}] compiles={len(compiles)} compile_s={secs:.1f} "
-        f"persistent_cache_hits={hits} "
-        f"slowest={[(n, round(s, 1)) for n, s in big]}")
+def report(phase, mark, extra):
+    compiles, secs, hits = compiled_since(mark)
+    big = sorted(compiles, key=lambda c: -c[2])[:3]
+    log(f"[{phase}] compiles={sum(c[1] for c in compiles)} "
+        f"compile_s={secs:.1f} persistent_cache_hits={hits} "
+        f"slowest={[(n, round(s, 1)) for n, _, s in big]}")
     log(f"[{phase}] peak_bytes_in_use(process so far)={peak_bytes()} "
         + " ".join(f"{k}={v}" for k, v in extra.items()))
 
@@ -176,7 +176,7 @@ def finite(values, what):
 
 
 # ----------------------------------------------------------------- train --
-def phase_train(clog, steps=6, rehearse=False):
+def phase_train(steps=6, rehearse=False):
     """The BERT cell's construction (benchmark/runners/train_step.py):
     the cell's model and loss -> O2 decorate -> TrainStep, dropout on."""
     import jax.numpy as jnp
@@ -190,7 +190,7 @@ def phase_train(clog, steps=6, rehearse=False):
     cfg = {**cfg, "hidden_dropout_prob": TRAIN_DROPOUT,
            "attention_probs_dropout_prob": TRAIN_DROPOUT}
     o = cell["optimizer"]
-    mark = clog.mark()
+    mark = compiled_so_far()
     paddle.seed(2024)
     model, loss_fn = model_mod.build(cfg, cell["model_args"])
     opt = optimizer.AdamW(
@@ -206,17 +206,19 @@ def phase_train(clog, steps=6, rehearse=False):
     x = jnp.asarray(rng.randint(0, vocab, shape, dtype=np.int32))
     y = jnp.asarray(rng.randint(0, vocab, shape, dtype=np.int32))
 
-    losses, ms, marks = [], [], []
-    for _ in range(steps):
-        marks.append(clog.mark())
+    losses, ms = [], []
+    for i in range(steps):
+        if i == 2:          # steps 0 and 1 may build helpers
+            third = compiled_so_far()
         t0 = time.perf_counter()
         losses.append(float(step(x, y)))        # float() waits for the chip
         ms.append((time.perf_counter() - t0) * 1000)
     finite(losses, "train loss")
     if not losses[-1] < losses[0]:
         raise AssertionError(f"train loss did not fall: {losses}")
-    n_step = sum("step_fn" in name for name, _ in clog.since(mark)[0])
-    late = clog.since(marks[2])[0]     # steps 0 and 1 may build helpers
+    n_step = sum(n for name, n, _ in compiled_since(mark)[0]
+                 if "step_fn" in name)
+    late = compiled_since(third)[0]
     if n_step != 1 or late:
         raise AssertionError(
             f"train step compiled {n_step} times (want 1); compiles "
@@ -225,7 +227,7 @@ def phase_train(clog, steps=6, rehearse=False):
     # per layer would be 36; one is enough to prove the tier
     n_kernels = mosaic_kernels(executable_text("jit_step_fn"), 3,
                                "train step")
-    report("train", clog, mark, {
+    report("train", mark, {
         "losses": [round(v, 4) for v in losses],
         "first_step_ms(compile incl.)": round(ms[0]),
         "smoke_step_ms": [round(v, 1) for v in ms[2:]],
@@ -256,7 +258,7 @@ def build_static(cell, cfg, mix, model_mod):
     return prog, loss, feed
 
 
-def phase_static(clog, steps=3, rehearse=False):
+def phase_static(steps=3, rehearse=False):
     """The static cell's program through the Executor, default flags.
     Compiled for a described v5e it has 14.86 GiB live of the chip's
     15.75 (PERF.md, Findings), so it fits — as long as the phase before
@@ -265,7 +267,7 @@ def phase_static(clog, steps=3, rehearse=False):
     from paddle_tpu.observability import explain_compiles
     from paddle_tpu.utils import monitor
 
-    mark, sel0 = clog.mark(), selections()
+    mark, sel0 = compiled_so_far(), selections()
     paddle.enable_static()
     try:
         cell, cfg, mix, model_mod = load_parts(STATIC_CELL, rehearse)
@@ -299,7 +301,7 @@ def phase_static(clog, steps=3, rehearse=False):
         # Adam adds one kernel per parameter XLA did not fold away
         n_kernels = mosaic_kernels(executable_text("jit_train_fn"),
                                    2 * n_epi + 1, "static step")
-        report("static", clog, mark, {
+        report("static", mark, {
             "losses": [round(v, 4) for v in losses],
             "first_step_ms(compile incl.)": round(ms[0]),
             "smoke_step_ms": [round(v, 1) for v in ms[1:]],
@@ -315,7 +317,7 @@ def phase_static(clog, steps=3, rehearse=False):
 
 
 # ----------------------------------------------------------------- serve --
-def phase_serve(clog, model_cfg=SERVE, engine_cfg=SERVE_ENGINE,
+def phase_serve(model_cfg=SERVE, engine_cfg=SERVE_ENGINE,
                 prompt_lens=SERVE_PROMPT_LENS, new_tokens=SERVE_NEW_TOKENS):
     """GenerationEngine behind serving.ServingServer, as tools/serve.py
     wires an engine: bind not-ready, warm up, mark ready, serve."""
@@ -323,7 +325,7 @@ def phase_serve(clog, model_cfg=SERVE, engine_cfg=SERVE_ENGINE,
     from paddle_tpu.core.flags import get_flag, set_flags
     from paddle_tpu.ops import attention as attn
 
-    mark, sel0 = clog.mark(), selections()
+    mark, sel0 = compiled_so_far(), selections()
     model = serving.PagedDecoderLM(**model_cfg)
     rng = np.random.RandomState(7)
     prompts = [rng.randint(1, model_cfg["vocab_size"], (n,)).tolist()
@@ -339,7 +341,7 @@ def phase_serve(clog, model_cfg=SERVE, engine_cfg=SERVE_ENGINE,
         variants = eng.warmup()
         warm_s = time.perf_counter() - t0
         srv.mark_ready()
-        warm = clog.mark()
+        warm = compiled_so_far()
 
         results, errors, lat = {}, [], {}
 
@@ -366,7 +368,7 @@ def phase_serve(clog, model_cfg=SERVE, engine_cfg=SERVE_ENGINE,
             raise AssertionError("a /generate request did not return")
         st = eng.stats()
         c = st["counters"]
-        late = clog.since(warm)[0]
+        late = compiled_since(warm)[0]
         late = [x for x in late if "step_fn" in x[0] or "prefill" in x[0]]
         if st["recompiles_after_warmup"] or late:
             raise AssertionError(
@@ -424,7 +426,7 @@ def phase_serve(clog, model_cfg=SERVE, engine_cfg=SERVE_ENGINE,
             f"kernel-tier tokens equal the reference tier's in only "
             f"{len(same)} of {len(results)} requests; first "
             f"differences at {first_diff}")
-    report("serve", clog, mark, {
+    report("serve", mark, {
         "warm_variants": variants, "warmup_s": round(warm_s, 1),
         "requests": len(results), "wall_s": round(wall, 2),
         "smoke_request_ms": [round(lat[i]) for i in sorted(lat)],
@@ -842,13 +844,13 @@ def check_chunk_matmul(errs, bert):
             f"chunk matmul {tag}")
 
 
-def phase_kernels(clog, bert=BERT_BASE, serve=SERVE, engine=SERVE_ENGINE):
+def phase_kernels(bert=BERT_BASE, serve=SERVE, engine=SERVE_ENGINE):
     import jax
 
     from paddle_tpu.ops.pallas.support import interpret_mode
     if interpret_mode():
         raise AssertionError("Pallas interpret mode is on: not a TPU run")
-    mark, sel0, errs = clog.mark(), selections(), {}
+    mark, sel0, errs = compiled_so_far(), selections(), {}
     with jax.default_matmul_precision("highest"):
         check_flash(errs, bert)
         check_flash_dropout(errs, bert)
@@ -870,13 +872,13 @@ def phase_kernels(clog, bert=BERT_BASE, serve=SERVE, engine=SERVE_ENGINE):
         check_paged(errs, serve, engine)
         check_chunk_matmul(errs, bert)
     gc.collect()
-    report("kernels", clog, mark, {
+    report("kernels", mark, {
         "tolerance": f"f32 {F32_TOL:g} / bf16 {BF16_TOL:g} (x max|ref|)",
         "rel_err": errs, "selected": selected_since(sel0)})
 
 
 # ------------------------------------------------------------- callbacks --
-def phase_callbacks(clog):
+def phase_callbacks():
     """Host callbacks on this backend: a traced print shows the runtime
     value, a traced assert checks it."""
     import jax
@@ -884,7 +886,7 @@ def phase_callbacks(clog):
     import paddle_tpu as paddle
     from paddle_tpu import jit
 
-    mark = clog.mark()
+    mark = compiled_so_far()
 
     @jit.to_static
     def f(x):
@@ -914,11 +916,11 @@ def phase_callbacks(clog):
     # run (private name: there is no public way to drop a failed token)
     from jax._src import dispatch
     dispatch.runtime_tokens.clear()
-    report("callbacks", clog, mark, {"print": "ok", "assert": "ok"})
+    report("callbacks", mark, {"print": "ok", "assert": "ok"})
 
 
 # ------------------------------------------------------------- multichip --
-def phase_multichip(clog, steps=3, rehearse=False):
+def phase_multichip(steps=3, rehearse=False):
     """README "Sharded training": fleet.init + sharding_rules on a
     {dp: 2, mp: 2} mesh through the Executor, against the same seeded
     program on one device of this host."""
@@ -926,7 +928,7 @@ def phase_multichip(clog, steps=3, rehearse=False):
     import paddle_tpu.distributed as dist
     from paddle_tpu.distributed.mesh import get_mesh
 
-    mark = clog.mark()
+    mark = compiled_so_far()
 
     def build(sharded):
         """The static cell's program; when sharded, its optimizer goes
@@ -952,13 +954,13 @@ def phase_multichip(clog, steps=3, rehearse=False):
             losses, ms = [], []
             for i in range(steps):
                 if i == 1:
-                    warm = clog.mark()
+                    warm = compiled_so_far()
                 t0 = time.perf_counter()
                 out = exe.run(prog, feed=feed, fetch_list=[loss])
                 losses.append(float(np.asarray(out[0])))
                 ms.append((time.perf_counter() - t0) * 1000)
             placement = _placement(exe, prog) if sharded else None
-            late = [c for c in clog.since(warm)[0] if "train_fn" in c[0]]
+            late = [c for c in compiled_since(warm)[0] if "train_fn" in c[0]]
             if exe.compile_count != 1 or late:
                 raise AssertionError(
                     f"Executor compiled {exe.compile_count} times; jax "
@@ -982,7 +984,7 @@ def phase_multichip(clog, steps=3, rehearse=False):
         raise AssertionError(f"losses not falling: {four} / {one}")
     if dict(get_mesh().shape) != {"dp": 2, "mp": 2}:
         raise AssertionError("mesh is not {dp: 2, mp: 2}")
-    report("multichip", clog, mark, {
+    report("multichip", mark, {
         "mesh": "{dp: 2, mp: 2}", "cell": STATIC_CELL,
         "one_device_losses": [round(v, 4) for v in one],
         "four_chip_losses": [round(v, 4) for v in four],
@@ -1080,24 +1082,23 @@ def main(argv=None):
         f"({'JAX_COMPILATION_CACHE_DIR' if os.environ.get('JAX_COMPILATION_CACHE_DIR') else 'set by core.xla_env.place_compile_cache'})")
     log("timings below are smoke timings, not benchmark numbers")
 
-    clog = CompileLog()
-    t0 = time.perf_counter()
+    begin, t0 = compiled_so_far(), time.perf_counter()
     phases = ([phase_multichip] if args.multichip else
               [phase_kernels, phase_callbacks, phase_train, phase_static,
                phase_serve])
     for phase in phases:
         t = time.perf_counter()
-        phase(clog)
+        phase()
         # the two BERT phases each need nearly the whole chip: what a
         # phase leaves behind is the next one's out-of-memory
         gc.collect()
         log(f"[{phase.__name__[6:]}] passed in "
             f"{time.perf_counter() - t:.1f}s; live device arrays after "
             f"it: {sum(a.nbytes for a in jax.live_arrays())} bytes")
-    compiles, secs, hits = clog.since((0, 0))
+    compiles, secs, hits = compiled_since(begin)
     log(f"all phases passed in {time.perf_counter() - t0:.1f}s; "
-        f"{len(compiles)} compiles ({secs:.1f}s), {hits} answered by the "
-        f"persistent cache")
+        f"{sum(c[1] for c in compiles)} compiles ({secs:.1f}s), {hits} "
+        f"answered by the persistent cache")
     print(json.dumps({"ok": True, "device": {
         "platform": devs[0].platform, "kind": devs[0].device_kind,
         "count": len(devs)}}), flush=True)

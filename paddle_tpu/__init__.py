@@ -14,6 +14,7 @@ from __future__ import annotations
 import time as _time
 
 _t_import = _time.perf_counter()     # setup.import_s, see the last lines
+_t_import_wall = _time.time()        # the span setup.import, on jax's clock
 
 __version__ = "0.1.0"
 
@@ -40,6 +41,10 @@ if "JAX_DEFAULT_PRNG_IMPL" not in _os.environ:
 # XLA's parser, so a CPU process never gets TPU flags appended)
 from .core import xla_env as _xla_env  # noqa: E402
 
+# whether the caller made jax's backend before importing the package (then
+# ``import jax`` and the client lie in setup.before_import_s); nothing
+# above touches it
+_backend_was_made = _xla_env._backend_initialized()
 _xla_env.apply_latency_hiding_flags()
 
 from .core import (Parameter, Tensor, enable_grad, get_default_dtype,  # noqa
@@ -139,5 +144,8 @@ from .ops.math import (add_n, broadcast_shape, mv, rank, shape,  # noqa
                        tanh_)
 
 # always-on set-up counter: this file's first line to its last, jax's
-# own import included when it happens here
+# own import included when it happens here; and the same stretch as the
+# set-up span ``setup.import``, after the age the process had at the first
+# line (``setup.before_import_s``)
 utils.monitor.stat_set("setup.import_s", _time.perf_counter() - _t_import)
+observability.compiles.import_done(_t_import_wall, _backend_was_made)

@@ -15,12 +15,15 @@ from __future__ import annotations
 
 import contextlib
 import threading
+import time
 
 import jax.numpy as jnp
 import numpy as np
 
 from ..core.dtype import convert_dtype
 from ..core.tensor import Tensor
+from ..observability.compiles import setup_span
+from ..utils import monitor
 
 # op lists (reference: fluid/contrib/mixed_precision/fp16_lists.py)
 WHITE_LIST = {
@@ -119,12 +122,17 @@ def decorate(models=None, optimizers=None, level="O2", dtype="bfloat16",
     dt = convert_dtype(dtype)
     single = not isinstance(models, (list, tuple))
     ms = [models] if single else list(models)
-    for m in ms:
-        if m is not None:
-            m.to(dtype=dt)
-            # record the decorated dtype; jit.TrainStep(amp_level=...)
-            # uses it when the caller opts into tracing under auto_cast
-            m._amp_dtype = dt
+    # the casts, an eager op a parameter: a set-up span and an always-on
+    # counter, as parameter creation's
+    t0 = time.perf_counter()
+    with setup_span("setup.amp_decorate"):
+        for m in ms:
+            if m is not None:
+                m.to(dtype=dt)
+                # record the decorated dtype; jit.TrainStep(amp_level=...)
+                # uses it when the caller opts into tracing under auto_cast
+                m._amp_dtype = dt
+    monitor.stat_add("setup.amp_decorate_s", time.perf_counter() - t0)
     if optimizers is not None:
         opts = ([optimizers] if not isinstance(optimizers, (list, tuple))
                 else list(optimizers))

@@ -9,15 +9,28 @@ from __future__ import annotations
 
 import jax
 
+from .core import xla_env
+from .observability.compiles import setup_span
+
 _current_device = None
 
 
+def _devices(*kind):
+    """``jax.devices``; the query that makes jax's backend is the set-up
+    span ``setup.backend_init`` (where the caller made it first, that
+    time lies in ``setup.before_import_s``)."""
+    if xla_env._backend_initialized():
+        return jax.devices(*kind)
+    with setup_span("setup.backend_init"):
+        return jax.devices(*kind)
+
+
 def get_all_devices():
-    return jax.devices()
+    return _devices()
 
 
 def device_count(kind=None) -> int:
-    return len(jax.devices(kind) if kind else jax.devices())
+    return len(_devices(kind) if kind else _devices())
 
 
 def is_compiled_with_cuda() -> bool:
@@ -26,7 +39,7 @@ def is_compiled_with_cuda() -> bool:
 
 def is_compiled_with_tpu() -> bool:
     try:
-        return any(d.platform == "tpu" for d in jax.devices())
+        return any(d.platform == "tpu" for d in _devices())
     except RuntimeError:
         return False
 
@@ -44,9 +57,9 @@ def set_device(device: str):
     idx = int(device.split(":")[1]) if ":" in device else 0
     if kind in ("gpu", "cuda", "tpu", "xpu"):
         # jax.devices raises RuntimeError when no TPU backend exists
-        _current_device = jax.devices("tpu")[idx]
+        _current_device = _devices("tpu")[idx]
     elif kind == "cpu":
-        _current_device = jax.devices("cpu")[idx]
+        _current_device = _devices("cpu")[idx]
     else:
         raise ValueError(f"unknown device {device!r}; expected 'cpu', "
                          f"'tpu' or 'tpu:<index>'")
@@ -55,5 +68,5 @@ def set_device(device: str):
 
 
 def get_device() -> str:
-    d = _current_device or jax.devices()[0]
+    d = _current_device or _devices()[0]
     return f"{d.platform}:{d.id}"

@@ -35,7 +35,7 @@ from jax.extend.core import jaxpr_as_fun
 
 from ..core import autograd, rng
 from ..core.tensor import Tensor
-from ..observability import device_counters, scopes, span
+from ..observability import compiles, device_counters, scopes, span
 from ..utils import monitor
 from .bind import bind, buffer_arrays, buffer_names, param_list
 
@@ -168,6 +168,7 @@ class TrainStep:
                       dynamic=scaler._dynamic)
 
         def step_fn(p_arr, b_arr, opt_state, aux, lr, inputs, labels):
+            compiles.claim("train_step.call")   # a recompile's owner
             # aux carries everything that changes per step but lives on
             # device: the RNG base key, the effective step counter, and the
             # loss-scaling state.  Keeping these in-graph means __call__
@@ -344,6 +345,7 @@ class TrainStep:
         out_tree = jax.tree.structure(traced.out_info)
 
         def step_fn(p_arr, b_arr, opt_state, aux, lr, inputs, labels):
+            compiles.claim("train_step.call")
             aux = dict(aux)
             carry = aux.pop("counters")
             loss, new_p, new_b, new_s, new_aux = jax.tree.unflatten(
@@ -435,14 +437,20 @@ class TrainStep:
             training = self.model.training
             compiled = self._compiled.get(training)
         t1 = time.perf_counter_ns()
+        first = None
         if compiled is None:
             # one-off: the step is traced here (``_carry_counters``), where
             # it used to be traced inside its first call below, so this is
-            # kept out of the step's own python as that was
-            compiled = self._carry_counters(
-                self._build(training),
-                (p_arr, b_arr, self._opt_state, self._scaler_state,
-                 self._lr_device, inputs, labels))
+            # kept out of the step's own python as that was.  The set-up
+            # span ``train_step.first_call`` owns what jax traces, lowers,
+            # loads and compiles from here through the return of the first
+            # compiled call (``observability.setup_report``)
+            first = compiles.begin_setup("train_step.first_call")
+            with compiles.setup_span("train_step.build"):
+                compiled = self._carry_counters(
+                    self._build(training),
+                    (p_arr, b_arr, self._opt_state, self._scaler_state,
+                     self._lr_device, inputs, labels))
             self._compiled[training] = compiled
             if self._counter_spec:
                 self._scaler_state.setdefault(
@@ -450,10 +458,14 @@ class TrainStep:
                     device_counters.zero_carry(self._counter_spec))
             t0 += time.perf_counter_ns() - t1
             t1 = time.perf_counter_ns()
-        with span("train_step.execute"):
-            loss, new_p, new_b, new_s, new_sc = compiled(
-                p_arr, b_arr, self._opt_state, self._scaler_state,
-                self._lr_device, inputs, labels)
+        try:
+            with span("train_step.execute"):
+                loss, new_p, new_b, new_s, new_sc = compiled(
+                    p_arr, b_arr, self._opt_state, self._scaler_state,
+                    self._lr_device, inputs, labels)
+        finally:
+            if first is not None:
+                compiles.first_call_done("train_step", first)
         t2 = time.perf_counter_ns()
         with span("train_step.writeback"):
             # write back (device-side aliasing, no host copies)
@@ -480,8 +492,12 @@ class TrainStep:
 
         key = ("eval", model.training)
         compiled = self._compiled.get(key)
+        first = None
         if compiled is None:
+            first = compiles.begin_setup("eval_step.first_call")
+
             def eval_fn(p_arr, b_arr, key_data, inputs, labels):
+                compiles.claim("eval_step.call")
                 k = jax.random.wrap_key_data(key_data)
                 p_model = self._decode_params(list(p_arr))
                 with autograd.no_grad(), rng.seed_scope(k):
@@ -495,8 +511,12 @@ class TrainStep:
                 return loss_t.data, out_arr
             compiled = jax.jit(eval_fn)
             self._compiled[key] = compiled
-        p_arr = self._param_arrays()
-        b_arr = tuple(buffer_arrays(self.model))
-        key_data = jax.random.key_data(rng.next_key())
-        loss, out = compiled(p_arr, b_arr, key_data, inputs, labels)
+        try:
+            p_arr = self._param_arrays()
+            b_arr = tuple(buffer_arrays(self.model))
+            key_data = jax.random.key_data(rng.next_key())
+            loss, out = compiled(p_arr, b_arr, key_data, inputs, labels)
+        finally:
+            if first is not None:
+                compiles.first_call_done("eval_step", first)
         return Tensor(loss), jax.tree.map(Tensor, out)

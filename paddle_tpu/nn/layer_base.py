@@ -22,6 +22,7 @@ import numpy as np
 
 from ..core.dtype import convert_dtype, get_default_dtype
 from ..core.tensor import Parameter, Tensor
+from ..observability.compiles import setup_span
 from ..utils import monitor
 from . import initializer as I
 
@@ -147,9 +148,11 @@ class Layer:
             self._param_suffix_counts[suffix] = k + 1
             name = f"{self._auto_name}.{suffix}_{k}"
         t0 = time.perf_counter()
-        p = Parameter(init(tuple(shape), dtype), name=name,
-                      trainable=attr.trainable, regularizer=attr.regularizer,
-                      need_clip=attr.need_clip)
+        with setup_span("setup.param_init"):
+            p = Parameter(init(tuple(shape), dtype), name=name,
+                          trainable=attr.trainable,
+                          regularizer=attr.regularizer,
+                          need_clip=attr.need_clip)
         p.optimize_attr["learning_rate"] = attr.learning_rate
         # always-on set-up counters (the eager initialiser is the cost)
         monitor.stat_add("setup.param_init_s", time.perf_counter() - t0)

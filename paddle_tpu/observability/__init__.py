@@ -28,6 +28,11 @@ The reproduction's four telemetry islands (profiler host spans,
   named cause (new program version, new feed signature, new bucket,
   ...) with a diff against the previous signature — always on, counted
   per-cause in ``monitor``.
+- :func:`setup_report` is the set-up timeline: every trace, lowering,
+  cache load and compile jax made, with its function's name, self
+  seconds and owner (the program's set-up spans ``setup.import``,
+  ``setup.param_init``, ``train_step.first_call`` ..., or ``outside``),
+  on the clock a caller stamps its own start with — always on.
 - :func:`prometheus_text` / :func:`metrics_snapshot` /
   :func:`dump_metrics` export the whole monitor registry as Prometheus
   text exposition or JSON (``serving/http.py`` content-negotiates
@@ -61,7 +66,7 @@ import jax
 
 from ..core import obs_hook
 from .compiles import (annotate_compile, explain_compiles,
-                       record_compile, reset_compiles)
+                       record_compile, reset_compiles, setup_report)
 from .device_counters import (collecting, device_counter,
                               read_device_counters)
 from .export import (TelemetryExporter, get_exporter, install_exporter,
@@ -86,7 +91,7 @@ __all__ = [
     "get_tracer", "emit", "span", "begin_span", "end_span", "counter",
     "set_step", "device_counter", "collecting", "read_device_counters",
     "record_compile", "explain_compiles", "reset_compiles",
-    "annotate_compile",
+    "annotate_compile", "setup_report",
     "prometheus_text", "metrics_snapshot", "dump_metrics", "build_info",
     "install_flight_recorder", "uninstall_flight_recorder",
     "dump_flight", "flight_recorder_path",

@@ -23,6 +23,7 @@ import numpy as np
 from ..core import autograd
 from ..core.tensor import Parameter, Tensor
 from ..observability import scopes
+from ..observability.compiles import setup_span
 from ..utils import monitor
 from .clip import ClipGradBase
 from .lr import LRScheduler
@@ -181,12 +182,13 @@ class Optimizer:
     def functional_init(self, param_arrays: Sequence[jnp.ndarray]):
         t0 = time.perf_counter()
         states = []
-        for p in param_arrays:
-            s = self.init_slots(p)
-            if (self._multi_precision
-                    and p.dtype in (jnp.bfloat16, jnp.float16)):
-                s["master"] = p.astype(jnp.float32)
-            states.append(s)
+        with setup_span("setup.opt_state_init"):
+            for p in param_arrays:
+                s = self.init_slots(p)
+                if (self._multi_precision
+                        and p.dtype in (jnp.bfloat16, jnp.float16)):
+                    s["master"] = p.astype(jnp.float32)
+                states.append(s)
         # always-on set-up counter (eager ops, one or more a leaf)
         monitor.stat_add("setup.opt_state_init_s",
                          time.perf_counter() - t0)

@@ -46,7 +46,8 @@ import numpy as np
 from ..core import obs_hook
 from ..core.flags import get_flag
 from ..core.tensor import Tensor
-from ..observability import begin_span, end_span, scopes, span
+from ..observability import (begin_span, compiles, end_span, scopes,
+                             span)
 from .program import Program, Variable, default_main_program
 
 __all__ = ["Executor", "global_scope"]
@@ -249,6 +250,7 @@ class Executor:
     def __init__(self, place=None):
         self.place = place
         self._cache: Dict[tuple, object] = {}
+        self._first_run = None      # the open ``executor.first_run`` span
         # keyed by Program._serial (monotonic, never recycled) — id()
         # keys could be reused after GC, handing a new Program a dead
         # program's run counter / optimizer slots.  Serials never
@@ -640,7 +642,13 @@ class Executor:
                 h(e, f"executor.run(program#{program._serial})")
             raise
         finally:
+            if self._first_run is not None:     # the build or the call raised
+                self._first_run_done()
             end_span(sid)
+
+    def _first_run_done(self):
+        first, self._first_run = self._first_run, None
+        compiles.first_call_done("executor", first)
 
     def _run(self, program, feed, fetch_list, return_numpy, seed):
         # chaos hook: lets fault specs crash a training step on demand
@@ -708,6 +716,10 @@ class Executor:
         compiled = self._cache.get(key)
         compiled_this_run = compiled is None
         if compiled is None:
+            # the set-up span ``executor.first_run`` owns what jax traces,
+            # lowers, loads and compiles from here through the return of
+            # this key's first compiled call (``_first_run_done``)
+            self._first_run = compiles.begin_setup("executor.first_run")
             # recompile for a NEW version: executables for older
             # versions of this program can never be requested again
             # (the version only grows), so drop them — each one pins
@@ -734,10 +746,11 @@ class Executor:
                 if skey not in self._shard_verified:
                     program.verify(fetch_list=fetch_list, sharding=plan)
                     self._shard_verified.add(skey)
-            compiled = self._build(program, params, feed_names, fetch_names,
-                                   donate, plan=plan,
-                                   feed_arrays=feed_arrays,
-                                   sentry=sentry_on)
+            with compiles.setup_span("executor.build"):
+                compiled = self._build(program, params, feed_names,
+                                       fetch_names, donate, plan=plan,
+                                       feed_arrays=feed_arrays,
+                                       sentry=sentry_on)
             self._cache[key] = compiled
             if plan is not None:
                 # replacing the mesh while this executable lives would
@@ -909,6 +922,8 @@ class Executor:
                     state.p_arrays, state.opt_state, state.aux,
                     state.lr_device, state.base_key, *seed_args,
                     *feed_arrays)
+            if compiled_this_run:
+                self._first_run_done()
             state.p_arrays = list(new_p)
             state.opt_state = new_s
             state.aux = new_aux
@@ -945,6 +960,8 @@ class Executor:
             t_d0 = time.perf_counter() if perf is not None else 0.0
             with span("executor.execute"):
                 fetches = compiled(state.p_arrays, rng_key, *feed_arrays)
+            if compiled_this_run:
+                self._first_run_done()
 
         # step anatomy: host lane every run, device fence + memory
         # sample on the observatory's cadence.  The run that compiled
@@ -1217,6 +1234,7 @@ class Executor:
 
         def train_fn(p_arrays, opt_state, aux, lr, base_key, sflag,
                      rseed, *feed_arrays):
+            compiles.claim("executor.run")   # a recompile's owner
             p_arrays = list(p_arrays)
             run_i = aux["run"] + 1
             step_i = (aux["step"] + 1).astype(jnp.float32)
@@ -1486,6 +1504,7 @@ class Executor:
 
         if opt_pack is None:
             def run_fn(p_arrays, rng_key, *feed_arrays):
+                compiles.claim("executor.run")   # a recompile's owner
                 # random ops (dropout) draw from the per-run key
                 with _rng.seed_scope(rng_key):
                     env = forward_env(p_arrays, feed_arrays)
@@ -1589,6 +1608,7 @@ class Executor:
 
         def train_fn(p_arrays, opt_state, aux, lr, base_key, sflag, rseed,
                      *feed_arrays):
+            compiles.claim("executor.run")   # a recompile's owner
             p_arrays = list(p_arrays)
             # counters live in the donated aux carry: no per-step scalar
             # uploads.  'run' keys RNG (advances every run); 'step' is
@@ -1771,6 +1791,7 @@ class Executor:
         if opt_pack is None:
             @jax.jit
             def run_fn(p_arrays, rng_key, *feed_arrays):
+                compiles.claim("executor.run")   # a recompile's owner
                 with _rng.seed_scope(rng_key):
                     env = forward_env(p_arrays, feed_arrays)
                 return [env[n] for n in fetch_names]
@@ -1793,6 +1814,7 @@ class Executor:
         @jax.jit
         def train_fn(p_arrays, opt_state, lr, step_i, rng_key,
                      *feed_arrays):
+            compiles.claim("executor.run")   # a recompile's owner
             p_arrays = list(p_arrays)
 
             def loss_of(tlist):

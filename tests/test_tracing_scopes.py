@@ -474,7 +474,17 @@ def test_executor_run_spans_go_through_the_same_primitive():
                  if e["kind"] == "span"}
         run = spans["executor.run"]
         assert spans["executor.feed"]["parent"] == run["id"]
-        assert spans["executor.execute"]["parent"] == run["id"]
+        # a key's first run puts its set-up span between the two
+        first = spans["executor.first_run"]
+        assert first["parent"] == run["id"]
+        assert spans["executor.build"]["parent"] == first["id"]
+        assert spans["executor.execute"]["parent"] == first["id"]
+        exe.run(prog, feed={"x": np.ones((2, 4), "float32")},
+                fetch_list=[out])
+        again = [e for e in tracer.events() if e["kind"] == "span"][-3:]
+        assert [e["name"] for e in again] == [
+            "executor.feed", "executor.execute", "executor.run"]
+        assert again[1]["parent"] == again[2]["id"]
         exe.close()
     finally:
         observability.disable()
