@@ -834,10 +834,13 @@ def cross_entropy(input, label, weight=None, ignore_index=-100,
                  ignore_index=ignore_index, reduction=reduction)
 
 
-def _chunk_nll(hc, w, b, lc, *wc, ignore_index):
-    """One chunk's summed (or weighted) loss and its count of kept
-    tokens; its [per, vocab] float32 logits live only here."""
-    logits = (jnp.matmul(hc, w) + b).astype(jnp.float32)
+def _chunk_logits(hc, w, b):
+    return (jnp.matmul(hc, w) + b).astype(jnp.float32)
+
+
+def _nll_of_logits(logits, lc, *wc, ignore_index):
+    """A chunk's summed (or weighted) loss and its count of kept tokens,
+    from its [per, vocab] float32 logits."""
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
     safe = jnp.where(lc == ignore_index, 0, lc)
     tgt = jnp.take_along_axis(logits, safe[:, None], axis=-1)[:, 0]
@@ -848,6 +851,12 @@ def _chunk_nll(hc, w, b, lc, *wc, ignore_index):
         # anything, and its loss gives the weight no gradient
         return jnp.sum(jnp.where(keep, nll * wc[0], 0.0)), jnp.sum(keep)
     return jnp.sum(nll * keep), jnp.sum(keep)
+
+
+def _chunk_nll(hc, w, b, lc, *wc, ignore_index):
+    """One chunk's loss and count; its logits live only here."""
+    return _nll_of_logits(_chunk_logits(hc, w, b), lc, *wc,
+                          ignore_index=ignore_index)
 
 
 def _head_of(w, b, ignore_index):
@@ -894,19 +903,35 @@ def _chunked_ce_fwd(ignore_index, hs, w, b, ls, tws):
     weighted sum.  ``dw`` and ``db`` accumulate in ``w``'s and ``b``'s own
     types, last chunk first, which is how a scan's transpose accumulated
     them: at a cotangent of 1 every gradient is that transpose's bit for
-    bit."""
+    bit.  The chunk's rule is pulled in its two halves, the loss's down to
+    the logits' cotangent ``dl`` and the logits' own, so that ``dh`` can
+    walk ``_dh_rows`` rows a product (a row's ``dh`` is its own sum over
+    the vocabulary: the same numbers); counted at trace time as
+    ``linear_cross_entropy.dh_rows.<rows>``."""
     from ...utils import monitor
     monitor.stat_add("linear_cross_entropy.grads_in_forward")
     count = jnp.sum(ls != ignore_index)
     ct = _mean_or_sum(jnp.float32(1.0), count, tws)
+    per = hs.shape[1]
+    block = _dh_rows(per, w)
+    monitor.stat_add(f"linear_cross_entropy.dh_rows.{block}")
 
     def body(carry, xs):
         hc, lc, *wc = xs
-        part, pull, _ = jax.vjp(
-            lambda hc, w, b, *wc: _chunk_nll(hc, w, b, lc, *wc,
-                                             ignore_index=ignore_index),
-            hc, w, b, *wc, has_aux=True)
-        dhc, dwc, dbc, *dwc_t = pull(ct)
+        # dh = dl @ w.T for ``block`` rows: the transpose of their logits
+        dh_of = jax.linear_transpose(
+            lambda hb: jnp.matmul(hb, w).astype(jnp.float32),
+            jax.ShapeDtypeStruct((block, hc.shape[1]), hc.dtype))
+        logits, pull_head = jax.vjp(
+            lambda w, b: _chunk_logits(hc, w, b), w, b)
+        part, pull_nll, _ = jax.vjp(
+            lambda z, *wc: _nll_of_logits(z, lc, *wc,
+                                          ignore_index=ignore_index),
+            logits, *wc, has_aux=True)
+        dl, *dwc_t = pull_nll(ct)
+        dwc, dbc = pull_head(dl)
+        dhc = jnp.concatenate([dh_of(dl[i:i + block])[0]
+                               for i in range(0, per, block)])
         return ((carry[0] + dwc, carry[1] + dbc), (part, dhc, *dwc_t))
 
     (dw, db), (parts, dh, *dtw) = jax.lax.scan(
@@ -927,6 +952,49 @@ def _chunked_ce_bwd(ignore_index, grads, g):
 _chunked_ce.defvjp(_chunked_ce_fwd, _chunked_ce_bwd)
 
 
+# The rows a chunk of the head holds where the caller names none, and the
+# rows one ``dh`` product walks.  Both are measured constants of the
+# program; the head alone (loss and gradients in one jit, 1024-row chunks
+# unless said) on one v5e, ms a call (PERF.md, PR 50):
+#
+#   rows a chunk                 512     1024    2048    4096
+#   f32 [32768, 2048] x 49152   161.4   154.7   162.4   152.2 (Ouro)
+#   f32 [32768, 2048] x 18992    63.4    49.5    57.8         (Keye)
+#   f32 [16384, 2048] x 25024    40.7    32.4    38.1         (Trinity)
+#   f32 [16384, 2688] x 16384    36.4    29.0    31.3         (Nemotron)
+#   bf16 [16384, 1536] x 50257   46.4    44.0    47.4         (GPT)
+#
+#   rows a dh product           1024     512     256
+#   f32 x 49152                 154.7   135.2   137.4
+#   f32 x 18992 / 25024 / 16384  49.5 / 32.4 / 29.0   48.4 / 32.3 / 28.2
+#   bf16 x 50257                 44.0    44.8    47.8
+#
+# XLA tiles each product by the chunk: at 2048 rows it splits ``dw``'s
+# contraction three ways and walks the float32 accumulator once a part,
+# so fewer, larger chunks are slower although they move less (4096 rows
+# win 1.6 % at four [rows, vocab] float32 buffers of 805 MB).  At 1024
+# rows over 49,152 columns it cuts ``dh``'s [1024, H] result in four and
+# reads both operands twice, and it remakes the logits' cotangent inside
+# ``dh`` and inside ``dw``; handed two products of 512 rows it keeps each
+# result whole, makes the cotangent once, in the bfloat16 the products
+# read it in, and ``dw`` reads half the bytes.  Under 32,768 columns and
+# for a bfloat16 head the one product is as fast or faster in the step.
+_HEAD_ROWS = 1024
+_DH_ROWS = 512
+_DH_SPLIT_FROM = 32768      # vocabulary columns
+
+
+def _dh_rows(per, w):
+    """The rows of a chunk of ``per`` that one ``dh`` product walks: from
+    what the head sees of its weight, ``_DH_ROWS`` for float32 over at
+    least ``_DH_SPLIT_FROM`` columns where they divide the chunk, else the
+    whole chunk."""
+    if (jnp.dtype(w.dtype).itemsize == 4 and w.shape[-1] >= _DH_SPLIT_FROM
+            and per % _DH_ROWS == 0):
+        return _DH_ROWS
+    return per
+
+
 def _linear_ce_fn(h, w, b, lab, *tw, chunk, ignore_index):
     """Chunked fused head+CE: the [T, vocab] logits (and their cotangent)
     never hit HBM in full, because a chunk's logits live only inside the
@@ -941,7 +1009,12 @@ def _linear_ce_fn(h, w, b, lab, *tw, chunk, ignore_index):
     from the forward pass into the backward.  With a token weight ``tw``
     [T] the result is the weighted sum of the kept tokens' losses, not
     their mean.  The padding of a ragged tail stays outside either rule:
-    its gradient is jax's slice."""
+    its gradient is jax's slice.  ``chunk`` None: ``_HEAD_ROWS``; counted
+    as ``linear_cross_entropy.rows.<rows>``."""
+    from ...utils import monitor
+    if chunk is None:
+        chunk = _HEAD_ROWS
+    monitor.stat_add(f"linear_cross_entropy.rows.{chunk}")
     T = h.shape[0]
     n = max(1, -(-T // chunk))          # ceil: pad the tail chunk
     per = -(-T // n)
@@ -962,7 +1035,8 @@ def _linear_ce_fn(h, w, b, lab, *tw, chunk, ignore_index):
 
 
 @jax.named_scope(scopes.LINEAR_CROSS_ENTROPY)
-def linear_cross_entropy(hidden, weight, bias, label, chunk: int = 1024,
+def linear_cross_entropy(hidden, weight, bias, label,
+                         chunk: Optional[int] = None,
                          ignore_index: int = -100, name=None,
                          token_weight=None):
     """Fused ``cross_entropy(hidden @ weight + bias, label)`` with chunked
@@ -971,6 +1045,22 @@ def linear_cross_entropy(hidden, weight, bias, label, chunk: int = 1024,
     entropy_op.cu) to include the vocab projection: the full-vocab logits
     tensor is never materialized.  ``hidden``: [T, H]; ``weight``:
     [H, vocab]; ``label``: [T] int.
+
+    ``chunk``: the rows of ``hidden`` a chunk holds.  ``None`` (the
+    default): the head chooses, 1024 rows, the measured best on a v5e at
+    every head shape and for either itemsize (the table beside
+    ``_HEAD_ROWS``: XLA tiles the three products by the chunk, and at
+    2048 rows it splits ``dw``'s contraction and walks the float32
+    accumulator three times a chunk, so a larger chunk is slower although
+    it moves fewer bytes).  An int is taken as given.  What a chunk costs
+    in memory is its ``[rows, vocab]`` float32 arrays, the logits, their
+    exponentials, their cotangent and the buffer the labels' gather
+    scatters into (four live at once in a compiled step: 4 x 201 MB at
+    1024 x 49,152).  Differentiated, a float32 head over at least 32,768
+    columns makes ``dh`` 512 rows a product inside the chunk
+    (``_dh_rows``: the same numbers, a row's ``dh`` is its own sum; XLA
+    then keeps each product's result whole and makes the logits' cotangent
+    once, not once a product: Ouro's head 142.9 -> 131.5 ms a step).
 
     ``token_weight`` [T] float32 turns the mean into the weighted sum
     ``sum_i token_weight_i * nll_i`` over the tokens that are not
@@ -995,9 +1085,13 @@ def linear_cross_entropy(hidden, weight, bias, label, chunk: int = 1024,
     ``jax.checkpoint`` the forward pass runs the value and the replay
     the three passes.
 
-    Counted at trace time: ``linear_cross_entropy.calls``, and
-    ``linear_cross_entropy.grads_in_forward`` for each call whose
-    gradients were made in its forward rule."""
+    Counted at trace time: ``linear_cross_entropy.calls``,
+    ``linear_cross_entropy.rows.<rows>`` (the rows a traced call's chunks
+    hold, chosen or given: a program's report says which each head got),
+    and for each call whose gradients were made in its forward rule
+    ``linear_cross_entropy.grads_in_forward`` and
+    ``linear_cross_entropy.dh_rows.<rows>`` (the rows a ``dh`` product
+    walks)."""
     from ...utils import monitor
     monitor.stat_add("linear_cross_entropy.calls")
     args = [hidden, weight, bias, label]
@@ -1005,7 +1099,8 @@ def linear_cross_entropy(hidden, weight, bias, label, chunk: int = 1024,
         args.append(token_weight)
     return apply(_linear_ce_fn, *args,
                  op_name="linear_cross_entropy", cacheable=True,
-                 chunk=int(chunk), ignore_index=int(ignore_index))
+                 chunk=None if chunk is None else int(chunk),
+                 ignore_index=int(ignore_index))
 
 
 def softmax_with_cross_entropy(logits, label, soft_label=False,
@@ -2169,7 +2264,8 @@ def loop_exit_distribution(gate_logits, name=None):
 
 
 def loop_exit_loss(states, gate_logits, weight, bias, label, beta=0.1,
-                   chunk: int = 1024, ignore_index: int = -100, name=None):
+                   chunk: Optional[int] = None, ignore_index: int = -100,
+                   name=None):
     """A looped model's training objective over its T exits (the LoopLM
     family's pre-training stage: the expected loss under the exit
     distribution, entropy-regularised towards a uniform prior over the
@@ -2190,6 +2286,12 @@ def loop_exit_loss(states, gate_logits, weight, bias, label, beta=0.1,
     pass, and the head's gradient is accumulated once: a pass an exit
     holds T float32 partial gradients of the head (2.0 GB more at
     2048 x 49,152 and T = 4, for the same step time; PERF.md, PR 37).
+    ``chunk`` goes to that call as it is: ``None`` (the default) lets the
+    head choose its rows as it does for any caller (1024, and ``dh`` 512
+    rows a product for a float32 head over 32,768 columns or more;
+    counted as ``linear_cross_entropy.rows.<rows>`` and
+    ``.dh_rows.<rows>``), at four ``[rows, vocab]`` float32 arrays a
+    chunk; an int is taken as given.
     The distribution, the entropy and the weights sit under the scope
     ``loop_exit``.  Device counters, float32: ``loop.exit_share`` [T] (the
     mean of ``p_t``) and ``loop.exit_entropy`` (the mean of H)."""

@@ -304,17 +304,44 @@ def _kernel_count(text, kernel):
     return len(re.findall(rf"%{kernel}[.\d]* = ", text))
 
 
-def _head_made_its_gradients_in_the_forward_pass(text, calls):
+def _head_scans(text, H):
+    """{(chunks, rows)} of the chunked head's scans in a compiled step:
+    the [chunks, rows, H] ``dh`` (and hidden states) that the while loops
+    under the head's scope carry.  The ``dw`` accumulator's shape is the
+    weight's and says nothing of the chunk (ROADMAP S9, PR 45)."""
+    from paddle_tpu.observability import scopes
+    found = set()
+    for line in text.splitlines():
+        if " while(" in line and re.search(
+                rf'op_name="[^"]*/{scopes.LINEAR_CROSS_ENTROPY}/while"', line):
+            carried = line.split(" while(")[0]
+            found |= {(int(n), int(rows)) for n, rows in re.findall(
+                rf"(?:f32|bf16)\[(\d+),(\d+),{H}\]", carried)}
+    return found
+
+
+def _head_made_its_gradients_in_the_forward_pass(text, calls, H, chunks,
+                                                 rows=1024, dh_rows=None):
     """The chunked head of a compiled step, ``calls`` of them traced: each
     took the forward rule (``linear_cross_entropy.grads_in_forward``), so
     nothing under the scope is a replay, its products sit in the forward
     pass's scan and the backward pass holds no loop of its own: at most
     the scaling by the cotangent, which XLA folds away where that is 1
-    (BERT, GPT, Keye)."""
+    (BERT, GPT, Keye).  The scan walks ``chunks`` chunks of ``rows`` rows
+    of ``H``, the rows the head chose (1024 in every cell: 2048 for the
+    float32 heads was tried on the chip and lost in all of them, PR 50)
+    and counted, and ``dh`` walks ``dh_rows`` rows a product (the chunk's,
+    but 512 under Ouro's 49,152 columns)."""
     from paddle_tpu.observability import scopes
     from paddle_tpu.utils import monitor
     assert monitor.get_stat("linear_cross_entropy.calls") == calls
     assert monitor.get_stat("linear_cross_entropy.grads_in_forward") == calls
+    assert {k[len("linear_cross_entropy."):]: v
+            for k, v in monitor.all_stats().items()
+            if k.startswith(("linear_cross_entropy.rows.",
+                             "linear_cross_entropy.dh_rows."))} \
+        == {f"rows.{rows}": calls, f"dh_rows.{dh_rows or rows}": calls}
+    assert _head_scans(text, H) == {(chunks, rows)}
     head = [n.split("/") for n in set(re.findall(r'op_name="([^"]*)"', text))
             if scopes.LINEAR_CROSS_ENTROPY in n.split("/")]
     assert head and not any("rematted_computation" in s for s in head)
@@ -357,6 +384,7 @@ def test_evabyte_cell_step_fits_the_chip(one_chip, monkeypatch):
     assert stats["pallas.selected.eva_attention"] >= L
     assert "eva_attention.xla_path" not in stats
     assert stats["linear_cross_entropy.calls"] == cfg["num_pred_heads"] == 8
+    assert stats["linear_cross_entropy.rows.1024"] == 8
     assert "linear_cross_entropy.grads_in_forward" not in stats
     assert any("rematted_computation" in n and "linear_cross_entropy" in n
                for n in re.findall(r'op_name="([^"]*)"', text))
@@ -419,7 +447,8 @@ def test_keye_vl2_cell_step_fits_the_chip(one_chip, monkeypatch):
                                ("dsa_kl", "dsa_indexer_loss")):
         assert stats[f"pallas.selected.{kernel}"] >= L, kernel
         assert f"{functional}.xla_path" not in stats, functional
-    _head_made_its_gradients_in_the_forward_pass(text, calls=1)
+    _head_made_its_gradients_in_the_forward_pass(
+        text, calls=1, H=cfg["hidden_size"], chunks=32)
     _token_major_passes_walk_the_buffer(
         text, stats, L, mix["seq"], cfg["num_experts_per_tok"],
         cfg["hidden_size"])
@@ -542,24 +571,26 @@ def _one_backward_kernel_a_block(text, blocks):
 
 
 @pytest.mark.parametrize(
-    "cell_name,blocks,kept,parameters,on_record,finds", [
+    "cell_name,blocks,kept,parameters,on_record,finds,head", [
         # 15,094,667,264 and 14,093,140,992 before PR 38: ``dw`` and ``dh``
         # are allocated as the forward pass closes, not as the backward
         # opens.  96-wide heads keep the [B, H, L, D] copies (PR 44)
         ("gpt3_large.train_bf16_b8_s2048", 24, 24, 760e6,
-         (1556, 15_109_924_352), dict(transposed=24)),
+         (1556, 15_109_924_352), dict(transposed=24),
+         dict(H=1536, chunks=16)),
         # 14,193,097,728 before PR 44: a step without replay held every
         # layer's [64, 12, 512, 64] copies of q, k, v and out (64 lanes
         # laid out as 128) until its backward; two heads to a lane block,
         # the kernels read the projections' own [64, 512, 768]
         ("bert_base.train_bf16_b64_s512", 12, 0, 132e6,
          (796, 11_154_483_200),
-         dict(lies=12, vmem=(2_138_112, 3_297_280))),
+         dict(lies=12, vmem=(2_138_112, 3_297_280)),
+         dict(H=768, chunks=32)),
     ], ids=["gpt", "bert"])
 def test_flash_cell_step_runs_one_backward_kernel(one_chip, monkeypatch,
                                                   cell_name, blocks, kept,
                                                   parameters, on_record,
-                                                  finds):
+                                                  finds, head):
     """The GPT and BERT cells' whole steps for the described v5e: causal
     at 2048 x 96 under per-block recompute, non-causal at 512 x 64 in one
     block a (batch, head), a grid step a pair of heads."""
@@ -572,7 +603,7 @@ def test_flash_cell_step_runs_one_backward_kernel(one_chip, monkeypatch,
     assert 0.9 * parameters < n < 1.1 * parameters
     text = compiled.as_text()
     _one_backward_kernel_a_block(text, blocks)
-    _head_made_its_gradients_in_the_forward_pass(text, calls=1)
+    _head_made_its_gradients_in_the_forward_pass(text, calls=1, **head)
     stats = monitor.all_stats()
     # BERT's step runs no replay, so nothing is kept for one
     assert _kept(stats) == ({scopes.ATTN_OUT: kept, scopes.ATTN_LSE: kept}
@@ -612,7 +643,8 @@ def test_joyai_llm_flash_cell_step_fits_the_chip(one_chip, monkeypatch):
     blocks = cfg["num_hidden_layers"] + cfg["num_nextn_predict_layers"]
     _one_backward_kernel_a_block(text, blocks)
     # the main model's pass through the head and the MTP module's
-    _head_made_its_gradients_in_the_forward_pass(text, calls=2)
+    _head_made_its_gradients_in_the_forward_pass(
+        text, calls=2, H=cfg["hidden_size"], chunks=16)
     assert "ragged-dot" in text
     stats = monitor.all_stats()
     assert _kept(stats) == {scopes.ATTN_OUT: blocks, scopes.ATTN_LSE: blocks}
@@ -653,8 +685,22 @@ def test_ouro_cell_step_fits_the_chip(one_chip, monkeypatch):
     # own ``out`` and ``lse``, so the replay holds no forward kernel
     text = compiled.as_text()
     _one_backward_kernel_a_block(text, T * L)
-    # one pass of the chunked head over the four exits stacked
-    _head_made_its_gradients_in_the_forward_pass(text, calls=1)
+    # one pass of the chunked head over the four exits stacked, reached
+    # through ``F.loop_exit_loss`` with no chunk named: 32 chunks of 1024
+    # rows (16 of 2048 ran 9 ms a step slower on the chip: XLA splits
+    # ``dw``'s contraction there), and under 49,152 float32 columns ``dh``
+    # is two products of 512 rows a chunk, which XLA leaves whole (the one
+    # product of 1024 rows it cut in four, reading both operands twice);
+    # the logits' cotangent is then made ONCE a chunk (at the benchmark's
+    # default precision in the bfloat16 the products read; float32 under
+    # conftest's), where ``dh`` and ``dw`` each remade it (PR 50: the head
+    # 142.9 -> 131.5 ms a step on the chip)
+    _head_made_its_gradients_in_the_forward_pass(
+        text, calls=1, H=cfg["hidden_size"], chunks=32, dh_rows=512)
+    in_head = [ln for ln in text.splitlines()
+               if f"/{scopes.LINEAR_CROSS_ENTROPY}/while/body" in ln]
+    assert sum(" fusion(" in ln and re.search(r"= f32\[512,2048\]", ln)
+               is not None for ln in in_head) == 2
     stats = monitor.all_stats()
     assert _kept(stats) == {scopes.ATTN_OUT: T * L, scopes.ATTN_LSE: T * L}
     assert (stats["loop.steps"], stats["loop.block_calls"]) == (T, T * L)
@@ -669,8 +715,11 @@ def test_ouro_cell_step_fits_the_chip(one_chip, monkeypatch):
     # conftest's matmul precision: not the benchmark's program to the
     # byte); 14,592,852,992 in PR 37, which the chip laid out in
     # 14,463,649,792.  14,557,063,680 since PR 44 (v, out, dO and dv as
-    # they lie: the temporaries 5,303,908,864 -> 5,254,384,640)
-    assert abs(footprint - 14_557_063_680) < 64 * 2 ** 20, footprint
+    # they lie: the temporaries 5,303,908,864 -> 5,254,384,640); with the
+    # head in 16 chunks of 2048 rows it compiled to 15,361,608,192: four
+    # [rows, 49152] float32 buffers live in a chunk (PR 50, not shipped);
+    # 14,558,675,968 with ``dh`` in two products (PR 50)
+    assert abs(footprint - 14_558_675_968) < 64 * 2 ** 20, footprint
     assert footprint < 15.75 * 2 ** 30
 
 
@@ -698,7 +747,8 @@ def test_nemotron_h_cell_step_fits_the_chip(one_chip, monkeypatch):
     assert pattern == "MEMEM*EME"
     text = compiled.as_text()
     _one_backward_kernel_a_block(text, pattern.count("*"))
-    _head_made_its_gradients_in_the_forward_pass(text, calls=1)
+    _head_made_its_gradients_in_the_forward_pass(
+        text, calls=1, H=cfg["hidden_size"], chunks=16)
     assert "ragged-dot" in text
     stats = monitor.all_stats()
     experts, mixers = pattern.count("E"), pattern.count("M")
@@ -779,7 +829,8 @@ def test_granite_cell_step_fits_the_chip(one_chip, monkeypatch):
     assert (mixers, attention) == (9, 1)
     text = compiled.as_text()
     _one_backward_kernel_a_block(text, attention)
-    _head_made_its_gradients_in_the_forward_pass(text, calls=1)
+    _head_made_its_gradients_in_the_forward_pass(
+        text, calls=1, H=cfg["hidden_size"], chunks=8)
     stats = monitor.all_stats()
     assert _kept(stats) == {scopes.ATTN_OUT: 1, scopes.ATTN_LSE: 1}
     assert stats["pallas.selected.flash_attention"] >= 1
@@ -838,7 +889,8 @@ def test_trinity_mini_cell_step_fits_the_chip(one_chip, monkeypatch):
     assert (windows, fulls, cfg["sliding_window"]) == (4, 1, 2048)
     text = compiled.as_text()
     _one_backward_kernel_a_block(text, len(kinds))
-    _head_made_its_gradients_in_the_forward_pass(text, calls=1)
+    _head_made_its_gradients_in_the_forward_pass(
+        text, calls=1, H=cfg["hidden_size"], chunks=16)
     assert "ragged-dot" in text
     stats = monitor.all_stats()
     assert _kept(stats) == {scopes.ATTN_OUT: 5, scopes.ATTN_LSE: 5}

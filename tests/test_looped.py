@@ -377,6 +377,48 @@ def test_loop_exit_loss_is_the_reference_objective_with_ignored_tokens():
         np.testing.assert_allclose(g, r, rtol=2e-4, atol=1e-6, err_msg=name)
 
 
+@pytest.mark.parametrize("dtype,chunk,rows", [
+    ("float32", None, 1024), ("bfloat16", None, 1024), ("float32", 16, 16)],
+    ids=["f32_chooses", "bf16_chooses", "given"])
+def test_loop_exit_loss_hands_its_chunk_on_as_it_is(dtype, chunk, rows):
+    """``F.loop_exit_loss`` has no chunk of its own: with none named the
+    head sees ``None`` and chooses as for any caller (the counter
+    ``linear_cross_entropy.rows.1024`` set by a call through it that names
+    none: a default handed on by keyword hid Ouro's call from PR 45's rule),
+    a named one arrives as given; and the objective and its gradients over
+    4 x 1100 rows in chunks of 2048 are those at the chosen rows."""
+    T, N, H, V = 4, 1100, 16, 50
+    ks = jax.random.split(jax.random.key(8), 4)
+    states = jax.random.normal(ks[0], (T, N, H)).astype(dtype)
+    z = jax.random.normal(ks[1], (T, N))
+    head = (0.3 * jax.random.normal(ks[2], (H, V))).astype(dtype)
+    lab = jax.random.randint(ks[3], (N,), 0, V).at[::4].set(-100)
+
+    def pulled(**chunk):
+        def loss(states, z, head):
+            with paddle.no_grad():
+                return F.loop_exit_loss(
+                    Tensor(states), Tensor(z), Tensor(head),
+                    Tensor(jnp.zeros((V,), dtype)), Tensor(lab),
+                    **chunk).data
+        monitor.stat_reset()
+        out = jax.jit(jax.value_and_grad(loss, (0, 1, 2)))(states, z, head)
+        return out, {k: v for k, v in monitor.all_stats().items()
+                     if k.startswith("linear_cross_entropy.rows.")}
+
+    got, counted = pulled(**({} if chunk is None else {"chunk": chunk}))
+    assert counted == {f"linear_cross_entropy.rows.{rows}": 1}
+    want, counted = pulled(chunk=2048)
+    assert counted == {"linear_cross_entropy.rows.2048": 1}
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(got[0], want[0], rtol=tol)
+    for g, r, name in zip(got[1], want[1], ("states", "gate", "head")):
+        g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
+        assert np.abs(r).max() > 0, name
+        np.testing.assert_allclose(g, r, rtol=tol,
+                                   atol=tol * np.abs(r).max(), err_msg=name)
+
+
 def _count(jaxpr, primitive):
     """How many ``primitive`` equations a jaxpr holds, its sub-jaxprs
     (a scan's body, a custom rule's primal) counted once each."""
