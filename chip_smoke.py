@@ -708,6 +708,53 @@ def check_causal_conv(errs, shape=(2, 8192, 10304), first=4096,
         f"form {took['xla'][0]} / {took['xla'][1]}")
 
 
+def check_gated_short_conv(errs, shape=(4, 8192, 6144), taps=3,
+                           tag="short_conv"):
+    """A convolution mixer's operator at the LFM2 cell's shape (the
+    in-projection's output [B ; C ; z] whole in bfloat16, three taps): the
+    two kernels (ops/pallas/causal_conv.py) and the plain form (ops/ssm.py:
+    XLA's slices, products and shifted multiply-adds) each against that
+    form in float32, y and the gradients dB, dC, dz and the taps', worst
+    element over the largest; and what a forward and a forward + backward
+    of each take alone (PERF.md's table)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import causal_conv as kernels
+    from paddle_tpu.ops.ssm import gated_short_conv
+    H, f32 = shape[2] // 3, jnp.float32
+    x = _rnd(81, shape, jnp.bfloat16)
+    w = (0.5 + _rnd(82, (taps, H), f32, 0.3)).astype(jnp.bfloat16)
+    ct = _rnd(83, shape[:2] + (H,), f32)
+    assert kernels.gated_short_conv_supported(shape, w.shape, x.dtype)
+
+    def both(fn):
+        def loss(x, w):
+            y = fn(x, w)
+            return jnp.sum(y.astype(f32) * ct), y
+        return jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))
+
+    def parts(y, grads):
+        dx, dw = grads
+        return (y, dx[..., :H], dx[..., H:2 * H], dx[..., 2 * H:], dw)
+
+    (_, want), want_g = both(gated_short_conv)(x.astype(f32), w.astype(f32))
+    took = {}
+    for side, fn in (("kernels", kernels.gated_short_conv),
+                     ("xla", gated_short_conv)):
+        (_, got), got_g = both(fn)(x, w)
+        for n, a, ref in zip(("y", "dB", "dC", "dz", "dw"),
+                             parts(got, got_g), parts(want, want_g)):
+            errs[f"{tag}_{side}_{n}"] = _close(
+                a, ref, BF16_TOL if n == "y" else 4 * BF16_TOL,
+                f"{tag} {side} {n}")
+        took[side] = (_call_ms(jax.jit(fn), x, w), _call_ms(both(fn), x, w))
+    log(f"[kernels] {tag} at {list(shape)}, {taps} taps: forward / forward "
+        f"+ backward alone, ms a call: the kernels {took['kernels'][0]} / "
+        f"{took['kernels'][1]}, XLA's form {took['xla'][0]} / "
+        f"{took['xla'][1]}")
+
+
 def check_moe_combine(errs, n=8192, K=8, H=2048, F=768, held=16, total=128):
     """One chunk of the Keye cell's expert layers (8192 tokens, 8 of 128
     experts a token, 16 held, bfloat16 weights): with the small buffer the
@@ -866,6 +913,7 @@ def phase_kernels(bert=BERT_BASE, serve=SERVE, engine=SERVE_ENGINE):
         check_causal_conv(errs)
         check_causal_conv(errs, (1, 8192, 8512), parts=(4096, 128, 128),
                           tag="conv_one_group")
+        check_gated_short_conv(errs)
         check_moe_combine(errs)
         check_epilogue(errs, bert)
         check_adam(errs, bert)

@@ -60,3 +60,4 @@ from .layer.looped import LoopedStack, LoopExitGate  # noqa: F401,E402
 from .layer.ssm import Mamba2Mixer  # noqa: F401,E402
 from .layer.ffn import GatedFFN  # noqa: F401,E402
 from .layer.attention import GroupedQueryAttention  # noqa: F401,E402
+from .layer.short_conv import ShortConv  # noqa: F401,E402

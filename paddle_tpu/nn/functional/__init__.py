@@ -1970,7 +1970,7 @@ def eva_attention(query, key, value, mu, phi, window_size, chunk_size,
 def moe_experts(x, router_weight, w_gate, w_up, w_down, top_k, first_expert=0,
                 norm_topk_prob=True, name=None, scoring="softmax",
                 router_bias=None, routed_scaling_factor=1.0, shared=None,
-                train_router=True):
+                train_router=True, gate_epsilon=None):
     """The part of a mixture-of-experts layer that the held experts give
     (ops/moe.py).  ``x`` [..., H] is the float32 normed stream; the router
     ``router_weight`` [H, E] spans all E experts and runs in float32; the
@@ -1986,7 +1986,9 @@ def moe_experts(x, router_weight, w_gate, w_up, w_down, top_k, first_expert=0,
     logit.  ``router_bias`` [E]: added to the scores for the SELECTION
     only, the gates are the chosen scores' (DeepSeek-V3's bias-corrected
     ``noaux_tc`` selection); it gets no gradient.  The gates are
-    multiplied by ``routed_scaling_factor``.  ``shared``: the three
+    multiplied by ``routed_scaling_factor``.  ``gate_epsilon``: what the
+    normalisation adds to the sum of a token's chosen scores (a family's
+    own constant; None: ops/moe.py's default).  ``shared``: the three
     weights ``(gate [H, Fs], up [H, Fs], down [Fs, H])`` of a shared
     expert, one expert of the same form over every token whose result is added
     unscaled: every member of an expert-parallel group computes it alike,
@@ -2014,7 +2016,7 @@ def moe_experts(x, router_weight, w_gate, w_up, w_down, top_k, first_expert=0,
             scaling=float(routed_scaling_factor),
             shared=None if shared is None else
             (t.get("s_gate"), t["s_up"], t["s_down"]),
-            train_router=bool(train_router))
+            train_router=bool(train_router), gate_epsilon=gate_epsilon)
 
     return apply(fn, x, router_weight, *given.values(), op_name="moe_experts")
 
@@ -2202,6 +2204,35 @@ def causal_conv1d(x, weight, bias=None, activation=None, name=None, *,
                 return out
             return tuple(jnp.split(out, np.cumsum(widths)[:-1], axis=2))
     return apply(fn, *args, op_name="causal_conv1d")
+
+
+@jax.named_scope(scopes.SHORT_CONV_OP)
+def gated_short_conv(bcz, weight, bias=None, name=None):
+    """A gated short convolution, the token mixer of a convolution layer
+    (LFM2's ``conv``) between its two projections: ``[B ; C ; z] = bcz``
+    [batch, T, 3 H], thirds in that order; ``v = B * z``; ``c`` the
+    depthwise causal convolution of v over time (``weight`` [K, H], tap
+    K - 1 on the position itself, ``bias`` [H] or None, NO activation);
+    ``y = C * c`` -> [batch, T, H] in bcz's type.  Float32 inside; a row
+    starts from a zero state.
+
+    On a TPU, without a bias and for the shapes they take (H whole lane
+    tiles, K <= 8, T a whole number of the kernels' blocks), two Pallas
+    kernels that read B, C and z out of ``bcz`` as the in-projection left
+    it and write y, and in the backward ``d bcz`` once
+    (ops/pallas/causal_conv.py); the form of ops/ssm.py elsewhere.
+    Counted at trace time: ``pallas.selected.gated_short_conv`` /
+    ``gated_short_conv.xla_path``."""
+    from ...ops.pallas import causal_conv as _kernels
+    from ...ops.pallas.support import choose_kernel
+    from ...ops.ssm import gated_short_conv as _plain
+    takes = bias is None and _kernels.gated_short_conv_supported(
+        tuple(bcz.shape), tuple(weight.shape), as_array(bcz).dtype)
+    if choose_kernel("gated_short_conv", takes):
+        return apply(_kernels.gated_short_conv, bcz, weight,
+                     op_name="gated_short_conv")
+    args = (bcz, weight) if bias is None else (bcz, weight, bias)
+    return apply(_plain, *args, op_name="gated_short_conv")
 
 
 @jax.named_scope(scopes.SSM_SCAN)

@@ -38,13 +38,18 @@ class MoELayer(Layer):
     ``train_router=False`` holds the router still: its weight gets no
     gradient and the stream none through it.  For a member that trains
     without its group, whose partial gradient would only teach the router
-    to prefer the experts held here (ops/moe.py)."""
+    to prefer the experts held here (ops/moe.py).
+
+    ``gate_epsilon``: what the gates' normalisation adds to the sum of a
+    token's chosen scores, where a family's public code states its own
+    (None: ops/moe.py's)."""
 
     def __init__(self, hidden_size, expert_width, num_experts, top_k,
                  held=None, norm_topk_prob=True, name=None,
                  scoring="softmax", selection_bias=False,
                  routed_scaling_factor=1.0, shared_width=None,
-                 train_router=True, expert_form="swiglu"):
+                 train_router=True, expert_form="swiglu",
+                 gate_epsilon=None):
         super().__init__()
         if scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"scoring {scoring!r}: 'softmax' or 'sigmoid'")
@@ -62,6 +67,8 @@ class MoELayer(Layer):
         self.scoring = scoring
         self.routed_scaling_factor = float(routed_scaling_factor)
         self.train_router = bool(train_router)
+        self.gate_epsilon = (None if gate_epsilon is None
+                             else float(gate_epsilon))
         n = len(held)
         init = I.Normal(0.0, 0.02)
         self.router_weight = self.create_parameter(
@@ -96,7 +103,7 @@ class MoELayer(Layer):
             routed_scaling_factor=self.routed_scaling_factor,
             shared=(self.shared_gate, self.shared_up, self.shared_down)
             if self.shared_width else None,
-            train_router=self.train_router)
+            train_router=self.train_router, gate_epsilon=self.gate_epsilon)
 
     def extra_repr(self):
         return (f"experts {self.held.start}..{self.held.stop - 1} of "

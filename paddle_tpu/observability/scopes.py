@@ -77,12 +77,20 @@ WINDOW_ATTENTION = "window_attention"  # a sliding-window call of
                               # scope: the kernels or the banded XLA form
 ATTN_GATE = "attn_gate"       # the sigmoid gate on attention's output
                               # (F.attention_output_gate)
+SHORT_CONV = "short_conv"     # a gated short-convolution mixer
+                              # (nn.ShortConv), every part of it, its two
+                              # projections too
+SHORT_CONV_OP = "short_conv_op"  # the gates and the taps alone,
+                              # C * conv(B * z) (F.gated_short_conv: the
+                              # kernels short_conv_fwd / short_conv_bwd, or
+                              # XLA's slices, products and shifted
+                              # multiply-adds)
 FUNCTIONALS = (ATTENTION, LINEAR_CROSS_ENTROPY, GELU, LAYER_NORM, EMBEDDING,
                DROPOUT, EVA_ATTENTION, EVA_POOL, RMS_NORM, ROPE, MOE,
                MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, DSA_INDEXER, DSA_SELECT,
                SPARSE_ATTENTION, QK_NORM, MOE_SHARED, MLA_ATTENTION, MTP,
                LOOP_STACK, LOOP_EXIT, SSM, SSM_CONV, SSM_SCAN, SSM_GATE_NORM,
-               FFN, WINDOW_ATTENTION, ATTN_GATE)
+               FFN, WINDOW_ATTENTION, ATTN_GATE, SHORT_CONV, SHORT_CONV_OP)
 
 # -- Pallas kernels ----------------------------------------------------------
 FLASH_FWD = "flash_fwd"
@@ -114,11 +122,16 @@ CONV_BWD = "conv_bwd"         # its backward, the taps transposed
 MOE_COMBINE = "moe_combine"   # an expert layer's sums over a token's held
                               # slots, rows in token order (under
                               # MOE_DISPATCH, forward and backward)
+SHORT_CONV_FWD = "short_conv_fwd"  # a gated short convolution out of the
+                              # in-projection's rows: B * z, the taps, times
+                              # C (ops/pallas/causal_conv.py)
+SHORT_CONV_BWD = "short_conv_bwd"  # its backward: the in-projection's
+                              # cotangent d[B ; C ; z] written once
 KERNELS = (FLASH_FWD, FLASH_BWD_DKV, EPILOGUE_FWD, EPILOGUE_BWD,
            FUSED_ADAM, PAGED_ATTENTION, COLLECTIVE_MATMUL_CHUNK, EVA_FWD,
            EVA_BWD_DQ, DSA_SCORES, DSA_THRESHOLD, DSA_KL, SPARSE_FWD,
            SPARSE_BWD_DKV, SSD_FWD, SSD_BWD, MOE_COMBINE, CONV_FWD,
-           CONV_BWD)
+           CONV_BWD, SHORT_CONV_FWD, SHORT_CONV_BWD)
 
 
 # -- values named for a rematerialisation policy -----------------------------

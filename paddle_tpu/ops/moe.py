@@ -94,14 +94,17 @@ __all__ = ["moe_route", "moe_experts", "moe_forward"]
 
 @jax.named_scope(scopes.MOE_ROUTER)
 def moe_route(x32, router_w, top_k, norm_topk_prob=True, scoring="softmax",
-              bias=None, scaling=1.0):
+              bias=None, scaling=1.0, gate_epsilon=None):
     """x32 [N, H] float32 -> (gates [N, top_k] float32, expert ids
     [N, top_k] int32 over all E).  The matmul at full float32 precision:
     a TPU's default for float32 operands is one bfloat16 pass.
     ``scoring``: "softmax" over the experts or "sigmoid" of each logit.
     ``bias`` [E] joins the scores for the selection only (no gradient
     reaches it); the gates are the chosen experts' scores, renormalised
-    under ``norm_topk_prob`` and multiplied by ``scaling``.  Equal
+    under ``norm_topk_prob`` and multiplied by ``scaling``.  The
+    normalisation divides by the chosen scores' sum plus ``gate_epsilon``,
+    a family's own constant (LFM2's public code adds 1e-6); None: nothing
+    for a softmax, DeepSeek-V3's floor of 1e-20 for sigmoids.  Equal
     selection values: the lower expert index."""
     logits = jax.lax.dot_general(
         x32.astype(jnp.float32), router_w.astype(jnp.float32),
@@ -120,7 +123,9 @@ def moe_route(x32, router_w, top_k, norm_topk_prob=True, scoring="softmax",
         gates = jnp.take_along_axis(scores, ids, -1)
     if norm_topk_prob:
         total = jnp.sum(gates, -1, keepdims=True)
-        if scoring == "sigmoid":
+        if gate_epsilon is not None:
+            total = total + gate_epsilon
+        elif scoring == "sigmoid":
             total = total + 1e-20   # DeepSeek-V3's floor: eight may vanish
         gates = gates / total
     if scaling != 1.0:
@@ -453,12 +458,13 @@ def _count_load(sizes, small):
 @jax.named_scope(scopes.MOE)
 def moe_forward(x32, router_w, w_gate, w_up, w_down, *, top_k, first,
                 norm_topk_prob=True, scoring="softmax", router_bias=None,
-                scaling=1.0, shared=None, train_router=True):
+                scaling=1.0, shared=None, train_router=True,
+                gate_epsilon=None):
     """x32 [..., H], the float32 normed stream -> the held experts' part
     of the layer's result, float32, same shape.  The experts take x in
     the weights' type; ``w_gate`` None: experts of the form without a
-    gate.  ``scoring``, ``router_bias`` and ``scaling`` are
-    ``moe_route``'s; ``shared`` the (gate, up, down) weights of a shared
+    gate.  ``scoring``, ``router_bias``, ``scaling`` and ``gate_epsilon``
+    are ``moe_route``'s; ``shared`` the (gate, up, down) weights of a shared
     expert of the same form (its gate None too), whose result over every
     token is added.  ``train_router``
     False: no gradient reaches the router's weight or, through the
@@ -485,7 +491,7 @@ def moe_forward(x32, router_w, w_gate, w_up, w_down, *, top_k, first,
     N = flat.shape[0]
     chunk = shape[-2] if x32.ndim > 2 else N      # one sequence of the batch
     gates, ids = moe_route(flat, router_w, top_k, norm_topk_prob, scoring,
-                           router_bias, scaling)
+                           router_bias, scaling, gate_epsilon)
     if not train_router:
         gates = jax.lax.stop_gradient(gates)
     local = ids - first

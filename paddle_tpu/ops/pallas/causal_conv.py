@@ -3,6 +3,9 @@ kernels, ``conv_fwd`` and ``conv_bwd``, that read their channels out of a
 wider array where they lie and write each part of them as an array of its
 own.  The mathematics is ``ops.ssm.causal_conv1d``'s: K shifted
 multiply-adds, the bias and silu in float32, the result in x's type.
+At the file's end a second pair over the same walk, ``short_conv_fwd``
+and ``short_conv_bwd``: the gated form ``C * conv(B * z)`` that is a
+convolution mixer's whole operator (``ops.ssm.gated_short_conv``).
 
 What they are for: a Mamba-2 mixer convolves the middle ``conv_dim``
 channels of its in-projection's output [B, T, W] and hands the result to
@@ -86,11 +89,13 @@ def _starts(first, parts):
     return out
 
 
-def _t_block(T, channels, itemsize):
+def _t_block(T, channels, itemsize, arrays=3):
     """Rows a grid step takes: the most of ``_T_BLOCKS`` that divide T
-    and whose blocks fit ``_BLOCK_BYTES`` in the backward; 0 if none."""
+    and whose blocks (``arrays`` of ``channels``, double-buffered: x, dy
+    and dx) fit ``_BLOCK_BYTES`` in the backward; 0 if none."""
     for tb in _T_BLOCKS:
-        if T % tb == 0 and 6 * tb * channels * itemsize <= _BLOCK_BYTES:
+        if (T % tb == 0
+                and 2 * arrays * tb * channels * itemsize <= _BLOCK_BYTES):
             return tb
     return 0
 
@@ -364,3 +369,208 @@ def causal_conv1d(x, weight, bias=None, activation=None, first=0,
     return _conv(x, weight, bias, int(first),
                  tuple(parts) if parts else (weight.shape[1],),
                  activation == "silu")
+
+
+# -- the gated form: y = C * conv(B * z) --------------------------------------
+# A convolution mixer's operator (``ops.ssm.gated_short_conv``): the
+# operand is its in-projection's output [batch, T, 3 H], B, C and z three
+# parts of H lanes, each starting on a multiple of its own width, so the
+# index-map rule above holds.  Left to XLA at [4, 8192, 6144] bfloat16 it
+# is three slices of the operand (403 MB read, 403 written), a product, the
+# taps, a product, and in the backward the float32 shifted products the
+# header speaks of.  ``short_conv_fwd`` reads the operand's rows once and
+# writes y [batch, T, H]: 537 MB; ``short_conv_bwd`` reads the operand and
+# dy and writes d[B ; C ; z] [batch, T, 3 H] once, as the in-projection's
+# cotangent: 940 MB.  They share the walk above: `_each_slab`, the chunks,
+# the halo block, the tap-gradient sums.
+
+# blocks of H lanes a grid step holds: B, C, z in and y out forward; B, C,
+# z and dy in, d[B ; C ; z] out backward
+_GATED_FWD_ARRAYS, _GATED_BWD_ARRAYS = 4, 7
+
+
+def gated_short_conv_supported(x_shape, w_shape, dtype) -> bool:
+    """Shapes the gated kernels take: x [B, T, 3 H], weight [K, H] with
+    K <= 8 taps, H whole lane tiles, T a whole number of T blocks
+    (``_t_block``), bfloat16 or float32."""
+    if len(x_shape) != 3 or len(w_shape) != 2 or not dtype_ok(dtype):
+        return False
+    K, H = w_shape
+    if not 1 <= K <= _MAX_TAPS or H % _LANES or x_shape[2] != 3 * H:
+        return False
+    return _t_block(x_shape[1], H, jnp.dtype(dtype).itemsize,
+                    _GATED_BWD_ARRAYS) > 0
+
+
+def _gated_operands(b_ref, z_ref, before, lanes, c, rows, K):
+    """Chunk ``c`` of ``v = B * z`` -> (its first row in the block, B's
+    and z's rows, the taps' operands); ``before``: B's and z's eight rows
+    before the block."""
+    r0, b, b_ext = _chunk_rows(b_ref, lanes, c, rows, before[0])
+    _, z, z_ext = _chunk_rows(z_ref, lanes, c, rows, before[1])
+    return r0, b, z, _shifted(b_ext * z_ext, b * z, K)
+
+
+def _tap_sum(w, vs):
+    out = vs[0] * w[0]
+    for wk, vk in zip(w[1:], vs[1:]):
+        out = out + vk * wk
+    return out
+
+
+def _gated_fwd_kernel(b_ref, c_ref, z_ref, bh_ref, zh_ref, w_ref, y_ref, *,
+                      K, rows, slab):
+    at_row_start = pl.program_id(1) == 0
+    chunks = b_ref.shape[1] // rows
+
+    def a_slab(_, lanes, among):
+        w = [w_ref[k:k + 1, among] for k in range(K)]
+        before = [_before(h, lanes, at_row_start) for h in (bh_ref, zh_ref)]
+
+        def chunk(c, carry):
+            r0, _, _, vs = _gated_operands(b_ref, z_ref, before, lanes, c,
+                                           rows, K)
+            gate = c_ref[0, pl.ds(r0, rows), lanes].astype(jnp.float32)
+            y_ref[0, pl.ds(r0, rows), lanes] = (
+                gate * _tap_sum(w, vs)).astype(y_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, chunks, chunk, 0)
+
+    _each_slab((y_ref.shape[2],), slab, a_slab)
+
+
+def _gated_bwd_kernel(b_ref, c_ref, z_ref, bh_ref, zh_ref, dy_ref, w_ref,
+                      dx_ref, dw_ref, after_ref, *, K, rows, slab):
+    f32 = jnp.float32
+    H = dy_ref.shape[2]
+    step = pl.program_id(1)            # T blocks from the row's end
+    at_row_start = step == pl.num_programs(1) - 1
+    chunks = b_ref.shape[1] // rows
+
+    @pl.when(step == 0)
+    def _():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+        after_ref[...] = jnp.zeros_like(after_ref)
+
+    def a_slab(_, lanes, among):
+        w = [w_ref[k:k + 1, among] for k in range(K)]
+        before = [_before(h, lanes, at_row_start) for h in (bh_ref, zh_ref)]
+
+        def part(p):                   # the slab's lanes in part p of dx
+            return pl.ds(pl.multiple_of(p * H + lanes.start, lanes.size),
+                         lanes.size)
+
+        def chunk(i, carry):
+            after, sums = carry[0], carry[1:]
+            r0, b, z, vs = _gated_operands(b_ref, z_ref, before, lanes,
+                                           chunks - 1 - i, rows, K)
+            here = pl.ds(r0, rows)
+            dy = dy_ref[0, here, lanes].astype(f32)
+            dx_ref[0, here, part(1)] = (dy * _tap_sum(w, vs)).astype(
+                dx_ref.dtype)
+            g = dy * c_ref[0, here, lanes].astype(f32)    # to the taps' sum
+            sums = tuple(acc + _fold(g * vk) for acc, vk in zip(sums, vs))
+            # the taps transposed: tap k meets the row K - 1 - k on
+            gext = jnp.concatenate([g, after], 0)
+            dv = g * w[K - 1]
+            for k in range(K - 1):
+                dv = dv + pltpu.roll(gext, rows + _SUB - (K - 1 - k),
+                                     0)[:rows] * w[k]
+            dx_ref[0, here, part(0)] = (dv * z).astype(dx_ref.dtype)
+            dx_ref[0, here, part(2)] = (dv * b).astype(dx_ref.dtype)
+            return (g[:_SUB],) + sums
+
+        zero = jnp.zeros((_SUB, lanes.size), f32)
+        carry = jax.lax.fori_loop(0, chunks, chunk,
+                                  (after_ref[:, among],) + (zero,) * K)
+        after_ref[:, among] = carry[0]
+        for k, acc in enumerate(carry[1:]):
+            dw_ref[0, k * _SUB:(k + 1) * _SUB, among] += acc
+
+    _each_slab((H,), slab, a_slab)
+
+
+def _gated_specs(H, tb, t_of):
+    """A T block's rows of B, C and z, and the 16 rows before B's and
+    z's (C meets no tap)."""
+    main, halo = _part_specs(0, (H, H, H), tb, t_of)
+    return main + [halo[0], halo[2]]
+
+
+@once_a_shape(2)
+def _gated_fwd_call(x, weight, interpret):
+    B, T, _ = x.shape
+    K, H = weight.shape
+    tb = _t_block(T, H, x.dtype.itemsize, _GATED_FWD_ARRAYS)
+    return pl.pallas_call(
+        functools.partial(_gated_fwd_kernel, K=K, rows=min(_ROWS, tb),
+                          slab=_SLAB),
+        grid=(B, T // tb),
+        in_specs=_gated_specs(H, tb, lambda t: t)
+        + [pl.BlockSpec((K, H), lambda b, t: (0, 0))],
+        out_specs=pl.BlockSpec((1, tb, H), lambda b, t: (b, t, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, T, H), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name=scopes.SHORT_CONV_FWD,
+    )(*([x] * 5), weight.astype(jnp.float32))
+
+
+@once_a_shape(3)
+def _gated_bwd_call(x, weight, dy, interpret):
+    """-> (d[B ; C ; z] [B, T, 3 H] in x's type; the taps' gradient
+    [B, K, 8, H] float32, to be summed over the batch and the
+    sublanes)."""
+    B, T, W = x.shape
+    K, H = weight.shape
+    tb = _t_block(T, H, x.dtype.itemsize, _GATED_BWD_ARRAYS)
+    nt = T // tb
+
+    def rows_of(width):
+        return pl.BlockSpec((1, tb, width), lambda b, t: (b, nt - 1 - t, 0))
+
+    dx, dw = pl.pallas_call(
+        functools.partial(_gated_bwd_kernel, K=K, rows=min(_ROWS, tb),
+                          slab=_SLAB),
+        grid=(B, nt),
+        in_specs=_gated_specs(H, tb, lambda t: nt - 1 - t)
+        + [rows_of(H), pl.BlockSpec((K, H), lambda b, t: (0, 0))],
+        out_specs=[rows_of(W),
+                   pl.BlockSpec((1, K * _SUB, H), lambda b, t: (b, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((B, T, W), x.dtype),
+                   jax.ShapeDtypeStruct((B, K * _SUB, H), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((_SUB, H), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name=scopes.SHORT_CONV_BWD,
+    )(*([x] * 5), dy, weight.astype(jnp.float32))
+    return dx, dw.reshape(B, K, _SUB, H)
+
+
+@jax.custom_vjp
+def _gated(x, weight):
+    return _gated_fwd_call(x, weight, _interpret())
+
+
+def _gated_fwd(x, weight):
+    return _gated(x, weight), (x, weight)
+
+
+def _gated_bwd(res, dy):
+    x, weight = res
+    dx, dw = _gated_bwd_call(x, weight, dy, _interpret())
+    return dx, jnp.sum(dw, (0, 2)).astype(weight.dtype)
+
+
+_gated.defvjp(_gated_fwd, _gated_bwd)
+
+
+def gated_short_conv(x, weight):
+    """``ops.ssm.gated_short_conv`` (no bias) through the kernels, for
+    what ``gated_short_conv_supported`` takes: x [B, T, 3 H] -> y
+    [B, T, H]."""
+    count_kernel_selection("gated_short_conv")
+    return _gated(x, weight)

@@ -1,6 +1,9 @@
 """The parts of a Mamba-2 state-space mixer (Dao & Gu 2024): a depthwise
 causal convolution, the selective state-space scan in its chunked
-("SSD") form with a hand-written backward, and the gated group norm.
+("SSD") form with a hand-written backward, and the gated group norm; and,
+over the same convolution, the gated short convolution that is a
+convolution mixer's whole operator (`gated_short_conv`: LFM2's ``conv``
+layers).
 
 The recurrence, for a head h of group g(h) = h // (H / G) with a state
 ``S_t`` [P, N] that starts at zero at the start of every row:
@@ -43,7 +46,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-__all__ = ["causal_conv1d", "ssd_scan", "gated_group_rms_norm"]
+__all__ = ["causal_conv1d", "gated_short_conv", "ssd_scan",
+           "gated_group_rms_norm"]
 
 
 def causal_conv1d(x, weight, bias=None, activation=None):
@@ -64,6 +68,20 @@ def causal_conv1d(x, weight, bias=None, activation=None):
     elif activation is not None:
         raise ValueError(f"activation {activation!r}: None or 'silu'")
     return out.astype(x.dtype)
+
+
+def gated_short_conv(bcz, weight, bias=None):
+    """A gated short convolution (the token mixer of LFM2's ``conv``
+    layers): ``[B ; C ; z] = bcz`` [batch, T, 3 H], thirds in that order;
+    ``v = B * z``; ``c = causal_conv1d(v, weight)`` (``weight`` [K, H],
+    tap K - 1 on the position itself, ``bias`` [H] or None, no
+    activation); ``y = C * c`` [batch, T, H].  Two elementwise gates round
+    K shifted multiply-adds, all in float32; the result has bcz's type.
+    The state at the start of a row is zero."""
+    H = weight.shape[1]
+    f32 = jnp.float32
+    B, C, z = (bcz[..., i * H:(i + 1) * H].astype(f32) for i in range(3))
+    return (C * causal_conv1d(B * z, weight, bias)).astype(bcz.dtype)
 
 
 def gated_group_rms_norm(y, z, weight, groups, epsilon=1e-5):

@@ -70,6 +70,11 @@ def _ssd_scan(dtype):
         _sds(1, 130, 1, 128, dtype=dtype), _sds(8, dtype=f32))
 
 
+def _gated_short_conv(dtype):
+    return F.gated_short_conv, (_sds(1, 64, 384, dtype=dtype),
+                                _sds(3, 128, dtype=dtype))
+
+
 # functional -> (its call at a small shape its gate takes in interpret
 # mode, the kernel selections of one traced call, its XLA side's counter)
 FUNCTIONALS = {
@@ -83,6 +88,8 @@ FUNCTIONALS = {
     "mla_attention": (_mla, ("mla_attention", "flash_attention"),
                       "mla_attention"),
     "ssd_scan": (_ssd_scan, ("ssd_scan",), "ssd_scan"),
+    "gated_short_conv": (_gated_short_conv, ("gated_short_conv",),
+                         "gated_short_conv"),
 }
 
 # flags, the operands' dtype (float16: no gate takes it) -> kernels?

@@ -275,6 +275,11 @@ def kernel_calls():
                 *a, "silu", 128, (128, 128))), (0, 1, 2)))(
             jnp.ones((1, 32, 512)), jnp.ones((4, 256)),
             jnp.ones((256,))).jaxpr, [])
+        # a convolution mixer's gated operator: its forward kernel and
+        # its backward's
+        found += _pallas_calls(jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(conv.gated_short_conv(*a)), (0, 1)))(
+            jnp.ones((1, 32, 384)), jnp.ones((3, 128))).jaxpr, [])
         # an expert layer's two sums over a token's held slots: the
         # weighted one forward, the plain one in the dispatch's transpose
         moe = importlib.import_module("paddle_tpu.ops.moe")
@@ -320,7 +325,7 @@ def test_every_pallas_call_site_carries_its_name(kernel_calls, kernel):
 
 
 def test_no_pallas_call_is_left_without_a_name(kernel_calls):
-    assert len(kernel_calls) == 21
+    assert len(kernel_calls) == 23
     assert {name for name, _ in kernel_calls} == set(scopes.KERNELS)
     src = os.path.join(os.path.dirname(paddle.__file__), "ops", "pallas")
     for path in glob.glob(os.path.join(src, "*.py")):
